@@ -29,6 +29,11 @@ import os as _os
 import threading
 from typing import Any, Dict, Optional
 
+# Compile cache directory: settled once, before anything imports jax
+# (_private/compile_cache.py); spawned workers inherit it.
+from ._private import compile_cache as _compile_cache
+_compile_cache.configure()
+
 # Opt-in runtime lock-order detector (devtools/lockdebug.py).  Installed
 # BEFORE the _private imports so the wrappers catch module-level framework
 # locks too, not just ones created after init().  Workers inherit the env

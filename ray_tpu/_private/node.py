@@ -114,6 +114,7 @@ class NodeManager:
         self._workers: Dict[WorkerID, WorkerHandle] = {}
         self._idle: Dict[str, List[WorkerID]] = {}
         self._lock = threading.RLock()
+        self._num_tpu_chips = num_tpu_chips
         self._chip_pool: List[int] = list(range(num_tpu_chips))
         self._closed = False
         # (sys.path ships per SPAWN, not frozen here: a driver that
@@ -646,7 +647,8 @@ class NodeManager:
             from ..accelerators.accelerator import get_accelerator
             mgr = get_accelerator("TPU")
             if mgr is not None and env_name == "TPU_VISIBLE_CHIPS":
-                env_vars.update(mgr.visibility_env(grant))
+                env_vars.update(mgr.visibility_env(
+                    grant, host_chips=self._num_tpu_chips))
             else:
                 env_vars[env_name] = ",".join(str(c) for c in grant)
         if target_worker is not None:

@@ -27,6 +27,10 @@ from .accelerator import AcceleratorManager, register_accelerator
 _CHIPS_PER_HOST = {"v2": 4, "v3": 4, "v4": 4, "v5e": 8, "v5p": 4, "v6e": 8}
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
+# Chips owned by one process -> its TPU_CHIPS_PER_HOST_BOUNDS.
+_SUBHOST_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
 TPU_ACCELERATOR_TYPE_ENV = "TPU_ACCELERATOR_TYPE"  # e.g. "v5litepod-256"
 TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 TPU_NAME_ENV = "TPU_NAME"
@@ -39,8 +43,18 @@ class TPUAcceleratorManager(AcceleratorManager):
     resource_name = "TPU"
 
     @staticmethod
-    def visibility_env(chip_ids: List[int]) -> Dict[str, str]:
-        return {TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chip_ids)}
+    def visibility_env(chip_ids: List[int],
+                       host_chips: Optional[int] = None) -> Dict[str, str]:
+        """Pin a process to ``chip_ids``.  A process that owns only part
+        of a host's ``host_chips`` also gets per-process bounds, or
+        libtpu lays it out for the whole host (reference: tpu.py
+        set_current_process_visible_accelerator_ids)."""
+        env = {TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chip_ids)}
+        bounds = _SUBHOST_BOUNDS.get(len(chip_ids))
+        if bounds and host_chips and len(chip_ids) < host_chips:
+            env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+            env[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+        return env
 
     @staticmethod
     def detect_num_chips() -> int:

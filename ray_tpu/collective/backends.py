@@ -225,17 +225,9 @@ class XlaBackend:
         else:
             addr = _wait_for(key).decode()
 
-        import os
-
         import jax
         # Must not touch the backend (jax.devices/default_backend) before
-        # distributed.initialize.  Platform comes from env only.
-        if "tpu" not in os.environ.get("JAX_PLATFORMS", "").lower():
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass
+        # distributed.initialize.  CPU worlds use gloo, jax's default.
         jax.distributed.initialize(addr, num_processes=self.world_size,
                                    process_id=self.rank)
         import numpy as np

@@ -143,7 +143,8 @@ def _wrap(kind: str, orig):
             # Nested coercion (tolist -> __array__): the outer call
             # already owns the timing; don't double count.
             return orig(self, *args, **kwargs)
-        if getattr(self, "_npy_value", None) is not None:
+        if getattr(self, "_npy_value", None) is not None \
+                or getattr(self, "_ray_tpu_host_value", False):
             # Host value already materialized: no device round-trip.
             # Bare int increment (GIL-atomic): no clock, no frames.
             _state.cached_fastpath += 1
@@ -151,11 +152,16 @@ def _wrap(kind: str, orig):
         _tls.active = True
         t0 = time.perf_counter()
         try:
-            return orig(self, *args, **kwargs)
+            out = orig(self, *args, **kwargs)
         finally:
             elapsed = time.perf_counter() - t0
             _tls.active = False
             _record(kind, elapsed)
+        # jax >= 0.9 fills _npy_value only when the read copied.  A
+        # zero-copy read (host-resident buffer) leaves it None although
+        # the host now holds the value: mark the array ourselves.
+        self._ray_tpu_host_value = True
+        return out
 
     wrapper.__name__ = getattr(orig, "__name__", kind)
     wrapper.__qualname__ = getattr(orig, "__qualname__", kind)

@@ -97,8 +97,7 @@ def write_prefill(kv_pages, ks, vs, page_ids, offs):
     """Scatter a prefilled prompt's K/V into every layer's pages in ONE
     device program (kv_pages: per-layer tuple of combined
     [NP, page, 2*Hkv, D] arrays, donated) — per-layer host-dispatched
-    scatters would cost 2*layers dispatches per admission, which over a
-    high-latency host link takes longer than the decode itself.
+    scatters would cost 2*layers dispatches per admission.
 
     ks/vs: [L, S_pad, Hkv, D] from prefill; page_ids/offs: [S_pad]
     (positions past the real prompt length point at reserved page 0, so
@@ -260,11 +259,11 @@ def decode_chunk(params: Dict[str, Any], kv_pages,
                  steps: int, temperature: float, top_k: int):
     """Device-resident multi-token decode: ``steps`` decode iterations
     under one jit with ON-DEVICE sampling, so the host syncs once per
-    chunk instead of once per token.  On a TPU behind a high-latency
-    host link (or any setup where per-step d2h dominates), this is the
-    difference between latency-bound and compute-bound decode — the
-    TPU-native analog of the reference engine's multi-step scheduling
-    (reference: vLLM num_scheduler_steps / multi-step decode).
+    chunk instead of once per token.  Where the per-step device-to-host
+    read dominates, this is the difference between latency-bound and
+    compute-bound decode — the TPU-native analog of the reference
+    engine's multi-step scheduling (reference: vLLM num_scheduler_steps
+    / multi-step decode).
 
     tokens/positions/active: [B] as in decode_step.  Returns
     (sampled [steps, B], new positions, kv_pages).  Sampling:

@@ -313,8 +313,7 @@ class InferenceEngine:
             # device program for all layers (bucket-static shape; padding
             # positions land in reserved page 0, which no block table
             # references).  Per-layer host-side scatters would cost
-            # 2*layers dispatches per admission — slower than the decode
-            # itself over a high-latency host link.
+            # 2*layers dispatches per admission.
             page_ids_np = np.zeros((bucket,), np.int32)
             for t in range(n):
                 page_ids_np[t] = pages[t // self.page_size]
@@ -899,9 +898,8 @@ class InferenceEngine:
                       max_chunks: int = 1_000_000) -> List[Request]:
         """Drain all queued work with DOUBLE-BUFFERED chunks: the device
         executes chunk k+1 while the host reads back and applies chunk
-        k — over a high-latency host link the readback latency is fully
-        hidden behind compute (reference analog: vLLM's async engine
-        loop overlapping scheduling with execution).
+        k, so the readback hides behind compute (reference analog:
+        vLLM's async engine loop overlapping scheduling with execution).
 
         Admission happens at pipeline bubbles (start, drain, or when
         requests are waiting — one bubble per admission wave), so new

@@ -20,6 +20,25 @@ from .engine import InferenceEngine, SamplingParams
 _ABANDON_GRACE_S = 5.0
 
 
+def _require_chip_grant() -> None:
+    """A replica the framework placed reaches a chip only through a
+    grant (``num_tpus >= 1``).  Placed with none on a host that has
+    chips, it would take the chip if it happened to be free and the CPU
+    if not: refuse, unless ``JAX_PLATFORMS=cpu`` asks for a CPU replica."""
+    import os
+
+    from ..accelerators.tpu import TPUAcceleratorManager as tpu
+    if "RAY_TPU_WORKER_ID" not in os.environ:
+        return  # built directly in the caller's own process
+    if tpu.get_current_process_visible_chips() is None \
+            and os.environ.get("JAX_PLATFORMS") != "cpu" \
+            and tpu.detect_num_chips() > 0:
+        raise RuntimeError(
+            "LLM replica started without a TPU grant on a host with "
+            "chips: deploy with num_tpus=1, or set JAX_PLATFORMS=cpu in "
+            "the worker's environment for a CPU replica")
+
+
 class LLMServer:
     """Deployment callable hosting one InferenceEngine.
 
@@ -37,6 +56,7 @@ class LLMServer:
                  engine_options: Optional[Dict[str, Any]] = None):
         from .._private import sanitizer
 
+        _require_chip_grant()
         params, cfg = build_params()
         self.engine = InferenceEngine(params, cfg,
                                       **(engine_options or {}))

@@ -191,7 +191,11 @@ def _attend(cfg: LlamaConfig, q, k, v, positions):
     if cfg.attention_impl in ("auto", "flash", "flash_interpret",
                               "reference"):
         impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
-        return _attention(q, k, v, causal=True, impl=impl)
+        from ..parallel.mesh import get_global_mesh
+
+        # Inside a pipeline stage the island is already manual over pp.
+        mesh = None if cfg.pp_microbatches else get_global_mesh()
+        return _attention(q, k, v, causal=True, impl=impl, mesh=mesh)
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 

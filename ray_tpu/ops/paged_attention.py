@@ -53,7 +53,8 @@ def paged_decode_attention(q, kv_pages, block_table, seq_lens,
 
     q: [B, H, D] (one new token per slot); kv_pages:
     [NP, page, 2*Hkv, D] combined; block_table: [B, P] page ids;
-    seq_lens: [B] sequence length INCLUDING the new token.
+    seq_lens: [B] sequence length INCLUDING the new token; 0 marks an
+    empty slot, whose output row is unspecified.
     Returns [B, H, D].
     """
     from .attention import _on_tpu
@@ -73,7 +74,12 @@ def _ragged_path(q, kv_pages, block_table, seq_lens):
     num_seqs = jnp.array([B], jnp.int32)
     out = ragged_paged_attention(
         q, kv_pages,
-        kv_lens=seq_lens.astype(jnp.int32),
+        # The kernel prefetches the next sequence's pages and waits for
+        # them only inside that sequence's kv loop: a sequence of length
+        # 0 leaves its DMA unawaited and the core halts at kernel exit
+        # (libtpu 0.0.34 checks the semaphores).  An empty slot reads one
+        # position of whatever page its table names; callers discard it.
+        kv_lens=jnp.maximum(seq_lens.astype(jnp.int32), 1),
         page_indices=block_table.astype(jnp.int32),
         cu_q_lens=cu_q_lens, num_seqs=num_seqs,
         sm_scale=1.0 / math.sqrt(D),

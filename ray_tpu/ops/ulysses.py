@@ -67,11 +67,6 @@ def ulysses_attention_sharded(q, k, v, mesh=None, *, axis_name: str = "sp",
     spec = in_spec if in_spec is not None else P(None, None, axis_name, None)
     fn = partial(ulysses_attention, axis_name=axis_name, causal=causal,
                  scale=scale)
-    if hasattr(jax, "shard_map"):
-        wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                                out_specs=spec, check_vma=False)
-    else:  # pre-stable API (jax < 0.6)
-        from jax.experimental.shard_map import shard_map as _shard_map
-        wrapped = _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)
     return wrapped(q, k, v)

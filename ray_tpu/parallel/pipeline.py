@@ -95,29 +95,12 @@ def pipeline_blocks(stacked_params, x, stage_body: Callable, *,
     body = partial(_pipeline_island, stage_body=stage_body,
                    axis_name=axis_name, num_stages=num_stages,
                    num_microbatches=M)
-    if hasattr(jax, "shard_map"):
-        island = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(param_specs, P()),
-            out_specs=P(),
-            axis_names={axis_name},  # manual over pp only; rest GSPMD
-            check_vma=False,
-        )
-    else:
-        # Pre-stable API (jax < 0.6): always take the fully-manual
-        # lowering (empty auto set) — partial-auto lowers a PartitionId
-        # op legacy XLA-CPU cannot partition.  The in/out specs claim
-        # every non-pp axis replicated, so shard_map all-gathers the
-        # batch/params onto each rank and the pp psum-broadcast output
-        # is truly replicated: numerically identical to
-        # manual-over-pp-only, at an activation-memory cost acceptable
-        # for the legacy fallback.
-        from jax.experimental.shard_map import shard_map as _shard_map
-        island = _shard_map(
-            body, mesh=mesh,
-            in_specs=(param_specs, P()),
-            out_specs=P(),
-            check_rep=False,
-        )
+    island = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(param_specs, P()),
+        out_specs=P(),
+        axis_names={axis_name},  # manual over pp only; rest GSPMD
+        check_vma=False,
+    )
     out = island(stacked_params, x_mb)
     return out.reshape(B, S, E)

@@ -59,7 +59,7 @@ class ElasticScalingPolicy:
 
     def _per_worker_resources(self) -> Dict[str, float]:
         res = dict(self.scaling.resources_per_worker or {})
-        if self.scaling.use_tpu and self.scaling.chips_per_worker:
+        if self.scaling.use_tpu:
             res["TPU"] = float(self.scaling.chips_per_worker)
         if not res:
             res = {"CPU": 1.0}

@@ -106,6 +106,15 @@ class ScalingConfig:
     # survivors block inside initialize).
     formation_timeout_s: float = 300.0
 
+    def __post_init__(self):
+        # A chip is only ever given by a grant: a "TPU" worker with no
+        # chips would land in a pooled process that takes the chip if it
+        # happens to be free and the CPU if not.
+        if self.use_tpu and self.chips_per_worker < 1:
+            raise ValueError(
+                "ScalingConfig(use_tpu=True) needs chips_per_worker >= 1: "
+                "a worker reaches a chip only through a TPU grant")
+
     @property
     def elastic(self) -> bool:
         return self.min_workers is not None or self.max_workers is not None

@@ -22,11 +22,4 @@ def test_llama2_7b_aot_compiles():
     assert "llama2_7b_fsdp8_aot_compile" in names
     assert "llama2_7b_pp2_fsdp4_aot_compile" in names
     for name, d in names.items():
-        if d.get("skipped"):
-            # Legacy jax (< 0.6, no jax.shard_map) cannot lower the
-            # GPipe island's partial-auto shard_map on XLA-CPU; the
-            # bench reports the pp spec skipped-with-reason there.
-            assert name == "llama2_7b_pp2_fsdp4_aot_compile", name
-            assert "shard_map" in d["skipped"]
-            continue
         assert d["ok"] and d["params_b"] > 6.0

@@ -1,0 +1,179 @@
+"""The rules of the chip path that can be checked without a chip: one
+compile-cache decision, chips only by grant, no fallback when the device
+is missing, and the flash kernel's shard_map island on a mesh."""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class TestCompileCache:
+    def test_env_wins_and_is_left_alone(self, monkeypatch):
+        from ray_tpu._private import compile_cache
+        monkeypatch.setenv(compile_cache.ENV, "/somewhere/else")
+        monkeypatch.setitem(sys.modules, "jax", None)  # leave jax's config
+        assert compile_cache.configure() == "/somewhere/else"
+        assert os.environ[compile_cache.ENV] == "/somewhere/else"
+
+    def test_default_is_one_fixed_path_in_the_checkout(self, monkeypatch):
+        from ray_tpu._private import compile_cache
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        monkeypatch.setitem(sys.modules, "jax", None)  # as before jax loads
+        assert compile_cache.configure() == os.path.join(REPO, ".jax_cache")
+        assert os.environ[compile_cache.ENV] == compile_cache.DEFAULT_DIR
+
+    def test_import_settles_it_before_jax_in_a_fresh_process(self):
+        code = ("import os, sys; os.environ.pop('JAX_COMPILATION_CACHE_DIR',"
+                " None); import ray_tpu; assert 'jax' not in sys.modules; "
+                "import jax; print(jax.config.jax_compilation_cache_dir)")
+        out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == os.path.join(REPO, ".jax_cache")
+
+
+class TestChipsOnlyByGrant:
+    @pytest.mark.parametrize("chips", [0, -1])
+    def test_use_tpu_without_chips_is_an_error(self, chips):
+        from ray_tpu.train import ScalingConfig
+        with pytest.raises(ValueError, match="chips_per_worker"):
+            ScalingConfig(use_tpu=True, chips_per_worker=chips)
+        assert ScalingConfig(use_tpu=True, chips_per_worker=1).use_tpu
+
+    @pytest.mark.parametrize("grant,host,bounds", [
+        ([0], 4, "1,1,1"), ([2, 3], 4, "1,2,1"),
+        ([0], 1, None), ([0, 1, 2, 3], 4, None), ([0], None, None)])
+    def test_sub_host_grant_sets_process_bounds(self, grant, host, bounds):
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+        env = TPUAcceleratorManager.visibility_env(grant, host_chips=host)
+        assert env["TPU_VISIBLE_CHIPS"] == ",".join(map(str, grant))
+        assert env.get("TPU_CHIPS_PER_HOST_BOUNDS") == bounds
+        assert env.get("TPU_HOST_BOUNDS") == ("1,1,1" if bounds else None)
+
+    @pytest.mark.parametrize("env,chips,raises", [
+        ({"RAY_TPU_WORKER_ID": "ab"}, 1, True),        # placed, no grant
+        ({"RAY_TPU_WORKER_ID": "ab"}, 0, False),       # no chips on host
+        ({"RAY_TPU_WORKER_ID": "ab", "TPU_VISIBLE_CHIPS": "0"}, 1, False),
+        ({"RAY_TPU_WORKER_ID": "ab", "JAX_PLATFORMS": "cpu"}, 1, False),
+        ({}, 1, False),                                # caller's own process
+    ])
+    def test_llm_replica_needs_a_grant_where_chips_exist(
+            self, monkeypatch, env, chips, raises):
+        from ray_tpu._private.config import Config
+        from ray_tpu.llm import serving
+        for k in ("RAY_TPU_WORKER_ID", "TPU_VISIBLE_CHIPS", "JAX_PLATFORMS"):
+            monkeypatch.delenv(k, raising=False)
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        monkeypatch.setattr(
+            Config, "get", classmethod(
+                lambda cls, name, _orig=Config.get:
+                chips if name == "tpu_chips_per_host_override"
+                else _orig(name)))
+        if raises:
+            with pytest.raises(RuntimeError, match="num_tpus=1"):
+                serving._require_chip_grant()
+        else:
+            serving._require_chip_grant()
+
+
+class TestNoFallback:
+    def test_on_tpu_reads_the_platform_and_swallows_nothing(self,
+                                                            monkeypatch):
+        import importlib
+
+        import jax
+        mod = importlib.import_module("ray_tpu.ops.attention")
+        assert mod._on_tpu() is False           # the CPU, really read
+
+        def broken():
+            raise RuntimeError("backend failed to initialize")
+        monkeypatch.setattr(jax, "default_backend", broken)
+        with pytest.raises(RuntimeError, match="failed to initialize"):
+            mod._on_tpu()
+
+    def test_bench_refuses_a_device_that_is_not_a_tpu(self):
+        sys.path.insert(0, REPO)
+        try:
+            import bench
+        finally:
+            sys.path.remove(REPO)
+        assert "cpu" not in bench.PEAK_BF16_FLOPS
+        with pytest.raises(SystemExit, match="needs a TPU"):
+            bench._detect_gen()
+
+    def test_chip_smoke_fails_without_an_accelerator(self, tmp_path):
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+            cwd=REPO, capture_output=True, text=True, timeout=300,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert proc.returncode != 0
+        assert '"ok"' not in proc.stdout
+
+    def test_chip_smoke_alone_in_a_directory_fails(self, tmp_path):
+        import shutil
+        shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+        env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+        proc = subprocess.run(
+            [sys.executable, "chip_smoke.py"], cwd=tmp_path, env=env,
+            capture_output=True, text=True, timeout=120)
+        assert proc.returncode != 0
+        assert '"ok"' not in proc.stdout
+
+    def test_chip_smoke_driver_never_imports_jax(self):
+        import ast
+        with open(os.path.join(REPO, "chip_smoke.py")) as f:
+            tree = ast.parse(f.read())
+        top = [n for n in tree.body
+               if isinstance(n, (ast.Import, ast.ImportFrom))]
+        names = {a.name.split(".")[0] for n in top
+                 if isinstance(n, ast.Import) for a in n.names}
+        names |= {(n.module or "").split(".")[0] for n in top
+                  if isinstance(n, ast.ImportFrom)}
+        assert not names & {"jax", "jaxlib", "numpy", "ray_tpu"}
+
+
+class TestFlashOnAMesh:
+    @pytest.fixture(scope="class")
+    def qkv(self):
+        import jax
+        import jax.numpy as jnp
+        ks = jax.random.split(jax.random.key(0), 3)
+        return tuple(jax.random.normal(k, (4, 4, 128, 32), jnp.float32)
+                     for k in ks)
+
+    @pytest.mark.parametrize("spec", ["fsdp4", "dp2xfsdp2xtp2"])
+    def test_island_matches_reference_forward_and_backward(self, qkv, spec):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from ray_tpu.ops.attention import attention, reference_attention
+        from ray_tpu.parallel import build_mesh
+        from ray_tpu.train import MeshConfig
+
+        n = 4 if spec == "fsdp4" else 8
+        mesh = build_mesh(MeshConfig.parse(spec).spec_for(n),
+                          devices=jax.devices()[:n])
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+        got = jax.jit(jax.value_and_grad(loss(lambda q, k, v: attention(
+            q, k, v, impl="flash_interpret", mesh=mesh)), (0, 1, 2)))(*qkv)
+        want = jax.value_and_grad(loss(reference_attention), (0, 1, 2))(*qkv)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+    def test_sequence_sharded_mesh_is_refused(self, qkv):
+        import jax
+        from ray_tpu.ops.attention import attention
+        from ray_tpu.parallel import MeshSpec, build_mesh
+        mesh = build_mesh(MeshSpec(sp=2), devices=jax.devices()[:2])
+        with pytest.raises(ValueError, match="ring"):
+            attention(*qkv, impl="flash_interpret", mesh=mesh)

@@ -1,0 +1,186 @@
+"""Main-path programs compile for a described v5e, without a chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (``v5e:2x2``, device kind ``TPU v5 lite``): what
+it refuses here — a misaligned slice, too much VMEM, a Mosaic kernel
+GSPMD cannot partition — it would refuse on the chip.  Nothing runs, so
+this says nothing about results or times; ``chip_smoke.py`` does.
+
+All of it lives in THIS file, behind module-scoped fixtures that are not
+``autouse``: describing the topology loads the TPU library, which only
+one process may hold, so it must happen in the one xdist worker that is
+given this file, after collection, never while a module is imported.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import pytest
+
+# chip_smoke.py's widths and serving shapes (bench.py's 1.36B model).
+MODEL = dict(vocab_size=32000, hidden=2048, layers=24, heads=16, kv_heads=16,
+             head_dim=128, mlp_dim=5632, max_seq_len=2048)
+SLOTS, NUM_PAGES, PAGE, MAX_SEQ = 64, 2200, 16, 640
+FLASH_SHAPE = (12, 16, 2048, 128)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # A program compiled for a described device is written to the
+    # persistent cache but cannot be read back without the chip.
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.fixture
+def v5e_block_sizes(monkeypatch):
+    """Upstream's tuned-block lookup asks jax.devices(), which is the
+    CPU here: answer for the described chip instead."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
+        tuned_block_sizes)
+    monkeypatch.setattr(tuned_block_sizes, "get_tpu_version", lambda: 5)
+    monkeypatch.setattr(tuned_block_sizes, "get_device_name",
+                        lambda num_devices=None: "TPU v5")
+
+
+def test_described_device_is_a_v5e(topo):
+    assert len(topo.devices) == 4
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+
+def test_flash_forward_compiles(one_chip):
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+
+    x = _sds(FLASH_SHAPE, jnp.bfloat16, one_chip)
+    compiled = jax.jit(partial(flash_attention, causal=True)).lower(
+        x, x, x).compile()
+    assert _kernels(compiled) == 1
+
+
+def test_flash_backward_compiles(one_chip):
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    x = _sds(FLASH_SHAPE, jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert _kernels(compiled) == 3          # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("pages_per_seq", [32, 40])
+def test_ragged_paged_decode_compiles(one_chip, v5e_block_sizes,
+                                      pages_per_seq):
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.paged_attention import _ragged_path
+
+    compiled = jax.jit(_ragged_path).lower(
+        _sds((SLOTS, 16, 128), jnp.bfloat16, one_chip),
+        _sds((NUM_PAGES, PAGE, 32, 128), jnp.bfloat16, one_chip),
+        _sds((SLOTS, pages_per_seq), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.int32, one_chip)).compile()
+    assert _kernels(compiled) == 1
+
+
+def test_decode_step_compiles_at_smoke_shapes(one_chip, v5e_block_sizes,
+                                              monkeypatch):
+    """The serving engine's whole decode program: 24 layers, 64 slots,
+    2200 pages of 16, the ragged kernel once per layer, in HBM."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.llm import _model
+    from ray_tpu.models import LlamaConfig, init_params
+
+    # paged_decode_attention picks its path from the platform JAX
+    # reports, which is the CPU here: steer it in the test.
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"),
+                        "_on_tpu", lambda: True)
+    cfg = LlamaConfig(**MODEL, dtype=jnp.bfloat16, remat=False)
+    params = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(partial(init_params, cfg, param_dtype=jnp.bfloat16),
+                       jax.random.key(0)))
+    kv = tuple(_sds((NUM_PAGES, PAGE, 2 * cfg.kv_heads, cfg.head_dim),
+                    cfg.dtype, one_chip) for _ in range(cfg.layers))
+    pages_per_seq = math.ceil(MAX_SEQ / PAGE)
+    compiled = jax.jit(
+        partial(_model.decode_step, cfg=cfg, page_size=PAGE),
+        donate_argnums=(1,)).lower(
+            params, kv,
+            _sds((SLOTS,), jnp.int32, one_chip),
+            _sds((SLOTS,), jnp.int32, one_chip),
+            _sds((SLOTS, pages_per_seq), jnp.int32, one_chip),
+            _sds((SLOTS,), jnp.bool_, one_chip)).compile()
+    assert _kernels(compiled) == cfg.layers
+    mem = compiled.memory_analysis()
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert resident < 15.75e9, resident     # one v5e chip's HBM budget
+
+
+def test_fsdp4_train_step_compiles(topo):
+    """Mesh training with flash attention: a Mosaic kernel cannot be
+    partitioned by GSPMD, so the step compiles for four chips only with
+    the kernel in a shard_map island (depth cut to 2 layers)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+    from ray_tpu.parallel.spmd import make_lm_train_step
+
+    cfg = LlamaConfig(**{**MODEL, "layers": 2}, dtype=jnp.bfloat16,
+                      remat=True, attention_impl="flash")
+    before = get_global_mesh()
+    try:
+        mesh = build_mesh(MeshSpec(fsdp=4), devices=topo.devices)
+        init_fn, step_fn, _ = make_lm_train_step(
+            cfg, mesh, learning_rate=1e-4, param_dtype=jnp.bfloat16)
+        params, opt = jax.eval_shape(init_fn, jax.random.key(0))
+        batch = {"tokens": jax.ShapeDtypeStruct((8, 2048), jnp.int32)}
+        compiled = step_fn.lower(params, opt, batch).compile()
+    finally:
+        set_global_mesh(before)
+    assert _kernels(compiled) >= 3
+    # Per-device batch rows x heads reach the kernel, not the global 8.
+    assert "bf16[32,2048,128]" in compiled.as_text()
