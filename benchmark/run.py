@@ -1,0 +1,170 @@
+#!/usr/bin/env python3
+"""benchmark/run.py --workload <config>.<traffic> --seed n --seconds s --trace 0|1
+
+Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
+prints, as the last line of its standard output, one JSON object with the
+cell's end-to-end metrics (``--trace 0``) or its per-layer metrics
+(``--trace 1``).  Everything that belongs to one configuration, one traffic
+mix or one metric is found by name:
+
+  configs/<config>.json     the sizes as run, the options the program needs
+  traffic/<traffic>.json    the mix; its ``kind`` names the runner
+  kinds/<kind>.py           ``run(cell) -> facts`` through the normal entry point
+  end_to_end/<name>.py      ``read(facts)`` for an end-to-end metric
+  layer_metrics/<stem>.py   ``read(facts)`` for ``<stem>`` or ``<stem>.<suffix>``
+
+Without the chips the cell asks for it exits non-zero and prints no result.
+``--rehearse`` walks the same control flow on the CPU at the toy sizes of
+``tests/tiny.json``, also for the cells that ``tests/later_cells.json`` keeps
+for a later PR, and can never print a result line.
+"""
+
+from __future__ import annotations
+
+import time
+
+T0 = time.time()            # the run's clock starts with the process
+
+import argparse             # noqa: E402
+import importlib            # noqa: E402
+import json                 # noqa: E402
+import os                   # noqa: E402
+import sys                  # noqa: E402
+import tempfile             # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+
+
+def _environment(rehearse: bool) -> None:
+    """Make ``benchmark`` and ``ray_tpu`` importable here and in every
+    worker, and settle the compile cache before anything imports jax."""
+    sys.path.insert(0, ROOT)
+    os.environ["PYTHONPATH"] = os.pathsep.join(
+        [ROOT] + [p for p in os.environ.get("PYTHONPATH", "").split(
+            os.pathsep) if p])
+    # Small programs too: a run after the first compiles nothing.
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+    # The program's session logs go under this run's TMPDIR, not to the
+    # fixed /tmp/ray_tpu that two sides of a comparison would share.
+    os.environ.setdefault("RAY_TPU_SESSION_DIR",
+                          os.path.join(tempfile.gettempdir(), "ray_tpu"))
+    if rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
+
+def _reader(folder: str, name: str):
+    stem = name.split(".")[0]
+    return importlib.import_module(f"benchmark.{folder}.{stem}").read
+
+
+def _cells_of(metric, bench):
+    """The cells a metric is read in: its own list, or every cell that
+    reports the end-to-end metric it moves (every cell, for an end-to-end
+    metric without a list)."""
+    if "workloads" in metric:
+        return metric["workloads"]
+    if "moves" in metric:
+        return _cells_of(next(m for m in bench["end_to_end"]
+                              if m["name"] == metric["moves"]), bench)
+    return [w["name"] for w in bench["workloads"]]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args()
+    _environment(args.rehearse)
+
+    from benchmark import common, weights
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    if args.rehearse:
+        # Cells kept for a later PR (PERF.md, section 7) are rehearsed too.
+        later = common.load_json("tests", "later_cells.json")
+        for table in ("workloads", "end_to_end", "per_layer"):
+            bench[table] = bench[table] + later[table]
+    entry = next((w for w in bench["workloads"]
+                  if w["name"] == args.workload), None)
+    if entry is None:
+        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
+    config = common.load_json("configs", entry["config"] + ".json")
+    mix = common.load_json("traffic", entry["traffic"] + ".json")
+    if args.rehearse:
+        tiny = common.load_json("tests", "tiny.json")
+        config = {**config, **tiny["config"],
+                  "train": {**config.get("train", {}), **tiny["train"]},
+                  "serve": {**config.get("serve", {}), **tiny["serve"]}}
+        mix = {**mix, **tiny["traffic"].get(mix["kind"], {})}
+    cell = {"name": entry["name"], "chips": entry["chips"], "config": config,
+            "traffic": mix, "sizes": weights.sizes_of(config),
+            "seed": args.seed, "seconds": args.seconds,
+            "trace": bool(args.trace), "rehearse": args.rehearse}
+    common.say("cell", name=entry["name"], chips=entry["chips"],
+               seed=args.seed, seconds=args.seconds, trace=args.trace,
+               parameters=weights.num_params(cell["sizes"]),
+               compile_cache=os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+
+    import ray_tpu  # noqa: F401  (settles JAX_COMPILATION_CACHE_DIR)
+    facts = importlib.import_module(
+        f"benchmark.kinds.{mix['kind']}").run(cell)
+    if "jax" in sys.modules:
+        raise RuntimeError("the driver process imported jax")
+    facts.update(cell=cell, setup_s=facts["window_start"] - T0)
+
+    def report(table, folder):
+        """Read this cell's metrics of one table, by BENCHMARK.json alone."""
+        out = {}
+        for m in table:
+            if entry["name"] in _cells_of(m, bench):
+                try:
+                    value = _reader(folder, m["name"])(facts)
+                except KeyError as e:
+                    if not args.rehearse:
+                        raise
+                    value = None        # no peaks for a CPU: refused
+                    common.say("metric", name=m["name"], refused=e)
+                common.say("metric", name=m["name"], value=value,
+                           unit=m["unit"])
+                if value is not None:
+                    out[m["name"]] = {"value": value, "unit": m["unit"]}
+        return out
+
+    metrics = report(bench["end_to_end"], "end_to_end")
+    if args.trace:
+        metrics = report(bench["per_layer"], "layer_metrics")
+
+    correct = common.verdict(facts["compared"], config["correct"]) \
+        and facts["failed"] == 0
+    device = {"platform": facts["device"]["platform"],
+              "kind": facts["device"]["kind"],
+              "count": facts["device"]["count"],
+              "memory_peak_bytes": facts["memory_peak_bytes"]}
+    result = {"correct": correct, "attempted": facts["attempted"],
+              "failed": facts["failed"], "metrics": metrics,
+              "device": device}
+    if args.trace:
+        reduced = facts.get("trace") or {}
+        if not reduced.get("busy_s") and not args.rehearse:
+            raise RuntimeError("the trace shows no operation on the device")
+        device["busy_s"] = reduced.get("busy_s")
+        device["window_s"] = reduced.get("window_s")
+        result["breakdown"] = reduced.get("breakdown")
+    if args.rehearse:
+        print(json.dumps({"rehearsal": True, "device": device,
+                          "metrics_named": sorted(metrics)}))
+        return 0
+    if device["platform"] != "tpu" or device["count"] != entry["chips"]:
+        raise RuntimeError(f"not the chips the cell asks for: {device}")
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
