@@ -124,10 +124,14 @@ def init(*, num_cpus: Optional[float] = None, num_tpus: Optional[int] = None,
             if ignore_reinit_error:
                 return _runtime_mod.driver_runtime()
             raise RuntimeError("ray_tpu.init() already called")
-        return _runtime_mod.init_runtime(
-            num_cpus=num_cpus, num_tpus=num_tpus, resources=resources,
-            namespace=namespace, head_port=head_port,
-            cluster_token=cluster_token, state_dir=state_dir)
+        # Cluster start: runtime, node, object store up.  The span ends
+        # with a runtime in place, so it is the first one recorded.
+        from .util import telemetry as _telemetry
+        with _telemetry.profile_span("runtime_init", "system"):
+            return _runtime_mod.init_runtime(
+                num_cpus=num_cpus, num_tpus=num_tpus, resources=resources,
+                namespace=namespace, head_port=head_port,
+                cluster_token=cluster_token, state_dir=state_dir)
 
 
 def is_initialized() -> bool:

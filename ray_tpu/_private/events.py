@@ -78,14 +78,24 @@ class TaskEvent:
 
 @dataclass
 class ProfileSpan:
-    """A user/system span for the chrome-trace timeline."""
+    """A finished user/system span, as ``telemetry._emit_span`` makes it:
+    wall-clock start and end, the process and thread it ran in, and in
+    ``extra`` its ``span_id`` / ``parent_id`` / ``self_s`` and whatever
+    the spans of one request or step share."""
     name: str
     category: str
     start_s: float
     end_s: float
-    pid: str  # row group (node / component)
-    tid: str  # row (worker / thread)
+    process: int
+    thread: int
     extra: Optional[Dict[str, Any]] = None
+
+    def to_dict(self) -> Dict[str, Any]:
+        """One line of ``<session>/trace/spans.jsonl``."""
+        return {**(self.extra or {}), "name": self.name,
+                "cat": self.category, "start": self.start_s,
+                "end": self.end_s, "process": self.process,
+                "thread": self.thread}
 
 
 class TaskEventBuffer:
@@ -99,11 +109,14 @@ class TaskEventBuffer:
 
     def __init__(self, max_events: int = 10000):
         self._max = max_events
+        # Spans come several to a decode step: their own, larger bound,
+        # so that a run's set-up spans outlive its step loop.
+        self._max_spans = max(max_events, 50_000)
+        from collections import deque
         self._events: "OrderedDict[str, TaskEvent]" = OrderedDict()
-        self._spans: List[ProfileSpan] = []
+        self._spans: "deque[ProfileSpan]" = deque(maxlen=self._max_spans)
         self._lock = threading.Lock()
         self.num_dropped = 0
-        from collections import deque
         self._pending: "deque" = deque()
         self._fold_at = max(1000, min(max_events * 2, 100_000))
 
@@ -187,11 +200,13 @@ class TaskEventBuffer:
                 telemetry.observe_many("ray_tpu_sched_stage_wait_seconds",
                                        vals, tags={"stage": stage})
 
-    def add_span(self, span: ProfileSpan) -> None:
+    def add_spans(self, spans: List[ProfileSpan]) -> None:
         with self._lock:
-            self._spans.append(span)
-            if len(self._spans) > self._max:
-                self._spans = self._spans[-self._max:]
+            self._spans.extend(spans)     # the deque drops the oldest
+
+    def spans(self) -> List[ProfileSpan]:
+        with self._lock:
+            return list(self._spans)
 
     def snapshot(self, filters: Optional[Dict[str, Any]] = None,
                  limit: int = 10000, stage: Optional[str] = None,
@@ -314,6 +329,11 @@ class TaskEventBuffer:
                 "name": sp.name, "cat": sp.category, "ph": "X",
                 "ts": sp.start_s * 1e6,
                 "dur": max(0.0, sp.end_s - sp.start_s) * 1e6,
-                "pid": sp.pid, "tid": sp.tid, "args": sp.extra or {},
+                # One row per THREAD, not per process: concurrent spans
+                # from different threads on a shared row would break the
+                # viewer's nesting of same-thread parent/child spans.
+                "pid": sp.category,
+                "tid": f"pid:{sp.process}:t{sp.thread % 100000}",
+                "args": sp.extra or {},
             })
         return trace

@@ -37,8 +37,8 @@ from .controller import NodeInfo
 from .ids import ActorID, NodeID, TaskID, WorkerID
 from .object_store import NativeArenaStore, create_store
 from .protocol import (ActorStateMsg, AllocReply, AllocRequest,
-                       BorrowRetained, ContainedRefs, GetRequest,
-                       KillWorker, ProfileReply, ProfileRequest,
+                       BorrowRetained, ContainedRefs, FlushTelemetry,
+                       GetRequest, KillWorker, ProfileReply, ProfileRequest,
                        PutFromWorker, ReadDone, RpcCall, RunTask,
                        SealObject, StackDumpReply, StackDumpRequest,
                        SubmitFromWorker, TaskDone, TaskSpec, WaitRequest,
@@ -91,6 +91,9 @@ class WorkerHandle:
     direct_since: float = 0.0
     reader: Optional[threading.Thread] = None
     ready: threading.Event = field(default_factory=threading.Event)
+    # (wall, monotonic) at spawn: the start of the worker_start span that
+    # ends when the worker registers.
+    spawned_at: tuple = (0.0, 0.0)
     send_lock: threading.Lock = field(default_factory=threading.Lock)
     assigned_chips: Dict[TaskID, List[int]] = field(default_factory=dict)
     # Messages queued before the worker registered (async spawn): flushed
@@ -223,6 +226,13 @@ class NodeManager:
                 handle.pending_msgs.clear()
             handle.ready.set()
             self._cancel_register_watchdog(handle)
+            # Process start-up of this worker: spawn -> registered.
+            wall, mono = handle.spawned_at
+            telemetry._emit_span(
+                "worker_start", "system", wall,
+                wall + (time.monotonic() - mono),
+                extra={"pid": handle.proc.pid,
+                       "worker_id": handle.worker_id.hex()})
             with self._lock:
                 self._poll_conns[conn] = handle
                 self._conns_version += 1
@@ -470,6 +480,7 @@ class NodeManager:
         # pip runtime envs run the worker under their venv interpreter
         # (reference: pip plugin's python_interpreter override).
         python = child_env.pop("RAY_TPU_PYTHON", sys.executable)
+        spawned_wall = time.time()
         try:
             proc = subprocess.Popen(
                 [python, "-m", "ray_tpu._private.worker_main"],
@@ -478,7 +489,8 @@ class NodeManager:
             for f in popen_kw.values():
                 f.close()  # child holds the fd; parent must not leak it
         self.cgroup.add_process(proc.pid)
-        handle = WorkerHandle(worker_id, proc, None)
+        handle = WorkerHandle(worker_id, proc, None,
+                              spawned_at=(spawned_wall, time.monotonic()))
         with self._lock:
             self._workers[worker_id] = handle
         # Async spawn: dispatches queue in pending_msgs and the task starts
@@ -970,6 +982,18 @@ class NodeManager:
                        and h.conn is not None]
         for h in handles:
             self._send(h, req)
+        return [h.worker_id for h in handles]
+
+    def broadcast_flush(self) -> List[WorkerID]:
+        """Ship a FlushTelemetry to every registered live worker (same
+        ready-gating as broadcast_stack_dump); returns the worker ids an
+        answer is expected from."""
+        with self._lock:
+            handles = [h for h in self._workers.values()
+                       if h.state != DEAD and h.ready.is_set()
+                       and h.conn is not None]
+        for h in handles:
+            self._send(h, FlushTelemetry())
         return [h.worker_id for h in handles]
 
     # -- receive ------------------------------------------------------------

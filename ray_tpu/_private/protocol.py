@@ -262,6 +262,15 @@ class ProfileReply:
 
 
 @dataclass
+class FlushTelemetry:
+    """node -> worker: ship the buffered profile spans and a final metrics
+    snapshot now (fire and forget), then answer with the control call
+    ``telemetry_flushed``.  Handled on the worker's receive thread, so a
+    worker busy in a task still answers.  The head sends it at shutdown,
+    before it closes the connections."""
+
+
+@dataclass
 class RpcCall:
     """worker -> node: generic control-plane call (KV, actor lookup, ...)."""
     request_id: int

@@ -408,6 +408,9 @@ class Runtime:
         # machinery; see ctl_profile).
         self._profile_seq = 0
         self._profiles: Dict[int, Dict[str, Any]] = {}
+        # One release per worker that answered the shutdown-time request
+        # for its buffered spans and final metrics.
+        self._flush_acks = threading.Semaphore(0)
         # Rate limiter for the worker-death flight recorder.
         # None = no bundle written yet (0.0 would suppress the first
         # bundle on a freshly booted host: monotonic ~= uptime).
@@ -2954,11 +2957,58 @@ class Runtime:
     def ctl_timeline(self):
         return self.events.chrome_trace()
 
-    def ctl_add_profile_span(self, name, category, start_s, end_s, pid, tid,
-                             extra=None):
-        self.events.add_span(
-            ProfileSpan(name, category, start_s, end_s, pid, tid, extra))
+    def ctl_add_profile_span(self, spans):
+        """A batch of finished spans, each the tuple that
+        ``telemetry._emit_span`` makes: the head's own one at a time, a
+        worker's with its metrics flush."""
+        self.events.add_spans([ProfileSpan(*s) for s in spans])
         return True
+
+    def ctl_telemetry_flushed(self):
+        """A worker answered ``FlushTelemetry``: its spans and metrics
+        frames came ahead of this one on the same connection."""
+        self._flush_acks.release()
+        return True
+
+    def _collect_terminal_flush(self, timeout_s: float = 2.0) -> None:
+        """Ask every live worker of this host for its buffered spans and
+        final metrics, and wait (bounded) for the answers: the last
+        seconds of a run are otherwise lost with the connection."""
+        try:
+            deadline = time.monotonic() + timeout_s
+            for _ in self.node.broadcast_flush():
+                if not self._flush_acks.acquire(
+                        timeout=max(0.0, deadline - time.monotonic())):
+                    break
+        except Exception as e:  # noqa: BLE001 — shutdown goes on
+            telemetry.note_swallowed("runtime.terminal_flush", e)
+
+    def write_trace_files(self) -> Optional[str]:
+        """``<session>/trace/spans.jsonl`` (every span the head holds,
+        of every process, one JSON object a line) and ``counters.json``
+        (the cluster-merged metric samples): what a finished run leaves
+        for whoever reads it afterwards."""
+        import json
+        from ray_tpu.util import metrics as _metrics
+        trace_dir = os.path.join(self.session_dir, "trace")
+        try:
+            os.makedirs(trace_dir, exist_ok=True)
+            with open(os.path.join(trace_dir, "spans.jsonl"), "w") as f:
+                for sp in self.events.spans():
+                    f.write(json.dumps(sp.to_dict(), default=str) + "\n")
+            by_name, acc = _metrics._aggregate_snapshots()
+            counters = {
+                "types": {n: m["type"] for n, m in by_name.items()},
+                "samples": {
+                    name: [{"tags": tags, "value": value}
+                           for _k, (tags, value) in sorted(bucket.items())]
+                    for name, bucket in acc.items()}}
+            with open(os.path.join(trace_dir, "counters.json"), "w") as f:
+                json.dump(counters, f)
+        except Exception as e:  # noqa: BLE001 — shutdown goes on
+            telemetry.note_swallowed("runtime.write_trace_files", e)
+            return None
+        return trace_dir
 
     _STORE_OP_KINDS = ("create", "seal", "get", "pin", "unpin", "delete")
     _STORE_SPILL_KEYS = (("spill", "num_spilled"),
@@ -3111,6 +3161,10 @@ class Runtime:
     # ------------------------------------------------------------------ #
 
     def shutdown(self) -> None:
+        # While the workers' connections still stand: their last spans
+        # and counters, then the two files a finished run leaves.
+        self._collect_terminal_flush()
+        self.write_trace_files()
         self._shutdown = True
         self.scheduler.stop()
         with self._actors_lock:

@@ -25,7 +25,8 @@ from .exceptions import TaskError
 from .ids import ActorID, ObjectID, TaskID, WorkerID
 from .object_store import ArenaReader, RemoteObjectReader
 from .protocol import (ActorStateMsg, AllocReply, AllocRequest,
-                       BorrowRetained, GetReply, GetRequest, KillWorker,
+                       BorrowRetained, FlushTelemetry, GetReply,
+                       GetRequest, KillWorker,
                        ProfileReply, ProfileRequest, PutFromWorker,
                        ReadDone, RpcCall, RpcReply, RunTask, SealObject,
                        StackDumpReply, StackDumpRequest, SubmitFromWorker,
@@ -922,6 +923,13 @@ class WorkerLoop:
                                      record))
             from . import sanitizer
             sanitizer.spawn(_capture, name="profile-capture")
+        elif isinstance(msg, FlushTelemetry):
+            # The head is about to close: spans and final metrics go out
+            # ahead of the answer (one FIFO outbox), from THIS thread so
+            # that a worker busy in a task answers too.
+            from ..util.metrics import flush_terminal
+            flush_terminal()
+            rt.send(RpcCall(0, rt.worker_id, "telemetry_flushed", (), {}))
         elif isinstance(msg, KillWorker):
             return False
         return True

@@ -132,6 +132,34 @@ class TPUAcceleratorManager(AcceleratorManager):
         return [int(c) for c in v.split(",") if c.strip() != ""]
 
 
+def init_backend() -> int:
+    """The program's own first touch of the backend, under the span
+    ``worker_backend_init``: without it the user's function (or its
+    weights) is the first to call jax, and the seconds a process takes to
+    reach its chips hide inside user code.  Returns the number of local
+    devices; on a TPU that differs from this process's chip grant it
+    raises, before anything is placed on the wrong devices.  Also the
+    place where a process that runs jax gets its ``xla_compile`` listener
+    (``profiler/recompile.py``)."""
+    from ..profiler import recompile
+    from ..util import telemetry
+    import time
+    extra: Dict[str, float] = {}
+    with telemetry.profile_span("worker_backend_init", "system", extra):
+        t0 = time.monotonic()
+        import jax
+        extra["import_s"] = time.monotonic() - t0     # the rest: the backend
+        devices = jax.local_devices()
+    recompile.ensure_listener()
+    granted = TPUAcceleratorManager.get_current_process_visible_chips()
+    if granted and devices[0].platform == "tpu" \
+            and len(devices) != len(granted):
+        raise RuntimeError(
+            f"this process was granted chips {granted} and jax sees "
+            f"{len(devices)} devices")
+    return len(devices)
+
+
 def get_tpu_coordinator_env_vars(slice_id: int, num_slices: int,
                                  coordinator_address: str) -> Dict[str, str]:
     """MEGASCALE env plumbing for multi-slice (DCN) jobs (reference:

@@ -68,26 +68,29 @@ def prefill(params: Dict[str, Any], tokens: jax.Array, length: jax.Array,
     x = params["embed"].astype(dt)[tokens]
 
     def body(x, layer):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, layer, h, positions[None, :])
-        # Causal masking suffices: queries at/after `length` are padding
-        # whose logits are never read, and valid queries only see valid
-        # (earlier) key positions.
-        from ..ops.attention import reference_attention
-        attn = reference_attention(q, k, v, causal=True)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn,
-                              layer["wo"].astype(dt))
-        x = x + attn_out
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, layer, h2)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(cfg, layer, h, positions[None, :])
+            # Causal masking suffices: queries at/after `length` are
+            # padding whose logits are never read, and valid queries only
+            # see valid (earlier) key positions.
+            from ..ops.attention import reference_attention
+            attn = reference_attention(q, k, v, causal=True)
+            attn_out = jnp.einsum("bhsd,hde->bse", attn,
+                                  layer["wo"].astype(dt))
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp(cfg, layer, h2)
         # [S, Hkv, D] per layer for the cache.
         return x, (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.clip(length - 1, 0, S - 1)
-    logits = jnp.einsum("e,ev->v", x[0, last].astype(jnp.float32),
-                        params["lm_head"].astype(jnp.float32))
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = jnp.clip(length - 1, 0, S - 1)
+        logits = jnp.einsum("e,ev->v", x[0, last].astype(jnp.float32),
+                            params["lm_head"].astype(jnp.float32))
     return logits, ks, vs
 
 
@@ -105,9 +108,10 @@ def write_prefill(kv_pages, ks, vs, page_ids, offs):
     from ..ops.paged_attention import combine_kv
     kv = list(kv_pages)
     dt = kv[0].dtype
-    for li in range(len(kv)):
-        comb = combine_kv(ks[li], vs[li]).astype(dt)   # [S_pad, 2Hkv, D]
-        kv[li] = kv[li].at[page_ids, offs, :, :].set(comb)
+    with jax.named_scope("cache_write"):
+        for li in range(len(kv)):
+            comb = combine_kv(ks[li], vs[li]).astype(dt)  # [S_pad, 2Hkv, D]
+            kv[li] = kv[li].at[page_ids, offs, :, :].set(comb)
     return tuple(kv)
 
 
@@ -163,9 +167,10 @@ def prefill_chunk(params: Dict[str, Any], kv_pages,
         # Write this chunk's K/V first, then gather the WHOLE sequence
         # back from pages: chunk-internal causality rides the same mask
         # as cross-chunk context.
-        comb = combine_kv(k[0].transpose(1, 0, 2),
-                          v[0].transpose(1, 0, 2)).astype(kv.dtype)
-        kv = kv.at[page_ids, offs, :, :].set(comb)
+        with jax.named_scope("cache_write"):
+            comb = combine_kv(k[0].transpose(1, 0, 2),
+                              v[0].transpose(1, 0, 2)).astype(kv.dtype)
+            kv = kv.at[page_ids, offs, :, :].set(comb)
         kv_pages[li] = kv
         pages = jnp.take(kv, block_table, axis=0)  # [P, page, 2Hkv, D]
         ks = pages[:, :, 0::2, :].reshape(S, Hkv, D)
@@ -229,27 +234,33 @@ def decode_step(params: Dict[str, Any], kv_pages,
     for li in range(n_layers):
         layer = jax.tree.map(lambda a, li=li: a[li], params["blocks"])
         kv = kv_pages[li]
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(cfg, layer, h, positions[:, None])
-        # ONE combined scatter: target kv[page_idx, page_off] is
-        # [B, 2*Hkv, D] with a contiguous window per index.
-        comb = combine_kv(k[:, :, 0, :], v[:, :, 0, :]).astype(kv.dtype)
-        kv = kv.at[page_idx, page_off, :, :].set(comb,
-                                                 unique_indices=False)
-        kv_pages[li] = kv
-        attn = paged_decode_attention(q[:, :, 0, :], kv, block_tables,
-                                      seq_lens, page_size)
-        attn_out = jnp.einsum("bhd,hde->be", attn, layer["wo"].astype(dt))
-        x = x + attn_out[:, None, :]
-        h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp(cfg, layer, h2)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(cfg, layer, h, positions[:, None])
+            # ONE combined scatter: target kv[page_idx, page_off] is
+            # [B, 2*Hkv, D] with a contiguous window per index.
+            with jax.named_scope("cache_write"):
+                comb = combine_kv(k[:, :, 0, :],
+                                  v[:, :, 0, :]).astype(kv.dtype)
+                kv = kv.at[page_idx, page_off, :, :].set(
+                    comb, unique_indices=False)
+            kv_pages[li] = kv
+            attn = paged_decode_attention(q[:, :, 0, :], kv, block_tables,
+                                          seq_lens, page_size)
+            attn_out = jnp.einsum("bhd,hde->be", attn,
+                                  layer["wo"].astype(dt))
+            x = x + attn_out[:, None, :]
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp(cfg, layer, h2)
     kv_pages = tuple(kv_pages)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    # bf16 reads with f32 MXU accumulation: casting lm_head to f32 would
-    # materialize a 4-byte copy of the largest matrix every step.
-    logits = jnp.einsum("be,ev->bv", x[:, 0, :], params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits.astype(jnp.float32), kv_pages
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        # bf16 reads with f32 MXU accumulation: casting lm_head to f32
+        # would materialize a 4-byte copy of the largest matrix every step.
+        logits = jnp.einsum("be,ev->bv", x[:, 0, :], params["lm_head"],
+                            preferred_element_type=jnp.float32)
+        return logits.astype(jnp.float32), kv_pages
 
 
 def decode_chunk(params: Dict[str, Any], kv_pages,

@@ -17,7 +17,6 @@ import math
 import threading
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -58,6 +57,10 @@ class Request:
     finish_reason: str = ""
     # Telemetry: submission time (perf_counter) for TTFT.
     t_submit: float = 0.0
+    # Admission time (perf_counter): the request left ``waiting`` for a
+    # slot, so queue wait (t_admit - t_submit) is told apart from
+    # prefill (t_first - t_admit).  0.0 until first admitted.
+    t_admit: float = 0.0
     # First-token time (perf_counter); 0.0 until the first token lands.
     t_first: float = 0.0
     # Admission sequence (preemption picks the youngest victim; -1 =
@@ -70,6 +73,15 @@ class Request:
     # engine was built with record_token_times=True (serve_load bench:
     # inter-token latency percentiles need per-token arrival times).
     token_times: List[float] = field(default_factory=list)
+
+
+def _named(name: str, fn, **fixed):
+    """``fn`` with ``fixed`` keyword arguments bound, under the name
+    ``name``: what ``jax.jit`` calls the program (``jit_<name>``)."""
+    def call(*args):
+        return fn(*args, **fixed)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def sample_logits(logits: np.ndarray, params: SamplingParams,
@@ -144,6 +156,7 @@ class InferenceEngine:
         self.prefill_chunk = prefill_chunk
         self.record_token_times = record_token_times
         self._admit_seq = itertools.count()
+        self._step_seq = itertools.count()
         self._prefilling: Dict[int, int] = {}   # slot -> prompt tokens done
         self._prefill_chunk_jit = None
         # RLock: step() -> _admit() nests; server threads call
@@ -151,8 +164,12 @@ class InferenceEngine:
         self._lock = threading.RLock()
         self._rng = np.random.default_rng(0)
 
+        # Named functions, not partials: a jitted partial is
+        # ``jit__unknown_`` in a device trace, a named one is the
+        # program's name there and in ``xla_compile`` spans.
         self._decode = jax.jit(
-            partial(_model.decode_step, cfg=cfg, page_size=page_size),
+            _named("decode_step", _model.decode_step, cfg=cfg,
+                   page_size=page_size),
             donate_argnums=(1,))
         self._decode_chunk = None
         # (steps, temp, top_k) -> jit fn.  LRU-bounded: varied sampling
@@ -167,16 +184,17 @@ class InferenceEngine:
         # chunks skip the host->device upload round-trips entirely.
         self._dev_state = None
         self._prefills = {
-            b: jax.jit(partial(_model.prefill, cfg=cfg),
-                       static_argnums=())
+            b: jax.jit(_named(f"prefill_{b}", _model.prefill, cfg=cfg))
             for b in self.prefill_buckets}
         self._write_prefill = jax.jit(_model.write_prefill,
                                       donate_argnums=(0,))
 
     # -- request intake -----------------------------------------------------
 
-    def add_request(self, prompt_tokens: List[int],
-                    params: Optional[SamplingParams] = None) -> int:
+    def submit(self, prompt_tokens: List[int],
+               params: Optional[SamplingParams] = None) -> Request:
+        """Queue a request and return it: a caller that follows its
+        progress holds the object (``running`` drops a finished one)."""
         params = params or SamplingParams()
         req = Request(next(self._req_ids), list(prompt_tokens), params,
                       t_submit=time.perf_counter())
@@ -184,7 +202,11 @@ class InferenceEngine:
             self.waiting.append(req)
             self.running[req.request_id] = req
             self._update_gauges()
-        return req.request_id
+        return req
+
+    def add_request(self, prompt_tokens: List[int],
+                    params: Optional[SamplingParams] = None) -> int:
+        return self.submit(prompt_tokens, params).request_id
 
     # -- telemetry ----------------------------------------------------------
 
@@ -227,6 +249,24 @@ class InferenceEngine:
     # -- scheduling ---------------------------------------------------------
 
     def _admit(self) -> None:
+        """Admission under the span ``engine_admit`` (no span for an empty
+        queue: that is every step of a saturated batch)."""
+        if not self.waiting:
+            self._update_gauges()
+            return
+        with telemetry.profile_span("engine_admit", "llm"):
+            self._admit_waiting()
+
+    def _note_admitted(self, req: Request) -> None:
+        """``req`` just left ``waiting`` for a slot: stamp the first
+        admission (a preempted request's later ones are recompute, not
+        queueing) and record its queue wait."""
+        if not req.t_admit:
+            req.t_admit = time.perf_counter()
+            telemetry.observe("ray_tpu_llm_queue_wait_seconds",
+                              max(0.0, req.t_admit - req.t_submit))
+
+    def _admit_waiting(self) -> None:
         """Move waiting requests into free slots (prefill + page alloc).
 
         Host work is batched: every admitted request's last-position
@@ -277,6 +317,7 @@ class InferenceEngine:
                 if pages is None:
                     break  # no KV memory; stay queued (backpressure)
                 self.waiting.pop(0)
+                self._note_admitted(req)
                 slot = free_slots[0]
                 req.slot = slot
                 req.pages = pages
@@ -297,6 +338,7 @@ class InferenceEngine:
             if pages is None:
                 break  # no KV memory; stay queued (backpressure)
             self.waiting.pop(0)
+            self._note_admitted(req)
             slot = free_slots[0]
 
             # Prefill on the padded bucket; returns last logits + K/V.
@@ -339,8 +381,10 @@ class InferenceEngine:
             self._update_gauges()
             return
         self._dev_state = None  # new slots: host mirrors are authoritative
-        all_logits = np.asarray(self._jax.numpy.stack(
-            [lg for _r, _s, lg in staged]))       # ONE host sync
+        with telemetry.profile_span("engine_prefill_sync", "llm",
+                                    extra={"requests": len(staged)}):
+            all_logits = np.asarray(self._jax.numpy.stack(
+                [lg for _r, _s, lg in staged]))       # ONE host sync
         now = time.perf_counter()
         for (req, slot, _lg), logits in zip(staged, all_logits):
             first_tok = self._sample_host(logits, req.params)
@@ -410,8 +454,8 @@ class InferenceEngine:
             self.block_tables[slot, base:base + len(pages)] = pages
         if self._prefill_chunk_jit is None:
             self._prefill_chunk_jit = self._jax.jit(
-                partial(_model.prefill_chunk, cfg=self.cfg,
-                        page_size=self.page_size),
+                _named("prefill_chunk", _model.prefill_chunk, cfg=self.cfg,
+                       page_size=self.page_size),
                 donate_argnums=(1,))
         toks = np.zeros((1, C), np.int32)
         toks[0, :end - done] = seed[done:end]
@@ -680,28 +724,40 @@ class InferenceEngine:
         Runs under the engine lock: add_request/cancel from server threads
         must not interleave with slot/page mutation (a cancel between page
         alloc and table write would let two sequences share pages)."""
-        jnp = self._jnp
         with self._lock:
-            self._admit()
-            self._prefill_tick()
-            finished = list(self._admission_finished)
-            self._admission_finished.clear()
-            if not any(self.slot_active):
-                return finished
-            self._ensure_decode_capacity(1)
-            if not any(self.slot_active):
-                return finished
-            t0 = time.perf_counter()
-            with telemetry.profile_span("engine_step", "llm"):
-                self._dev_state = None  # per-token path mutates mirrors
-                logits, self.kv_pages = self._decode(
-                    self.params, self.kv_pages,
-                    jnp.asarray(self.slot_tokens.copy()),
-                    jnp.asarray(self.slot_pos.copy()),
-                    jnp.asarray(self.block_tables.copy()),
-                    jnp.asarray(self.slot_active.copy()))
-                logits = np.asarray(logits)
-            decoded = 0
+            # group: the whole step; its parts are the spans an idle gap
+            # on the device can be given to.
+            with telemetry.profile_span(
+                    "engine_step", "llm",
+                    extra={"step": next(self._step_seq)}, group=True):
+                return self._step_locked()
+
+    def _step_locked(self) -> List[Request]:
+        jnp = self._jnp
+        self._admit()
+        self._prefill_tick()
+        finished = list(self._admission_finished)
+        self._admission_finished.clear()
+        if not any(self.slot_active):
+            return finished
+        self._ensure_decode_capacity(1)
+        if not any(self.slot_active):
+            return finished
+        t0 = time.perf_counter()
+        self._dev_state = None  # per-token path mutates mirrors
+        with telemetry.profile_span("engine_upload", "llm"):
+            tokens = jnp.asarray(self.slot_tokens.copy())
+            positions = jnp.asarray(self.slot_pos.copy())
+            tables = jnp.asarray(self.block_tables.copy())
+            active = jnp.asarray(self.slot_active.copy())
+        with telemetry.profile_span("engine_decode_dispatch", "llm"):
+            logits, self.kv_pages = self._decode(
+                self.params, self.kv_pages, tokens, positions, tables,
+                active)
+        with telemetry.profile_span("engine_logits_read", "llm"):
+            logits = np.asarray(logits)
+        decoded = 0
+        with telemetry.profile_span("engine_sample", "llm"):
             for slot in range(self.max_slots):
                 if not self.slot_active[slot]:
                     continue
@@ -716,12 +772,12 @@ class InferenceEngine:
                 self._maybe_finish(req, tok)
                 if req.finished:
                     finished.append(req)
-            self._note_decode(time.perf_counter() - t0, steps=1)
-            if decoded:
-                telemetry.inc("ray_tpu_llm_tokens_total", decoded,
-                              tags={"kind": "decode"})
-            self._update_gauges()
-            return finished
+        self._note_decode(time.perf_counter() - t0, steps=1)
+        if decoded:
+            telemetry.inc("ray_tpu_llm_tokens_total", decoded,
+                          tags={"kind": "decode"})
+        self._update_gauges()
+        return finished
 
     def step_chunk(self, max_steps: int = 32) -> List[Request]:
         """Admit + up to ``max_steps`` decode iterations in ONE device
@@ -813,11 +869,10 @@ class InferenceEngine:
         shape_key = (steps, sp0.temperature, sp0.top_k)
         fn = self._chunk_cache.get(shape_key)
         if fn is None:
-            from functools import partial
             fn = self._jax.jit(
-                partial(_model.decode_chunk, cfg=self.cfg,
-                        page_size=self.page_size, steps=steps,
-                        temperature=sp0.temperature, top_k=sp0.top_k),
+                _named(f"decode_chunk_{steps}", _model.decode_chunk,
+                       cfg=self.cfg, page_size=self.page_size, steps=steps,
+                       temperature=sp0.temperature, top_k=sp0.top_k),
                 donate_argnums=(1,))
             self._chunk_cache[shape_key] = fn
             while len(self._chunk_cache) > self._chunk_cache_cap:

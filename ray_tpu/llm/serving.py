@@ -55,11 +55,18 @@ class LLMServer:
     def __init__(self, build_params: Callable[[], tuple],
                  engine_options: Optional[Dict[str, Any]] = None):
         from .._private import sanitizer
+        from ..accelerators.tpu import init_backend
+        from ..util import telemetry
 
         _require_chip_grant()
-        params, cfg = build_params()
-        self.engine = InferenceEngine(params, cfg,
-                                      **(engine_options or {}))
+        # The replica's own first touch of the backend, before the
+        # weights are: seconds to the chip apart from seconds to weights.
+        init_backend()
+        with telemetry.profile_span("llm_build_params", "llm"):
+            params, cfg = build_params()
+        with telemetry.profile_span("engine_init", "llm"):
+            self.engine = InferenceEngine(params, cfg,
+                                          **(engine_options or {}))
         self._results: Dict[int, Any] = {}
         self._events: Dict[int, threading.Event] = {}
         # request id -> monotonic deadline after which the request
@@ -73,15 +80,17 @@ class LLMServer:
 
     def _submit(self, prompt_tokens: List[int], params: SamplingParams,
                 timeout_s: float) -> tuple:
-        """Register + enqueue one request; kicks the drive thread."""
+        """Register + enqueue one request; kicks the drive thread.
+        Returns (request id, its completion event, the Request)."""
         ev = threading.Event()
         with self._lock:
-            rid = self.engine.add_request(list(prompt_tokens), params)
+            req = self.engine.submit(list(prompt_tokens), params)
+            rid = req.request_id
             self._events[rid] = ev
             self._deadlines[rid] = time.monotonic() + timeout_s \
                 + _ABANDON_GRACE_S
         self._work.set()
-        return rid, ev
+        return rid, ev, req
 
     def _forget(self, rid: int) -> None:
         with self._lock:
@@ -137,8 +146,8 @@ class LLMServer:
         {"output_tokens": [...], "finish_reason": ...}"""
         params = SamplingParams.from_body(body)
         timeout_s = float(body.get("timeout_s", 300))
-        rid, ev = self._submit(list(body["prompt_tokens"]), params,
-                               timeout_s)
+        rid, ev, _req = self._submit(list(body["prompt_tokens"]), params,
+                                     timeout_s)
         if not ev.wait(timeout=timeout_s):
             # Abandon cleanly: release the engine slot/pages and drop the
             # bookkeeping so repeated timeouts can't leak.
@@ -158,18 +167,22 @@ class LLMServer:
         actor call, so each token publishes the moment it exists —
         reference: serve.llm streaming chat completions)."""
         import time as _time
+
+        from ..util import telemetry
         params = SamplingParams.from_body(body)
         timeout_s = float(body.get("timeout_s", 300))
-        rid, ev = self._submit(list(body["prompt_tokens"]), params,
-                               timeout_s)
-        with self._lock:
-            req = self.engine.running.get(rid)
+        # The Request that _submit created, not a later lookup in
+        # ``engine.running``: a request that finished first is gone there.
+        rid, ev, req = self._submit(list(body["prompt_tokens"]), params,
+                                    timeout_s)
         deadline = _time.monotonic() + timeout_s
         sent = 0
+        polls = 0
         try:
             while True:
                 done = ev.wait(timeout=0.01)
-                toks = list(req.output_tokens) if req is not None else []
+                polls += 1
+                toks = list(req.output_tokens)
                 while sent < len(toks):
                     yield {"token": int(toks[sent]), "index": sent}
                     sent += 1
@@ -182,6 +195,9 @@ class LLMServer:
                     yield {"error": "generation timed out"}
                     return
         finally:
+            # How much polling ran beside the engine's loop: one counter,
+            # not a span per 10 ms round of every stream.
+            telemetry.inc("ray_tpu_llm_stream_polls_total", polls)
             self._forget(rid)
             # A consumer that drops the generator mid-stream
             # (GeneratorExit) must not leave the slot generating to
@@ -197,7 +213,7 @@ class LLMServer:
         # k-th request is legitimately uncollected for up to k*600 s —
         # its abandon deadline must cover the whole batch, not one slot.
         evs = [self._submit(list(p), SamplingParams(max_tokens=max_tokens),
-                            timeout_s=600.0 * len(prompts))
+                            timeout_s=600.0 * len(prompts))[:2]
                for p in prompts]
         out = []
         for rid, ev in evs:

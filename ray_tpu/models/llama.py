@@ -199,6 +199,7 @@ def _attend(cfg: LlamaConfig, q, k, v, positions):
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 
+@jax.named_scope("block/attn")
 def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
     """Attention residual branch. x: [B, S, E] -> [B, S, E]."""
     dt = cfg.dtype
@@ -217,6 +218,7 @@ def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
     return x + attn_out
 
 
+@jax.named_scope("block/mlp")
 def _mlp_half(cfg: LlamaConfig, x, layer):
     """MLP/MoE residual branch. x: [B, S, E] -> ([B, S, E], aux)."""
     dt = cfg.dtype
@@ -256,7 +258,8 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     sharded callers pass their shard's global positions).
     """
     dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
@@ -323,7 +326,8 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         auxes = jnp.zeros((), jnp.float32)
     else:
         x, auxes = jax.lax.scan(scan_body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, jnp.sum(auxes)
 
 
@@ -396,26 +400,28 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
         denom = jnp.maximum(jnp.sum(mask), 1.0)
     if cfg.loss_chunks:
         x, aux = _forward_hidden(params, tokens, cfg, positions)
-        nll_sum = _chunked_nll_sum(x, params["lm_head"], targets, mask,
-                                   cfg.loss_chunks, cfg.dtype)
-        loss = nll_sum / denom
+        with jax.named_scope("loss"):
+            nll_sum = _chunked_nll_sum(x, params["lm_head"], targets, mask,
+                                       cfg.loss_chunks, cfg.dtype)
+            loss = nll_sum / denom
     else:
         logits, aux = forward_with_aux(params, tokens, cfg, positions)
-        # logsumexp formulation: nll = LSE(logits) - logit[target].
-        # Unlike log_softmax this never materializes a second
-        # [B, S, vocab] array — the LSE reduce fuses into the lm_head
-        # matmul consumer, and the backward's softmax is recomputed
-        # elementwise into the dW/dx matmuls.
-        logits = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        # promise_in_bounds: targets are token ids < vocab by
-        # construction (see _chunked_nll_sum for why the default NaN
-        # fill breaks under a vocab-sharded partitioned gather).
-        tgt = jnp.take_along_axis(logits, targets[..., None],
-                                  axis=-1,
-                                  mode="promise_in_bounds")[..., 0]
-        nll = lse - tgt
-        loss = jnp.sum(nll * mask) / denom
+        with jax.named_scope("loss"):
+            # logsumexp formulation: nll = LSE(logits) - logit[target].
+            # Unlike log_softmax this never materializes a second
+            # [B, S, vocab] array — the LSE reduce fuses into the lm_head
+            # matmul consumer, and the backward's softmax is recomputed
+            # elementwise into the dW/dx matmuls.
+            logits = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            # promise_in_bounds: targets are token ids < vocab by
+            # construction (see _chunked_nll_sum for why the default NaN
+            # fill breaks under a vocab-sharded partitioned gather).
+            tgt = jnp.take_along_axis(logits, targets[..., None],
+                                      axis=-1,
+                                      mode="promise_in_bounds")[..., 0]
+            nll = lse - tgt
+            loss = jnp.sum(nll * mask) / denom
     if cfg.num_experts:
         loss = loss + 0.01 * aux / cfg.layers
     return loss

@@ -213,6 +213,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             _vmem((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **params,
     )(qr, kr, vr)
     out = res[0].reshape(B, H, Sq, D)
@@ -373,6 +374,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[_vmem((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
         **params,
     )(qr, kr, vr, dor, lser, dir_).reshape(B, H, Sq, D)
 
@@ -420,6 +422,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             _vmem((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
         **params,
     )(qr, kr, vr, dor, lser, dir_)
 

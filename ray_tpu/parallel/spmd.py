@@ -14,6 +14,7 @@ from __future__ import annotations
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..util import telemetry
 from .mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ, MeshSpec, build_mesh,
                    set_global_mesh)
 from .sharding import (ShardingRules, default_rules, logical_to_pspec,
@@ -141,7 +142,16 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
         finally:
             jax.config.update("jax_threefry_partitionable", old)
 
-    def step(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
+        with jax.named_scope("forward_backward"):
+            loss, grads = loss_and_grads(params, batch)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
+        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
+
+    def loss_and_grads(params, batch):
         if grad_accum > 1:
             def split(v):
                 b = v.shape[0]
@@ -176,21 +186,21 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
             gzero = jax.tree.map(jnp.zeros_like, params)
             (grads, loss), _ = jax.lax.scan(
                 acc_body, (gzero, jnp.zeros((), jnp.float32)), micro)
-        else:
-            loss, grads = jax.value_and_grad(L.loss_fn)(params, batch, cfg)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
+            return loss, grads
+        return jax.value_and_grad(L.loss_fn)(params, batch, cfg)
 
+    # The jitted function's name is the program's name in a device trace
+    # (``jit_train_step``); the scopes inside are metadata only.
     step_fn = jax.jit(
-        step,
+        train_step,
         in_shardings=(param_shardings, opt_shardings, bsharding),
         out_shardings=(param_shardings, opt_shardings, None),
         donate_argnums=(0, 1) if donate else ())
 
     def place_batch(batch: Dict[str, Any]):
-        return {k: jax.device_put(v, bsharding) for k, v in batch.items()}
+        with telemetry.profile_span("train_place_batch", "train"):
+            return {k: jax.device_put(v, bsharding)
+                    for k, v in batch.items()}
 
     return init_fn, step_fn, place_batch
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import logging
 import sys
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 from ..util import telemetry
@@ -37,6 +38,8 @@ logger = logging.getLogger("ray_tpu.profiler")
 
 #: jax.monitoring event that marks one real XLA compilation.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: ... and the one a fetch from the persistent compile cache records.
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _lock = threading.Lock()
 _listener_registered = False
@@ -69,10 +72,29 @@ class _SiteState:
         self.donate_argnums: tuple = ()
 
 
-def _on_event_duration(event: str, duration_s: float, **_kw) -> None:
-    """jax.monitoring listener: charge backend compiles to whichever
-    tracked site is currently executing on this thread."""
-    if not _enabled or event != _COMPILE_EVENT:
+def _on_event_duration(event: str, duration_s: float, **kw) -> None:
+    """jax.monitoring listener.  EVERY backend compile (or fetch from the
+    persistent cache: jax times both under this event) becomes an
+    ``xla_compile`` span with the program's name, tracked site or not;
+    with the detector on it is also charged to whichever tracked site is
+    currently executing on this thread."""
+    if event != _COMPILE_EVENT:
+        return
+    program = str(kw.get("fun_name") or "unknown")
+    hit = bool(getattr(_tls, "cache_hit", False))
+    _tls.cache_hit = False
+    # Wall clock on purpose: it anchors the span among the others; the
+    # length is jax's own measurement.
+    end = time.time()
+    telemetry._emit_span(
+        "xla_compile", "compile",
+        end - duration_s, end,  # ray-tpu: noqa[RT203]
+        extra={"program": program, "seconds": duration_s,
+               "cache_hit": hit})
+    telemetry.inc("ray_tpu_xla_compiles_total", tags={"program": program})
+    if hit:
+        telemetry.inc("ray_tpu_compile_cache_hits_total")
+    if not _enabled:
         return
     frame = getattr(_tls, "site", None)
     if frame is None:
@@ -81,7 +103,19 @@ def _on_event_duration(event: str, duration_s: float, **_kw) -> None:
     frame["compile_s"] += duration_s
 
 
-def _ensure_listener() -> bool:
+def _on_event(event: str, **_kw) -> None:
+    """jax.monitoring listener: a persistent-cache hit, reported (where
+    this jax reports it) inside the compile that it saves, on its
+    thread."""
+    if event == _CACHE_HIT_EVENT:
+        _tls.cache_hit = True
+
+
+def ensure_listener() -> bool:
+    """Register the ``jax.monitoring`` listeners once, if jax is loaded
+    (never imports it).  ``accelerators.tpu.init_backend`` calls this for
+    every process that runs jax on a main path, so ``xla_compile`` spans
+    do not depend on the detector being installed."""
     global _listener_registered
     if _listener_registered:
         return True
@@ -96,6 +130,10 @@ def _ensure_listener() -> bool:
         with _lock:
             if not _listener_registered:
                 register(_on_event_duration)
+                on_event = getattr(jax.monitoring,
+                                   "register_event_listener", None)
+                if on_event is not None:
+                    on_event(_on_event)
                 _listener_registered = True
     except Exception:  # noqa: BLE001 — detector must never break user code
         return False
@@ -199,7 +237,7 @@ class TrackedFunction:
         return getattr(self.__wrapped__, name)
 
     def __call__(self, *args, **kwargs):
-        if not _enabled or not _ensure_listener():
+        if not _enabled or not ensure_listener():
             return self.__wrapped__(*args, **kwargs)
         frame = {"compiles": 0, "compile_s": 0.0}
         prev = getattr(_tls, "site", None)
@@ -306,7 +344,7 @@ def install(patch_jit: bool = True) -> bool:
     global _enabled, _jit_patched, _orig_jit
     _enabled = True
     if not patch_jit or _jit_patched:
-        return _ensure_listener()
+        return ensure_listener()
     if "jax" not in sys.modules:
         # Deliberately NOT importing jax here; callers install after
         # their own jax import (the train worker does).
@@ -328,8 +366,8 @@ def install(patch_jit: bool = True) -> bool:
         jax.jit = _tracking_jit
         _jit_patched = True
     except Exception:  # noqa: BLE001 — fall back to explicit track()
-        return _ensure_listener()
-    return _ensure_listener()
+        return ensure_listener()
+    return ensure_listener()
 
 
 def uninstall() -> None:

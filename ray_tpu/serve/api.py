@@ -103,11 +103,15 @@ class _ReplicaActor:
 
     def __init__(self, cls_blob: bytes, init_args, init_kwargs):
         from .._private import serialization
-        target = serialization.loads_control(cls_blob)
-        if isinstance(target, type):
-            self._callable = target(*init_args, **init_kwargs)
-        else:
-            self._callable = target
+        from ..util import telemetry
+        # Seconds to a ready replica: unpickling the deployment and its
+        # constructor (for an LLM replica: backend, weights, engine).
+        with telemetry.profile_span("serve_replica_init", "serve"):
+            target = serialization.loads_control(cls_blob)
+            if isinstance(target, type):
+                self._callable = target(*init_args, **init_kwargs)
+            else:
+                self._callable = target
 
     def _resolve_target(self, method: str):
         target = getattr(self._callable, method, None)
@@ -668,9 +672,12 @@ def run(app: Application, *, name: Optional[str] = None,
         ray_tpu.init()
     dep = app.deployment if isinstance(app, Application) else app
     from .._private import serialization
-    ctrl = _controller()
-    ray_tpu.get(ctrl.deploy.remote(serialization.dumps_control(dep)),
-                timeout=300)
+    from ..util import telemetry
+    with telemetry.profile_span("serve_run", "serve",
+                                extra={"deployment": dep.name}):
+        ctrl = _controller()
+        ray_tpu.get(ctrl.deploy.remote(serialization.dumps_control(dep)),
+                    timeout=300)
     with _app_lock:
         _routers.pop(dep.name, None)  # drop stale replica cache
     if http_port is not None:

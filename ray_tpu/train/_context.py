@@ -161,7 +161,15 @@ def get_context() -> TrainContext:
 def report(metrics: Dict[str, Any],
            checkpoint: Optional[Checkpoint] = None) -> None:
     """Report metrics (+ checkpoint) from inside the train fn."""
+    from ..util import telemetry
     ctx = get_context()
+    with telemetry.profile_span("train_report", "train",
+                                extra={"step": ctx._report_seq + 1}):
+        _report(ctx, metrics, checkpoint)
+
+
+def _report(ctx: "TrainContext", metrics: Dict[str, Any],
+            checkpoint: Optional[Checkpoint]) -> None:
     ctx._report_seq += 1
     from .._private.api import _control
     from ..profiler import attribution

@@ -105,8 +105,14 @@ class TrainWorker:
             checkpoint_options=ctx_info.get("checkpoint"),
             mesh_info=ctx_info.get("mesh"))
         _context.set_context(ctx)
+        from ..util import telemetry
         try:
-            fn = serialization.loads_control(fn_blob)
+            # Unpickling restores the fn's module imports, jax among them.
+            with telemetry.profile_span("train_load_fn", "train"):
+                fn = serialization.loads_control(fn_blob)
+            if ctx_info.get("use_tpu"):
+                from ..accelerators.tpu import init_backend
+                init_backend()
             # Recompile detector: shape churn in the user's jitted step
             # fn is the #1 silent TPU step-time regression — every train
             # worker watches for it by default
@@ -119,10 +125,15 @@ class TrainWorker:
             if os.environ.get("RAY_TPU_RECOMPILE_DETECT", "1") != "0":
                 from ..profiler import recompile
                 recompile.install()
-            if config is not None:
-                fn(config)
-            else:
-                fn()
+            # group: it holds the whole step loop, whose parts are the
+            # user's own and train_place_batch / train_report.
+            with telemetry.profile_span(
+                    "train_loop", "train", extra={"rank": self.rank},
+                    group=True):
+                if config is not None:
+                    fn(config)
+                else:
+                    fn()
             # Drain the async checkpoint writer BEFORE reporting success:
             # every submitted save must have published + acked (or raised)
             # by the time the controller sees this rank finish.
@@ -308,6 +319,16 @@ class TrainController:
         ray_tpu.get([w.ping.remote() for w in group.workers],
                     timeout=min(120.0, form_t))
         if n > 1 or self.scaling.force_distributed:
+            self._init_dist(group, n, form_t)
+        return group
+
+    def _init_dist(self, group: WorkerGroupState, n: int,
+                   form_t: float) -> None:
+        """Form the group's jax.distributed world(s)."""
+        import ray_tpu
+        from ..util import telemetry
+        with telemetry.profile_span("train_dist_init", "train",
+                                    extra={"world": n}):
             if self.scaling.num_slices > 1 and not self.scaling.use_tpu \
                     and n % self.scaling.num_slices == 0:
                 # CPU multi-slice emulation: each slice forms its own
@@ -330,7 +351,6 @@ class TrainController:
                 ray_tpu.get(
                     [w.setup_dist.remote(addr) for w in group.workers],
                     timeout=form_t)
-        return group
 
     def _teardown_group(self, group: WorkerGroupState) -> None:
         import ray_tpu
@@ -575,6 +595,9 @@ class TrainController:
             "experiment_name": self.run_config.name,
             "latest_checkpoint": self.manager.latest(),
             "num_slices": self.scaling.num_slices,
+            # Chip workers touch the backend themselves, under a span,
+            # before the user's function does (worker_backend_init).
+            "use_tpu": bool(self.scaling.use_tpu),
             # Resolved mesh for THIS incarnation's world: workers build
             # the global mesh from it (train.get_mesh()).  The rules
             # overrides ride along so every rank shards identically.
@@ -734,6 +757,7 @@ class TrainController:
     def run(self):
         import ray_tpu
 
+        from ..util import telemetry
         from .trainer import Result
 
         failures = 0
@@ -768,7 +792,10 @@ class TrainController:
                 resize_to: Optional[int] = None
                 group: Optional[WorkerGroupState] = None
                 try:
-                    group = self._start_group(world)
+                    with telemetry.profile_span(
+                            "train_start_group", "train",
+                            extra={"world": world}):
+                        group = self._start_group(world)
                     self._note_mesh_formed(world)
                 except Exception as e:  # noqa: BLE001 — restartable
                     # Formation failure (capacity vanished between the

@@ -193,6 +193,7 @@ class JaxTrainer:
         with telemetry.profile_span(
                 "train_fit", "train",
                 extra={"experiment": self._run_config.name,
-                       "num_workers": self._scaling.num_workers}):
+                       "num_workers": self._scaling.num_workers},
+                group=True):
             result = controller.run()
         return result

@@ -214,11 +214,36 @@ def local_snapshots() -> List[Dict[str, Any]]:
     return [m.snapshot() for m in metrics]
 
 
+def note_pending() -> None:
+    """A span was buffered (telemetry._emit_span): the next flush has
+    something to ship even if no metric moved, and a process that records
+    spans but no metric still needs its flusher."""
+    global _dirty
+    _dirty = True
+    if not _flusher_started:
+        _ensure_flusher()
+
+
+def _ship_spans(rt) -> None:
+    """The buffered profile spans as ONE ``add_profile_span`` frame, ahead
+    of the metrics frame of the same flush."""
+    from .telemetry import drain_spans
+    spans = drain_spans()
+    if not spans:
+        return
+    if hasattr(rt, "send") and hasattr(rt, "worker_id"):
+        from ray_tpu._private.protocol import RpcCall
+        rt.send(RpcCall(0, rt.worker_id, "add_profile_span", (spans,), {}))
+    else:
+        rt.control("add_profile_span", spans)
+
+
 def flush() -> None:
-    """Push this process's metrics to the driver (no-op on the driver: its
-    registry is read directly).  One batched ``metrics_push`` verb per
-    flush — the same frame feeds both the merged scrape and the head's
-    time-series store (ray_tpu.metricsview)."""
+    """Push this process's metrics and buffered spans to the driver (no-op
+    on the driver: its registry is read directly).  One batched
+    ``metrics_push`` verb per flush — the same frame feeds both the
+    merged scrape and the head's time-series store
+    (ray_tpu.metricsview)."""
     global _dirty
     from ray_tpu._private import runtime as rt_mod
     rt = rt_mod.current_runtime()
@@ -228,6 +253,7 @@ def flush() -> None:
     source_id = source.hex() if source is not None else "unknown"
     _dirty = False
     try:
+        _ship_spans(rt)
         rt.control("metrics_push", source_id, local_snapshots())
     except Exception:
         pass  # driver shutting down; metrics are best-effort
@@ -243,6 +269,7 @@ def _push_fire_and_forget() -> bool:
             or not hasattr(rt, "send") or not hasattr(rt, "worker_id"):
         return False
     from ray_tpu._private.protocol import RpcCall
+    _ship_spans(rt)
     rt.send(RpcCall(0, rt.worker_id, "metrics_push",
                     (rt.worker_id.hex(), local_snapshots()), {}))
     return True

@@ -13,6 +13,7 @@ import json
 from typing import Any, Dict, List, Optional
 
 from ray_tpu._private.api import _control
+from ray_tpu.util import telemetry as _telemetry
 
 
 def list_tasks(filters: Optional[List] = None,
@@ -211,14 +212,14 @@ def profile(duration_s: float = 2.0, hz: float = 67.0,
     return _control("profile", duration_s, hz, jax_profile, timeout_s)
 
 
-class profile_span:
+class profile_span(_telemetry.profile_span):
     """Context manager recording a user span onto the timeline
     (reference: ray.profiling / ProfileEvent, core_worker/profile_event.h).
 
-    Nesting-aware and re-entrant: spans share the per-thread open-span
-    stack with ``telemetry.profile_span``, so an inner span links to its
-    parent (``extra["parent_id"]``) and the parent's ``extra["self_s"]``
-    excludes nested time instead of double counting it.
+    The framework's own recorder (``telemetry.profile_span``) under the
+    category ``"user"``: nesting-aware and re-entrant, buffered and
+    shipped in batches from a worker, written to
+    ``<session>/trace/spans.jsonl`` at shutdown.
 
     Example::
 
@@ -226,43 +227,11 @@ class profile_span:
             ...
     """
 
+    __slots__ = ()
+
     def __init__(self, name: str, category: str = "user",
-                 pid: str = "user", tid: Optional[str] = None,
                  extra: Optional[Dict[str, Any]] = None):
-        import os
-        import threading
-        self.name = name
-        self.category = category
-        self.pid = pid
-        self.tid = tid or f"pid:{os.getpid()}:{threading.get_ident() % 10000}"
-        self.extra = extra
-        self._frames: List[Dict[str, Any]] = []
-
-    def __enter__(self):
-        import time
-
-        from ..telemetry import _span_enter
-
-        # Wall clock anchors the span's position on the timeline; the
-        # DURATION comes from the monotonic clock so an NTP step mid-span
-        # cannot produce a negative/garbage length.
-        self._frames.append(_span_enter({"start": time.time(),
-                                         "start_mono": time.monotonic()}))
-        return self
-
-    def __exit__(self, *exc):
-        import time
-
-        from ..telemetry import _span_exit
-
-        entry = self._frames.pop()
-        dur = time.monotonic() - entry["start_mono"]
-        extra = dict(self.extra or {})
-        extra.update(_span_exit(entry, dur))
-        _control("add_profile_span", self.name, self.category,
-                 entry["start"], entry["start"] + dur, self.pid, self.tid,
-                 extra)
-        return False
+        super().__init__(name, category, extra)
 
 
 def timeline(filename: Optional[str] = None) -> str:

@@ -16,12 +16,15 @@ Three pieces:
   lazily instantiate against the catalog — a typo'd or undeclared name
   raises instead of silently minting a new series
   (tests/test_telemetry_catalog.py locks the naming scheme down).
-* ``profile_span(name, category)`` — a cheap span recorder feeding the
-  chrome-trace timeline buffer (``_private/events.py``).  On the driver
-  it is a direct buffer append; in a worker it is a FIRE-AND-FORGET
-  control frame (no reply round-trip — safe on per-decode-step hot
-  paths); with no runtime at all it is a no-op, so library code (the
+* ``profile_span(name, category)`` — the span recorder feeding the
+  chrome-trace timeline buffer (``_private/events.py``) and, at
+  shutdown, ``<session>/trace/spans.jsonl``.  On the head it is a direct
+  buffer append; in a worker it is a deque append that the metrics
+  flusher ships in batches (no frame per span — safe on per-decode-step
+  hot paths); with no runtime at all it is a no-op, so library code (the
   inference engine under bench.py) can stay instrumented unconditionally.
+  Where jax is loaded a span is also a ``TraceAnnotation``, so a profiler
+  session shows it on the device trace's clock.
 * ``GoodputTracker`` — partitions a training run's wall time into
   productive-step vs init/checkpoint/restart/idle (MegaScale-style
   goodput accounting) and exposes ``ray_tpu_train_goodput_ratio``.
@@ -29,9 +32,12 @@ Three pieces:
 
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, Optional
 
 from . import metrics as _metrics
@@ -107,6 +113,17 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "boundaries": _LATENCY_BUCKETS,
         "description": "Time to first token: request add -> first output "
                        "token sampled (includes queueing + prefill)."},
+    "ray_tpu_llm_queue_wait_seconds": {
+        "type": "histogram", "tag_keys": (),
+        "boundaries": _LATENCY_BUCKETS,
+        "description": "Queue wait: request add -> first admission to a "
+                       "slot (Request.t_admit - t_submit); the rest of "
+                       "TTFT is prefill."},
+    "ray_tpu_llm_stream_polls_total": {
+        "type": "counter", "tag_keys": (),
+        "description": "10 ms wait rounds of LLMServer.stream, summed per "
+                       "finished stream: how much polling ran beside the "
+                       "engine's loop."},
     "ray_tpu_llm_decode_token_seconds": {
         "type": "histogram", "tag_keys": (),
         "boundaries": _LATENCY_BUCKETS,
@@ -339,6 +356,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "boundaries": _STEP_BUCKETS,
         "description": "Seconds spent in XLA backend compilation per "
                        "tracked call site."},
+    "ray_tpu_xla_compiles_total": {
+        "type": "counter", "tag_keys": ("program",),
+        "description": "XLA backend compiles (persistent-cache fetches "
+                       "included) by program name; each is also an "
+                       "xla_compile span."},
+    "ray_tpu_compile_cache_hits_total": {
+        "type": "counter", "tag_keys": (),
+        "description": "Programs fetched from the persistent compile "
+                       "cache instead of compiled (where this jax "
+                       "reports it)."},
     "ray_tpu_profiler_recompiles_total": {
         "type": "counter", "tag_keys": ("fn",),
         "description": "POST-WARMUP recompilations: a tracked site that "
@@ -619,36 +646,57 @@ def _reset_for_tests() -> None:
 
 # -- profile spans ---------------------------------------------------------
 
+#: Finished spans of a process that is not the head, until the metrics
+#: flusher (``util/metrics.py``: every 2 s, at task end, at worker exit)
+#: ships them to the head in ONE frame.  Bounded: the oldest are dropped.
+_span_buffer: "deque" = deque(maxlen=65536)
+
+# os.getpid() is a system call on every span (microseconds on a sandboxed
+# host): read once, and again in a forked child.
+_pid = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
+
+
+def drain_spans() -> list:
+    """Take every buffered span (deque pops are atomic: safe against
+    threads that append meanwhile)."""
+    out = []
+    try:
+        while True:
+            out.append(_span_buffer.popleft())
+    except IndexError:
+        return out
+
 
 def _emit_span(name: str, category: str, start_s: float, end_s: float,
                extra: Optional[Dict[str, Any]] = None) -> None:
-    """Record one finished span into the driver's timeline buffer.
+    """Record one finished span: ``(name, category, start, end, process
+    id, thread id, extra)``.
 
-    Driver: direct append.  Worker: fire-and-forget control frame (request
-    id 0 is never in the pending-reply table, so the head's reply is
-    dropped harmlessly) — no round-trip on hot paths.  No runtime: no-op.
+    Head: straight into the timeline buffer.  Any other process: a deque
+    append; the metrics flusher ships the batch, so a span on a per-token
+    path costs no frame of its own.  No runtime: no-op.
     """
     from ray_tpu._private import runtime as rtmod
     rt = rtmod.current_runtime()
     if rt is None:
         return
-    pid = category
-    # One timeline row per THREAD, not per process: concurrent spans from
-    # different threads on a shared row would interleave and break the
-    # viewer's nesting of same-thread parent/child spans.
-    tid = f"pid:{os.getpid()}:t{threading.get_ident() % 100000}"
+    span = (name, category, start_s, end_s, _pid, threading.get_ident(),
+            extra)
     try:
-        if hasattr(rt, "ctl_add_profile_span"):
-            rt.ctl_add_profile_span(name, category, start_s, end_s,
-                                    pid, tid, extra)
-        elif hasattr(rt, "send") and hasattr(rt, "worker_id"):
-            from ray_tpu._private.protocol import RpcCall
-            rt.send(RpcCall(0, rt.worker_id, "add_profile_span",
-                            (name, category, start_s, end_s, pid, tid,
-                             extra), {}))
-        elif hasattr(rt, "control"):
-            rt.control("add_profile_span", name, category, start_s, end_s,
-                       pid, tid, extra)
+        add = getattr(rt, "ctl_add_profile_span", None)
+        if add is not None:
+            add((span,))
+        else:
+            _span_buffer.append(span)
+            _metrics.note_pending()
     except Exception:
         pass  # telemetry is never allowed to fail the instrumented path
 
@@ -656,17 +704,8 @@ def _emit_span(name: str, category: str, start_s: float, end_s: float,
 # Per-thread open-span stack: gives nested profile_spans parent linkage
 # and lets a parent subtract its children's time (``self_s``), so an
 # inner span's duration is never silently attributed to both levels.
-# Shared by telemetry.profile_span and util.state.profile_span.
 _span_tls = threading.local()
-_span_seq_lock = threading.Lock()
-_span_seq = 0
-
-
-def _next_span_id() -> int:
-    global _span_seq
-    with _span_seq_lock:
-        _span_seq += 1
-        return _span_seq
+_span_seq = itertools.count(1)
 
 
 def _span_stack() -> list:
@@ -680,7 +719,7 @@ def _span_enter(entry: Dict[str, Any]) -> Dict[str, Any]:
     """Push one open-span frame; returns it annotated with its id and
     its parent's id (None at the top level)."""
     stack = _span_stack()
-    entry["span_id"] = _next_span_id()
+    entry["span_id"] = next(_span_seq)
     entry["parent_id"] = stack[-1]["span_id"] if stack else None
     entry["child_s"] = 0.0
     stack.append(entry)
@@ -706,27 +745,41 @@ def _span_exit(entry: Dict[str, Any], dur_s: float) -> Dict[str, Any]:
 
 
 class profile_span:
-    """Cheap system-span context manager for framework hot paths.
+    """The span recorder: one context manager for the framework's own hot
+    paths and (as ``util.state.profile_span``) for user code.
 
-    Unlike ``util.state.profile_span`` (the user API, which requires a
-    runtime and does a blocking control call), this one no-ops without a
-    runtime and never waits on a reply — safe inside the engine decode
-    loop or a bench process that never called ``ray_tpu.init()``.
+    No-ops without a runtime and never waits on a reply — safe inside the
+    engine decode loop or a bench process that never called
+    ``ray_tpu.init()``.
 
     Re-entrant and nesting-aware: a span opened inside another span is
     linked to its parent (``extra["parent_id"]``) and the parent's
     ``extra["self_s"]`` excludes nested time, so inner durations are
     attributed exactly once.  One instance may be entered recursively
-    (per-entry state lives on a stack, not the instance).
+    (per-entry state lives on a stack, not the instance).  ``extra``
+    carries what the spans of one request or step share (``request_id``,
+    ``step``).
+
+    On the device trace's clock: where jax is already imported (it is
+    never imported for this) the span is also a
+    ``jax.profiler.TraceAnnotation``: an atomic read while no profiler
+    session runs, an event on the trace's host plane beside
+    ``PjitFunction`` and ``np.asarray`` while one does, so a gap on the
+    device can be given to the program's own span.  ``group=True`` marks
+    a span that only holds other spans (a whole loop, a whole step): it
+    is recorded like any other but stays off the host plane, where a gap
+    belongs to the part and not to the whole.
     """
 
-    __slots__ = ("name", "category", "extra", "_frames")
+    __slots__ = ("name", "category", "extra", "group", "_frames")
 
     def __init__(self, name: str, category: str = "system",
-                 extra: Optional[Dict[str, Any]] = None):
+                 extra: Optional[Dict[str, Any]] = None,
+                 group: bool = False):
         self.name = name
         self.category = category
         self.extra = extra
+        self.group = group
         self._frames: list = []
 
     def __enter__(self) -> "profile_span":
@@ -735,6 +788,16 @@ class profile_span:
         entry = _span_enter({"start": time.time(),
                              "start_mono": time.monotonic()})
         self._frames.append(entry)
+        jax = None if self.group else sys.modules.get("jax")
+        if jax is not None:
+            try:
+                note = jax.profiler.TraceAnnotation(
+                    self.name, span_id=entry["span_id"],
+                    **(self.extra or {}))
+                note.__enter__()
+                entry["note"] = note
+            except Exception:
+                pass  # a half-imported jax: the span itself still counts
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -744,6 +807,11 @@ class profile_span:
         extra.update(_span_exit(entry, dur))
         _emit_span(self.name, self.category, entry["start"],
                    entry["start"] + dur, extra)
+        # Closed last: the annotation then outlasts every host event it
+        # holds, and a device gap they all cover goes to the span.
+        note = entry.get("note")
+        if note is not None:
+            note.__exit__(None, None, None)
         return False
 
 

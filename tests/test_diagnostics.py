@@ -267,26 +267,7 @@ class TestMonotonicSpans:
         finally:
             real_time.time = orig
 
-    def test_state_profile_span_survives_clock_step(self, ray_start):
-        sp = state_api.profile_span("ntp_probe_state", category="diag")
-        self._with_wall_clock_jump(
-            (sp.__enter__, lambda: sp.__exit__(None, None, None)))
-        trace = json.loads(ray_tpu.timeline())
-        spans = [e for e in trace if e["name"] == "ntp_probe_state"]
-        assert spans
-        assert spans[0]["dur"] >= 0
-        assert spans[0]["dur"] < 60e6  # microseconds; not an hour
-
-    def test_telemetry_profile_span_survives_clock_step(self, ray_start):
-        from ray_tpu.util import telemetry
-        sp = telemetry.profile_span("ntp_probe_telemetry")
-        self._with_wall_clock_jump(
-            (sp.__enter__, lambda: sp.__exit__()))
-        trace = json.loads(ray_tpu.timeline())
-        spans = [e for e in trace if e["name"] == "ntp_probe_telemetry"]
-        assert spans
-        assert spans[0]["dur"] >= 0
-        assert spans[0]["dur"] < 60e6
+    # profile_span's case: tests/test_span_recorder.py (both spellings).
 
     def test_tracing_task_span_survives_clock_step(self, ray_start):
         from ray_tpu.util import tracing

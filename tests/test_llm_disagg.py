@@ -381,7 +381,7 @@ class TestLLMServerLifecycle:
             free0 = srv.engine.pool.num_free
             # Submit and never wait: max_tokens large enough that it is
             # still running when the deadline (0 + grace) passes.
-            rid, _ev = srv._submit([5, 6, 7],
+            rid, _ev, _req = srv._submit([5, 6, 7],
                                    SamplingParams(max_tokens=4),
                                    timeout_s=0.0)
             deadline = time.monotonic() + 30

@@ -107,26 +107,7 @@ class TestTimeline:
         assert json.loads(out.read_text()) == trace
 
 
-class TestProfileSpan:
-    def test_user_span_in_timeline(self, ray_start):
-        with state_api.profile_span("my_phase", category="demo"):
-            time.sleep(0.01)
-        trace = json.loads(ray_tpu.timeline())
-        spans = [e for e in trace if e["name"] == "my_phase"]
-        assert spans and spans[0]["cat"] == "demo"
-        assert spans[0]["dur"] >= 10_000  # >= 10ms in microseconds
-
-    def test_span_from_worker(self, ray_start):
-        @ray_tpu.remote
-        def traced():
-            from ray_tpu.util import state
-            with state.profile_span("inner_work"):
-                time.sleep(0.01)
-            return True
-
-        assert ray_tpu.get(traced.remote())
-        trace = json.loads(ray_tpu.timeline())
-        assert any(e["name"] == "inner_work" for e in trace)
+# profile_span (user and framework spelling alike): tests/test_span_recorder.py
 
 
 class TestMetrics:

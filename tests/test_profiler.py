@@ -368,23 +368,6 @@ class TestSpanNesting:
         inner, outer = spans  # inner exits first
         assert inner["parent_id"] == outer["span_id"]
 
-    def test_state_profile_span_links_to_parent(self, ray_start):
-        """state.profile_span shares the stack: nested user spans carry
-        parent linkage all the way into the driver timeline."""
-        with state_api.profile_span("outer_user"):
-            with state_api.profile_span("inner_user"):
-                time.sleep(0.01)
-        trace = json.loads(ray_tpu.timeline())
-        by_name = {}
-        for ev in trace:
-            if ev.get("name") in ("outer_user", "inner_user"):
-                by_name[ev["name"]] = ev
-        assert set(by_name) == {"outer_user", "inner_user"}
-        outer_args = by_name["outer_user"]["args"]
-        inner_args = by_name["inner_user"]["args"]
-        assert inner_args["parent_id"] == outer_args["span_id"]
-        assert "self_s" in outer_args
-
 
 class TestCompareGate:
     def _bench(self):

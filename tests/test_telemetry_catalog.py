@@ -24,7 +24,7 @@ from ray_tpu.util import telemetry
 _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
-              "alerts", "store", "lock", "jax")
+              "alerts", "store", "lock", "jax", "xla", "compile")
 
 
 class TestCatalog:
@@ -423,6 +423,13 @@ class TestSmokeAllSubsystems:
         tracked = profiler.track(jax.jit(lambda x: x + 1),
                                  name="telemetry_smoke_inc")
         tracked(jnp.ones((4,), jnp.float32))
+
+        # -- xla / compile: every backend compile is counted by program;
+        # a fetch from the persistent compile cache is counted when jax
+        # reports one inside the compile.  The suite runs with the cache
+        # off, so the report is made here the way jax makes it.
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.jit(lambda x: x * 5)(jnp.ones((4,), jnp.float32))
 
         # -- lock: the contention profiler publishes on a double 1/8
         # sample (hold timing every 8th acquire, telemetry every 8th
