@@ -2,22 +2,33 @@
 
 FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
 
-- Forward blocks over BOTH sequence axes — grid (B*H, Sq/bq, Sk/bk) with the
-  K/V axis innermost ("arbitrary" semantics) so pallas double-buffers K/V
+- Every kernel walks a grid (B*H, steps) over the (q block, k block) pairs
+  that hold a visible element, and no others: ``block_schedule`` lists them
+  at trace time from the shapes, the blocks, ``q_offset`` and ``causal``, and
+  the table reaches the kernels and their index maps by scalar prefetch.
+  Under a causal mask that is the triangle (36 steps of 64 at 4,096 tokens
+  and 512 x 512 blocks); ``causal=False`` is the same kernels under the full
+  rectangle.  Blocks above the diagonal are not grid steps at all.  Every
+  causal step masks its tile, also the *interior* ones the diagonal does not
+  cross (there the mask adds 0.0): on the v5e the mask hides behind the MXU,
+  and a second, unmasked step body measured no faster (PERF.md, PR 28).
+  The table is one packed int32 a step, so SMEM holds it at any length the
+  kernels would be asked for (131 KB at 128k tokens).
+- The grid is ("parallel", "arbitrary"): only B*H splits across the cores of
+  a two-core chip (v4, v5p), so a call with one or two heads a device no
+  longer spreads its q blocks over both.  The v5e has one core.
+- Forward blocks over BOTH sequence axes, the steps of one Q block in a row
+  with K ascending ("arbitrary" semantics) so pallas double-buffers K/V
   block DMAs while the MXU works.  Online softmax state (running max m,
   denominator l, unnormalized accumulator) lives in VMEM scratch carried
   across K blocks; the [Sq, Sk] score matrix never exists in HBM.  The
   log-sum-exp is written out as a residual (broadcast over the 128-lane
   minor dim, the TPU-friendly layout the jax flash kernel also uses).
-- Backward is two kernels: dq (grid over K blocks innermost, accumulating
-  dq for a resident Q block) and dk/dv (grid over Q blocks innermost,
-  accumulating dk/dv for a resident K/V block).  Both recompute probabilities
-  from the saved LSE — one exp, no second softmax pass — with fp32
-  accumulation and bf16 MXU inputs.
-- Causal block-skipping: blocks strictly above the diagonal are predicated
-  out with pl.when and their K/V DMAs are redirected to block 0 (the next
-  useful block), so the skipped half of the grid costs neither FLOPs nor
-  bandwidth.
+- Backward is two kernels: dq (the same walk as the forward, accumulating
+  dq for a resident Q block) and dk/dv (K-major: the steps of one K/V block
+  in a row with Q ascending, accumulating dk/dv for the resident block).
+  Both recompute probabilities from the saved LSE — one exp, no second
+  softmax pass — with fp32 accumulation and bf16 MXU inputs.
 - GQA is native: the K/V index maps collapse query heads onto their shared
   KV head; dk/dv are emitted per query head and group-summed outside only
   when kv_heads < heads.
@@ -28,7 +39,8 @@ callers (ring attention) can flash-attend a mid-sequence Q shard.
 Design provenance (patterns, not code): the reference delegates attention to
 engines (SURVEY §2.4 SP/CP row — no in-repo kernel); the block/layout recipe
 follows jax.experimental.pallas.ops.tpu.flash_attention (LSE lane broadcast,
-dual-axis grid, prefetch-redirect on skipped causal blocks).
+dual-axis blocking); the prefetched table of visible blocks is how upstream's
+splash attention walks a sparse mask.
 """
 
 from __future__ import annotations
@@ -39,6 +51,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -82,9 +95,69 @@ def _bcast_lanes(x128, n):
     raise NotImplementedError(f"n={n} not a multiple of {LANES}")
 
 
-def _visible(qi, bq, ki, bk, q_offset):
-    """Causal: does q block qi see any of k block ki?"""
-    return (qi + 1) * bq - 1 + q_offset >= ki * bk
+# Rows of a block schedule, and the kinds of step.
+QI, KI, KIND, FIRST, LAST = range(5)
+INTERIOR, DIAGONAL, EMPTY = range(3)
+# A step as the kernels read it, one int32: qi | ki | run | first | last.
+_BLOCK_BITS = 14
+_KI_SHIFT, _QI_SHIFT = 3, 3 + _BLOCK_BITS
+_BLOCK_MASK = (1 << _BLOCK_BITS) - 1
+_LAST_BIT, _FIRST_BIT, _RUN_BIT = 1, 2, 4
+
+
+def block_schedule(Sq, Sk, block_q, block_k, q_offset=0, causal=True,
+                   major="q"):
+    """The grid steps of one (batch, head): int32 [5, steps], rows QI, KI,
+    KIND, FIRST, LAST.
+
+    One step for every (q block, k block) pair that holds a visible element.
+    INTERIOR: every element is visible, no mask is needed.  DIAGONAL: the
+    causal diagonal crosses the pair.  ``major="q"`` walks q blocks with k
+    ascending inside each (forward, dq: the q block is resident);
+    ``major="k"`` walks k blocks with q ascending (dk/dv).  FIRST and LAST
+    mark the steps that open and close a resident block.  A resident block
+    that sees nothing (a k block beyond every q row) still gets one EMPTY
+    step, so that its output is written (as zeros).
+
+    The kinds are the static count of what a call does (tests and PERF.md
+    read them); the kernels only tell EMPTY from the rest."""
+    nq, nk = Sq // block_q, Sk // block_k
+    steps = []
+    for a in range(nq if major == "q" else nk):
+        run = []
+        for b in range(nk if major == "q" else nq):
+            qi, ki = (a, b) if major == "q" else (b, a)
+            # The last q row against the first k column, then the reverse.
+            if causal and (qi + 1) * block_q - 1 + q_offset < ki * block_k:
+                continue
+            whole = (not causal
+                     or qi * block_q + q_offset >= (ki + 1) * block_k - 1)
+            run.append([qi, ki, INTERIOR if whole else DIAGONAL, 0, 0])
+        if not run:
+            run = [[a, 0, EMPTY, 0, 0] if major == "q"
+                   else [nq - 1, a, EMPTY, 0, 0]]
+        run[0][FIRST] = run[-1][LAST] = 1
+        steps += run
+    return np.array(steps, np.int32).T
+
+
+def _packed_schedule(*args):
+    """``block_schedule`` as the scalar-prefetch operand: int32 [steps]."""
+    sched = block_schedule(*args)
+    if max(sched[QI].max(), sched[KI].max()) > _BLOCK_MASK:
+        raise ValueError(f"more than {_BLOCK_MASK + 1} blocks a side")
+    return (sched[QI] << _QI_SHIFT | sched[KI] << _KI_SHIFT
+            | (sched[KIND] != EMPTY) * _RUN_BIT
+            | sched[FIRST] * _FIRST_BIT | sched[LAST] * _LAST_BIT
+            ).astype(np.int32)
+
+
+def _step_qi(step):
+    return step >> _QI_SHIFT
+
+
+def _step_ki(step):
+    return (step >> _KI_SHIFT) & _BLOCK_MASK
 
 
 def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset):
@@ -95,23 +168,21 @@ def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset):
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, nk, q_offset):
+def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                acc_scr, *, causal, scale, block_q, block_k, q_offset):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = sched_ref[pl.program_id(1)]
+    qi, ki = _step_qi(step), _step_ki(step)
 
-    @pl.when(ki == 0)
+    @pl.when(step & _FIRST_BIT != 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    run = True if not causal else _visible(qi, block_q, ki, block_k, q_offset)
-
-    @pl.when(run)
+    @pl.when(step & _RUN_BIT != 0)
     def _step():
         q = q_ref[0]                                   # [bq, D]
         k = k_ref[0]                                   # [bk, D]
@@ -134,7 +205,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[...] = acc_scr[...] * _bcast_lanes(alpha, acc_scr.shape[1]) \
             + pv
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step & _LAST_BIT != 0)
     def _finish():
         l = l_scr[...]
         l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
@@ -154,36 +225,22 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     _, Hkv, Sk, _ = k.shape
     if H % Hkv:
         raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
-    group = H // Hkv
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     if Sq % block_q or Sk % block_k:
         raise ValueError(
             f"seq ({Sq},{Sk}) not divisible by blocks ({block_q},{block_k})")
-    nq, nk = Sq // block_q, Sk // block_k
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q")
 
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * Hkv, Sk, D)
     vr = v.reshape(B * Hkv, Sk, D)
 
-    def q_index(bh, qi, ki):
-        return (bh, qi, 0)
-
-    def kv_index(bh, qi, ki):
-        row = (bh // H) * Hkv + (bh % H) // group
-        if causal:
-            ki = jnp.where(
-                _visible(qi, block_q, ki, block_k, q_offset), ki, 0)
-        return (row, ki, 0)
+    q_index, kv_index, _ = _index_maps(H, Hkv)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk, q_offset=q_offset)
-
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+        _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, q_offset=q_offset)
 
     out_specs = [pl.BlockSpec((1, block_q, D), q_index)]
     out_shape = [jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype)]
@@ -193,29 +250,32 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             jax.ShapeDtypeStruct((B * H, Sq, LANES), jnp.float32))
     else:
         # No LSE output at all: skip ~B*H*Sq*128 fp32 of dead HBM writes.
-        kernel = functools.partial(
-            lambda q, k, v, o, m, l, a, *, _k: _k(q, k, v, o, None, m, l, a),
-            _k=kernel)
+        with_lse = kernel
+
+        def kernel(sched, q, k, v, o, *scratch):
+            return with_lse(sched, q, k, v, o, None, *scratch)
 
     res = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, sched.size),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), q_index),
+                pl.BlockSpec((1, block_k, D), kv_index),
+                pl.BlockSpec((1, block_k, D), kv_index),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                _vmem((block_q, LANES), jnp.float32),
+                _vmem((block_q, LANES), jnp.float32),
+                _vmem((block_q, D), jnp.float32),
+            ]),
         out_shape=out_shape,
-        scratch_shapes=[
-            _vmem((block_q, LANES), jnp.float32),
-            _vmem((block_q, LANES), jnp.float32),
-            _vmem((block_q, D), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_fwd",
-        **params,
-    )(qr, kr, vr)
+        **_compiler_params(interpret),
+    )(sched, qr, kr, vr)
     out = res[0].reshape(B, H, Sq, D)
     if not need_lse:
         return out, None
@@ -227,22 +287,46 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
+def _index_maps(H, Hkv):
+    """Index maps (q-shaped, k/v, dk/dv) of a grid (B*H, steps) whose blocks
+    come from the prefetched schedule.  Query heads fold onto their shared
+    key head; dk/dv are per query head."""
+    group = H // Hkv
+
+    def q_index(bh, t, sched):
+        return (bh, _step_qi(sched[t]), 0)
+
+    def kv_index(bh, t, sched):
+        return ((bh // H) * Hkv + (bh % H) // group, _step_ki(sched[t]), 0)
+
+    def dkv_index(bh, t, sched):
+        return (bh, _step_ki(sched[t]), 0)
+
+    return q_index, kv_index, dkv_index
+
+
+def _compiler_params(interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))}
+
+
 # ---------------------------------------------------------------- backward
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr,
-               *, scale, causal, block_q, block_k, nk, q_offset):
+def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+               dq_ref, dq_scr, *, causal, scale, block_q, block_k, q_offset):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = sched_ref[pl.program_id(1)]
+    qi, ki = _step_qi(step), _step_ki(step)
 
-    @pl.when(ki == 0)
+    @pl.when(step & _FIRST_BIT != 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    run = True if not causal else _visible(qi, block_q, ki, block_k, q_offset)
-
-    @pl.when(run)
+    @pl.when(step & _RUN_BIT != 0)
     def _step():
         q = q_ref[0]
         k = k_ref[0]
@@ -264,27 +348,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr,
         dq_scr[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step & _LAST_BIT != 0)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, block_q, block_k, nq, q_offset):
+                *, causal, scale, block_q, block_k, q_offset):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = sched_ref[pl.program_id(1)]
+    qi, ki = _step_qi(step), _step_ki(step)
 
-    @pl.when(qi == 0)
+    @pl.when(step & _FIRST_BIT != 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    run = True if not causal else _visible(qi, block_q, ki, block_k, q_offset)
-
-    @pl.when(run)
+    @pl.when(step & _RUN_BIT != 0)
     def _step():
         q = q_ref[0]
         k = k_ref[0]
@@ -308,7 +390,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dk_scr[...] += jax.lax.dot(
             ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step & _LAST_BIT != 0)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -324,7 +406,6 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     group = H // Hkv
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
-    nq, nk = Sq // block_q, Sk // block_k
 
     # delta_i = rowsum(dO * O): one fused elementwise+reduce pass in XLA.
     di = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -339,92 +420,60 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     dir_ = jnp.broadcast_to(di.reshape(B * H, Sq)[..., None],
                             (B * H, Sq, LANES))
 
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    q_index, kv_index, dkv_index = _index_maps(H, Hkv)
+    in_specs = [
+        pl.BlockSpec((1, block_q, D), q_index),
+        pl.BlockSpec((1, block_k, D), kv_index),
+        pl.BlockSpec((1, block_k, D), kv_index),
+        pl.BlockSpec((1, block_q, D), q_index),
+        pl.BlockSpec((1, block_q, LANES), q_index),
+        pl.BlockSpec((1, block_q, LANES), q_index),
+    ]
+    static = dict(causal=causal, scale=scale, block_q=block_q,
+                  block_k=block_k, q_offset=q_offset)
 
-    def kv_row(bh):
-        return (bh // H) * Hkv + (bh % H) // group
-
-    # ---- dq: Q block resident, K/V blocks stream (ki innermost).
-    def q_index(bh, qi, ki):
-        return (bh, qi, 0)
-
-    def kv_index_dq(bh, qi, ki):
-        if causal:
-            ki = jnp.where(
-                _visible(qi, block_q, ki, block_k, q_offset), ki, 0)
-        return (kv_row(bh), ki, 0)
-
+    # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q")
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nk=nk,
-                          q_offset=q_offset),
-        grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_k, D), kv_index_dq),
-            pl.BlockSpec((1, block_k, D), kv_index_dq),
-            pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_q, LANES), q_index),
-            pl.BlockSpec((1, block_q, LANES), q_index),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), q_index),
+        functools.partial(_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, sched.size),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, block_q, D), q_index),
+            scratch_shapes=[_vmem((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        scratch_shapes=[_vmem((block_q, D), jnp.float32)],
         interpret=interpret,
         name="flash_dq",
-        **params,
-    )(qr, kr, vr, dor, lser, dir_).reshape(B, H, Sq, D)
+        **_compiler_params(interpret),
+    )(sched, qr, kr, vr, dor, lser, dir_).reshape(B, H, Sq, D)
 
-    # ---- dk/dv: K/V block resident, Q blocks stream (qi innermost).
+    # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk).
     # Emitted per *query* head; group-summed below when GQA.
-    def kv_index(bh, ki, qi):
-        return (kv_row(bh), ki, 0)
-
-    def q_index_dkv(bh, ki, qi):
-        if causal:
-            # Skipped q blocks (above diagonal) redirect their DMA to the
-            # next diagonal block to avoid wasted bandwidth.
-            qi = jnp.where(
-                _visible(qi, block_q, ki, block_k, q_offset), qi,
-                jnp.minimum((ki * block_k) // block_q, nq - 1))
-        return (bh, qi, 0)
-
-    def dkv_index(bh, ki, qi):
-        return (bh, ki, 0)
-
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "k")
     dkv_dtype = jnp.float32 if group > 1 else q.dtype
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq=nq,
-                          q_offset=q_offset),
-        grid=(B * H, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), q_index_dkv),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_q, D), q_index_dkv),
-            pl.BlockSpec((1, block_q, LANES), q_index_dkv),
-            pl.BlockSpec((1, block_q, LANES), q_index_dkv),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), dkv_index),
-            pl.BlockSpec((1, block_k, D), dkv_index),
-        ],
+        functools.partial(_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H, sched.size),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), dkv_index),
+                pl.BlockSpec((1, block_k, D), dkv_index),
+            ],
+            scratch_shapes=[
+                _vmem((block_k, D), jnp.float32),
+                _vmem((block_k, D), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sk, D), dkv_dtype),
             jax.ShapeDtypeStruct((B * H, Sk, D), dkv_dtype),
         ],
-        scratch_shapes=[
-            _vmem((block_k, D), jnp.float32),
-            _vmem((block_k, D), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_dkv",
-        **params,
-    )(qr, kr, vr, dor, lser, dir_)
+        **_compiler_params(interpret),
+    )(sched, qr, kr, vr, dor, lser, dir_)
 
     dk = dk.reshape(B, H, Sk, D)
     dv = dv.reshape(B, H, Sk, D)

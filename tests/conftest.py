@@ -53,7 +53,9 @@ _QUICK_IN_SLOW = {
     "test_models": ("test_num_params_matches",
                     "test_logical_axes_tree_matches_params"),
     "test_ops": ("TestRmsNorm", "TestRope", "TestMeshSharding",
-                 "test_routing_topk"),
+                 "test_routing_topk", "test_block_schedule",
+                 "test_packed_schedule",
+                 "test_three_blocks_a_side", "test_k_block_no_q_sees"),
     "test_llm": ("test_stop_tokens",),
     "test_rl": ("TestBuffers", "TestGAE"),
     "test_pipeline": ("test_pp_requires_mesh",),

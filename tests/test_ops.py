@@ -1,5 +1,7 @@
 """Numerics tests for the ops layer on the 8-device CPU mesh."""
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -9,9 +11,14 @@ import jax.numpy as jnp
 from ray_tpu.ops import (apply_rope, attention, flash_attention, moe_layer,
                          reference_attention, ring_attention,
                          rms_norm, rope_frequencies, top_k_routing)
+from ray_tpu.ops.attention import (DIAGONAL, EMPTY, FIRST, INTERIOR, KI,
+                                   KIND, LAST, QI, block_schedule)
 from ray_tpu.ops.ring_attention import ring_attention_sharded
 from ray_tpu.ops.ulysses import ulysses_attention_sharded
 from ray_tpu.parallel import MeshSpec, build_mesh
+
+# ``ray_tpu.ops.attention`` the attribute is the function of that name.
+attention_ops = importlib.import_module("ray_tpu.ops.attention")
 
 
 def test_devices_available():
@@ -132,6 +139,157 @@ class TestFlashAttention:
         for a, b, name in zip(gr, gf, ("dq", "dk", "dv")):
             np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3,
                                        err_msg=name)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("blocks", [(64, 64), (64, 32)])
+    def test_three_blocks_a_side(self, dtype, blocks):
+        # 3 x 3 (and 3 x 6) blocks: interior and diagonal steps both occur,
+        # and in the k-major walk of dk/dv later k blocks start at later q
+        # rows.  Grouped-query heads: dk/dv are float32 per query head.
+        block_q, block_k = blocks
+        q, k, v = _qkv(jax.random.key(8), B=1, H=4, Hkv=2, S=192,
+                       dtype=dtype)
+        do = jax.random.normal(jax.random.key(9), q.shape, dtype)
+
+        def fwd_bwd(fn, *args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(do.astype(out.dtype))
+
+        got = fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k,
+            interpret=True), q, k, v)
+        # The reference in float32 on the very inputs the kernel saw.
+        want = fwd_bwd(lambda q, k, v: reference_attention(
+            q, k, v, causal=True),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        tol = 5e-4 if dtype == jnp.float32 else 3e-2
+        for a, b, name in zip(want, got, ("out", "dq", "dk", "dv")):
+            assert b.dtype == dtype, name
+            a = np.asarray(a)
+            np.testing.assert_allclose(
+                np.asarray(b, np.float32), a, atol=tol * np.abs(a).max(),
+                rtol=tol, err_msg=name)
+
+
+# (Sq, Sk, block_q, block_k, q_offset, causal) -> steps by kind, or None
+# where only the properties are checked.
+SCHEDULES = {
+    "cells_4096_512": ((4096, 4096, 512, 512, 0, True),
+                       {INTERIOR: 28, DIAGONAL: 8, EMPTY: 0}),
+    "cells_noncausal": ((4096, 4096, 512, 512, 0, False),
+                        {INTERIOR: 64, DIAGONAL: 0, EMPTY: 0}),
+    "smoke_2048_512": ((2048, 2048, 512, 512, 0, True),
+                       {INTERIOR: 6, DIAGONAL: 4, EMPTY: 0}),
+    "one_block": ((128, 128, 128, 128, 0, True),
+                  {INTERIOR: 0, DIAGONAL: 1, EMPTY: 0}),
+    "bq64_bk128": ((128, 128, 64, 128, 0, True), None),
+    "bq32_bk64": ((128, 128, 32, 64, 0, True), None),
+    "bq64_bk32": ((192, 192, 64, 32, 0, True), None),
+    "three_a_side": ((192, 192, 64, 64, 0, True),
+                     {INTERIOR: 3, DIAGONAL: 3, EMPTY: 0}),
+    "noncausal_bq32": ((64, 64, 32, 64, 0, False), None),
+    "ring_shard_offset": ((64, 128, 32, 64, 64, True), None),
+    # Sk > Sq + q_offset: no q row reaches the second k block.
+    "k_block_unseen": ((64, 128, 32, 64, 0, True), None),
+    "offset_off_the_blocks": ((128, 256, 32, 64, 48, True), None),
+}
+
+
+@pytest.mark.parametrize("major", ["q", "k"])
+@pytest.mark.parametrize("case", SCHEDULES)
+def test_block_schedule(case, major):
+    """The schedule alone, no kernel: every visible element lies in
+    exactly one step, no step is wholly masked, an interior step has no
+    masked element, and FIRST / LAST bracket each resident block."""
+    (Sq, Sk, bq, bk, off, causal), counts = SCHEDULES[case]
+    sched = block_schedule(Sq, Sk, bq, bk, off, causal, major)
+    assert sched.dtype == np.int32 and sched.shape[0] == 5
+    visible = np.ones((Sq, Sk), bool)
+    if causal:
+        visible = (np.arange(Sq)[:, None] + off) >= np.arange(Sk)[None, :]
+
+    covered = np.zeros((Sq, Sk), int)
+    for qi, ki, kind, _, _ in sched.T:
+        tile = visible[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+        if kind == EMPTY:
+            continue
+        covered[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk] += 1
+        assert tile.any(), (qi, ki)
+        assert tile.all() == (kind == INTERIOR), (qi, ki, kind)
+    assert covered.max() <= 1
+    assert (covered[visible] == 1).all()
+
+    # The resident blocks come in order, each as one run of steps whose
+    # streamed blocks ascend, opened by FIRST and closed by LAST.
+    res, streamed = (QI, KI) if major == "q" else (KI, QI)
+    n_res = (Sq // bq) if major == "q" else (Sk // bk)
+    starts = np.flatnonzero(sched[FIRST])
+    ends = np.flatnonzero(sched[LAST])
+    assert list(sched[res][starts]) == list(range(n_res))
+    assert len(starts) == len(ends)
+    for a, b in zip(starts, ends):
+        assert a <= b
+        assert (sched[res][a:b + 1] == sched[res][a]).all()
+        assert (np.diff(sched[streamed][a:b + 1]) > 0).all()
+        assert sched[FIRST][a:b + 1].sum() == sched[LAST][a:b + 1].sum() == 1
+        # An EMPTY step stands alone, for a block that sees nothing.
+        if (sched[KIND][a:b + 1] == EMPTY).any():
+            assert a == b
+            r = sched[res][a]
+            seen = (visible[r * bq:(r + 1) * bq] if major == "q"
+                    else visible[:, r * bk:(r + 1) * bk])
+            assert not seen.any()
+    assert ends[-1] == sched.shape[1] - 1
+
+    if counts is not None:
+        assert {kind: int((sched[KIND] == kind).sum())
+                for kind in counts} == counts
+    unseen = {"k_block_unseen": 1, "offset_off_the_blocks": 1}.get(case, 0)
+    assert (sched[KIND] == EMPTY).sum() == (unseen if major == "k" else 0)
+
+    # What the kernels read: one int32 a step, nothing lost in the packing.
+    A = attention_ops
+    packed = A._packed_schedule(Sq, Sk, bq, bk, off, causal, major)
+    assert packed.dtype == np.int32 and packed.shape == (sched.shape[1],)
+    assert (A._step_qi(packed) == sched[QI]).all()
+    assert (A._step_ki(packed) == sched[KI]).all()
+    for bit, row in ((A._RUN_BIT, sched[KIND] != EMPTY),
+                     (A._FIRST_BIT, sched[FIRST]), (A._LAST_BIT, sched[LAST])):
+        assert ((packed & bit != 0) == row.astype(bool)).all()
+
+
+def test_packed_schedule_holds_the_longest_side():
+    A = attention_ops
+    n = A._BLOCK_MASK + 1                      # blocks a side that fit
+    for major, decode in (("q", A._step_qi), ("k", A._step_ki)):
+        sides = (n, 1) if major == "q" else (1, n)
+        packed = A._packed_schedule(*sides, 1, 1, 0, False, major)
+        assert packed.dtype == np.int32 and (packed > 0).all()
+        assert (decode(packed) == np.arange(n)).all()
+    with pytest.raises(ValueError, match="blocks a side"):
+        A._packed_schedule(2 * n, 8, 1, 8, 0, False, "q")
+
+
+def test_k_block_no_q_sees_gets_zero_gradient():
+    # Sk > Sq + q_offset: the kernel still writes dk / dv of the k block
+    # that no q row reaches, as zeros.
+    ks = jax.random.split(jax.random.key(10), 4)
+    q = jax.random.normal(ks[0], (1, 2, 64, 32))
+    k = jax.random.normal(ks[1], (1, 2, 128, 32))
+    v = jax.random.normal(ks[2], (1, 2, 128, 32))
+    do = jax.random.normal(ks[3], q.shape)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * do),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=64, interpret=True))
+    want = grads(lambda q, k, v: reference_attention(q, k, v, causal=True))
+    for a, b, name in zip(want, got, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
+    assert not np.asarray(got[1][:, :, 64:]).any()
+    assert not np.asarray(got[2][:, :, 64:]).any()
 
 
 class TestRingAttention:

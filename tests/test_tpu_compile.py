@@ -91,7 +91,8 @@ def test_flash_forward_compiles(one_chip):
     assert _kernels(compiled) == 1
 
 
-def test_flash_backward_compiles(one_chip):
+def _flash_grads(q, k, v):
+    """The jitted gradient of a causal flash attention: forward, dq, dk/dv."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
@@ -100,10 +101,58 @@ def test_flash_backward_compiles(one_chip):
         return jnp.sum(flash_attention(q, k, v, causal=True)
                        .astype(jnp.float32))
 
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
+
+
+def test_flash_backward_compiles(one_chip):
+    import jax.numpy as jnp
+
     x = _sds(FLASH_SHAPE, jnp.bfloat16, one_chip)
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile()
+    compiled = _flash_grads(x, x, x)
     assert _kernels(compiled) == 3          # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("batch,heads,kv_heads", [
+    (4, 16, 16),        # yi-coder-1.5b.train-sft4k
+    (1, 32, 8),         # mistral-7b-v0.3.train-fsdp4, one chip's share
+])
+def test_flash_kernels_return_what_the_roofline_reader_matches(
+        one_chip, batch, heads, kv_heads):
+    """benchmark/layer_metrics/flash_attn_roofline.py tells the three
+    kernels apart by the result types at the end of each custom call's
+    label: a kernel that returns anything its own pattern does not match,
+    or that another's matches too, blinds or falsifies the per-layer
+    metric.  At the benchmark cells' shapes."""
+    import re
+
+    import jax.numpy as jnp
+    from benchmark.layer_metrics.flash_attn_roofline import KERNELS
+
+    q = _sds((batch, heads, 4096, 128), jnp.bfloat16, one_chip)
+    kv = _sds((batch, kv_heads, 4096, 128), jnp.bfloat16, one_chip)
+    compiled = _flash_grads(q, kv, kv)
+    matched = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name, _, rest = line.strip().partition(" = ")
+        kernel = re.search(r"flash_(fwd|dq|dkv)", name).group(1)
+        assert kernel not in matched, line
+        types = re.findall(r"([a-z]+[0-9]+)\[",
+                           rest.partition(" custom-call(")[0])
+        label = f"{name}<{','.join(types)}>"
+        matched[kernel] = [which for which, pattern in KERNELS.items()
+                           if re.search(pattern, label)]
+    assert matched == {which: [which] for which in KERNELS}
+
+
+def test_flash_compiles_at_128k_tokens(one_chip):
+    """ROADMAP R9's length: the prefetched schedule (32,896 steps) has to
+    fit SMEM next to everything else."""
+    import jax.numpy as jnp
+
+    x = _sds((1, 1, 131072, 128), jnp.bfloat16, one_chip)
+    assert _kernels(_flash_grads(x, x, x)) == 3
 
 
 @pytest.mark.parametrize("pages_per_seq", [32, 40])
