@@ -1,0 +1,23 @@
+"""The held experts' largest load over their mean load (layer-mean) in the
+last reported step, as the program recorded it: the gauge
+``ray_tpu_moe_load_max_over_mean`` in the ``counters.json`` that
+``ray_tpu.shutdown()`` leaves beside ``spans.jsonl``.  None where the program
+records no such gauge."""
+
+import json
+import os
+
+from benchmark import spans
+
+
+def read(facts):
+    found = spans.find()
+    if found is None:
+        return None
+    try:
+        with open(os.path.join(os.path.dirname(found), "counters.json")) as f:
+            samples = json.load(f)["samples"]
+    except (OSError, ValueError, KeyError):
+        return None
+    got = samples.get("ray_tpu_moe_load_max_over_mean")
+    return float(got[0]["value"]) if got else None

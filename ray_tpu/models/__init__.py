@@ -3,10 +3,11 @@
 from .llama import (LlamaConfig, init_params, forward, loss_fn,
                     param_logical_axes, llama_tiny, llama_125m, llama_1b,
                     llama_7b)
+from .afmoe import AfmoeConfig
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
     "LlamaConfig", "init_params", "forward", "loss_fn", "param_logical_axes",
-    "llama_tiny", "llama_125m", "llama_1b", "llama_7b",
+    "llama_tiny", "llama_125m", "llama_1b", "llama_7b", "AfmoeConfig",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from . import _lm
 from ..ops.attention import attention as _attention
 from ..ops.attention import reference_attention
 from ..ops.moe import moe_layer
@@ -263,40 +264,18 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
-    # remat modes: False = save everything (small models only); True/"full" =
-    # recompute the whole block in backward; "mlp_only" = keep the attention
-    # half's residuals (incl. the flash kernel's q/k/v/out/LSE — the
-    # quadratic part is never recomputed) and recompute only the cheap MLP
-    # half.  "mlp_only" is the throughput sweet spot when HBM allows.
-    if cfg.remat in (True, "full"):
-        block = jax.checkpoint(
-            partial(_block, cfg, cos, sin, positions),
-            policy=jax.checkpoint_policies.nothing_saveable)
-    elif cfg.remat == "mlp_only":
-        mlp = jax.checkpoint(
-            partial(_mlp_half, cfg),
-            policy=jax.checkpoint_policies.nothing_saveable)
+    # remat modes (see _lm.remat); "mlp_only" = keep the attention half's
+    # residuals (incl. the flash kernel's q/k/v/out/LSE — the quadratic part
+    # is never recomputed) and recompute only the cheap MLP half: the
+    # throughput sweet spot when HBM allows.
+    if cfg.remat == "mlp_only":
+        mlp = _lm.remat(partial(_mlp_half, cfg), "full")
 
         def block(x, layer):
             return mlp(_attn_half(cfg, cos, sin, positions, x, layer), layer)
-    elif cfg.remat == "dots":
-        # Selective per-op saving: keep every matmul output (the MXU work
-        # worth not repeating), recompute the cheap VPU elementwise ops
-        # (norms/rope/silu) in backward — between "full" and no remat on
-        # the memory/FLOPs trade.
-        block = jax.checkpoint(
-            partial(_block, cfg, cos, sin, positions),
-            policy=jax.checkpoint_policies.checkpoint_dots)
-    elif cfg.remat == "dots_nobatch":
-        # Save only batch-free dots (weights-stationary projections);
-        # activation-activation matmuls recompute.
-        block = jax.checkpoint(
-            partial(_block, cfg, cos, sin, positions),
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-    elif cfg.remat is False:
-        block = partial(_block, cfg, cos, sin, positions)
     else:
-        raise ValueError(f"unknown remat mode {cfg.remat!r}")
+        block = _lm.remat(partial(_block, cfg, cos, sin, positions),
+                          cfg.remat)
 
     def scan_body(x, layer):
         x, aux = block(x, layer)
@@ -346,82 +325,13 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     return forward_with_aux(params, tokens, cfg, positions)[0]
 
 
-def _chunked_nll_sum(x, lm_head, targets, mask, num_chunks: int, dt):
-    """Masked next-token NLL sum with the lm_head applied per sequence
-    chunk under remat: peak logits memory is one chunk's [B, S/c, vocab]
-    f32 slab (forward AND backward) instead of the full tensor."""
-    B, S, E = x.shape
-    assert S % num_chunks == 0, (S, num_chunks)
-    c = S // num_chunks
-    xs = jnp.swapaxes(x.reshape(B, num_chunks, c, E), 0, 1)
-    ts = jnp.swapaxes(targets.reshape(B, num_chunks, c), 0, 1)
-    ms = jnp.swapaxes(mask.reshape(B, num_chunks, c), 0, 1)
-
-    @jax.checkpoint
-    def chunk_nll(xc, tc, mc):
-        logits = jnp.einsum("bse,ev->bsv", xc, lm_head.astype(dt),
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        # promise_in_bounds: targets are token ids < vocab by
-        # construction.  The default mode's NaN fill value poisons the
-        # SPMD-partitioned gather when vocab is sharded (tp) — each
-        # shard's locally-OOB rows fill NaN before the cross-shard
-        # combine.
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1,
-                                  mode="promise_in_bounds")[..., 0]
-        return jnp.sum((lse - tgt) * mc)
-
-    def body(acc, xtm):
-        return acc + chunk_nll(*xtm), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                            (xs, ts, ms))
-    return total
-
-
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
             cfg: LlamaConfig,
             positions: Optional[jax.Array] = None) -> jax.Array:
     """Next-token cross-entropy.  batch: tokens [B,S], loss_mask [B,S]."""
-    tokens = batch["tokens"]
-    targets = jnp.concatenate(
-        [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
-    mask = batch.get("loss_mask")
-    if mask is None:
-        mask = jnp.concatenate(
-            [jnp.ones_like(tokens[:, 1:]), jnp.zeros_like(tokens[:, :1])],
-            axis=1)
-    mask = mask.astype(jnp.float32)
-    # Gradient-accumulation callers inject the FULL batch's token count
-    # so per-microbatch means sum to exactly the unaccumulated loss even
-    # with uneven masking (see spmd.make_lm_train_step).
-    denom = batch.get("loss_denom")
-    if denom is None:
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-    if cfg.loss_chunks:
-        x, aux = _forward_hidden(params, tokens, cfg, positions)
-        with jax.named_scope("loss"):
-            nll_sum = _chunked_nll_sum(x, params["lm_head"], targets, mask,
-                                       cfg.loss_chunks, cfg.dtype)
-            loss = nll_sum / denom
-    else:
-        logits, aux = forward_with_aux(params, tokens, cfg, positions)
-        with jax.named_scope("loss"):
-            # logsumexp formulation: nll = LSE(logits) - logit[target].
-            # Unlike log_softmax this never materializes a second
-            # [B, S, vocab] array — the LSE reduce fuses into the lm_head
-            # matmul consumer, and the backward's softmax is recomputed
-            # elementwise into the dW/dx matmuls.
-            logits = logits.astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            # promise_in_bounds: targets are token ids < vocab by
-            # construction (see _chunked_nll_sum for why the default NaN
-            # fill breaks under a vocab-sharded partitioned gather).
-            tgt = jnp.take_along_axis(logits, targets[..., None],
-                                      axis=-1,
-                                      mode="promise_in_bounds")[..., 0]
-            nll = lse - tgt
-            loss = jnp.sum(nll * mask) / denom
+    x, aux = _forward_hidden(params, batch["tokens"], cfg, positions)
+    loss = _lm.next_token_loss(x, params["lm_head"], batch, cfg.loss_chunks,
+                               cfg.dtype)
     if cfg.num_experts:
         loss = loss + 0.01 * aux / cfg.layers
     return loss

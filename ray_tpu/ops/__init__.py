@@ -8,8 +8,9 @@ delegated to vLLM/DeepSpeed).  Built natively here:
 - ``ring_attention``— context parallelism over an ICI ring
                       (K/V rotate via ppermute, online-softmax accumulation)
 - ``ulysses``       — sequence<->head all-to-all context parallelism
-- ``moe``           — top-k routed mixture-of-experts with expert-parallel
-                      dispatch
+- ``moe``           — mixture-of-experts: softmax top-k with a capacity and
+                      expert-parallel dispatch; sigmoid scores with a
+                      selection bias, dropless, over the experts held
 - ``norms``/``rope``/``swiglu`` — fused-friendly elementwise building blocks
 """
 

@@ -35,6 +35,10 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
 
 ``q_offset`` shifts query positions for causal masking so sequence-sharded
 callers (ring attention) can flash-attend a mid-sequence Q shard.
+``window`` narrows the triangle to a band (a key is visible iff
+``0 <= t - s < window``): the pairs left of the band leave the grid, the
+mask gains the band's lower edge, and the three kernels carry the window in
+their names (``flash_fwd_w2048``).  With ``window=None`` nothing changes.
 
 Design provenance (patterns, not code): the reference delegates attention to
 engines (SURVEY §2.4 SP/CP row — no in-repo kernel); the block/layout recipe
@@ -60,11 +64,12 @@ LANES = 128
 
 def reference_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        q_offset: int = 0):
+                        q_offset: int = 0, window: Optional[int] = None):
     """Plain-jnp attention. q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D].
 
     ``q_offset`` shifts query positions for causal masking (used by
     sequence-sharded callers where the local Q block starts mid-sequence).
+    ``window`` (with ``causal``) keeps the keys with ``0 <= t - s < window``.
     """
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -79,6 +84,8 @@ def reference_attention(q, k, v, *, causal: bool = True,
         qpos = jnp.arange(Sq) + q_offset
         kpos = jnp.arange(Sk)
         mask = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            mask &= qpos[:, None] - kpos[None, :] < window
         scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -106,13 +113,16 @@ _LAST_BIT, _FIRST_BIT, _RUN_BIT = 1, 2, 4
 
 
 def block_schedule(Sq, Sk, block_q, block_k, q_offset=0, causal=True,
-                   major="q"):
+                   major="q", window=None):
     """The grid steps of one (batch, head): int32 [5, steps], rows QI, KI,
     KIND, FIRST, LAST.
 
     One step for every (q block, k block) pair that holds a visible element.
     INTERIOR: every element is visible, no mask is needed.  DIAGONAL: the
-    causal diagonal crosses the pair.  ``major="q"`` walks q blocks with k
+    causal diagonal crosses the pair, or the lower edge of the band does
+    (``window``: a key is visible iff ``0 <= t - s < window``, so the pairs
+    left of the band are no steps either: 70 steps a head at 8,192 / 512 /
+    512 and a window of 2,048, where the triangle has 136).  ``major="q"`` walks q blocks with k
     ascending inside each (forward, dq: the q block is resident);
     ``major="k"`` walks k blocks with q ascending (dk/dv).  FIRST and LAST
     mark the steps that open and close a resident block.  A resident block
@@ -130,8 +140,13 @@ def block_schedule(Sq, Sk, block_q, block_k, q_offset=0, causal=True,
             # The last q row against the first k column, then the reverse.
             if causal and (qi + 1) * block_q - 1 + q_offset < ki * block_k:
                 continue
-            whole = (not causal
-                     or qi * block_q + q_offset >= (ki + 1) * block_k - 1)
+            # The first q row against the last k column: the nearest pair.
+            near = qi * block_q + q_offset - ((ki + 1) * block_k - 1)
+            far = (qi + 1) * block_q - 1 + q_offset - ki * block_k
+            if window is not None and near >= window:
+                continue
+            whole = (not causal or near >= 0) and (window is None
+                                                   or far < window)
             run.append([qi, ki, INTERIOR if whole else DIAGONAL, 0, 0])
         if not run:
             run = [[a, 0, EMPTY, 0, 0] if major == "q"
@@ -160,16 +175,23 @@ def _step_ki(step):
     return (step >> _KI_SHIFT) & _BLOCK_MASK
 
 
-def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset):
+def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset, window=None):
     row = jax.lax.broadcasted_iota(jnp.int32, s_shape, 0) + qi * bq + q_offset
     col = jax.lax.broadcasted_iota(jnp.int32, s_shape, 1) + ki * bk
-    return jnp.where(col <= row, 0.0, MASK_VALUE)
+    visible = col <= row
+    if window is not None:
+        # A row that sees nothing of its first tile takes MASK_VALUE as its
+        # running max there; the first visible score then scales that
+        # tile's sums by exp(MASK_VALUE - max) = 0.
+        visible &= row - col < window
+    return jnp.where(visible, 0.0, MASK_VALUE)
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, causal, scale, block_q, block_k, q_offset):
+                acc_scr, *, causal, scale, block_q, block_k, q_offset,
+                window=None):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
@@ -191,7 +213,7 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
         if causal:
             s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset)
+                                      q_offset, window)
         m_prev = m_scr[...]                            # [bq, 128]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -216,8 +238,14 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             lse_ref[0] = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
+def _kernel_name(base, window):
+    """A windowed call carries its window in its name, so that a device
+    trace tells it from a full-causal call (``flash_fwd_w2048``)."""
+    return base if window is None else f"{base}_w{window}"
+
+
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
-                   interpret, *, need_lse):
+                   interpret, *, need_lse, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -230,7 +258,10 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     if Sq % block_q or Sk % block_k:
         raise ValueError(
             f"seq ({Sq},{Sk}) not divisible by blocks ({block_q},{block_k})")
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q")
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q",
+                             window)
 
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * Hkv, Sk, D)
@@ -240,7 +271,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, q_offset=q_offset)
+        block_k=block_k, q_offset=q_offset, window=window)
 
     out_specs = [pl.BlockSpec((1, block_q, D), q_index)]
     out_shape = [jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype)]
@@ -273,7 +304,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             ]),
         out_shape=out_shape,
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
         **_compiler_params(interpret),
     )(sched, qr, kr, vr)
     out = res[0].reshape(B, H, Sq, D)
@@ -316,7 +347,8 @@ def _compiler_params(interpret):
 # ---------------------------------------------------------------- backward
 
 def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-               dq_ref, dq_scr, *, causal, scale, block_q, block_k, q_offset):
+               dq_ref, dq_scr, *, causal, scale, block_q, block_k, q_offset,
+               window=None):
     from jax.experimental import pallas as pl
 
     step = sched_ref[pl.program_id(1)]
@@ -339,7 +371,7 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             preferred_element_type=jnp.float32) * scale
         if causal:
             s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset)
+                                      q_offset, window)
         p = jnp.exp(s - _bcast_lanes(lse, s.shape[1]))
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -355,7 +387,8 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
 def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, scale, block_q, block_k, q_offset):
+                *, causal, scale, block_q, block_k, q_offset,
+                window=None):
     from jax.experimental import pallas as pl
 
     step = sched_ref[pl.program_id(1)]
@@ -379,7 +412,7 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
         if causal:
             s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset)
+                                      q_offset, window)
         p = jnp.exp(s - _bcast_lanes(lse, s.shape[1]))
         dv_scr[...] += jax.lax.dot(
             p.T.astype(do.dtype), do, preferred_element_type=jnp.float32)
@@ -397,7 +430,7 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
-                    q_offset, interpret):
+                    q_offset, interpret, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -430,10 +463,11 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         pl.BlockSpec((1, block_q, LANES), q_index),
     ]
     static = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k, q_offset=q_offset)
+                  block_k=block_k, q_offset=q_offset, window=window)
 
     # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q")
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q",
+                             window)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -444,13 +478,14 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             scratch_shapes=[_vmem((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         interpret=interpret,
-        name="flash_dq",
+        name=_kernel_name("flash_dq", window),
         **_compiler_params(interpret),
     )(sched, qr, kr, vr, dor, lser, dir_).reshape(B, H, Sq, D)
 
     # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk).
     # Emitted per *query* head; group-summed below when GQA.
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "k")
+    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "k",
+                             window)
     dkv_dtype = jnp.float32 if group > 1 else q.dtype
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **static),
@@ -471,7 +506,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, Sk, D), dkv_dtype),
         ],
         interpret=interpret,
-        name="flash_dkv",
+        name=_kernel_name("flash_dkv", window),
         **_compiler_params(interpret),
     )(sched, qr, kr, vr, dor, lser, dir_)
 
@@ -485,24 +520,28 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
 
 # ---------------------------------------------------------------- wrapper
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, q_offset, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
+           window):
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            q_offset, interpret, need_lse=False)
+                            q_offset, interpret, need_lse=False,
+                            window=window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, q_offset, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
+               window):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              q_offset, interpret, need_lse=True)
+                              q_offset, interpret, need_lse=True,
+                              window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, res,
-               dout):
+def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, window,
+               res, dout):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q,
-                           block_k, q_offset, interpret)
+                           block_k, q_offset, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -511,12 +550,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, block_q: int = 512,
                     block_k: int = 512, q_offset: int = 0,
-                    interpret: bool = False):
+                    interpret: bool = False, window: Optional[int] = None):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
-    q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D]."""
+    q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D].  ``window``: with ``causal``,
+    a key is visible iff ``0 <= t - s < window``."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
-                  interpret)
+                  interpret, window)
 
 
 def _on_tpu() -> bool:
@@ -526,7 +566,8 @@ def _on_tpu() -> bool:
 
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
-              impl: Optional[str] = None, mesh=None):
+              impl: Optional[str] = None, mesh=None,
+              window: Optional[int] = None):
     """Dispatching entry point: pallas flash on TPU, reference elsewhere.
 
     ``mesh`` is the SPMD mesh q/k/v are laid out on inside a GSPMD
@@ -536,9 +577,11 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     if impl is None:
         impl = "flash" if _on_tpu() else "reference"
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, scale=scale)
+        return reference_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
-                           interpret=impl == "flash_interpret")
+                           interpret=impl == "flash_interpret",
+                           window=window)
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
 

@@ -1,17 +1,30 @@
-"""Mixture-of-experts: top-k routing + expert-parallel dispatch.
+"""Mixture-of-experts: routing and dispatch, two families.
 
 Absent from the reference (SURVEY §2.4 EP row: delegated to vLLM) — built
-natively.  The expert dimension carries the ``expert`` logical axis, so
-under the ``ep`` mesh axis GSPMD partitions the expert einsums and inserts
-the token exchange implied by the dispatch.  The default dispatch is
-capacity-based and SORTED (argsort assignments by expert + segment
-offsets -> O(T*k) index arrays) rather than the GShard one-hot
-``[T, X, C]`` tensor; dense (masked) dispatch remains available via
-``capacity_factor=0`` for exactness tests.
+natively.
+
+* Softmax top-k with a capacity (``moe_layer``, what ``LlamaConfig`` with
+  ``num_experts > 0`` runs).  The expert dimension carries the ``expert``
+  logical axis, so under the ``ep`` mesh axis GSPMD partitions the expert
+  einsums and inserts the token exchange implied by the dispatch.  The
+  default dispatch is capacity-based and SORTED (argsort assignments by
+  expert + segment offsets -> O(T*k) index arrays) rather than the GShard
+  one-hot ``[T, X, C]`` tensor, and DROPS what passes the capacity; dense
+  (masked) dispatch remains available via ``capacity_factor=0`` for
+  exactness tests.
+* Sigmoid scores with a selection bias, nothing dropped, and a layer that is
+  told which experts it holds (``sigmoid_routing``, ``dropless_experts``,
+  ``update_selection_bias``; what ``models/afmoe.py`` runs).  The router
+  scores all ``X`` experts and picks ``k`` of them (8 of 128 there); the
+  layer computes the part of the result that its own ``Xh`` experts give,
+  as one chip of an expert-parallel group does, without the exchange.  The
+  assignments to held experts are sorted by expert into a buffer of static
+  size and multiplied by grouped matrix products over the ragged groups.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -75,8 +88,10 @@ def capacity_dispatch(info: RoutingInfo, num_experts: int,
     counts = jnp.zeros((X,), jnp.int32)
     dispatch = jnp.zeros((B * S, X, capacity), jnp.float32)
     combine = jnp.zeros((B * S, X, capacity), jnp.float32)
-    # Traced inside callers' jitted MoE layers; k is the top-k constant
-    # (1-2), so the unrolled loop is two fused segments, not dispatch.
+    # Traced inside callers' jitted MoE layers; k is this path's top-k
+    # constant (1-2 for the softmax router; the sigmoid router's 8 go
+    # through dropless_experts), so the unrolled loop is two fused
+    # segments, not dispatch.
     for j in range(k):  # ray-tpu: noqa[RT506]
         oh = jax.nn.one_hot(idx[:, j], X, dtype=jnp.int32)     # [T, X]
         pos = jnp.cumsum(oh, axis=0) - 1 + counts[None, :]     # [T, X]
@@ -186,3 +201,161 @@ def moe_layer(x, router_w, w_gate, w_up, w_down, k: int = 2,
         out = jnp.einsum("bsxe,bsx->bse", expert_out,
                          info.combine_weights.astype(expert_out.dtype))
     return out.astype(x.dtype), load_balancing_loss(info, X)
+
+
+# ------------------------------------------------- sigmoid router, dropless
+
+class SigmoidRouting(NamedTuple):
+    expert_index: jax.Array     # [T, k] int32, over all X experts
+    weights: jax.Array          # [T, k] float32
+    counts: jax.Array           # [X] int32: assignments each expert got
+
+
+def sigmoid_routing(x, router_w, bias, k: int, route_scale: float = 1.0,
+                    route_norm: bool = True) -> SigmoidRouting:
+    """x [T, E], router_w [E, X], bias [X] float32 (the selection bias: state,
+    not a parameter).  ``s = sigmoid(x W)`` in float32; the ``k`` experts are
+    chosen by ``s + bias`` and weighted by ``s`` alone, normalised over the
+    chosen (``route_norm``) and scaled.  torchtitan's MoE router with
+    ``score_func="sigmoid"``."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "te,ex->tx", x.astype(jnp.float32), router_w.astype(jnp.float32)))
+    _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
+    w = jnp.take_along_axis(s, top, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    counts = jnp.zeros((s.shape[-1],), jnp.int32).at[top.reshape(-1)].add(1)
+    return SigmoidRouting(top, w * route_scale, counts)
+
+
+def update_selection_bias(bias, counts, rate: float = 1e-3):
+    """The step's update of the selection bias from the assignments each
+    expert got in it: ``d = rate * sign(mean(n) - n)``, ``b + d - mean(d)``
+    (torchtitan's rule; no gradient reaches the bias).  Leading axes (layers)
+    broadcast."""
+    n = counts.astype(jnp.float32)
+    d = rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+    return bias + d - jnp.mean(d, axis=-1, keepdims=True)
+
+
+#: (m, k, n) tiles of the grouped products on the chip (PERF.md, PR 29).
+GMM_TILING = (512, 1024, 1024)
+
+#: The dropless buffer holds 1 / BUFFER_TIERS of the worst case's rows.
+BUFFER_TIERS = 4
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl: Optional[str] = None):
+    """``lhs[rows of group g] @ rhs[g]``: lhs [R, K] sorted by group, rhs
+    [G, K, N], group_sizes [G] int32 -> [R, N].  Rows past the last group
+    are unspecified (the Pallas kernel does not visit them, so its time
+    follows the rows in use and not R); callers mask them.
+
+    ``impl``: "gmm" is upstream's Pallas grouped matmul (megablox; ``gmm``
+    and ``tgmm`` in a device trace), "gmm_interpret" the same interpreted,
+    "ragged_dot" is ``lax.ragged_dot``; None takes "gmm" on a TPU."""
+    if impl is None:
+        impl = "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
+    if impl == "ragged_dot":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    tiling = tuple(min(t, d) for t, d in zip(
+        GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])))
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
+               interpret=impl == "gmm_interpret")
+
+
+def _sort_by_group(group, num_groups):
+    """(stable argsort of ``group`` [N] with values below ``num_groups``, the
+    groups' sizes).  A counting sort: a row's place is its group's start plus
+    its rank inside the group, from one running sum over [N, groups].  An XLA
+    sort of 262,144 keys takes the TPU compiler 12 s a call site; this takes
+    1.5 s (described v5e, PR 29)."""
+    n = group.shape[0]
+    member = (group[:, None] == jnp.arange(num_groups)[None, :]
+              ).astype(jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(member, axis=0), group[:, None],
+                               axis=1)[:, 0] - 1
+    sizes = jnp.sum(member, axis=0)
+    place = (jnp.cumsum(sizes) - sizes)[group] + rank
+    order = jnp.zeros((n,), jnp.int32).at[place].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    return order, sizes
+
+
+def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl):
+    """The held experts' part for tokens xt [T, E] in a buffer of ``rows``
+    rows: (out [T, E], rows in use).  Right whenever the assignments to held
+    experts number at most ``rows``."""
+    T, k = top.shape
+    Xh = w_gate.shape[0]
+    with jax.named_scope("dispatch"):
+        local = top - held_start
+        local = jnp.where((local >= 0) & (local < Xh), local, Xh)
+        flat = local.reshape(T * k)
+        order, sizes = _sort_by_group(flat, Xh + 1)
+        # Held assignments first, grouped by expert, in token order.
+        order, sizes = order[:rows], sizes[:Xh]
+        used = jnp.minimum(jnp.sum(sizes), rows)
+        # Rows past ``used`` belong to no group: the grouped products leave
+        # them unwritten, forward and backward, so they are cut off on both
+        # sides (here, or their garbage gradients would be added to tokens).
+        live = (jnp.arange(rows) < used)[:, None]
+        tok = order // k
+        x_rows = jnp.where(live, xt[tok], 0)
+    with jax.named_scope("experts"):
+        mm = functools.partial(grouped_matmul, group_sizes=sizes, impl=impl)
+        h = jax.nn.silu(mm(x_rows, w_gate)) * mm(x_rows, w_up)
+        y_rows = mm(h, w_down)
+    with jax.named_scope("combine"):
+        w_rows = w.reshape(T * k)[order][:, None].astype(y_rows.dtype)
+        y_rows = jnp.where(live, y_rows, 0) * w_rows
+        out = jnp.zeros(xt.shape, y_rows.dtype).at[tok].add(y_rows)
+    return out, used
+
+
+def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
+                     held_start: int = 0, impl: Optional[str] = None):
+    """``sum_j w[t, j] * Expert_{top[t, j]}(xt[t])`` over the assignments
+    to the experts held here, ``held_start <= e < held_start + Xh``; every
+    such assignment is computed, whatever the imbalance.
+
+    xt [T, E]; w_gate / w_up [Xh, E, M], w_down [Xh, M, E].  Returns (out
+    [T, E], stats) with ``stats = (held, dropped)``: the assignments to held
+    experts and those of them not computed (identically 0).
+
+    Buffers are static.  All T*k assignments may go to held experts, so the
+    worst case needs T*k rows; a share of Xh / X is the usual case.  The
+    tokens therefore go through a buffer of T*k / BUFFER_TIERS rows at once
+    when the held assignments fit it, and otherwise in BUFFER_TIERS slices
+    of the tokens, one after the other through the same buffer (a slice of
+    T / BUFFER_TIERS tokens has at most that many assignments).  The XLA passes
+    over the buffer (gather, activation, scatter) cost its whole size, the
+    grouped products only the rows in use."""
+    T, k = routing.expert_index.shape
+    Xh, tiers = w_gate.shape[0], BUFFER_TIERS
+    top, w = routing.expert_index, routing.weights
+    held = jnp.sum(routing.counts[held_start:held_start + Xh])
+    run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
+                            w_down=w_down, held_start=held_start, impl=impl)
+    if T % tiers:
+        out, used = run(xt, top, w, rows=T * k)
+        return out, (held, held - used)
+    rows = T * k // tiers
+
+    def at_once():
+        return run(xt, top, w, rows=rows)
+
+    def in_slices():
+        split = lambda a: a.reshape((tiers, T // tiers) + a.shape[1:])
+        # Recomputed in the backward pass, or the gradient would keep every
+        # slice's buffers (and, through the cond, keep room for them on the
+        # usual path too).
+        out, used = jax.lax.map(
+            jax.checkpoint(lambda s: run(*s, rows=rows)),
+            (split(xt), split(top), split(w)))
+        return out.reshape(xt.shape), jnp.sum(used)
+
+    out, used = jax.lax.cond(held <= rows, at_once, in_slices)
+    return out, (held, held - used)

@@ -11,8 +11,9 @@ collective calls in user code.
 
 from __future__ import annotations
 
+import importlib
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 from ..util import telemetry
 from .mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ, MeshSpec, build_mesh,
@@ -54,6 +55,22 @@ def _mirror_param_shardings(opt_state_shape, params_shape,
     return jax.tree_util.tree_map_with_path(match, opt_state_shape)
 
 
+class StepState(NamedTuple):
+    """The second argument of a step whose model carries state that no
+    optimizer may touch (``init_state`` in its module): the optimizer's state
+    and the model's, side by side."""
+    opt: Any
+    model: Any
+
+
+def model_module(cfg):
+    """The module that defines a model configuration's class, and with it
+    the model: ``init_params``, ``param_logical_axes``, ``loss_fn`` and,
+    where the model carries state, ``init_state``, ``loss_and_loads`` and
+    ``update_state`` (models/llama.py, models/afmoe.py)."""
+    return importlib.import_module(type(cfg).__module__)
+
+
 def batch_pspec(mesh, rules: Optional[ShardingRules] = None):
     """Token batches: [B, S] -> (dp,fsdp) on batch, sp on seq."""
     import jax
@@ -68,10 +85,21 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
                        optimizer=None, learning_rate: float = 3e-4,
                        donate: bool = True, param_dtype=None,
                        grad_accum: int = 1):
-    """Build (init_fn, step_fn) for a models.llama LM on ``mesh``.
+    """Build (init_fn, step_fn, place_batch) on ``mesh`` for the language
+    model whose configuration ``cfg`` is: the model's ``init_params``,
+    ``param_logical_axes`` and ``loss_fn`` are those of the module that
+    defines ``cfg``'s class (``model_module``).
 
     init_fn(key) -> (params, opt_state) already sharded.
     step_fn(params, opt_state, batch) -> (params, opt_state, metrics).
+
+    A model with state the optimizer must not touch (afmoe's selection
+    bias: no gradient reaches it, and AdamW's weight decay would shrink it)
+    gets ``StepState(opt, model)`` as its ``opt_state``: the step hands the
+    model's part to the loss, and after the update to the model's
+    ``update_state``, whose metrics join the step's (afmoe: the scalars
+    ``moe_held_assignments``, ``moe_load_max_over_mean``, ``moe_dropped``
+    and the routers' choices ``moe_choices`` [expert layers, tokens, k]).
 
     ``param_dtype`` overrides parameter (and hence optimizer-state)
     storage: bfloat16 halves the adamw footprint so ~1.5B params fit one
@@ -89,7 +117,11 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
     import optax
     from jax.sharding import NamedSharding
 
-    from ..models import llama as L
+    L = model_module(cfg)
+    stateful = hasattr(L, "init_state")
+    if stateful and grad_accum > 1:
+        raise NotImplementedError("grad_accum with a model that carries "
+                                  "state")
 
     rules = rules or default_rules()
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -113,6 +145,8 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
         params = L.init_params(cfg, key) if param_dtype is None else \
             L.init_params(cfg, key, param_dtype=param_dtype)
         opt_state = optimizer.init(params)
+        if stateful:
+            opt_state = StepState(opt_state, L.init_state(cfg))
         return params, opt_state
 
     # Opt-state shardings are pinned EXPLICITLY to mirror the params
@@ -143,13 +177,23 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
             jax.config.update("jax_threefry_partitionable", old)
 
     def train_step(params, opt_state, batch):
+        extra = {}
         with jax.named_scope("forward_backward"):
-            loss, grads = loss_and_grads(params, batch)
+            if stateful:
+                opt_state, model_state = opt_state
+                (loss, loads), grads = jax.value_and_grad(
+                    L.loss_and_loads, has_aux=True)(
+                        params, model_state, batch, cfg)
+            else:
+                loss, grads = loss_and_grads(params, batch)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
+            if stateful:
+                model_state, extra = L.update_state(model_state, loads, cfg)
+                opt_state = StepState(opt_state, model_state)
+        return params, opt_state, {"loss": loss, "grad_norm": gnorm, **extra}
 
     def loss_and_grads(params, batch):
         if grad_accum > 1:
@@ -209,7 +253,7 @@ def make_lm_eval_step(cfg, mesh, *, rules: Optional[ShardingRules] = None):
     import jax
     from jax.sharding import NamedSharding
 
-    from ..models import llama as L
+    L = model_module(cfg)
 
     rules = rules or default_rules()
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))

@@ -164,8 +164,23 @@ def report(metrics: Dict[str, Any],
     from ..util import telemetry
     ctx = get_context()
     with telemetry.profile_span("train_report", "train",
-                                extra={"step": ctx._report_seq + 1}):
+                                extra={"step": ctx._report_seq + 1,
+                                       **_moe_loads(metrics)}):
         _report(ctx, metrics, checkpoint)
+
+
+#: a train step's metrics of its expert layers' loads (parallel.spmd) ->
+#: the built-in metric each is recorded as
+_MOE_KEYS = {"moe_held_assignments": "ray_tpu_moe_held_assignments",
+             "moe_load_max_over_mean": "ray_tpu_moe_load_max_over_mean",
+             "moe_dropped": "ray_tpu_moe_dropped_total"}
+
+
+def _moe_loads(metrics: Dict[str, Any]) -> Dict[str, float]:
+    """The reported step's expert loads under their built-in names, read to
+    the host (they may still be device scalars)."""
+    return {name: float(metrics[key]) for key, name in _MOE_KEYS.items()
+            if key in metrics}
 
 
 def _report(ctx: "TrainContext", metrics: Dict[str, Any],
@@ -384,6 +399,11 @@ def _note_step(ctx: "TrainContext", now: float, now_mono: float,
     from ..profiler import attribution
     from ..util import telemetry
     telemetry.inc("ray_tpu_train_reports_total")
+    for name, value in _moe_loads(metrics).items():
+        if name.endswith("_total"):
+            telemetry.inc(name, value)
+        else:
+            telemetry.set_gauge(name, value)
     for key in ("tokens", "num_tokens", "tokens_per_step"):
         v = metrics.get(key)
         if isinstance(v, (int, float)) and v > 0:

@@ -111,8 +111,9 @@ class Counter(Metric):
     def inc(self, value: float = 1.0,
             tags: Optional[Dict[str, str]] = None) -> None:
         global _dirty
-        if value <= 0:
-            raise ValueError("Counter.inc() value must be positive")
+        # 0 is a count too: a series that reads 0 (nothing dropped) exists.
+        if value < 0:
+            raise ValueError("Counter.inc() value must not be negative")
         key = _tags_key(self._merge_tags(tags))
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + value

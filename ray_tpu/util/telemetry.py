@@ -191,6 +191,20 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_train_reports_total": {
         "type": "counter", "tag_keys": (),
         "description": "train.report() calls across all ranks."},
+    # Expert layers' loads, from report() metrics carrying a train step's
+    # moe_* keys (parallel.spmd: models with a sigmoid-routed dropless MoE).
+    "ray_tpu_moe_held_assignments": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Assignments to the experts held here in the last "
+                       "reported step (layer-mean)."},
+    "ray_tpu_moe_load_max_over_mean": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Held experts' largest load over their mean load "
+                       "in the last reported step (layer-mean)."},
+    "ray_tpu_moe_dropped_total": {
+        "type": "counter", "tag_keys": (),
+        "description": "Assignments to held experts that were not "
+                       "computed (0: the dispatch is dropless)."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,

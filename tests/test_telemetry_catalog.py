@@ -24,7 +24,7 @@ from ray_tpu.util import telemetry
 _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
-              "alerts", "store", "lock", "jax", "xla", "compile")
+              "alerts", "store", "lock", "jax", "xla", "compile", "moe")
 
 
 class TestCatalog:
@@ -366,7 +366,10 @@ def _smoke_train_fn(config):
         # ckpt subsystem rides the same smoke: an async sharded save per
         # step exercises save-blocking/write/bytes/inflight series.
         train.save_checkpoint({"w": w + i, "step": i})
-        train.report({"loss": 1.0 / (i + 1), "tokens": 64})
+        # moe subsystem: a step's expert loads ride the report's own keys.
+        train.report({"loss": 1.0 / (i + 1), "tokens": 64,
+                      "moe_held_assignments": 8.0,
+                      "moe_load_max_over_mean": 1.25, "moe_dropped": 0.0})
 
 
 @serve.deployment(name="telemetry_echo")

@@ -233,3 +233,72 @@ def test_fsdp4_train_step_compiles(topo):
     assert _kernels(compiled) >= 3
     # Per-device batch rows x heads reach the kernel, not the global 8.
     assert "bf16[32,2048,128]" in compiled.as_text()
+
+
+def test_windowed_flash_kernels_compile_and_are_named(one_chip):
+    """The three kernels at Trinity's shape with its window: in the compiled
+    program under names that tell them from the full-causal calls."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+
+    q = _sds((4, 32, 8192, 128), jnp.bfloat16, one_chip)
+    kv = _sds((4, 4, 8192, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, window=2048)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    for name in ("flash_fwd_w2048", "flash_dq_w2048", "flash_dkv_w2048"):
+        assert name in text, name
+
+
+def test_trinity_train_step_compiles_at_the_cell_sizes(topo, capsys):
+    """``trinity-mini.train-moe8k``'s step as the benchmark builds it (9
+    layers, 16 of 128 experts, 4 rows of 8,192, full remat, flash, Pallas
+    grouped products) compiles for one described v5e chip; its memory is
+    stated (the temporaries over-state what the runtime reserves)."""
+    import json
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+    from ray_tpu.parallel.spmd import StepState, make_lm_train_step
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmark.archs import afmoe as arch
+    with open(os.path.join(root, "benchmark/configs/trinity-mini.json")) as f:
+        config = json.load(f)
+    s = arch.sizes_of(config)
+    cfg = arch.program_config(s, 8192, config["train"]).replace(
+        moe_impl="gmm")
+    rows = config["train"]["tokens_per_chip"] // 8192
+    before = get_global_mesh()
+    try:
+        mesh = build_mesh(MeshSpec(), devices=topo.devices[:1])
+        init_fn, step_fn, _ = make_lm_train_step(
+            cfg, mesh, learning_rate=1e-5, param_dtype=jnp.bfloat16)
+        params, state = jax.eval_shape(init_fn, jax.random.key(0))
+        batch = {k: jax.ShapeDtypeStruct((rows, 8192), jnp.int32)
+                 for k in ("tokens", "loss_mask")}
+        compiled = step_fn.lower(params, state, batch).compile()
+    finally:
+        set_global_mesh(before)
+    assert isinstance(state, StepState)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\ntrinity-mini.train-moe8k step for a described v5e: "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"{_kernels(compiled)} kernels")
+    for name in ("flash_fwd_w2048", "flash_dkv_w2048", "flash_fwd",
+                 "flash_dq", "gmm", "tgmm"):
+        assert name in text, name
+    # bf16 weights and two bf16 moments of 1,243 M parameters.
+    assert 7.4e9 < mem.argument_size_in_bytes < 7.6e9
