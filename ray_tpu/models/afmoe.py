@@ -271,8 +271,8 @@ def _attn_half(cfg: AfmoeConfig, full, cos, sin, x, layer):
 
 def _moe(cfg: AfmoeConfig, h, layer, bias):
     """F(h) of an expert layer and its loads: (out [B, S, E], {"counts" [X]
-    int32 over all the experts, "dropped" int32, "top" [B*S, k] the router's
-    choices})."""
+    int32 over all the experts, "dropped" int32, "sliced" int32 (1 if the
+    call took the buffer in slices), "top" [B*S, k] the router's choices})."""
     dt = cfg.dtype
     B, S, E = h.shape
     with jax.named_scope("block/moe"):
@@ -283,12 +283,14 @@ def _moe(cfg: AfmoeConfig, h, layer, bias):
         with jax.named_scope("shared"):
             shared = _swiglu(h, layer["shared_gate"], layer["shared_up"],
                              layer["shared_down"], dt)
-        routed, (_, dropped) = moe.dropless_experts(
+        routed, (held, dropped) = moe.dropless_experts(
             h.reshape(B * S, E), routing, layer["w_gate"].astype(dt),
             layer["w_up"].astype(dt), layer["w_down"].astype(dt),
             held_start=cfg.held_start, impl=cfg.moe_impl)
         return (shared + routed.reshape(B, S, E).astype(dt),
                 {"counts": routing.counts, "dropped": dropped,
+                 "sliced": (held > moe.buffer_rows(B * S, cfg.top_k)
+                            ).astype(jnp.int32),
                  "top": routing.expert_index})
 
 
@@ -307,7 +309,7 @@ def _layer(cfg: AfmoeConfig, full, cos, sin, x, layer, bias=None):
 
 def _forward_hidden(params, state, tokens, cfg: AfmoeConfig):
     """tokens [B, S] -> (final hidden [B, S, E], loads of the expert layers:
-    {"counts" [Lm, X], "dropped" [Lm], "top" [Lm, B*S, k]})."""
+    {"counts" [Lm, X], "dropped" [Lm], "sliced" [Lm], "top" [Lm, B*S, k]})."""
     from ..parallel.mesh import get_global_mesh
     mesh = get_global_mesh()
     if mesh is not None and mesh.size > 1:
@@ -345,6 +347,7 @@ def _forward_hidden(params, state, tokens, cfg: AfmoeConfig):
         if loads is not None:
             loads = {"counts": jnp.sum(loads["counts"], axis=0),
                      "dropped": jnp.sum(loads["dropped"]),
+                     "sliced": jnp.sum(loads["sliced"]),
                      "top": loads["top"].reshape(-1, cfg.top_k)}
         return y.reshape(x.shape), loads
 
@@ -371,6 +374,7 @@ def _forward_hidden(params, state, tokens, cfg: AfmoeConfig):
     else:
         loads = {"counts": jnp.zeros((0, cfg.num_experts), jnp.int32),
                  "dropped": jnp.zeros((0,), jnp.int32),
+                 "sliced": jnp.zeros((0,), jnp.int32),
                  "top": jnp.zeros((0, tokens.size, cfg.top_k), jnp.int32)}
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -400,7 +404,8 @@ def update_state(state, loads, cfg: AfmoeConfig):
     """(the state after a step with these loads, the step's metrics from
     them): layer-means of the assignments to held experts, of the held
     experts' largest load over their mean load, and of the assignments not
-    computed (0: the dispatch is dropless); and the routers' choices
+    computed (0: the dispatch is dropless); the number of layer calls of
+    the step that took the buffer in slices; and the routers' choices
     themselves, int32 [expert layers, tokens, k] over all the experts, so
     that what a step routed where can be read from the step that did it."""
     counts = loads["counts"]
@@ -412,6 +417,7 @@ def update_state(state, loads, cfg: AfmoeConfig):
         "moe_load_max_over_mean": jnp.mean(
             jnp.max(held, axis=-1) / jnp.maximum(mean, 1.0)),
         "moe_dropped": jnp.mean(loads["dropped"].astype(jnp.float32)),
+        "moe_sliced_calls": jnp.sum(loads["sliced"].astype(jnp.float32)),
         "moe_choices": loads["top"]}
     return {"bias": moe.update_selection_bias(
         state["bias"], counts, cfg.bias_update_rate)}, metrics

@@ -20,6 +20,12 @@ natively.
   as one chip of an expert-parallel group does, without the exchange.  The
   assignments to held experts are sorted by expert into a buffer of static
   size and multiplied by grouped matrix products over the ragged groups.
+  Rows go into the buffer and come back out through a pair of primitives
+  that are each other's transpose (``rows_of_tokens``, ``tokens_from_rows``)
+  on the indices of one counting sort a call (``_places``): gathers and
+  dense passes, forward and backward, and no scatter of rows, places or
+  counts.  On a TPU v5e a gathered row of 4 KB costs 6.4 ns and a
+  scatter-added one 88 (PERF.md, PR 30).
 """
 
 from __future__ import annotations
@@ -221,10 +227,14 @@ def sigmoid_routing(x, router_w, bias, k: int, route_scale: float = 1.0,
     s = jax.nn.sigmoid(jnp.einsum(
         "te,ex->tx", x.astype(jnp.float32), router_w.astype(jnp.float32)))
     _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
-    w = jnp.take_along_axis(s, top, axis=-1)
+    # One compare serves the weights and the counts: as a gather and a
+    # scatter-add (and the gather's transpose, a second one) they cost 2.2
+    # of a call's 2.7 ms in the router on the chip (PERF.md, PR 30).
+    chosen = top[..., None] == jnp.arange(s.shape[-1])       # [T, k, X]
+    w = jnp.sum(jnp.where(chosen, s[:, None, :], 0), axis=-1)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    counts = jnp.zeros((s.shape[-1],), jnp.int32).at[top.reshape(-1)].add(1)
+    counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
     return SigmoidRouting(top, w * route_scale, counts)
 
 
@@ -266,53 +276,164 @@ def grouped_matmul(lhs, rhs, group_sizes, impl: Optional[str] = None):
                interpret=impl == "gmm_interpret")
 
 
-def _sort_by_group(group, num_groups):
-    """(stable argsort of ``group`` [N] with values below ``num_groups``, the
-    groups' sizes).  A counting sort: a row's place is its group's start plus
-    its rank inside the group, from one running sum over [N, groups].  An XLA
-    sort of 262,144 keys takes the TPU compiler 12 s a call site; this takes
-    1.5 s (described v5e, PR 29)."""
-    n = group.shape[0]
-    member = (group[:, None] == jnp.arange(num_groups)[None, :]
-              ).astype(jnp.int32)
-    rank = jnp.take_along_axis(jnp.cumsum(member, axis=0), group[:, None],
-                               axis=1)[:, 0] - 1
-    sizes = jnp.sum(member, axis=0)
-    place = (jnp.cumsum(sizes) - sizes)[group] + rank
-    order = jnp.zeros((n,), jnp.int32).at[place].set(
-        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
-    return order, sizes
+def _take(v, at):
+    """``v[at]`` for a 1-D ``v``, moved as rows of 128: the row that holds
+    the place is gathered and the place picked out of it by a compare.  On
+    the chip a scalar gather costs 8.6 ns a place, a row of 128 costs 1.9
+    (PERF.md, PR 30)."""
+    blocks = -(-v.shape[0] // 128)
+    rows = jnp.pad(v, (0, blocks * 128 - v.shape[0])).reshape(blocks, 128)
+    here = jnp.arange(128, dtype=at.dtype) == (at % 128)[..., None]
+    return jnp.sum(jnp.where(here, rows[at // 128], 0), axis=-1)
+
+
+def _first_set(running, size):
+    """Places of the first ``size`` set entries of a 0/1 array, from its
+    inclusive running sum ``running`` [L]; L where there are fewer.  The
+    inverse of a running sum without a scatter or a sort: the r-th set entry
+    lies in the first block of 128 whose end passes r (a compare against
+    every block's end), and in it at the first entry that passes r (one
+    gathered block a place, a compare and a sum)."""
+    L = running.shape[0]
+    blocks = -(-L // 128)
+    by_block = jnp.pad(running, (0, blocks * 128 - L), mode="edge"
+                       ).reshape(blocks, 128)
+    r = jnp.arange(size, dtype=jnp.int32)[:, None]
+    block = jnp.sum(by_block[:, -1][None, :] <= r, axis=1, dtype=jnp.int32)
+    block = jnp.minimum(block, blocks - 1)
+    inside = jnp.sum(by_block[block] <= r, axis=1, dtype=jnp.int32)
+    return jnp.minimum(block * 128 + inside, L)
+
+
+class _Places(NamedTuple):
+    """Where the rows of one call's buffer come from and go back to: the
+    indices of one counting sort, computed once a call (``_places``) and
+    shared by dispatch and combine, forward and backward."""
+    sizes: jax.Array    # [Xh] rows of each held expert
+    live: jax.Array     # [R, 1] bool: a group holds the row
+    tok: jax.Array      # [R] the row's token
+    slot: jax.Array     # [R] the row's assignment, t * k + j
+    row: jax.Array      # [T, k] the assignment's row; R where it has none
+
+
+def _places(local, Xh, rows):
+    """``local`` [T, k]: each assignment's expert among the Xh held, Xh where
+    it is not held; a token names an expert at most once.  -> (_Places, rows
+    in use).
+
+    A counting sort by (expert, token) without a scatter.  A (token, expert)
+    cell is set where the token goes to the expert; the running sum over the
+    cells read expert by expert gives each set cell, and so each held
+    assignment, its row.  The other direction, each row's cell, is that
+    sum's inverse (``_first_set``).  Assignments past ``rows`` get no row."""
+    T, k = local.shape
+    chosen = local[:, :, None] == jnp.arange(Xh, dtype=local.dtype)
+    cells = jnp.any(chosen, axis=1)                          # [T, Xh]
+    running = jnp.cumsum(cells.T.reshape(Xh * T).astype(jnp.int32))
+    # Rows past ``used`` belong to no group: the grouped products leave
+    # them unwritten, forward and backward, so nothing may read them.
+    used = jnp.minimum(running[-1], rows)
+    live = (jnp.arange(rows) < used)[:, None]
+    place = running.reshape(Xh, T).T - 1                     # [T, Xh]
+    row = jnp.sum(jnp.where(chosen, place[:, None, :], 0), axis=2)
+    row = jnp.where((local < Xh) & (row < rows), row, rows)
+    cell = jnp.minimum(_first_set(running, rows), Xh * T - 1)    # e * T + t
+    tok, expert = cell % T, cell // T
+    which = jnp.sum(jnp.where(chosen, jnp.arange(k)[None, :, None], 0),
+                    axis=1)                                  # [T, Xh]: j
+    slot = tok * k + _take(which.reshape(T * Xh), tok * Xh + expert)
+    return _Places(jnp.sum(cells, axis=0, dtype=jnp.int32), live, tok, slot,
+                   row), used
+
+
+def _gather_rows(x, at: _Places):
+    return jnp.where(at.live, x[at.tok], 0)
+
+
+def _sum_rows(y, w, at: _Places):
+    """[T, E]: ``sum_j w[t, j] * y[at.row[t, j]]`` over the assignments that
+    have a row (``w`` None: 1), in float32, rounded once.  One gather over
+    all [T, k] slots: on the chip a gathered row costs 6.4 ns where a
+    scatter-added one cost 88 (PERF.md, PR 30)."""
+    R = y.shape[0]
+    z = y[jnp.minimum(at.row, R - 1)].astype(jnp.float32)    # [T, k, E]
+    if w is not None:
+        z = z * w[..., None]
+    return jnp.sum(jnp.where((at.row < R)[..., None], z, 0),
+                   axis=1).astype(y.dtype)
+
+
+@jax.custom_vjp
+def rows_of_tokens(x, at: _Places):
+    """x [T, E] -> [R, E]: row r is ``x[at.tok[r]]``, 0 where no group holds
+    the row.  Its transpose is ``tokens_from_rows`` with weights of 1."""
+    with jax.named_scope("dispatch"):
+        return _gather_rows(x, at)
+
+
+def _rows_fwd(x, at):
+    return rows_of_tokens(x, at), at
+
+
+def _rows_bwd(at, g):
+    with jax.named_scope("dispatch"):
+        return _sum_rows(g, None, at), None
+
+
+rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def tokens_from_rows(y, w, at: _Places):
+    """y [R, E], w [T, k] float32 -> [T, E]: each token's rows, each times
+    its assignment's weight, summed.  Rows no group holds are never read (a
+    select, not a product: they may hold anything).  Its transpose is
+    ``rows_of_tokens`` times the rows' weights, and a row-dot for ``w``."""
+    with jax.named_scope("combine"):
+        return _sum_rows(y, w, at)
+
+
+def _tokens_fwd(y, w, at):
+    return tokens_from_rows(y, w, at), (y, w, at)
+
+
+def _tokens_bwd(res, g):
+    y, w, at = res
+    with jax.named_scope("combine"):
+        g_rows = _gather_rows(g, at).astype(jnp.float32)     # 0 if not live
+        d_rows = jnp.sum(jnp.where(at.live, y, 0).astype(jnp.float32)
+                         * g_rows, axis=1)
+        d_w = _take(jnp.pad(d_rows, (0, 1)), at.row)
+        d_y = g_rows * _take(w.reshape(-1), at.slot)[:, None]
+        return d_y.astype(y.dtype), d_w, None
+
+
+tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
 
 
 def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl):
     """The held experts' part for tokens xt [T, E] in a buffer of ``rows``
     rows: (out [T, E], rows in use).  Right whenever the assignments to held
     experts number at most ``rows``."""
-    T, k = top.shape
     Xh = w_gate.shape[0]
     with jax.named_scope("dispatch"):
         local = top - held_start
         local = jnp.where((local >= 0) & (local < Xh), local, Xh)
-        flat = local.reshape(T * k)
-        order, sizes = _sort_by_group(flat, Xh + 1)
-        # Held assignments first, grouped by expert, in token order.
-        order, sizes = order[:rows], sizes[:Xh]
-        used = jnp.minimum(jnp.sum(sizes), rows)
-        # Rows past ``used`` belong to no group: the grouped products leave
-        # them unwritten, forward and backward, so they are cut off on both
-        # sides (here, or their garbage gradients would be added to tokens).
-        live = (jnp.arange(rows) < used)[:, None]
-        tok = order // k
-        x_rows = jnp.where(live, xt[tok], 0)
+        at, used = _places(local, Xh, rows)
+    x_rows = rows_of_tokens(xt, at)
     with jax.named_scope("experts"):
-        mm = functools.partial(grouped_matmul, group_sizes=sizes, impl=impl)
+        mm = functools.partial(grouped_matmul, group_sizes=at.sizes,
+                               impl=impl)
         h = jax.nn.silu(mm(x_rows, w_gate)) * mm(x_rows, w_up)
         y_rows = mm(h, w_down)
-    with jax.named_scope("combine"):
-        w_rows = w.reshape(T * k)[order][:, None].astype(y_rows.dtype)
-        y_rows = jnp.where(live, y_rows, 0) * w_rows
-        out = jnp.zeros(xt.shape, y_rows.dtype).at[tok].add(y_rows)
-    return out, used
+    return tokens_from_rows(y_rows, w.astype(jnp.float32), at), used
+
+
+def buffer_rows(T: int, k: int) -> int:
+    """Rows of the buffer a call of T tokens with k assignments each goes
+    through: a call whose held assignments pass them takes the buffer in
+    BUFFER_TIERS slices (``dropless_experts``)."""
+    return T * k if T % BUFFER_TIERS else T * k // BUFFER_TIERS
 
 
 def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
@@ -330,31 +451,37 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     tokens therefore go through a buffer of T*k / BUFFER_TIERS rows at once
     when the held assignments fit it, and otherwise in BUFFER_TIERS slices
     of the tokens, one after the other through the same buffer (a slice of
-    T / BUFFER_TIERS tokens has at most that many assignments).  The XLA passes
-    over the buffer (gather, activation, scatter) cost its whole size, the
+    T / BUFFER_TIERS tokens has at most that many assignments).
+
+    Rows move by gathers alone: into the buffer each row reads its token
+    (``rows_of_tokens``), out of it each token reads the rows of its k
+    assignments and sums them in float32 (``tokens_from_rows``: a select
+    where an assignment has no row), and the backward of each is the other.
+    The gather into the buffer and the activation cost the buffer's whole
+    size, the gather out of it T*k slots whatever their number in use, the
     grouped products only the rows in use."""
     T, k = routing.expert_index.shape
     Xh, tiers = w_gate.shape[0], BUFFER_TIERS
     top, w = routing.expert_index, routing.weights
     held = jnp.sum(routing.counts[held_start:held_start + Xh])
+    rows = buffer_rows(T, k)
     run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
-                            w_down=w_down, held_start=held_start, impl=impl)
-    if T % tiers:
-        out, used = run(xt, top, w, rows=T * k)
+                            w_down=w_down, held_start=held_start, rows=rows,
+                            impl=impl)
+    if rows == T * k:
+        out, used = run(xt, top, w)
         return out, (held, held - used)
-    rows = T * k // tiers
 
     def at_once():
-        return run(xt, top, w, rows=rows)
+        return run(xt, top, w)
 
     def in_slices():
         split = lambda a: a.reshape((tiers, T // tiers) + a.shape[1:])
         # Recomputed in the backward pass, or the gradient would keep every
         # slice's buffers (and, through the cond, keep room for them on the
         # usual path too).
-        out, used = jax.lax.map(
-            jax.checkpoint(lambda s: run(*s, rows=rows)),
-            (split(xt), split(top), split(w)))
+        out, used = jax.lax.map(jax.checkpoint(lambda s: run(*s)),
+                                (split(xt), split(top), split(w)))
         return out.reshape(xt.shape), jnp.sum(used)
 
     out, used = jax.lax.cond(held <= rows, at_once, in_slices)

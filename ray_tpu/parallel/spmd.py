@@ -98,8 +98,9 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
     gets ``StepState(opt, model)`` as its ``opt_state``: the step hands the
     model's part to the loss, and after the update to the model's
     ``update_state``, whose metrics join the step's (afmoe: the scalars
-    ``moe_held_assignments``, ``moe_load_max_over_mean``, ``moe_dropped``
-    and the routers' choices ``moe_choices`` [expert layers, tokens, k]).
+    ``moe_held_assignments``, ``moe_load_max_over_mean``, ``moe_dropped``,
+    ``moe_sliced_calls`` and the routers' choices ``moe_choices`` [expert
+    layers, tokens, k]).
 
     ``param_dtype`` overrides parameter (and hence optimizer-state)
     storage: bfloat16 halves the adamw footprint so ~1.5B params fit one

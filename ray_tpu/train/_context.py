@@ -173,7 +173,8 @@ def report(metrics: Dict[str, Any],
 #: the built-in metric each is recorded as
 _MOE_KEYS = {"moe_held_assignments": "ray_tpu_moe_held_assignments",
              "moe_load_max_over_mean": "ray_tpu_moe_load_max_over_mean",
-             "moe_dropped": "ray_tpu_moe_dropped_total"}
+             "moe_dropped": "ray_tpu_moe_dropped_total",
+             "moe_sliced_calls": "ray_tpu_moe_sliced_calls_total"}
 
 
 def _moe_loads(metrics: Dict[str, Any]) -> Dict[str, float]:

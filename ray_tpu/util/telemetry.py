@@ -205,6 +205,11 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "type": "counter", "tag_keys": (),
         "description": "Assignments to held experts that were not "
                        "computed (0: the dispatch is dropless)."},
+    "ray_tpu_moe_sliced_calls_total": {
+        "type": "counter", "tag_keys": (),
+        "description": "Expert-layer calls that took the dropless buffer "
+                       "in slices, their held assignments passing its "
+                       "rows (0: every call went through it at once)."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,

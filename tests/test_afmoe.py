@@ -177,10 +177,9 @@ def test_dropless_dispatch(push, held, impl):
     assert int(routing.counts.sum()) == 64 * 4
 
 
-def test_rows_no_group_holds_reach_neither_result_nor_gradient(monkeypatch):
-    """The Pallas grouped matmul leaves the rows past the last group
-    unwritten, forward and backward.  With those rows poisoned, the layer's
-    result and every gradient stay those of the clean products."""
+def _poisoned_grouped_matmul(lhs, rhs, group_sizes, impl=None):
+    """``lax.ragged_dot`` that, as the Pallas grouped matmul does, leaves the
+    rows past the last group unwritten, forward and backward: NaN here."""
     def dead_rows(lhs, sizes):
         return (jnp.arange(lhs.shape[0]) >= jnp.sum(sizes))[:, None]
 
@@ -201,10 +200,13 @@ def test_rows_no_group_holds_reach_neither_result_nor_gradient(monkeypatch):
         return jnp.where(dead, jnp.nan, dl), dr, None
 
     mm.defvjp(fwd, bwd)
+    return mm(lhs, rhs, group_sizes)
 
-    def poisoned(lhs, rhs, group_sizes, impl=None):
-        return mm(lhs, rhs, group_sizes)
 
+def test_rows_no_group_holds_reach_neither_result_nor_gradient(monkeypatch):
+    """The Pallas grouped matmul leaves the rows past the last group
+    unwritten, forward and backward.  With those rows poisoned, the layer's
+    result and every gradient stay those of the clean products."""
     xt, rw, wg, wu, wd = _experts()
 
     def layer(xt, rw, wg, wu, wd):
@@ -214,12 +216,112 @@ def test_rows_no_group_holds_reach_neither_result_nor_gradient(monkeypatch):
 
     want = jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4))(
         xt, rw, wg, wu, wd)
-    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    monkeypatch.setattr(moe, "grouped_matmul", _poisoned_grouped_matmul)
     got = jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4))(
         xt, rw, wg, wu, wd)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def _scattered_held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows,
+                         impl):
+    """``ops/moe._held_rows`` as it was before the pair (PR 29): a stable
+    sort of the assignments by held expert, ``xt[tok]`` into the buffer and
+    ``.at[tok].add`` out of it."""
+    T, k = top.shape
+    Xh = w_gate.shape[0]
+    local = top - held_start
+    local = jnp.where((local >= 0) & (local < Xh), local, Xh).reshape(T * k)
+    order = jnp.argsort(local, stable=True)[:rows]
+    sizes = jnp.sum(local[:, None] == jnp.arange(Xh)[None, :], axis=0,
+                    dtype=jnp.int32)
+    used = jnp.minimum(jnp.sum(sizes), rows)
+    live = (jnp.arange(rows) < used)[:, None]
+    tok = order // k
+    x_rows = jnp.where(live, xt[tok], 0)
+    mm = lambda a, b: moe.grouped_matmul(a, b, sizes, impl)
+    y_rows = mm(jax.nn.silu(mm(x_rows, w_gate)) * mm(x_rows, w_up), w_down)
+    y_rows = jnp.where(live, y_rows, 0) * w.reshape(T * k)[order][:, None]
+    return jnp.zeros(xt.shape, y_rows.dtype).at[tok].add(y_rows), used
+
+
+@pytest.mark.parametrize("poisoned", [False, True], ids=["clean", "poisoned"])
+@pytest.mark.parametrize("T,push", [(64, 0.0), (62, 0.0), (64, 10.0)],
+                         ids=["at-once", "odd-tokens", "sliced"])
+def test_the_pair_is_the_gather_and_scatter_add_it_replaced(
+        T, push, poisoned, monkeypatch):
+    """``rows_of_tokens`` / ``tokens_from_rows`` against ``xt[tok]`` and
+    ``.at[tok].add`` in float32: the layer's result and all five gradients,
+    with tokens that have 0, 1 and 8 held assignments, in the buffer at
+    once, in its slices and at a token count the tiers do not divide."""
+    xt, rw, wg, wu, wd = _experts(T=T, X=16, Xh=8, k=8)
+    # The first feature decides how many of a token's 8 choices are held:
+    # all of them, none, or (weakly pushed) a few; few enough in all for
+    # the buffer to take them at once.
+    lean = jnp.asarray(np.resize([6.0, -6.0, -6.0, -0.9, -6.0, -1.5, -6.0], T))
+    xt = xt.at[:, 0].set(lean)
+    rw = rw.at[0].set(jnp.where(jnp.arange(16) < 8, 1.0, -1.0))
+    bias = jnp.where(jnp.arange(16) < 8, push, 0.0)
+
+    def layer(xt, rw, wg, wu, wd):
+        routing = moe.sigmoid_routing(xt, rw, bias, 8, 2.5)
+        out, stats = moe.dropless_experts(xt, routing, wg, wu, wd, 0)
+        sliced = stats[0] > moe.buffer_rows(T, 8)
+        return jnp.sum(jnp.sin(out)), (out, routing, (*stats, sliced))
+
+    run = jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    with monkeypatch.context() as m:
+        m.setattr(moe, "_held_rows", _scattered_held_rows)
+        (want, (want_out, routing, _)), want_grads = run(xt, rw, wg, wu, wd)
+    if poisoned:
+        monkeypatch.setattr(moe, "grouped_matmul", _poisoned_grouped_matmul)
+    (got, (out, _, (held, dropped, sliced))), grads = run(xt, rw, wg, wu, wd)
+    a_token = np.asarray((routing.expert_index < 8).sum(-1))
+    if push:
+        assert set(a_token) == {8} and int(sliced) == 1
+    else:
+        assert {0, 1, 8} <= set(a_token) and int(sliced) == 0
+    assert int(dropped) == 0 and int(held) == a_token.sum()
+    np.testing.assert_allclose(out, want_out, atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("T", [64, 62], ids=["at-once-or-sliced",
+                                             "odd-tokens"])
+def test_no_scatter_of_rows_or_counts_in_the_expert_layer(T):
+    """Forward and backward of the routed experts move rows by gathers and
+    dense passes alone: no scatter or scatter-add lands in a ``[*, E]`` row
+    array or in the ``[X]`` counts."""
+    xt, rw, wg, wu, wd = _experts(T=T)
+    E, X = xt.shape[1], rw.shape[1]
+
+    def layer(xt, rw, wg, wu, wd):
+        routing = moe.sigmoid_routing(xt, rw, jnp.zeros((X,)), 4, 2.5)
+        out, _ = moe.dropless_experts(xt, routing, wg, wu, wd, 0,
+                                      "ragged_dot")
+        return jnp.sum(jnp.sin(out)) + 1e-3 * jnp.sum(routing.counts)
+
+    jaxpr = jax.make_jaxpr(jax.grad(layer, argnums=(0, 1, 2, 3, 4)))(
+        xt, rw, wg, wu, wd)
+    seen = [e for e in _equations(jaxpr.jaxpr)]
+    assert any(e.primitive.name == "gather" for e in seen)
+    for eqn in seen:
+        if eqn.primitive.name.startswith("scatter"):
+            shape = eqn.invars[0].aval.shape
+            assert shape != (X,) and not (len(shape) == 2 and shape[1] == E), \
+                (eqn.primitive.name, shape)
 
 
 def test_sigmoid_routing_chooses_by_score_plus_bias_and_weighs_by_score():
@@ -306,6 +408,11 @@ def test_train_step_carries_the_selection_bias_past_the_optimizer():
     np.testing.assert_allclose(first[1], want, atol=1e-7)
     assert float(m["loss"]) < float(first[0]["loss"])
     assert float(m["moe_dropped"]) == 0.0
+    # Half the experts are held here, twice the buffer's rows: each of the
+    # four expert layers' calls takes it in slices.
+    assert float(first[0]["moe_sliced_calls"]) == float(
+        (loads["counts"][:, 4:8].sum(-1) > moe.buffer_rows(2 * 64, cfg.top_k)
+         ).sum()) == 4.0
     assert float(first[0]["moe_held_assignments"]) == float(
         loads["counts"][:, 4:8].sum(-1).mean())
     assert float(m["moe_load_max_over_mean"]) >= 1.0
@@ -376,9 +483,11 @@ def test_published_stack_is_built_but_not_run():
 def test_report_records_the_experts_loads():
     from ray_tpu.train import _context
     got = _context._moe_loads({"moe_held_assignments": jnp.float32(5.0),
-                               "moe_dropped": 0.0, "loss": 1.0})
+                               "moe_dropped": 0.0, "loss": 1.0,
+                               "moe_sliced_calls": jnp.float32(2.0)})
     assert got == {"ray_tpu_moe_held_assignments": 5.0,
-                   "ray_tpu_moe_dropped_total": 0.0}
+                   "ray_tpu_moe_dropped_total": 0.0,
+                   "ray_tpu_moe_sliced_calls_total": 2.0}
 
 
 def test_benchmark_cell_rehearses_on_the_cpu():
@@ -397,3 +506,6 @@ def test_benchmark_cell_rehearses_on_the_cpu():
                 or "share" in n}
     assert "name=routing_mismatch_share" in done.stdout
     assert "'moe_dropped': 0.0" in done.stdout
+    # At the toy sizes a quarter of the experts are held: the calls take
+    # the buffer in slices, and the runner's line says so.
+    assert "'moe_sliced_calls': " in done.stdout

@@ -369,7 +369,8 @@ def _smoke_train_fn(config):
         # moe subsystem: a step's expert loads ride the report's own keys.
         train.report({"loss": 1.0 / (i + 1), "tokens": 64,
                       "moe_held_assignments": 8.0,
-                      "moe_load_max_over_mean": 1.25, "moe_dropped": 0.0})
+                      "moe_load_max_over_mean": 1.25, "moe_dropped": 0.0,
+                      "moe_sliced_calls": 0.0})
 
 
 @serve.deployment(name="telemetry_echo")

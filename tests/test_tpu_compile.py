@@ -302,3 +302,13 @@ def test_trinity_train_step_compiles_at_the_cell_sizes(topo, capsys):
         assert name in text, name
     # bf16 weights and two bf16 moments of 1,243 M parameters.
     assert 7.4e9 < mem.argument_size_in_bytes < 7.6e9
+    # 9.69 GB of temporaries with the scatters (PR 29); the gather over all
+    # of a token's slots holds [T, k, E] for a moment.
+    assert mem.temp_size_in_bytes < 10.5e9
+    # Rows and counts move by gathers and dense passes alone.  (Upstream's
+    # grouped matmul builds its tiles' table with a scatter-add of 47
+    # places, under ``experts``: not ours.)
+    scatters = [line for line in text.splitlines()
+                if " scatter(" in line and "block/moe" in line
+                and "/experts/" not in line]
+    assert not scatters, scatters[:2]
