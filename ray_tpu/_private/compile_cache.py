@@ -5,8 +5,8 @@ the caller (or the machine) set it; otherwise the cache is one fixed,
 git-ignored directory in the checkout.  The path is part of the cache
 key, so it is never a temporary name, a pid or a time.  The decision is
 written into ``os.environ`` at ``import ray_tpu`` — before jax reads its
-flags — so the driver, bench.py, chip_smoke.py and every worker the node
-spawns (they inherit the environment) share one cache.
+flags — so the driver, benchmark/run.py, chip_smoke.py and every worker the
+node spawns (they inherit the environment) share one cache.
 """
 
 from __future__ import annotations

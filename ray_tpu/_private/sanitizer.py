@@ -30,8 +30,7 @@ also land in flight-recorder debug bundles as ``leak_findings.json``.
 
 Scope: the check runs in the *driver* process (worker-process threads
 die with their process).  Overhead when disabled is zero — nothing is
-patched; when enabled it is one dict write per tracked event
-(``bench.py --spec sanitize`` keeps it under the 2% budget).
+patched; when enabled it is one dict write per tracked event.
 """
 
 from __future__ import annotations

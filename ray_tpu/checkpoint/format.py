@@ -522,8 +522,7 @@ def restore_tree(dirpath: str, placement: Optional[Callable] = None,
 def save_pytree(tree: Any, path: str, use_orbax: bool = False) -> None:
     """Legacy synchronous save: device arrays -> host numpy -> one pickle.
 
-    Kept as the compat path behind ``train._checkpoint.save_pytree`` and
-    as the sync baseline in ``bench.py --spec checkpoint``.
+    Kept as the compat path behind ``train._checkpoint.save_pytree``.
     """
     import time as _time
 

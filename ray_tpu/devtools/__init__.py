@@ -19,8 +19,7 @@ and understands ``ray_tpu`` semantics):
   the full lock-order detector: a per-process acquisition-order graph
   flags cycles (AB/BA potential deadlocks) and sleeps under a held
   lock.  ``RAY_TPU_LOCK_PROFILE=1`` is the lighter contention
-  profiler: per-creation-site wait/hold histograms only (<2% on
-  scheduler throughput, gated by ``bench.py --spec control_plane``),
+  profiler: per-creation-site wait/hold histograms only,
   reported by ``contention_report()``, published to the
   ``ray_tpu_lock_{wait,hold}_seconds`` catalog series, dumped into
   flight-recorder bundles as ``lock_contention.json`` and rendered by
@@ -45,8 +44,8 @@ and understands ``ray_tpu`` semantics):
 
 * ``ray_tpu.devtools.chaos`` — the chaos SLA harness: scripted
   kill/preempt/add schedules replayed against a live cluster, so drain
-  SLAs and goodput-under-preemption are measured (``bench.py --spec
-  preempt``), not asserted from a single hand-timed kill.
+  SLAs and goodput-under-preemption are measured, not asserted from a
+  single hand-timed kill.
 """
 
 from .lint import (Finding, LintResult, Rule, iter_rules, lint_paths,

@@ -31,8 +31,7 @@ and delayed capacity arrivals.  Events carry ``node=None`` (a symbolic
 victim); the runner resolves a live worker at FIRE time, so the same
 seeded schedule replays against clusters whose membership churns.
 
-Used by ``bench.py --spec preempt`` / ``--spec spotfleet`` and the
-tier-1 drain-SLA chaos tests.
+Used by the tier-1 drain-SLA chaos tests.
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""Open-loop serving load generator (the serve_load bench harness).
+"""Open-loop serving load generator.
 
 Open-loop means arrivals follow a Poisson process pinned to the WALL
 CLOCK: a slow server does not slow the generator down, so saturation
@@ -8,8 +8,9 @@ closed-loop (wait-for-completion) driver can never produce, and the one
 
 The workload is the disagg motivation mix: mostly short interactive
 prompts plus a fraction of long prompts whose inline prefill would
-stall every active decode.  Used by ``bench.py --spec serve_load`` and
-the tier-1 saturation smoke test.
+stall every active decode.  Used by the tier-1 saturation smoke test
+(``tests/test_llm_disagg.py``); the benchmark's cells have
+``benchmark/loadgen.py``.
 """
 
 from __future__ import annotations

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from . import _lm
 from ..ops.attention import attention as _attention
 from ..ops.attention import reference_attention
-from ..ops.moe import moe_layer
 from ..ops.norms import rms_norm
 from ..ops.ring_attention import ring_attention
 from ..ops.rope import apply_rope, rope_frequencies
@@ -51,19 +50,12 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # MoE: 0 experts = dense model.
-    num_experts: int = 0
-    moe_top_k: int = 2
-    # 0 = dense (masked) dispatch; > 0 = capacity-based sorted dispatch
-    # with this capacity factor (see ops/moe.py).  Sparse is the default:
-    # expert FLOPs scale as top_k*capacity_factor/num_experts of dense.
-    moe_capacity_factor: float = 1.25
     # "auto" (flash on TPU / reference on CPU), "reference", "flash",
     # "flash_interpret", "ring", "ulysses"
     attention_impl: str = "auto"
     # Mesh axis used by ring/ulysses attention.
     seq_axis: str = "sp"
-    # False | True/"full" | "mlp_only" (see forward_with_aux)
+    # False | True/"full" | "mlp_only" (see _forward_hidden)
     remat: Any = True
     # Pipeline parallelism: number of microbatches (0 = off).  Needs a
     # mesh with pp > 1 and layers % pp == 0; the "layers" logical axis is
@@ -110,20 +102,10 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "wv": ("layers", "embed", "kv_heads", "head_dim"),
         "wo": ("layers", "heads", "head_dim", "embed"),
         "mlp_norm": ("layers", None),
+        "w_gate": ("layers", "embed", "mlp"),
+        "w_up": ("layers", "embed", "mlp"),
+        "w_down": ("layers", "mlp", "embed"),
     }
-    if cfg.num_experts:
-        block.update({
-            "router": ("layers", "embed", None),
-            "w_gate": ("layers", "expert", "embed", "mlp"),
-            "w_up": ("layers", "expert", "embed", "mlp"),
-            "w_down": ("layers", "expert", "mlp", "embed"),
-        })
-    else:
-        block.update({
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        })
     return {
         "embed": ("vocab", "embed"),
         "blocks": block,
@@ -134,6 +116,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
 
 def init_params(cfg: LlamaConfig, key: jax.Array,
                 param_dtype=jnp.float32) -> Dict[str, Any]:
+    # Ten keys, of which ks[5] is not used: a seed gives the weights it
+    # always gave.
     ks = jax.random.split(key, 10)
     L, E, H, Hkv, D, M = (cfg.layers, cfg.hidden, cfg.heads, cfg.kv_heads,
                           cfg.head_dim, cfg.mlp_dim)
@@ -149,21 +133,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         "wv": trunc(ks[3], (L, E, Hkv, D), E),
         "wo": trunc(ks[4], (L, H, D, E), H * D),
         "mlp_norm": jnp.ones((L, E), param_dtype),
+        "w_gate": trunc(ks[6], (L, E, M), E),
+        "w_up": trunc(ks[7], (L, E, M), E),
+        "w_down": trunc(ks[8], (L, M, E), M),
     }
-    if cfg.num_experts:
-        X = cfg.num_experts
-        blocks.update({
-            "router": trunc(ks[5], (L, E, X), E),
-            "w_gate": trunc(ks[6], (L, X, E, M), E),
-            "w_up": trunc(ks[7], (L, X, E, M), E),
-            "w_down": trunc(ks[8], (L, X, M, E), M),
-        })
-    else:
-        blocks.update({
-            "w_gate": trunc(ks[6], (L, E, M), E),
-            "w_up": trunc(ks[7], (L, E, M), E),
-            "w_down": trunc(ks[8], (L, M, E), M),
-        })
     return {
         "embed": trunc(ks[0], (cfg.vocab_size, E), E),
         "blocks": blocks,
@@ -221,26 +194,17 @@ def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
 
 @jax.named_scope("block/mlp")
 def _mlp_half(cfg: LlamaConfig, x, layer):
-    """MLP/MoE residual branch. x: [B, S, E] -> ([B, S, E], aux)."""
+    """MLP residual branch. x: [B, S, E] -> [B, S, E]."""
     dt = cfg.dtype
     h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    if cfg.num_experts:
-        mlp_out, aux = moe_layer(h, layer["router"].astype(dt),
-                                 layer["w_gate"].astype(dt),
-                                 layer["w_up"].astype(dt),
-                                 layer["w_down"].astype(dt),
-                                 k=cfg.moe_top_k,
-                                 capacity_factor=cfg.moe_capacity_factor)
-    else:
-        gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(dt),
-                          preferred_element_type=dt)
-        up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(dt),
-                        preferred_element_type=dt)
-        mlp_out = jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                             layer["w_down"].astype(dt),
-                             preferred_element_type=dt)
-        aux = jnp.zeros((), jnp.float32)
-    return x + mlp_out, aux
+    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(dt),
+                      preferred_element_type=dt)
+    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(dt),
+                    preferred_element_type=dt)
+    mlp_out = jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
+                         layer["w_down"].astype(dt),
+                         preferred_element_type=dt)
+    return x + mlp_out
 
 
 def _block(cfg: LlamaConfig, cos, sin, positions, x, layer):
@@ -252,8 +216,8 @@ def _block(cfg: LlamaConfig, cos, sin, positions, x, layer):
 def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                     cfg: LlamaConfig,
                     positions: Optional[jax.Array] = None):
-    """tokens: [B, S] int32 -> (final hidden [B, S, E], moe aux loss);
-    forward_with_aux applies the lm_head on top.
+    """tokens: [B, S] int32 -> final hidden [B, S, E]; forward applies the
+    lm_head on top.
 
     ``positions``: absolute positions [S] (defaults to arange; sequence-
     sharded callers pass their shard's global positions).
@@ -278,8 +242,7 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
                           cfg.remat)
 
     def scan_body(x, layer):
-        x, aux = block(x, layer)
-        return x, aux
+        return block(x, layer), None
 
     if cfg.pp_microbatches:
         # Microbatched pipeline over the pp mesh axis: each stage scans its
@@ -290,8 +253,6 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
         if mesh is None or mesh.shape.get("pp", 1) <= 1:
             raise ValueError(
                 "cfg.pp_microbatches > 0 needs a global mesh with pp > 1")
-        if cfg.num_experts:
-            raise NotImplementedError("MoE + pipeline parallelism")
         if cfg.attention_impl in ("ring", "ulysses"):
             raise NotImplementedError(
                 "sequence-parallel attention inside a pipeline stage")
@@ -302,47 +263,32 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
 
         x = pipeline_blocks(params["blocks"], x, stage_body,
                             num_microbatches=cfg.pp_microbatches, mesh=mesh)
-        auxes = jnp.zeros((), jnp.float32)
     else:
-        x, auxes = jax.lax.scan(scan_body, x, params["blocks"])
+        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, jnp.sum(auxes)
-
-
-def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
-                     cfg: LlamaConfig,
-                     positions: Optional[jax.Array] = None):
-    x, aux = _forward_hidden(params, tokens, cfg, positions)
-    logits = jnp.einsum("bse,ev->bsv", x,
-                        params["lm_head"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, aux
+    return x
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
             positions: Optional[jax.Array] = None) -> jax.Array:
-    return forward_with_aux(params, tokens, cfg, positions)[0]
+    x = _forward_hidden(params, tokens, cfg, positions)
+    return jnp.einsum("bse,ev->bsv", x,
+                      params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
             cfg: LlamaConfig,
             positions: Optional[jax.Array] = None) -> jax.Array:
     """Next-token cross-entropy.  batch: tokens [B,S], loss_mask [B,S]."""
-    x, aux = _forward_hidden(params, batch["tokens"], cfg, positions)
-    loss = _lm.next_token_loss(x, params["lm_head"], batch, cfg.loss_chunks,
+    x = _forward_hidden(params, batch["tokens"], cfg, positions)
+    return _lm.next_token_loss(x, params["lm_head"], batch, cfg.loss_chunks,
                                cfg.dtype)
-    if cfg.num_experts:
-        loss = loss + 0.01 * aux / cfg.layers
-    return loss
 
 
 def num_params(cfg: LlamaConfig) -> int:
     L, E, H, Hkv, D, M, V = (cfg.layers, cfg.hidden, cfg.heads, cfg.kv_heads,
                              cfg.head_dim, cfg.mlp_dim, cfg.vocab_size)
-    per_layer = E * H * D + 2 * E * Hkv * D + H * D * E + 2 * E
-    if cfg.num_experts:
-        per_layer += E * cfg.num_experts + 3 * cfg.num_experts * E * M
-    else:
-        per_layer += 3 * E * M
+    per_layer = E * H * D + 2 * E * Hkv * D + H * D * E + 2 * E + 3 * E * M
     return V * E + L * per_layer + E + E * V

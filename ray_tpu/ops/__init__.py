@@ -8,9 +8,8 @@ delegated to vLLM/DeepSpeed).  Built natively here:
 - ``ring_attention``— context parallelism over an ICI ring
                       (K/V rotate via ppermute, online-softmax accumulation)
 - ``ulysses``       — sequence<->head all-to-all context parallelism
-- ``moe``           — mixture-of-experts: softmax top-k with a capacity and
-                      expert-parallel dispatch; sigmoid scores with a
-                      selection bias, dropless, over the experts held
+- ``moe``           — mixture-of-experts: sigmoid scores with a selection
+                      bias, dropless, over the experts held
 - ``norms``/``rope``/``swiglu`` — fused-friendly elementwise building blocks
 """
 
@@ -19,10 +18,9 @@ from .rope import apply_rope, rope_frequencies
 from .attention import attention, flash_attention, reference_attention
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
-from .moe import moe_layer, top_k_routing
 
 __all__ = [
     "rms_norm", "apply_rope", "rope_frequencies",
     "attention", "flash_attention", "reference_attention",
-    "ring_attention", "ulysses_attention", "moe_layer", "top_k_routing",
+    "ring_attention", "ulysses_attention",
 ]

@@ -23,9 +23,8 @@ Pieces:
 * Reason codes (``R_*``) — the closed vocabulary every rejection is
   tallied under; `ray-tpu task why`, ``state.explain_task()`` and the
   ``sched_decisions.json`` flight-recorder section all speak it.
-* ``set_enabled()/enabled()`` — the instrumentation kill switch the
-  ``bench.py --spec control_plane`` overhead phase toggles (and
-  ``RAY_TPU_SCHED_TRACE=0`` for operators who want the last word).
+* ``set_enabled()/enabled()`` — the instrumentation kill switch
+  (``RAY_TPU_SCHED_TRACE=0`` for operators who want the last word).
 """
 
 from .decisions import (DecisionRing, R_AFFINITY, R_BUNDLE, R_DRAINING,

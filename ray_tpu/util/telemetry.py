@@ -22,7 +22,8 @@ Three pieces:
   buffer append; in a worker it is a deque append that the metrics
   flusher ships in batches (no frame per span — safe on per-decode-step
   hot paths); with no runtime at all it is a no-op, so library code (the
-  inference engine under bench.py) can stay instrumented unconditionally.
+  inference engine driven without a cluster) can stay instrumented
+  unconditionally.
   Where jax is loaded a span is also a ``TraceAnnotation``, so a profiler
   session shows it on the device trace's clock.
 * ``GoodputTracker`` — partitions a training run's wall time into

@@ -10,8 +10,9 @@ The env vars must be set before the first jax import anywhere in the process.
 import os
 
 # Tests run on the CPU: eight forced host devices stand in for a TPU host.
-# The chip is reached only through chip_smoke.py and bench.py.  Nothing
-# here (or in any test module) may touch jax or the TPU library at import:
+# The chip is reached only through chip_smoke.py and benchmark/run.py.
+# Nothing here (or in any test module) may touch jax or the TPU library at
+# import:
 # every xdist worker imports every test file.
 os.environ["JAX_PLATFORMS"] = "cpu"
 if "xla_force_host_platform_device_count" not in os.environ.get(
@@ -39,8 +40,7 @@ import pytest  # noqa: E402
 # representatives.  The full suite (no -m) is unchanged.
 
 _SLOW_MODULES = {
-    "test_7b_shapes", "test_models", "test_ops", "test_pipeline",
-    "test_llm", "test_rl", "test_rl_breadth", "test_train",
+    "test_pipeline", "test_llm", "test_rl", "test_rl_breadth", "test_train",
     "test_train_elastic", "test_train_multislice", "test_collective",
     "test_dag", "test_tune", "test_chaos", "test_recovery", "test_oom",
     "test_serve_ha", "test_runtime_env", "test_autoscaler", "test_head_ft",
@@ -50,12 +50,6 @@ _SLOW_MODULES = {
 # Fast representatives inside slow modules so the quick tier still touches
 # every subsystem (node ids are matched by substring).
 _QUICK_IN_SLOW = {
-    "test_models": ("test_num_params_matches",
-                    "test_logical_axes_tree_matches_params"),
-    "test_ops": ("TestRmsNorm", "TestRope", "TestMeshSharding",
-                 "test_routing_topk", "test_block_schedule",
-                 "test_packed_schedule",
-                 "test_three_blocks_a_side", "test_k_block_no_q_sees"),
     "test_llm": ("test_stop_tokens",),
     "test_rl": ("TestBuffers", "TestGAE"),
     "test_pipeline": ("test_pp_requires_mesh",),
@@ -74,7 +68,6 @@ _QUICK_IN_SLOW = {
     "test_runtime_env": ("test_working_dir_ships_files", "test_endpoints"),
     "test_chaos": ("test_workload_correct_under_message_delays",),
     "test_serve_ha": (),
-    "test_7b_shapes": (),
     "test_rl_breadth": (),
     "test_train_elastic": (),
 }

@@ -667,45 +667,6 @@ def train_loop(params, opt_state, batches):
             assert not rt5, f"{mod}: {[(f.rule, f.line) for f in rt5]}"
 
 
-# -- bench smoke ------------------------------------------------------------
-
-
-class TestLintBenchSmoke:
-    def test_fast_bench_end_to_end(self, tmp_path):
-        """`bench.py --spec lint --fast` as a tier-1 smoke: the lint
-        pass gates its 8 s budget and the sync-tripwire overhead phase
-        produces its doc (the fast profile smoke-tests the harness; the
-        < 2% overhead gate runs on the full profile's rep count)."""
-        import subprocess
-        import sys
-        repo_root = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        out = str(tmp_path / "BENCH_lint.json")
-        code = (
-            "import bench\n"
-            "try:\n"
-            f"    bench.bench_lint(fast=True, out_path={out!r})\n"
-            "except SystemExit:\n"
-            "    pass\n"
-            "print('BENCH_DONE')\n")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, "-u", "-c", code], cwd=repo_root, env=env,
-            capture_output=True, text=True, timeout=300)
-        assert proc.returncode == 0 and "BENCH_DONE" in proc.stdout, \
-            f"stdout:\n{proc.stdout[-2000:]}\nstderr:\n" \
-            f"{proc.stderr[-4000:]}"
-        with open(out) as f:
-            doc = json.load(f)
-        assert doc["findings"] == 0
-        assert doc["within_budget"] is True
-        tw = doc["sync_tripwire"]
-        assert tw["budget_pct"] == 2.0
-        assert len(tw["per_rep_delta_pct"]) == tw["reps"]
-        assert isinstance(tw["overhead_pct"], float)
-        assert doc["pass"] is True
-
-
 # -- TrackedFunction jit-kwarg forwarding -----------------------------------
 
 

@@ -1,7 +1,7 @@
 """Disaggregated serving tests: chunked prefill exactness, KV-pressure
 preemption/re-admission, prefill->decode KV handoff (direct + through the
-shm object store), SLO-aware admission shedding, and the serve_load
-saturation smoke (the tier-1 half of the serve_load bench contract).
+shm object store), SLO-aware admission shedding, and a saturation smoke
+under the open-loop generator of ``llm/disagg/loadgen.py``.
 """
 
 from __future__ import annotations

@@ -8,9 +8,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import (apply_rope, attention, flash_attention, moe_layer,
+from ray_tpu.ops import (apply_rope, attention, flash_attention,
                          reference_attention, ring_attention,
-                         rms_norm, rope_frequencies, top_k_routing)
+                         rms_norm, rope_frequencies)
 from ray_tpu.ops.attention import (DIAGONAL, EMPTY, FIRST, INTERIOR, KI,
                                    KIND, LAST, QI, block_schedule)
 from ray_tpu.ops.ring_attention import ring_attention_sharded
@@ -319,103 +319,6 @@ class TestUlysses:
         ref = reference_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=1e-4)
-
-
-class TestMoE:
-    def test_routing_topk(self):
-        x = jax.random.normal(jax.random.key(0), (2, 8, 16))
-        rw = jax.random.normal(jax.random.key(1), (16, 4))
-        info = top_k_routing(x, rw, k=2)
-        nz = (np.asarray(info.combine_weights) > 0).sum(-1)
-        assert (nz == 2).all()
-        np.testing.assert_allclose(
-            np.asarray(info.combine_weights).sum(-1), 1.0, rtol=1e-5)
-
-    def test_moe_layer_shapes_and_grad(self):
-        B, S, E, M, X = 2, 8, 16, 32, 4
-        ks = jax.random.split(jax.random.key(0), 5)
-        x = jax.random.normal(ks[0], (B, S, E))
-        rw = jax.random.normal(ks[1], (E, X)) * 0.1
-        wg = jax.random.normal(ks[2], (X, E, M)) * 0.1
-        wu = jax.random.normal(ks[3], (X, E, M)) * 0.1
-        wd = jax.random.normal(ks[4], (X, M, E)) * 0.1
-        out, aux = moe_layer(x, rw, wg, wu, wd, k=2)
-        assert out.shape == (B, S, E)
-        assert np.isfinite(aux)
-
-        def loss(rw):
-            o, a = moe_layer(x, rw, wg, wu, wd, k=2)
-            return (o ** 2).mean() + 0.01 * a
-        g = jax.grad(loss)(rw)
-        assert np.isfinite(np.asarray(g)).all()
-
-    def test_sparse_dispatch_matches_dense_at_full_capacity(self):
-        # Capacity >= T means nothing drops: sparse == dense exactly.
-        B, S, E, M, X = 2, 8, 16, 32, 4
-        ks = jax.random.split(jax.random.key(1), 5)
-        x = jax.random.normal(ks[0], (B, S, E))
-        rw = jax.random.normal(ks[1], (E, X)) * 0.1
-        wg = jax.random.normal(ks[2], (X, E, M)) * 0.1
-        wu = jax.random.normal(ks[3], (X, E, M)) * 0.1
-        wd = jax.random.normal(ks[4], (X, M, E)) * 0.1
-        dense, _ = moe_layer(x, rw, wg, wu, wd, k=2, capacity_factor=0.0)
-        # capacity_factor X/k -> capacity == T: no token can overflow.
-        sparse, _ = moe_layer(x, rw, wg, wu, wd, k=2,
-                              capacity_factor=X / 2)
-        np.testing.assert_allclose(np.asarray(sparse), np.asarray(dense),
-                                   atol=1e-5, rtol=1e-4)
-
-    def test_sparse_dispatch_capacity_drops_and_grads(self):
-        from ray_tpu.ops.moe import capacity_dispatch
-        B, S, E, M, X = 2, 16, 16, 32, 4
-        ks = jax.random.split(jax.random.key(2), 5)
-        x = jax.random.normal(ks[0], (B, S, E))
-        rw = jax.random.normal(ks[1], (E, X)) * 0.1
-        info = top_k_routing(x, rw, k=2)
-        capacity = 4  # far below T*k/X = 16: forces drops
-        dispatch, combine = capacity_dispatch(info, X, capacity)
-        # No expert slot is double-assigned; per-expert load <= capacity.
-        per_slot = np.asarray(dispatch).sum(axis=0)  # [X, C]
-        assert (per_slot <= 1.0 + 1e-6).all()
-        assert (np.asarray(dispatch).sum(axis=(0, 2)) <= capacity).all()
-        # Dropped tokens have zero combine weight but output stays finite
-        # and differentiable.
-        wg = jax.random.normal(ks[2], (X, E, M)) * 0.1
-        wu = jax.random.normal(ks[3], (X, E, M)) * 0.1
-        wd = jax.random.normal(ks[4], (X, M, E)) * 0.1
-
-        def loss(rw):
-            o, a = moe_layer(x, rw, wg, wu, wd, k=2, capacity_factor=0.5)
-            return (o ** 2).mean() + 0.01 * a
-        g = jax.grad(loss)(rw)
-        assert np.isfinite(np.asarray(g)).all()
-
-    def test_sorted_dispatch_invariants(self):
-        from ray_tpu.ops.moe import sorted_dispatch
-        B, S, E, X, k = 2, 16, 8, 4, 2
-        ks = jax.random.split(jax.random.key(3), 2)
-        x = jax.random.normal(ks[0], (B, S, E))
-        rw = jax.random.normal(ks[1], (E, X)) * 0.1
-        info = top_k_routing(x, rw, k=k)
-        capacity = 4  # below T*k/X = 16: forces drops
-        tok_s, e_s, slot_s, w_s, keep = sorted_dispatch(info, X, capacity)
-        tok_s, e_s, slot_s, keep = (np.asarray(tok_s), np.asarray(e_s),
-                                    np.asarray(slot_s), np.asarray(keep))
-        # Kept (expert, slot) pairs are unique and within capacity.
-        kept = [(int(e), int(s)) for e, s, f in zip(e_s, slot_s, keep) if f]
-        assert len(kept) == len(set(kept))
-        assert all(0 <= s < capacity for _e, s in kept)
-        # Per-expert kept load <= capacity; dropped slots read as OOB.
-        for e in range(X):
-            assert sum(1 for ee, _s in kept if ee == e) <= capacity
-        assert (slot_s[~keep] == capacity).all()
-        # Every (token, expert) assignment appears exactly once.
-        pairs = sorted(zip(tok_s.tolist(), e_s.tolist()))
-        want = sorted((t, int(e))
-                      for t in range(B * S)
-                      for e in np.asarray(info.expert_index).reshape(
-                          B * S, k)[t])
-        assert pairs == want
 
 
 class TestMeshSharding:

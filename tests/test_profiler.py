@@ -1,5 +1,5 @@
 """Cluster profiler: on-demand merged capture, recompile detection,
-step-phase attribution, span nesting, bench --compare gate.
+step-phase attribution, span nesting.
 
 Reference analogs: the reference dashboard's py-spy/`ray timeline`
 integration and the OpenTelemetry substrate its native layer ships —
@@ -8,7 +8,6 @@ here the TPU-native equivalents built in PR 10 (ISSUE 10).
 
 import json
 import os
-import sys
 import threading
 import time
 
@@ -19,8 +18,6 @@ from ray_tpu import profiler
 from ray_tpu.profiler import attribution, recompile
 from ray_tpu.util import state as state_api
 from ray_tpu.util import telemetry
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _wait_for(predicate, timeout=15.0, period=0.1):
@@ -367,60 +364,6 @@ class TestSpanNesting:
         assert len(spans) == 2
         inner, outer = spans  # inner exits first
         assert inner["parent_id"] == outer["span_id"]
-
-
-class TestCompareGate:
-    def _bench(self):
-        sys.path.insert(0, REPO_ROOT)
-        import bench
-        return bench
-
-    def _write(self, tmp_path, name, doc):
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    def test_regressions_detected_by_direction(self, tmp_path):
-        bench = self._bench()
-        a = self._write(tmp_path, "a.json", {
-            "tps": 100.0, "itl_p99_ms": 10.0, "within_budget": True,
-            "budget_pct": 2.0, "knobs": {"steps": 10}})
-        b = self._write(tmp_path, "b.json", {
-            "tps": 80.0, "itl_p99_ms": 13.0, "within_budget": False,
-            "budget_pct": 4.0, "knobs": {"steps": 99}})
-        out = bench.compare_bench(a, b, threshold=0.10)
-        regressed = {r[0] for r in out["regressions"]}
-        # Throughput down, latency up, health boolean flipped — and the
-        # bookkeeping fields (budget, knobs) never gate.
-        assert regressed == {"tps", "itl_p99_ms", "within_budget"}
-        with pytest.raises(SystemExit):
-            bench.run_compare(a, b, 0.10)
-
-    def test_noise_below_threshold_passes(self, tmp_path):
-        bench = self._bench()
-        a = self._write(tmp_path, "a.json", {"tps": 100.0, "p99_ms": 10.0})
-        b = self._write(tmp_path, "b.json", {"tps": 95.0, "p99_ms": 10.8})
-        out = bench.compare_bench(a, b, threshold=0.10)
-        assert not out["regressions"]
-
-    def test_rep_lists_use_trimmed_mean(self, tmp_path):
-        bench = self._bench()
-        # One wild outlier rep in the candidate must not gate: the
-        # trimmed mean drops best+worst before comparing.
-        a = self._write(tmp_path, "a.json",
-                        {"phases_on_s": [1.0, 1.0, 1.0, 1.0, 1.0]})
-        b = self._write(tmp_path, "b.json",
-                        {"phases_on_s": [1.0, 1.0, 1.02, 1.0, 9.0]})
-        out = bench.compare_bench(a, b, threshold=0.10)
-        assert not out["regressions"]
-
-    def test_improvements_reported_not_fatal(self, tmp_path):
-        bench = self._bench()
-        a = self._write(tmp_path, "a.json", {"tokens_per_sec": 100.0})
-        b = self._write(tmp_path, "b.json", {"tokens_per_sec": 150.0})
-        out = bench.compare_bench(a, b, threshold=0.10)
-        assert out["improvements"] and not out["regressions"]
-        bench.run_compare(a, b, 0.10)  # exits 0
 
 
 class TestRequestTrace:

@@ -1,22 +1,19 @@
 """Goodput-driven autoscaling + spot-fleet elasticity.
 
 Policy unit tests (pure decision logic), the seeded spot-market schedule
-generator, the checked-in BENCH_spotfleet.json SLA gate, and the tier-1
-smoke of ``bench.py --spec spotfleet --fast`` (bounded runtime).
+generator, the autoscaler's published status, and the declarative
+InstanceManager's pre-buy timing.
 """
 
 from __future__ import annotations
 
 import json
-import os
-import sys
+import time
 
 import pytest
 
 from ray_tpu.autoscaler import (GoodputAutoscalePolicy,
                                 GoodputPolicyConfig)
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestGoodputPolicy:
@@ -149,56 +146,12 @@ class TestSpotFleetSchedule:
             [(e.at_s, e.action) for e in b.events]
 
 
-class TestSpotfleetBenchGate:
-    """The checked-in BENCH_spotfleet.json is the elasticity-SLA
-    baseline: it must hold its own SLA, and the --compare gate must
-    treat its metrics as gateable (directions resolve)."""
-
-    def _load(self):
-        path = os.path.join(REPO_ROOT, "BENCH_spotfleet.json")
-        assert os.path.exists(path), \
-            "BENCH_spotfleet.json baseline missing"
-        with open(path) as f:
-            return path, json.load(f)
-
-    def test_checked_in_baseline_holds_sla(self):
-        _path, doc = self._load()
-        sla = doc["sla"]
-        assert sla["pass"] is True
-        assert sla["floor_held"] and sla["beats_naive_goodput"]
-        assert sla["lost_under_budget"] and sla["beats_naive_lost_steps"]
-        assert sla["prebuy_before_deadline"]
-        assert sla["multislice_survivor_committed"]
-        assert sla["multislice_zero_lost_steps"]
-        g = doc["churn"]["graceful"]
-        n = doc["churn"]["naive"]
-        assert g["scaled_goodput"] > n["scaled_goodput"]
-        assert g["lost_steps"] <= n["lost_steps"]
-        assert g["prebuy_total"] >= 1
-
-    def test_compare_gate_covers_spotfleet_metrics(self):
-        sys.path.insert(0, REPO_ROOT)
-        import bench
-        path, doc = self._load()
-        out = bench.compare_bench(path, path, threshold=0.10)
-        assert not out["regressions"]
-        # The SLA booleans and goodput numbers actually gate (present in
-        # the checked set), so a silently eroded rerun would fail.
-        flat = bench._flatten_bench(doc)
-        gated = [p for p in flat
-                 if bench._metric_direction(p) is not None]
-        assert any("scaled_goodput" in p for p in gated)
-        assert any(p.endswith("sla.pass") for p in gated)
-
-
 class TestAutoscalerStatusPublish:
     def test_reconcile_publishes_prebuy_status_to_kv(self):
         """The reconcile loop drops its live view (pending pre-buys,
         prebuy total, policy state) into the head KV under
         AUTOSCALER_KV_KEY — what `ray-tpu status` and
         /api/cluster/status print next to the goodput line."""
-        import time
-
         import ray_tpu
         from ray_tpu.autoscaler import (AUTOSCALER_KV_KEY, Autoscaler,
                                         AutoscalerConfig,
@@ -239,70 +192,68 @@ class TestAutoscalerStatusPublish:
             ray_tpu.shutdown()
 
 
-class TestSpotfleetSmoke:
-    # SLA axes that measure wall-clock goodput of the chaos scenarios.
-    # On a loaded single-core host these dip without any code
-    # regression (replacement boot + join competes with the training
-    # loop for the same CPU), so they get ONE retry.  Everything else
-    # in the SLA is deterministic and must hold on every attempt.
-    _LOAD_SENSITIVE = ("floor_held",)
+def _spotfleet_prebuy_timing() -> dict:
+    """Deterministic pre-buy timing over the declarative layer: a
+    FakeCloudProvider posts a preemption notice and the InstanceManager
+    must REQUEST the replacement on its next pass and have it RUNNING
+    before the victim's deadline (provisioning time << deadline here, as
+    on a spot market with capacity)."""
+    from ray_tpu.autoscaler.instance_manager import (FakeCloudProvider,
+                                                     InstanceManager,
+                                                     JOINED, RUNNING)
 
-    def test_fast_bench_end_to_end(self, tmp_path):
-        """`bench.py --spec spotfleet --fast` wired into tier-1 as a
-        smoke: the full three-scenario run (churn graceful-vs-naive,
-        pre-buy timing, 2-slice drain) in a SUBPROCESS with a hard wall
-        bound, so even a pathological stall cannot eat the tier-1
-        budget."""
-        import subprocess
-
-        out = str(tmp_path / "BENCH_spotfleet.json")
-        code = (
-            "import bench, json, sys\n"
-            f"doc = bench.bench_spotfleet(fast=True, out_path={out!r})\n"
-            "print('SLA_PASS', doc['sla']['pass'])\n")
-        env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="")
-        for attempt in (1, 2):
-            if os.path.exists(out):
-                os.remove(out)  # never judge a stale doc
-            proc = subprocess.run(
-                [sys.executable, "-u", "-c", code], cwd=REPO_ROOT,
-                env=env, capture_output=True, text=True, timeout=420)
-            # bench_spotfleet raises SystemExit(1) on an SLA fail but
-            # still writes the doc; anything else (crash, no doc) is a
-            # hard failure with no retry.
-            assert os.path.exists(out), \
-                f"stdout:\n{proc.stdout[-2000:]}\nstderr:\n" \
-                f"{proc.stderr[-4000:]}"
-            with open(out) as f:
-                doc = json.load(f)
-            sla = doc["sla"]
-            assert doc["churn"]["graceful"]["completed"]
-            assert doc["churn"]["naive"]["completed"]
-            assert sla["lost_under_budget"], sla
-            assert sla["prebuy_before_deadline"], sla
-            assert sla["multislice_survivor_committed"], sla
-            assert sla["multislice_zero_lost_steps"], sla
-            if sla["pass"]:
-                assert proc.returncode == 0, \
-                    f"stdout:\n{proc.stdout[-2000:]}\nstderr:\n" \
-                    f"{proc.stderr[-4000:]}"
-                break
-            failed = [k for k in self._LOAD_SENSITIVE if not sla[k]]
-            assert failed, f"SLA failed outside load-sensitive axes: {sla}"
-            assert attempt == 1, \
-                f"goodput SLA failed on both attempts: {sla}"
-        assert "SLA_PASS True" in proc.stdout
-        assert doc["sla"]["pass"] is True
+    provider = FakeCloudProvider(run_delay_s=0.4)
+    mgr = InstanceManager(provider, drain_hook=lambda *a: None,
+                          prebuy=True, max_pending_prebuys=2)
+    desired = {"tpu": 2}
+    deadline_s = 5.0
+    # Converge to steady state.
+    t_end = time.monotonic() + 10
+    while time.monotonic() < t_end:
+        mgr.reconcile(desired)
+        insts = [i for i in mgr.store.alive() if i.status == RUNNING]
+        if len(insts) == 2:
+            break
+        time.sleep(0.05)
+    victim = next(i for i in mgr.store.alive() if i.status == RUNNING)
+    n_before = len(provider.request_log)
+    t_notice = time.monotonic()
+    provider.preempt_notice(victim.cloud_id, deadline_s=deadline_s)
+    t_request = t_running = None
+    t_end = time.monotonic() + deadline_s + 5
+    while time.monotonic() < t_end:
+        mgr.reconcile(desired)
+        if t_request is None and len(provider.request_log) > n_before:
+            t_request = time.monotonic()
+        fresh = [i for i in mgr.store.alive()
+                 if i.status in (RUNNING, JOINED)
+                 and i.cloud_id != victim.cloud_id
+                 and i.instance_id != victim.instance_id
+                 and i.request_id != victim.request_id]
+        if t_request is not None and fresh:
+            t_running = time.monotonic()
+            break
+        time.sleep(0.05)
+    # The victim then actually dies; the fleet is already whole.
+    provider.lose_instance(victim.cloud_id)
+    mgr.reconcile(desired)
+    return {
+        "deadline_s": deadline_s,
+        "notice_to_request_s": round(t_request - t_notice, 3)
+        if t_request else None,
+        "notice_to_running_s": round(t_running - t_notice, 3)
+        if t_running else None,
+        "replacement_running_before_deadline":
+            t_running is not None
+            and (t_running - t_notice) < deadline_s,
+    }
 
 
 class TestSpotfleetSmokeQuick:
     def test_prebuy_timing_scenario(self):
-        """The deterministic slice of the bench (declarative
-        InstanceManager pre-buy) runs in tier-1 directly: replacement
+        """The declarative InstanceManager's pre-buy: replacement
         REQUESTED at notice time, RUNNING before the deadline."""
-        sys.path.insert(0, REPO_ROOT)
-        import bench
-        out = bench._spotfleet_prebuy_timing()
+        out = _spotfleet_prebuy_timing()
         assert out["replacement_running_before_deadline"]
         assert out["notice_to_request_s"] is not None
         assert out["notice_to_request_s"] < 1.0

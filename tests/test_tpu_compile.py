@@ -19,7 +19,7 @@ from functools import partial
 
 import pytest
 
-# chip_smoke.py's widths and serving shapes (bench.py's 1.36B model).
+# chip_smoke.py's widths and serving shapes (a 1.36B model).
 MODEL = dict(vocab_size=32000, hidden=2048, layers=24, heads=16, kv_heads=16,
              head_dim=128, mlp_dim=5632, max_seq_len=2048)
 SLOTS, NUM_PAGES, PAGE, MAX_SEQ = 64, 2200, 16, 640
