@@ -6,8 +6,10 @@ the full width of the 1.36B model (hidden 2048, 24 layers, 16 heads of
 128, MLP 5632, vocabulary 32,000, bf16; weights random, from ``--seed``):
 
   kernels  a task with a one-chip grant checks the flash forward and
-           backward against ``reference_attention`` and one decode step
-           of the ragged paged kernel against the dense-gather path;
+           backward against ``reference_attention`` (at the model's
+           shape, and for one key head's group of 8 query heads under a
+           window at 8,192 tokens) and one decode step of the ragged
+           paged kernel against the dense-gather path;
   train    ``JaxTrainer`` with one worker that owns one chip builds the
            step with ``train.get_mesh()`` + ``make_lm_train_step``;
   serve    ``build_llm_deployment(..., num_tpus=1)`` answers requests of
@@ -45,6 +47,7 @@ REAL = dict(
     train_attention="flash",
     # slots, pages, page size, kv heads, pages per sequence
     ragged_shape=[64, 2200, 16, 16, 40],
+    grouped=(8192, 2048),         # tokens, window of the grouped flash check
     engine_options=dict(max_slots=64, page_size=16, num_pages=2200,
                         max_seq_len=640, prefill_buckets=(64, 256)),
     # (prompt tokens, tokens asked for)
@@ -56,6 +59,7 @@ TOY = dict(
                head_dim=32, mlp_dim=256, max_seq_len=256),
     train_attention="flash_interpret",
     ragged_shape=None,            # the ragged kernel has no interpret mode
+    grouped=(256, 96),
     engine_options=dict(max_slots=4, page_size=8, num_pages=64,
                         max_seq_len=128, prefill_buckets=(16, 64)),
     warm_requests=[(5, 4), (40, 4)],
@@ -122,8 +126,9 @@ def _rel_err(got, want):
 
 
 def kernel_checks(spec):
-    """Flash fwd/bwd vs reference_attention and the ragged paged decode
-    kernel vs the dense-gather path, at the model's head shape."""
+    """Flash fwd/bwd vs reference_attention (the model's head shape; a
+    grouped, windowed one) and the ragged paged decode kernel vs the
+    dense-gather path."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -133,24 +138,35 @@ def kernel_checks(spec):
     out = {"device": _device_facts(spec), "tolerance": KERNEL_TOL}
     B, H, S, D = spec["flash_shape"]
     ks = jax.random.split(jax.random.key(spec["seed"]), 8)
-    q, k, v, do = (jax.random.normal(kk, (B, H, S, D), jnp.bfloat16)
-                   for kk in ks[:4])
+    errs, out["flash"] = {}, []
+    # The model's own shape, then one key head's group of 8 under a window
+    # (a grouped step, the band's edges), small enough that the
+    # reference's float32 scores fit.
+    seq, window = spec["grouped"]
+    for tag, (b, h, hkv, seq), window in (
+            ("flash", (B, H, H, S), None),
+            ("flash_grouped", (1, 8, 1, seq), window)):
+        q, do = (jax.random.normal(kk, (b, h, seq, D), jnp.bfloat16)
+                 for kk in ks[:2])
+        k, v = (jax.random.normal(kk, (b, hkv, seq, D), jnp.bfloat16)
+                for kk in ks[2:4])
 
-    def fwd_bwd(attn):
-        def run(q, k, v):
-            o, vjp = jax.vjp(attn, q, k, v)
-            return (o,) + vjp(do)
-        return jax.jit(run)
+        def fwd_bwd(attn):
+            def run(q, k, v):
+                o, vjp = jax.vjp(attn, q, k, v)
+                return (o,) + vjp(do)
+            return jax.jit(run)
 
-    t0 = time.perf_counter()
-    got = fwd_bwd(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, interpret=spec["rehearse"]))(q, k, v)
-    want = fwd_bwd(lambda q, k, v: reference_attention(
-        q, k, v, causal=True))(q, k, v)
-    errs = {f"flash_{name}": _rel_err(g, w) for name, g, w in
-            zip(("out", "dq", "dk", "dv"), got, want)}
-    out["flash"] = {"shape": [B, H, S, D],
-                    "seconds": round(time.perf_counter() - t0, 1)}
+        t0 = time.perf_counter()
+        got = fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=spec["rehearse"],
+            window=window))(q, k, v)
+        want = fwd_bwd(lambda q, k, v: reference_attention(
+            q, k, v, causal=True, window=window))(q, k, v)
+        errs.update({f"{tag}_{name}": _rel_err(g, w) for name, g, w in
+                     zip(("out", "dq", "dk", "dv"), got, want)})
+        out["flash"].append({"shape": [b, h, hkv, seq, D], "window": window,
+                             "seconds": round(time.perf_counter() - t0, 1)})
 
     if spec["ragged_shape"] is None:
         out["ragged"] = "skipped: the ragged kernel has no interpret mode"

@@ -1330,6 +1330,14 @@ class NodeManager:
                 h.proc.wait(timeout=2)
             except subprocess.TimeoutExpired:
                 h.proc.kill()
+                # Reaped means its files are closed: a worker that held
+                # chips has given them back before shutdown returns (a
+                # four-chip worker takes seconds to go; the next process
+                # found /dev/vfio/<n> busy: PERF.md, PR 33).
+                try:
+                    h.proc.wait(timeout=30)
+                except subprocess.TimeoutExpired:
+                    pass
         # Cleanup only after the workers are dead: rmdir on a cgroup with
         # live members fails EBUSY and strands the tree.
         self.cgroup.cleanup()

@@ -132,6 +132,32 @@ class TPUAcceleratorManager(AcceleratorManager):
         return [int(c) for c in v.split(",") if c.strip() != ""]
 
 
+def wait_for_chips(granted: Optional[List[int]],
+                   timeout_s: float = 60.0) -> float:
+    """Seconds waited until the host's chips can be opened.  A chip worker
+    that was killed keeps its device nodes for seconds after its parent has
+    returned (a four-chip worker longest), and a backend that starts
+    meanwhile dies of ``open(/dev/vfio/<n>): Device or resource busy``
+    (PERF.md, PR 33).  Only where this process's grant is every chip of the
+    host: a node another live worker holds is not ours to wait for."""
+    import errno
+    import time
+    nodes = glob.glob("/dev/vfio/[0-9]*")
+    if not granted or len(granted) != len(nodes):
+        return 0.0
+    t0 = time.monotonic()
+    for node in nodes:
+        while time.monotonic() - t0 < timeout_s:
+            try:
+                os.close(os.open(node, os.O_RDWR))
+                break
+            except OSError as e:
+                if e.errno != errno.EBUSY:
+                    break                # not ours to judge: jax will say
+                time.sleep(0.5)
+    return time.monotonic() - t0
+
+
 def init_backend() -> int:
     """The program's own first touch of the backend, under the span
     ``worker_backend_init``: without it the user's function (or its
@@ -145,13 +171,14 @@ def init_backend() -> int:
     from ..util import telemetry
     import time
     extra: Dict[str, float] = {}
+    granted = TPUAcceleratorManager.get_current_process_visible_chips()
     with telemetry.profile_span("worker_backend_init", "system", extra):
         t0 = time.monotonic()
         import jax
         extra["import_s"] = time.monotonic() - t0     # the rest: the backend
+        extra["chip_wait_s"] = wait_for_chips(granted)
         devices = jax.local_devices()
     recompile.ensure_listener()
-    granted = TPUAcceleratorManager.get_current_process_visible_chips()
     if granted and devices[0].platform == "tpu" \
             and len(devices) != len(granted):
         raise RuntimeError(

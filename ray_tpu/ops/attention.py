@@ -2,7 +2,7 @@
 
 FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
 
-- Every kernel walks a grid (B*H, steps) over the (q block, k block) pairs
+- Every kernel walks a grid (rows, steps) over the (q block, k block) pairs
   that hold a visible element, and no others: ``block_schedule`` lists them
   at trace time from the shapes, the blocks, ``q_offset`` and ``causal``, and
   the table reaches the kernels and their index maps by scalar prefetch.
@@ -13,25 +13,43 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   cross (there the mask adds 0.0): on the v5e the mask hides behind the MXU,
   and a second, unmasked step body measured no faster (PERF.md, PR 28).
   The table is one packed int32 a step, so SMEM holds it at any length the
-  kernels would be asked for (131 KB at 128k tokens).
-- The grid is ("parallel", "arbitrary"): only B*H splits across the cores of
-  a two-core chip (v4, v5p), so a call with one or two heads a device no
-  longer spreads its q blocks over both.  The v5e has one core.
+  kernels would be asked for (131 KB at 128k tokens and 512 x 512).
+- **The geometry of a step follows the call's shapes** (``_tiles``; PERF.md,
+  PR 33 has the chip's table behind each choice).  A step is bound by how
+  it feeds the MXU and by what a grid step costs beside its products, and
+  both are paid once a step, so a step should hold as many rows behind each
+  128 x 128 tile of the other operand as the shapes offer.  Under
+  grouped-query attention a grid row is a *key* head and a step holds the
+  ``block_q`` rows of all its query heads (up to 8), stacked: K / V blocks
+  are fetched once a group, the group shares one mask, and dk / dv add up
+  inside the kernel and leave per key head in the inputs' dtype.  With no
+  group to stack (and no window) the blocks are 1,024 x 1,024.  Anything
+  else keeps 512 x 512 and a head a row.  Each traced kernel counts its
+  geometry in ``ray_tpu_flash_step_geometry_total``.
+- The grid is ("parallel", "arbitrary"): only the grid rows split across
+  the cores of a two-core chip (v4, v5p): B * H of them without a group,
+  B * Hkv with one, so a call with one or two key heads a device no longer
+  spreads over both.  The v5e has one core.  A step of 4,096 stacked rows
+  asks for up to 96 MiB of scoped VMEM (the v5e has 128).
 - Forward blocks over BOTH sequence axes, the steps of one Q block in a row
   with K ascending ("arbitrary" semantics) so pallas double-buffers K/V
   block DMAs while the MXU works.  Online softmax state (running max m,
   denominator l, unnormalized accumulator) lives in VMEM scratch carried
   across K blocks; the [Sq, Sk] score matrix never exists in HBM.  The
-  log-sum-exp is written out as a residual (broadcast over the 128-lane
-  minor dim, the TPU-friendly layout the jax flash kernel also uses).
+  state is kept broadcast over the 128-lane minor dim (the TPU-friendly
+  layout the jax flash kernel also uses); the log-sum-exp leaves the kernel
+  as a residual in rows along the lanes, [.., 1, Sq]: a q block's last step
+  transposes its column once, and nothing 128 times its size reaches HBM.
 - Backward is two kernels: dq (the same walk as the forward, accumulating
   dq for a resident Q block) and dk/dv (K-major: the steps of one K/V block
   in a row with Q ascending, accumulating dk/dv for the resident block).
   Both recompute probabilities from the saved LSE — one exp, no second
-  softmax pass — with fp32 accumulation and bf16 MXU inputs.
-- GQA is native: the K/V index maps collapse query heads onto their shared
-  KV head; dk/dv are emitted per query head and group-summed outside only
-  when kv_heads < heads.
+  softmax pass — with fp32 accumulation and bf16 MXU inputs.  dk/dv forms
+  its scores transposed (``k q^T``, as upstream's splash kernel does), so
+  dv = p^T do and dk = ds^T q are plain products with no transpose in the
+  kernel, and reads ``lse`` / ``di`` as rows along the lanes.  dq needs
+  them as columns and makes those in VMEM, once a resident q block, from
+  the same rows: no lane-broadcast copy of either is written outside.
 
 ``q_offset`` shifts query positions for causal masking so sequence-sharded
 callers (ring attention) can flash-attend a mid-sequence Q shard.
@@ -43,19 +61,22 @@ their names (``flash_fwd_w2048``).  With ``window=None`` nothing changes.
 Design provenance (patterns, not code): the reference delegates attention to
 engines (SURVEY §2.4 SP/CP row — no in-repo kernel); the block/layout recipe
 follows jax.experimental.pallas.ops.tpu.flash_attention (LSE lane broadcast,
-dual-axis blocking); the prefetched table of visible blocks is how upstream's
-splash attention walks a sparse mask.
+dual-axis blocking); the prefetched table of visible blocks and the
+transposed scores of dk/dv are how upstream's splash attention walks a
+sparse mask.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..util import telemetry
 
 NEG_INF = -1e30
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -175,9 +196,20 @@ def _step_ki(step):
     return (step >> _KI_SHIFT) & _BLOCK_MASK
 
 
-def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset, window=None):
-    row = jax.lax.broadcasted_iota(jnp.int32, s_shape, 0) + qi * bq + q_offset
-    col = jax.lax.broadcasted_iota(jnp.int32, s_shape, 1) + ki * bk
+def _causal_mask_bias(q_rows, k_rows, qi, ki, block_q, block_k, q_offset,
+                      window=None, transposed=False):
+    """0.0 where a key is visible and MASK_VALUE elsewhere, for one pair of
+    blocks: [q_rows, k_rows], or [k_rows, q_rows] when ``transposed``.
+    ``q_rows`` may hold several heads' ``block_q`` rows one after another:
+    they share the positions."""
+    shape = (k_rows, q_rows) if transposed else (q_rows, k_rows)
+    q_in = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
+    if q_rows != block_q:
+        q_in = (q_in & (block_q - 1) if block_q & (block_q - 1) == 0
+                else jax.lax.rem(q_in, block_q))
+    row = q_in + qi * block_q + q_offset
+    col = jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0 if transposed else 1) + ki * block_k
     visible = col <= row
     if window is not None:
         # A row that sees nothing of its first tile takes MASK_VALUE as its
@@ -185,6 +217,85 @@ def _causal_mask_bias(s_shape, qi, bq, ki, bk, q_offset, window=None):
         # tile's sums by exp(MASK_VALUE - max) = 0.
         visible &= row - col < window
     return jnp.where(visible, 0.0, MASK_VALUE)
+
+
+# --------------------------------------------------------------- geometry
+
+class Tiles(NamedTuple):
+    """What one grid step of a kernel holds: ``heads`` query heads of one
+    key head, ``block_q`` rows of each, against ``block_k`` keys; and which
+    way round the scores are formed (``qk``: [q rows, keys]; ``kq``: [keys,
+    q rows])."""
+    block_q: int
+    block_k: int
+    heads: int
+    scores: str
+
+
+# The fallback, and what the chip's table (PERF.md, PR 33, step 0) says pays
+# beside it on a v5e at head dim 128.
+_BLOCK = 512            # 512 x 512 pairs, a head a grid row
+_BIG_BLOCK = 1024       # no group to stack and no window: 1,024 x 1,024
+_MAX_HEADS = 8          # query heads a step stacks: 4,096 rows at 512 each
+_FWD_SCORES = 2 ** 20   # the forward's score tile, elements: halves block_k
+
+
+def _tiles(kind, Sq, Sk, D, group, window=None):
+    """The geometry of a grid step of kernel ``kind`` (``fwd``, ``dq``,
+    ``dkv``), from the shapes of the call alone.
+
+    - A key head's query heads share one step (the most that divide the
+      group, up to 8): forward and dq stack their rows behind each tile of
+      K / V, dk/dv adds them into the one resident block.  With 4,096 rows
+      stacked the forward takes its keys 256 at a time.
+    - With no group to stack, no window and at least four of them a side,
+      blocks of 1,024 x 1,024: fewer, larger steps win over the extra work
+      on the diagonal (40 units of 512 x 512 for 36 at 4,096 tokens); under
+      a window they waste at both edges of the band and lose.
+    - dk/dv always forms its scores transposed (``kq``).
+    - Anything else (a head dim over 128, a length the larger blocks do
+      not divide) keeps 512 x 512 and a head a step."""
+    scores = "kq" if kind == "dkv" else "qk"
+    block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
+    if D > LANES:
+        return Tiles(block_q, block_k, heads, scores)
+    heads = max(h for h in range(1, min(group, _MAX_HEADS) + 1)
+                if group % h == 0)
+    if heads > 1:
+        if (kind == "fwd" and heads * block_q * block_k > _FWD_SCORES
+                and block_k % (2 * LANES) == 0):
+            block_k //= 2
+    elif (window is None and min(Sq, Sk) >= 4 * _BIG_BLOCK
+          and Sq % _BIG_BLOCK == 0 and Sk % _BIG_BLOCK == 0):
+        block_q = block_k = _BIG_BLOCK
+    return Tiles(block_q, block_k, heads, scores)
+
+
+def _geometry(kind, q, k, block_q, block_k, window):
+    """``_tiles``' answer for this call, an explicit block size winning,
+    checked against the lengths and counted."""
+    _, H, Sq, D = q.shape
+    _, Hkv, Sk, _ = k.shape
+    if H % Hkv:
+        raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
+    t = _tiles(kind, Sq, Sk, D, H // Hkv, window)
+    t = t._replace(block_q=min(block_q or t.block_q, Sq),
+                   block_k=min(block_k or t.block_k, Sk))
+    if Sq % t.block_q or Sk % t.block_k:
+        raise ValueError(f"seq ({Sq},{Sk}) not divisible by blocks "
+                         f"({t.block_q},{t.block_k})")
+    telemetry.inc("ray_tpu_flash_step_geometry_total", tags={
+        "kernel": _kernel_name(f"flash_{kind}", window),
+        "block_q": str(t.block_q), "block_k": str(t.block_k),
+        "heads_a_step": str(t.heads), "scores": t.scores})
+    return t
+
+
+def _rows(ref):
+    """A block [1, heads, rows, n] as [heads * rows, n]: the heads of a
+    step one after another."""
+    x = ref[0]
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
 
 
 # ---------------------------------------------------------------- forward
@@ -206,19 +317,19 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
-        q = q_ref[0]                                   # [bq, D]
+        q = _rows(q_ref)                               # [heads * bq, D]
         k = k_ref[0]                                   # [bk, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
+            preferred_element_type=jnp.float32) * scale  # [heads * bq, bk]
         if causal:
-            s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset, window)
-        m_prev = m_scr[...]                            # [bq, 128]
+            s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
+                                      block_k, q_offset, window)
+        m_prev = m_scr[...]                            # [heads * bq, 128]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         p = jnp.exp(s - _bcast_lanes(m_next, s.shape[1]))
-        alpha = jnp.exp(m_prev - m_next)               # [bq, 128]
+        alpha = jnp.exp(m_prev - m_next)
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
         m_scr[...] = m_next
         v = v_ref[0]
@@ -231,11 +342,14 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _finish():
         l = l_scr[...]
         l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
-        o_ref[0] = (acc_scr[...]
-                    * _bcast_lanes(l_inv, acc_scr.shape[1])
-                    ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] * _bcast_lanes(l_inv, acc_scr.shape[1])
+                    ).astype(o_ref.dtype).reshape(o_ref.shape[1:])
         if lse_ref is not None:
-            lse_ref[0] = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
+            # Out as rows along the lanes, [heads, 1, bq]: what the backward
+            # kernels read, and 1/128 of the lane-broadcast columns.
+            lse = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
+            for h in range(lse_ref.shape[1]):
+                lse_ref[0, h] = lse[h * block_q:(h + 1) * block_q].T[:1]
 
 
 def _kernel_name(base, window):
@@ -251,36 +365,27 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
 
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
-    if H % Hkv:
-        raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    if Sq % block_q or Sk % block_k:
-        raise ValueError(
-            f"seq ({Sq},{Sk}) not divisible by blocks ({block_q},{block_k})")
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q",
-                             window)
+    t = _geometry("fwd", q, k, block_q, block_k, window)
+    sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset, causal,
+                             "q", window)
+    n, rows = B * H // t.heads, t.heads * t.block_q
 
-    qr = q.reshape(B * H, Sq, D)
-    kr = k.reshape(B * Hkv, Sk, D)
-    vr = v.reshape(B * Hkv, Sk, D)
-
-    q_index, kv_index, _ = _index_maps(H, Hkv)
+    q_spec, kv_spec, row_spec, _ = _specs(t, H // Hkv, D)
 
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, window=window)
+        _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
+        block_k=t.block_k, q_offset=q_offset, window=window)
 
-    out_specs = [pl.BlockSpec((1, block_q, D), q_index)]
-    out_shape = [jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype)]
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((n, t.heads, Sq, D), q.dtype)]
     if need_lse:
-        out_specs.append(pl.BlockSpec((1, block_q, LANES), q_index))
+        out_specs.append(row_spec)
         out_shape.append(
-            jax.ShapeDtypeStruct((B * H, Sq, LANES), jnp.float32))
+            jax.ShapeDtypeStruct((n, t.heads, 1, Sq), jnp.float32))
     else:
-        # No LSE output at all: skip ~B*H*Sq*128 fp32 of dead HBM writes.
+        # No LSE output at all: nothing of it is computed or written.
         with_lse = kernel
 
         def kernel(sched, q, k, v, o, *scratch):
@@ -290,27 +395,24 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B * H, sched.size),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), q_index),
-                pl.BlockSpec((1, block_k, D), kv_index),
-                pl.BlockSpec((1, block_k, D), kv_index),
-            ],
+            grid=(n, sched.size),
+            in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=out_specs,
             scratch_shapes=[
-                _vmem((block_q, LANES), jnp.float32),
-                _vmem((block_q, LANES), jnp.float32),
-                _vmem((block_q, D), jnp.float32),
+                _vmem((rows, LANES), jnp.float32),
+                _vmem((rows, LANES), jnp.float32),
+                _vmem((rows, D), jnp.float32),
             ]),
         out_shape=out_shape,
         interpret=interpret,
         name=_kernel_name("flash_fwd", window),
-        **_compiler_params(interpret),
-    )(sched, qr, kr, vr)
+        **_compiler_params(interpret, rows, t.block_k),
+    )(sched, q.reshape(n, t.heads, Sq, D), k.reshape(B * Hkv, Sk, D),
+      v.reshape(B * Hkv, Sk, D))
     out = res[0].reshape(B, H, Sq, D)
     if not need_lse:
         return out, None
-    return out, res[1][..., 0].reshape(B, H, Sq)
+    return out, res[1].reshape(B, H, Sq)
 
 
 def _vmem(shape, dtype):
@@ -318,37 +420,57 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _index_maps(H, Hkv):
-    """Index maps (q-shaped, k/v, dk/dv) of a grid (B*H, steps) whose blocks
-    come from the prefetched schedule.  Query heads fold onto their shared
-    key head; dk/dv are per query head."""
-    group = H // Hkv
+def _specs(t, group, D):
+    """Block specs of a grid (n, steps) at geometry ``t``, the blocks coming
+    from the prefetched schedule; a grid row is ``t.heads`` query heads of
+    one key head.  q-shaped blocks of [n, heads, S, D]; k/v blocks of
+    [B*Hkv, S, D]; rows along the lanes of [n, heads, 1, S] (LSE, delta);
+    dk/dv blocks of [n, S, D]."""
+    from jax.experimental import pallas as pl
 
-    def q_index(bh, t, sched):
-        return (bh, _step_qi(sched[t]), 0)
+    def q_index(r, s, sched):
+        return (r, 0, _step_qi(sched[s]), 0)
 
-    def kv_index(bh, t, sched):
-        return ((bh // H) * Hkv + (bh % H) // group, _step_ki(sched[t]), 0)
+    def kv_index(r, s, sched):
+        return (r * t.heads // group, _step_ki(sched[s]), 0)
 
-    def dkv_index(bh, t, sched):
-        return (bh, _step_ki(sched[t]), 0)
+    def row_index(r, s, sched):
+        return (r, 0, 0, _step_qi(sched[s]))
 
-    return q_index, kv_index, dkv_index
+    def dkv_index(r, s, sched):
+        return (r, _step_ki(sched[s]), 0)
+
+    return (pl.BlockSpec((1, t.heads, t.block_q, D), q_index),
+            pl.BlockSpec((1, t.block_k, D), kv_index),
+            pl.BlockSpec((1, t.heads, 1, t.block_q), row_index),
+            pl.BlockSpec((1, t.block_k, D), dkv_index))
 
 
-def _compiler_params(interpret):
+# A step's float32 tiles (scores, probabilities and their like) above which
+# the kernel asks for more than Mosaic's default 16 MiB of scoped VMEM.
+_VMEM_DEFAULT_TILE = 512 * 512
+
+
+def _compiler_params(interpret, rows, cols):
     from jax.experimental.pallas import tpu as pltpu
     if interpret:
         return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))}
+    params = dict(dimension_semantics=("parallel", "arbitrary"))
+    if rows * cols > _VMEM_DEFAULT_TILE:
+        # About ten live [rows, cols] float32 tiles at the worst.
+        params["vmem_limit_bytes"] = min(
+            100 * 2 ** 20, 16 * 2 ** 20 + 40 * rows * cols)
+    return {"compiler_params": pltpu.CompilerParams(**params)}
 
 
 # ---------------------------------------------------------------- backward
 
 def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-               dq_ref, dq_scr, *, causal, scale, block_q, block_k, q_offset,
-               window=None):
+               dq_ref, dq_scr, lse_scr, di_scr, *, causal, scale, block_q,
+               block_k, q_offset, window=None):
+    """``lse`` and ``di`` arrive as rows along the lanes (as dk/dv reads
+    them); the resident q block's first step turns them into the
+    lane-broadcast columns [heads * bq, 128] the steps subtract."""
     from jax.experimental import pallas as pl
 
     step = sched_ref[pl.program_id(1)]
@@ -357,38 +479,45 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     @pl.when(step & _FIRST_BIT != 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        for h in range(q_ref.shape[1]):
+            rows = slice(h * block_q, (h + 1) * block_q)
+            for row_ref, col_scr in ((lse_ref, lse_scr), (di_ref, di_scr)):
+                col_scr[rows, :] = jnp.broadcast_to(
+                    row_ref[0, h], (LANES, block_q)).T
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
-        q = q_ref[0]
+        q = _rows(q_ref)                               # [heads * bq, D]
+        do = _rows(do_ref)
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                               # [bq, 128]
-        di = di_ref[0]                                 # [bq, 128]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset, window)
-        p = jnp.exp(s - _bcast_lanes(lse, s.shape[1]))
+            s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
+                                      block_k, q_offset, window)
+        p = jnp.exp(s - _bcast_lanes(lse_scr[...], s.shape[1]))
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - _bcast_lanes(di, s.shape[1])) * scale
+        ds = p * (dp - _bcast_lanes(di_scr[...], s.shape[1])) * scale
         dq_scr[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32)
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(
+            dq_ref.shape[1:])
 
 
 def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, causal, scale, block_q, block_k, q_offset,
                 window=None):
+    """The scores are formed transposed, ``k q^T`` [bk, bq], so that dv =
+    p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
+    along the lanes.  The step's heads add into the one resident dk / dv."""
     from jax.experimental import pallas as pl
 
     step = sched_ref[pl.program_id(1)]
@@ -401,27 +530,33 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
-        q = q_ref[0]
-        k = k_ref[0]
+        k = k_ref[0]                                   # [bk, D]
         v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]
-        di = di_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
         if causal:
-            s = s + _causal_mask_bias(s.shape, qi, block_q, ki, block_k,
-                                      q_offset, window)
-        p = jnp.exp(s - _bcast_lanes(lse, s.shape[1]))
-        dv_scr[...] += jax.lax.dot(
-            p.T.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _bcast_lanes(di, s.shape[1])) * scale
-        dk_scr[...] += jax.lax.dot(
-            ds.T.astype(q.dtype), q, preferred_element_type=jnp.float32)
+            bias = _causal_mask_bias(block_q, block_k, qi, ki, block_q,
+                                     block_k, q_offset, window,
+                                     transposed=True)  # [bk, bq]
+        dk = dk_scr[...]
+        dv = dv_scr[...]
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]                            # [bq, D]
+            do = do_ref[0, h]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [bk, bq]
+            if causal:
+                st = st + bias
+            pt = jnp.exp(st - lse_ref[0, h])           # lse: [1, bq]
+            dv += jax.lax.dot(pt.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dst = pt * (dpt - di_ref[0, h]) * scale
+            dk += jax.lax.dot(dst.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)
+        dk_scr[...] = dk
+        dv_scr[...] = dv
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
@@ -437,85 +572,63 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     group = H // Hkv
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
+    kr = k.reshape(B * Hkv, Sk, D)
+    vr = v.reshape(B * Hkv, Sk, D)
 
     # delta_i = rowsum(dO * O): one fused elementwise+reduce pass in XLA.
     di = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
-    qr = q.reshape(B * H, Sq, D)
-    kr = k.reshape(B * Hkv, Sk, D)
-    vr = v.reshape(B * Hkv, Sk, D)
-    dor = dout.reshape(B * H, Sq, D)
-    # LSE/delta residuals broadcast over the lane dim (layout-friendly).
-    lser = jnp.broadcast_to(lse.reshape(B * H, Sq)[..., None],
-                            (B * H, Sq, LANES))
-    dir_ = jnp.broadcast_to(di.reshape(B * H, Sq)[..., None],
-                            (B * H, Sq, LANES))
-
-    q_index, kv_index, dkv_index = _index_maps(H, Hkv)
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), q_index),
-        pl.BlockSpec((1, block_k, D), kv_index),
-        pl.BlockSpec((1, block_k, D), kv_index),
-        pl.BlockSpec((1, block_q, D), q_index),
-        pl.BlockSpec((1, block_q, LANES), q_index),
-        pl.BlockSpec((1, block_q, LANES), q_index),
-    ]
-    static = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k, q_offset=q_offset, window=window)
+    def call(kind, kernel, t, major, out_specs, out_shape, scratch, tile):
+        """One backward kernel at its geometry ``t``; LSE / delta enter as
+        rows along the lanes, with no broadcast outside."""
+        n = B * H // t.heads
+        q_spec, kv_spec, row_spec, _ = _specs(t, group, D)
+        sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset,
+                                 causal, major, window)
+        return pl.pallas_call(
+            functools.partial(kernel, causal=causal, scale=scale,
+                              block_q=t.block_q, block_k=t.block_k,
+                              q_offset=q_offset, window=window),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n, sched.size),
+                in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                          row_spec],
+                out_specs=out_specs,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            interpret=interpret,
+            name=_kernel_name(f"flash_{kind}", window),
+            **_compiler_params(interpret, *tile),
+        )(sched, q.reshape(n, t.heads, Sq, D), kr, vr,
+          dout.reshape(n, t.heads, Sq, D),
+          lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
 
     # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "q",
-                             window)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **static),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B * H, sched.size),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, D), q_index),
-            scratch_shapes=[_vmem((block_q, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        interpret=interpret,
-        name=_kernel_name("flash_dq", window),
-        **_compiler_params(interpret),
-    )(sched, qr, kr, vr, dor, lser, dir_).reshape(B, H, Sq, D)
+    t = _geometry("dq", q, k, block_q, block_k, window)
+    rows = t.heads * t.block_q
+    dq = call(
+        "dq", _dq_kernel, t, "q", _specs(t, group, D)[0],
+        jax.ShapeDtypeStruct((B * H // t.heads, t.heads, Sq, D), q.dtype),
+        [_vmem((rows, D), jnp.float32), _vmem((rows, LANES), jnp.float32),
+         _vmem((rows, LANES), jnp.float32)],
+        (rows, t.block_k)).reshape(q.shape)
 
-    # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk).
-    # Emitted per *query* head; group-summed below when GQA.
-    sched = _packed_schedule(Sq, Sk, block_q, block_k, q_offset, causal, "k",
-                             window)
-    dkv_dtype = jnp.float32 if group > 1 else q.dtype
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **static),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B * H, sched.size),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), dkv_index),
-                pl.BlockSpec((1, block_k, D), dkv_index),
-            ],
-            scratch_shapes=[
-                _vmem((block_k, D), jnp.float32),
-                _vmem((block_k, D), jnp.float32),
-            ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sk, D), dkv_dtype),
-            jax.ShapeDtypeStruct((B * H, Sk, D), dkv_dtype),
-        ],
-        interpret=interpret,
-        name=_kernel_name("flash_dkv", window),
-        **_compiler_params(interpret),
-    )(sched, qr, kr, vr, dor, lser, dir_)
-
-    dk = dk.reshape(B, H, Sk, D)
-    dv = dv.reshape(B, H, Sk, D)
-    if group > 1:
-        dk = dk.reshape(B, Hkv, group, Sk, D).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(B, Hkv, group, Sk, D).sum(axis=2).astype(v.dtype)
-    return dq, dk, dv
+    # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk), the
+    # step's query heads adding into it.  Where a step holds the whole
+    # group the results leave per key head in the inputs' dtype; else per
+    # step's heads in float32, summed over the group below.
+    t = _geometry("dkv", q, k, block_q, block_k, window)
+    parts = group // t.heads
+    dk, dv = call(
+        "dkv", _dkv_kernel, t, "k", [_specs(t, group, D)[3]] * 2,
+        [jax.ShapeDtypeStruct((B * H // t.heads, Sk, D),
+                              jnp.float32 if parts > 1 else k.dtype)] * 2,
+        [_vmem((t.block_k, D), jnp.float32)] * 2, (t.block_q, t.block_k))
+    if parts > 1:
+        dk = dk.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(k.dtype)
+        dv = dv.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(v.dtype)
+    return dq, dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------- wrapper
@@ -548,12 +661,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None, block_q: int = 512,
-                    block_k: int = 512, q_offset: int = 0,
+                    scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None, q_offset: int = 0,
                     interpret: bool = False, window: Optional[int] = None):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
     q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D].  ``window``: with ``causal``,
-    a key is visible iff ``0 <= t - s < window``."""
+    a key is visible iff ``0 <= t - s < window``.  ``block_q`` /
+    ``block_k`` default to what ``_tiles`` picks for each kernel from the
+    shapes."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
                   interpret, window)

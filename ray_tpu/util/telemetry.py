@@ -381,6 +381,13 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "XLA backend compiles (persistent-cache fetches "
                        "included) by program name; each is also an "
                        "xla_compile span."},
+    "ray_tpu_flash_step_geometry_total": {
+        "type": "counter",
+        "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
+                     "scores"),
+        "description": "Flash-attention kernels traced, by the geometry "
+                       "of a grid step that ops/attention._tiles chose "
+                       "from the call's shapes (scores: qk or kq)."},
     "ray_tpu_compile_cache_hits_total": {
         "type": "counter", "tag_keys": (),
         "description": "Programs fetched from the persistent compile "

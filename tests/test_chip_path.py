@@ -83,6 +83,54 @@ class TestChipsOnlyByGrant:
             serving._require_chip_grant()
 
 
+class TestWaitForChips:
+    """A killed chip worker keeps its device nodes for seconds; the next
+    process's backend waits for them, bounded, and only where its grant
+    is the whole host."""
+
+    @pytest.fixture
+    def nodes(self, monkeypatch):
+        """Two chip nodes; opening ``busy`` ones says EBUSY ``n`` times."""
+        import errno
+        from ray_tpu.accelerators import tpu
+        state = {"busy": {}, "opened": []}
+
+        def fake_open(path, flags):
+            if state["busy"].get(path, 0) > 0:
+                state["busy"][path] -= 1
+                raise OSError(errno.EBUSY, "Device or resource busy")
+            state["opened"].append(path)
+            return 99
+
+        monkeypatch.setattr(tpu.glob, "glob",
+                            lambda pat: ["/dev/vfio/0", "/dev/vfio/1"])
+        monkeypatch.setattr(tpu.os, "open", fake_open)
+        monkeypatch.setattr(tpu.os, "close", lambda fd: None)
+        monkeypatch.setattr("time.sleep", lambda s: None)
+        return state
+
+    def test_waits_until_a_busy_node_opens(self, nodes):
+        from ray_tpu.accelerators.tpu import wait_for_chips
+        nodes["busy"]["/dev/vfio/1"] = 3
+        assert wait_for_chips([0, 1]) >= 0.0
+        assert nodes["opened"] == ["/dev/vfio/0", "/dev/vfio/1"]
+        assert nodes["busy"]["/dev/vfio/1"] == 0
+
+    @pytest.mark.parametrize("granted", [None, [], [0]])
+    def test_a_grant_of_part_of_the_host_waits_for_nothing(self, nodes,
+                                                           granted):
+        from ray_tpu.accelerators.tpu import wait_for_chips
+        nodes["busy"]["/dev/vfio/1"] = 10 ** 9   # a sibling worker's chip
+        assert wait_for_chips(granted) == 0.0
+        assert nodes["opened"] == []
+
+    def test_gives_up_at_its_limit(self, nodes):
+        from ray_tpu.accelerators.tpu import wait_for_chips
+        nodes["busy"]["/dev/vfio/0"] = 10 ** 9
+        assert wait_for_chips([0, 1], timeout_s=0.05) >= 0.05
+        assert nodes["opened"] == []             # the limit covers all
+
+
 class TestNoFallback:
     def test_on_tpu_reads_the_platform_and_swallows_nothing(self,
                                                             monkeypatch):

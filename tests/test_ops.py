@@ -182,6 +182,18 @@ SCHEDULES = {
                        {INTERIOR: 6, DIAGONAL: 4, EMPTY: 0}),
     "one_block": ((128, 128, 128, 128, 0, True),
                   {INTERIOR: 0, DIAGONAL: 1, EMPTY: 0}),
+    # Non-square blocks, the step counts written out: a q block of 1,024
+    # sees 2, 4, 6, 8 k blocks of 512 (20 pairs = 40 units of 512 x 512
+    # where 512s do 36); 256-row q blocks under 512-key blocks cover the
+    # same 36 units in 72 half-unit steps; the k-major mirror image.
+    "bq1024_bk512": ((4096, 4096, 1024, 512, 0, True),
+                     {INTERIOR: 12, DIAGONAL: 8, EMPTY: 0}),
+    "bq256_bk512": ((4096, 4096, 256, 512, 0, True),
+                    {INTERIOR: 56, DIAGONAL: 16, EMPTY: 0}),
+    "bq512_bk1024": ((4096, 4096, 512, 1024, 0, True),
+                     {INTERIOR: 12, DIAGONAL: 8, EMPTY: 0}),
+    "bq256_bk1024_8k": ((8192, 8192, 256, 1024, 0, True),
+                        {INTERIOR: 112, DIAGONAL: 32, EMPTY: 0}),
     "bq64_bk128": ((128, 128, 64, 128, 0, True), None),
     "bq32_bk64": ((128, 128, 32, 64, 0, True), None),
     "bq64_bk32": ((192, 192, 64, 32, 0, True), None),
@@ -290,6 +302,141 @@ def test_k_block_no_q_sees_gets_zero_gradient():
         np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
     assert not np.asarray(got[1][:, :, 64:]).any()
     assert not np.asarray(got[2][:, :, 64:]).any()
+
+
+# (Sq, Sk, D, group, window) -> (block_q, block_k, heads a step) of forward,
+# dq and dk/dv.  From the chip's table of step 0 (PERF.md, PR 33).
+TILES = {
+    # yi-coder-1.5b.train-sft4k: no group to stack, so larger pairs.
+    "yi_4096": ((4096, 4096, 128, 1, None),
+                [(1024, 1024, 1)] * 3),
+    # mistral-7b-v0.3.train-fsdp4: 4 query heads a key head, 2,048 rows.
+    "mistral_4096_group4": ((4096, 4096, 128, 4, None),
+                            [(512, 512, 4)] * 3),
+    # trinity-mini.train-moe8k, full and window layers: 8 heads, 4,096 rows
+    # a step; the forward takes its keys 256 at a time.
+    "trinity_8192_group8": ((8192, 8192, 128, 8, None),
+                            [(512, 256, 8), (512, 512, 8), (512, 512, 8)]),
+    "trinity_8192_group8_window": ((8192, 8192, 128, 8, 2048),
+                                   [(512, 256, 8), (512, 512, 8),
+                                    (512, 512, 8)]),
+    "tokens_128k": ((131072, 131072, 128, 1, None), [(1024, 1024, 1)] * 3),
+    # A ring shard (q_offset != 0 in the call): shapes alone decide.
+    "ring_shard": ((4096, 8192, 128, 1, None), [(1024, 1024, 1)] * 3),
+    # Lengths the larger blocks do not divide fall back, and do not raise.
+    "not_divided_4608": ((4608, 4608, 128, 1, None), [(512, 512, 1)] * 3),
+    "not_divided_1536": ((1536, 1536, 128, 1, None), [(512, 512, 1)] * 3),
+    # Too short for the larger pairs to pay (chip_smoke's 2,048).
+    "smoke_2048": ((2048, 2048, 128, 1, None), [(512, 512, 1)] * 3),
+    # A window and no group: large pairs waste at both edges of the band.
+    "window_no_group": ((8192, 8192, 128, 1, 2048), [(512, 512, 1)] * 3),
+    "head_dim_256": ((4096, 4096, 256, 4, None), [(512, 512, 1)] * 3),
+    # A group wider than a step: the most heads that divide it, up to 8.
+    "group16": ((4096, 4096, 128, 16, None),
+                [(512, 256, 8), (512, 512, 8), (512, 512, 8)]),
+    "group3_short": ((192, 192, 32, 3, None), [(192, 192, 3)] * 3),
+}
+
+
+@pytest.mark.parametrize("case", TILES)
+def test_tiles(case):
+    args, want = TILES[case]
+    for kind, (block_q, block_k, heads) in zip(("fwd", "dq", "dkv"), want):
+        assert attention_ops._tiles(kind, *args) == (
+            block_q, block_k, heads, "kq" if kind == "dkv" else "qk"), kind
+
+
+@pytest.mark.parametrize("causal,q_offset", [(False, 0), (True, 64)])
+def test_default_geometry_off_the_causal_square(causal, q_offset):
+    """``causal=False`` and a ring shard's ``q_offset`` with the blocks
+    ``_tiles`` picks, forward and gradients."""
+    ks = jax.random.split(jax.random.key(15), 4)
+    q = jax.random.normal(ks[0], (1, 4, 64, 32))
+    k = jax.random.normal(ks[1], (1, 2, 128, 32))
+    v = jax.random.normal(ks[2], (1, 2, 128, 32))
+    do = jax.random.normal(ks[3], q.shape)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * do),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, q_offset=q_offset, interpret=True))
+    want = grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal, q_offset=q_offset))
+    for a, b, name in zip(want, got, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
+
+
+def _geometry_counts():
+    """ray_tpu_flash_step_geometry_total as {kernel: {tags: count}}."""
+    from ray_tpu.util import metrics
+    _by_name, acc = metrics._aggregate_snapshots()
+    out = {}
+    for tags, value in acc.get("ray_tpu_flash_step_geometry_total",
+                               {}).values():
+        tags = dict(tags)
+        out.setdefault(tags.pop("kernel"), {})[
+            tuple(sorted(tags.items()))] = value
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("group", [4, 8])
+def test_a_key_heads_query_heads_share_a_step(group, window):
+    """Grouped-query attention: the group's heads are one grid step, its
+    rows stacked in forward and dq, its dk / dv added up inside the kernel
+    and handed out per key head in the inputs' dtype; against the
+    reference in float32 on the very inputs the kernels saw."""
+    dtype = jnp.bfloat16
+    q, k, v = _qkv(jax.random.key(11), B=2, H=2 * group, Hkv=2, S=192,
+                   dtype=dtype)
+    do = jax.random.normal(jax.random.key(12), q.shape, dtype)
+    before = _geometry_counts()
+
+    def fwd_bwd(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(do.astype(out.dtype))
+
+    got = fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, block_q=64, block_k=64, interpret=True, window=window),
+        q, k, v)
+    want = fwd_bwd(lambda q, k, v: reference_attention(
+        q, k, v, window=window), *(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, b, x, name in zip(want, got, (q, q, k, v),
+                             ("out", "dq", "dk", "dv")):
+        assert b.dtype == dtype and b.shape == x.shape, name
+        a = np.asarray(a)
+        np.testing.assert_allclose(
+            np.asarray(b, np.float32), a, atol=3e-2 * np.abs(a).max(),
+            rtol=3e-2, err_msg=name)
+
+    # Which geometry each kernel took is counted where it is chosen.
+    after = _geometry_counts()
+    w = "" if window is None else f"_w{window}"
+    for kernel, scores in (("fwd", "qk"), ("dq", "qk"), ("dkv", "kq")):
+        tags = (("block_k", "64"), ("block_q", "64"),
+                ("heads_a_step", str(group)), ("scores", scores))
+        name = f"flash_{kernel}{w}"
+        assert after[name][tags] > before.get(name, {}).get(tags, 0), name
+
+
+def test_group_wider_than_a_step_is_summed_outside():
+    """A group of more heads than a step takes (16 > 8): the steps hold 8,
+    dk / dv leave per step's heads in float32 and are summed after."""
+    q, k, v = _qkv(jax.random.key(13), B=1, H=16, Hkv=1, S=128)
+    do = jax.random.normal(jax.random.key(14), q.shape)
+    assert attention_ops._tiles("dkv", 128, 128, 32, 16).heads == 8
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * do),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, block_q=64, block_k=64, interpret=True))
+    want = grads(lambda q, k, v: reference_attention(q, k, v))
+    for a, b, name in zip(want, got, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
 
 
 class TestRingAttention:

@@ -24,7 +24,8 @@ from ray_tpu.util import telemetry
 _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
-              "alerts", "store", "lock", "jax", "xla", "compile", "moe")
+              "alerts", "store", "lock", "jax", "xla", "compile", "moe",
+              "flash")
 
 
 class TestCatalog:
@@ -434,6 +435,12 @@ class TestSmokeAllSubsystems:
         # off, so the report is made here the way jax makes it.
         jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
         jax.jit(lambda x: x * 5)(jnp.ones((4,), jnp.float32))
+
+        # -- flash: a traced flash-attention kernel counts the geometry of
+        # its grid step (interpreted here: no chip).
+        from ray_tpu.ops.attention import flash_attention
+        x = jnp.ones((1, 2, 64, 32), jnp.float32)
+        flash_attention(x, x[:, :1], x[:, :1], interpret=True)
 
         # -- lock: the contention profiler publishes on a double 1/8
         # sample (hold timing every 8th acquire, telemetry every 8th

@@ -255,6 +255,47 @@ def test_windowed_flash_kernels_compile_and_are_named(one_chip):
         assert name in text, name
 
 
+@pytest.mark.parametrize("batch,heads,kv_heads,seq,window", [
+    (4, 16, 16, 4096, None),    # yi-coder-1.5b.train-sft4k
+    (1, 32, 8, 4096, None),     # mistral-7b-v0.3.train-fsdp4, a chip's share
+    (1, 32, 4, 8192, None),     # trinity-mini.train-moe8k, a full layer
+    (1, 32, 4, 8192, 2048),     # ... and a window layer
+])
+def test_flash_compiles_at_the_cells_shapes_one_kernel_a_name(
+        one_chip, batch, heads, kv_heads, seq, window):
+    """With the geometry ``_tiles`` picks at each cell's shapes: forward,
+    dq and dk/dv are one Mosaic call each under today's names (the
+    roofline readers multiply a trace's calls by a call's least time), and
+    dk / dv leave in the inputs' dtype, per key head, with no float32
+    array a query head behind them."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+
+    q = _sds((batch, heads, seq, 128), jnp.bfloat16, one_chip)
+    kv = _sds((batch, kv_heads, seq, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    suffix = "" if window is None else f"_w{window}"
+    names = sorted(re.search(r"flash_[a-z]+(_w\d+)?", c.partition(" = ")[0])
+                   .group(0) for c in calls)
+    assert names == sorted(f"flash_{k}{suffix}"
+                           for k in ("fwd", "dq", "dkv"))
+    dkv = next(c for c in calls if "flash_dkv" in c.partition(" = ")[0])
+    results = dkv.partition(" = ")[2].partition(" custom-call(")[0]
+    assert re.findall(r"([a-z]+[0-9]+)\[([0-9,]+)\]", results) == [
+        ("bf16", f"{batch * kv_heads},{seq},128")] * 2, results
+
+
 def test_trinity_train_step_compiles_at_the_cell_sizes(topo, capsys):
     """``trinity-mini.train-moe8k``'s step as the benchmark builds it (9
     layers, 16 of 128 experts, 4 rows of 8,192, full remat, flash, Pallas
