@@ -1,29 +1,66 @@
-"""What every language model here shares beyond ``ops/``: the masked
-next-token cross-entropy (fused, or in sequence chunks under remat) and the
-remat wrapper of a block.  ``models/llama.py`` and ``models/afmoe.py`` both
-build on it; neither holds a copy."""
+"""What every language model here shares beyond ``ops/``: the next-token
+NLL of every position (fused, or in sequence chunks under remat), the masked
+mean cross-entropy built on it, and the remat wrapper of a block.
+``models/llama.py``, ``models/afmoe.py`` and ``models/ouro.py`` build on it;
+none holds a copy."""
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
 
 
-def chunked_nll_sum(x, lm_head, targets, mask, num_chunks: int, dt):
-    """Masked next-token NLL sum with the lm_head applied per sequence
-    chunk under remat: peak logits memory is one chunk's [B, S/c, vocab]
-    f32 slab (forward AND backward) instead of the full tensor."""
-    B, S, E = x.shape
-    assert S % num_chunks == 0, (S, num_chunks)
-    c = S // num_chunks
-    xs = jnp.swapaxes(x.reshape(B, num_chunks, c, E), 0, 1)
-    ts = jnp.swapaxes(targets.reshape(B, num_chunks, c), 0, 1)
-    ms = jnp.swapaxes(mask.reshape(B, num_chunks, c), 0, 1)
+def is_shape(x) -> bool:
+    """A leaf of a model's ``param_shapes``: (shape, fan-in)."""
+    return isinstance(x, tuple) and isinstance(x[1], int)
 
-    @jax.checkpoint
-    def chunk_nll(xc, tc, mc):
+
+def init_from_shapes(shapes, key: jax.Array, param_dtype) -> Dict[str, Any]:
+    """Parameters for a tree of (shape, fan-in): truncated normal /
+    sqrt(fan-in); fan-in 0 marks a weight that starts at a constant, a
+    norm's weight at one and a scalar (a bias) at zero."""
+    leaves, treedef = jax.tree.flatten(shapes, is_leaf=is_shape)
+    keys = jax.random.split(key, len(leaves))
+    out = []
+    for k, (shape, fan_in) in zip(keys, leaves):
+        if fan_in == 0:
+            out.append(jnp.full(shape, 1.0 if shape else 0.0, param_dtype))
+        else:
+            out.append((jax.random.truncated_normal(
+                k, -2, 2, shape, jnp.float32)
+                * (1.0 / math.sqrt(fan_in))).astype(param_dtype))
+    return jax.tree.unflatten(treedef, out)
+
+
+def count_params(shapes) -> int:
+    return sum(math.prod(shape) for shape, _ in jax.tree.leaves(
+        shapes, is_leaf=is_shape))
+
+
+def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
+    """Next-token NLL of every position, float32 [B, S]: hidden states
+    x [B, S, E] under ``lm_head`` [E, V] against ``targets`` [B, S].  With
+    ``weights`` [B, S] it returns their weighted sum, a scalar, instead:
+    summed inside each chunk, so that no per-token array leaves it (the
+    per-token form under a plain masked mean cost the sparse model's step
+    1.6 % on the chip: PERF.md, PR 34).
+
+    ``num_chunks`` > 0 applies the head per sequence chunk under remat: peak
+    logits memory is one chunk's [B, S/c, vocab] f32 slab (forward AND
+    backward) instead of the full tensor.  Every loss here is built on this
+    one function: a masked mean is its weighted sum (``next_token_loss``);
+    a model that weighs several hidden states per token takes the
+    per-token form (models/ouro.py)."""
+
+    def nll(xc, tc, wc=None):
+        # logsumexp formulation: nll = LSE(logits) - logit[target].
+        # Unlike log_softmax this never materializes a second
+        # [B, S, vocab] array — the LSE reduce fuses into the lm_head
+        # matmul consumer, and the backward's softmax is recomputed
+        # elementwise into the dW/dx matmuls.
         logits = jnp.einsum("bse,ev->bsv", xc, lm_head.astype(dt),
                             preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
@@ -34,23 +71,31 @@ def chunked_nll_sum(x, lm_head, targets, mask, num_chunks: int, dt):
         # combine.
         tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1,
                                   mode="promise_in_bounds")[..., 0]
-        return jnp.sum((lse - tgt) * mc)
+        return lse - tgt if wc is None else jnp.sum((lse - tgt) * wc)
 
-    def body(acc, xtm):
-        return acc + chunk_nll(*xtm), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                            (xs, ts, ms))
+    if not num_chunks:
+        return nll(x, targets, weights)
+    B, S, E = x.shape
+    assert S % num_chunks == 0, (S, num_chunks)
+    c = S // num_chunks
+    chunks = lambda a: jnp.swapaxes(
+        a.reshape((B, num_chunks, c) + a.shape[2:]), 0, 1)
+    chunk_nll = jax.checkpoint(nll)
+    if weights is None:
+        _, out = jax.lax.scan(lambda _, xt: (None, chunk_nll(*xt)), None,
+                              (chunks(x), chunks(targets)))
+        return jnp.swapaxes(out, 0, 1).reshape(B, S)
+    total, _ = jax.lax.scan(lambda acc, xtw: (acc + chunk_nll(*xtw), None),
+                            jnp.zeros((), jnp.float32),
+                            (chunks(x), chunks(targets), chunks(weights)))
     return total
 
 
-def next_token_loss(x, lm_head, batch: Dict[str, jax.Array],
-                    loss_chunks: int, dt) -> jax.Array:
-    """Masked mean next-token cross-entropy of the final hidden states
-    x [B, S, E] under ``lm_head`` [E, V].  batch: tokens [B,S], optional
-    loss_mask [B,S] and loss_denom.  ``loss_chunks`` > 0 computes the
-    [B, S, vocab] logits in that many sequence chunks (scan + remat), so
-    only ONE chunk's f32 logits are ever resident."""
+def targets_and_mask(batch: Dict[str, jax.Array]):
+    """(targets [B, S], float32 mask [B, S], the count the masked sum is
+    divided by) of a batch: tokens [B, S], optional loss_mask [B, S] and
+    loss_denom.  Position t predicts token t + 1; the last predicts
+    nothing."""
     tokens = batch["tokens"]
     targets = jnp.concatenate(
         [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
@@ -66,24 +111,19 @@ def next_token_loss(x, lm_head, batch: Dict[str, jax.Array],
     denom = batch.get("loss_denom")
     if denom is None:
         denom = jnp.maximum(jnp.sum(mask), 1.0)
-    if loss_chunks:
-        with jax.named_scope("loss"):
-            return chunked_nll_sum(x, lm_head, targets, mask, loss_chunks,
-                                   dt) / denom
-    logits = jnp.einsum("bse,ev->bsv", x, lm_head.astype(dt),
-                        preferred_element_type=jnp.float32)
+    return targets, mask, denom
+
+
+def next_token_loss(x, lm_head, batch: Dict[str, jax.Array],
+                    loss_chunks: int, dt) -> jax.Array:
+    """Masked mean next-token cross-entropy of the final hidden states
+    x [B, S, E] under ``lm_head`` [E, V].  batch: tokens [B,S], optional
+    loss_mask [B,S] and loss_denom.  ``loss_chunks`` > 0 computes the
+    [B, S, vocab] logits in that many sequence chunks (scan + remat), so
+    only ONE chunk's f32 logits are ever resident."""
+    targets, mask, denom = targets_and_mask(batch)
     with jax.named_scope("loss"):
-        # logsumexp formulation: nll = LSE(logits) - logit[target].
-        # Unlike log_softmax this never materializes a second
-        # [B, S, vocab] array — the LSE reduce fuses into the lm_head
-        # matmul consumer, and the backward's softmax is recomputed
-        # elementwise into the dW/dx matmuls.
-        logits = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        # promise_in_bounds: see chunked_nll_sum.
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1,
-                                  mode="promise_in_bounds")[..., 0]
-        return jnp.sum((lse - tgt) * mask) / denom
+        return token_nll(x, lm_head, targets, loss_chunks, dt, mask) / denom
 
 
 def remat(block: Callable, mode: Any) -> Callable:

@@ -187,28 +187,16 @@ def param_shapes(cfg: AfmoeConfig) -> Dict[str, Any]:
         "lm_head": ((E, V), E)}
 
 
-def _is_shape(x) -> bool:
-    return isinstance(x, tuple) and isinstance(x[1], int)
+_is_shape = _lm.is_shape
 
 
 def init_params(cfg: AfmoeConfig, key: jax.Array,
                 param_dtype=jnp.float32) -> Dict[str, Any]:
-    leaves, treedef = jax.tree.flatten(param_shapes(cfg), is_leaf=_is_shape)
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, (shape, fan_in) in zip(keys, leaves):
-        if fan_in == 0:
-            out.append(jnp.ones(shape, param_dtype))
-        else:
-            out.append((jax.random.truncated_normal(
-                k, -2, 2, shape, jnp.float32)
-                * (1.0 / math.sqrt(fan_in))).astype(param_dtype))
-    return jax.tree.unflatten(treedef, out)
+    return _lm.init_from_shapes(param_shapes(cfg), key, param_dtype)
 
 
 def num_params(cfg: AfmoeConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in jax.tree.leaves(
-        param_shapes(cfg), is_leaf=_is_shape))
+    return _lm.count_params(param_shapes(cfg))
 
 
 def init_state(cfg: AfmoeConfig) -> Dict[str, jax.Array]:
@@ -396,8 +384,15 @@ def loss_and_loads(params, state, batch, cfg: AfmoeConfig):
                                cfg.dtype), loads
 
 
+def loss_and_report(params, batch, cfg: AfmoeConfig, state=None):
+    """What the train step differentiates (parallel.spmd): the loss, and the
+    loads as what it reports, which ``update_state`` turns into the step's
+    metrics."""
+    return loss_and_loads(params, state or init_state(cfg), batch, cfg)
+
+
 def loss_fn(params, batch, cfg: AfmoeConfig, state=None) -> jax.Array:
-    return loss_and_loads(params, state or init_state(cfg), batch, cfg)[0]
+    return loss_and_report(params, batch, cfg, state)[0]
 
 
 def update_state(state, loads, cfg: AfmoeConfig):

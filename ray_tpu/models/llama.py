@@ -173,11 +173,12 @@ def _attend(cfg: LlamaConfig, q, k, v, positions):
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 
-@jax.named_scope("block/attn")
-def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
-    """Attention residual branch. x: [B, S, E] -> [B, S, E]."""
+def attention_branch(cfg: LlamaConfig, cos, sin, positions, h, layer):
+    """Attn(h) of a layer for normed h [B, S, E]: q, k, v, rotary embedding,
+    the configured attention, the output projection.  The residual and the
+    norms round it are the caller's (here ``_attn_half``; models/ouro.py
+    puts a second norm behind it)."""
     dt = cfg.dtype
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"].astype(dt),
                    preferred_element_type=dt)
     k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"].astype(dt),
@@ -187,24 +188,34 @@ def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
     attn = _attend(cfg, q, k, v, positions)
-    attn_out = jnp.einsum("bhsd,hde->bse", attn, layer["wo"].astype(dt),
-                          preferred_element_type=dt)
-    return x + attn_out
+    return jnp.einsum("bhsd,hde->bse", attn, layer["wo"].astype(dt),
+                      preferred_element_type=dt)
+
+
+def mlp_branch(cfg: LlamaConfig, h, layer):
+    """SwiGLU(h) of a layer for normed h [B, S, E]."""
+    dt = cfg.dtype
+    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(dt),
+                      preferred_element_type=dt)
+    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(dt),
+                    preferred_element_type=dt)
+    return jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
+                      layer["w_down"].astype(dt),
+                      preferred_element_type=dt)
+
+
+@jax.named_scope("block/attn")
+def _attn_half(cfg: LlamaConfig, cos, sin, positions, x, layer):
+    """Attention residual branch. x: [B, S, E] -> [B, S, E]."""
+    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+    return x + attention_branch(cfg, cos, sin, positions, h, layer)
 
 
 @jax.named_scope("block/mlp")
 def _mlp_half(cfg: LlamaConfig, x, layer):
     """MLP residual branch. x: [B, S, E] -> [B, S, E]."""
-    dt = cfg.dtype
     h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(dt),
-                      preferred_element_type=dt)
-    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(dt),
-                    preferred_element_type=dt)
-    mlp_out = jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                         layer["w_down"].astype(dt),
-                         preferred_element_type=dt)
-    return x + mlp_out
+    return x + mlp_branch(cfg, h, layer)
 
 
 def _block(cfg: LlamaConfig, cos, sin, positions, x, layer):

@@ -58,16 +58,20 @@ def _mirror_param_shardings(opt_state_shape, params_shape,
 class StepState(NamedTuple):
     """The second argument of a step whose model carries state that no
     optimizer may touch (``init_state`` in its module): the optimizer's state
-    and the model's, side by side."""
+    and the model's, side by side.  What a step reports beside ``loss`` and
+    ``grad_norm`` does not depend on it: that is the loss's to hand out
+    (``loss_and_report``), with or without state."""
     opt: Any
     model: Any
 
 
 def model_module(cfg):
     """The module that defines a model configuration's class, and with it
-    the model: ``init_params``, ``param_logical_axes``, ``loss_fn`` and,
-    where the model carries state, ``init_state``, ``loss_and_loads`` and
-    ``update_state`` (models/llama.py, models/afmoe.py)."""
+    the model: ``init_params``, ``param_logical_axes``, ``loss_fn``; where
+    the loss hands out more than a scalar, ``loss_and_report(params, batch,
+    cfg, state)`` -> (loss, report); and where the model carries state,
+    ``init_state`` and ``update_state(state, report, cfg)`` -> (state,
+    metrics) (models/llama.py, models/ouro.py, models/afmoe.py)."""
     return importlib.import_module(type(cfg).__module__)
 
 
@@ -93,11 +97,16 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
     init_fn(key) -> (params, opt_state) already sharded.
     step_fn(params, opt_state, batch) -> (params, opt_state, metrics).
 
-    A model with state the optimizer must not touch (afmoe's selection
-    bias: no gradient reaches it, and AdamW's weight decay would shrink it)
-    gets ``StepState(opt, model)`` as its ``opt_state``: the step hands the
-    model's part to the loss, and after the update to the model's
-    ``update_state``, whose metrics join the step's (afmoe: the scalars
+    ``metrics`` holds ``loss``, ``grad_norm`` and whatever the model's loss
+    reports: a module with ``loss_and_report`` returns (loss, report) from
+    the compiled step's own forward pass, and the report's arrays join the
+    metrics (ouro: ``loop_loss`` and ``loop_exit_share`` [loops],
+    ``loop_exit_entropy``).  A model with state the optimizer must not touch
+    (afmoe's selection bias: no gradient reaches it, and AdamW's weight
+    decay would shrink it) goes the same way and gets ``StepState(opt,
+    model)`` as its ``opt_state``: the step hands the model's part to the
+    loss, and after the update the report to the model's ``update_state``,
+    whose metrics are the ones that join (afmoe: the scalars
     ``moe_held_assignments``, ``moe_load_max_over_mean``, ``moe_dropped``,
     ``moe_sliced_calls`` and the routers' choices ``moe_choices`` [expert
     layers, tokens, k]).
@@ -111,7 +120,9 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
     optimizer update — the effective batch is unchanged, but saved
     activations (and thus the remat policy's HBM bill) shrink by the
     same factor, which is what lets lighter-recompute policies like
-    remat="mlp_only" fit a 16G chip at headline model sizes.
+    remat="mlp_only" fit a 16G chip at headline model sizes.  A loss that
+    reports is refused with it: the accumulation sums losses and gradients,
+    and knows no rule for a report.
     """
     import jax
     import jax.numpy as jnp
@@ -120,9 +131,10 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
 
     L = model_module(cfg)
     stateful = hasattr(L, "init_state")
-    if stateful and grad_accum > 1:
-        raise NotImplementedError("grad_accum with a model that carries "
-                                  "state")
+    reports = hasattr(L, "loss_and_report")
+    if reports and grad_accum > 1:
+        raise NotImplementedError("grad_accum with a loss that reports "
+                                  "metrics (ROADMAP)")
 
     rules = rules or default_rules()
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -178,25 +190,28 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
             jax.config.update("jax_threefry_partitionable", old)
 
     def train_step(params, opt_state, batch):
-        extra = {}
+        model_state = None
+        if stateful:
+            opt_state, model_state = opt_state
         with jax.named_scope("forward_backward"):
-            if stateful:
-                opt_state, model_state = opt_state
-                (loss, loads), grads = jax.value_and_grad(
-                    L.loss_and_loads, has_aux=True)(
-                        params, model_state, batch, cfg)
-            else:
-                loss, grads = loss_and_grads(params, batch)
+            (loss, report), grads = loss_and_grads(params, model_state,
+                                                   batch)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             gnorm = optax.global_norm(grads)
             if stateful:
-                model_state, extra = L.update_state(model_state, loads, cfg)
+                model_state, report = L.update_state(model_state, report,
+                                                     cfg)
                 opt_state = StepState(opt_state, model_state)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm, **extra}
+        return params, opt_state, {"loss": loss, "grad_norm": gnorm,
+                                   **report}
 
-    def loss_and_grads(params, batch):
+    def loss_and_grads(params, model_state, batch):
+        """((loss, what the loss reports), gradients)."""
+        if reports:
+            return jax.value_and_grad(L.loss_and_report, has_aux=True)(
+                params, batch, cfg, model_state)
         if grad_accum > 1:
             def split(v):
                 b = v.shape[0]
@@ -231,8 +246,9 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
             gzero = jax.tree.map(jnp.zeros_like, params)
             (grads, loss), _ = jax.lax.scan(
                 acc_body, (gzero, jnp.zeros((), jnp.float32)), micro)
-            return loss, grads
-        return jax.value_and_grad(L.loss_fn)(params, batch, cfg)
+            return (loss, {}), grads
+        loss, grads = jax.value_and_grad(L.loss_fn)(params, batch, cfg)
+        return (loss, {}), grads
 
     # The jitted function's name is the program's name in a device trace
     # (``jit_train_step``); the scopes inside are metadata only.

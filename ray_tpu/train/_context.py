@@ -165,7 +165,8 @@ def report(metrics: Dict[str, Any],
     ctx = get_context()
     with telemetry.profile_span("train_report", "train",
                                 extra={"step": ctx._report_seq + 1,
-                                       **_moe_loads(metrics)}):
+                                       **_moe_loads(metrics),
+                                       **_loop_readings(metrics)}):
         _report(ctx, metrics, checkpoint)
 
 
@@ -182,6 +183,22 @@ def _moe_loads(metrics: Dict[str, Any]) -> Dict[str, float]:
     the host (they may still be device scalars)."""
     return {name: float(metrics[key]) for key, name in _MOE_KEYS.items()
             if key in metrics}
+
+
+#: a train step's metrics of its passes over a looped stack (parallel.spmd)
+#: -> the gauge each is recorded as; one sample a pass (tag ``pass``) where
+#: the metric has one value a pass
+_LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
+              "loop_exit_share": "ray_tpu_train_loop_exit_share",
+              "loop_exit_entropy": "ray_tpu_train_loop_exit_entropy"}
+
+
+def _loop_readings(metrics: Dict[str, Any]) -> Dict[str, Any]:
+    """The reported step's passes under their gauges' names, read to the
+    host: a float, or a list with one float a pass."""
+    import numpy as np
+    return {name: np.asarray(metrics[key], float).tolist()
+            for key, name in _LOOP_KEYS.items() if key in metrics}
 
 
 def _report(ctx: "TrainContext", metrics: Dict[str, Any],
@@ -403,6 +420,12 @@ def _note_step(ctx: "TrainContext", now: float, now_mono: float,
     for name, value in _moe_loads(metrics).items():
         if name.endswith("_total"):
             telemetry.inc(name, value)
+        else:
+            telemetry.set_gauge(name, value)
+    for name, value in _loop_readings(metrics).items():
+        if isinstance(value, list):
+            for t, v in enumerate(value):
+                telemetry.set_gauge(name, v, tags={"pass": str(t)})
         else:
             telemetry.set_gauge(name, value)
     for key in ("tokens", "num_tokens", "tokens_per_step"):

@@ -211,6 +211,20 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "Expert-layer calls that took the dropless buffer "
                        "in slices, their held assignments passing its "
                        "rows (0: every call went through it at once)."},
+    # A looped stack's passes, from report() metrics carrying a train step's
+    # loop_* keys (parallel.spmd: models whose loss reports them).
+    "ray_tpu_train_loop_loss": {
+        "type": "gauge", "tag_keys": ("pass",),
+        "description": "Masked mean next-token loss of each pass over a "
+                       "looped stack in the last reported step."},
+    "ray_tpu_train_loop_exit_share": {
+        "type": "gauge", "tag_keys": ("pass",),
+        "description": "Mean share of the exit distribution that each pass "
+                       "of a looped stack took in the last reported step."},
+    "ray_tpu_train_loop_exit_entropy": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean entropy (nats) of a looped stack's exit "
+                       "distribution in the last reported step."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,

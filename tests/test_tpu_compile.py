@@ -353,3 +353,60 @@ def test_trinity_train_step_compiles_at_the_cell_sizes(topo, capsys):
                 if " scatter(" in line and "block/moe" in line
                 and "/experts/" not in line]
     assert not scatters, scatters[:2]
+
+
+def test_ouro_train_step_compiles_at_the_cell_sizes(topo, capsys):
+    """``ouro-2.6b.train-loop4k``'s step as the benchmark builds it (12
+    layers run 4 times, 4 rows of 4,096, full remat, flash, 8 loss chunks a
+    pass) compiles for one described v5e chip; its memory is stated (the
+    temporaries over-state what the runtime reserves).  The passes are told
+    apart in the program's text, which the scope readers join a trace with."""
+    import json
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+    from ray_tpu.parallel.spmd import StepState, make_lm_train_step
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmark.archs import ouro as arch
+    with open(os.path.join(root, "benchmark/configs/ouro-2.6b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark/traffic/train-loop4k.json")) as f:
+        seq = json.load(f)["seq_len"]
+    s = arch.sizes_of(config)
+    cfg = arch.program_config(s, seq, config["train"])
+    rows = config["train"]["tokens_per_chip"] // seq
+    before = get_global_mesh()
+    try:
+        mesh = build_mesh(MeshSpec(), devices=topo.devices[:1])
+        init_fn, step_fn, _ = make_lm_train_step(
+            cfg, mesh, learning_rate=1e-5, param_dtype=jnp.bfloat16)
+        params, state = jax.eval_shape(init_fn, jax.random.key(0))
+        batch = {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for k in ("tokens", "loss_mask")}
+        compiled = step_fn.lower(params, state, batch).compile()
+    finally:
+        set_global_mesh(before)
+    assert not isinstance(state, StepState)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nouro-2.6b.train-loop4k step for a described v5e: "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"{_kernels(compiled)} kernels")
+    assert sum(a.size for a in jax.tree.leaves(params)) == \
+        arch.parameters(s)["held"] == config["parameters"] == 817991681
+    # bf16 weights and two bf16 moments of 818 M parameters.
+    assert 4.85e9 < mem.argument_size_in_bytes < 5.0e9
+    # 14.95 GB of temporaries stated, where the chip's runtime reserves
+    # 8.66 GB beside 5.04 GB in use (PERF.md, PR 34): every pass's stacked
+    # gradient lives until the optimizer's fused sum.
+    assert mem.temp_size_in_bytes < 15.5e9
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "loop/0/", "loop/3/",
+                 "block/attn", "block/mlp", "/loss/"):
+        assert name in text, name
