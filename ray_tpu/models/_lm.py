@@ -65,7 +65,12 @@ def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
     backward) instead of the full tensor.  Every loss here is built on this
     one function: a masked mean is its weighted sum (``next_token_loss``);
     a model that weighs several hidden states per token takes the
-    per-token form (models/ouro.py)."""
+    per-token form (models/ouro.py).
+
+    Several heads a position (models/evabyte.py): ``lm_head`` [E, J, V],
+    ``targets`` and ``weights`` [B, S, J]; the per-token form is [B, S, J]
+    and the weighted sums are one a head, [J]."""
+    spec = "bse,ev->bsv" if lm_head.ndim == 2 else "bse,ejv->bsjv"
 
     def nll(xc, tc, wc=None):
         # logsumexp formulation: nll = LSE(logits) - logit[target].
@@ -73,7 +78,7 @@ def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
         # [B, S, vocab] array — the LSE reduce fuses into the lm_head
         # matmul consumer, and the backward's softmax is recomputed
         # elementwise into the dW/dx matmuls.
-        logits = jnp.einsum("bse,ev->bsv", xc, lm_head.astype(dt),
+        logits = jnp.einsum(spec, xc, lm_head.astype(dt),
                             preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         # promise_in_bounds: targets are token ids < vocab by
@@ -83,7 +88,8 @@ def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
         # combine.
         tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1,
                                   mode="promise_in_bounds")[..., 0]
-        return lse - tgt if wc is None else jnp.sum((lse - tgt) * wc)
+        return lse - tgt if wc is None else jnp.sum((lse - tgt) * wc,
+                                                    axis=(0, 1))
 
     if not num_chunks:
         return nll(x, targets, weights)
@@ -96,9 +102,9 @@ def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
     if weights is None:
         _, out = jax.lax.scan(lambda _, xt: (None, chunk_nll(*xt)), None,
                               (chunks(x), chunks(targets)))
-        return jnp.swapaxes(out, 0, 1).reshape(B, S)
+        return jnp.swapaxes(out, 0, 1).reshape(targets.shape)
     total, _ = jax.lax.scan(lambda acc, xtw: (acc + chunk_nll(*xtw), None),
-                            jnp.zeros((), jnp.float32),
+                            jnp.zeros(weights.shape[2:], jnp.float32),
                             (chunks(x), chunks(targets), chunks(weights)))
     return total
 

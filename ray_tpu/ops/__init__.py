@@ -10,6 +10,9 @@ delegated to vLLM/DeepSpeed).  Built natively here:
 - ``ulysses``       — sequence<->head all-to-all context parallelism
 - ``moe``           — mixture-of-experts: sigmoid scores with a selection
                       bias, dropless, over the experts held
+- ``eva``           — EVA chunked linearized attention: a window's tokens
+                      and 16-token summaries of every earlier window under
+                      one softmax; pooling and two-operand flash kernels
 - ``norms``/``swiglu`` — fused-friendly elementwise building blocks
 - ``rope``          — rotary embedding: the split rotation, and on the TPU a
                       rotate-and-place kernel pair between a projection
@@ -20,6 +23,7 @@ from .norms import rms_norm
 from .rope import (apply_rope, rope_frequencies, rope_lane_tables,
                    rotate_heads)
 from .attention import attention, flash_attention, reference_attention
+from .eva import eva_attention, eva_summaries
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
 
@@ -27,5 +31,6 @@ __all__ = [
     "rms_norm", "apply_rope", "rope_frequencies", "rope_lane_tables",
     "rotate_heads",
     "attention", "flash_attention", "reference_attention",
+    "eva_attention", "eva_summaries",
     "ring_attention", "ulysses_attention",
 ]

@@ -191,14 +191,20 @@ def _moe_loads(metrics: Dict[str, Any]) -> Dict[str, float]:
 _LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
               "loop_exit_share": "ray_tpu_train_loop_exit_share",
               "loop_exit_entropy": "ray_tpu_train_loop_exit_entropy"}
+#: and of a model's several output heads a position: one sample a head
+_HEAD_KEYS = {"head_loss": "ray_tpu_train_head_loss"}
+#: the tag a gauge's samples are told apart by where it has one a pass or head
+_LIST_TAGS = {**{name: "pass" for name in _LOOP_KEYS.values()},
+              **{name: "head" for name in _HEAD_KEYS.values()}}
 
 
 def _loop_readings(metrics: Dict[str, Any]) -> Dict[str, Any]:
-    """The reported step's passes under their gauges' names, read to the
-    host: a float, or a list with one float a pass."""
+    """The reported step's passes and heads under their gauges' names, read
+    to the host: a float, or a list with one float a pass or head."""
     import numpy as np
     return {name: np.asarray(metrics[key], float).tolist()
-            for key, name in _LOOP_KEYS.items() if key in metrics}
+            for key, name in {**_LOOP_KEYS, **_HEAD_KEYS}.items()
+            if key in metrics}
 
 
 def _report(ctx: "TrainContext", metrics: Dict[str, Any],
@@ -425,7 +431,7 @@ def _note_step(ctx: "TrainContext", now: float, now_mono: float,
     for name, value in _loop_readings(metrics).items():
         if isinstance(value, list):
             for t, v in enumerate(value):
-                telemetry.set_gauge(name, v, tags={"pass": str(t)})
+                telemetry.set_gauge(name, v, tags={_LIST_TAGS[name]: str(t)})
         else:
             telemetry.set_gauge(name, value)
     for key in ("tokens", "num_tokens", "tokens_per_step"):

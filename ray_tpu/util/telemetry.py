@@ -225,6 +225,11 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "type": "gauge", "tag_keys": (),
         "description": "Mean entropy (nats) of a looped stack's exit "
                        "distribution in the last reported step."},
+    "ray_tpu_train_head_loss": {
+        "type": "gauge", "tag_keys": ("head",),
+        "description": "Masked mean cross-entropy of each output head of a "
+                       "model that predicts several tokens a position, in "
+                       "the last reported step (head 0 predicts the next)."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,
@@ -402,6 +407,14 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
                        "from the call's shapes (scores: qk or kq)."},
+    "ray_tpu_eva_step_geometry_total": {
+        "type": "counter",
+        "tag_keys": ("kernel", "block_q", "block_k", "block_s",
+                     "summary_steps", "token_steps"),
+        "description": "EVA kernels traced (ops/eva.py), by a grid step's "
+                       "blocks (q rows, token keys, summary keys) and the "
+                       "steps a head's walk takes on each operand: the "
+                       "earlier windows' summaries, the window's tokens."},
     "ray_tpu_rope_path_total": {
         "type": "counter", "tag_keys": ("path", "rows", "heads"),
         "description": "Calls of ops/rope.rotate_heads traced, by the path "

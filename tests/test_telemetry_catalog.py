@@ -25,7 +25,7 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope")
+              "flash", "rope", "eva")
 
 
 class TestCatalog:
@@ -446,6 +446,12 @@ class TestSmokeAllSubsystems:
         from ray_tpu.ops.rope import rope_lane_tables, rotate_heads
         rotate_heads(jnp.ones((1, 8, 2, 32), jnp.float32),
                      *rope_lane_tables(32, 8))
+
+        # -- eva: a traced EVA kernel counts its table's steps (two
+        # windows of 32 in chunks of 8, interpreted here).
+        from ray_tpu.ops.eva import eva_attention
+        eva_attention(x, x, x, x[:, :, :8], x[:, :, :8], 32, 8,
+                      impl="flash_interpret")
 
         # -- lock: the contention profiler publishes on a double 1/8
         # sample (hold timing every 8th acquire, telemetry every 8th
