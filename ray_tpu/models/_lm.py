@@ -69,44 +69,64 @@ def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):
 
     Several heads a position (models/evabyte.py): ``lm_head`` [E, J, V],
     ``targets`` and ``weights`` [B, S, J]; the per-token form is [B, S, J]
-    and the weighted sums are one a head, [J]."""
+    and the weighted sums are one a head, [J].
+
+    On an ambient mesh whose ``fsdp`` axis shards the head, a chunked loss
+    of one head a position gathers it once for the call and not once a
+    chunk and pass (``parallel/fsdp.on_rows``; where the mesh or the batch
+    does not allow a manual region, the partitioner's program as before)."""
     spec = "bse,ev->bsv" if lm_head.ndim == 2 else "bse,ejv->bsjv"
 
-    def nll(xc, tc, wc=None):
-        # logsumexp formulation: nll = LSE(logits) - logit[target].
-        # Unlike log_softmax this never materializes a second
-        # [B, S, vocab] array — the LSE reduce fuses into the lm_head
-        # matmul consumer, and the backward's softmax is recomputed
-        # elementwise into the dW/dx matmuls.
-        logits = jnp.einsum(spec, xc, lm_head.astype(dt),
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        # promise_in_bounds: targets are token ids < vocab by
-        # construction.  The default mode's NaN fill value poisons the
-        # SPMD-partitioned gather when vocab is sharded (tp) — each
-        # shard's locally-OOB rows fill NaN before the cross-shard
-        # combine.
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1,
-                                  mode="promise_in_bounds")[..., 0]
-        return lse - tgt if wc is None else jnp.sum((lse - tgt) * wc,
-                                                    axis=(0, 1))
+    def run(lm_head, x, targets, weights=None):
+        def nll(xc, tc, wc=None):
+            # logsumexp formulation: nll = LSE(logits) - logit[target].
+            # Unlike log_softmax this never materializes a second
+            # [B, S, vocab] array — the LSE reduce fuses into the lm_head
+            # matmul consumer, and the backward's softmax is recomputed
+            # elementwise into the dW/dx matmuls.
+            logits = jnp.einsum(spec, xc, lm_head.astype(dt),
+                                preferred_element_type=jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            # promise_in_bounds: targets are token ids < vocab by
+            # construction.  The default mode's NaN fill value poisons the
+            # SPMD-partitioned gather when vocab is sharded (tp) — each
+            # shard's locally-OOB rows fill NaN before the cross-shard
+            # combine.
+            tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1,
+                                      mode="promise_in_bounds")[..., 0]
+            return lse - tgt if wc is None else jnp.sum((lse - tgt) * wc,
+                                                        axis=(0, 1))
 
-    if not num_chunks:
-        return nll(x, targets, weights)
-    B, S, E = x.shape
-    assert S % num_chunks == 0, (S, num_chunks)
-    c = S // num_chunks
-    chunks = lambda a: jnp.swapaxes(
-        a.reshape((B, num_chunks, c) + a.shape[2:]), 0, 1)
-    chunk_nll = jax.checkpoint(nll)
-    if weights is None:
-        _, out = jax.lax.scan(lambda _, xt: (None, chunk_nll(*xt)), None,
-                              (chunks(x), chunks(targets)))
-        return jnp.swapaxes(out, 0, 1).reshape(targets.shape)
-    total, _ = jax.lax.scan(lambda acc, xtw: (acc + chunk_nll(*xtw), None),
-                            jnp.zeros(weights.shape[2:], jnp.float32),
-                            (chunks(x), chunks(targets), chunks(weights)))
-    return total
+        if not num_chunks:
+            return nll(x, targets, weights)
+        B, S, E = x.shape
+        assert S % num_chunks == 0, (S, num_chunks)
+        c = S // num_chunks
+        chunks = lambda a: jnp.swapaxes(
+            a.reshape((B, num_chunks, c) + a.shape[2:]), 0, 1)
+        chunk_nll = jax.checkpoint(nll)
+        if weights is None:
+            _, out = jax.lax.scan(lambda _, xt: (None, chunk_nll(*xt)), None,
+                                  (chunks(x), chunks(targets)))
+            return jnp.swapaxes(out, 0, 1).reshape(targets.shape)
+        total, _ = jax.lax.scan(
+            lambda acc, xtw: (acc + chunk_nll(*xtw), None),
+            jnp.zeros(weights.shape[2:], jnp.float32),
+            (chunks(x), chunks(targets), chunks(weights)))
+        return total
+
+    from ..parallel import fsdp
+    from ..parallel.mesh import get_global_mesh
+    mesh = get_global_mesh()
+    if num_chunks and lm_head.ndim == 2 and fsdp.manual_mesh(mesh,
+                                                             x.shape[0]):
+        # The head crosses the ICI once a step and its gradient once, not
+        # once a chunk forward, recomputed and backward.
+        rows = (x, targets) if weights is None else (x, targets, weights)
+        return fsdp.on_rows(run, lm_head, rows, mesh=mesh,
+                            logical=("embed", "vocab"), dtype=dt,
+                            reduce=weights is not None)
+    return run(lm_head, x, targets, weights)
 
 
 def targets_and_mask(batch: Dict[str, jax.Array]):

@@ -39,7 +39,10 @@ def default_rules() -> ShardingRules:
     - batch over (dp, fsdp): every data shard trains a distinct slice
     - embed dim sharded over tp for attention/MLP projections (Megatron)
     - the *other* matmul dim of each weight sharded over fsdp (ZeRO-3-style
-      parameter sharding; XLA all-gathers just-in-time per layer)
+      parameter sharding).  XLA does not gather such a weight for its layer:
+      it turns every dot that meets it into a ring of partial dots, the
+      shards going round by collective-permute (a windowed einsum).  A
+      chunked loss's head is gathered once a step (``parallel/fsdp.on_rows``)
     - sequence over sp (ring/Ulysses context parallelism in ops/)
     - experts over ep
     """

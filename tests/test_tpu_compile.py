@@ -242,12 +242,13 @@ def test_decode_step_compiles_at_smoke_shapes(one_chip, v5e_block_sizes,
     assert resident < 15.75e9, resident     # one v5e chip's HBM budget
 
 
-def test_fsdp4_train_step_compiles(topo, as_on_the_chip):
-    """Mesh training with flash attention: a Mosaic kernel cannot be
-    partitioned by GSPMD, so the step compiles for four chips only with
-    the kernels, the rotary pair too, in shard_map islands (depth cut to 2
-    layers)."""
-    import re
+@pytest.fixture(scope="module")
+def fsdp4_step_text(topo):
+    """The compiled text of a bf16 train step on a described ``{fsdp: 4}``
+    (chip_smoke's widths, depth cut to 2 layers, the loss in 4 chunks),
+    steered as ``as_on_the_chip`` steers, once for the tests that read
+    it."""
+    import importlib
 
     import jax
     import jax.numpy as jnp
@@ -257,27 +258,39 @@ def test_fsdp4_train_step_compiles(topo, as_on_the_chip):
     from ray_tpu.parallel.spmd import make_lm_train_step
 
     cfg = LlamaConfig(**{**MODEL, "layers": 2}, dtype=jnp.bfloat16,
-                      remat=True, attention_impl="flash")
-    before = get_global_mesh()
+                      remat=True, attention_impl="flash", loss_chunks=4)
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    before = get_global_mesh(), attention._on_tpu
+    attention._on_tpu = lambda: True
     try:
         mesh = build_mesh(MeshSpec(fsdp=4), devices=topo.devices)
         init_fn, step_fn, _ = make_lm_train_step(
             cfg, mesh, learning_rate=1e-4, param_dtype=jnp.bfloat16)
         params, opt = jax.eval_shape(init_fn, jax.random.key(0))
         batch = {"tokens": jax.ShapeDtypeStruct((8, 2048), jnp.int32)}
-        compiled = step_fn.lower(params, opt, batch).compile()
+        return step_fn.lower(params, opt, batch).compile().as_text()
     finally:
-        set_global_mesh(before)
-    text = compiled.as_text()
+        set_global_mesh(before[0])
+        attention._on_tpu = before[1]
+
+
+def test_fsdp4_train_step_compiles(fsdp4_step_text):
+    """Mesh training with flash attention: a Mosaic kernel cannot be
+    partitioned by GSPMD, so the step compiles for four chips only with
+    the kernels, the rotary pair too, in shard_map islands."""
+    import re
+
+    text = fsdp4_step_text
     # Forward, recomputed forward, dq, dk/dv; q and k into the forward
     # twice and their gradients out once.
-    assert _kernels(compiled) == 10
+    assert text.count("tpu_custom_call") == 10
     # Per-device batch rows x heads reach the kernel, not the global 8.
     assert "bf16[32,2048,128]" in text
     assert "rope_to_heads" in text and "rope_from_heads" in text
     # What is gathered over fsdp is parameters and the tokens, never q or k
     # on their way into a kernel; 35 gathers before the rotary kernels
-    # (PR 35), 34 with them.
+    # (PR 35), 34 with them, and 34 with the head gathered once a step (PR
+    # 37: 16 of them are that one gather's pieces).
     gathered = [re.search(r"= \(?([a-z0-9]+\[[0-9,]*\])", line).group(1)
                 for line in text.splitlines()
                 if re.search(r"= .* all-gather(-start)?\(", line)]
@@ -285,6 +298,70 @@ def test_fsdp4_train_step_compiles(topo, as_on_the_chip):
         "bf16[2048,16,128]", "bf16[16,128,2048]", "bf16[2048,5632]",
         "bf16[5632,2048]", "bf16[2048,32000]", "s32[4,2,2048]"}, gathered
     assert len(gathered) <= 35
+
+
+def _computations(text):
+    """{computation name: its lines} of a compiled program's text."""
+    import re
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _while_bodies(comps):
+    """[lines of every computation a while loop's body reaches], a list a
+    loop of the program."""
+    import re
+    called = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+    out = []
+    for body in {m.group(1) for lines in comps.values() for line in lines
+                 for m in [re.search(r" while\(.*body=%?([\w.\-]+)", line)]
+                 if m}:
+        todo, seen = [body], set()
+        while todo:
+            name = todo.pop()
+            if name not in seen:
+                seen.add(name)
+                todo += [c for line in comps[name]
+                         for c in called.findall(line)]
+        out.append([line for name in seen for line in comps[name]])
+    return out
+
+
+@pytest.mark.parametrize("what", ["no_collective_in_the_loss_s_loops",
+                                  "one_gather", "one_reduce_scatter"])
+def test_fsdp4_head_crosses_the_ici_once_a_step(fsdp4_step_text, what):
+    """The ``{fsdp: 4}`` step under a chunked loss (parallel/fsdp.on_rows):
+    the head is gathered once a step and its gradient reduce-scattered once,
+    float32, outside the loss's chunk loops."""
+    import re
+
+    text = fsdp4_step_text
+    if what == "one_gather":
+        # One collective (one channel), which the compiler carries through
+        # the forward layer loop in pieces of an asynchronous fusion.
+        assert len(set(re.findall(
+            r"= \(?bf16\[2048,32000\][^=]* all-gather(?:-start)?\([^)]*\), "
+            r"channel_id=(\d+)", text))) == 1
+        return
+    if what == "one_reduce_scatter":
+        assert len(re.findall(r"= f32\[512,32000\][^=]* reduce-scatter\(",
+                              text)) == 1
+        return
+    loss = [lines for lines in _while_bodies(_computations(text))
+            if any("loss" in line for line in lines)
+            and not any("block/" in line for line in lines)]
+    assert len(loss) == 2, len(loss)             # forward, backward
+    for lines in loss:
+        assert not [line for line in lines if re.search(
+            r" (all-gather|all-reduce|reduce-scatter|collective-permute)"
+            r"(-start)?\(", line)]
 
 
 def test_windowed_flash_kernels_compile_and_are_named(one_chip):
