@@ -2985,17 +2985,22 @@ class Runtime:
 
     def write_trace_files(self) -> Optional[str]:
         """``<session>/trace/spans.jsonl`` (every span the head holds,
-        of every process, one JSON object a line) and ``counters.json``
-        (the cluster-merged metric samples): what a finished run leaves
-        for whoever reads it afterwards."""
+        of every process, one JSON object a line), ``stalls.json`` (what
+        ``telemetry.stalls`` reads out of them: the step periods that ran
+        long and what each coincided with) and ``counters.json`` (the
+        cluster-merged metric samples): what a finished run leaves for
+        whoever reads it afterwards."""
         import json
         from ray_tpu.util import metrics as _metrics
         trace_dir = os.path.join(self.session_dir, "trace")
         try:
             os.makedirs(trace_dir, exist_ok=True)
+            spans = [sp.to_dict() for sp in self.events.spans()]
             with open(os.path.join(trace_dir, "spans.jsonl"), "w") as f:
-                for sp in self.events.spans():
-                    f.write(json.dumps(sp.to_dict(), default=str) + "\n")
+                for span in spans:
+                    f.write(json.dumps(span, default=str) + "\n")
+            with open(os.path.join(trace_dir, "stalls.json"), "w") as f:
+                json.dump(telemetry.stalls(spans), f, default=str)
             by_name, acc = _metrics._aggregate_snapshots()
             counters = {
                 "types": {n: m["type"] for n, m in by_name.items()},

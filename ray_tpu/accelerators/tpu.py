@@ -166,8 +166,9 @@ def init_backend() -> int:
     devices; on a TPU that differs from this process's chip grant it
     raises, before anything is placed on the wrong devices.  Also the
     place where a process that runs jax gets its ``xla_compile`` listener
-    (``profiler/recompile.py``)."""
-    from ..profiler import recompile
+    (``profiler/recompile.py``), and its ``py_gc`` and ``worker_sample``
+    spans (``profiler/attribution.watch_process``)."""
+    from ..profiler import attribution, recompile
     from ..util import telemetry
     import time
     extra: Dict[str, float] = {}
@@ -179,6 +180,7 @@ def init_backend() -> int:
         extra["chip_wait_s"] = wait_for_chips(granted)
         devices = jax.local_devices()
     recompile.ensure_listener()
+    attribution.watch_process()
     if granted and devices[0].platform == "tpu" \
             and len(devices) != len(granted):
         raise RuntimeError(

@@ -258,8 +258,16 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
         out_shardings=(param_shardings, opt_shardings, None),
         donate_argnums=(0, 1) if donate else ())
 
+    # The one call a step loop makes into the framework each step: its
+    # spans, numbered from 0, give every step's period (start to start).
+    # (Imported here: a line added above ``train_step`` moves the line
+    # numbers that the kernels' compile-cache keys hold.)
+    import itertools
+    calls = itertools.count()
+
     def place_batch(batch: Dict[str, Any]):
-        with telemetry.profile_span("train_place_batch", "train"):
+        with telemetry.profile_span("train_place_batch", "train",
+                                    extra={"step": next(calls)}):
             return {k: jax.device_put(v, bsharding)
                     for k, v in batch.items()}
 

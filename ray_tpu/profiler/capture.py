@@ -131,9 +131,20 @@ def _jax_profile_window(duration_s: float) -> Dict[str, Any]:
     return info
 
 
+#: What a record of ``device_memory_stats`` holds of a device's
+#: ``memory_stats()``: None where the backend does not give the key.
+#: ``bytes_reserved`` is what the runtime keeps for the programs'
+#: temporaries, outside ``bytes_in_use``.
+MEMORY_KEYS = ("bytes_in_use", "bytes_reserved", "peak_bytes_in_use",
+               "peak_bytes_reserved", "largest_free_block_bytes",
+               "num_allocs", "bytes_limit")
+
+
 def device_memory_stats() -> List[Dict[str, Any]]:
     """Per-device memory stats from jax (empty when jax isn't loaded or
-    the backend doesn't report them — CPU usually doesn't)."""
+    the backend doesn't report them — CPU usually doesn't).  The one
+    place that reads them: the profile capture, the HBM gauges and the
+    ``worker_sample`` span all take their numbers from here."""
     if "jax" not in sys.modules:
         return []
     out: List[Dict[str, Any]] = []
@@ -146,12 +157,8 @@ def device_memory_stats() -> List[Dict[str, Any]]:
                 stats = None
             if not stats:
                 continue
-            out.append({
-                "device": str(d),
-                "bytes_in_use": stats.get("bytes_in_use"),
-                "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
-                "bytes_limit": stats.get("bytes_limit"),
-            })
+            out.append({"device": str(d),
+                        **{k: stats.get(k) for k in MEMORY_KEYS}})
     except Exception:  # noqa: BLE001 — stats are garnish
         return out
     return out

@@ -17,8 +17,9 @@ attributes them to *tracked call sites*:
   once-per-site warning names the argument shapes/dtypes that changed —
   the culprit, not just the symptom.
 * :func:`install` additionally patches ``jax.jit`` so functions jitted
-  after the install are tracked automatically (train workers install
-  this by default; ``RAY_TPU_RECOMPILE_DETECT=0`` opts out).
+  after the install are tracked automatically, each site named after its
+  function.  A train worker calls ``install(patch_jit=False)``: the
+  listener and explicit :func:`track`, and ``jax.jit`` left alone.
 
 Everything degrades to a no-op when jax (or its monitoring API) is
 absent — the module never imports jax on its own.

@@ -113,18 +113,18 @@ class TrainWorker:
             if ctx_info.get("use_tpu"):
                 from ..accelerators.tpu import init_backend
                 init_backend()
-            # Recompile detector: shape churn in the user's jitted step
-            # fn is the #1 silent TPU step-time regression — every train
-            # worker watches for it by default
-            # (RAY_TPU_RECOMPILE_DETECT=0 opts out).  install() only
-            # engages once jax is imported, so it runs AFTER the train
-            # fn deserialized (unpickling restores the fn's module
-            # imports, incl. jax) and after any setup_dist import;
-            # fns that only import jax lazily inside their body wrap
-            # explicitly with ray_tpu.profiler.track().
-            if os.environ.get("RAY_TPU_RECOMPILE_DETECT", "1") != "0":
-                from ..profiler import recompile
-                recompile.install()
+            # Compile accounting: every backend compile of this worker is
+            # an ``xla_compile`` span and a count in
+            # ``ray_tpu_xla_compiles_total`` (the jax.monitoring listener:
+            # it needs jax imported, so this runs AFTER the train fn
+            # deserialized and after any setup_dist import), and a site
+            # wrapped with ``ray_tpu.profiler.track()`` warns when it
+            # compiles again after its warm-up.  ``jax.jit`` itself stays
+            # jax's: the patch named a site after its function, so every
+            # ``<lambda>`` of a process was one site and a helper jitted
+            # after the warm-up read as a recompilation of the step.
+            from ..profiler import recompile
+            recompile.install(patch_jit=False)
             # group: it holds the whole step loop, whose parts are the
             # user's own and train_place_batch / train_report.
             with telemetry.profile_span(

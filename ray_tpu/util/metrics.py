@@ -225,18 +225,19 @@ def note_pending() -> None:
         _ensure_flusher()
 
 
-def _ship_spans(rt) -> None:
+def _ship_spans(rt) -> int:
     """The buffered profile spans as ONE ``add_profile_span`` frame, ahead
-    of the metrics frame of the same flush."""
+    of the metrics frame of the same flush.  Returns their count."""
     from .telemetry import drain_spans
     spans = drain_spans()
     if not spans:
-        return
+        return 0
     if hasattr(rt, "send") and hasattr(rt, "worker_id"):
         from ray_tpu._private.protocol import RpcCall
         rt.send(RpcCall(0, rt.worker_id, "add_profile_span", (spans,), {}))
     else:
         rt.control("add_profile_span", spans)
+    return len(spans)
 
 
 def flush() -> None:
@@ -253,9 +254,16 @@ def flush() -> None:
     source = getattr(rt, "worker_id", None)
     source_id = source.hex() if source is not None else "unknown"
     _dirty = False
+    from .telemetry import profile_span
+    # ``worker_flush``: the frame's size and its length, the wait for the
+    # head's reply included; it ships with the next flush.
+    frame: Dict[str, Any] = {}
     try:
-        _ship_spans(rt)
-        rt.control("metrics_push", source_id, local_snapshots())
+        with profile_span("worker_flush", "system", frame):
+            frame["spans"] = _ship_spans(rt)
+            snapshots = local_snapshots()
+            frame["series"] = sum(len(m["samples"]) for m in snapshots)
+            rt.control("metrics_push", source_id, snapshots)
     except Exception:
         pass  # driver shutting down; metrics are best-effort
 
@@ -327,8 +335,10 @@ def _ensure_flusher() -> None:
     gen = _flusher_gen
 
     def loop():
+        from ray_tpu.profiler import attribution
         while gen == _flusher_gen:
             time.sleep(2.0)
+            attribution.sample_if_watching()  # a process that holds chips
             flush()
 
     from ray_tpu._private import sanitizer

@@ -25,7 +25,9 @@ Three pieces:
   inference engine driven without a cluster) can stay instrumented
   unconditionally.
   Where jax is loaded a span is also a ``TraceAnnotation``, so a profiler
-  session shows it on the device trace's clock.
+  session shows it on the device trace's clock.  ``stalls(spans)`` reads a
+  finished run's spans for the step periods that ran long and what each
+  coincided with; the head leaves its result as ``trace/stalls.json``.
 * ``GoodputTracker`` — partitions a training run's wall time into
   productive-step vs init/checkpoint/restart/idle (MegaScale-style
   goodput accounting) and exposes ``ray_tpu_train_goodput_ratio``.
@@ -35,6 +37,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import statistics
 import sys
 import threading
 import time
@@ -873,6 +876,119 @@ class profile_span:
         if note is not None:
             note.__exit__(None, None, None)
         return False
+
+
+# -- reading a run's spans: the stalled step ---------------------------------
+
+#: keys of a span's dict (``ProfileSpan.to_dict``) that are not its state
+_SPAN_FRAME = frozenset(("name", "cat", "start", "end", "process", "thread",
+                         "span_id", "parent_id", "self_s"))
+
+
+def _sample_state(span: Dict[str, Any]) -> Dict[str, Any]:
+    state = {k: v for k, v in span.items()
+             if k not in _SPAN_FRAME and isinstance(v, (int, float))}
+    state["at"] = span["start"]
+    return state
+
+
+def _periods(beats: list) -> list:
+    """(span, seconds to the next span's start) for consecutive cadence
+    spans; a pair whose ``step`` does not follow on is another closure's
+    or has lost a span, and gives none."""
+    beats.sort(key=lambda s: s["start"])
+    return [(a, b["start"] - a["start"]) for a, b in zip(beats, beats[1:])
+            if a.get("step") is None or b.get("step") is None
+            or b["step"] == a["step"] + 1]
+
+
+def _covering(others: list, start: float, end: float) -> list:
+    """Seconds of [start, end) that ``others`` cover, summed by name and
+    process, the largest first."""
+    covered: Dict[tuple, list] = {}
+    for s in others:
+        overlap = min(end, s["end"]) - max(start, s["start"])
+        if overlap > 0:
+            entry = covered.setdefault((s["name"], s.get("process")), [0.0, 0])
+            entry[0] += overlap
+            entry[1] += 1
+    return [{"name": name, "process": pid, "seconds": sec, "spans": n}
+            for (name, pid), (sec, n) in sorted(
+                covered.items(), key=lambda kv: -kv[1][0])]
+
+
+def _bracket(samples: list, start: float, end: float) -> Dict[str, Any]:
+    """The last sample that starts before [start, end), the first that ends
+    after it, and what changed between them."""
+    before = [s for s in samples if s["start"] <= start]
+    after = [s for s in samples if s["end"] >= end]
+    out: Dict[str, Any] = {}
+    if before:
+        out["before"] = _sample_state(before[-1])
+    if after:
+        out["after"] = _sample_state(after[0])
+    if before and after:
+        out["difference"] = {k: out["after"][k] - v
+                             for k, v in out["before"].items()
+                             if k in out["after"]}
+    return out
+
+
+def stalls(spans, cadence: str = "train_place_batch",
+           factor: float = 1.5) -> Dict[str, Any]:
+    """What each slow period of a step loop coincided with, from a run's
+    own spans (the dicts of ``<session>/trace/spans.jsonl``).  Pure: no
+    clock, no file, no runtime.
+
+    Per process, the ``cadence`` spans in order of their start give the
+    loop's periods, start to start.  ``processes`` holds each process's
+    count, median and largest period.  ``stalls`` holds every period over
+    ``factor`` x its process's median: its ``step``, ``start`` and
+    ``seconds``; ``overlapping``, the seconds of it that every other span
+    of that process and of the head (the process of ``runtime_init``)
+    covers, summed by name, without the spans that hold the whole loop;
+    and ``samples``, the state of the two ``worker_sample`` spans of the
+    process that bracket it and their differences (CPU seconds spent,
+    switches, faults, pressure, bytes).  The set-up's periods are stalls
+    too, and say so: ``xla_compile`` covers them.
+    """
+    heads = {s["process"] for s in spans if s["name"] == "runtime_init"}
+    by_process: Dict[Any, list] = {}
+    for s in spans:
+        if s["name"] == cadence:
+            by_process.setdefault(s.get("process"), []).append(s)
+    processes, found = [], []
+    for process, beats in sorted(by_process.items(),
+                                 key=lambda kv: str(kv[0])):
+        periods = _periods(beats)
+        if not periods:
+            continue
+        median = statistics.median(p for _a, p in periods)
+        longest = max(periods, key=lambda ap: ap[1])
+        processes.append({"process": process, "periods": len(periods),
+                          "median_s": median, "max_s": longest[1],
+                          "max_step": longest[0].get("step")})
+        slow = [(a, p) for a, p in periods if p > factor * median]
+        if not slow:
+            continue
+        lo, hi = beats[0]["start"], beats[-1]["start"]
+        others = [s for s in spans
+                  if s["name"] not in (cadence, "worker_sample")
+                  and (s.get("process") == process
+                       or s.get("process") in heads)
+                  and not (s["start"] <= lo and s["end"] >= hi)]
+        samples = sorted((s for s in spans if s["name"] == "worker_sample"
+                          and s.get("process") == process),
+                         key=lambda s: s["start"])
+        for beat, seconds in slow:
+            start, end = beat["start"], beat["start"] + seconds
+            found.append({
+                "process": process, "step": beat.get("step"),
+                "start": start, "seconds": seconds, "median_s": median,
+                "overlapping": _covering(others, start, end),
+                "samples": _bracket(samples, start, end)})
+    return {"cadence": cadence, "factor": factor, "processes": processes,
+            "stalls": found}
 
 
 # -- goodput accounting ----------------------------------------------------
