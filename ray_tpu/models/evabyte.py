@@ -122,7 +122,10 @@ def num_params(cfg: EvaByteConfig) -> int:
 
 
 def offset_norm(x, g, eps: float, dt):
-    """``x / rms(x) * (1 + g)`` of the float32 stream, handed on in ``dt``."""
+    """``x / rms(x) * (1 + g)`` of the float32 stream, handed on in ``dt``.
+    On a TPU ``rms_norm`` pins a float32 input row-major, which is what keeps
+    this model's one-row stream from lying sequence-minor through the whole
+    step (``ops/norms.py``; PERF.md, PR 39)."""
     return rms_norm(x, 1.0 + g.astype(jnp.float32), eps).astype(dt)
 
 

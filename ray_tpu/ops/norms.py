@@ -1,19 +1,46 @@
-"""Normalization ops."""
+"""Normalization ops.
+
+``rms_norm`` is XLA's elementwise chain (square, mean, rsqrt, two products,
+a cast), and XLA fuses it well: in a compiled train step the sum of squares
+rides out of the projection before it as that product's epilogue, the scale
+rides into the next one, and where either stands alone it reads its stream
+at 92 % of HBM pace (0.71 ms for 537 MB on a v5e; PERF.md, PR 39).  No
+kernel is needed, and a Pallas pair measured no faster in the step.
+
+What the chain cannot say is how its stream lies in memory.  A float32
+residual stream of ONE long row (EvaByte's ``fp32_skip_add`` at
+[1, 32768, 4096]) is kept by the TPU compiler with the sequence as its minor
+dimension through the whole step, and the step's projections run 3 % slower
+round it (52 ms of a 1,600 ms step).  So on a TPU a float32 input is pinned
+row-major (``with_layout_constraint``): one hint, which turns the stream
+round everywhere it goes.  bf16 streams, which every other model carries,
+are left to the compiler and compile to what they compiled to.  Which way a
+call went is counted in ``ray_tpu_norm_path_total``.
+"""
 
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
+
+from ..util import telemetry
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
-    """RMSNorm in fp32 accumulation, cast back to input dtype.
-
-    Elementwise chain (square, mean, rsqrt, mul) fuses into neighboring
-    matmuls under XLA; no pallas needed at current sizes.
-    """
-    import jax.lax as lax
+    """RMSNorm in fp32 accumulation, cast back to input dtype."""
+    from .attention import _on_tpu          # at the call: tests steer it
     dtype = x.dtype
+    row_major = dtype == jnp.float32 and x.ndim > 1 and _on_tpu()
+    telemetry.inc("ray_tpu_norm_path_total", tags={
+        "path": "row_major" if row_major else "xla",
+        "rows": str(math.prod(x.shape[:-1]))})
     x32 = x.astype(jnp.float32)
+    if row_major:
+        x32 = with_layout_constraint(
+            x32, Layout(major_to_minor=tuple(range(x.ndim))))
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    normed = x32 * lax.rsqrt(var + eps)
+    normed = x32 * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(dtype)

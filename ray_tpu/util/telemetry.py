@@ -424,6 +424,12 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "taken: kernel (rows and heads of a grid step's "
                        "tile) or xla (apply_rope behind a transpose; the "
                        "call's sequence length and heads)."},
+    "ray_tpu_norm_path_total": {
+        "type": "counter", "tag_keys": ("path", "rows"),
+        "description": "Calls of ops/norms.rms_norm traced, by the path "
+                       "taken: row_major (a float32 input on a TPU, its "
+                       "layout pinned) or xla (the layout left to the "
+                       "compiler); rows: the call's rows."},
     "ray_tpu_compile_cache_hits_total": {
         "type": "counter", "tag_keys": (),
         "description": "Programs fetched from the persistent compile "

@@ -415,6 +415,39 @@ def test_remat_chunks_and_kernels_do_not_change_loss_or_gradient(both,
     assert float(ref.relative_distance(grads, want_grads)) < 2e-5
 
 
+@pytest.mark.parametrize("remat", [False, "full"])
+def test_pinning_the_stream_does_not_change_loss_or_gradient(monkeypatch,
+                                                             remat):
+    """Where JAX reports a TPU the float32 stream's norms pin its layout
+    (``ops/norms.py``): the program carries the constraint at each of its
+    three traced norms, forward and backward, and the loss, the heads and
+    the gradient of every leaf are what they are without it, up to the
+    order of a float32 sum (the compiler fuses differently round it)."""
+    import importlib
+    from tests.test_ops import _norm_paths
+    cfg = CFG.replace(remat=remat)
+    w, batch = _weights(), _batch()
+    program = lambda: jax.jit(jax.value_and_grad(   # each traced anew
+        lambda w, b: evabyte.loss_and_report(w, b, cfg), has_aux=True))
+    (want, want_report), want_grads = program()(w, batch)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"),
+                        "_on_tpu", lambda: True)
+    before = _norm_paths()
+    fn = program()
+    assert fn.lower(w, batch).as_text().count("@LayoutConstraint") >= 6
+    (loss, report), grads = fn(w, batch)
+    # two rows of 256; the scan's body is traced once: its two norms and
+    # the final one (the call finds the lowering's trace)
+    assert _norm_paths().get(("row_major", "512"), 0) == \
+        before.get(("row_major", "512"), 0) + 3
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    np.testing.assert_allclose(report["head_loss"], want_report["head_loss"],
+                               rtol=1e-6)
+    for leaf in LEAVES:
+        assert float(ref.relative_distance(
+            _at(grads, leaf), _at(want_grads, leaf))) < 2e-6, leaf
+
+
 @pytest.mark.parametrize("what", ["pipeline", "mlp_only", "positions",
                                   "grouped heads", "ring"])
 def test_what_the_model_does_not_do_is_refused_by_name(what):

@@ -39,6 +39,77 @@ class TestRmsNorm:
         assert rms_norm(x, jnp.ones(16)).dtype == jnp.bfloat16
 
 
+def _counted(name, keys):
+    """A counter of the catalog as {its tags' values under ``keys``: count}."""
+    from ray_tpu.util import metrics
+    _by_name, acc = metrics._aggregate_snapshots()
+    return {tuple(dict(tags)[k] for k in keys): value
+            for tags, value in acc.get(name, {}).values()}
+
+
+def _norm_paths():
+    """ray_tpu_norm_path_total as {(path, rows): count}."""
+    return _counted("ray_tpu_norm_path_total", ("path", "rows"))
+
+
+def _norm_as_it_was(x, w, eps=1e-5):
+    """``rms_norm`` before PR 39: the chain and nothing else."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
+#: (shape, dtype, the platform JAX reports is a TPU, the path taken)
+NORM_CASES = [
+    ((1, 256, 128), jnp.float32, True, "row_major"),    # EvaByte's: one row
+    ((2, 96, 192), jnp.float32, True, "row_major"),     # any width and rows
+    ((64, 128), jnp.float32, True, "row_major"),        # a decode step's
+    ((2, 128, 256), jnp.bfloat16, True, "xla"),     # every other model's
+    ((4, 16, 64, 128), jnp.bfloat16, True, "xla"),      # a q / k norm
+    ((2, 128, 256), jnp.float32, False, "xla"),         # no TPU
+    ((128,), jnp.float32, True, "xla"),                 # nothing to lay out
+]
+
+
+@pytest.mark.parametrize("shape,dtype,on_tpu,path", NORM_CASES)
+def test_norm_pins_a_float32_input_row_major_on_a_tpu(monkeypatch, shape,
+                                                      dtype, on_tpu, path):
+    """The dtype and the backend decide: a float32 input on a TPU carries a
+    layout constraint into the program, every other call lowers to the
+    text it lowered to; the values and both gradients are the chain's, bit
+    for bit, either way; the counter says which way the call went."""
+    monkeypatch.setattr(
+        importlib.import_module("ray_tpu.ops.attention"), "_on_tpu",
+        lambda: on_tpu)
+    ks = jax.random.split(jax.random.key(len(shape)), 3)
+    x = (3.0 * jax.random.normal(ks[0], shape, jnp.float32)).astype(dtype)
+    w = 1.0 + 0.2 * jax.random.normal(ks[1], shape[-1:], jnp.float32)
+    dy = jax.random.normal(ks[2], shape, jnp.float32).astype(dtype)
+    rows = str(int(np.prod(shape[:-1])))
+    before = _norm_paths()
+    got, vjp = jax.vjp(lambda x, w: rms_norm(x, w, 1e-5), x, w)
+    assert _norm_paths().get((path, rows), 0) == \
+        before.get((path, rows), 0) + 1
+    want, vjp0 = jax.vjp(_norm_as_it_was, x, w)
+    for a, b in zip((got, *vjp(dy)), (want, *vjp0(dy))):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+    def program(x, w):
+        return rms_norm(x, w, 1e-5)
+
+    text = jax.jit(program).lower(x, w).as_text()
+    if path == "row_major":
+        minor_to_major = list(range(len(shape)))[::-1]
+        assert text.count("@LayoutConstraint") == 1
+        assert f"result_layouts = [dense<{minor_to_major}>" in text, text
+    else:
+        assert text == jax.jit(_norm_as_it_was).lower(x, w).as_text().replace(
+            "_norm_as_it_was", "program")
+
+
 class TestRope:
     def test_norm_preserved(self):
         cos, sin = rope_frequencies(32, 128)
@@ -62,11 +133,7 @@ class TestRope:
 
 def _rope_paths():
     """ray_tpu_rope_path_total as {(path, rows, heads): count}."""
-    from ray_tpu.util import metrics
-    _by_name, acc = metrics._aggregate_snapshots()
-    return {tuple(dict(tags)[k] for k in ("path", "rows", "heads")): value
-            for tags, value in acc.get("ray_tpu_rope_path_total",
-                                       {}).values()}
+    return _counted("ray_tpu_rope_path_total", ("path", "rows", "heads"))
 
 
 def _rotate_both_ways(x, g, positions=None, theta=1e6, **kw):

@@ -25,7 +25,7 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope", "eva")
+              "flash", "rope", "eva", "norm")
 
 
 class TestCatalog:

@@ -646,6 +646,12 @@ def test_evabyte_train_step_compiles_at_the_cell_sizes(evabyte_step, capsys):
                    "eva_dsum_w2048c16", "eva_pool_fwd_c16",
                    "eva_pool_bwd_c16", "rope_to_heads", "rope_from_heads"):
         assert any(kernel in c for c in calls), (kernel, calls)
+    # The float32 stream is row-major from the embedding to the heads:
+    # ``rms_norm`` pins it (PR 39), where the compiler alone kept the
+    # sequence minor (144 ``{1,2,0}`` in the parent's text) and the
+    # projections ran 3 % slower round it.
+    assert "f32[1,32768,4096]{1,2,0" not in text
+    assert text.count("f32[1,32768,4096]{2,1,0") > 100
     from benchmark import scopes
     by = {"scopes": {scopes.scope_path(name): 1.0
                      for name in scopes.op_names(text).values()}}
