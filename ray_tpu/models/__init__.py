@@ -5,11 +5,12 @@ from .llama import (LlamaConfig, init_params, forward, loss_fn,
                     llama_7b)
 from .afmoe import AfmoeConfig
 from .evabyte import EvaByteConfig, evabyte_tiny
+from .xing4 import Xing4Config, xing4_tiny
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
     "LlamaConfig", "init_params", "forward", "loss_fn", "param_logical_axes",
     "llama_tiny", "llama_125m", "llama_1b", "llama_7b", "AfmoeConfig",
-    "EvaByteConfig", "evabyte_tiny",
+    "EvaByteConfig", "evabyte_tiny", "Xing4Config", "xing4_tiny",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

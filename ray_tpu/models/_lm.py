@@ -14,20 +14,23 @@ import jax.numpy as jnp
 
 
 def is_shape(x) -> bool:
-    """A leaf of a model's ``param_shapes``: (shape, fan-in)."""
+    """A leaf of a model's ``param_shapes``: (shape, fan-in[, start])."""
     return isinstance(x, tuple) and isinstance(x[1], int)
 
 
 def init_from_shapes(shapes, key: jax.Array, param_dtype) -> Dict[str, Any]:
-    """Parameters for a tree of (shape, fan-in): truncated normal /
-    sqrt(fan-in); fan-in 0 marks a weight that starts at a constant, a
-    norm's weight at one and a scalar (a bias) at zero."""
+    """Parameters for a tree of (shape, fan-in[, start]): truncated normal /
+    sqrt(fan-in); fan-in 0 marks a weight that starts at a constant:
+    ``start`` (a number, or an array that broadcasts to the shape) where
+    given, else a norm's weight at one and a scalar (a bias) at zero."""
     leaves, treedef = jax.tree.flatten(shapes, is_leaf=is_shape)
     keys = jax.random.split(key, len(leaves))
     out = []
-    for k, (shape, fan_in) in zip(keys, leaves):
+    for k, (shape, fan_in, *start) in zip(keys, leaves):
         if fan_in == 0:
-            out.append(jnp.full(shape, 1.0 if shape else 0.0, param_dtype))
+            value = start[0] if start else (1.0 if shape else 0.0)
+            out.append(jnp.broadcast_to(
+                jnp.asarray(value, param_dtype), shape))
         else:
             out.append((jax.random.truncated_normal(
                 k, -2, 2, shape, jnp.float32)
@@ -36,7 +39,7 @@ def init_from_shapes(shapes, key: jax.Array, param_dtype) -> Dict[str, Any]:
 
 
 def count_params(shapes) -> int:
-    return sum(math.prod(shape) for shape, _ in jax.tree.leaves(
+    return sum(math.prod(leaf[0]) for leaf in jax.tree.leaves(
         shapes, is_leaf=is_shape))
 
 

@@ -1,0 +1,489 @@
+"""The ``xing4_0`` decoder (Xing4.0-29B-A4B): latent attention, a residual
+stream of several lanes mixed by hyper-connections, sigmoid-routed dropless
+experts beside a shared one, and a module that predicts a second token.
+
+What it has that no other model here has:
+
+- **Latent attention** (DeepSeek-V3's): queries through a ``q_lora_rank``
+  bottleneck with an RMSNorm, keys and values through a ``kv_lora_rank``
+  one; a head's query and key are ``qk_nope_head_dim`` channels without
+  position and ``qk_rope_head_dim`` rotary ones, the rotary key one head
+  for all query heads; values are ``v_head_dim`` wide.  The flash kernels
+  take q and k at 192 and v at 128 (``ops/attention.py``).  Rotary
+  frequencies are yarn's (``ops/rope.Yarn``), and the softmax scale carries
+  its ``mscale ** 2``.
+- **A stream of ``hc_mult`` lanes** [B, n, S, C]: every sublayer reads a
+  weighted sum of the lanes and writes back through a doubly stochastic
+  lane-to-lane map (``ops/hyper.py``).  With ``hc_mult`` 1 no map is made
+  and a layer is the pre-norm ``x + F(N(x))``.
+- **A prediction module** (``num_nextn_predict_layers`` 1, DeepSeek-V3's
+  form): the final hidden state of position t and the embedding of token
+  t + 1, each normed, through one projection, one expert layer of its own
+  and the model's head, against token t + 2; its loss joins the main one
+  with weight ``mtp_loss_weight``.
+
+The expert layer is ``models/afmoe.py``'s (``_moe``: ``ops/moe.py``'s sigmoid
+router, dropless held experts, the selection bias as state), as it is; a
+layer may hold a share of its experts.  The stack: dense layers unrolled,
+the expert layers one ``lax.scan``, each layer under the remat
+``layer_rows`` rows at a time.  The selection bias of the module's layer is
+the last row of the state's ``bias``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import _lm, afmoe
+from ..ops import hyper
+from ..ops.attention import attention as _attention
+from ..ops.norms import rms_norm
+from ..ops.rope import Yarn, rope_lane_tables, rotate_heads
+from .afmoe import _moe, _swiglu
+
+#: the two sublayers of a layer, as their weights' names say them
+SUBLAYERS = ("attn", "mlp")
+
+
+@dataclass(frozen=True)
+class Xing4Config:
+    """Defaults are Xing4.0-29B-A4B's published ``config.json``."""
+    vocab_size: int = 131072
+    hidden: int = 3584
+    layers: int = 40
+    heads: int = 32
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mlp_dim: int = 9216                 # the dense layers' SwiGLU
+    moe_mlp_dim: int = 1024             # every expert's, and the shared one's
+    num_experts: int = 64               # the router's width
+    experts_held: Optional[int] = None  # None = all of them
+    held_start: int = 0
+    top_k: int = 4
+    num_shared_experts: int = 1
+    num_dense_layers: int = 2           # ``first_k_dense_replace``
+    route_scale: float = 2.0            # ``routed_scaling_factor``
+    route_norm: bool = True             # ``norm_topk_prob``
+    bias_update_rate: float = 1e-3
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    mtp_layers: int = 1                 # ``num_nextn_predict_layers``
+    mtp_loss_weight: float = 0.3
+    rope_theta: float = 10000.0
+    yarn: Optional[Yarn] = Yarn(factor=64.0,
+                                original_max_position_embeddings=4096,
+                                beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                                mscale_all_dim=1.0)
+    norm_eps: float = 1e-6
+    max_seq_len: int = 8192             # the rotary tables' rows
+    dtype: Any = jnp.bfloat16
+    # "auto" (flash on TPU / reference on CPU), "reference", "flash",
+    # "flash_interpret"
+    attention_impl: str = "auto"
+    moe_impl: Optional[str] = None      # ops/moe.grouped_matmul
+    remat: Any = True                   # _lm.remat
+    layer_rows: Optional[int] = None    # as AfmoeConfig's
+    loss_chunks: int = 0
+    pp_microbatches: int = 0            # refused: see _refuse_a_mesh
+
+    def replace(self, **kw) -> "Xing4Config":
+        return dataclasses.replace(self, **kw)
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layers - self.num_dense_layers
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5 * (
+            self.yarn.softmax_scale if self.yarn else 1.0)
+
+    @property
+    def hc_width(self) -> int:
+        """Numbers a sublayer's maps take a token: 2 n + n * n."""
+        return 2 * self.hc_mult + self.hc_mult ** 2
+
+
+def xing4_tiny(**kw) -> Xing4Config:
+    """A CPU-test size that keeps what the kernels must tell apart: head
+    sizes 192 / 128 (128 + 64 rotary), four lanes, 8 experts with 4 a
+    token, 1 dense + 2 expert layers and the prediction module."""
+    return Xing4Config(**{**dict(
+        vocab_size=256, hidden=64, layers=3, heads=2, q_lora_rank=48,
+        kv_lora_rank=32, mlp_dim=96, moe_mlp_dim=32, num_experts=8, top_k=4,
+        num_dense_layers=1, max_seq_len=64, dtype=jnp.float32,
+        attention_impl="reference", remat=False), **kw})
+
+
+# ------------------------------------------------------------- parameters
+
+def _layer_axes(cfg: Xing4Config) -> Dict[str, Any]:
+    axes = {
+        "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+        "q_norm": ("layers", None), "kv_norm": ("layers", None),
+        "wq_a": ("layers", "embed", None),
+        "wq_b": ("layers", None, "heads", "head_dim"),
+        "wkv_a": ("layers", "embed", None),
+        "wkv_b": ("layers", None, "heads", "head_dim"),
+        "wo": ("layers", "heads", "head_dim", "embed")}
+    if cfg.hc_mult > 1:
+        for s in SUBLAYERS:
+            axes |= {f"hc_{s}_phi": ("layers", None, None),
+                     f"hc_{s}_b": ("layers", None),
+                     f"hc_{s}_alpha": ("layers", None)}
+    return axes
+
+
+def _moe_axes(cfg: Xing4Config) -> Dict[str, Any]:
+    return {**_layer_axes(cfg),
+            "router": ("layers", "embed", None),
+            "shared_gate": ("layers", "embed", "mlp"),
+            "shared_up": ("layers", "embed", "mlp"),
+            "shared_down": ("layers", "mlp", "embed"),
+            "w_gate": ("layers", "expert", "embed", "mlp"),
+            "w_up": ("layers", "expert", "embed", "mlp"),
+            "w_down": ("layers", "expert", "mlp", "embed")}
+
+
+def param_logical_axes(cfg: Xing4Config) -> Dict[str, Any]:
+    """Pytree (matching init_params) of logical axis tuples."""
+    axes = {
+        "embed": ("vocab", "embed"),
+        "dense": {**_layer_axes(cfg),
+                  "w_gate": ("layers", "embed", "mlp"),
+                  "w_up": ("layers", "embed", "mlp"),
+                  "w_down": ("layers", "mlp", "embed")},
+        "moe": _moe_axes(cfg),
+        "final_norm": (None,),
+        "lm_head": ("embed", "vocab")}
+    if cfg.mtp_layers:
+        axes["mtp"] = {"h_norm": (None,), "e_norm": (None,),
+                       "proj": (None, "embed"), "final_norm": (None,),
+                       "layer": _moe_axes(cfg)}
+    return axes
+
+
+def _hc_start(cfg: Xing4Config):
+    """What a sublayer's ``b`` starts at: H_pre = 1/2 and H_post = 1 in every
+    lane, H_res near the identity (4 on the diagonal of its logits)."""
+    n = cfg.hc_mult
+    return np.concatenate([np.zeros(2 * n), 4.0 * np.eye(n).reshape(-1)]
+                          ).astype(np.float32)
+
+
+def param_shapes(cfg: Xing4Config) -> Dict[str, Any]:
+    """leaf -> (shape, fan-in[, start]; fan-in 0 marks a weight that starts
+    at a constant: a norm's at one, a hyper-connection's gains at 0.01 and
+    its ``b`` at ``_hc_start``)."""
+    E, H, V, n = cfg.hidden, cfg.heads, cfg.vocab_size, cfg.hc_mult
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    def layer(L):
+        shapes = {
+            "attn_norm": ((L, E), 0), "mlp_norm": ((L, E), 0),
+            "q_norm": ((L, rq), 0), "kv_norm": ((L, rkv), 0),
+            "wq_a": ((L, E, rq), E), "wq_b": ((L, rq, H, dn + dr), rq),
+            "wkv_a": ((L, E, rkv + dr), E),
+            "wkv_b": ((L, rkv, H, dn + dv), rkv),
+            "wo": ((L, H, dv, E), H * dv)}
+        if n > 1:
+            for s in SUBLAYERS:
+                shapes |= {
+                    f"hc_{s}_phi": ((L, n * E, cfg.hc_width), n * E),
+                    f"hc_{s}_b": ((L, cfg.hc_width), 0, _hc_start(cfg)),
+                    f"hc_{s}_alpha": ((L, 3), 0, 0.01)}
+        return shapes
+
+    M, Me, X, Xh = cfg.mlp_dim, cfg.moe_mlp_dim, cfg.num_experts, cfg.held
+    Ms = Me * cfg.num_shared_experts
+
+    def moe_layer(L):
+        return {**layer(L), "router": ((L, E, X), E),
+                "shared_gate": ((L, E, Ms), E), "shared_up": ((L, E, Ms), E),
+                "shared_down": ((L, Ms, E), Ms),
+                "w_gate": ((L, Xh, E, Me), E), "w_up": ((L, Xh, E, Me), E),
+                "w_down": ((L, Xh, Me, E), Me)}
+
+    Ld = cfg.num_dense_layers
+    shapes = {
+        "embed": ((V, E), E),
+        "dense": {**layer(Ld), "w_gate": ((Ld, E, M), E),
+                  "w_up": ((Ld, E, M), E), "w_down": ((Ld, M, E), M)},
+        "moe": moe_layer(cfg.expert_layers),
+        "final_norm": ((E,), 0),
+        "lm_head": ((E, V), E)}
+    if cfg.mtp_layers:
+        shapes["mtp"] = {"h_norm": ((E,), 0), "e_norm": ((E,), 0),
+                         "proj": ((2 * E, E), 2 * E), "final_norm": ((E,), 0),
+                         "layer": moe_layer(cfg.mtp_layers)}
+    return shapes
+
+
+def init_params(cfg: Xing4Config, key: jax.Array,
+                param_dtype=jnp.float32) -> Dict[str, Any]:
+    return _lm.init_from_shapes(param_shapes(cfg), key, param_dtype)
+
+
+def num_params(cfg: Xing4Config) -> int:
+    return _lm.count_params(param_shapes(cfg))
+
+
+def init_state(cfg: Xing4Config) -> Dict[str, jax.Array]:
+    """The routers' selection bias, float32 [expert layers + the prediction
+    module's, experts]: state, which no optimizer touches."""
+    return {"bias": jnp.zeros((cfg.expert_layers + cfg.mtp_layers,
+                               cfg.num_experts), jnp.float32)}
+
+
+# ------------------------------------------------------------------ layers
+
+@jax.named_scope("block/attn")
+def _mla(cfg: Xing4Config, cos, sin, h, layer):
+    """Latent attention of h [B, S, E] -> [B, S, E]."""
+    dt, eps = cfg.dtype, cfg.norm_eps
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
+    with jax.named_scope("mla"):
+        c_q = rms_norm(jnp.einsum("bse,er->bsr", h, layer["wq_a"].astype(dt),
+                                  preferred_element_type=dt),
+                       layer["q_norm"], eps)
+        q = _lm.project_heads(c_q, layer["wq_b"], dt)       # [B, S, H, 192]
+        kv_a = jnp.einsum("bse,er->bsr", h, layer["wkv_a"].astype(dt),
+                          preferred_element_type=dt)
+        c_kv = rms_norm(kv_a[..., :rkv], layer["kv_norm"], eps)
+        kv = _lm.project_heads(c_kv, layer["wkv_b"], dt)    # [B, S, H, 256]
+    with jax.named_scope("rope"):
+        rope = lambda x: rotate_heads(x, cos, sin,
+                                      interpret=impl == "flash_interpret")
+        k_r = rope(kv_a[..., None, rkv:])                   # [B, 1, S, 64]
+        kv = jnp.swapaxes(kv, 1, 2)                         # [B, H, S, 256]
+        q = jnp.concatenate([jnp.swapaxes(q[..., :dn], 1, 2),
+                             rope(q[..., dn:])], axis=-1)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            k_r, kv.shape[:3] + k_r.shape[3:])], axis=-1)
+    o = _attention(q, k, kv[..., dn:], causal=True, impl=impl,
+                   scale=cfg.softmax_scale)                 # [B, H, S, 128]
+    with jax.named_scope("mla"):
+        return jnp.einsum("bhsd,hde->bse", o, layer["wo"].astype(dt),
+                          preferred_element_type=dt)
+
+
+def _sublayer(cfg: Xing4Config, X, layer, name: str, F):
+    """``X <- H_res X + H_post (x) F(N(sum_j H_pre[j] X[j]))`` on the stream
+    X [B, n, S, C]; ``F`` returns (y, what it reports).  -> (X, the report,
+    the largest |row or column sum - 1| of H_res)."""
+    norm = layer[f"{name}_norm"]
+    if cfg.hc_mult == 1:
+        y, aux = F(rms_norm(X[:, 0], norm, cfg.norm_eps))
+        return X + y[:, None], aux, jnp.zeros((), jnp.float32)
+    H_pre, H_post, H_res = hyper.hc_maps(
+        X, layer[f"hc_{name}_phi"], layer[f"hc_{name}_b"],
+        layer[f"hc_{name}_alpha"], cfg.hc_sinkhorn_iters, cfg.hc_eps,
+        cfg.hc_clamp, cfg.norm_eps)
+    y, aux = F(rms_norm(hyper.hc_collect(X, H_pre), norm, cfg.norm_eps))
+    return (hyper.hc_deposit(X, H_res, H_post, y), aux,
+            jax.lax.stop_gradient(hyper.sinkhorn_residual(H_res)))
+
+
+def _layer(cfg: Xing4Config, cos, sin, X, layer, bias=None):
+    """One layer on the stream; ``bias`` is None for a dense layer.  ->
+    (X, {"hc_residual", and an expert layer's loads as ``afmoe._moe``'s})."""
+    X, _, r_attn = _sublayer(
+        cfg, X, layer, "attn", lambda h: (_mla(cfg, cos, sin, h, layer), None))
+
+    def feed_forward(h):
+        if bias is not None:
+            return _moe(cfg, h, layer, bias)
+        with jax.named_scope("block/mlp"):
+            return _swiglu(h, layer["w_gate"], layer["w_up"],
+                           layer["w_down"], cfg.dtype), {}
+
+    X, loads, r_mlp = _sublayer(cfg, X, layer, "mlp", feed_forward)
+    return X, {**loads, "hc_residual": jnp.maximum(r_attn, r_mlp)}
+
+
+def _run(cfg: Xing4Config, cos, sin, X, layer, bias=None):
+    """The layer under the remat, ``layer_rows`` rows at a time (as
+    ``afmoe``'s): (X, the layer's report summed over its groups)."""
+    one = _lm.remat(lambda X, layer, bias: _layer(cfg, cos, sin, X, layer,
+                                                  bias), cfg.remat)
+    B = X.shape[0]
+    n = min(cfg.layer_rows or B, B)
+    if B % n:
+        raise ValueError(f"a batch of {B} rows does not split into groups "
+                         f"of layer_rows={n}")
+    if n == B:
+        return one(X, layer, bias)
+    Y, report = jax.lax.map(lambda rows: one(rows, layer, bias),
+                            X.reshape((B // n, n) + X.shape[1:]))
+    merged = {"hc_residual": jnp.max(report["hc_residual"])}
+    if bias is not None:
+        merged |= {"counts": jnp.sum(report["counts"], axis=0),
+                   "dropped": jnp.sum(report["dropped"]),
+                   "sliced": jnp.sum(report["sliced"]),
+                   "top": report["top"].reshape(-1, cfg.top_k)}
+    return Y.reshape(X.shape), merged
+
+
+def _refuse_a_mesh(cfg: Xing4Config) -> None:
+    from ..parallel.mesh import get_global_mesh
+    mesh = get_global_mesh()
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "xing4 on a mesh: the exchange of an expert-parallel group is "
+            "not built (ROADMAP M3)")
+    if cfg.pp_microbatches:
+        raise NotImplementedError(
+            "xing4 with pp_microbatches: a pipeline stage hands on one lane, "
+            "not hc_mult (ROADMAP M4)")
+
+
+def _lanes(x, n: int):
+    """x [B, S, C] in every one of n lanes: [B, n, S, C]."""
+    return jnp.broadcast_to(x[:, None], (x.shape[0], n) + x.shape[1:])
+
+
+def _collapse(X):
+    """The lanes' sum, [B, S, C] in the stream's dtype."""
+    return jnp.sum(X.astype(jnp.float32), axis=1).astype(X.dtype)
+
+
+def _forward_hidden(params, state, tokens, cfg: Xing4Config):
+    """tokens [B, S] -> (the lanes' sum after the last layer [B, S, C],
+    before the final norm; the expert layers' loads {"counts" [Lm, X],
+    "dropped" [Lm], "sliced" [Lm], "top" [Lm, B*S, k]}; the largest
+    Sinkhorn residual of the stack; the rotary tables)."""
+    _refuse_a_mesh(cfg)
+    dt = cfg.dtype
+    with jax.named_scope("embed"):
+        X = _lanes(params["embed"].astype(dt)[tokens], cfg.hc_mult)
+    cos, sin = rope_lane_tables(cfg.qk_rope_head_dim, cfg.max_seq_len,
+                                cfg.rope_theta, cfg.yarn)
+    residual = jnp.zeros((), jnp.float32)
+    for i in range(cfg.num_dense_layers):
+        X, report = _run(cfg, cos, sin, X,
+                         jax.tree.map(lambda a: a[i], params["dense"]))
+        residual = jnp.maximum(residual, report["hc_residual"])
+
+    def body(X, group):
+        return _run(cfg, cos, sin, X, group["layer"], group["bias"])
+
+    Lm = cfg.expert_layers
+    if Lm:
+        X, loads = jax.lax.scan(body, X, {"layer": params["moe"],
+                                          "bias": state["bias"][:Lm]})
+        residual = jnp.maximum(residual, jnp.max(loads.pop("hc_residual")))
+    else:
+        loads = {"counts": jnp.zeros((0, cfg.num_experts), jnp.int32),
+                 "dropped": jnp.zeros((0,), jnp.int32),
+                 "sliced": jnp.zeros((0,), jnp.int32),
+                 "top": jnp.zeros((0, tokens.size, cfg.top_k), jnp.int32)}
+    return _collapse(X), loads, residual, (cos, sin)
+
+
+def forward(params, tokens, cfg: Xing4Config, state=None) -> jax.Array:
+    """tokens [B, S] -> next-token logits [B, S, V] float32 (the prediction
+    module is a training part: its serving use is ROADMAP's)."""
+    x, *_ = _forward_hidden(params, state or init_state(cfg), tokens, cfg)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def mtp_targets_and_mask(targets, mask):
+    """The prediction module's targets and mask from the main loss's
+    (``_lm.targets_and_mask``): position t is judged on token t + 2 where
+    the main loss judges position t + 1, so over t <= S - 3 under a mask
+    that covers every position but a row's last."""
+    shift = lambda a: jnp.concatenate(
+        [a[:, 1:], jnp.zeros_like(a[:, :1])], axis=1)
+    return shift(targets), shift(mask)
+
+
+def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg: Xing4Config):
+    """(the module's masked mean loss, its layer's report): ``x_out``
+    [B, S, C] is the stack's result before the final norm."""
+    dt, eps, p = cfg.dtype, cfg.norm_eps, params["mtp"]
+    with jax.named_scope("mtp"):
+        with jax.named_scope("project"):
+            # ``targets`` holds token t + 1 at position t.
+            pair = jnp.concatenate(
+                [rms_norm(x_out, p["h_norm"], eps),
+                 rms_norm(params["embed"].astype(dt)[targets], p["e_norm"],
+                          eps)], axis=-1)
+            z = jnp.einsum("bsf,fe->bse", pair, p["proj"].astype(dt),
+                           preferred_element_type=dt)
+        Z, report = _run(cfg, *tables, _lanes(z, cfg.hc_mult),
+                         jax.tree.map(lambda a: a[0], p["layer"]), bias)
+        h = rms_norm(_collapse(Z), p["final_norm"], eps)
+        targets2, mask2 = mtp_targets_and_mask(targets, mask)
+        with jax.named_scope("loss"):
+            total = _lm.token_nll(h, params["lm_head"], targets2,
+                                  cfg.loss_chunks, dt, mask2)
+        return total / jnp.maximum(jnp.sum(mask2), 1.0), report
+
+
+def loss_and_report(params, batch, cfg: Xing4Config, state=None):
+    """What the train step differentiates (parallel.spmd): the loss ``main +
+    mtp_loss_weight * module's``, and what ``update_state`` turns into the
+    step's metrics: both losses, the expert layers' loads (the module's
+    layer last), the largest Sinkhorn residual."""
+    state = state or init_state(cfg)
+    x, loads, residual, tables = _forward_hidden(params, state,
+                                                 batch["tokens"], cfg)
+    targets, mask, denom = _lm.targets_and_mask(batch)
+    with jax.named_scope("final_norm"):
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("loss"):
+        main = _lm.token_nll(h, params["lm_head"], targets, cfg.loss_chunks,
+                             cfg.dtype, mask) / denom
+    loss, mtp = main, jnp.zeros((), jnp.float32)
+    if cfg.mtp_layers:
+        # Traced on its own, so that the scope ``mtp`` stays a scope in the
+        # backward's operations too (models/ouro._scoped has the reason).
+        mtp, report = jax.jit(
+            lambda params, bias, x, tables, targets, mask: _mtp_loss(
+                params, bias, x, tables, targets, mask, cfg))(
+            params, state["bias"][-1], x, tables, targets, mask)
+        residual = jnp.maximum(residual, report.pop("hc_residual"))
+        loads = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),
+                             loads, report)
+        loss = main + cfg.mtp_loss_weight * mtp
+    return loss, jax.lax.stop_gradient(
+        {**loads, "main_loss": main, "mtp_loss": mtp,
+         "hc_residual": residual})
+
+
+def loss_fn(params, batch, cfg: Xing4Config, state=None) -> jax.Array:
+    return loss_and_report(params, batch, cfg, state)[0]
+
+
+def update_state(state, report, cfg: Xing4Config):
+    """(the state after a step with this report, the step's metrics):
+    ``afmoe``'s metrics of the loads over the expert layers and the
+    module's, ``main_loss``, ``mtp_loss`` and ``hc_sinkhorn_residual``."""
+    state, metrics = afmoe.update_state(state, report, cfg)
+    return state, {**metrics, "main_loss": report["main_loss"],
+                   "mtp_loss": report["mtp_loss"],
+                   "hc_sinkhorn_residual": report["hc_residual"]}

@@ -26,6 +26,15 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   group to stack (and no window) the blocks are 1,024 x 1,024.  Anything
   else keeps 512 x 512 and a head a row.  Each traced kernel counts its
   geometry in ``ray_tpu_flash_step_geometry_total``.
+- **q / k and v / o may have head sizes of their own** (latent attention:
+  scores over 192 = 128 without position + 64 rotary, values of 128).  The
+  scores, dq and dk are formed over q's head size; the forward's
+  accumulator, ``o``, ``do``, ``dv`` and delta over v's.  What crosses HBM
+  is as wide as the call's operands, never padded: a 192-wide block is one
+  VMEM tile of a lane group and a half, and the MXU takes its contraction
+  as it is.  Such a call carries both sizes in its kernels' names
+  (``flash_fwd_d192v128``) and in the geometry counter's tags; with equal
+  sizes names and tags are as they were.
 - The grid is ("parallel", "arbitrary"): only the grid rows split across
   the cores of a two-core chip (v4, v5p): B * H of them without a group,
   B * Hkv with one, so a call with one or two key heads a device no longer
@@ -86,7 +95,9 @@ LANES = 128
 def reference_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         q_offset: int = 0, window: Optional[int] = None):
-    """Plain-jnp attention. q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D].
+    """Plain-jnp attention. q: [B, H, Sq, D]; k: [B, Hkv, Sk, D];
+    v: [B, Hkv, Sk, Dv] (``Dv`` may differ from ``D``; the default scale is
+    ``D ** -0.5``).  Returns [B, H, Sq, Dv].
 
     ``q_offset`` shifts query positions for causal masking (used by
     sequence-sharded callers where the local Q block starts mid-sequence).
@@ -242,7 +253,8 @@ _FWD_SCORES = 2 ** 20   # the forward's score tile, elements: halves block_k
 
 def _tiles(kind, Sq, Sk, D, group, window=None):
     """The geometry of a grid step of kernel ``kind`` (``fwd``, ``dq``,
-    ``dkv``), from the shapes of the call alone.
+    ``dkv``), from the shapes of the call alone; ``D`` is the larger of the
+    call's two head sizes.
 
     - A key head's query heads share one step (the most that divide the
       group, up to 8): forward and dq stack their rows behind each tile of
@@ -253,8 +265,11 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
       on the diagonal (40 units of 512 x 512 for 36 at 4,096 tokens); under
       a window they waste at both edges of the band and lose.
     - dk/dv always forms its scores transposed (``kq``).
-    - Anything else (a head dim over 128, a length the larger blocks do
-      not divide) keeps 512 x 512 and a head a step."""
+    - Anything else (a head size over 128, as latent attention's 192 / 128;
+      a length the larger blocks do not divide) keeps 512 x 512 and a head
+      a step.  At 192 / 128 alone the three kernels are 5 % faster at
+      1,024 x 1,024 (25.97 ms for 27.41 at [1, 32, 8192]); the train step
+      that held them did not return from its first call (PERF.md, PR 40)."""
     scores = "kq" if kind == "dkv" else "qk"
     block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
     if D > LANES:
@@ -271,23 +286,25 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
     return Tiles(block_q, block_k, heads, scores)
 
 
-def _geometry(kind, q, k, block_q, block_k, window):
+def _geometry(kind, q, k, v, block_q, block_k, window):
     """``_tiles``' answer for this call, an explicit block size winning,
     checked against the lengths and counted."""
     _, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
+    Dv = v.shape[-1]
     if H % Hkv:
         raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
-    t = _tiles(kind, Sq, Sk, D, H // Hkv, window)
+    t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window)
     t = t._replace(block_q=min(block_q or t.block_q, Sq),
                    block_k=min(block_k or t.block_k, Sk))
     if Sq % t.block_q or Sk % t.block_k:
         raise ValueError(f"seq ({Sq},{Sk}) not divisible by blocks "
                          f"({t.block_q},{t.block_k})")
     telemetry.inc("ray_tpu_flash_step_geometry_total", tags={
-        "kernel": _kernel_name(f"flash_{kind}", window),
+        "kernel": _kernel_name(f"flash_{kind}", window, D, Dv),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
-        "heads_a_step": str(t.heads), "scores": t.scores})
+        "heads_a_step": str(t.heads), "scores": t.scores,
+        **({} if D == Dv else {"d_qk": str(D), "d_v": str(Dv)})})
     return t
 
 
@@ -352,9 +369,13 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 lse_ref[0, h] = lse[h * block_q:(h + 1) * block_q].T[:1]
 
 
-def _kernel_name(base, window):
+def _kernel_name(base, window, D=None, Dv=None):
     """A windowed call carries its window in its name, so that a device
-    trace tells it from a full-causal call (``flash_fwd_w2048``)."""
+    trace tells it from a full-causal call (``flash_fwd_w2048``), and a
+    call whose values are not as wide as its keys both head sizes
+    (``flash_fwd_d192v128``)."""
+    if D != Dv:
+        base = f"{base}_d{D}v{Dv}"
     return base if window is None else f"{base}_w{window}"
 
 
@@ -365,23 +386,24 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
 
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
+    Dv = v.shape[-1]
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
-    t = _geometry("fwd", q, k, block_q, block_k, window)
+    t = _geometry("fwd", q, k, v, block_q, block_k, window)
     sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset, causal,
                              "q", window)
     n, rows = B * H // t.heads, t.heads * t.block_q
 
-    q_spec, kv_spec, row_spec, _ = _specs(t, H // Hkv, D)
+    sp = _specs(t, H // Hkv, D, Dv)
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
         block_k=t.block_k, q_offset=q_offset, window=window)
 
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((n, t.heads, Sq, D), q.dtype)]
+    out_specs = [sp.o]
+    out_shape = [jax.ShapeDtypeStruct((n, t.heads, Sq, Dv), q.dtype)]
     if need_lse:
-        out_specs.append(row_spec)
+        out_specs.append(sp.row)
         out_shape.append(
             jax.ShapeDtypeStruct((n, t.heads, 1, Sq), jnp.float32))
     else:
@@ -396,20 +418,20 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n, sched.size),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[sp.q, sp.k, sp.v],
             out_specs=out_specs,
             scratch_shapes=[
                 _vmem((rows, LANES), jnp.float32),
                 _vmem((rows, LANES), jnp.float32),
-                _vmem((rows, D), jnp.float32),
+                _vmem((rows, Dv), jnp.float32),
             ]),
         out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window),
+        name=_kernel_name("flash_fwd", window, D, Dv),
         **_compiler_params(interpret, rows, t.block_k),
     )(sched, q.reshape(n, t.heads, Sq, D), k.reshape(B * Hkv, Sk, D),
-      v.reshape(B * Hkv, Sk, D))
-    out = res[0].reshape(B, H, Sq, D)
+      v.reshape(B * Hkv, Sk, Dv))
+    out = res[0].reshape(B, H, Sq, Dv)
     if not need_lse:
         return out, None
     return out, res[1].reshape(B, H, Sq)
@@ -420,12 +442,20 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _specs(t, group, D):
+class Specs(NamedTuple):
+    q: object       # [n, heads, S, D] blocks by q block (q, dq)
+    o: object       # the same at the values' head size (o, do)
+    k: object       # [B*Hkv, S, D] blocks by k block
+    v: object       # the same at the values' head size
+    row: object     # rows along the lanes of [n, heads, 1, S] (LSE, delta)
+    dk: object      # [n, S, D] blocks by k block, a grid row's own
+    dv: object      # the same at the values' head size
+
+
+def _specs(t, group, D, Dv):
     """Block specs of a grid (n, steps) at geometry ``t``, the blocks coming
     from the prefetched schedule; a grid row is ``t.heads`` query heads of
-    one key head.  q-shaped blocks of [n, heads, S, D]; k/v blocks of
-    [B*Hkv, S, D]; rows along the lanes of [n, heads, 1, S] (LSE, delta);
-    dk/dv blocks of [n, S, D]."""
+    one key head.  ``D`` is q's and k's head size, ``Dv`` v's and o's."""
     from jax.experimental import pallas as pl
 
     def q_index(r, s, sched):
@@ -440,10 +470,14 @@ def _specs(t, group, D):
     def dkv_index(r, s, sched):
         return (r, _step_ki(sched[s]), 0)
 
-    return (pl.BlockSpec((1, t.heads, t.block_q, D), q_index),
-            pl.BlockSpec((1, t.block_k, D), kv_index),
-            pl.BlockSpec((1, t.heads, 1, t.block_q), row_index),
-            pl.BlockSpec((1, t.block_k, D), dkv_index))
+    return Specs(
+        q=pl.BlockSpec((1, t.heads, t.block_q, D), q_index),
+        o=pl.BlockSpec((1, t.heads, t.block_q, Dv), q_index),
+        k=pl.BlockSpec((1, t.block_k, D), kv_index),
+        v=pl.BlockSpec((1, t.block_k, Dv), kv_index),
+        row=pl.BlockSpec((1, t.heads, 1, t.block_q), row_index),
+        dk=pl.BlockSpec((1, t.block_k, D), dkv_index),
+        dv=pl.BlockSpec((1, t.block_k, Dv), dkv_index))
 
 
 # A step's float32 tiles (scores, probabilities and their like) above which
@@ -571,9 +605,10 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
 
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
+    Dv = v.shape[-1]
     group = H // Hkv
     kr = k.reshape(B * Hkv, Sk, D)
-    vr = v.reshape(B * Hkv, Sk, D)
+    vr = v.reshape(B * Hkv, Sk, Dv)
 
     # delta_i = rowsum(dO * O): one fused elementwise+reduce pass in XLA.
     di = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -582,7 +617,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         """One backward kernel at its geometry ``t``; LSE / delta enter as
         rows along the lanes, with no broadcast outside."""
         n = B * H // t.heads
-        q_spec, kv_spec, row_spec, _ = _specs(t, group, D)
+        sp = _specs(t, group, D, Dv)
         sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset,
                                  causal, major, window)
         return pl.pallas_call(
@@ -592,23 +627,22 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(n, sched.size),
-                in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
-                          row_spec],
+                in_specs=[sp.q, sp.k, sp.v, sp.o, sp.row, sp.row],
                 out_specs=out_specs,
                 scratch_shapes=scratch),
             out_shape=out_shape,
             interpret=interpret,
-            name=_kernel_name(f"flash_{kind}", window),
+            name=_kernel_name(f"flash_{kind}", window, D, Dv),
             **_compiler_params(interpret, *tile),
         )(sched, q.reshape(n, t.heads, Sq, D), kr, vr,
-          dout.reshape(n, t.heads, Sq, D),
+          dout.reshape(n, t.heads, Sq, Dv),
           lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
 
     # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-    t = _geometry("dq", q, k, block_q, block_k, window)
+    t = _geometry("dq", q, k, v, block_q, block_k, window)
     rows = t.heads * t.block_q
     dq = call(
-        "dq", _dq_kernel, t, "q", _specs(t, group, D)[0],
+        "dq", _dq_kernel, t, "q", _specs(t, group, D, Dv).q,
         jax.ShapeDtypeStruct((B * H // t.heads, t.heads, Sq, D), q.dtype),
         [_vmem((rows, D), jnp.float32), _vmem((rows, LANES), jnp.float32),
          _vmem((rows, LANES), jnp.float32)],
@@ -618,16 +652,19 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     # step's query heads adding into it.  Where a step holds the whole
     # group the results leave per key head in the inputs' dtype; else per
     # step's heads in float32, summed over the group below.
-    t = _geometry("dkv", q, k, block_q, block_k, window)
+    t = _geometry("dkv", q, k, v, block_q, block_k, window)
     parts = group // t.heads
+    sp = _specs(t, group, D, Dv)
     dk, dv = call(
-        "dkv", _dkv_kernel, t, "k", [_specs(t, group, D)[3]] * 2,
-        [jax.ShapeDtypeStruct((B * H // t.heads, Sk, D),
-                              jnp.float32 if parts > 1 else k.dtype)] * 2,
-        [_vmem((t.block_k, D), jnp.float32)] * 2, (t.block_q, t.block_k))
+        "dkv", _dkv_kernel, t, "k", [sp.dk, sp.dv],
+        [jax.ShapeDtypeStruct((B * H // t.heads, Sk, d),
+                              jnp.float32 if parts > 1 else k.dtype)
+         for d in (D, Dv)],
+        [_vmem((t.block_k, d), jnp.float32) for d in (D, Dv)],
+        (t.block_q, t.block_k))
     if parts > 1:
         dk = dk.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(v.dtype)
+        dv = dv.reshape(B, Hkv, parts, Sk, Dv).sum(axis=2).astype(v.dtype)
     return dq, dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -666,7 +703,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_k: Optional[int] = None, q_offset: int = 0,
                     interpret: bool = False, window: Optional[int] = None):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
-    q: [B, H, Sq, D]; k/v: [B, Hkv, Sk, D].  ``window``: with ``causal``,
+    q: [B, H, Sq, D]; k: [B, Hkv, Sk, D]; v: [B, Hkv, Sk, Dv], with ``Dv``
+    free to differ from ``D``; returns [B, H, Sq, Dv].  ``window``: with ``causal``,
     a key is visible iff ``0 <= t - s < window``.  ``block_q`` /
     ``block_k`` default to what ``_tiles`` picks for each kernel from the
     shapes."""

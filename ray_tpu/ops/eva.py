@@ -490,10 +490,10 @@ def _two_operand_call(kind, kernel, q, k, v, ks, vs, extra, results,
     n = B * H
     t, block_s, sched, taken = _geometry(kind, S, D, window, chunk, *blocks)
     steps = sched.shape[1]
-    q_spec, kv_spec, row_spec, _ = _specs(t, 1, D)
+    sp = _specs(t, 1, D, D)
     sum_spec = pl.BlockSpec(
         (1, block_s, D), lambda r, s, sched: (r, sched[steps + s] >> 1, 0))
-    spec = {4: q_spec, 3: row_spec}
+    spec = {4: sp.q, 3: sp.row}
     shape = {4: (n, 1, S, D), 3: (n, 1, 1, S)}
     flat = lambda a: a.reshape(n, a.shape[2], D)
     return pl.pallas_call(
@@ -503,7 +503,7 @@ def _two_operand_call(kind, kernel, q, k, v, ks, vs, extra, results,
             per=window // chunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n, steps),
-            in_specs=[q_spec, kv_spec, kv_spec, sum_spec, sum_spec]
+            in_specs=[sp.q, sp.k, sp.k, sum_spec, sum_spec]
             + [spec[a.ndim] for a in extra],
             out_specs=[spec[ndim] for ndim, _ in results],
             scratch_shapes=scratch(t.block_q)),
@@ -546,7 +546,7 @@ def _eva_backward(q, k, v, ks, vs, out, lse, dout, window, chunk, scale,
         """``attention._dkv_kernel`` on this kind's K-major table: the
         gradients of ``keys`` and ``values`` [B, H, rows, D]."""
         t, _, sched, _ = _geometry(kind, S, D, window, chunk, *blocks)
-        q_spec, kv_spec, row_spec, dkv_spec = _specs(t, 1, D)
+        sp = _specs(t, 1, D, D)
         flat = lambda a: a.reshape(n, a.shape[2], D)
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, causal=causal, scale=scale,
@@ -554,9 +554,9 @@ def _eva_backward(q, k, v, ks, vs, out, lse, dout, window, chunk, scale,
                               q_offset=0),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(n, sched.shape[1]),
-                in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
-                          row_spec],
-                out_specs=[dkv_spec] * 2,
+                in_specs=[sp.q, sp.k, sp.k, sp.q, sp.row,
+                          sp.row],
+                out_specs=[sp.dk] * 2,
                 scratch_shapes=[_vmem((t.block_k, D), jnp.float32)] * 2),
             out_shape=[jax.ShapeDtypeStruct(flat(keys).shape, keys.dtype),
                        jax.ShapeDtypeStruct(flat(values).shape,

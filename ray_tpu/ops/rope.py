@@ -27,6 +27,9 @@ Two ways to lay the same rotation on the lanes:
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -40,21 +43,73 @@ _ROWS = 512
 _MAX_HEADS = 8
 
 
-def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0):
-    """Returns (cos, sin) tables of shape [max_seq_len, head_dim//2]."""
-    inv_freq = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+class Yarn(NamedTuple):
+    """A ``rope_scaling`` of type ``yarn`` (arXiv:2309.00071, as DeepSeek-V3's
+    modelling code reads its keys)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+    @property
+    def table_scale(self) -> float:
+        """What the cosines and sines are multiplied by."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_scale(self) -> float:
+        """What attention's ``head size ** -0.5`` is multiplied by."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn: Yarn):
+    """The blended frequencies, float32 [head_dim // 2]: pair ``i`` keeps
+    ``theta ** (-2 i / head_dim)`` below the ramp, has it divided by
+    ``factor`` above it, and a mix inside.  The ramp runs from the pair that
+    turns ``beta_fast`` times over the original context to the one that turns
+    ``beta_slow`` times (pairs 10 and 23 at 64 / 10,000 / 4,096 / 32 / 1)."""
+    def pair_of(turns):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair_of(yarn.beta_fast)), 0)
+    hi = min(math.ceil(pair_of(yarn.beta_slow)), head_dim - 1)
+    i = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - lo) / ((hi - lo) or 1e-3), 0, 1)
+    plain = 1.0 / (theta ** (2 * i / head_dim))
+    return plain * (1 - ramp) + plain / yarn.factor * ramp
+
+
+def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0,
+                     yarn: Optional[Yarn] = None):
+    """Returns (cos, sin) tables of shape [max_seq_len, head_dim//2]; with
+    ``yarn`` at its blended frequencies and scaled by its ``table_scale``."""
+    if yarn is None:
+        inv_freq, scale = 1.0 / (theta ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)), 1.0
+    else:
+        inv_freq, scale = (yarn_inv_freq(head_dim, theta, yarn),
+                           yarn.table_scale)
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
-    return jnp.cos(freqs), jnp.sin(freqs)
+    return jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
 
 
-def rope_lane_tables(head_dim: int, max_seq_len: int, theta: float = 10000.0):
+def rope_lane_tables(head_dim: int, max_seq_len: int, theta: float = 10000.0,
+                     yarn: Optional[Yarn] = None):
     """``rope_frequencies`` laid over all of a head's lanes, for
     ``rotate_heads``: (``[cos, cos]``, ``[-sin, sin]``), each
     [max_seq_len, head_dim] float32.  Build them once a step, outside the
     layers' scan."""
-    cos, sin = rope_frequencies(head_dim, max_seq_len, theta)
+    cos, sin = rope_frequencies(head_dim, max_seq_len, theta, yarn)
     return (jnp.concatenate([cos, cos], axis=-1),
             jnp.concatenate([-sin, sin], axis=-1))
 

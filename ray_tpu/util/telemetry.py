@@ -233,6 +233,18 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "Masked mean cross-entropy of each output head of a "
                        "model that predicts several tokens a position, in "
                        "the last reported step (head 0 predicts the next)."},
+    "ray_tpu_lm_mtp_loss": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Masked mean cross-entropy of a prediction module "
+                       "(token t + 2 from position t) in the last reported "
+                       "step, beside the main loss it is added to."},
+    "ray_tpu_hc_sinkhorn_residual": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Largest |row sum - 1| or |column sum - 1| of a "
+                       "hyper-connection's lane-to-lane map in the last "
+                       "reported step, over every sublayer and token: how "
+                       "far Sinkhorn's iterations left it from doubly "
+                       "stochastic."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,
@@ -406,10 +418,12 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
-                     "scores"),
+                     "scores", "d_qk", "d_v"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
-                       "from the call's shapes (scores: qk or kq)."},
+                       "from the call's shapes (scores: qk or kq; d_qk "
+                       "and d_v only where a call's values are not as "
+                       "wide as its keys)."},
     "ray_tpu_eva_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "block_s",
@@ -424,6 +438,12 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "taken: kernel (rows and heads of a grid step's "
                        "tile) or xla (apply_rope behind a transpose; the "
                        "call's sequence length and heads)."},
+    "ray_tpu_hc_path_total": {
+        "type": "counter", "tag_keys": ("path", "lanes"),
+        "description": "Sublayers' hyper-connection maps traced "
+                       "(ops/hyper.hc_maps), by the path their passes over "
+                       "the stream take (xla; kernel once a Pallas pair "
+                       "exists) and the stream's lanes."},
     "ray_tpu_norm_path_total": {
         "type": "counter", "tag_keys": ("path", "rows"),
         "description": "Calls of ops/norms.rms_norm traced, by the path "

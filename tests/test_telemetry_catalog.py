@@ -25,7 +25,7 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope", "eva", "norm")
+              "flash", "rope", "eva", "norm", "hc", "lm")
 
 
 class TestCatalog:
@@ -371,7 +371,10 @@ def _smoke_train_fn(config):
         train.report({"loss": 1.0 / (i + 1), "tokens": 64,
                       "moe_held_assignments": 8.0,
                       "moe_load_max_over_mean": 1.25, "moe_dropped": 0.0,
-                      "moe_sliced_calls": 0.0})
+                      "moe_sliced_calls": 0.0,
+                      # lm and hc: a prediction module's loss and the
+                      # hyper-connections' Sinkhorn residual ride there too.
+                      "mtp_loss": 2.0, "hc_sinkhorn_residual": 1e-5})
 
 
 @serve.deployment(name="telemetry_echo")

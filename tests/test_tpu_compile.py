@@ -666,3 +666,59 @@ def test_evabyte_train_step_compiles_at_the_cell_sizes(evabyte_step, capsys):
         dims = [int(d) for d in shape.split(",")]
         assert not (32 in dims and sum(d >= 2048 for d in dims) >= 2), shape
         assert 32768 + 2048 not in dims, shape
+
+
+@pytest.fixture(scope="module")
+def xing4_step(topo):
+    """``xing4.0-29b-a4b.train-mhc8k``'s step (1 dense + 4 expert layers and
+    the prediction module, 8 of 64 experts, rows of 8,192 a layer at a time,
+    full remat, flash at 192 / 128, Pallas grouped products)."""
+    import json
+    import os
+    from benchmark.archs import xing4_0
+    with open(os.path.join(ROOT, "benchmark/traffic/train-mhc8k.json")) as f:
+        seq = json.load(f)["seq_len"]
+    return _cell_step(topo, xing4_0, "xing4.0-29b-a4b.json", seq,
+                      moe_impl="gmm")
+
+
+def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
+    """The step compiles for one described v5e chip with the Mosaic kernels
+    in it: the three flash kernels at head sizes 192 / 128 by name, with no
+    operand or result padded to 256, and the grouped products; its memory is
+    stated; the scopes the readers sum are in its text."""
+    import re
+
+    import jax
+    from benchmark import scopes
+    from benchmark.archs import xing4_0 as arch
+
+    compiled, text = xing4_step["compiled"], xing4_step["text"]
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nxing4.0-29b-a4b.train-mhc8k step for a described v5e: "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"{_kernels(compiled)} kernels")
+    assert sum(a.size for a in jax.tree.leaves(xing4_step["params"])) == \
+        arch.parameters(xing4_step["sizes"])["held"] == \
+        xing4_step["config"]["parameters"] == 913473348
+    # bf16 weights and two bf16 moments of 913 M parameters.
+    assert 5.4e9 < mem.argument_size_in_bytes < 5.6e9
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in ("flash_fwd_d192v128", "flash_dq_d192v128",
+                   "flash_dkv_d192v128", "gmm", "tgmm"):
+        assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
+    # What crosses HBM at a flash call is 192 and 128 wide.
+    for call in calls:
+        if "flash_" in call.partition(" = ")[0]:
+            widths = {int(dims.split(",")[-1]) for dims in re.findall(
+                r"bf16\[([0-9,]+)\]", call)}
+            assert widths == {192, 128}, call[:300]
+    by = {"scopes": {scopes.scope_path(name): 1.0
+                     for name in scopes.op_names(text).values()}}
+    for scope in ("block/hc/maps", "block/hc/collect", "block/hc/deposit",
+                  "block/attn/mla", "block/moe/experts", "mtp",
+                  "mtp/block/hc", "loss"):
+        assert scopes.seconds_under(by, scope) > 0, scope
