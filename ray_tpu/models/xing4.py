@@ -297,12 +297,13 @@ def _sublayer(cfg: Xing4Config, X, layer, name: str, F):
     if cfg.hc_mult == 1:
         y, aux = F(rms_norm(X[:, 0], norm, cfg.norm_eps))
         return X + y[:, None], aux, jnp.zeros((), jnp.float32)
-    H_pre, H_post, H_res = hyper.hc_maps(
+    interpret = cfg.attention_impl == "flash_interpret"
+    X, u, H_post, H_res = hyper.collect(
         X, layer[f"hc_{name}_phi"], layer[f"hc_{name}_b"],
         layer[f"hc_{name}_alpha"], cfg.hc_sinkhorn_iters, cfg.hc_eps,
-        cfg.hc_clamp, cfg.norm_eps)
-    y, aux = F(rms_norm(hyper.hc_collect(X, H_pre), norm, cfg.norm_eps))
-    return (hyper.hc_deposit(X, H_res, H_post, y), aux,
+        cfg.hc_clamp, cfg.norm_eps, interpret=interpret)
+    y, aux = F(rms_norm(u, norm, cfg.norm_eps))
+    return (hyper.deposit(X, H_res, H_post, y, interpret=interpret), aux,
             jax.lax.stop_gradient(hyper.sinkhorn_residual(H_res)))
 
 

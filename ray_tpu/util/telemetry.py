@@ -440,10 +440,10 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "call's sequence length and heads)."},
     "ray_tpu_hc_path_total": {
         "type": "counter", "tag_keys": ("path", "lanes"),
-        "description": "Sublayers' hyper-connection maps traced "
-                       "(ops/hyper.hc_maps), by the path their passes over "
-                       "the stream take (xla; kernel once a Pallas pair "
-                       "exists) and the stream's lanes."},
+        "description": "Sublayers' hyper-connections traced "
+                       "(ops/hyper.collect), by the path their passes over "
+                       "the stream take (kernel: Pallas, forward and "
+                       "backward; xla: the jnp forms) and its lanes."},
     "ray_tpu_norm_path_total": {
         "type": "counter", "tag_keys": ("path", "rows"),
         "description": "Calls of ops/norms.rms_norm traced, by the path "

@@ -708,8 +708,16 @@ def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     for kernel in ("flash_fwd_d192v128", "flash_dq_d192v128",
-                   "flash_dkv_d192v128", "gmm", "tgmm"):
+                   "flash_dkv_d192v128", "gmm", "tgmm", "hc_collect_n4",
+                   "hc_deposit_n4", "hc_deposit_bwd_n4", "hc_pre_bwd_n4",
+                   "hc_collect_bwd_n4"):
         assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
+    # The four-lane stream lies row-major wherever it is held (PR 42: the
+    # ``jnp`` passes kept it sequence-minor, 159 times in this text), and no
+    # ``copy`` stands beside a pass's kernel: none of one row's stream.
+    assert "bf16[1,4,8192,3584]{3,2,1,0" in text
+    assert "bf16[1,4,8192,3584]{2,3,1,0" not in text
+    assert not re.search(r"= bf16\[1,4,8192,3584\]\S* copy\(", text)
     # What crosses HBM at a flash call is 192 and 128 wide.
     for call in calls:
         if "flash_" in call.partition(" = ")[0]:

@@ -420,15 +420,25 @@ def test_a_mesh_and_a_pipeline_are_refused_by_name():
         set_global_mesh(before)
 
 
-def test_compiled_step_names_the_scopes_the_benchmark_sums():
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_compiled_step_names_the_scopes_the_benchmark_sums(path, monkeypatch):
     """``hc_stream_roofline``, ``hc_device_share`` and ``mtp_device_share``
     are what ``benchmark/scopes.py`` finds under ``block/hc`` and ``mtp`` in
-    the compiled step's text, forward and backward alike."""
+    the compiled step's text, forward and backward alike; on the kernel
+    path (interpreted here) every one of the five kernels' operations is
+    under a pass's scope, the module's under ``mtp`` too."""
     from benchmark import scopes
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.parallel.spmd import make_lm_train_step
+    from ray_tpu.util import telemetry
+    seen = []
+    monkeypatch.setattr(
+        telemetry, "inc", lambda name, value=1.0, tags=None: seen.append(
+            tags["path"]) if name == "ray_tpu_hc_path_total" else None)
     cfg = xing4.xing4_tiny(experts_held=4, held_start=4, remat=True,
                            layer_rows=1)
+    if path == "kernel":
+        cfg = cfg.replace(hidden=128, attention_impl="flash_interpret")
     mesh = build_mesh(MeshSpec(), devices=jax.devices()[:1])
     init_fn, step_fn, _ = make_lm_train_step(cfg, mesh, learning_rate=1e-3)
     params, state = jax.eval_shape(init_fn, jax.random.key(0))
@@ -436,6 +446,7 @@ def test_compiled_step_names_the_scopes_the_benchmark_sums():
              for k in ("tokens", "loss_mask")}
     names = list(scopes.op_names(step_fn.lower(params, state, batch)
                                  .compile().as_text()).values())
+    assert seen and set(seen) == {path}
     paths = {scopes.scope_path(n) for n in names}
     by = {"scopes": dict.fromkeys(paths, 1.0)}
     for scope in ("block/hc/maps", "block/hc/collect", "block/hc/deposit",
@@ -444,6 +455,21 @@ def test_compiled_step_names_the_scopes_the_benchmark_sums():
         assert scopes.seconds_under(by, scope) > 0, scope
     assert any("mtp" in n and "transpose(jvp(" in n for n in names)
     assert not any("jvp" in p or "while" in p for p in paths)
+    if path == "kernel":
+        for kernel, scope in (("hc_collect_n4", "collect"),
+                              ("hc_pre_bwd_n4", "collect"),
+                              ("hc_collect_bwd_n4", "collect"),
+                              ("hc_deposit_n4", "deposit"),
+                              ("hc_deposit_bwd_n4", "deposit")):
+            mine = [n for n in names if f"/{kernel}/" in n]
+            assert mine and all(f"block/hc/{scope}/{kernel}/" in n
+                                for n in mine), kernel
+            assert any("/mtp/" in n for n in mine), kernel
+            assert any("transpose(jvp(" in n for n in mine), kernel
+            # Forward (under the jvp), and recomputed under the remat.
+            if not kernel.endswith("bwd_n4"):
+                assert any("rematted_computation" in n for n in mine), kernel
+                assert any("transpose(" not in n for n in mine), kernel
 
 
 def test_published_stack_is_built_but_not_run():
