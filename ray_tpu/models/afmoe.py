@@ -207,12 +207,12 @@ def init_state(cfg: AfmoeConfig) -> Dict[str, jax.Array]:
                               jnp.float32)}
 
 
-def _swiglu(h, w_gate, w_up, w_down, dt):
+def _swiglu(h, w_gate, w_up, w_down, dt, act=jax.nn.silu):
     gate = jnp.einsum("bse,em->bsm", h, w_gate.astype(dt),
                       preferred_element_type=dt)
     up = jnp.einsum("bse,em->bsm", h, w_up.astype(dt),
                     preferred_element_type=dt)
-    return jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
+    return jnp.einsum("bsm,me->bse", act(gate) * up,
                       w_down.astype(dt), preferred_element_type=dt)
 
 
@@ -261,11 +261,27 @@ def _attn_half(cfg: AfmoeConfig, full, cos, sin, x, layer):
     return x + rms_norm(out, layer["attn_post_norm"], eps)
 
 
-def _moe(cfg: AfmoeConfig, h, layer, bias):
+def _feed_forward(h, w_gate, w_up, w_down, dt, act="silu"):
+    """``act(h W_gate) * (h W_up)`` then ``W_down`` (a SwiGLU where ``act``
+    is silu), or with no gate (``w_gate`` None) ``act(h W_up) W_down``: the
+    forms ``ops/moe.dropless_experts`` takes."""
+    if w_gate is not None:
+        return _swiglu(h, w_gate, w_up, w_down, dt, moe.ACTIVATIONS[act])
+    up = jnp.einsum("bse,em->bsm", h, w_up.astype(dt),
+                    preferred_element_type=dt)
+    return jnp.einsum("bsm,me->bse", moe.ACTIVATIONS[act](up),
+                      w_down.astype(dt), preferred_element_type=dt)
+
+
+def _moe(cfg, h, layer, bias, act: str = "silu"):
     """F(h) of an expert layer and its loads: (out [B, S, E], {"counts" [X]
     int32 over all the experts, "dropped" int32, "sliced" int32 (1 if the
-    call took the buffer in slices), "top" [B*S, k] the router's choices})."""
+    call took the buffer in slices), "top" [B*S, k] the router's choices}).
+    A layer without ``w_gate`` / ``shared_gate`` has un-gated experts
+    (``act(h W_up) W_down``); ``cfg`` is any configuration with this one's
+    routing and share fields (``models/xing4.py``, ``models/nemotron_h.py``)."""
     dt = cfg.dtype
+    gate = layer.get("w_gate")
     B, S, E = h.shape
     with jax.named_scope("block/moe"):
         with jax.named_scope("route"):
@@ -273,12 +289,14 @@ def _moe(cfg: AfmoeConfig, h, layer, bias):
                 h.reshape(B * S, E), layer["router"], bias, cfg.top_k,
                 cfg.route_scale, cfg.route_norm)
         with jax.named_scope("shared"):
-            shared = _swiglu(h, layer["shared_gate"], layer["shared_up"],
-                             layer["shared_down"], dt)
+            shared = _feed_forward(h, layer.get("shared_gate"),
+                                   layer["shared_up"], layer["shared_down"],
+                                   dt, act)
         routed, (held, dropped) = moe.dropless_experts(
-            h.reshape(B * S, E), routing, layer["w_gate"].astype(dt),
+            h.reshape(B * S, E), routing,
+            None if gate is None else gate.astype(dt),
             layer["w_up"].astype(dt), layer["w_down"].astype(dt),
-            held_start=cfg.held_start, impl=cfg.moe_impl)
+            held_start=cfg.held_start, impl=cfg.moe_impl, activation=act)
         return (shared + routed.reshape(B, S, E).astype(dt),
                 {"counts": routing.counts, "dropped": dropped,
                  "sliced": (held > moe.buffer_rows(B * S, cfg.top_k)

@@ -10,7 +10,10 @@ scores all ``X`` experts and picks ``k`` of them (8 of 128 there); the
 layer computes the part of the result that its own ``Xh`` experts give,
 as one chip of an expert-parallel group does, without the exchange.  The
 assignments to held experts are sorted by expert into a buffer of static
-size and multiplied by grouped matrix products over the ragged groups.
+size and multiplied by grouped matrix products over the ragged groups:
+three a row for a gated expert (``act(x W_gate) * (x W_up)``, then
+``W_down``; Trinity's and Xing4.0's SwiGLU), two for an un-gated one
+(``act(x W_up) W_down``; Nemotron-H's ``relu2``).
 Rows go into the buffer and come back out through a pair of primitives
 that are each other's transpose (``rows_of_tokens``, ``tokens_from_rows``)
 on the indices of one counting sort a call (``_places``): gathers and
@@ -228,11 +231,17 @@ def _tokens_bwd(res, g):
 tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
 
 
-def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl):
+#: an expert's activation, by the name a model's configuration gives it
+ACTIVATIONS = {"silu": jax.nn.silu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
+               activation="silu"):
     """The held experts' part for tokens xt [T, E] in a buffer of ``rows``
     rows: (out [T, E], rows in use).  Right whenever the assignments to held
-    experts number at most ``rows``."""
-    Xh = w_gate.shape[0]
+    experts number at most ``rows``.  ``w_gate`` None: an un-gated expert."""
+    Xh, act = w_up.shape[0], ACTIVATIONS[activation]
     with jax.named_scope("dispatch"):
         local = top - held_start
         local = jnp.where((local >= 0) & (local < Xh), local, Xh)
@@ -241,7 +250,10 @@ def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl):
     with jax.named_scope("experts"):
         mm = functools.partial(grouped_matmul, group_sizes=at.sizes,
                                impl=impl)
-        h = jax.nn.silu(mm(x_rows, w_gate)) * mm(x_rows, w_up)
+        if w_gate is None:
+            h = act(mm(x_rows, w_up))
+        else:
+            h = act(mm(x_rows, w_gate)) * mm(x_rows, w_up)
         y_rows = mm(h, w_down)
     return tokens_from_rows(y_rows, w.astype(jnp.float32), at), used
 
@@ -254,14 +266,19 @@ def buffer_rows(T: int, k: int) -> int:
 
 
 def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
-                     held_start: int = 0, impl: Optional[str] = None):
+                     held_start: int = 0, impl: Optional[str] = None,
+                     activation: str = "silu"):
     """``sum_j w[t, j] * Expert_{top[t, j]}(xt[t])`` over the assignments
     to the experts held here, ``held_start <= e < held_start + Xh``; every
     such assignment is computed, whatever the imbalance.
 
-    xt [T, E]; w_gate / w_up [Xh, E, M], w_down [Xh, M, E].  Returns (out
-    [T, E], stats) with ``stats = (held, dropped)``: the assignments to held
-    experts and those of them not computed (identically 0).
+    xt [T, E]; w_gate / w_up [Xh, E, M], w_down [Xh, M, E].  An expert is
+    ``act(x W_gate) * (x W_up)``, then ``W_down``, with ``act`` the
+    ``activation`` named (``silu``, ``relu2``); with ``w_gate`` None it has
+    no gate, ``act(x W_up) W_down``: two grouped products a row for three.
+    Returns (out [T, E], stats) with ``stats = (held, dropped)``: the
+    assignments to held experts and those of them not computed (identically
+    0).
 
     Buffers are static.  All T*k assignments may go to held experts, so the
     worst case needs T*k rows; a share of Xh / X is the usual case.  The
@@ -278,13 +295,13 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     size, the gather out of it T*k slots whatever their number in use, the
     grouped products only the rows in use."""
     T, k = routing.expert_index.shape
-    Xh, tiers = w_gate.shape[0], BUFFER_TIERS
+    Xh, tiers = w_up.shape[0], BUFFER_TIERS
     top, w = routing.expert_index, routing.weights
     held = jnp.sum(routing.counts[held_start:held_start + Xh])
     rows = buffer_rows(T, k)
     run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
                             w_down=w_down, held_start=held_start, rows=rows,
-                            impl=impl)
+                            impl=impl, activation=activation)
     if rows == T * k:
         out, used = run(xt, top, w)
         return out, (held, held - used)

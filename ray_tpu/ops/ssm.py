@@ -1,0 +1,596 @@
+"""State-space layers (Mamba-2, the SSD form of arXiv:2405.21060): a causal
+depthwise convolution, the chunked scan of a recurrence that carries a state
+from chunk to chunk, and the gated RMSNorm over groups of channels.  What
+``models/nemotron_h.py``'s mixer runs.
+
+The recurrence, a head ``h`` of ``P`` channels on a state of ``P x N`` (in
+float32, the state zero at the start of every row)::
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t X_t (x) B_t
+    y_t = S_t C_t + D X_t
+
+with ``B`` and ``C`` shared by the heads of a group.  ``ssd_scan`` computes it
+in chunks of ``chunk`` tokens, equal to the recurrence: with ``l_t`` the
+running sum of ``dt A`` inside a chunk,
+
+- inside a chunk, ``y_t = sum_{s <= t} exp(l_t - l_s) (C_t . B_s) dt_s X_s``:
+  one [Q, Q] product a group for ``C . B``, a decay mask a head, one
+  [Q, Q] x [Q, P] product a head;
+- a chunk's own state ``sum_s exp(l_Q - l_s) dt_s X_s (x) B_s`` (a
+  [heads of a group * P, Q] x [Q, N] product a group);
+- the states handed on, ``S_c = exp(l_Q) S_{c-1} + own_c``: a ``lax.scan``
+  over the chunks, elementwise on [B, H, P, N] float32;
+- ``exp(l_t) C_t . S_{c-1}`` added to a chunk's own result.
+
+Decays, running sums, ``dt`` and the states are float32; the products take
+bfloat16 operands (the inputs as they come, the masked ``C . B`` and the
+states rounded once) and add up in float32, as upstream's kernels do.  A row
+whose length the chunk does not divide is padded with tokens that neither
+decay nor add (``dt`` 0), which nothing before them sees.
+
+Two forms compute it.  On a TPU, where a group's channels, the state and the
+chunk each fill whole tiles of 128 lanes (``_kernels``; Nemotron-3-Nano's 8
+heads of 64 a group, state 128, chunk 128), a pair of Pallas kernels
+(``ssd_fwd_q128`` / ``ssd_bwd_q128``, one ``jax.custom_vjp``): a grid step
+is one chunk of one group, the chunks of a row in order with the group's
+state [N, heads P] float32 in VMEM scratch, so the decayed masks, the
+masked ``C . B`` and every float32 partial result never reach HBM; the
+backward keeps the state each chunk started from (written by the forward
+its rule runs) and walks the chunks last to first.  XLA makes what is small:
+``l`` by a product with a triangle of ones, and ``l`` and ``dt`` a head as
+columns and as rows (64 numbers a token).  Elsewhere ``_scan_xla``: the same
+four products in ``jnp`` with a ``lax.scan`` over the chunk states and the
+backward JAX derives, the definition the kernels are held to and the CPU's
+path; on the chip its masks and partial results cross HBM at HBM pace: 2.4
+ms a row of 8,192 forward and 6.1 forward and backward, against the
+kernels' 1.0 and 2.7 (PERF.md, PR 43).
+
+The convolution and the gated norm are elementwise passes over 6,144 and
+4,096 channels a token, bound by HBM; each has its backward written out
+(``jax.custom_vjp``, still ``jnp``), because what JAX derives moves several
+times the bytes: by XLA's own count for a described v5e, a row of 8,192
+forward and backward, the convolution 4.23 -> 0.81 GB and the norm 3.06 ->
+1.12 GB (PERF.md, PR 43).
+
+Which path a scan took is counted in ``ray_tpu_ssm_path_total`` (``kernel``
+or ``xla``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..util import telemetry
+
+F32 = jnp.float32
+
+
+def _conv_taps(c, w, b):
+    """The convolution before its silu, float32 [B, S, Ch], and the padded
+    input its taps read (in c's dtype: a float32 copy of it would be written
+    and read four times)."""
+    K, S = w.shape[0], c.shape[1]
+    padded = jnp.pad(c, ((0, 0), (K - 1, 0), (0, 0)))
+    acc = b.astype(F32)
+    for j in range(K):
+        acc = acc + padded[:, j:j + S].astype(F32) * w[j].astype(F32)
+    return acc, padded
+
+
+@jax.custom_vjp
+def causal_conv(c, w, b):
+    """``silu(b + sum_j w[j] * c[t - (K - 1) + j])`` with ``c[s] = 0`` before
+    the row's start: c [B, S, Ch], w [K, Ch] (tap K - 1 reads the token
+    itself), b [Ch] -> [B, S, Ch] in c's dtype.  Depthwise: a channel reads
+    its own past and nothing else; a row reads nothing of another row.
+
+    The backward is written out (the taps read the other way, the weight's
+    gradient K reductions over the same shifted reads): what JAX derives
+    moves 2.6 times the bytes by XLA's own count for a described v5e."""
+    with jax.named_scope("block/ssm/conv"):
+        return jax.nn.silu(_conv_taps(c, w, b)[0]).astype(c.dtype)
+
+
+def _conv_fwd(c, w, b):
+    return causal_conv(c, w, b), (c, w, b)
+
+
+def _conv_bwd(res, g):
+    c, w, b = res
+    K, S = w.shape[0], c.shape[1]
+    with jax.named_scope("block/ssm/conv"):
+        acc, padded = _conv_taps(c, w, b)
+        sig = jax.nn.sigmoid(acc)
+        ga = g.astype(F32) * sig * (1.0 + acc * (1.0 - sig))
+        # Token t's cotangent reaches the inputs of tokens t - (K - 1) .. t.
+        ahead = jnp.pad(ga, ((0, 0), (0, K - 1), (0, 0)))
+        dc = sum(ahead[:, K - 1 - j:K - 1 - j + S] * w[j].astype(F32)
+                 for j in range(K))
+        dw = jnp.stack([jnp.sum(padded[:, j:j + S].astype(F32) * ga,
+                                axis=(0, 1)) for j in range(K)])
+        return (dc.astype(c.dtype), dw.astype(w.dtype),
+                jnp.sum(ga, axis=(0, 1)).astype(b.dtype))
+
+
+causal_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def chunk_carry(dt, A, chunk: int):
+    """Mean over rows, chunks and heads of ``exp(l_Q)``, the share of a state
+    that a whole chunk hands on: dt [B, S, H] float32, A [H].  Only whole
+    chunks count.  No gradient."""
+    B, S, H = dt.shape
+    n = S // chunk
+    if not n:
+        return jnp.ones((), F32)
+    a = dt[:, :n * chunk].astype(F32).reshape(B, n, chunk, H) * A.astype(F32)
+    return jax.lax.stop_gradient(jnp.mean(jnp.exp(jnp.sum(a, axis=2))))
+
+
+def _refuse_a_mesh() -> None:
+    from ..parallel.mesh import get_global_mesh
+    mesh = get_global_mesh()
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "ssd_scan on a mesh: heads and groups split over tp, and a row "
+            "split over sp handing its state on, are not built (ROADMAP M8)")
+
+
+def _scan_xla(X, dt, A, B, C, D, Q: int):
+    """The chunked form in ``jnp`` over whole chunks: X [Bt, S, H, P] with
+    ``Q`` dividing S -> y float32 of X's shape."""
+    Bt, S, H, P = X.shape
+    G, N = B.shape[2:]
+    R, nc, dtype = H // G, S // Q, X.dtype
+    Xc = X.reshape(Bt, nc, Q, G, R, P)
+    Bc, Cc = B.reshape(Bt, nc, Q, G, N), C.reshape(Bt, nc, Q, G, N)
+    dtc = dt.reshape(Bt, nc, Q, H)
+    # [Bt, nc, H, Q]: the running sum of dt A inside a chunk, and dt.
+    l = jnp.moveaxis(jnp.cumsum(dtc * A.astype(F32), axis=2), 2, 3)
+    dth = jnp.moveaxis(dtc, 2, 3)
+    total = l[..., -1]                                   # [Bt, nc, H]
+
+    # Inside a chunk: the masked, decayed C . B, a head at a time.
+    cb = jnp.einsum("bcqgn,bcsgn->bcgqs", Cc, Bc, preferred_element_type=F32)
+    visible = jnp.tril(jnp.ones((Q, Q), bool))
+    decay = jnp.exp(jnp.where(visible, l[..., :, None] - l[..., None, :],
+                              -jnp.inf))                 # [Bt, nc, H, Q, Q]
+    m = (cb[:, :, :, None] * (decay * dth[..., None, :]).reshape(
+        Bt, nc, G, R, Q, Q)).astype(dtype)
+    y = jnp.einsum("bcgrqs,bcsgrp->bcqgrp", m, Xc,
+                   preferred_element_type=F32)
+
+    # A chunk's own state, then the states handed on from chunk to chunk.
+    left = (jnp.exp(total[..., None] - l) * dth).reshape(Bt, nc, G, R, Q)
+    own = jnp.einsum(
+        "bcsgrp,bcsgn->bcgrpn",
+        (Xc.astype(F32) * jnp.moveaxis(left, 4, 2)[..., None]).astype(dtype),
+        Bc, preferred_element_type=F32)
+    keep = jnp.exp(total).reshape(Bt, nc, G, R)
+
+    def hand_on(state, chunk_):
+        own_c, keep_c = chunk_
+        return keep_c[..., None, None] * state + own_c, state
+
+    _, before = jax.lax.scan(
+        hand_on, jnp.zeros((Bt, G, R, P, N), F32),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(keep, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                  # [Bt, nc, G, R, P, N]
+    carried = jnp.einsum("bcqgn,bcgrpn->bcqgrp", Cc, before.astype(dtype),
+                         preferred_element_type=F32)
+    y = y + carried * jnp.moveaxis(
+        jnp.exp(l).reshape(Bt, nc, G, R, Q), 4, 2)[..., None]
+    y = y + Xc.astype(F32) * D.astype(F32).reshape(G, R)[..., None]
+    return y.reshape(Bt, S, H, P)
+
+
+# ------------------------------------------------------- the Pallas kernels
+#
+# A grid step is one chunk of one group of one row: the group's ``R`` heads
+# (R P channels along the lanes) on the one B and C they share.  The chunks
+# of a row run in order (``arbitrary``) and hand the group's state on in
+# VMEM scratch, [N, R P] float32; the backward walks them the other way and
+# hands the state's cotangent back.  The decayed masks [Q, Q] a head, the
+# masked C . B and every float32 partial result live in VMEM only.  What a
+# head needs of ``l`` (the running sum of dt A inside the chunk) and dt as a
+# column [Q, 1] and as a row [1, Q] comes in from XLA in both layouts
+# ([Bt, G, S, R] and [Bt, G, R, S]; 64 numbers a token); their cotangents
+# go back as columns.  Heads of 64 channels are taken two at a time, a tile
+# of 128 lanes, each product with the other head's lanes zeroed: nothing is
+# cut or joined inside a tile (0.61 ms a row forward for 0.86).  The loops
+# over a group's heads unroll while a kernel is traced, so the lint's RT506
+# (op-by-op dispatch in a loop) does not apply to them.
+
+
+def _nt(a, b):
+    """a [m, k] . b [n, k]^T -> [m, n] float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _tn(a, b):
+    """a [k, m]^T . b [k, n] -> [m, n] float32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _mm(a, b):
+    return jnp.dot(a, b, preferred_element_type=F32)
+
+
+def _causal(Q: int):
+    """[Q, Q] float32, 0 where s <= q and -inf elsewhere: added to an
+    exponent, it masks what a token may not see."""
+    q = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    return jnp.where(s <= q, 0.0, -jnp.inf).astype(F32)
+
+
+def _heads_a_tile(P: int):
+    """(heads whose channels share a lane tile, the tile's channels): heads
+    of 64 channels go through the kernels in pairs, so that nothing is cut
+    or joined inside a tile of 128 lanes."""
+    from .attention import LANES
+    per = LANES // P if P < LANES else 1
+    return per, per * P
+
+
+def _of_head(a, k: int, P: int):
+    """a [rows, w] with the lanes of every head but the tile's k-th zero."""
+    if a.shape[1] == P:
+        return a
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, a.shape[1]), 1)
+    return jnp.where(lane // P == k, a, jnp.zeros_like(a))
+
+
+def _by_head(columns, P: int):
+    """Per-head columns [rows, 1] (a tile's heads in order) along the lanes
+    of their heads: [rows, heads * P]."""
+    w = len(columns) * P
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    out = columns[-1]
+    for k in range(len(columns) - 2, -1, -1):
+        out = jnp.where(lane // P == k, columns[k], out)
+    return jnp.broadcast_to(out, (columns[0].shape[0], w))
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, lcol_ref, dtcol_ref, lrow_ref,
+                    dtrow_ref, d_ref, y_ref, *rest, R: int, P: int):
+    """y of a chunk [Q, R P]; with a ``s_ref`` among ``rest``, the state the
+    chunk started from is kept for the backward."""
+    from jax.experimental import pallas as pl
+    *s_ref, state = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _row_start():
+        state[...] = jnp.zeros(state.shape, F32)
+
+    Bm, Cm = b_ref[...], c_ref[...]
+    dtype, Q = Bm.dtype, Bm.shape[0]
+    lcol, dtcol = lcol_ref[...], dtcol_ref[...]          # [Q, R]
+    lrow, dtrow = lrow_ref[...], dtrow_ref[...]          # [R, Q]
+    cb, causal = _nt(Cm, Bm), _causal(Q)                 # [Q, Q]
+    per, w = _heads_a_tile(P)
+    for j in range(R // per):  # ray-tpu: noqa[RT506]
+        at = slice(j * w, (j + 1) * w)
+        X, prev = x_ref[:, at], state[:, at]
+        if s_ref:
+            s_ref[0][:, at] = prev
+        X32 = X.astype(F32)
+        y = jnp.zeros((Q, w), F32)
+        e, left, keep = [], [], []
+        for k in range(per):  # ray-tpu: noqa[RT506]
+            r = j * per + k
+            lc = lcol[:, r:r + 1]
+            m = cb * (jnp.exp(lc - lrow[r:r + 1] + causal) * dtrow[r:r + 1])
+            y = y + _mm(m.astype(dtype), _of_head(X, k, P))
+            total = lc[Q - 1:Q]                          # [1, 1]
+            e.append(jnp.exp(lc))
+            left.append(jnp.exp(total - lc) * dtcol[:, r:r + 1])
+            keep.append(jnp.exp(total))
+        y = y + _mm(Cm, prev.astype(dtype)) * _by_head(e, P)
+        y_ref[:, at] = (y + X32 * d_ref[:, at]).astype(y_ref.dtype)
+        state[:, at] = (_by_head(keep, P) * prev
+                        + _tn(Bm, (X32 * _by_head(left, P)).astype(dtype)))
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, lcol_ref, dtcol_ref, lrow_ref,
+                    dtrow_ref, d_ref, s_ref, dy_ref, dx_ref, db_ref, dc_ref,
+                    dlcol_ref, ddtcol_ref, dd_ref, dstate, *, R: int,
+                    P: int):
+    """The cotangents of a chunk, the chunks of a row walked last to first;
+    ``dstate`` holds the cotangent of the state the chunk hands on.  With m
+    = cb exp(l_q - l_s) dt_s the masked product a head: l_q's cotangent is
+    the rows' sums of dm m, which is dy_q . (m X)_q; l_s's the columns'
+    (negative), which is X_s . (m^T dy)_s, and dt_s's that over dt_s: two
+    products more a head, and no [Q, Q] array is summed."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _row_end():
+        dstate[...] = jnp.zeros(dstate.shape, F32)
+
+    Bm, Cm = b_ref[...], c_ref[...]
+    dtype, Q = Bm.dtype, Bm.shape[0]
+    lcol, dtcol = lcol_ref[...], dtcol_ref[...]
+    lrow, dtrow = lrow_ref[...], dtrow_ref[...]
+    cb, causal = _nt(Cm, Bm), _causal(Q)
+    last = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
+    dcb = jnp.zeros((Q, Q), F32)
+    dB = dC = jnp.zeros(Bm.shape, F32)
+    per, w = _heads_a_tile(P)
+    rows = lambda a, k: jnp.sum(_of_head(a, k, P), axis=1, keepdims=True)
+    for j in range(R // per):  # ray-tpu: noqa[RT506]
+        at = slice(j * w, (j + 1) * w)
+        X, dY = x_ref[:, at], dy_ref[:, at]
+        X32, dY32 = X.astype(F32), dY.astype(F32)
+        prev, dS = s_ref[:, at], dstate[:, at]           # [N, w] float32
+        prev_b, dS_b = prev.astype(dtype), dS.astype(dtype)
+        dXl = _mm(Bm, dS_b)      # the cotangent of left_s X_s, [Q, w]
+        held = jnp.sum(dS * prev, axis=0, keepdims=True)             # [1, w]
+        y = jnp.zeros((Q, w), F32)
+        dx = jnp.zeros((Q, w), F32)
+        e, grow, left, keep = [], [], [], []
+        for k in range(per):  # ray-tpu: noqa[RT506]
+            r = j * per + k
+            lc = lcol[:, r:r + 1]
+            Ld = jnp.exp(lc - lrow[r:r + 1] + causal) * dtrow[r:r + 1]
+            dYk = _of_head(dY, k, P)
+            dcb = dcb + _nt(dYk, X) * Ld
+            m = (cb * Ld).astype(dtype)
+            y = y + _mm(m, _of_head(X, k, P))
+            dx = dx + _tn(m, dYk)
+            total = lc[Q - 1:Q]
+            e.append(jnp.exp(lc))
+            grow.append(jnp.exp(total - lc))
+            left.append(grow[k] * dtcol[:, r:r + 1])
+            keep.append(jnp.exp(total))
+        e_, left_ = _by_head(e, P), _by_head(left, P)
+        y = y + _mm(Cm, prev_b) * e_                     # y less D X
+        into, out_of, lefts = dY32 * y, X32 * dx, dXl * X32
+        for k in range(per):  # ray-tpu: noqa[RT506]
+            r = j * per + k
+            dtc = dtcol[:, r:r + 1]
+            dleft, down = rows(lefts, k), rows(out_of, k)            # [Q, 1]
+            t = dleft * left[k]
+            dtotal = (jnp.sum(t, axis=0, keepdims=True)
+                      + keep[k] * rows(held, k))
+            dlcol_ref[:, r:r + 1] = (rows(into, k) - down - t
+                                     + jnp.where(last, dtotal, 0.0))
+            ddtcol_ref[:, r:r + 1] = (
+                jnp.where(dtc > 0, down / jnp.where(dtc > 0, dtc, 1.0), 0.0)
+                + dleft * grow[k])
+        dx_ref[:, at] = (dx + dXl * left_
+                         + dY32 * d_ref[:, at]).astype(dx_ref.dtype)
+        dd_ref[:, at] = jnp.sum(dY32 * X32, axis=0, keepdims=True)
+        dYe, Xl = (dY32 * e_).astype(dtype), (X32 * left_).astype(dtype)
+        dC = dC + _nt(dYe, prev_b)
+        dB = dB + _nt(Xl, dS_b)
+        dstate[:, at] = _by_head(keep, P) * dS + _tn(Cm, dYe)
+    dcb = dcb.astype(dtype)
+    dc_ref[...] = (_mm(dcb, Bm) + dC).astype(dc_ref.dtype)
+    db_ref[...] = (_tn(dcb, Cm) + dB).astype(db_ref.dtype)
+
+
+def _ssd_call(kernel, name, dims, reverse, extra_in, outs, interpret):
+    """A kernel over the grid (row, group, chunk): the eight arrays every
+    kernel reads first (``_laid_out``), then ``extra_in`` / ``outs`` as
+    (spec, array or shape) pairs."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    Bt, G, nc, Q, R, P, N = dims
+    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+    tokens = lambda w: pl.BlockSpec((None, Q, w),
+                                    lambda b, g, c: (b, at(c), g))
+    col = pl.BlockSpec((None, None, Q, R), lambda b, g, c: (b, g, at(c), 0))
+    row = pl.BlockSpec((None, None, R, Q), lambda b, g, c: (b, g, 0, at(c)))
+    lanes = pl.BlockSpec((None, 1, R * P), lambda b, g, c: (g, 0, 0))
+    state = pl.BlockSpec((None, None, None, N, R * P),
+                         lambda b, g, c: (b, g, at(c), 0, 0))
+    part = pl.BlockSpec((None, None, None, 1, R * P),
+                        lambda b, g, c: (b, g, at(c), 0, 0))
+    specs = {"tokens": tokens(R * P), "bc": tokens(N), "col": col,
+             "row": row, "lanes": lanes, "state": state, "part": part}
+    first = ["tokens", "bc", "bc", "col", "col", "row", "row", "lanes"]
+    return pl.pallas_call(
+        functools.partial(kernel, R=R, P=P), grid=(Bt, G, nc),
+        in_specs=[specs[k] for k in first + [k for k, _ in extra_in]],
+        out_specs=[specs[k] for k, _ in outs],
+        out_shape=[shape for _, shape in outs],
+        scratch_shapes=[pltpu.VMEM((N, R * P), F32)],
+        interpret=interpret, name=name,
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))}))
+
+
+def _running_sum(a, Q: int, layout: str, back: bool = False):
+    """The sums of a [Bt, S, H] float32 over the tokens up to each one inside
+    its chunk of Q (``back``: from each one on), laid out ``bcqh`` [Bt, nc,
+    Q, H] or ``bhcq``: a product with a triangle of ones at full precision,
+    the layout falling out of the product (a cumulative sum over a view with
+    the group's 8 heads minor, and the turns after it, took 2.3 ms a row of
+    8,192 on the chip where these take 0.7)."""
+    Bt, S, H = a.shape
+    ones = jnp.tril(jnp.ones((Q, Q), F32))
+    return jnp.einsum(f"qs,bcsh->{layout}", ones.T if back else ones,
+                      a.reshape(Bt, S // Q, Q, H),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _laid_out(X, dt, A, B, C, D, Q):
+    """(dims, the eight arrays a kernel reads first) from the scan's
+    arguments over whole chunks."""
+    Bt, S, H, P = X.shape
+    G, N = B.shape[2:]
+    R = H // G
+    a = dt * A.astype(F32)
+    col = lambda v: jnp.moveaxis(v.reshape(Bt, S, G, R), 1, 2)
+    row = lambda v: v.reshape(Bt, G, R, S)
+    lanes = jnp.repeat(D.astype(F32), P).reshape(G, 1, R * P)
+    return (Bt, G, S // Q, Q, R, P, N), (
+        X.reshape(Bt, S, H * P), B.reshape(Bt, S, G * N),
+        C.reshape(Bt, S, G * N), col(_running_sum(a, Q, "bcqh")), col(dt),
+        row(_running_sum(a, Q, "bhcq")), row(jnp.swapaxes(dt, 1, 2)), lanes)
+
+
+def _kernel_forward(X, dt, A, B, C, D, Q, interpret, keep: bool):
+    dims, ins = _laid_out(X, dt, A, B, C, D, Q)
+    Bt, G, nc, _, R, P, N = dims
+    outs = [("tokens", jax.ShapeDtypeStruct(ins[0].shape, X.dtype))]
+    if keep:
+        outs.append(("state", jax.ShapeDtypeStruct((Bt, G, nc, N, R * P),
+                                                   F32)))
+    out = _ssd_call(_ssd_fwd_kernel, f"ssd_fwd_q{Q}", dims, False, [], outs,
+                    interpret)(*ins)
+    return [out[0].reshape(X.shape)] + list(out[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan_kernels(X, dt, A, B, C, D, Q, interpret):
+    with jax.named_scope("block/ssm/scan"):
+        return _kernel_forward(X, dt, A, B, C, D, Q, interpret, False)[0]
+
+
+def _scan_kernels_fwd(X, dt, A, B, C, D, Q, interpret):
+    with jax.named_scope("block/ssm/scan"):
+        y, states = _kernel_forward(X, dt, A, B, C, D, Q, interpret, True)
+    return y, (X, dt, A, B, C, D, states)
+
+
+def _scan_kernels_bwd(Q, interpret, saved, dy):
+    X, dt, A, B, C, D, states = saved
+    with jax.named_scope("block/ssm/scan"):
+        dims, ins = _laid_out(X, dt, A, B, C, D, Q)
+        Bt, G, nc, _, R, P, N = dims
+        S, H = X.shape[1], X.shape[2]
+        like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, F32)
+        dx, db, dc, dl, ddt, dd = _ssd_call(
+            _ssd_bwd_kernel, f"ssd_bwd_q{Q}", dims, True,
+            [("state", states), ("tokens", dy)],
+            [("tokens", like(ins[0])), ("bc", like(ins[1])),
+             ("bc", like(ins[2])), ("col", f32(Bt, G, S, R)),
+             ("col", f32(Bt, G, S, R)), ("part", f32(Bt, G, nc, 1, R * P))],
+            interpret)(*ins, states, dy.reshape(ins[0].shape))
+        by_token = lambda c: jnp.moveaxis(c, 1, 2).reshape(Bt, S, H)
+        # l is the running sum of dt A inside a chunk: a token's dt A is in
+        # the l of every token from it on there.
+        da = _running_sum(by_token(dl), Q, "bcqh", back=True).reshape(
+            Bt, S, H)
+        return (dx.reshape(X.shape),
+                (by_token(ddt) + da * A.astype(F32)).astype(dt.dtype),
+                jnp.sum(da * dt, axis=(0, 1)).astype(A.dtype),
+                db.reshape(B.shape), dc.reshape(C.shape),
+                jnp.sum(dd.reshape(Bt, G, nc, R, P), axis=(0, 2, 4)
+                        ).reshape(H).astype(D.dtype))
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
+def _kernels(X, B, Q: int, interpret: bool) -> bool:
+    """Whether the kernels take a scan of these shapes here: a group's
+    channels, the state's size and the chunk each whole lane tiles."""
+    from .attention import LANES, _on_tpu     # at the call: tests steer it
+    H, P = X.shape[2:]
+    G, N = B.shape[2:]
+    return bool((interpret or _on_tpu()) and (H // G * P) % LANES == 0
+                and (LANES % P == 0 or P % LANES == 0)
+                and N % LANES == 0 and Q % LANES == 0)
+
+
+def ssd_scan(X, dt, A, B, C, D, chunk: int, *, interpret: bool = False):
+    """The recurrence above over every row, in chunks of ``chunk`` tokens.
+
+    X [Bt, S, H, P]; dt [Bt, S, H] float32, positive (after the softplus);
+    A [H] negative; B, C [Bt, S, G, N] with H a multiple of G (head h reads
+    group ``h // (H / G)``); D [H].  Returns y [Bt, S, H, P] in X's dtype.
+    Every row starts from a zero state.  On a TPU (or with ``interpret``, for
+    the tests) and where the shapes tile (``_kernels``) the Pallas pair
+    computes it, elsewhere ``jnp``."""
+    _refuse_a_mesh()
+    S, H = X.shape[1:3]
+    G = B.shape[2]
+    if H % G:
+        raise ValueError(f"{H} heads do not split into {G} groups")
+    kernel = _kernels(X, B, chunk, interpret)
+    telemetry.inc("ray_tpu_ssm_path_total",
+                  tags={"path": "kernel" if kernel else "xla",
+                        "chunk": str(chunk)})
+    dt = dt.astype(F32)
+    pad = -S % chunk
+    if pad:
+        # Tokens that keep the state (dt 0: decay 1, nothing added) and that
+        # nothing before them sees.
+        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                                 * (a.ndim - 2))
+        X, dt, B, C = grow(X), grow(dt), grow(B), grow(C)
+    if kernel:
+        y = _scan_kernels(X, dt, A, B, C, D, chunk, interpret)
+    else:
+        with jax.named_scope("block/ssm/scan"):
+            y = _scan_xla(X, dt, A, B, C, D, chunk)
+    return y[:, :S].astype(X.dtype)
+
+
+def _groups(x, groups: int):
+    """The last axis in ``groups`` contiguous slices.  (As slices, and not as
+    a [..., groups, d / groups] view: the TPU compiler tiles that view's two
+    minor dimensions together and writes a copy of it out, and one of every
+    statistic broadcast back over it.)"""
+    w = x.shape[-1] // groups
+    return [x[..., k * w:(k + 1) * w] for k in range(groups)]
+
+
+def _gated(y, z, eps):
+    """(``y * silu(z)`` float32, 1 / rms over the last axis)."""
+    v = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    return v, jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True)
+                            + eps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated_group_norm(y, z, g, groups: int, eps: float = 1e-5):
+    """Gate, then norm: ``v = y * silu(z)``; each of ``groups`` groups of
+    channels divided by its own rms; times g.  y, z [B, S, d], g [d] ->
+    [B, S, d] in y's dtype, float32 inside.
+
+    The backward is written out: two passes over y, z and the cotangent (a
+    group's two sums, then the gradients)."""
+    with jax.named_scope("block/ssm/norm"):
+        out = []
+        for yk, zk, gk in zip(*(_groups(a, groups) for a in (y, z, g))):
+            v, r = _gated(yk, zk, eps)
+            out.append((v * r * gk.astype(F32)).astype(y.dtype))
+        return jnp.concatenate(out, axis=-1)
+
+
+def _norm_fwd(y, z, g, groups, eps):
+    return gated_group_norm(y, z, g, groups, eps), (y, z, g)
+
+
+def _norm_bwd(groups, eps, res, dout):
+    y, z, g = res
+    dy, dz, dg = [], [], []
+    with jax.named_scope("block/ssm/norm"):
+        for yk, zk, gk, dk in zip(*(_groups(a, groups)
+                                    for a in (y, z, g, dout))):
+            v, r = _gated(yk, zk, eps)
+            dk = dk.astype(F32)
+            h = dk * gk.astype(F32)
+            # out = v r g with r = (mean v^2 + eps)^-1/2 over the group.
+            dv = r * h - v * r ** 3 * jnp.mean(v * h, axis=-1, keepdims=True)
+            z32 = zk.astype(F32)
+            sig = jax.nn.sigmoid(z32)
+            dy.append((dv * z32 * sig).astype(y.dtype))
+            dz.append((dv * yk.astype(F32) * sig
+                       * (1.0 + z32 * (1.0 - sig))).astype(z.dtype))
+            dg.append(jnp.sum(dk * v * r, axis=tuple(range(y.ndim - 1))
+                              ).astype(g.dtype))
+        return tuple(jnp.concatenate(a, axis=-1) for a in (dy, dz, dg))
+
+
+gated_group_norm.defvjp(_norm_fwd, _norm_bwd)

@@ -245,6 +245,14 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "reported step, over every sublayer and token: how "
                        "far Sinkhorn's iterations left it from doubly "
                        "stochastic."},
+    "ray_tpu_ssm_chunk_carry": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean over a state-space model's mixers, chunks and "
+                       "heads of exp(sum of dt * A over a chunk) in the last "
+                       "reported step: the share of a recurrent state that "
+                       "a whole chunk hands on.  It moves if someone "
+                       "changes the chunk, drops the carry or starts A_log "
+                       "elsewhere."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,
@@ -444,6 +452,12 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "(ops/hyper.collect), by the path their passes over "
                        "the stream take (kernel: Pallas, forward and "
                        "backward; xla: the jnp forms) and its lanes."},
+    "ray_tpu_ssm_path_total": {
+        "type": "counter", "tag_keys": ("path", "chunk"),
+        "description": "Chunked state-space scans traced "
+                       "(ops/ssm.ssd_scan), by the path they take (kernel: "
+                       "the Pallas pair, forward and backward; xla: the jnp "
+                       "form) and the chunk's tokens."},
     "ray_tpu_norm_path_total": {
         "type": "counter", "tag_keys": ("path", "rows"),
         "description": "Calls of ops/norms.rms_norm traced, by the path "

@@ -25,7 +25,7 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope", "eva", "norm", "hc", "lm")
+              "flash", "rope", "eva", "norm", "hc", "lm", "ssm")
 
 
 class TestCatalog:
@@ -374,7 +374,9 @@ def _smoke_train_fn(config):
                       "moe_sliced_calls": 0.0,
                       # lm and hc: a prediction module's loss and the
                       # hyper-connections' Sinkhorn residual ride there too.
-                      "mtp_loss": 2.0, "hc_sinkhorn_residual": 1e-5})
+                      "mtp_loss": 2.0, "hc_sinkhorn_residual": 1e-5,
+                      # ssm: the share of a state a chunk hands on
+                      "ssm_chunk_carry": 0.3})
 
 
 @serve.deployment(name="telemetry_echo")
