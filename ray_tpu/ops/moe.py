@@ -20,6 +20,30 @@ on the indices of one counting sort a call (``_places``): gathers and
 dense passes, forward and backward, and no scatter of rows, places or
 counts.  On a TPU v5e a gathered row of 4 KB costs 6.4 ns and a
 scatter-added one 88 (PERF.md, PR 30).
+
+The grouped products are upstream's two Pallas kernels (megablox ``gmm``
+and ``tgmm``) under a ``custom_vjp`` of this module (``grouped_matmul``),
+and the tiles of each follow the call's shapes (``_gmm_tiles``).  ``gmm``
+walks the buffer in tiles of ``tm`` rows and VISITS, for every group, every
+tile the group touches, paying a whole tile's products a visit: a group of
+``n`` rows that starts anywhere costs about ``n / tm + 1`` visits, so the
+share of the products that is work is ``tiles in use / (tiles in use +
+groups - 1)``.  With one row of 8,192 tokens a call a held expert gets
+384-512 rows, and at ``tm`` 512 every tile straddles two groups and is
+computed twice (30 / 40 / 46 % of the roofline in the three sparse cells;
+PERF.md, PR 44).  The three kernels a product meets want different tiles:
+the forward product and the rows' gradient (``gmm``, the second with the
+weights transposed) a low tile of rows, which pays only while the whole
+contraction is one tile, because the weight block's index then stays put
+over a group's visits and the pipeline does not fetch it again (with k in
+tiles the block is fetched anew every grid step: 2 MB against a few
+microseconds of products); the weights' gradient (``tgmm``) contracts over
+``tm``, its bytes a step do not depend on it, and its result's tiles cover
+k and n with the least padding.  Every tile set fits Mosaic's DEFAULT
+scoped VMEM (16 MiB), double buffers and the float32 accumulator counted
+(``_VMEM_BUDGET``): ``vmem_limit_bytes`` is never raised, because a kernel
+that asks for more hangs Xing4.0's compiled step in its first call
+(PERF.md, PR 42).
 """
 
 from __future__ import annotations
@@ -29,6 +53,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..util import telemetry
 
 
 class SigmoidRouting(NamedTuple):
@@ -68,32 +94,176 @@ def update_selection_bias(bias, counts, rate: float = 1e-3):
     return bias + d - jnp.mean(d, axis=-1, keepdims=True)
 
 
-#: (m, k, n) tiles of the grouped products on the chip (PERF.md, PR 29).
-GMM_TILING = (512, 1024, 1024)
-
 #: The dropless buffer holds 1 / BUFFER_TIERS of the worst case's rows.
 BUFFER_TIERS = 4
 
+#: The tiles every grouped product took until PR 44 (PERF.md, PR 29: best
+#: of six at 2,048 rows a group), still ``tgmm``'s where groups are tall and
+#: ``gmm``'s where the contraction is too long to stay whole; 1,024 is the
+#: widest k / n tile of ``tgmm``.
+_BIG_TILES = (512, 1024, 1024)
 
-def grouped_matmul(lhs, rhs, group_sizes, impl: Optional[str] = None):
+#: Rows of a tile where a group is a few tiles tall or less, and the rows a
+#: group from which ``tgmm`` reads faster at 512 again (PERF.md, PR 44,
+#: step 0: 256 wins by 7 % at 1,024 rows a group, 512 by 1.5 % at 4,096).
+_LOW_TILE, _TALL_GROUP = 256, 4096
+
+#: What the double buffers of a grid step's three blocks and its float32
+#: accumulator may count: three quarters of Mosaic's default scoped VMEM of
+#: 16 MiB, the rest being the body's own temporaries (a float32 product, a
+#: transposed block: the compiler's own count passed this one by up to
+#: 3.0 MiB over 321 tile sets compiled for a v5e; PERF.md, PR 44).
+_VMEM_BUDGET = 12 * 2 ** 20
+
+#: the three kernels a grouped product meets
+GMM_KINDS = ("fwd", "dlhs", "tgmm")
+
+
+def _gmm_vmem_bytes(kind: str, tm: int, tk: int, tn: int,
+                    itemsize: int = 2) -> int:
+    """Scoped VMEM a grid step of upstream's kernel holds: two buffers of
+    each of its three blocks and the float32 accumulator.  ``gmm`` ("fwd",
+    "dlhs") accumulates a [tm, tn] tile of rows over k; ``tgmm`` a [tk, tn]
+    tile of one group's weights over chunks of tm rows."""
+    if kind == "tgmm":
+        return 2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _cover(size: int, most: int) -> int:
+    """The tile up to ``most`` that covers ``size`` with the least padding:
+    ``size`` itself where it fits, else a multiple of 128 (the largest at a
+    tie).  2,688 -> 896 (x 3), 1,856 -> 640 (x 3 = 1,920), 3,584 -> 896."""
+    if size <= most:
+        return size
+    return min(range(most - most % 128, 127, -128),
+               key=lambda t: -(-size // t) * t - size)
+
+
+def _row_tile(tile: int, rows: int) -> int:
+    """Upstream asks that ``tm`` divide the buffer's rows: the largest
+    multiple of 128 up to ``tile`` that does, else of 8 (a sublane tile),
+    else the largest divisor."""
+    for step in (128, 8, 1):
+        for t in range(min(tile, rows) // step * step, 0, -step):
+            if rows % t == 0:
+                return t
+    return rows
+
+
+def _gmm_tiles(kind: str, rows_a_group: float, k: int, n: int):
+    """(tm, tk, tn) of one of the three kernels of a grouped product, from
+    the call's shapes alone.  ``kind``: "fwd" (``gmm``: rows [R, k] times
+    weights [G, k, n]), "dlhs" (``gmm`` on transposed weights [G, n, k]: the
+    rows' gradient, which contracts the forward's n) or "tgmm" (the weights'
+    gradient [G, k, n], contracting the rows).  ``k`` and ``n`` are the
+    KERNEL's, as upstream's tiling is.  ``rows_a_group``: the rows a group
+    is expected to hold (static: T * k / X for a layer call).
+
+    A visit pays a whole tile's products, so a tile is low
+    (``_LOW_TILE``: at 384-512 rows a group 256 and 128 read the same,
+    1.2-1.7 times faster than 512; PERF.md, PR 44).  For ``gmm`` a low
+    tile pays only with the contraction whole: the weight block ``(group,
+    0, n)`` then keeps its index over a group's visits and is fetched once
+    a group, where with k in tiles it is fetched anew every step.  So
+    tk = k, and tn covers n with the least padding among the widths that
+    keep the step inside ``_VMEM_BUDGET``; that read faster than today's
+    tiles at every height from 128 to 8,192 rows a group, so ``gmm`` does
+    not ask for the rows.  A k too long for that at tn 512 keeps today's
+    tiles.  ``tgmm`` contracts over tm, reads tm * (tk + tn) a step
+    whatever tm is, covers k and n with the least padding up to 1,024, and
+    takes the tall tile again from ``_TALL_GROUP`` rows a group."""
+    if kind == "tgmm":
+        tm = _LOW_TILE if rows_a_group < _TALL_GROUP else _BIG_TILES[0]
+        return (tm, _cover(k, _BIG_TILES[1]), _cover(n, _BIG_TILES[2]))
+    fits = next((tn for tn in range(2 * _BIG_TILES[2], 511, -128)
+                 if _gmm_vmem_bytes(kind, _LOW_TILE, k, tn) <= _VMEM_BUDGET),
+                None)
+    return (_LOW_TILE, k, _cover(n, fits)) if fits else _BIG_TILES
+
+
+def _tiles_of(kind, rows, k, n, rows_a_group, tiling):
+    """The tiles of one kernel of a call: an explicit ``tiling`` or
+    ``_gmm_tiles``' pick, cut to the operand (``tm`` to a divisor of the
+    buffer's ``rows``) and counted."""
+    tm, tk, tn = tiling or _gmm_tiles(kind, rows_a_group, k, n)
+    t = (_row_tile(tm, rows), min(tk, k), min(tn, n))
+    telemetry.inc("ray_tpu_gmm_tile_geometry_total", tags={
+        "kind": kind, "tm": str(t[0]), "tk": str(t[1]), "tn": str(t[2]),
+        "rows_a_group": str(int(rows_a_group))})
+    return t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gmm(lhs, rhs, group_sizes, rows_a_group, tiling, interpret):
+    """Upstream's ``gmm`` under a rule of this module's, so that the
+    forward product, the rows' gradient and the weights' gradient each take
+    tiles of their own (upstream's ``ops.gmm`` hands all three one)."""
+    # the submodule by its full name: the package exports the function
+    # ``gmm`` (upstream's custom_vjp) over the module's
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    (R, K), N = lhs.shape, rhs.shape[2]
+    return gmm(
+        lhs, rhs, group_sizes, lhs.dtype,
+        _tiles_of("fwd", R, K, N, rows_a_group, tiling), interpret=interpret)
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, rows_a_group, tiling, interpret):
+    return (_gmm(lhs, rhs, group_sizes, rows_a_group, tiling, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _gmm_bwd(rows_a_group, tiling, interpret, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    lhs, rhs, group_sizes = res
+    (R, K), (G, _, N) = lhs.shape, rhs.shape
+    d_lhs = gmm(
+        g, rhs, group_sizes, lhs.dtype,
+        _tiles_of("dlhs", R, N, K, rows_a_group, tiling),
+        transpose_rhs=True, interpret=interpret)
+    d_rhs = tgmm(
+        lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+        _tiles_of("tgmm", R, K, N, rows_a_group, tiling),
+        num_actual_groups=G, interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl: Optional[str] = None,
+                   rows_a_group: Optional[float] = None, tiling=None):
     """``lhs[rows of group g] @ rhs[g]``: lhs [R, K] sorted by group, rhs
     [G, K, N], group_sizes [G] int32 -> [R, N].  Rows past the last group
-    are unspecified (the Pallas kernel does not visit them, so its time
-    follows the rows in use and not R); callers mask them.
+    are unspecified, forward and backward (the Pallas kernels do not visit
+    them, so their time follows the rows in use and not R); callers mask
+    them.
 
     ``impl``: "gmm" is upstream's Pallas grouped matmul (megablox; ``gmm``
     and ``tgmm`` in a device trace), "gmm_interpret" the same interpreted,
-    "ragged_dot" is ``lax.ragged_dot``; None takes "gmm" on a TPU."""
+    "ragged_dot" is ``lax.ragged_dot``; None takes "gmm" on a TPU.
+
+    The Pallas path is upstream's two kernels under this module's own
+    ``custom_vjp``: ``gmm`` for the product, ``gmm`` on the transposed
+    weights for the rows' gradient, ``tgmm`` for the weights' gradient.  A
+    kernel VISITS, for every group, every tile of ``tm`` rows the group
+    touches, and pays a whole tile's products a visit; so each of the three
+    takes the tiles ``_gmm_tiles`` picks for its shapes and for
+    ``rows_a_group``, the rows a group is expected to hold (static; None:
+    R / G, a full buffer), bf16 operands and float32 accumulation whatever
+    the tiles.  An explicit ``tiling`` (tm, tk, tn) wins, for all three as
+    upstream's does.  No tile set asks for more than Mosaic's default
+    scoped VMEM (``_VMEM_BUDGET``)."""
     if impl is None:
         impl = "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
     if impl == "ragged_dot":
         return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                                   preferred_element_type=lhs.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-    tiling = tuple(min(t, d) for t, d in zip(
-        GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])))
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
-               interpret=impl == "gmm_interpret")
+    if rows_a_group is None:
+        rows_a_group = lhs.shape[0] / rhs.shape[0]
+    return _gmm(lhs, rhs, group_sizes, float(rows_a_group),
+                None if tiling is None else tuple(tiling),
+                impl == "gmm_interpret")
 
 
 def _take(v, at):
@@ -237,11 +407,14 @@ ACTIVATIONS = {"silu": jax.nn.silu,
 
 
 def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
-               activation="silu"):
+               activation="silu", experts=None):
     """The held experts' part for tokens xt [T, E] in a buffer of ``rows``
     rows: (out [T, E], rows in use).  Right whenever the assignments to held
-    experts number at most ``rows``.  ``w_gate`` None: an un-gated expert."""
+    experts number at most ``rows``.  ``w_gate`` None: an un-gated expert.
+    ``experts``: all X the router chose among, so that a held one expects
+    T * k / X rows (None: the buffer's rows over the held)."""
     Xh, act = w_up.shape[0], ACTIVATIONS[activation]
+    expected = top.size / experts if experts else None
     with jax.named_scope("dispatch"):
         local = top - held_start
         local = jnp.where((local >= 0) & (local < Xh), local, Xh)
@@ -249,7 +422,7 @@ def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
     x_rows = rows_of_tokens(xt, at)
     with jax.named_scope("experts"):
         mm = functools.partial(grouped_matmul, group_sizes=at.sizes,
-                               impl=impl)
+                               impl=impl, rows_a_group=expected)
         if w_gate is None:
             h = act(mm(x_rows, w_up))
         else:
@@ -301,7 +474,8 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     rows = buffer_rows(T, k)
     run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
                             w_down=w_down, held_start=held_start, rows=rows,
-                            impl=impl, activation=activation)
+                            impl=impl, activation=activation,
+                            experts=routing.counts.shape[0])
     if rows == T * k:
         out, used = run(xt, top, w)
         return out, (held, held - used)

@@ -432,6 +432,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "from the call's shapes (scores: qk or kq; d_qk "
                        "and d_v only where a call's values are not as "
                        "wide as its keys)."},
+    "ray_tpu_gmm_tile_geometry_total": {
+        "type": "counter",
+        "tag_keys": ("kind", "tm", "tk", "tn", "rows_a_group"),
+        "description": "Grouped-product kernels traced "
+                       "(ops/moe.grouped_matmul), by the tiles that "
+                       "ops/moe._gmm_tiles chose from the call's shapes: "
+                       "kind fwd (gmm), dlhs (gmm on transposed weights, "
+                       "the rows' gradient) or tgmm (the weights' "
+                       "gradient); rows_a_group the rows a group is "
+                       "expected to hold."},
     "ray_tpu_eva_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "block_s",

@@ -25,7 +25,7 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope", "eva", "norm", "hc", "lm", "ssm")
+              "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm")
 
 
 class TestCatalog:
@@ -446,6 +446,12 @@ class TestSmokeAllSubsystems:
         from ray_tpu.ops.attention import flash_attention
         x = jnp.ones((1, 2, 64, 32), jnp.float32)
         flash_attention(x, x[:, :1], x[:, :1], interpret=True)
+
+        # -- gmm: a traced grouped product counts the tiles its kernel took.
+        from ray_tpu.ops.moe import grouped_matmul
+        grouped_matmul(jnp.zeros((16, 8), jnp.float32),
+                       jnp.zeros((2, 8, 8), jnp.float32),
+                       jnp.asarray([5, 9], jnp.int32), "gmm_interpret")
 
         # -- rope: a traced rotary embedding counts the path it took.
         from ray_tpu.ops.rope import rope_lane_tables, rotate_heads
