@@ -19,7 +19,19 @@ that are each other's transpose (``rows_of_tokens``, ``tokens_from_rows``)
 on the indices of one counting sort a call (``_places``): gathers and
 dense passes, forward and backward, and no scatter of rows, places or
 counts.  On a TPU v5e a gathered row of 4 KB costs 6.4 ns and a
-scatter-added one 88 (PERF.md, PR 30).
+scatter-added one 88 (PERF.md, PR 30).  Into the buffer a row reads its
+token (one XLA gather of R rows).  Out of it a token sums the rows it
+holds, and on a TPU that is a Pallas kernel (``_sum_rows_kernel``) that
+copies only the rows in use: a group's rows are in token order, so the rows
+a tile of tokens holds of one expert are one contiguous run of the buffer,
+and the kernel copies the Xh runs of a tile and adds each row to its
+token's float32 sum in VMEM.  The ``jnp`` form it replaced there
+(``_sum_rows_xla``, still what runs off the chip) gathers all T * k slots
+of a call where an eighth hold a row and sums over k: 0.86 ms a time at
+k 8, and 3.04 / 2.30 ms at k 6 / 4, where k in the second-minor place of
+the float32 ``[T, k, E]`` array does not fill a tile of 8 and XLA copies
+the whole array into a padded layout first; the kernel takes 0.28 / 0.31 /
+0.27 ms at the three sparse cells' shapes (PERF.md, PR 45).
 
 The grouped products are upstream's two Pallas kernels (megablox ``gmm``
 and ``tgmm``) under a ``custom_vjp`` of this module (``grouped_matmul``),
@@ -295,15 +307,37 @@ def _first_set(running, size):
     return jnp.minimum(block * 128 + inside, L)
 
 
+#: tokens between two entries of ``_Places.starts``: the smallest tile of
+#: tokens of ``_sum_rows_kernel``
+_GRANULE = 64
+
+#: copies of runs in flight in ``_sum_rows_kernel``
+_RUNS_IN_FLIGHT = 8
+
+#: rows a copy starts on a multiple of: a bf16 tile's
+_RUN_ALIGN = 16
+
+#: the tests' way in: the kernel path off the chip, interpreted
+_INTERPRET_ROWS = False
+
+
 class _Places(NamedTuple):
     """Where the rows of one call's buffer come from and go back to: the
     indices of one counting sort, computed once a call (``_places``) and
-    shared by dispatch and combine, forward and backward."""
+    shared by dispatch and combine, forward and backward.  ``tok`` and
+    ``live`` serve the gather into the buffer, ``row`` the ``jnp`` sum out
+    of it and the weights' gradient, ``starts`` and ``tok`` the kernel's
+    (``_sum_rows_kernel``: the runs it copies, and each row's token),
+    ``slot`` the rows' weights."""
     sizes: jax.Array    # [Xh] rows of each held expert
     live: jax.Array     # [R, 1] bool: a group holds the row
     tok: jax.Array      # [R] the row's token
     slot: jax.Array     # [R] the row's assignment, t * k + j
     row: jax.Array      # [T, k] the assignment's row; R where it has none
+    starts: jax.Array   # [Xh, T / _GRANULE + 1] first row of each held
+    #                     expert for the tokens from g * _GRANULE on (a
+    #                     group's rows are in token order); its last, the
+    #                     group's end; none past R
 
 
 def _places(local, Xh, rows):
@@ -332,25 +366,203 @@ def _places(local, Xh, rows):
     which = jnp.sum(jnp.where(chosen, jnp.arange(k)[None, :, None], 0),
                     axis=1)                                  # [T, Xh]: j
     slot = tok * k + _take(which.reshape(T * Xh), tok * Xh + expert)
+    ends = running.reshape(Xh, T)
+    starts = jnp.concatenate([(ends - cells.T)[:, ::_GRANULE], ends[:, -1:]],
+                             axis=1)
     return _Places(jnp.sum(cells, axis=0, dtype=jnp.int32), live, tok, slot,
-                   row), used
+                   row, jnp.minimum(starts, rows)), used
 
 
 def _gather_rows(x, at: _Places):
     return jnp.where(at.live, x[at.tok], 0)
 
 
-def _sum_rows(y, w, at: _Places):
-    """[T, E]: ``sum_j w[t, j] * y[at.row[t, j]]`` over the assignments that
-    have a row (``w`` None: 1), in float32, rounded once.  One gather over
-    all [T, k] slots: on the chip a gathered row costs 6.4 ns where a
-    scatter-added one cost 88 (PERF.md, PR 30)."""
+def _sum_rows_xla(y, w, at: _Places):
+    """``_sum_rows`` in ``jnp``: one gather over all [T, k] slots, a select
+    and a float32 sum over k.  What runs off the chip, and what the kernel
+    is tested against."""
     R = y.shape[0]
     z = y[jnp.minimum(at.row, R - 1)].astype(jnp.float32)    # [T, k, E]
     if w is not None:
         z = z * w[..., None]
     return jnp.sum(jnp.where((at.row < R)[..., None], z, 0),
                    axis=1).astype(y.dtype)
+
+
+def _run_rows(R: int, Xh: int, tiles: int) -> int:
+    """Rows one copy of ``_sum_rows_kernel`` fetches: the run of a tile and
+    an expert at a FULL buffer (twice the usual load) and ``_RUN_ALIGN``
+    for where it starts, in whole tiles of rows; a longer run takes a
+    second copy."""
+    run = R // (Xh * tiles) + _RUN_ALIGN
+    return min(R, -(-run // _RUN_ALIGN) * _RUN_ALIGN)
+
+
+def _rows_tile(T: int, R: int, E: int, Xh: int, dtype) -> Optional[int]:
+    """Tokens of a grid step of ``_sum_rows_kernel``, from what the call can
+    see; None where it goes down the ``jnp`` form: off the chip (but for
+    ``_INTERPRET_ROWS``), lanes not in whole tiles, a dtype the kernel was
+    not written for, a token count ``_GRANULE`` does not divide.  The
+    largest tile whose blocks stay inside ``_VMEM_BUDGET``: the copies in
+    flight, a run in float32, the tile's float32 sum and the two buffers of
+    its result."""
+    from .attention import LANES, _on_tpu   # at the call: tests steer it
+    if not ((_on_tpu() or _INTERPRET_ROWS) and E % LANES == 0
+            and R % _RUN_ALIGN == 0
+            and dtype in (jnp.bfloat16, jnp.float32)):
+        return None
+    size = jnp.dtype(dtype).itemsize
+    for tm in (512, 256, 128, _GRANULE):
+        if T % tm:
+            continue
+        run = _run_rows(R, Xh, T // tm)
+        if (_RUNS_IN_FLIGHT * run * size + 4 * run + 4 * tm
+                + 2 * tm * size) * E <= _VMEM_BUDGET:
+            return tm
+    return None
+
+
+def _sum_rows_body(starts, tok, w, y, out, stage, wide, acc, sems, *, Xh,
+                   tm, run, R, weighted):
+    """One tile of ``tm`` tokens.  A group's rows are in token order, so the
+    tile's rows of one held expert are one contiguous run of the buffer,
+    from one entry of ``starts`` to a later one: the runs are copied out of
+    HBM, up to
+    ``_RUNS_IN_FLIGHT`` at once, ``run`` rows a copy from a multiple of
+    ``_RUN_ALIGN`` at or under the run's first row, and each row of a run
+    is added, times its weight, to its token's row of the tile's float32
+    sum.  Only rows INSIDE a run are added (the copies bring neighbours
+    along, and rows no group holds: never read past the conversion)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    i = pl.program_id(0)
+    step = tm // _GRANULE
+    entries = pl.num_programs(0) * step + 1     # of ``starts``, an expert
+    acc[...] = jnp.zeros_like(acc)
+
+    def run_of(e):
+        a = starts[e * entries + i * step]
+        b = starts[e * entries + (i + 1) * step]
+        base = a // _RUN_ALIGN * _RUN_ALIGN
+        return a, b, base, jnp.where(b > a, (b - base + run - 1) // run, 0)
+
+    total = Xh * jax.lax.fori_loop(
+        0, Xh, lambda e, most: jnp.maximum(most, run_of(e)[3]), 0)
+
+    def item(q):
+        """Copy ``q`` of the tile: round q // Xh of expert q % Xh."""
+        a, b, base, copies = run_of(q % Xh)
+        nth = q // Xh
+        first = base + nth * run
+        start = pl.multiple_of(jnp.minimum(first, R - run), _RUN_ALIGN)
+        return (nth < copies, start, jnp.maximum(a, first),
+                jnp.minimum(b, first + run))
+
+    def copy(q, start):
+        slot = q % _RUNS_IN_FLIGHT
+        return pltpu.make_async_copy(y.at[pl.ds(start, run)], stage.at[slot],
+                                     sems.at[slot])
+
+    def issue(q):
+        there, start, _, _ = item(q)
+
+        @pl.when(jnp.logical_and(q < total, there))
+        def _():
+            copy(q, start).start()
+
+    def ahead(q, carry):
+        issue(q)
+        return carry
+
+    jax.lax.fori_loop(0, _RUNS_IN_FLIGHT, ahead, 0)
+
+    def visit(q, carry):
+        there, start, lo, hi = item(q)
+
+        @pl.when(there)
+        def _():
+            copy(q, start).wait()
+            slot = q % _RUNS_IN_FLIGHT
+
+            def widen(g, c):
+                at = pl.multiple_of(g * _RUN_ALIGN, _RUN_ALIGN)
+                wide[pl.ds(at, _RUN_ALIGN), :] = stage[
+                    slot, pl.ds(at, _RUN_ALIGN), :].astype(jnp.float32)
+                return c
+
+            jax.lax.fori_loop((lo - start) // _RUN_ALIGN,
+                              (hi - start + _RUN_ALIGN - 1) // _RUN_ALIGN,
+                              widen, 0)
+
+            def add(r, c):
+                t = tok[r] - i * tm
+                v = wide[pl.ds(r - start, 1), :]
+                if weighted:
+                    v = v * w[r]
+                acc[pl.ds(t, 1), :] = acc[pl.ds(t, 1), :] + v
+                return c
+
+            jax.lax.fori_loop(lo, hi, add, 0)
+
+        issue(q + _RUNS_IN_FLIGHT)
+        return carry
+
+    jax.lax.fori_loop(0, total, visit, 0)
+    out[...] = acc[...].astype(out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _sum_rows_kernel(y, w_rows, starts, tok, *, tm: int, interpret: bool):
+    """``_sum_rows`` as a Pallas kernel over tiles of ``tm`` tokens: the
+    buffer stays in HBM and only the runs of rows the tile's tokens hold
+    are copied (``_sum_rows_body``); ``w_rows`` [R] float32 the rows'
+    weights, None for 1; ``starts`` and ``tok`` are ``_Places``'.  Rows,
+    weights and run starts ride as scalars.  A jit of its own, so that the
+    layers of a step that call it at one shape trace and lower it once
+    (0.5 s a time on the host: 20 of them made a cell's cached step 15 s
+    slower to fetch; PERF.md, PR 45)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    (R, E), Xh = y.shape, starts.shape[0]
+    tiles = (starts.shape[1] - 1) * _GRANULE // tm
+    run = _run_rows(R, Xh, tiles)
+    weighted = w_rows is not None
+    body = functools.partial(_sum_rows_body, Xh=Xh, tm=tm, run=run, R=R,
+                             weighted=weighted)
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tm, E), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((_RUNS_IN_FLIGHT, run, E), y.dtype),
+                pltpu.VMEM((run, E), jnp.float32),
+                pltpu.VMEM((tm, E), jnp.float32),
+                pltpu.SemaphoreType.DMA((_RUNS_IN_FLIGHT,))]),
+        out_shape=jax.ShapeDtypeStruct((tiles * tm, E), y.dtype),
+        interpret=interpret,
+        name="rows_sum_weighted" if weighted else "rows_sum",
+    )(starts.reshape(-1), tok,
+      w_rows if weighted else jnp.zeros((1,), jnp.float32), y)
+
+
+def _sum_rows(y, w, at: _Places, op: str):
+    """[T, E]: ``sum_j w[t, j] * y[at.row[t, j]]`` over the assignments that
+    have a row (``w`` None: 1), in float32, rounded once; rows no group
+    holds are never added.  On a TPU a kernel that copies the rows in use
+    (``_sum_rows_kernel``), elsewhere ``_sum_rows_xla``; ``op`` names the
+    mover for the counter."""
+    T, k = at.row.shape
+    tm = _rows_tile(T, *y.shape, at.sizes.shape[0], y.dtype)
+    telemetry.inc("ray_tpu_moe_rows_path_total", tags={
+        "path": "kernel" if tm else "xla", "op": op, "tokens": str(T),
+        "slots": str(k), "lanes": str(y.shape[1])})
+    if tm is None:
+        return _sum_rows_xla(y, w, at)
+    w_rows = None if w is None else _take(w.reshape(-1), at.slot)
+    return _sum_rows_kernel(y, w_rows, at.starts, at.tok, tm=tm,
+                            interpret=_INTERPRET_ROWS)
 
 
 @jax.custom_vjp
@@ -367,7 +579,7 @@ def _rows_fwd(x, at):
 
 def _rows_bwd(at, g):
     with jax.named_scope("dispatch"):
-        return _sum_rows(g, None, at), None
+        return _sum_rows(g, None, at, "rows_of_tokens_bwd"), None
 
 
 rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
@@ -380,7 +592,7 @@ def tokens_from_rows(y, w, at: _Places):
     select, not a product: they may hold anything).  Its transpose is
     ``rows_of_tokens`` times the rows' weights, and a row-dot for ``w``."""
     with jax.named_scope("combine"):
-        return _sum_rows(y, w, at)
+        return _sum_rows(y, w, at, "tokens_from_rows")
 
 
 def _tokens_fwd(y, w, at):
@@ -460,13 +672,20 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     of the tokens, one after the other through the same buffer (a slice of
     T / BUFFER_TIERS tokens has at most that many assignments).
 
-    Rows move by gathers alone: into the buffer each row reads its token
-    (``rows_of_tokens``), out of it each token reads the rows of its k
-    assignments and sums them in float32 (``tokens_from_rows``: a select
-    where an assignment has no row), and the backward of each is the other.
-    The gather into the buffer and the activation cost the buffer's whole
-    size, the gather out of it T*k slots whatever their number in use, the
-    grouped products only the rows in use."""
+    Rows move without a scatter: into the buffer each row reads its token
+    (``rows_of_tokens``), out of it each token sums the rows it holds, each
+    times its weight, in float32 (``tokens_from_rows``; rows no group holds
+    are never read), and the backward of each is the other.  What each
+    costs on a TPU v5e at the sparse cells' shapes (one row of 8,192
+    tokens, an eighth of the slots held; PERF.md, PR 45): the gather into
+    the buffer its whole size (R rows read and written, 0.10 ms, and the
+    select that zeroes the rows no group holds 0.09-0.20 ms more), as do
+    the activation and the backward's row passes; the sum out of it the
+    rows in use (the kernel: 0.27-0.31 ms, a third of it a tile's fixed
+    costs, the rest two cycles a row's vector register: each row is added
+    to its token's row of the sum on its own); the grouped products the
+    rows in use; ``_places`` about 0.2 ms, twice a call (the recomputed
+    forward makes it again)."""
     T, k = routing.expert_index.shape
     Xh, tiers = w_up.shape[0], BUFFER_TIERS
     top, w = routing.expert_index, routing.weights

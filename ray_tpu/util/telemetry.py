@@ -442,6 +442,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "the rows' gradient) or tgmm (the weights' "
                        "gradient); rows_a_group the rows a group is "
                        "expected to hold."},
+    "ray_tpu_moe_rows_path_total": {
+        "type": "counter",
+        "tag_keys": ("path", "op", "tokens", "slots", "lanes"),
+        "description": "Sums over a token's rows of the experts' buffer "
+                       "traced (ops/moe._sum_rows), by the path taken "
+                       "(kernel: the Pallas kernel that copies the runs of "
+                       "rows in use; xla: the jnp gather over all slots), "
+                       "the mover (op: tokens_from_rows, or "
+                       "rows_of_tokens_bwd) and the call's tokens, slots a "
+                       "token (k) and lanes a row."},
     "ray_tpu_eva_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "block_s",

@@ -505,8 +505,8 @@ def test_trinity_train_step_compiles_at_the_cell_sizes(trinity_step, capsys):
         assert name in text, name
     # bf16 weights and two bf16 moments of 1,243 M parameters.
     assert 7.4e9 < mem.argument_size_in_bytes < 7.6e9
-    # 9.69 GB of temporaries with the scatters (PR 29); the gather over all
-    # of a token's slots holds [T, k, E] for a moment.
+    # 9.69 GB of temporaries with the scatters (PR 29); 9.71 GB since the
+    # sums over a token's rows are kernels (PR 45).
     assert mem.temp_size_in_bytes < 10.5e9
     # Rows and counts move by gathers and dense passes alone.  (Upstream's
     # grouped matmul builds its tiles' table with a scatter-add of 47
@@ -795,3 +795,44 @@ def test_nemotron_train_step_compiles_at_the_cell_sizes(nemotron_step,
                   "block/ssm/norm", "block/ssm", "block/attn",
                   "block/moe/experts", "block/moe/shared", "loss"):
         assert scopes.seconds_under(by, scope) > 0, scope
+
+
+@pytest.mark.parametrize("step,T,k,E", [
+    ("trinity_step", 8192, 8, 2048), ("xing4_step", 8192, 4, 3584),
+    ("nemotron_step", 8192, 6, 2688)], ids=["trinity", "xing4", "nemotron"])
+def test_rows_leave_the_experts_buffer_by_the_rows_in_use(step, T, k, E,
+                                                         request):
+    """In the three sparse cells' compiled steps the sums over a token's
+    rows are the Mosaic kernels (``tokens_from_rows`` under ``combine``,
+    ``rows_of_tokens``' backward under ``dispatch``, in the branch that takes
+    the buffer at once and in the slices'), inside Mosaic's default scoped
+    VMEM (the compile refuses more), by names no roofline reader's pattern
+    takes for another's; no array of all T * k slots is in the text, and
+    no scatter of rows."""
+    import re
+
+    from benchmark import scopes
+    text = request.getfixturevalue(step)["text"]
+    names = scopes.op_names(text)
+    calls = [line.strip().partition(" = ")[0].lstrip("%")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    under = {"rows_sum_weighted": "block/moe/combine",
+             "rows_sum": "block/moe/dispatch"}
+    seen = {}
+    for call in calls:
+        kernel = re.sub(r"[.\d]+$", "", call)
+        if kernel in under:
+            assert under[kernel] in scopes.scope_path(names[call]), call
+            seen[kernel] = seen.get(kernel, 0) + 1
+        else:
+            assert "rows_sum" not in kernel, call
+    assert set(seen) == set(under) and min(seen.values()) >= 2, seen
+    for pattern in ("gmm", "flash_", "ssd_", "hc_", "ragged-dot"):
+        assert not any(pattern in kernel for kernel in under)
+    assert f"[{T},{k},{E}]" not in text and f"[{T * k},{E}]" not in text
+    scatters = [line for line in text.splitlines()
+                if " scatter(" in line and "block/moe" in line
+                and "/experts/" not in line]
+    assert not scatters, scatters[:2]
+
