@@ -261,49 +261,3 @@ def decode_step(params: Dict[str, Any], kv_pages,
         logits = jnp.einsum("be,ev->bv", x[:, 0, :], params["lm_head"],
                             preferred_element_type=jnp.float32)
         return logits.astype(jnp.float32), kv_pages
-
-
-def decode_chunk(params: Dict[str, Any], kv_pages,
-                 tokens: jax.Array, positions: jax.Array,
-                 block_tables: jax.Array, active: jax.Array,
-                 rng_key: jax.Array, cfg: LlamaConfig, page_size: int,
-                 steps: int, temperature: float, top_k: int):
-    """Device-resident multi-token decode: ``steps`` decode iterations
-    under one jit with ON-DEVICE sampling, so the host syncs once per
-    chunk instead of once per token.  Where the per-step device-to-host
-    read dominates, this is the difference between latency-bound and
-    compute-bound decode — the TPU-native analog of the reference
-    engine's multi-step scheduling (reference: vLLM num_scheduler_steps
-    / multi-step decode).
-
-    tokens/positions/active: [B] as in decode_step.  Returns
-    (sampled [steps, B], new positions, kv_pages).  Sampling:
-    greedy when temperature <= 0 else top-k/categorical, per-step keys
-    folded from ``rng_key``.  Stop tokens are enforced by the HOST after
-    the chunk (bounded overgeneration by design)."""
-
-    def sample(logits, key):
-        if temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        logits = logits / temperature
-        if top_k:
-            kth = jax.lax.top_k(logits, top_k)[0][:, -1:]
-            logits = jnp.where(logits < kth, NEG_INF, logits)
-        return jax.random.categorical(key, logits, axis=-1).astype(
-            jnp.int32)
-
-    def body(carry, i):
-        toks, pos, kv = carry
-        logits, kv = decode_step(params, kv, toks, pos,
-                                 block_tables, active, cfg, page_size)
-        nxt = sample(logits, jax.random.fold_in(rng_key, i))
-        nxt = jnp.where(active, nxt, toks)
-        pos = jnp.where(active, pos + 1, pos)
-        return (nxt, pos, kv), nxt
-
-    # lax.scan keeps one copy of the (donated) cache live across steps.
-    import jax.lax as lax
-    (_, positions, kv_pages), out = lax.scan(
-        body, (tokens, positions, kv_pages),
-        jnp.arange(steps, dtype=jnp.int32))
-    return out, positions, kv_pages

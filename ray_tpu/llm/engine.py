@@ -64,11 +64,8 @@ class Request:
     # First-token time (perf_counter); 0.0 until the first token lands.
     t_first: float = 0.0
     # Admission sequence (preemption picks the youngest victim; -1 =
-    # never admitted) and incarnation counter (bumped on preemption so
-    # in-flight chunk snapshots from the previous residency never apply
-    # to a re-admitted request).
+    # never admitted).
     admit_seq: int = -1
-    gen: int = 0
     # Per-output-token perf_counter stamps, recorded only when the
     # engine was built with record_token_times=True (serve_load bench:
     # inter-token latency percentiles need per-token arrival times).
@@ -171,18 +168,6 @@ class InferenceEngine:
             _named("decode_step", _model.decode_step, cfg=cfg,
                    page_size=page_size),
             donate_argnums=(1,))
-        self._decode_chunk = None
-        # (steps, temp, top_k) -> jit fn.  LRU-bounded: varied sampling
-        # params across serving traffic must not grow the compiled-program
-        # set (and its device executable memory) without bound.
-        from collections import OrderedDict
-        self._chunk_cache: "OrderedDict" = OrderedDict()
-        self._chunk_cache_cap = 32
-        self._chunk_key = jax.random.key(0)
-        # Device-resident (tokens, positions) between chunks: valid while
-        # no admission/finish mutated the host mirrors, so back-to-back
-        # chunks skip the host->device upload round-trips entirely.
-        self._dev_state = None
         self._prefills = {
             b: jax.jit(_named(f"prefill_{b}", _model.prefill, cfg=cfg))
             for b in self.prefill_buckets}
@@ -225,13 +210,6 @@ class InferenceEngine:
                       tags={"reason": req.finish_reason or "unknown"})
         if preempted:
             telemetry.inc("ray_tpu_llm_preemptions_total")
-
-    def _note_decode(self, wall_s: float, steps: int) -> None:
-        """One decode dispatch ran ``steps`` model steps in ``wall_s``
-        seconds; per-token latency is the per-step wall time."""
-        if steps > 0:
-            telemetry.observe("ray_tpu_llm_decode_token_seconds",
-                              wall_s / steps)
 
     def _bucket_for(self, n: int) -> Optional[int]:
         for b in self.prefill_buckets:
@@ -380,7 +358,6 @@ class InferenceEngine:
         if not staged:
             self._update_gauges()
             return
-        self._dev_state = None  # new slots: host mirrors are authoritative
         with telemetry.profile_span("engine_prefill_sync", "llm",
                                     extra={"requests": len(staged)}):
             all_logits = np.asarray(self._jax.numpy.stack(
@@ -488,53 +465,27 @@ class InferenceEngine:
         self.slot_pos[slot] = n
         self.slot_tokens[slot] = int(first)
         self.slot_active[slot] = True
-        self._dev_state = None  # host mirrors changed
         self._maybe_finish(req, int(first))
         if req.finished:
             self._admission_finished.append(req)
         self._update_gauges()
 
-    def _need_pages(self, slot: int, steps: int) -> int:
-        """Extra pages ``slot`` needs to write KV for ``steps`` more
-        decode tokens (capped at its token budget: pipelined
-        overgeneration beyond it overflow-writes to reserved page 0)."""
+    def _need_pages(self, slot: int) -> int:
+        """Extra pages ``slot`` needs to write its next token's KV."""
         req = self.slot_req[slot]
-        total = len(req.prompt_tokens) + req.params.max_tokens
-        cover = min(int(self.slot_pos[slot]) + steps, total)
+        cover = int(self.slot_pos[slot]) + 1
         return max(0, math.ceil(cover / self.page_size) - len(req.pages))
 
-    def _try_extend_capacity(self, steps: int) -> bool:
-        """Non-preempting capacity extension for the PIPELINED path: a
-        chunk is in flight, so host mirrors lag the device by one chunk
-        and preemption would rewind every other slot on the re-upload.
-        Returns False when the pool can't cover all active slots — the
-        caller must process the in-flight chunk first, then retry with
-        preemption allowed."""
-        active = [s for s in range(self.max_slots) if self.slot_active[s]]
-        if sum(self._need_pages(s, steps) for s in active) \
-                > self.pool.num_free:
-            return False
-        for slot in active:
-            need = self._need_pages(slot, steps)
-            if need == 0:
-                continue
-            pages = self.pool.alloc(need)
-            req = self.slot_req[slot]
-            base = len(req.pages)
-            req.pages.extend(pages)
-            self.block_tables[slot, base:base + len(pages)] = pages
-        return True
-
-    def _ensure_decode_capacity(self, steps: int) -> None:
+    def _ensure_decode_capacity(self) -> None:
         """Lazily extend block tables so every active slot can write KV
-        for its next ``steps`` tokens, preempting the YOUNGEST request
+        for its next token, preempting the YOUNGEST request
         (recompute preemption: victims re-queue at the FRONT, so
         re-admission preserves arrival order) when the pool runs dry.
         Callers hold the lock."""
         while True:
             active = [s for s in range(self.max_slots)
                       if self.slot_active[s]]
-            if sum(self._need_pages(s, steps) for s in active) \
+            if sum(self._need_pages(s) for s in active) \
                     <= self.pool.num_free:
                 break
             cands = [s for s in range(self.max_slots)
@@ -546,7 +497,7 @@ class InferenceEngine:
         for slot in range(self.max_slots):
             if not self.slot_active[slot]:
                 continue
-            need = self._need_pages(slot, steps)
+            need = self._need_pages(slot)
             if need == 0:
                 continue
             pages = self.pool.alloc(need)
@@ -573,9 +524,7 @@ class InferenceEngine:
         self.pool.free(req.pages)
         req.pages = []
         req.slot = None
-        req.gen += 1   # stale in-flight chunk snapshots must not apply
         self.block_tables[slot] = 0
-        self._dev_state = None
         # Preemption order is youngest-first, each inserting at the
         # front: after multiple preemptions the queue front is back in
         # arrival order ahead of never-admitted requests (which always
@@ -616,7 +565,6 @@ class InferenceEngine:
                 self.slot_active[req.slot] = False
                 self.slot_req[req.slot] = None
                 self._prefilling.pop(req.slot, None)
-                req.gen += 1
             self.pool.free(req.pages)
             req.pages = []
             req.finished = True
@@ -689,7 +637,6 @@ class InferenceEngine:
             bt[:n_pages] = pages
             self.block_tables[slot] = bt
             self.running[req.request_id] = req
-            self._dev_state = None
             self._maybe_finish(req, first)
             if req.finished:
                 self._admission_finished.append(req)
@@ -740,11 +687,10 @@ class InferenceEngine:
         self._admission_finished.clear()
         if not any(self.slot_active):
             return finished
-        self._ensure_decode_capacity(1)
+        self._ensure_decode_capacity()
         if not any(self.slot_active):
             return finished
         t0 = time.perf_counter()
-        self._dev_state = None  # per-token path mutates mirrors
         with telemetry.profile_span("engine_upload", "llm"):
             tokens = jnp.asarray(self.slot_tokens.copy())
             positions = jnp.asarray(self.slot_pos.copy())
@@ -772,267 +718,13 @@ class InferenceEngine:
                 self._maybe_finish(req, tok)
                 if req.finished:
                     finished.append(req)
-        self._note_decode(time.perf_counter() - t0, steps=1)
+        telemetry.observe("ray_tpu_llm_decode_token_seconds",
+                          time.perf_counter() - t0)
         if decoded:
             telemetry.inc("ray_tpu_llm_tokens_total", decoded,
                           tags={"kind": "decode"})
         self._update_gauges()
         return finished
-
-    def step_chunk(self, max_steps: int = 32) -> List[Request]:
-        """Admit + up to ``max_steps`` decode iterations in ONE device
-        program with on-device sampling (_model.decode_chunk): the host
-        syncs once per chunk instead of once per token, which keeps
-        decode compute-bound even when host<->device latency is large
-        (reference analog: vLLM multi-step scheduling).
-
-        Used when every active request shares compatible sampling params
-        (the common serving case); falls back to per-token step()
-        otherwise.  Stop tokens/budgets are enforced host-side after the
-        chunk — the bounded overgeneration is the price of the batching.
-        """
-        with self._lock:
-            self._admit()
-            self._prefill_tick()
-            finished = list(self._admission_finished)
-            self._admission_finished.clear()
-            # Clock starts AFTER admission: prefill time is not decode
-            # latency (step() excludes it the same way).
-            t0 = time.perf_counter()
-            d = self._dispatch_chunk(max_steps)
-        if d is None:
-            return finished
-        if d == "incompatible":
-            return finished + self.step()
-        with telemetry.profile_span("engine_step_chunk", "llm",
-                                    extra={"steps": d[1]}):
-            out = self._process_chunk(*d)
-        self._note_decode(time.perf_counter() - t0, steps=d[1])
-        return finished + out
-
-    def _process_pending(self, pending, t_mark: float) -> List[Request]:
-        """Pipelined-path chunk application with the same telemetry as
-        step_chunk: one timeline span per chunk, and iteration cadence
-        (t_mark -> apply complete, overlap included) as the per-token
-        decode latency."""
-        with telemetry.profile_span("engine_step_chunk", "llm",
-                                    extra={"steps": pending[1],
-                                           "pipelined": True}):
-            out = self._process_chunk(*pending, keep_dev_state=True)
-        self._note_decode(time.perf_counter() - t_mark, steps=pending[1])
-        return out
-
-    def _dispatch_chunk(self, max_steps: int, allow_preempt: bool = True,
-                        pos_lag: int = 0):
-        """Dispatch one chunk (async — no host sync).  Caller holds the
-        lock.  Returns None (nothing active), "incompatible" (mixed
-        sampling params / exhausted budgets: use per-token step()),
-        "need_sync" (page pressure while a chunk is in flight — the
-        caller must apply it before capacity work can preempt), or
-        (device_out, steps, per-slot request snapshot).
-
-        ``pos_lag``: steps of an IN-FLIGHT chunk not yet applied to the
-        host mirrors — page capacity must cover the device's true
-        positions (mirror pos + lag + this chunk), not the stale
-        mirrors."""
-        jnp = self._jnp
-        from . import _model
-
-        active_reqs = [self.slot_req[s] for s in range(self.max_slots)
-                       if self.slot_active[s]]
-        if not active_reqs:
-            return None
-        sp0 = active_reqs[0].params
-        if any(r.params.temperature != sp0.temperature
-               or r.params.top_k != sp0.top_k for r in active_reqs):
-            return "incompatible"
-        # Cap the chunk so no request overruns its token budget or
-        # page allocation, then round DOWN to a power of two: the
-        # compiled-program set stays tiny (log2(max_steps) shapes,
-        # dict-cached) instead of recompiling the scanned model for
-        # every distinct remaining-budget value.
-        steps = min([max_steps] + [
-            r.params.max_tokens - len(r.output_tokens)
-            for r in active_reqs])
-        if steps <= 0:
-            return "incompatible"
-        steps = 1 << (steps.bit_length() - 1)
-        # Page capacity for the whole chunk BEFORE dispatch: block
-        # tables are frozen for the chunk's duration, so lazy extension
-        # (and any preemption it forces) must happen now.
-        if allow_preempt:
-            self._ensure_decode_capacity(steps + pos_lag)
-            if not any(self.slot_active):
-                return None
-        elif not self._try_extend_capacity(steps + pos_lag):
-            return "need_sync"
-        shape_key = (steps, sp0.temperature, sp0.top_k)
-        fn = self._chunk_cache.get(shape_key)
-        if fn is None:
-            fn = self._jax.jit(
-                _named(f"decode_chunk_{steps}", _model.decode_chunk,
-                       cfg=self.cfg, page_size=self.page_size, steps=steps,
-                       temperature=sp0.temperature, top_k=sp0.top_k),
-                donate_argnums=(1,))
-            self._chunk_cache[shape_key] = fn
-            while len(self._chunk_cache) > self._chunk_cache_cap:
-                self._chunk_cache.popitem(last=False)
-        else:
-            self._chunk_cache.move_to_end(shape_key)
-        self._decode_chunk = fn
-        self._chunk_key, key = self._jax.random.split(self._chunk_key)
-        if self._dev_state is not None:
-            toks_dev, pos_dev = self._dev_state
-        else:
-            toks_dev = jnp.asarray(self.slot_tokens.copy())
-            pos_dev = jnp.asarray(self.slot_pos.copy())
-        out, new_pos, self.kv_pages = self._decode_chunk(
-            self.params, self.kv_pages,
-            toks_dev, pos_dev, jnp.asarray(self.block_tables.copy()),
-            jnp.asarray(self.slot_active.copy()), key)
-        # Next chunk can resume from device state (last sampled token
-        # per slot + advanced positions) with no host upload.
-        self._dev_state = (out[-1], new_pos)
-        # Snapshot carries the request's incarnation: a preempted-and-
-        # re-admitted request must not receive this chunk's stale tokens
-        # even if it lands back in the same slot.
-        snap = [(self.slot_req[s], self.slot_req[s].gen)
-                if self.slot_active[s] else None
-                for s in range(self.max_slots)]
-        return (out, steps, snap)
-
-    def _process_chunk(self, out_dev, steps: int, snap,
-                       keep_dev_state: bool = False) -> List[Request]:
-        """Sync one dispatched chunk to host and apply its tokens.
-
-        ``snap`` is the per-slot request snapshot at dispatch: a slot
-        freed and re-admitted since then is skipped (the old request's
-        overgenerated tail is dropped).  ``keep_dev_state=True`` is the
-        pipelined mode: a LATER chunk has already been dispatched from
-        the current device state, so finishing a request here must not
-        invalidate it (inactive slots are masked by the `active` array
-        at the next dispatch instead)."""
-        out = np.asarray(out_dev)                       # ONE host sync
-        finished: List[Request] = []
-        now = time.perf_counter()
-        with self._lock:
-            any_finished = False
-            applied = 0
-            for slot, entry in enumerate(snap):
-                if entry is None:
-                    continue
-                req, gen = entry
-                if req.finished:
-                    continue
-                if self.slot_req[slot] is not req or req.gen != gen:
-                    continue  # slot re-admitted / request preempted
-                for i in range(steps):
-                    tok = int(out[i, slot])
-                    req.output_tokens.append(tok)
-                    if self.record_token_times:
-                        req.token_times.append(now)
-                    applied += 1
-                    self.slot_pos[slot] += 1
-                    self.slot_tokens[slot] = tok
-                    self._maybe_finish(req, tok)
-                    if req.finished:
-                        # Overgenerated tail beyond a stop token is
-                        # dropped with the request.
-                        finished.append(req)
-                        any_finished = True
-                        break
-            if any_finished and not keep_dev_state:
-                self._dev_state = None  # host mirrors changed
-            if applied:
-                telemetry.inc("ray_tpu_llm_tokens_total", applied,
-                              tags={"kind": "decode"})
-            self._update_gauges()
-        return finished
-
-    def run_pipelined(self, max_steps: int = 64,
-                      max_chunks: int = 1_000_000) -> List[Request]:
-        """Drain all queued work with DOUBLE-BUFFERED chunks: the device
-        executes chunk k+1 while the host reads back and applies chunk
-        k, so the readback hides behind compute (reference analog:
-        vLLM's async engine loop overlapping scheduling with execution).
-
-        Admission happens at pipeline bubbles (start, drain, or when
-        requests are waiting — one bubble per admission wave), so new
-        requests wait at most one chunk.  Finished requests may
-        overgenerate up to one extra chunk whose tokens are dropped
-        host-side; budget-exhausted slots overflow-write to reserved
-        page 0.  Returns every finished request."""
-        done: List[Request] = []
-        pending = None
-        t_mark = time.perf_counter()
-        for _ in range(max_chunks):
-            d = None
-            with self._lock:
-                if pending is None:
-                    self._admit()
-                    self._prefill_tick()
-                    done.extend(self._admission_finished)
-                    self._admission_finished.clear()
-                skip = False
-                if pending is not None:
-                    free_slot = any(self.slot_req[i] is None
-                                    for i in range(self.max_slots))
-                    if self._prefilling:
-                        # An in-flight chunked prefill only advances at
-                        # bubbles; starving it would deadlock its slot.
-                        skip = True
-                    elif self.waiting and free_slot:
-                        # Bubble ONLY when admission can actually make
-                        # progress (a slot is free AND the head request's
-                        # first pages fit): at saturation — or against an
-                        # oversized head request — the queue stays
-                        # non-empty for the whole run and a bubble per
-                        # chunk would serialize the pipeline exactly when
-                        # load is highest.
-                        head = self.waiting[0]
-                        seed_n = len(head.prompt_tokens) \
-                            + len(head.output_tokens)
-                        need = math.ceil((seed_n + 1) / self.page_size)
-                        skip = self.pool.num_free >= need
-                    else:
-                        # The in-flight chunk already covers every active
-                        # budget: a further dispatch would be pure
-                        # overgeneration (a whole wasted device chunk).
-                        rem = [r.params.max_tokens - len(r.output_tokens)
-                               - pending[1]
-                               for r in (self.slot_req[s]
-                                         for s in range(self.max_slots)
-                                         if self.slot_active[s])]
-                        skip = bool(rem) and max(rem) <= 0
-                if not skip:
-                    d = self._dispatch_chunk(
-                        max_steps, allow_preempt=pending is None,
-                        pos_lag=pending[1] if pending is not None else 0)
-            if d == "need_sync":
-                # Page pressure with a chunk in flight: apply it so the
-                # host mirrors catch up, then the next iteration may
-                # preempt safely.
-                done.extend(self._process_pending(pending, t_mark))
-                pending = None
-                t_mark = time.perf_counter()
-                continue
-            if d == "incompatible":
-                if pending is not None:
-                    done.extend(self._process_pending(pending, t_mark))
-                    pending = None
-                done.extend(self.step_chunk(max_steps))
-                t_mark = time.perf_counter()
-                continue
-            if pending is not None:
-                done.extend(self._process_pending(pending, t_mark))
-            pending = d
-            t_mark = time.perf_counter()
-            if pending is None:
-                with self._lock:
-                    if not self.waiting and not self.slot_active.any() \
-                            and not self._prefilling:
-                        return done
-        raise RuntimeError("run_pipelined did not drain")
 
     # -- offline batch API --------------------------------------------------
 

@@ -52,6 +52,9 @@ from ..disagg.router import AdmissionConfig, AdmissionController, _Pending
 #: Cluster-KV key prefix for published fleet snapshots (CLI/dashboard).
 FLEET_KV_PREFIX = "serve:fleet:"
 
+#: Finishes kept for a (replica, rid) that ``_map`` has not registered.
+_EARLY_FINISHES = 64
+
 
 @dataclass
 class FleetConfig:
@@ -116,6 +119,11 @@ class FleetServer:
         self._rid_map: Dict[tuple, int] = {}      # (replica, rid) -> pub
         self._pub_to_rid: Dict[int, tuple] = {}   # pub -> (replica, rid)
         self._outcome: Dict[int, tuple] = {}      # pub -> (outcome, replica)
+        # (replica, rid) -> request that finished before _map knew the
+        # rid: a short request on a warm replica can reach the finish
+        # callback (a drive or poll thread) before the dispatcher is
+        # back from import_prefill.  _map takes it from here.
+        self._early: Dict[tuple, Any] = {}
         self._pub_ids = itertools.count(1)
 
         self._n_done = 0
@@ -447,6 +455,7 @@ class FleetServer:
         can't see an unregistered rid, so the request would hang until
         caller timeout — shed it here instead)."""
         with self._lock:
+            early = self._early.pop((replica, rid), None)
             alive = item.pub_id in self._meta
             routed = self._replicas.get(replica) is rep
             if alive and routed:
@@ -467,6 +476,8 @@ class FleetServer:
                           tags={"outcome": outcome})
         self.admission.note_dequeued(item.clazz)
         self._work.set()
+        if early is not None and alive:
+            self._on_replica_finish(rep, early)
 
     def _dispatch(self, item: _Pending) -> None:
         """Route one admitted request: score replicas, try the cache-hit
@@ -575,15 +586,20 @@ class FleetServer:
     # -- replica finish callback (runs on replica drive threads) ------------
 
     def _on_replica_finish(self, replica, req) -> None:
+        key = (replica.name, req.request_id)
         with self._lock:
-            pub_id = self._rid_map.pop((replica.name, req.request_id),
-                                       None)
-            item = self._meta.get(pub_id) if pub_id is not None else None
+            pub_id = self._rid_map.pop(key, None)
+            if pub_id is None:
+                # Not mapped yet, or no longer (abandoned, shed with its
+                # replica): keep it for _map, which comes within
+                # milliseconds if at all, so the newest few suffice.
+                self._early[key] = req
+                while len(self._early) > _EARLY_FINISHES:
+                    del self._early[next(iter(self._early))]
+                return
+            item = self._meta.get(pub_id)
             outcome, rep_name = self._outcome.pop(
-                pub_id, (None, replica.name)) if pub_id is not None \
-                else (None, replica.name)
-        if pub_id is None:
-            return
+                pub_id, (None, replica.name))
         self._release_budget(item)
         itl = [b - a for a, b in zip(req.token_times,
                                      req.token_times[1:])]

@@ -50,7 +50,7 @@ _SLOW_MODULES = {
 # Fast representatives inside slow modules so the quick tier still touches
 # every subsystem (node ids are matched by substring).
 _QUICK_IN_SLOW = {
-    "test_llm": ("test_stop_tokens",),
+    "test_llm": ("TestInferenceEngine",),
     "test_rl": ("TestBuffers", "TestGAE"),
     "test_pipeline": ("test_pp_requires_mesh",),
     "test_tune": ("test_variant_expansion", "test_schedulers_unit",

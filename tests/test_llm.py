@@ -46,43 +46,6 @@ class TestInferenceEngine:
         want = naive_greedy(params, prompt, 8)
         assert got == want
 
-    def test_chunked_decode_matches_per_step(self, params):
-        """Device-resident multi-token chunks (step_chunk: lax.scan with
-        on-device sampling, one host sync per chunk) produce exactly the
-        per-token greedy stream."""
-        prompts = [[3, 17, 92, 5, 41], [7, 9, 23, 6]]
-        sp = SamplingParams(max_tokens=9)
-        eng_a = InferenceEngine(params, CFG, max_slots=2, page_size=8,
-                                num_pages=64, prefill_buckets=(16,))
-        ids = [eng_a.add_request(p, sp) for p in prompts]
-        done = {}
-        guard = 0
-        while eng_a.has_work():
-            for r in eng_a.step_chunk(4):
-                done[r.request_id] = r.output_tokens
-            guard += 1
-            assert guard < 100
-        chunked = [done[i] for i in ids]
-        want = [naive_greedy(params, p, 9) for p in prompts]
-        assert chunked == want
-
-    def test_pipelined_decode_matches_per_step(self, params):
-        """Double-buffered chunk pipelining (run_pipelined: host applies
-        chunk k while the device runs k+1) is a pure latency
-        optimization — the greedy token streams are identical, including
-        mid-flight admission at a pipeline bubble."""
-        prompts = [[3, 17, 92, 5, 41], [7, 9, 23, 6], [11, 4], [8, 8, 2]]
-        sp = SamplingParams(max_tokens=9)
-        eng = InferenceEngine(params, CFG, max_slots=2, page_size=8,
-                              num_pages=64, prefill_buckets=(16,))
-        # max_slots=2 < 4 prompts forces admission waves mid-pipeline.
-        ids = [eng.add_request(p, sp) for p in prompts]
-        done = {r.request_id: r.output_tokens
-                for r in eng.run_pipelined(4, max_chunks=200)}
-        got = [done[i] for i in ids]
-        want = [naive_greedy(params, p, 9) for p in prompts]
-        assert got == want
-
     def test_continuous_batching_matches_sequential(self, params):
         prompts = [[7, 9, 23], [4, 4, 8, 15, 16, 23, 42], [99], [1, 2]]
         eng = InferenceEngine(params, CFG, max_slots=2, page_size=8,
@@ -125,6 +88,25 @@ class TestInferenceEngine:
         got = eng.generate([prompt], SamplingParams(
             max_tokens=8, stop_token_ids=(stop,)))[0]
         assert got == full[:3]        # stops when the stop token appears
+
+    def test_greedy_beside_a_sampled_request(self, params):
+        """A greedy request keeps its exact stream while a request with
+        ``temperature > 0`` and a ``top_k`` decodes in the same batch:
+        ``step`` samples each slot by its own parameters."""
+        eng = InferenceEngine(params, CFG, max_slots=2, page_size=8,
+                              num_pages=64, prefill_buckets=(16,))
+        prompt = [3, 17, 92, 5, 41]
+        greedy = eng.submit(prompt, SamplingParams(max_tokens=8))
+        sampled = eng.submit([7, 9, 23, 6], SamplingParams(
+            max_tokens=8, temperature=0.8, top_k=5))
+        shared = 0
+        while eng.has_work():
+            eng.step()
+            shared += int(eng.slot_active.sum()) == 2
+        assert shared, "the two requests never shared a decode batch"
+        assert greedy.output_tokens == naive_greedy(params, prompt, 8)
+        assert len(sampled.output_tokens) == 8
+        assert all(0 <= t < CFG.vocab_size for t in sampled.output_tokens)
 
 
 class TestLLMServing:

@@ -100,7 +100,7 @@ class TestKVPressure:
     youngest request, re-queues it at the FRONT, and recompute
     re-admission reproduces the exact greedy stream."""
 
-    def _run(self, params, drive, num_pages=14, max_tokens=20):
+    def _run(self, params, num_pages=14, max_tokens=20):
         rng = np.random.default_rng(3)
         prompts = [rng.integers(1, CFG.vocab_size, 6).tolist()
                    for _ in range(4)]
@@ -118,31 +118,20 @@ class TestKVPressure:
         ids = [eng.add_request(p, SamplingParams(max_tokens=max_tokens))
                for p in prompts]
         done = {}
-        if drive == "pipelined":
-            done = {r.request_id: r.output_tokens
-                    for r in eng.run_pipelined(4, max_chunks=8000)}
-        else:
-            guard = 0
-            while eng.has_work():
-                rs = eng.step() if drive == "step" else eng.step_chunk(4)
-                for r in rs:
-                    done[r.request_id] = r.output_tokens
-                guard += 1
-                assert guard < 8000
+        guard = 0
+        while eng.has_work():
+            for r in eng.step():
+                done[r.request_id] = r.output_tokens
+            guard += 1
+            assert guard < 8000
         got = [done[i] for i in ids]
         assert got == want
         assert eng.pool.num_free == free0   # no page leaks
         return preempts
 
     def test_preemption_step_path(self, params):
-        preempts = self._run(params, "step")
+        preempts = self._run(params)
         assert preempts, "pool was sized to force preemption"
-
-    def test_preemption_chunk_path(self, params):
-        self._run(params, "chunk")
-
-    def test_preemption_pipelined_path(self, params):
-        self._run(params, "pipelined")
 
     def test_readmission_fairness(self, params):
         """Preempted requests re-queue at the FRONT: re-admission keeps

@@ -371,6 +371,44 @@ class TestFleetServer:
         # Both replicas took work (least-loaded miss routing spreads).
         assert {r["replica"] for r in outs if "replica" in r}
 
+    def test_finish_that_outruns_its_registration(self, params):
+        """A request may finish on its replica before the dispatcher is
+        back from ``import_prefill`` (a RemoteReplica's poller does that
+        to a short request on a warm engine): the finish is kept for the
+        registration, where it used to be dropped and the caller waited
+        out its timeout."""
+        from ray_tpu.llm.fleet.replica import DecodeReplica
+
+        class FinishFirst(DecodeReplica):
+            delivered = set()
+
+            def import_prefill(self, handoff, retain=True):
+                rid = super().import_prefill(handoff, retain=retain)
+                if rid is not None:
+                    _wait_for(lambda: rid in self.delivered)
+                return rid
+
+        def factory(name, on_finish):
+            def note(rep, req):
+                on_finish(rep, req)
+                rep.delivered.add(req.request_id)
+            return FinishFirst(_build(params), name=name,
+                               engine_options=dict(ENGINE_OPTS),
+                               on_finish=note)
+
+        srv = FleetServer(
+            _build(params), name="early",
+            config=FleetConfig(num_replicas=1,
+                               engine_options=dict(ENGINE_OPTS)),
+            replica_factory=factory)
+        try:
+            res = srv({"prompt_tokens": list(range(1, 13)),
+                       "max_tokens": 5, "timeout_s": 20})
+        finally:
+            srv.close()
+        assert "error" not in res, res
+        assert len(res["output_tokens"]) == 5
+
     def test_full_hit_replays_identical_tokens(self, params):
         srv = _fleet(params, n=1)
         try:

@@ -385,9 +385,6 @@ class TestServingPath:
         eng.submit(list(range(1, 41)), SamplingParams(max_tokens=2))
         eng.step()
         assert eng._prefill_chunk_jit.__name__ == "prefill_chunk"
-        eng.step_chunk(max_steps=4)
-        assert {f.__name__ for f in eng._chunk_cache.values()} <= \
-            {"decode_chunk_1", "decode_chunk_2", "decode_chunk_4"}
 
     def test_stream_of_a_request_that_finished_first(self, monkeypatch):
         """``stream`` holds the Request that ``_submit`` created: one that
