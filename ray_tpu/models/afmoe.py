@@ -273,12 +273,13 @@ def _feed_forward(h, w_gate, w_up, w_down, dt, act="silu"):
                       w_down.astype(dt), preferred_element_type=dt)
 
 
-def _moe(cfg, h, layer, bias, act: str = "silu"):
+def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20):
     """F(h) of an expert layer and its loads: (out [B, S, E], {"counts" [X]
     int32 over all the experts, "dropped" int32, "sliced" int32 (1 if the
     call took the buffer in slices), "top" [B*S, k] the router's choices}).
     A layer without ``w_gate`` / ``shared_gate`` has un-gated experts
-    (``act(h W_up) W_down``); ``cfg`` is any configuration with this one's
+    (``act(h W_up) W_down``), one without ``shared_up`` no shared expert
+    (``models/lfm2.py``); ``cfg`` is any configuration with this one's
     routing and share fields (``models/xing4.py``, ``models/nemotron_h.py``)."""
     dt = cfg.dtype
     gate = layer.get("w_gate")
@@ -287,17 +288,20 @@ def _moe(cfg, h, layer, bias, act: str = "silu"):
         with jax.named_scope("route"):
             routing = moe.sigmoid_routing(
                 h.reshape(B * S, E), layer["router"], bias, cfg.top_k,
-                cfg.route_scale, cfg.route_norm)
-        with jax.named_scope("shared"):
-            shared = _feed_forward(h, layer.get("shared_gate"),
-                                   layer["shared_up"], layer["shared_down"],
-                                   dt, act)
+                cfg.route_scale, cfg.route_norm, route_eps)
+        shared = None
+        if "shared_up" in layer:
+            with jax.named_scope("shared"):
+                shared = _feed_forward(h, layer.get("shared_gate"),
+                                       layer["shared_up"],
+                                       layer["shared_down"], dt, act)
         routed, (held, dropped) = moe.dropless_experts(
             h.reshape(B * S, E), routing,
             None if gate is None else gate.astype(dt),
             layer["w_up"].astype(dt), layer["w_down"].astype(dt),
             held_start=cfg.held_start, impl=cfg.moe_impl, activation=act)
-        return (shared + routed.reshape(B, S, E).astype(dt),
+        routed = routed.reshape(B, S, E).astype(dt)
+        return (routed if shared is None else shared + routed,
                 {"counts": routing.counts, "dropped": dropped,
                  "sliced": (held > moe.buffer_rows(B * S, cfg.top_k)
                             ).astype(jnp.int32),

@@ -16,7 +16,8 @@ delegated to vLLM/DeepSpeed).  Built natively here:
 - ``ssm``           — state-space layers (Mamba-2): a causal depthwise
                       convolution, the chunked scan of a recurrence whose
                       state is handed from chunk to chunk, and the gated
-                      RMSNorm over groups of channels
+                      RMSNorm over groups of channels; LFM2's
+                      double-gated short convolution
 - ``norms``/``swiglu`` — fused-friendly elementwise building blocks
 - ``rope``          — rotary embedding: the split rotation, and on the TPU a
                       rotate-and-place kernel pair between a projection
@@ -29,7 +30,8 @@ from .rope import (apply_rope, rope_frequencies, rope_lane_tables,
 from .attention import attention, flash_attention, reference_attention
 from .eva import eva_attention, eva_summaries
 from .ring_attention import ring_attention
-from .ssm import causal_conv, gated_group_norm, ssd_scan
+from .ssm import (causal_conv, gated_group_norm, gated_short_conv,
+                  ssd_scan)
 from .ulysses import ulysses_attention
 
 __all__ = [
@@ -38,5 +40,5 @@ __all__ = [
     "attention", "flash_attention", "reference_attention",
     "eva_attention", "eva_summaries",
     "ring_attention", "ulysses_attention",
-    "causal_conv", "ssd_scan", "gated_group_norm",
+    "causal_conv", "ssd_scan", "gated_group_norm", "gated_short_conv",
 ]

@@ -269,7 +269,13 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
       a length the larger blocks do not divide) keeps 512 x 512 and a head
       a step.  At 192 / 128 alone the three kernels are 5 % faster at
       1,024 x 1,024 (25.97 ms for 27.41 at [1, 32, 8192]); the train step
-      that held them did not return from its first call (PERF.md, PR 40)."""
+      that held them did not return from its first call (PERF.md, PR 40).
+    - A head size under 128 (LFM2's 64) takes the answers of 128: at
+      [4, 32 / 8, 8192, 64] the three kernels read 68.9 ms at 512 x 512 with
+      the group's 4 heads stacked, 70.7 - 71.8 with either block at 1,024
+      and 74.0 - 74.4 with either at 256 (PERF.md, PR 47).  The same
+      operations at [4, 16 / 4, 8192, 128] take 33.4 ms: a score product
+      that contracts over 64 fills half of the MXU's 128 x 128 tile."""
     scores = "kq" if kind == "dkv" else "qk"
     block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
     if D > LANES:
@@ -304,7 +310,8 @@ def _geometry(kind, q, k, v, block_q, block_k, window):
         "kernel": _kernel_name(f"flash_{kind}", window, D, Dv),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
-        **({} if D == Dv else {"d_qk": str(D), "d_v": str(Dv)})})
+        **({"d_qk": str(D), "d_v": str(Dv)} if D != Dv
+           else {} if D == LANES else {"d": str(D)})})
     return t
 
 
@@ -373,9 +380,12 @@ def _kernel_name(base, window, D=None, Dv=None):
     """A windowed call carries its window in its name, so that a device
     trace tells it from a full-causal call (``flash_fwd_w2048``), and a
     call whose values are not as wide as its keys both head sizes
-    (``flash_fwd_d192v128``)."""
+    (``flash_fwd_d192v128``), one whose one head size is not 128 that size
+    (``flash_fwd_d64``)."""
     if D != Dv:
         base = f"{base}_d{D}v{Dv}"
+    elif D is not None and D != LANES:
+        base = f"{base}_d{D}"
     return base if window is None else f"{base}_w{window}"
 
 

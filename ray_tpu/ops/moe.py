@@ -76,12 +76,12 @@ class SigmoidRouting(NamedTuple):
 
 
 def sigmoid_routing(x, router_w, bias, k: int, route_scale: float = 1.0,
-                    route_norm: bool = True) -> SigmoidRouting:
+                    route_norm: bool = True, eps=1e-20) -> SigmoidRouting:
     """x [T, E], router_w [E, X], bias [X] float32 (the selection bias: state,
     not a parameter).  ``s = sigmoid(x W)`` in float32; the ``k`` experts are
     chosen by ``s + bias`` and weighted by ``s`` alone, normalised over the
-    chosen (``route_norm``) and scaled.  torchtitan's MoE router with
-    ``score_func="sigmoid"``."""
+    chosen (``route_norm``, by ``sum + eps``) and scaled.  torchtitan's MoE
+    router with ``score_func="sigmoid"``; LFM2's adds 1e-6 to the sum."""
     s = jax.nn.sigmoid(jnp.einsum(
         "te,ex->tx", x.astype(jnp.float32), router_w.astype(jnp.float32)))
     _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
@@ -91,7 +91,7 @@ def sigmoid_routing(x, router_w, bias, k: int, route_scale: float = 1.0,
     chosen = top[..., None] == jnp.arange(s.shape[-1])       # [T, k, X]
     w = jnp.sum(jnp.where(chosen, s[:, None, :], 0), axis=-1)
     if route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
     return SigmoidRouting(top, w * route_scale, counts)
 

@@ -10,8 +10,9 @@ Two ways to lay the same rotation on the lanes:
   them (a dozen a layer call in a compiled train step; PERF.md, PR 35).
 - ``rotate_heads`` takes the projection's natural result ``[B, S, H, D]``
   and returns the flash kernels' operand ``[B, H, S, D]``.  Where the
-  backend is a TPU, ``D == 128`` and no ``positions`` are given, a Pallas
-  kernel reads a ``(rows, D)`` tile of a head out of ``[B, S, H*D]``,
+  backend is a TPU, ``D == 128`` (or 64, below) and no ``positions`` are
+  given, a Pallas kernel reads a ``(rows, D)`` tile of a head out of
+  ``[B, S, H*D]``,
   forms ``x * [cos, cos] + roll(x, D/2) * [-sin, sin]`` in float32 (the
   roll is one lane rotation, ``pltpu.roll``), rounds once to the input's
   type and writes the tile at its place in ``[B, H, S, D]``: q and k cross
@@ -21,7 +22,11 @@ Two ways to lay the same rotation on the lanes:
   nothing but the tables is saved.  Every other call (``positions`` given,
   as the sequence-sharded ``ring`` / ``ulysses`` callers do; another head
   size; no TPU) is ``apply_rope`` behind a transpose.  Which path a call
-  took is counted in ``ray_tpu_rope_path_total``.
+  took is counted in ``ray_tpu_rope_path_total``.  At ``D == 64`` (LFM2's
+  heads; an even number of them, one device) two heads share a tile of 128
+  lanes: the half-turn is inside each 64 (two rotations of the tile and a
+  select, ``_rotated_pair``), the tables are a head's laid twice, and each
+  head of the pair is written to its own place in ``[B, H, S, 64]``.
 """
 
 from __future__ import annotations
@@ -151,12 +156,39 @@ def _rotated(x, cos2, sin2, dtype):
             ).astype(dtype)
 
 
+def _rotated_pair(x, cos4, sin4, dtype):
+    """Two heads of 64 side by side in a [rows, 128] tile, each turned by
+    half of ITS 64 lanes: a lane of a head's first half takes the lane 32
+    on, one of its second half the lane 32 back (two rotations of the whole
+    tile and a select); the tables are a head's, laid twice."""
+    from jax.experimental.pallas import tpu as pltpu
+    x = x.astype(jnp.float32)
+    half = x.shape[-1] // 4
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    turned = jnp.where(lane % (2 * half) < half,
+                       pltpu.roll(x, x.shape[-1] - half, 1),
+                       pltpu.roll(x, half, 1))
+    return (x * cos4 + turned * sin4).astype(dtype)
+
+
 def _kernel(x_ref, cos_ref, sin_ref, o_ref, _tag, *, to_heads: bool):
     """``to_heads``: x [rows, heads*D] of [B, S, H*D] -> o [heads, rows, D]
     of [B, H, S, D]; else the transpose's layouts, o [rows, heads*D] from
-    x [heads, rows, D] (the caller hands in the sine negated)."""
+    x [heads, rows, D] (the caller hands in the sine negated).  At a head
+    size of 64 two heads share a tile of 128 lanes (``_rotated_pair``)."""
     heads, _, D = (o_ref if to_heads else x_ref).shape
     cos2, sin2 = cos_ref[...], sin_ref[...]
+    if 2 * D == cos2.shape[-1]:
+        for t in range(heads // 2):
+            lanes = slice(2 * t * D, 2 * (t + 1) * D)
+            if to_heads:
+                out = _rotated_pair(x_ref[:, lanes], cos2, sin2, o_ref.dtype)
+                o_ref[2 * t], o_ref[2 * t + 1] = out[:, :D], out[:, D:]
+            else:
+                o_ref[:, lanes] = _rotated_pair(
+                    jnp.concatenate([x_ref[2 * t], x_ref[2 * t + 1]], axis=1),
+                    cos2, sin2, o_ref.dtype)
+        return
     for h in range(heads):
         lanes = slice(h * D, (h + 1) * D)
         if to_heads:
@@ -165,22 +197,23 @@ def _kernel(x_ref, cos_ref, sin_ref, o_ref, _tag, *, to_heads: bool):
             o_ref[:, lanes] = _rotated(x_ref[h], cos2, sin2, o_ref.dtype)
 
 
-def _place(x, cos2, sin2, to_heads: bool, interpret: bool):
+def _place(x, cos2, sin2, to_heads: bool, interpret: bool, head_dim=None):
     """One kernel call: [B, S, H*D] -> [B, H, S, D] rotated by the tables
     (``to_heads``), or [B, H, S, D] -> [B, S, H*D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    D = cos2.shape[-1]
+    W = cos2.shape[-1]          # the tables' lanes: D, or 2 D at D == 64
     if to_heads:
-        B, S, H = x.shape[0], x.shape[1], x.shape[2] // D
+        B, S = x.shape[:2]
+        H, D = x.shape[2] // head_dim, head_dim
     else:
-        B, H, S, _ = x.shape
+        B, H, S, D = x.shape
     rows, heads = _tile(S, H)
     flat = pl.BlockSpec((None, rows, heads * D), lambda b, s, h: (b, s, h))
     by_head = pl.BlockSpec((None, heads, rows, D),
                            lambda b, s, h: (b, h, s, 0))
     # Heads innermost: a table's block stays while the heads go by.
-    table = pl.BlockSpec((rows, D), lambda b, s, h: (s, 0))
+    table = pl.BlockSpec((rows, W), lambda b, s, h: (s, 0))
     # A trace names a Mosaic call by its result types alone, and the
     # benchmark's flash reader takes every call that returns one bf16 array
     # for flash_dq (benchmark/layer_metrics/flash_attn_roofline.py).  A
@@ -203,19 +236,20 @@ def _place(x, cos2, sin2, to_heads: bool, interpret: bool):
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rotate_and_place(x, cos2, sin2, interpret):
-    return _place(x, cos2, sin2, True, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rotate_and_place(x, cos2, sin2, interpret, head_dim):
+    return _place(x, cos2, sin2, True, interpret, head_dim)
 
 
-def _rotate_fwd(x, cos2, sin2, interpret):
-    return _place(x, cos2, sin2, True, interpret), (cos2, sin2)
+def _rotate_fwd(x, cos2, sin2, interpret, head_dim):
+    return _place(x, cos2, sin2, True, interpret, head_dim), (cos2, sin2)
 
 
-def _rotate_bwd(interpret, tables, g):
+def _rotate_bwd(interpret, head_dim, tables, g):
     cos2, sin2 = tables
     # (x c + roll(x) s)^T g = g c + roll(g s) = g c + roll(g) (-s):
-    # roll by D/2 is its own inverse and turns [-sin, sin] into its negative.
+    # roll by D/2 is its own inverse and turns [-sin, sin] into its negative
+    # (at 64 the turn inside each head is its own inverse likewise).
     return _place(g, cos2, -sin2, False, interpret), None, None
 
 
@@ -241,8 +275,11 @@ def rotate_heads(x, cos2, sin2, positions=None, *, interpret: bool = False,
     if island:
         from ..parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ,
                                      AXIS_TENSOR)
-    kernel = (D == LANES and positions is None
-              and (interpret or _on_tpu())
+    # 128, or 64 with its heads in pairs (a step's tile then holds an even
+    # number of them: ``_tile`` takes the largest divisor of H up to 8)
+    kernel = ((D == LANES or (2 * D == LANES and H % 2 == 0
+                              and _tile(S, H)[1] % 2 == 0 and not island))
+              and positions is None and (interpret or _on_tpu())
               and not (island and mesh.shape.get(AXIS_SEQ, 1) > 1))
     if not kernel:
         telemetry.inc("ray_tpu_rope_path_total", tags={
@@ -255,8 +292,10 @@ def rotate_heads(x, cos2, sin2, positions=None, *, interpret: bool = False,
         rows, heads = _tile(S, h)
         telemetry.inc("ray_tpu_rope_path_total", tags={
             "path": "kernel", "rows": str(rows), "heads": str(heads)})
+        if 2 * D == LANES:      # a head's tables laid twice over a tile
+            cos2, sin2 = (jnp.tile(t, (1, 2)) for t in (cos2, sin2))
         return _rotate_and_place(x.reshape(b, S, h * D), cos2, sin2,
-                                 interpret)
+                                 interpret, D)
 
     if island:
         from jax.sharding import PartitionSpec as P

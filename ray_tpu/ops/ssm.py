@@ -1,7 +1,10 @@
 """State-space layers (Mamba-2, the SSD form of arXiv:2405.21060): a causal
 depthwise convolution, the chunked scan of a recurrence that carries a state
 from chunk to chunk, and the gated RMSNorm over groups of channels.  What
-``models/nemotron_h.py``'s mixer runs.
+``models/nemotron_h.py``'s mixer runs.  Beside the mixer's convolution
+(``causal_conv``: a bias and a silu) lives LFM2's, which shares its taps
+(``gated_short_conv``: two gates, three operands, no bias and no silu; what
+``models/lfm2.py``'s ``conv`` layers run).
 
 The recurrence, a head ``h`` of ``P`` channels on a state of ``P x N`` (in
 float32, the state zero at the start of every row)::
@@ -45,8 +48,9 @@ path; on the chip its masks and partial results cross HBM at HBM pace: 2.4
 ms a row of 8,192 forward and 6.1 forward and backward, against the
 kernels' 1.0 and 2.7 (PERF.md, PR 43).
 
-The convolution and the gated norm are elementwise passes over 6,144 and
-4,096 channels a token, bound by HBM; each has its backward written out
+The convolutions and the gated norm are elementwise passes over 6,144,
+2,048 (LFM2's) and 4,096 channels a token, bound by HBM; each has its
+backward written out
 (``jax.custom_vjp``, still ``jnp``), because what JAX derives moves several
 times the bytes: by XLA's own count for a described v5e, a row of 8,192
 forward and backward, the convolution 4.23 -> 0.81 GB and the norm 3.06 ->
@@ -68,16 +72,43 @@ from ..util import telemetry
 F32 = jnp.float32
 
 
-def _conv_taps(c, w, b):
+def _conv_taps(c, w, b=None):
     """The convolution before its silu, float32 [B, S, Ch], and the padded
     input its taps read (in c's dtype: a float32 copy of it would be written
-    and read four times)."""
-    K, S = w.shape[0], c.shape[1]
-    padded = jnp.pad(c, ((0, 0), (K - 1, 0), (0, 0)))
-    acc = b.astype(F32)
+    and read four times).  ``b`` None: no bias.  ``c`` a tuple of arrays:
+    the taps of their product, each padded apart in its own dtype and
+    multiplied in float32 tap by tap (``gated_short_conv``: the TPU compiler
+    then reads each operand where it lies, and no product is ever written:
+    a row of 8,192 at 2,048 channels forward, 134 MB for 403 with the
+    product padded, by its own count for a described v5e; PERF.md, PR 47)."""
+    several = isinstance(c, tuple)
+    cs = c if several else (c,)
+    K, S = w.shape[0], cs[0].shape[1]
+    padded = tuple(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))) for x in cs)
+    acc = 0.0 if b is None else b.astype(F32)
     for j in range(K):
-        acc = acc + padded[:, j:j + S].astype(F32) * w[j].astype(F32)
-    return acc, padded
+        x = padded[0][:, j:j + S].astype(F32)
+        for p in padded[1:]:
+            x = x * p[:, j:j + S].astype(F32)
+        acc = acc + x * w[j].astype(F32)
+    return acc, (padded if several else padded[0])
+
+
+def _ahead(ga, w):
+    """A convolution's taps read the other way: token t's cotangent ``ga``
+    (float32 [B, S, Ch], or a tuple of arrays whose product it is, as
+    ``_conv_taps`` takes them) reaches the inputs of tokens t - (K - 1) ..
+    t."""
+    gs = ga if isinstance(ga, tuple) else (ga,)
+    K, S = w.shape[0], gs[0].shape[1]
+    ahead = tuple(jnp.pad(x, ((0, 0), (0, K - 1), (0, 0))) for x in gs)
+    out = 0
+    for j in range(K):
+        x = ahead[0][:, K - 1 - j:K - 1 - j + S]
+        for p in ahead[1:]:
+            x = x.astype(F32) * p[:, K - 1 - j:K - 1 - j + S].astype(F32)
+        out = out + x * w[j].astype(F32)
+    return out
 
 
 @jax.custom_vjp
@@ -105,10 +136,7 @@ def _conv_bwd(res, g):
         acc, padded = _conv_taps(c, w, b)
         sig = jax.nn.sigmoid(acc)
         ga = g.astype(F32) * sig * (1.0 + acc * (1.0 - sig))
-        # Token t's cotangent reaches the inputs of tokens t - (K - 1) .. t.
-        ahead = jnp.pad(ga, ((0, 0), (0, K - 1), (0, 0)))
-        dc = sum(ahead[:, K - 1 - j:K - 1 - j + S] * w[j].astype(F32)
-                 for j in range(K))
+        dc = _ahead(ga, w)
         dw = jnp.stack([jnp.sum(padded[:, j:j + S].astype(F32) * ga,
                                 axis=(0, 1)) for j in range(K)])
         return (dc.astype(c.dtype), dw.astype(w.dtype),
@@ -116,6 +144,225 @@ def _conv_bwd(res, g):
 
 
 causal_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+# LFM2's double-gated short convolution.  In ``jnp`` (``_gconv_xla``) the
+# TPU compiler reads every operand where it lies and writes no product, but
+# its taps are slices of packed bfloat16 rows a token or two off the tile:
+# on the chip a call at [4, 8192, 2048] reads 29 % of HBM pace forward and
+# 20 % backward, 41 % of its roofline inside the step (PERF.md, PR 47).  The
+# Pallas pair walks a row's tokens in order, a tile of ``_GC_ROWS`` tokens by
+# ``_GC_LANES`` channels a grid step: the product ``B * u`` is formed once in
+# float32, a tap is a sublane rotation of it (``pltpu.roll``), and the
+# ``_GC_HEAD`` rows a rotation wraps round are put right from the last rows
+# of the tile before, which the walk carries in VMEM scratch (zeros at a
+# row's start); the backward reads the first rows of the tile AFTER from HBM
+# (a block of ``_GC_HEAD`` rows), for the taps read the other way, and adds
+# the taps' gradient up over every tile of a column of channels.
+_GC_ROWS, _GC_LANES, _GC_HEAD = 512, 512, 16
+
+
+def _gc_tile(S: int, Ch: int, backward: bool):
+    """(tokens, channels) of a grid step, or None where the shapes do not
+    tile: the backward's tile is half as long (twice the live arrays)."""
+    rows = _GC_ROWS // 2 if backward else _GC_ROWS
+    lanes = next((n for n in (_GC_LANES, 256, 128) if Ch % n == 0), None)
+    return (rows, lanes) if lanes and S % rows == 0 else None
+
+
+def _gc_shifted(x, d: int, before):
+    """x [rows, lanes] float32 a tile's values: its values ``d`` tokens
+    earlier, the first ``d`` rows from ``before``, the ``_GC_HEAD`` rows that
+    precede the tile.  A rotation, and its first ``_GC_HEAD`` rows again
+    with the wrapped ones replaced: (whole tile with wrapped rows, the
+    first rows put right)."""
+    from jax.experimental.pallas import tpu as pltpu
+    head = x[:_GC_HEAD]
+    first = jax.lax.broadcasted_iota(jnp.int32, head.shape, 0) < d
+    return pltpu.roll(x, d, 0), jnp.where(
+        first, pltpu.roll(before, d, 0), pltpu.roll(head, d, 0))
+
+
+def _gc_fwd_kernel(B_ref, C_ref, u_ref, w_ref, o_ref, tail):
+    from jax.experimental import pallas as pl
+    K = w_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _row_start():
+        tail[...] = jnp.zeros_like(tail)
+
+    w = w_ref[...].astype(F32)
+    bu = B_ref[...].astype(F32) * u_ref[...].astype(F32)
+    acc, head = bu * w[K - 1], bu[:_GC_HEAD] * w[K - 1]
+    for d in range(1, K):
+        whole, first = _gc_shifted(bu, d, tail[...])
+        acc, head = acc + whole * w[K - 1 - d], head + first * w[K - 1 - d]
+    o_ref[...] = (C_ref[...].astype(F32) * acc).astype(o_ref.dtype)
+    o_ref[:_GC_HEAD] = (C_ref[:_GC_HEAD].astype(F32) * head
+                        ).astype(o_ref.dtype)
+    tail[...] = bu[-_GC_HEAD:]
+
+
+def _gc_bwd_kernel(B_ref, C_ref, u_ref, g_ref, gn_ref, Cn_ref, w_ref,
+                   dB_ref, dC_ref, du_ref, dw_ref, tail):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    K, rows = w_ref.shape[0], B_ref.shape[0]
+    r, s, last = pl.program_id(1), pl.program_id(2), pl.num_programs(2) - 1
+
+    @pl.when(s == 0)
+    def _row_start():
+        tail[...] = jnp.zeros_like(tail)
+
+    @pl.when((r == 0) & (s == 0))
+    def _first_tile():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    w = w_ref[...].astype(F32)
+    Bf, uf, Cf = (x[...].astype(F32) for x in (B_ref, u_ref, C_ref))
+    bu, g = Bf * uf, g_ref[...].astype(F32)
+    ga = g * Cf                                 # the taps' cotangent
+    # What follows the tile: the next tile's first rows, nothing after the
+    # row's last token.
+    after = jnp.where(s == last, 0.0, gn_ref[...].astype(F32)
+                      * Cn_ref[...].astype(F32))
+    end = ga[-_GC_HEAD:]
+    late = jax.lax.broadcasted_iota(jnp.int32, end.shape, 0)
+    taps, taps_head = bu * w[K - 1], bu[:_GC_HEAD] * w[K - 1]
+    dbu, dbu_end = ga * w[K - 1], end * w[K - 1]
+    dw = [None] * K
+    dw[K - 1] = jnp.sum(bu * ga, axis=0, keepdims=True)
+    # (the taps unroll while the kernel is traced: no dispatch a turn)
+    for d in range(1, K):  # ray-tpu: noqa[RT506]
+        whole, first = _gc_shifted(bu, d, tail[...])
+        taps = taps + whole * w[K - 1 - d]
+        taps_head = taps_head + first * w[K - 1 - d]
+        dw[K - 1 - d] = jnp.sum(whole * ga, axis=0, keepdims=True) + jnp.sum(
+            (first - whole[:_GC_HEAD]) * ga[:_GC_HEAD], axis=0, keepdims=True)
+        # the taps read the other way: token t's input reaches t + d
+        dbu = dbu + pltpu.roll(ga, rows - d, 0) * w[K - 1 - d]
+        dbu_end = dbu_end + jnp.where(
+            late >= _GC_HEAD - d, pltpu.roll(after, _GC_HEAD - d, 0),
+            pltpu.roll(end, _GC_HEAD - d, 0)) * w[K - 1 - d]
+    dC_ref[...] = (g * taps).astype(dC_ref.dtype)
+    dC_ref[:_GC_HEAD] = (g[:_GC_HEAD] * taps_head).astype(dC_ref.dtype)
+    dB_ref[...] = (dbu * uf).astype(dB_ref.dtype)
+    dB_ref[-_GC_HEAD:] = (dbu_end * uf[-_GC_HEAD:]).astype(dB_ref.dtype)
+    du_ref[...] = (dbu * Bf).astype(du_ref.dtype)
+    du_ref[-_GC_HEAD:] = (dbu_end * Bf[-_GC_HEAD:]).astype(du_ref.dtype)
+    dw_ref[...] += jnp.concatenate(dw, axis=0)
+    tail[...] = bu[-_GC_HEAD:]
+
+
+def _gc_call(backward: bool, operands, w, interpret: bool):
+    """One kernel call over [rows, S, Ch] operands: the forward's result, or
+    (dB, dC, du, dw float32 [K, Ch])."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    R, S, Ch = operands[0].shape
+    K = w.shape[0]
+    rows, lanes = _gc_tile(S, Ch, backward)
+    # Channels outermost: the taps' gradient of a column of channels stays
+    # in VMEM while every tile of the column adds to it.
+    grid = (Ch // lanes, R, S // rows)
+    tile = pl.BlockSpec((None, rows, lanes), lambda c, r, s: (r, s, c))
+    taps = pl.BlockSpec((K, lanes), lambda c, r, s: (0, c))
+    like = jax.ShapeDtypeStruct((R, S, Ch), operands[0].dtype)
+    if backward:
+        step = rows // _GC_HEAD
+        after = pl.BlockSpec(
+            (None, _GC_HEAD, lanes), lambda c, r, s: (
+                r, jnp.minimum((s + 1) * step, S // _GC_HEAD - 1), c))
+        g, C = operands[3], operands[1]
+        operands = tuple(operands) + (g, C)
+        in_specs = [tile] * 4 + [after, after, taps]
+        out_specs, out_shape = [tile] * 3 + [taps], [like] * 3 + [
+            jax.ShapeDtypeStruct((K, Ch), F32)]
+    else:
+        in_specs, out_specs, out_shape = [tile] * 3 + [taps], tile, like
+    return pl.pallas_call(
+        _gc_bwd_kernel if backward else _gc_fwd_kernel, grid=grid,
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((_GC_HEAD, lanes), F32)],
+        interpret=interpret,
+        name="gated_conv_bwd" if backward else "gated_conv_fwd",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"))}),
+    )(*operands, w)
+
+
+def _gconv_xla(B, C, u, w):
+    return (C.astype(F32) * _conv_taps((B, u), w)[0]).astype(u.dtype)
+
+
+def _gconv_xla_bwd(B, C, u, w, g):
+    K, S = w.shape[0], u.shape[1]
+    taps, (pB, pu) = _conv_taps((B, u), w)
+    dbu = _ahead((g, C), w)                     # the taps' cotangent is g C
+    ga = g.astype(F32) * C.astype(F32)
+    dw = jnp.stack([jnp.sum(pB[:, j:j + S].astype(F32)
+                            * pu[:, j:j + S].astype(F32) * ga, axis=(0, 1))
+                    for j in range(K)])
+    return ((dbu * u.astype(F32)).astype(B.dtype),
+            (g.astype(F32) * taps).astype(C.dtype),
+            (dbu * B.astype(F32)).astype(u.dtype), dw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gconv(B, C, u, w, impl):
+    with jax.named_scope("block/conv/gate"):
+        if impl == "xla":
+            return _gconv_xla(B, C, u, w)
+        return _gc_call(False, (B, C, u), w, impl == "kernel_interpret")
+
+
+def _gconv_fwd(B, C, u, w, impl):
+    return _gconv(B, C, u, w, impl), (B, C, u, w)
+
+
+def _gconv_bwd(impl, res, g):
+    B, C, u, w = res
+    with jax.named_scope("block/conv/gate"):
+        if impl == "xla":
+            grads = _gconv_xla_bwd(B, C, u, w, g)
+        else:
+            grads = _gc_call(True, (B, C, u, g), w,
+                             impl == "kernel_interpret")
+        return grads[:3] + (grads[3].astype(w.dtype),)
+
+
+_gconv.defvjp(_gconv_fwd, _gconv_bwd)
+
+
+def gated_short_conv(B, C, u, w, *, impl=None):
+    """LFM2's double-gated short convolution, ``C * sum_j w[j] *
+    (B * u)[t - (K - 1) + j]`` with ``(B * u)[s] = 0`` before the row's
+    start: B, C, u [rows, S, Ch] in the stream's dtype, w [K, Ch] (tap
+    K - 1 reads the token itself) -> [rows, S, Ch] in u's dtype.  No bias,
+    no activation.  Depthwise: a channel reads its own past and nothing
+    else; a row reads nothing of another row.  The products and the sum are
+    float32, rounded once.
+
+    The backward is written out as ``causal_conv``'s: dC from the recomputed
+    taps, the taps' cotangent read the other way for ``d(B * u)`` and from
+    it dB and du, dw as K reductions.  ``impl``: None (the Pallas pair on a
+    TPU where the shapes tile, ``jnp`` elsewhere), ``"xla"``, ``"kernel"``,
+    ``"kernel_interpret"`` (the tests).  Which form a traced call took is
+    counted in ``ray_tpu_gated_conv_path_total``."""
+    from .attention import _on_tpu          # at the call: tests steer it
+    _, S, Ch = u.shape
+    if impl is None:
+        from ..parallel.mesh import get_global_mesh
+        mesh = get_global_mesh()
+        # (where the forward's tile divides S, the backward's half does)
+        impl = "kernel" if (_on_tpu() and _gc_tile(S, Ch, False)
+                            and w.shape[0] <= _GC_HEAD
+                            and not (mesh is not None and mesh.size > 1)) \
+            else "xla"
+    telemetry.inc("ray_tpu_gated_conv_path_total", tags={
+        "path": "xla" if impl == "xla" else "kernel",
+        "taps": str(w.shape[0])})
+    return _gconv(B, C, u, w, impl)
 
 
 def chunk_carry(dt, A, chunk: int):

@@ -426,12 +426,13 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
-                     "scores", "d_qk", "d_v"),
+                     "scores", "d_qk", "d_v", "d"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
                        "from the call's shapes (scores: qk or kq; d_qk "
                        "and d_v only where a call's values are not as "
-                       "wide as its keys)."},
+                       "wide as its keys; d only where the one head size "
+                       "is not 128)."},
     "ray_tpu_gmm_tile_geometry_total": {
         "type": "counter",
         "tag_keys": ("kind", "tm", "tk", "tn", "rows_a_group"),
@@ -478,6 +479,13 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "(ops/ssm.ssd_scan), by the path they take (kernel: "
                        "the Pallas pair, forward and backward; xla: the jnp "
                        "form) and the chunk's tokens."},
+    "ray_tpu_gated_conv_path_total": {
+        "type": "counter", "tag_keys": ("path", "taps"),
+        "description": "Double-gated short convolutions traced "
+                       "(ops/ssm.gated_short_conv), by the form they take "
+                       "(kernel: the Pallas pair, forward and backward; "
+                       "xla: jnp with the backward written out) and the "
+                       "taps."},
     "ray_tpu_norm_path_total": {
         "type": "counter", "tag_keys": ("path", "rows"),
         "description": "Calls of ops/norms.rms_norm traced, by the path "

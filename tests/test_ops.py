@@ -180,11 +180,12 @@ def test_rotate_and_place_kernel_is_the_split_rotation(B, H, S, dtype):
     assert _rope_paths().get(tile, 0) == before.get(tile, 0) + 1
 
 
-@pytest.mark.parametrize("D,positions", [(128, True), (64, False)])
+@pytest.mark.parametrize("D,positions", [(128, True), (32, False)])
 def test_rotate_heads_falls_back_to_the_split_rotation(D, positions):
-    """``positions`` given (a sequence shard's) or a head size that is not
-    the lane tile: ``apply_rope`` behind a transpose, bit for bit, although
-    the kernel was asked for."""
+    """``positions`` given (a sequence shard's) or a head size that is
+    neither the lane tile nor half of it (64 has the kernel since PR 47:
+    tests/test_lfm2.py): ``apply_rope`` behind a transpose, bit for bit,
+    although the kernel was asked for."""
     ks = jax.random.split(jax.random.key(D), 2)
     x = jax.random.normal(ks[0], (2, 96, 4, D), jnp.float32)
     g = jax.random.normal(ks[1], (2, 4, 96, D), jnp.float32)
@@ -553,9 +554,10 @@ def test_a_key_heads_query_heads_share_a_step(group, window):
     after = _geometry_counts()
     w = "" if window is None else f"_w{window}"
     for kernel, scores in (("fwd", "qk"), ("dq", "qk"), ("dkv", "kq")):
-        tags = (("block_k", "64"), ("block_q", "64"),
+        # a head size that is not 128 (32 here) is in the name and a tag
+        tags = (("block_k", "64"), ("block_q", "64"), ("d", "32"),
                 ("heads_a_step", str(group)), ("scores", scores))
-        name = f"flash_{kernel}{w}"
+        name = f"flash_{kernel}_d32{w}"
         assert after[name][tags] > before.get(name, {}).get(tags, 0), name
 
 

@@ -445,8 +445,8 @@ class TestTrainPath:
         text = lowered.as_text(debug_info=True)
         assert "jit_train_step" in text
         for scope in ("forward_backward", "optimizer", "embed", "block/attn",
-                      "block/mlp", "final_norm", "loss", "flash_fwd",
-                      "flash_dq", "flash_dkv"):
+                      "block/mlp", "final_norm", "loss", "flash_fwd_d16",
+                      "flash_dq_d16", "flash_dkv_d16"):   # heads of 16
             assert _has_scope(text, scope), scope
 
     def test_trainer_spans_reach_the_session_files(self, tmp_path):

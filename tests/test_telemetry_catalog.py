@@ -25,7 +25,8 @@ _NAME_RE = re.compile(r"^ray_tpu_[a-z0-9_]+$")
 SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
-              "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm")
+              "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
+              "gated")
 
 
 class TestCatalog:
@@ -457,6 +458,11 @@ class TestSmokeAllSubsystems:
         from ray_tpu.ops.rope import rope_lane_tables, rotate_heads
         rotate_heads(jnp.ones((1, 8, 2, 32), jnp.float32),
                      *rope_lane_tables(32, 8))
+
+        # -- gated: a traced double-gated short convolution counts its form.
+        from ray_tpu.ops.ssm import gated_short_conv
+        c = jnp.ones((1, 8, 4), jnp.float32)
+        gated_short_conv(c, c, c, jnp.ones((3, 4), jnp.float32))
 
         # -- eva: a traced EVA kernel counts its table's steps (two
         # windows of 32 in chunks of 8, interpreted here).
