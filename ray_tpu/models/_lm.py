@@ -44,15 +44,27 @@ def count_params(shapes) -> int:
 
 
 def project_heads(h, w, dt):
-    """h [B, S, E] x w [E, H, D] -> [B, S, H, D], the layout
-    ``ops.rope.rotate_heads`` takes.  Said as one [B*S, E] x [E, H*D]
-    product: as an einsum to ``bshd`` the TPU compiler emits the recomputed
-    forward's result with the sequence minor and copies it round for the
-    kernel (``copy`` of 67 MB a projection at Yi's shape; PERF.md, PR 35)."""
+    """h [B, S, E] x w [E, H, D] -> [B, S, H, D]: the bytes of [B, S, H*D],
+    the layout ``ops.rope.rotate_heads`` takes for q and k and the flash
+    kernels for v (``ops.attention``'s ``rows``).  Said as one [B*S, E] x
+    [E, H*D] product: as an einsum to ``bshd`` the TPU compiler emits the
+    recomputed forward's result with the sequence minor and copies it round
+    for the kernel (``copy`` of 67 MB a projection at Yi's shape; PERF.md,
+    PR 35), and as one to ``bhsd`` it transposes what it wrote (PR 49)."""
     E, H, D = w.shape
     flat = jnp.einsum("bse,ef->bsf", h, w.astype(dt).reshape(E, H * D),
                       preferred_element_type=dt)
     return flat.reshape(*h.shape[:2], H, D)
+
+
+def merge_heads(a, w, dt):
+    """a [B, S, H, D] x w [H, D, E] -> [B, S, E]: ``project_heads``' way
+    back, one [B*S, H*D] x [H*D, E] product over attention's result as the
+    flash kernels write it (``rows``)."""
+    H, D, E = w.shape
+    return jnp.einsum("bsf,fe->bse", a.reshape(*a.shape[:2], H * D),
+                      w.astype(dt).reshape(H * D, E),
+                      preferred_element_type=dt)
 
 
 def token_nll(x, lm_head, targets, num_chunks: int, dt, weights=None):

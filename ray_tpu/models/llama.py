@@ -145,8 +145,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     }
 
 
-def _attend(cfg: LlamaConfig, q, k, v, positions):
-    """q: [B, H, S, D]. Dispatch per configured impl.
+def _attend(cfg: LlamaConfig, q, k, v, positions, rows=False):
+    """q, k: [B, H, S, D]; v and the result head-major too, or [B, S, H, D]
+    with ``rows`` (``ops.attention``'s).  Dispatch per configured impl.
 
     ring/ulysses run as shard_map islands inside the GSPMD forward: the
     logically-full q/k/v keep their (dp,fsdp)/tp/sp layout, the island
@@ -158,6 +159,7 @@ def _attend(cfg: LlamaConfig, q, k, v, positions):
         from ..ops.ring_attention import ring_attention_sharded
         from ..ops.ulysses import ulysses_attention_sharded
         from ..parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_TENSOR
+        assert not rows                     # these take head-major arrays
         spec = P((AXIS_DATA, AXIS_FSDP), AXIS_TENSOR, cfg.seq_axis, None)
         fn = (ring_attention_sharded if cfg.attention_impl == "ring"
               else ulysses_attention_sharded)
@@ -166,7 +168,7 @@ def _attend(cfg: LlamaConfig, q, k, v, positions):
                               "reference"):
         impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
         return _attention(q, k, v, causal=True, impl=impl,
-                          mesh=_kernel_mesh(cfg))
+                          mesh=_kernel_mesh(cfg), rows=rows)
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 
@@ -177,25 +179,48 @@ def _kernel_mesh(cfg: LlamaConfig):
     return None if cfg.pp_microbatches else get_global_mesh()
 
 
+def _values_as_rows(cfg: LlamaConfig) -> bool:
+    """Whether v and attention's result stay where the projections leave
+    and take them, [B, S, H, D], the flash kernels addressing them there
+    (``rows``): on one device.  Head-major they cost five q-sized copies a
+    layer call there (v, the recomputed v, ``do``; ``out`` and ``dv`` for
+    the weight gradients: 1.7 % of Yi's step, 2.1 % of Ouro's).  On a mesh
+    the flat ``wv`` / ``wo`` products change the partitioner's rings (six
+    more ``collective-permute``s a layer in Mistral's step on four chips,
+    which then loses 1.4 %: 2,672.5 -> 2,635.6 tokens/s/chip; PERF.md,
+    PR 49), so a mesh keeps the head-major arrangement, as ring / ulysses
+    do, which need a mesh and take nothing else."""
+    from ..parallel.mesh import get_global_mesh
+    mesh = get_global_mesh()
+    return (cfg.attention_impl not in ("ring", "ulysses")
+            and (mesh is None or mesh.size == 1))
+
+
 def attention_branch(cfg: LlamaConfig, cos, sin, positions, h, layer):
     """Attn(h) of a layer for normed h [B, S, E]: q, k, v, rotary embedding,
     the configured attention, the output projection.  ``cos`` / ``sin`` are
     ``rope_lane_tables``'; q and k leave their projections as [B, S, H, D]
-    and ``rotate_heads`` places them head-major on the way.  The residual
+    and ``rotate_heads`` places them head-major on the way.  On one device
+    (``_values_as_rows``) v and the attention's result lie as rows too: the
+    flash kernels read and write them in place and nothing is transposed
+    between a projection and a kernel but by the rotary pair.  The residual
     and the norms round it are the caller's (here ``_attn_half``;
     models/ouro.py puts a second norm behind it)."""
     dt = cfg.dtype
     q = _lm.project_heads(h, layer["wq"], dt)
     k = _lm.project_heads(h, layer["wk"], dt)
-    v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"].astype(dt),
-                   preferred_element_type=dt)
     rope = partial(rotate_heads, cos2=cos, sin2=sin, positions=positions,
                    interpret=cfg.attention_impl == "flash_interpret",
                    mesh=_kernel_mesh(cfg))
     q, k = rope(q), rope(k)
-    attn = _attend(cfg, q, k, v, positions)
-    return jnp.einsum("bhsd,hde->bse", attn, layer["wo"].astype(dt),
-                      preferred_element_type=dt)
+    if _values_as_rows(cfg):
+        v = _lm.project_heads(h, layer["wv"], dt)
+        return _lm.merge_heads(_attend(cfg, q, k, v, positions, rows=True),
+                               layer["wo"], dt)
+    v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"].astype(dt),
+                   preferred_element_type=dt)
+    return jnp.einsum("bhsd,hde->bse", _attend(cfg, q, k, v, positions),
+                      layer["wo"].astype(dt), preferred_element_type=dt)
 
 
 def mlp_branch(cfg: LlamaConfig, h, layer):

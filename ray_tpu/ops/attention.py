@@ -35,6 +35,22 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   as it is.  Such a call carries both sizes in its kernels' names
   (``flash_fwd_d192v128``) and in the geometry counter's tags; with equal
   sizes names and tags are as they were.
+- **v and the result are read and written where the projections hold
+  them** (``rows``).  The kernels' home layout is head-major,
+  [B, H, S, D]: what the rotary pair writes for q and k
+  (``ops.rope.rotate_heads``).  A projection writes [B, S, H * D], and v,
+  ``out``, ``do`` and ``dv`` need not be turned for the kernels' sake: with
+  ``rows`` a call hands v as [B, S, Hkv, Dv] and takes the result as
+  [B, S, H, Dv]; the block of such an operand is ``(1, block, heads * Dv)``
+  at the lane-block of the grid row's heads, and a kernel takes a head as
+  the lane slice ``[:, h*Dv:(h+1)*Dv]`` (``_head``, ``_rows``,
+  ``_write_rows``: all a kernel body knows of the layout; ``_specs`` is the
+  rest).  ``dv`` leaves and ``do`` arrives so, and the residuals are kept
+  so.  A head must be whole lane tiles, so the specs apply where both head
+  sizes are multiples of 128; any other ``rows`` call is turned at
+  ``flash_attention``'s edge.  The geometry counter says which kernels a
+  trace took so (PERF.md, PR 49: five q-sized copies a layer call fewer in
+  Yi's and Ouro's steps).
 - The grid is ("parallel", "arbitrary"): only the grid rows split across
   the cores of a two-core chip (v4, v5p): B * H of them without a group,
   B * Hkv with one, so a call with one or two key heads a device no longer
@@ -292,14 +308,29 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
     return Tiles(block_q, block_k, heads, scores)
 
 
-def _geometry(kind, q, k, v, block_q, block_k, window):
-    """``_tiles``' answer for this call, an explicit block size winning,
-    checked against the lengths and counted."""
-    _, H, Sq, D = q.shape
-    _, Hkv, Sk, _ = k.shape
-    Dv = v.shape[-1]
+class Dims(NamedTuple):
+    """A call's sizes, whatever the layout of its operands."""
+    B: int
+    H: int
+    Hkv: int
+    Sq: int
+    Sk: int
+    D: int
+    Dv: int
+
+
+def _dims(q, k, v):
+    """``Dims`` of a call: q and k are head-major whatever v is."""
+    (B, H, Sq, D), (Hkv, Sk) = q.shape, k.shape[1:3]
     if H % Hkv:
         raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
+    return Dims(B, H, Hkv, Sq, Sk, D, v.shape[-1])
+
+
+def _geometry(kind, dims, block_q, block_k, window, rows):
+    """``_tiles``' answer for this call, an explicit block size winning,
+    checked against the lengths and counted."""
+    _, H, Hkv, Sq, Sk, D, Dv = dims
     t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window)
     t = t._replace(block_q=min(block_q or t.block_q, Sq),
                    block_k=min(block_k or t.block_k, Sk))
@@ -311,25 +342,57 @@ def _geometry(kind, q, k, v, block_q, block_k, window):
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
         **({"d_qk": str(D), "d_v": str(Dv)} if D != Dv
-           else {} if D == LANES else {"d": str(D)})})
+           else {} if D == LANES else {"d": str(D)}),
+        **({"rows": "vo"} if rows else {})})
     return t
 
 
-def _rows(ref):
-    """A block [1, heads, rows, n] as [heads * rows, n]: the heads of a
-    step one after another."""
-    x = ref[0]
-    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+# A step's block of ``heads`` query heads is [1, heads, rows, n] of a
+# head-major operand, or [1, rows, heads * n] of one that lies as rows (the
+# result and its cotangent under ``rows``: [B, S, H * n], what a projection
+# writes and reads): a head is then a slice of the lanes, on a tile's edge
+# since n % 128 == 0 (``flash_attention`` sees to it).  The three below
+# are all a kernel body knows of the layout.
+
+def _head(ref, h, heads, as_rows=False):
+    """Head ``h`` of a step's block: [rows, n]."""
+    if not as_rows:
+        return ref[0, h]
+    n = ref.shape[-1] // heads
+    return ref[0, :, h * n:(h + 1) * n]
+
+
+def _rows(ref, heads=None, as_rows=False):
+    """A step's block as [heads * rows, n]: the heads one after another."""
+    if not as_rows:
+        x = ref[0]
+        return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+    if heads == 1:
+        return ref[0]
+    return jnp.concatenate(
+        [_head(ref, h, heads, True) for h in range(heads)], axis=0)
+
+
+def _write_rows(ref, x, heads, as_rows=False):
+    """``_rows``' inverse: x [heads * rows, n] into a step's block."""
+    x = x.astype(ref.dtype)
+    if not as_rows:
+        ref[0] = x.reshape(ref.shape[1:])
+        return
+    rows, n = ref.shape[1], ref.shape[-1] // heads
+    for h in range(heads):
+        ref[0, :, h * n:(h + 1) * n] = x[h * rows:(h + 1) * rows]
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 acc_scr, *, causal, scale, block_q, block_k, q_offset,
-                window=None):
+                window=None, rows=False):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
+    heads = m_scr.shape[0] // block_q
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
@@ -366,13 +429,13 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _finish():
         l = l_scr[...]
         l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
-        o_ref[0] = (acc_scr[...] * _bcast_lanes(l_inv, acc_scr.shape[1])
-                    ).astype(o_ref.dtype).reshape(o_ref.shape[1:])
+        _write_rows(o_ref, acc_scr[...] * _bcast_lanes(
+            l_inv, acc_scr.shape[1]), heads, rows)
         if lse_ref is not None:
             # Out as rows along the lanes, [heads, 1, bq]: what the backward
             # kernels read, and 1/128 of the lane-broadcast columns.
             lse = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
-            for h in range(lse_ref.shape[1]):
+            for h in range(heads):
                 lse_ref[0, h] = lse[h * block_q:(h + 1) * block_q].T[:1]
 
 
@@ -390,28 +453,27 @@ def _kernel_name(base, window, D=None, Dv=None):
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
-                   interpret, *, need_lse, window=None):
+                   interpret, *, need_lse, window=None, rows=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, Sq, D = q.shape
-    _, Hkv, Sk, _ = k.shape
-    Dv = v.shape[-1]
+    dims = _dims(q, k, v)
+    B, H, Hkv, Sq, Sk, D, Dv = dims
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
-    t = _geometry("fwd", q, k, v, block_q, block_k, window)
+    t = _geometry("fwd", dims, block_q, block_k, window, rows)
     sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset, causal,
                              "q", window)
-    n, rows = B * H // t.heads, t.heads * t.block_q
+    n, stacked = B * H // t.heads, t.heads * t.block_q
 
-    sp = _specs(t, H // Hkv, D, Dv)
+    sp = _specs(t, dims, rows)
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
-        block_k=t.block_k, q_offset=q_offset, window=window)
+        block_k=t.block_k, q_offset=q_offset, window=window, rows=rows)
 
     out_specs = [sp.o]
-    out_shape = [jax.ShapeDtypeStruct((n, t.heads, Sq, Dv), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct(sp.shapes["o"], q.dtype)]
     if need_lse:
         out_specs.append(sp.row)
         out_shape.append(
@@ -431,17 +493,17 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             in_specs=[sp.q, sp.k, sp.v],
             out_specs=out_specs,
             scratch_shapes=[
-                _vmem((rows, LANES), jnp.float32),
-                _vmem((rows, LANES), jnp.float32),
-                _vmem((rows, Dv), jnp.float32),
+                _vmem((stacked, LANES), jnp.float32),
+                _vmem((stacked, LANES), jnp.float32),
+                _vmem((stacked, Dv), jnp.float32),
             ]),
         out_shape=out_shape,
         interpret=interpret,
         name=_kernel_name("flash_fwd", window, D, Dv),
-        **_compiler_params(interpret, rows, t.block_k),
-    )(sched, q.reshape(n, t.heads, Sq, D), k.reshape(B * Hkv, Sk, D),
-      v.reshape(B * Hkv, Sk, Dv))
-    out = res[0].reshape(B, H, Sq, Dv)
+        **_compiler_params(interpret, stacked, t.block_k),
+    )(sched, *(x.reshape(sp.shapes[name])
+               for name, x in (("q", q), ("k", k), ("v", v))))
+    out = res[0].reshape((B, Sq, H, Dv) if rows else (B, H, Sq, Dv))
     if not need_lse:
         return out, None
     return out, res[1].reshape(B, H, Sq)
@@ -453,41 +515,68 @@ def _vmem(shape, dtype):
 
 
 class Specs(NamedTuple):
-    q: object       # [n, heads, S, D] blocks by q block (q, dq)
+    """Block specs of a kernel's operands, and the shape each is handed
+    over in: a reshape of the caller's array, never a copy."""
+    q: object       # blocks by q block of t.heads query heads (q, dq)
     o: object       # the same at the values' head size (o, do)
-    k: object       # [B*Hkv, S, D] blocks by k block
+    k: object       # blocks by k block of the grid row's key head
     v: object       # the same at the values' head size
     row: object     # rows along the lanes of [n, heads, 1, S] (LSE, delta)
-    dk: object      # [n, S, D] blocks by k block, a grid row's own
+    dk: object      # blocks by k block, a grid row's own
     dv: object      # the same at the values' head size
+    shapes: dict    # name -> the shape that operand is handed over in
 
 
-def _specs(t, group, D, Dv):
+def _specs(t, dims, rows=False):
     """Block specs of a grid (n, steps) at geometry ``t``, the blocks coming
     from the prefetched schedule; a grid row is ``t.heads`` query heads of
-    one key head.  ``D`` is q's and k's head size, ``Dv`` v's and o's."""
+    one key head.  A head-major operand is [n, heads, S, D] (q, o) or
+    [B * Hkv, S, D] (k, v) and a block is whole heads; with ``rows`` the
+    operands at the values' head size (v, o, dv) lie as [B, S, H * Dv] and
+    a block is the ``block`` rows of the step's heads' lanes, ``heads *
+    Dv`` of them at lane-block ``r`` modulo the grid rows of a batch
+    element."""
     from jax.experimental import pallas as pl
+    B, H, Hkv, Sq, Sk, D, Dv = dims
+    group, n = H // Hkv, B * H // t.heads
+    per_b = H // t.heads            # grid rows a batch element
 
-    def q_index(r, s, sched):
-        return (r, 0, _step_qi(sched[s]), 0)
+    def of_q(d, as_rows):
+        """(spec, shape) of a q-side operand of head size ``d``."""
+        if as_rows:
+            return (pl.BlockSpec(
+                (1, t.block_q, t.heads * d), lambda r, s, sched: (
+                    r // per_b, _step_qi(sched[s]), r % per_b)),
+                (B, Sq, H * d))
+        return (pl.BlockSpec(
+            (1, t.heads, t.block_q, d), lambda r, s, sched: (
+                r, 0, _step_qi(sched[s]), 0)),
+            (n, t.heads, Sq, d))
 
-    def kv_index(r, s, sched):
-        return (r * t.heads // group, _step_ki(sched[s]), 0)
+    def of_k(d, as_rows, own=False):
+        """(spec, shape) of a k-side operand of head size ``d``: the block
+        of the grid row's key head, or with ``own`` the grid row's own (dk,
+        dv: one a step's heads)."""
+        heads = per_b if own else Hkv       # of them a batch element
+        head = (lambda r: r) if own else (lambda r: r * t.heads // group)
+        if as_rows:
+            return (pl.BlockSpec(
+                (1, t.block_k, d), lambda r, s, sched: (
+                    head(r) // heads, _step_ki(sched[s]), head(r) % heads)),
+                (B, Sk, heads * d))
+        return (pl.BlockSpec(
+            (1, t.block_k, d), lambda r, s, sched: (
+                head(r), _step_ki(sched[s]), 0)),
+            (B * heads, Sk, d))
 
-    def row_index(r, s, sched):
-        return (r, 0, 0, _step_qi(sched[s]))
-
-    def dkv_index(r, s, sched):
-        return (r, _step_ki(sched[s]), 0)
-
+    made = {"q": of_q(D, False), "o": of_q(Dv, rows),
+            "k": of_k(D, False), "v": of_k(Dv, rows),
+            "dk": of_k(D, False, own=True), "dv": of_k(Dv, rows, own=True)}
     return Specs(
-        q=pl.BlockSpec((1, t.heads, t.block_q, D), q_index),
-        o=pl.BlockSpec((1, t.heads, t.block_q, Dv), q_index),
-        k=pl.BlockSpec((1, t.block_k, D), kv_index),
-        v=pl.BlockSpec((1, t.block_k, Dv), kv_index),
-        row=pl.BlockSpec((1, t.heads, 1, t.block_q), row_index),
-        dk=pl.BlockSpec((1, t.block_k, D), dkv_index),
-        dv=pl.BlockSpec((1, t.block_k, Dv), dkv_index))
+        row=pl.BlockSpec((1, t.heads, 1, t.block_q), lambda r, s, sched: (
+            r, 0, 0, _step_qi(sched[s]))),
+        shapes={name: shape for name, (_, shape) in made.items()},
+        **{name: spec for name, (spec, _) in made.items()})
 
 
 # A step's float32 tiles (scores, probabilities and their like) above which
@@ -511,28 +600,29 @@ def _compiler_params(interpret, rows, cols):
 
 def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                dq_ref, dq_scr, lse_scr, di_scr, *, causal, scale, block_q,
-               block_k, q_offset, window=None):
+               block_k, q_offset, window=None, rows=False):
     """``lse`` and ``di`` arrive as rows along the lanes (as dk/dv reads
     them); the resident q block's first step turns them into the
     lane-broadcast columns [heads * bq, 128] the steps subtract."""
     from jax.experimental import pallas as pl
 
+    heads = lse_ref.shape[1]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
     @pl.when(step & _FIRST_BIT != 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
-        for h in range(q_ref.shape[1]):
-            rows = slice(h * block_q, (h + 1) * block_q)
+        for h in range(heads):
+            of_h = slice(h * block_q, (h + 1) * block_q)
             for row_ref, col_scr in ((lse_ref, lse_scr), (di_ref, di_scr)):
-                col_scr[rows, :] = jnp.broadcast_to(
+                col_scr[of_h, :] = jnp.broadcast_to(
                     row_ref[0, h], (LANES, block_q)).T
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
         q = _rows(q_ref)                               # [heads * bq, D]
-        do = _rows(do_ref)
+        do = _rows(do_ref, heads, rows)
         k = k_ref[0]
         v = v_ref[0]
         s = jax.lax.dot_general(
@@ -551,19 +641,19 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype).reshape(
-            dq_ref.shape[1:])
+        _write_rows(dq_ref, dq_scr[...], heads)
 
 
 def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, scale, block_q, block_k, q_offset,
-                window=None):
+                *, causal, scale, block_q, block_k, q_offset, window=None,
+                rows=False):
     """The scores are formed transposed, ``k q^T`` [bk, bq], so that dv =
     p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
     along the lanes.  The step's heads add into the one resident dk / dv."""
     from jax.experimental import pallas as pl
 
+    heads = lse_ref.shape[1]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
@@ -582,9 +672,9 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                                      transposed=True)  # [bk, bq]
         dk = dk_scr[...]
         dv = dv_scr[...]
-        for h in range(q_ref.shape[1]):
+        for h in range(heads):
             q = q_ref[0, h]                            # [bq, D]
-            do = do_ref[0, h]
+            do = _head(do_ref, h, heads, rows)
             st = jax.lax.dot_general(
                 k, q, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bk, bq]
@@ -609,31 +699,45 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
-                    q_offset, interpret, window=None):
+                    q_offset, interpret, window=None, rows=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, Sq, D = q.shape
-    _, Hkv, Sk, _ = k.shape
-    Dv = v.shape[-1]
-    group = H // Hkv
-    kr = k.reshape(B * Hkv, Sk, D)
-    vr = v.reshape(B * Hkv, Sk, Dv)
+    dims = _dims(q, k, v)
+    B, H, Hkv, Sq, Sk, D, Dv = dims
 
-    # delta_i = rowsum(dO * O): one fused elementwise+reduce pass in XLA.
-    di = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    # delta_i = rowsum(dO * O), [B, H, Sq]: one fused elementwise+reduce
+    # pass in XLA.
+    di = dout.astype(jnp.float32) * out.astype(jnp.float32)
+    if not rows:
+        di = jnp.sum(di, axis=-1)
+    elif Sq % 8:
+        di = jnp.swapaxes(jnp.sum(di, axis=-1), 1, 2)
+    else:
+        # Said with the rows in the groups of eight sublanes they lie in:
+        # summed straight to [B, Sq, H] the TPU compiler writes the float32
+        # product out and re-tiles it by heads before it sums it, three
+        # q-sized float32 passes for this one (PERF.md, PR 49).
+        di = jnp.moveaxis(di.reshape(B, Sq // 8, 8, H, Dv).sum(axis=-1),
+                          3, 1).reshape(B, H, Sq)
 
-    def call(kind, kernel, t, major, out_specs, out_shape, scratch, tile):
-        """One backward kernel at its geometry ``t``; LSE / delta enter as
+    def call(kind, kernel, t, major, outs, scratch, tile):
+        """One backward kernel at its geometry ``t``, its results the
+        operands named in ``outs`` (name -> dtype); LSE / delta enter as
         rows along the lanes, with no broadcast outside."""
         n = B * H // t.heads
-        sp = _specs(t, group, D, Dv)
+        sp = _specs(t, dims, rows)
         sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset,
                                  causal, major, window)
+        out_specs = [getattr(sp, name) for name in outs]
+        out_shape = [jax.ShapeDtypeStruct(sp.shapes[name], dtype)
+                     for name, dtype in outs.items()]
+        if len(outs) == 1:      # one result, not a list of one, as before
+            out_specs, out_shape = out_specs[0], out_shape[0]
         return pl.pallas_call(
             functools.partial(kernel, causal=causal, scale=scale,
                               block_q=t.block_q, block_k=t.block_k,
-                              q_offset=q_offset, window=window),
+                              q_offset=q_offset, window=window, rows=rows),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(n, sched.size),
@@ -644,83 +748,112 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             interpret=interpret,
             name=_kernel_name(f"flash_{kind}", window, D, Dv),
             **_compiler_params(interpret, *tile),
-        )(sched, q.reshape(n, t.heads, Sq, D), kr, vr,
-          dout.reshape(n, t.heads, Sq, Dv),
+        )(sched, q.reshape(sp.shapes["q"]), k.reshape(sp.shapes["k"]),
+          v.reshape(sp.shapes["v"]), dout.reshape(sp.shapes["o"]),
           lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
 
     # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-    t = _geometry("dq", q, k, v, block_q, block_k, window)
-    rows = t.heads * t.block_q
+    t = _geometry("dq", dims, block_q, block_k, window, rows)
+    stacked = t.heads * t.block_q
     dq = call(
-        "dq", _dq_kernel, t, "q", _specs(t, group, D, Dv).q,
-        jax.ShapeDtypeStruct((B * H // t.heads, t.heads, Sq, D), q.dtype),
-        [_vmem((rows, D), jnp.float32), _vmem((rows, LANES), jnp.float32),
-         _vmem((rows, LANES), jnp.float32)],
-        (rows, t.block_k)).reshape(q.shape)
+        "dq", _dq_kernel, t, "q", {"q": q.dtype},
+        [_vmem((stacked, D), jnp.float32),
+         _vmem((stacked, LANES), jnp.float32),
+         _vmem((stacked, LANES), jnp.float32)],
+        (stacked, t.block_k))
 
     # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk), the
     # step's query heads adding into it.  Where a step holds the whole
     # group the results leave per key head in the inputs' dtype; else per
     # step's heads in float32, summed over the group below.
-    t = _geometry("dkv", q, k, v, block_q, block_k, window)
-    parts = group // t.heads
-    sp = _specs(t, group, D, Dv)
+    t = _geometry("dkv", dims, block_q, block_k, window, rows)
+    parts = H // Hkv // t.heads
     dk, dv = call(
-        "dkv", _dkv_kernel, t, "k", [sp.dk, sp.dv],
-        [jax.ShapeDtypeStruct((B * H // t.heads, Sk, d),
-                              jnp.float32 if parts > 1 else k.dtype)
-         for d in (D, Dv)],
+        "dkv", _dkv_kernel, t, "k",
+        {name: jnp.float32 if parts > 1 else k.dtype
+         for name in ("dk", "dv")},
         [_vmem((t.block_k, d), jnp.float32) for d in (D, Dv)],
         (t.block_q, t.block_k))
     if parts > 1:
+        # Over a key head's parts, which lie side by side.
         dk = dk.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(B, Hkv, parts, Sk, Dv).sum(axis=2).astype(v.dtype)
-    return dq, dk.reshape(k.shape), dv.reshape(v.shape)
+        dv = (dv.reshape(B, Sk, Hkv, parts, Dv).sum(axis=3) if rows
+              else dv.reshape(B, Hkv, parts, Sk, Dv).sum(axis=2)
+              ).astype(v.dtype)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------- wrapper
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
-           window):
+           window, rows):
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                             q_offset, interpret, need_lse=False,
-                            window=window)
+                            window=window, rows=rows)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
-               window):
+               window, rows):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                               q_offset, interpret, need_lse=True,
-                              window=window)
+                              window=window, rows=rows)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, window,
-               res, dout):
+               rows, res, dout):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q,
-                           block_k, q_offset, interpret, window)
+                           block_k, q_offset, interpret, window, rows)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+def _head_major(fn, q, k, v, rows):
+    """``fn``, which takes and returns [B, H, S, D], for a call whose v and
+    result lie as [B, S, H, D] under ``rows``."""
+    if not rows:
+        return fn(q, k, v)
+    return jnp.swapaxes(fn(q, k, jnp.swapaxes(v, 1, 2)), 1, 2)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, q_offset: int = 0,
-                    interpret: bool = False, window: Optional[int] = None):
+                    interpret: bool = False, window: Optional[int] = None,
+                    rows: bool = False):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
-    q: [B, H, Sq, D]; k: [B, Hkv, Sk, D]; v: [B, Hkv, Sk, Dv], with ``Dv``
-    free to differ from ``D``; returns [B, H, Sq, Dv].  ``window``: with ``causal``,
-    a key is visible iff ``0 <= t - s < window``.  ``block_q`` /
-    ``block_k`` default to what ``_tiles`` picks for each kernel from the
-    shapes."""
+
+    q: [B, H, Sq, D]; k: [B, Hkv, Sk, D], head-major as
+    ``ops.rope.rotate_heads`` places them.  v: [B, Hkv, Sk, Dv], with
+    ``Dv`` free to differ from ``D``, and the result [B, H, Sq, Dv]; or,
+    with ``rows``, v [B, Sk, Hkv, Dv] and the result [B, Sq, H, Dv], as a
+    projection leaves and takes them.  A gradient has its operand's layout,
+    the result's cotangent the result's.  The kernels read and write rows
+    in place (module docstring) where both head sizes are multiples of 128;
+    for any other v is turned head-major here and the result back, so a
+    call may always say what it holds.  The kernels take a rows operand as
+    [B, S, heads * Dv]: hand over a projection's result as the same program
+    reshapes it (a bitcast), not an array that entered the jit as
+    [B, S, heads, Dv], which lies tiled by (heads, Dv) and is copied on its
+    way (PERF.md, PR 49).
+
+    ``window``: with ``causal``, a key is visible iff ``0 <= t - s <
+    window``.  ``block_q`` / ``block_k`` default to what ``_tiles`` picks
+    for each kernel from the shapes."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if rows and (q.shape[-1] % LANES or v.shape[-1] % LANES):
+        return _head_major(
+            functools.partial(flash_attention, causal=causal, scale=scale,
+                              block_q=block_q, block_k=block_k,
+                              q_offset=q_offset, interpret=interpret,
+                              window=window), q, k, v, rows)
     return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
-                  interpret, window)
+                  interpret, window, bool(rows))
 
 
 def _on_tpu() -> bool:
@@ -731,8 +864,10 @@ def _on_tpu() -> bool:
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               impl: Optional[str] = None, mesh=None,
-              window: Optional[int] = None):
+              window: Optional[int] = None, rows: bool = False):
     """Dispatching entry point: pallas flash on TPU, reference elsewhere.
+    ``rows`` is ``flash_attention``'s: v and the result lie as
+    [B, S, H, D].
 
     ``mesh`` is the SPMD mesh q/k/v are laid out on inside a GSPMD
     program.  A Mosaic kernel cannot be partitioned automatically, so on
@@ -741,8 +876,9 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     if impl is None:
         impl = "flash" if _on_tpu() else "reference"
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, scale=scale,
-                                   window=window)
+        return _head_major(
+            functools.partial(reference_attention, causal=causal,
+                              scale=scale, window=window), q, k, v, rows)
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
                            interpret=impl == "flash_interpret",
                            window=window)
@@ -756,6 +892,9 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
                 "flash attention needs the whole sequence on each device; "
                 "use attention_impl='ring' or 'ulysses' on an sp mesh")
         spec = P((AXIS_DATA, AXIS_FSDP), AXIS_TENSOR, None, None)
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    return fn(q, k, v)
+        # The island takes head-major arrays; no caller says ``rows`` on a
+        # mesh (models/llama._values_as_rows), one that did is turned here.
+        return _head_major(
+            jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                          out_specs=spec, check_vma=False), q, k, v, rows)
+    return fn(q, k, v, rows=rows)

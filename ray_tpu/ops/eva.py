@@ -57,7 +57,7 @@ from ..util import telemetry
 from .attention import (_BLOCK_MASK, _FIRST_BIT, _KI_SHIFT, _LAST_BIT,
                         _QI_SHIFT, _RUN_BIT, DIAGONAL, EMPTY, FIRST,
                         INTERIOR, KI, KIND, LANES, LAST, MASK_VALUE, NEG_INF,
-                        QI, Tiles, _bcast_lanes, _causal_mask_bias,
+                        QI, Dims, Tiles, _bcast_lanes, _causal_mask_bias,
                         _compiler_params, _dkv_kernel, _rows, _specs,
                         _step_ki, _step_qi, _tiles, _vmem, block_schedule)
 
@@ -490,7 +490,7 @@ def _two_operand_call(kind, kernel, q, k, v, ks, vs, extra, results,
     n = B * H
     t, block_s, sched, taken = _geometry(kind, S, D, window, chunk, *blocks)
     steps = sched.shape[1]
-    sp = _specs(t, 1, D, D)
+    sp = _specs(t, Dims(n, 1, 1, S, S, D, D))
     sum_spec = pl.BlockSpec(
         (1, block_s, D), lambda r, s, sched: (r, sched[steps + s] >> 1, 0))
     spec = {4: sp.q, 3: sp.row}
@@ -546,7 +546,7 @@ def _eva_backward(q, k, v, ks, vs, out, lse, dout, window, chunk, scale,
         """``attention._dkv_kernel`` on this kind's K-major table: the
         gradients of ``keys`` and ``values`` [B, H, rows, D]."""
         t, _, sched, _ = _geometry(kind, S, D, window, chunk, *blocks)
-        sp = _specs(t, 1, D, D)
+        sp = _specs(t, Dims(n, 1, 1, S, S, D, D))
         flat = lambda a: a.reshape(n, a.shape[2], D)
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, causal=causal, scale=scale,

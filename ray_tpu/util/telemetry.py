@@ -426,13 +426,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
-                     "scores", "d_qk", "d_v", "d"),
+                     "scores", "d_qk", "d_v", "d", "rows"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
                        "from the call's shapes (scores: qk or kq; d_qk "
                        "and d_v only where a call's values are not as "
                        "wide as its keys; d only where the one head size "
-                       "is not 128)."},
+                       "is not 128; rows=vo only where the kernel "
+                       "addresses v and the result as [B, S, H * D], the "
+                       "projections' layout; a kernel without the tag "
+                       "took them head-major)."},
     "ray_tpu_gmm_tile_geometry_total": {
         "type": "counter",
         "tag_keys": ("kind", "tm", "tk", "tn", "rows_a_group"),

@@ -208,6 +208,37 @@ class TestFlashOnAMesh:
         for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
 
+    def test_rows_call_on_a_mesh_is_turned_at_the_islands_edge(self):
+        """The island takes head-major arrays (the models say ``rows`` on
+        one device only); a call that says ``rows`` on a mesh has v turned
+        at the edge and the result back, and every gradient in its
+        operand's layout."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from ray_tpu.ops.attention import attention, reference_attention
+        from ray_tpu.parallel import build_mesh
+        from ray_tpu.train import MeshConfig
+
+        ks = jax.random.split(jax.random.key(1), 3)
+        q, k, v = (jax.random.normal(k, (4, 4, 128, 128), jnp.float32)
+                   for k in ks)
+        mesh = build_mesh(MeshConfig.parse("dp2xfsdp2xtp2").spec_for(8),
+                          devices=jax.devices()[:8])
+        turn = lambda x: jnp.swapaxes(x, 1, 2)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+        got = jax.jit(jax.value_and_grad(loss(lambda q, k, v: attention(
+            q, k, v, impl="flash_interpret", mesh=mesh, rows=True)),
+            (0, 1, 2)))(q, k, turn(v))
+        want = jax.value_and_grad(loss(reference_attention), (0, 1, 2))(
+            q, k, v)
+        np.testing.assert_allclose(got[0], want[0], rtol=2e-4)
+        for g, w in zip((got[1][0], got[1][1], turn(got[1][2])), want[1]):
+            np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
     def test_sequence_sharded_mesh_is_refused(self, qkv):
         import jax
         from ray_tpu.ops.attention import attention

@@ -1,6 +1,7 @@
 """Numerics tests for the ops layer on the 8-device CPU mesh."""
 
 import importlib
+from functools import partial
 
 import numpy as np
 import pytest
@@ -577,6 +578,166 @@ def test_group_wider_than_a_step_is_summed_outside():
     want = grads(lambda q, k, v: reference_attention(q, k, v))
     for a, b, name in zip(want, got, ("dq", "dk", "dv")):
         np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
+
+
+# v and the result where the projections leave them (PR 49): B, H, Hkv, D,
+# Dv, window, causal.
+ROWS_CASES = {
+    # Yi's and Ouro's layer call, Mistral's, Trinity's window layers.
+    "no_group_16": (1, 16, 16, 128, 128, None, True),
+    "group4": (1, 8, 2, 128, 128, None, True),
+    "group8": (1, 8, 1, 128, 128, None, True),
+    "group8_window": (1, 8, 1, 128, 128, 96, True),
+    "not_causal": (1, 2, 2, 128, 128, None, False),
+    # a group wider than a step: dk / dv per step's heads, summed outside
+    "group16": (1, 16, 1, 128, 128, None, True),
+    # several rows a call: a grid row's batch element and lane-block
+    "two_rows_no_group": (2, 2, 2, 128, 128, None, True),
+    "three_rows_group4_window": (3, 8, 2, 128, 128, 96, True),
+    "two_rows_group16": (2, 16, 1, 128, 128, None, True),
+    # values twice as wide as a tile: a head is two lane tiles of a row
+    "values_256": (1, 2, 1, 128, 256, None, True),
+    # head sizes whose lanes do not fall on tile edges in [B, S, H * D]
+    # (LFM2's 64; latent attention's 192 / 128): turned at the edge, and
+    # the kernels take today's head-major specs.
+    "falls_back_d64": (1, 4, 2, 64, 64, None, True),
+    "falls_back_d192v128": (1, 2, 2, 192, 128, None, True),
+}
+
+
+@pytest.mark.parametrize("case", ROWS_CASES)
+def test_values_where_the_projections_leave_them(case):
+    """``rows``: forward and every gradient of a call whose v and result lie
+    as [B, S, heads, Dv] equal the head-major call's, which runs the same
+    kernel bodies, and ``reference_attention``'s; the geometry counter says
+    which kernels took them so, and says nothing where the shapes fell back
+    to the head-major specs."""
+    B, H, Hkv, D, Dv, window, causal = ROWS_CASES[case]
+    S = 128
+    ks = jax.random.split(jax.random.key(21), 4)
+    q = jax.random.normal(ks[0], (B, H, S, D))
+    k = jax.random.normal(ks[1], (B, Hkv, S, D))
+    v = jax.random.normal(ks[2], (B, Hkv, S, Dv))
+    do = jax.random.normal(ks[3], (B, H, S, Dv))
+
+    def fwd_bwd(fn, rows=False):
+        """(out, dq, dk, dv) of ``fn``, head-major whatever it takes."""
+        turn = (lambda x: jnp.swapaxes(x, 1, 2)) if rows else (lambda x: x)
+        out, vjp = jax.vjp(fn, q, k, turn(v))
+        dq, dk, dv = vjp(turn(do))
+        return turn(out), dq, dk, turn(dv)
+
+    flash = partial(flash_attention, causal=causal, window=window,
+                    block_q=64, block_k=64, interpret=True)
+    before = _geometry_counts()
+    got = fwd_bwd(partial(flash, rows=True), rows=True)
+    after = _geometry_counts()
+    head_major = fwd_bwd(flash)
+    want = fwd_bwd(partial(reference_attention, causal=causal,
+                           window=window))
+    for a, b, c, x, name in zip(got, head_major, want, (do, q, k, v),
+                                ("out", "dq", "dk", "dv")):
+        assert a.shape == x.shape, name
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6, err_msg=name)
+        np.testing.assert_allclose(a, c, atol=5e-4, rtol=1e-3, err_msg=name)
+
+    engaged = D % 128 == 0 and Dv % 128 == 0
+    for kernel in ("fwd", "dq", "dkv"):
+        name = attention_ops._kernel_name(f"flash_{kernel}", window, D, Dv)
+        new = {tags for tags, n in after[name].items()
+               if n > before.get(name, {}).get(tags, 0)}
+        assert len(new) == 1, (name, new)
+        assert dict(new.pop()).get("rows") == ("vo" if engaged else None)
+
+
+def test_dispatcher_turns_rows_for_the_reference():
+    """``attention`` off the TPU: the reference takes head-major arrays, so
+    a call that says ``rows`` has v turned at the edge and its result
+    back."""
+    q, k, v = _qkv(jax.random.key(22), H=4, Hkv=2, S=32)
+    out = attention(q, k, jnp.swapaxes(v, 1, 2), rows=True)
+    np.testing.assert_allclose(jnp.swapaxes(out, 1, 2),
+                               reference_attention(q, k, v), atol=1e-6)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_no_transpose_beside_the_kernels_in_a_llama_layer(monkeypatch):
+    """The gradient of ``llama.attention_branch`` at a head size of 128:
+    between the projections and the seven kernels (the rotary pair forward
+    and back for q and k, flash forward, dq, dk/dv) nothing q-sized is
+    transposed: the rotary kernels place q and k, and flash reads v and
+    ``do`` and writes ``out`` and ``dv`` as the projections hold them."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.rope import rope_lane_tables
+    from ray_tpu.parallel import mesh
+    monkeypatch.setattr(mesh, "_GLOBAL_MESH", None)  # rows on ONE device
+    cfg = llama.LlamaConfig(vocab_size=64, hidden=256, layers=1, heads=4,
+                            kv_heads=2, head_dim=128, mlp_dim=256,
+                            max_seq_len=128, dtype=jnp.float32,
+                            attention_impl="flash_interpret")
+    layer = jax.tree.map(lambda x: x[0], llama.init_params(
+        cfg, jax.random.key(0))["blocks"])
+    cos, sin = rope_lane_tables(cfg.head_dim, cfg.max_seq_len,
+                                cfg.rope_theta)
+    h = jax.random.normal(jax.random.key(1), (2, 128, cfg.hidden))
+
+    def loss(h, layer):
+        return jnp.sum(llama.attention_branch(cfg, cos, sin, None, h, layer))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, layer)
+    kernels, turned = [], []
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["name"] if "name" in eqn.params
+                           else eqn.params["name_and_src_info"].name)
+        elif eqn.primitive.name == "transpose":
+            shape = eqn.invars[0].aval.shape
+            if len(shape) == 4 and shape[-1] == cfg.head_dim:
+                turned.append(shape)
+    assert sorted(kernels) == sorted(
+        ["rope_to_heads"] * 2 + ["rope_from_heads"] * 2
+        + ["flash_fwd", "flash_dq", "flash_dkv"]), kernels
+    assert not turned, turned
+
+
+def test_llama_layer_is_the_same_in_both_arrangements(monkeypatch):
+    """``llama.attention_branch`` with v and the result as rows (one
+    device) and head-major (a mesh): the same result and gradients."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.rope import rope_lane_tables
+    from ray_tpu.parallel import mesh
+    monkeypatch.setattr(mesh, "_GLOBAL_MESH", None)
+    cfg = llama.LlamaConfig(vocab_size=64, hidden=256, layers=1, heads=4,
+                            kv_heads=2, head_dim=128, mlp_dim=256,
+                            max_seq_len=64, dtype=jnp.float32,
+                            attention_impl="flash_interpret")
+    assert llama._values_as_rows(cfg)
+    layer = jax.tree.map(lambda x: x[0], llama.init_params(
+        cfg, jax.random.key(0))["blocks"])
+    cos, sin = rope_lane_tables(cfg.head_dim, cfg.max_seq_len,
+                                cfg.rope_theta)
+    h = jax.random.normal(jax.random.key(1), (2, 64, cfg.hidden))
+
+    def grads():
+        return jax.value_and_grad(lambda h, layer: jnp.sum(jnp.sin(
+            llama.attention_branch(cfg, cos, sin, None, h, layer))),
+            argnums=(0, 1))(h, layer)
+
+    rows = grads()
+    monkeypatch.setattr(llama, "_values_as_rows", lambda cfg: False)
+    for a, b in zip(jax.tree.leaves(rows), jax.tree.leaves(grads())):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 
 class TestRingAttention:

@@ -340,6 +340,36 @@ class TestCatalog:
         telemetry.inc("ray_tpu_profiler_captures_total", 0.0)
 
 
+def test_flash_geometry_counter_says_which_kernels_took_rows():
+    """``ray_tpu_flash_step_geometry_total`` carries ``rows="vo"`` where a
+    kernel took v and the result in the projections' layout (PR 49), on
+    each of the three kernels, and no such tag on a head-major call's."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.util import metrics as metrics_mod
+
+    name = "ray_tpu_flash_step_geometry_total"
+    assert telemetry.CATALOG[name]["type"] == "counter"
+    assert tuple(telemetry.CATALOG[name]["tag_keys"]) == (
+        "kernel", "block_q", "block_k", "heads_a_step", "scores", "d_qk",
+        "d_v", "d", "rows")
+    metrics_mod._reset_for_tests()
+    q = jnp.ones((1, 2, 64, 128), jnp.float32)          # [B, H, S, D]
+    for rows in (True, False):
+        v = jnp.swapaxes(q, 1, 2) if rows else q
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, q, v, interpret=True, rows=rows)))(q)
+    lines = [l for l in metrics_mod.prometheus_text().splitlines()
+             if l.startswith(name + "{")]
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        mine = [l for l in lines if f'kernel="{kernel}"' in l]
+        assert len(mine) == 2, mine
+        assert sum('rows="vo"' in l for l in mine) == 1
+        assert sum("rows=" not in l for l in mine) == 1
+    metrics_mod._reset_for_tests()
+
+
 def _base_series(prom_text):
     """Distinct catalog-level metric names present in an exposition."""
     names = set()

@@ -556,6 +556,39 @@ def test_q_and_k_cross_hbm_once_in_the_dense_step(ouro_step):
                             "64,4096,128"), ("4,16,4096,64",))
 
 
+def _q_sized_copies(text, q_shape, dtype="bf16", under="block/attn"):
+    """The copies and transposes the program runs on their own (those the
+    compiler gives a cost) whose result is as large as ``q_shape`` and of
+    ``dtype``, under the scope ``under``: (op_name's tail, result)."""
+    import math
+    import re
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (" + dtype + r"\[([0-9,]+)\])\S* "
+                     r"(copy|transpose)\(", line)
+        if not m or '"estimated_cycles"' not in line:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if (op_name and under in op_name.group(1) and math.prod(
+                int(n) for n in m.group(2).split(",")) == math.prod(q_shape)):
+            found.append((op_name.group(1)[-60:], m.group(1)))
+    return found
+
+
+def test_nothing_q_sized_is_copied_round_flash_in_the_dense_step(ouro_step):
+    """Yi's and Ouro's layer, four rows a call (PR 49): v, the recomputed v
+    and ``do`` are no longer placed head-major
+    (``bse,ehd->bhsd/transpose``, ``bhsd,hde->bse/transpose``) and flash's
+    ``out`` and ``dv`` are no longer re-laid for the ``wo`` / ``wv`` weight
+    gradients (``block/attn/reshape``): the kernels read and write them
+    where the projections hold them.  Nor is a float32 product written out
+    for ``delta``: it is one pass over ``do`` and ``out``."""
+    text = ouro_step["text"]
+    assert not _q_sized_copies(text, (4, 16, 4096, 128))
+    assert not _q_sized_copies(text, (4, 16, 4096, 128), "f32")
+    assert "f32[2048,8,16,128]" not in text
+
+
 def test_q_and_k_cross_hbm_once_in_the_sparse_step(trinity_step):
     """Trinity's window layers, a row at a time: q [1, 32, 8192, 128], k
     [1, 4, 8192, 128] (the flash kernels' view [4, 8, 8192, 128]); its full
