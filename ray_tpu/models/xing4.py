@@ -9,7 +9,9 @@ What it has that no other model here has:
   one; a head's query and key are ``qk_nope_head_dim`` channels without
   position and ``qk_rope_head_dim`` rotary ones, the rotary key one head
   for all query heads; values are ``v_head_dim`` wide.  The flash kernels
-  take q and k at 192 and v at 128 (``ops/attention.py``).  Rotary
+  take q and k in those two parts, 128 + 64, the rotary key head once, and
+  a head's key and value where the one projection wrote them side by side
+  (``ops/attention.py``, a call in parts).  Rotary
   frequencies are yarn's (``ops/rope.Yarn``), and the softmax scale carries
   its ``mscale ** 2``.
 - **A stream of ``hc_mult`` lanes** [B, n, S, C]: every sublayer reads a
@@ -258,9 +260,32 @@ def init_state(cfg: Xing4Config) -> Dict[str, jax.Array]:
 
 # ------------------------------------------------------------------ layers
 
+def _flat(w, shape, dt):
+    """A head weight as the flat matrix its product takes, behind a
+    barrier: without one the TPU compiler folds the reshape of a weight's
+    gradient into the product that forms it ([H, D, E] for [H * D, E]) and
+    then wants the activation it contracts with the sequence minor, a
+    transposing copy of q's size before every one of them at a row a call
+    (PERF.md, PR 50)."""
+    return jax.lax.optimization_barrier(w.astype(dt).reshape(shape))
+
+
+def _project(h, w, dt):
+    """``_lm.project_heads``: h [B, S, E] x w [E, H, D] -> [B, S, H, D]."""
+    E, H, D = w.shape
+    flat = jnp.einsum("bse,ef->bsf", h, _flat(w, (E, H * D), dt),
+                      preferred_element_type=dt)
+    return flat.reshape(*h.shape[:2], H, D)
+
+
 @jax.named_scope("block/attn")
 def _mla(cfg: Xing4Config, cos, sin, h, layer):
-    """Latent attention of h [B, S, E] -> [B, S, E]."""
+    """Latent attention of h [B, S, E] -> [B, S, E].  q and k reach the
+    kernels in the parts the projections write (``ops.attention``, a call
+    in parts): ``wq_b`` is sliced, a weight, so that q's 128 lanes without
+    position and its 64 rotary ones are two products' results, and ``kv``
+    goes as the one product leaves it, a head's key and value side by side.
+    Nothing of q's size is concatenated, broadcast, turned or sliced."""
     dt, eps = cfg.dtype, cfg.norm_eps
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
@@ -268,24 +293,23 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer):
         c_q = rms_norm(jnp.einsum("bse,er->bsr", h, layer["wq_a"].astype(dt),
                                   preferred_element_type=dt),
                        layer["q_norm"], eps)
-        q = _lm.project_heads(c_q, layer["wq_b"], dt)       # [B, S, H, 192]
+        q_n = _project(c_q, layer["wq_b"][..., :dn], dt)    # [B, S, H, 128]
+        q_r = _project(c_q, layer["wq_b"][..., dn:], dt)    # [B, S, H, 64]
         kv_a = jnp.einsum("bse,er->bsr", h, layer["wkv_a"].astype(dt),
-                          preferred_element_type=dt)
+                          preferred_element_type=dt)        # [B, S, 512 + 64]
         c_kv = rms_norm(kv_a[..., :rkv], layer["kv_norm"], eps)
-        kv = _lm.project_heads(c_kv, layer["wkv_b"], dt)    # [B, S, H, 256]
+        kv = _project(c_kv, layer["wkv_b"], dt)             # [B, S, H, 256]
     with jax.named_scope("rope"):
         rope = lambda x: rotate_heads(x, cos, sin,
                                       interpret=impl == "flash_interpret")
+        q_r = rope(q_r)                                     # [B, H, S, 64]
         k_r = rope(kv_a[..., None, rkv:])                   # [B, 1, S, 64]
-        kv = jnp.swapaxes(kv, 1, 2)                         # [B, H, S, 256]
-        q = jnp.concatenate([jnp.swapaxes(q[..., :dn], 1, 2),
-                             rope(q[..., dn:])], axis=-1)
-        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-            k_r, kv.shape[:3] + k_r.shape[3:])], axis=-1)
-    o = _attention(q, k, kv[..., dn:], causal=True, impl=impl,
-                   scale=cfg.softmax_scale)                 # [B, H, S, 128]
+    o = _attention((q_n, q_r), (kv, k_r), None, causal=True, impl=impl,
+                   scale=cfg.softmax_scale)                 # [B, S, H, 128]
     with jax.named_scope("mla"):
-        return jnp.einsum("bhsd,hde->bse", o, layer["wo"].astype(dt),
+        H, D, E = layer["wo"].shape
+        return jnp.einsum("bsf,fe->bse", o.reshape(*o.shape[:2], H * D),
+                          _flat(layer["wo"], (H * D, E), dt),
                           preferred_element_type=dt)
 
 

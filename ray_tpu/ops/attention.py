@@ -51,6 +51,30 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   ``flash_attention``'s edge.  The geometry counter says which kernels a
   trace took so (PERF.md, PR 49: five q-sized copies a layer call fewer in
   Yi's and Ouro's steps).
+- **A call in parts** hands q and k each in the two parts latent
+  attention's projections write, and the kernels form the scores as
+  ``q_n k_n^T + q_r k_r^T`` in float32 before the scale, the mask and the
+  softmax: the 192-wide contraction as the MXU makes it anyway, 128 + 64,
+  on the same bf16 operands.  ``q = (q_n [B, Sq, H, Dn], q_r [B, H, Sq,
+  Dr])``: the lanes without position as rows, the rotary ones head-major as
+  ``rotate_heads`` writes them.  ``k = (kv [B, Sk, H, Dn + Dv], k_r [B, 1,
+  Sk, Dr])`` and ``v = None``: a head's key without position and its value
+  lie side by side in the one product's result, one rows block of ``Dn +
+  Dv`` lanes a step, and the ONE rotary key head is every query head's
+  (its block's index map ignores the head: nothing is laid out H times in
+  HBM).  The result and its cotangent are rows; dq leaves in q's two
+  parts, dk and dv side by side as ``kv`` came, and the rotary head's
+  gradient as a grid row's (a head's) share, summed over the heads outside.
+  So nothing is concatenated, broadcast, sliced or turned round the
+  kernels; ``_specs`` and a few ``if parts`` in each kernel body are all
+  there is to it, the geometry is ``_tiles``' for the whole width, and the
+  names and the roofline's operations are a 192 / 128 call's.  A one-part
+  call traces the kernels it traced before.  The parts apply where ``Dn``
+  and ``Dv`` are multiples of 128 on one device; any other call in parts
+  (the reference path, a mesh's island) is put together at the edge
+  (``_one_part``).  The geometry counter tags such kernels ``parts="128+64"``,
+  ``rows="qkvo"`` (PERF.md, PR 50: the kernels alone cost 3.7 % more so,
+  and Xing4.0's step is 58 ms of 1,177 shorter for what left it round them).
 - The grid is ("parallel", "arbitrary"): only the grid rows split across
   the cores of a two-core chip (v4, v5p): B * H of them without a group,
   B * Hkv with one, so a call with one or two key heads a device no longer
@@ -309,7 +333,9 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
 
 
 class Dims(NamedTuple):
-    """A call's sizes, whatever the layout of its operands."""
+    """A call's sizes, whatever the layout of its operands.  ``D`` is the
+    whole width of a score's contraction; a call in parts has ``Dr`` of it
+    in its rotary parts (0: q and k are one operand each)."""
     B: int
     H: int
     Hkv: int
@@ -317,20 +343,45 @@ class Dims(NamedTuple):
     Sk: int
     D: int
     Dv: int
+    Dr: int = 0
 
 
 def _dims(q, k, v):
-    """``Dims`` of a call: q and k are head-major whatever v is."""
+    """``Dims`` of a call: q and k are head-major whatever v is; in parts
+    (``flash_attention``) q's and k's first lie as rows, and k's holds v."""
+    if _in_parts(q):
+        (q_n, q_r), (kv, k_r) = q, k
+        (B, Sq, H, Dn), Sk, Dr = q_n.shape, kv.shape[1], q_r.shape[-1]
+        if (v is not None or q_r.shape != (B, H, Sq, Dr)
+                or kv.shape[:3] != (B, Sk, H) or kv.shape[3] <= Dn
+                or k_r.shape != (B, 1, Sk, Dr)):
+            raise ValueError(
+                f"a call in parts takes q ([B, Sq, H, Dn], [B, H, Sq, Dr]), "
+                f"k ([B, Sk, H, Dn + Dv], [B, 1, Sk, Dr]) and no v: got "
+                f"{[x.shape for x in (q_n, q_r, kv, k_r)]} and v "
+                f"{None if v is None else v.shape}")
+        return Dims(B, H, H, Sq, Sk, Dn + Dr, kv.shape[3] - Dn, Dr)
     (B, H, Sq, D), (Hkv, Sk) = q.shape, k.shape[1:3]
     if H % Hkv:
         raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
     return Dims(B, H, Hkv, Sq, Sk, D, v.shape[-1])
 
 
+def _in_parts(q):
+    return isinstance(q, tuple)
+
+
+def _operands(q, k, v):
+    """A call's q, k and v as the kernels take them, by ``Specs``' names."""
+    if _in_parts(q):
+        return {"q": q[0], "q_r": q[1], "kv": k[0], "k_r": k[1]}
+    return {"q": q, "k": k, "v": v}
+
+
 def _geometry(kind, dims, block_q, block_k, window, rows):
     """``_tiles``' answer for this call, an explicit block size winning,
     checked against the lengths and counted."""
-    _, H, Hkv, Sq, Sk, D, Dv = dims
+    _, H, Hkv, Sq, Sk, D, Dv, Dr = dims
     t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window)
     t = t._replace(block_q=min(block_q or t.block_q, Sq),
                    block_k=min(block_k or t.block_k, Sk))
@@ -343,7 +394,8 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
         "heads_a_step": str(t.heads), "scores": t.scores,
         **({"d_qk": str(D), "d_v": str(Dv)} if D != Dv
            else {} if D == LANES else {"d": str(D)}),
-        **({"rows": "vo"} if rows else {})})
+        **({"parts": f"{D - Dr}+{Dr}", "rows": "qkvo"} if Dr
+           else {"rows": "vo"} if rows else {})})
     return t
 
 
@@ -352,7 +404,9 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
 # result and its cotangent under ``rows``: [B, S, H * n], what a projection
 # writes and reads): a head is then a slice of the lanes, on a tile's edge
 # since n % 128 == 0 (``flash_attention`` sees to it).  The three below
-# are all a kernel body knows of the layout.
+# are all a kernel body knows of the layout.  A call in parts has every
+# 128-wide operand as rows, and a head's key without position and its value
+# side by side in one block of ``kv`` (``_k_and_v``).
 
 def _head(ref, h, heads, as_rows=False):
     """Head ``h`` of a step's block: [rows, n]."""
@@ -384,14 +438,39 @@ def _write_rows(ref, x, heads, as_rows=False):
         ref[0, :, h * n:(h + 1) * n] = x[h * rows:(h + 1) * rows]
 
 
+def _k_and_v(kv_ref, Dn):
+    """A call in parts: the views of a ``kv`` block (or of its gradient's)
+    that are the key without position and the value, [1, bk, Dn] and
+    [1, bk, Dv]: lane slices on a tile's edge."""
+    return kv_ref.at[:, :, :Dn], kv_ref.at[:, :, Dn:]
+
+
+def _scores(a, b, a_r=None, b_r=None):
+    """``a b^T`` in float32, [rows of a, rows of b]; a call in parts adds
+    its rotary parts' product ``a_r b_r^T`` to it there: the contraction
+    over 128 + 64 that the MXU makes of a 192-wide one."""
+    over_lanes = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(a, b, over_lanes,
+                            preferred_element_type=jnp.float32)
+    if a_r is not None:
+        s += jax.lax.dot_general(a_r, b_r, over_lanes,
+                                 preferred_element_type=jnp.float32)
+    return s
+
+
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                acc_scr, *, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False):
+def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
+                window=None, rows=False, parts=False):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
+    if parts:
+        (q_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref, m_scr, l_scr,
+         acc_scr) = refs
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     heads = m_scr.shape[0] // block_q
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
@@ -404,11 +483,10 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
-        q = _rows(q_ref)                               # [heads * bq, D]
+        q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
         k = k_ref[0]                                   # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [heads * bq, bk]
+        q_r, k_r = (_rows(qr_ref), kr_ref[0]) if parts else (None, None)
+        s = _scores(q, k, q_r, k_r) * scale            # [heads * bq, bk]
         if causal:
             s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
                                       block_k, q_offset, window)
@@ -458,7 +536,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     from jax.experimental.pallas import tpu as pltpu
 
     dims = _dims(q, k, v)
-    B, H, Hkv, Sq, Sk, D, Dv = dims
+    B, H, Hkv, Sq, Sk, D, Dv, _ = dims
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     t = _geometry("fwd", dims, block_q, block_k, window, rows)
@@ -467,30 +545,33 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     n, stacked = B * H // t.heads, t.heads * t.block_q
 
     sp = _specs(t, dims, rows)
+    operands = _operands(q, k, v)
+    dtype = operands["q"].dtype
 
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
-        block_k=t.block_k, q_offset=q_offset, window=window, rows=rows)
+        block_k=t.block_k, q_offset=q_offset, window=window, rows=rows,
+        **({"parts": True} if dims.Dr else {}))
 
     out_specs = [sp.o]
-    out_shape = [jax.ShapeDtypeStruct(sp.shapes["o"], q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct(sp.shapes["o"], dtype)]
     if need_lse:
         out_specs.append(sp.row)
         out_shape.append(
             jax.ShapeDtypeStruct((n, t.heads, 1, Sq), jnp.float32))
     else:
         # No LSE output at all: nothing of it is computed or written.
-        with_lse = kernel
+        with_lse, n_io = kernel, len(operands) + 1
 
-        def kernel(sched, q, k, v, o, *scratch):
-            return with_lse(sched, q, k, v, o, None, *scratch)
+        def kernel(sched, *refs):
+            return with_lse(sched, *refs[:n_io], None, *refs[n_io:])
 
     res = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n, sched.size),
-            in_specs=[sp.q, sp.k, sp.v],
+            in_specs=[getattr(sp, name) for name in operands],
             out_specs=out_specs,
             scratch_shapes=[
                 _vmem((stacked, LANES), jnp.float32),
@@ -501,8 +582,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         interpret=interpret,
         name=_kernel_name("flash_fwd", window, D, Dv),
         **_compiler_params(interpret, stacked, t.block_k),
-    )(sched, *(x.reshape(sp.shapes[name])
-               for name, x in (("q", q), ("k", k), ("v", v))))
+    )(sched, *(x.reshape(sp.shapes[name]) for name, x in operands.items()))
     out = res[0].reshape((B, Sq, H, Dv) if rows else (B, H, Sq, Dv))
     if not need_lse:
         return out, None
@@ -516,15 +596,22 @@ def _vmem(shape, dtype):
 
 class Specs(NamedTuple):
     """Block specs of a kernel's operands, and the shape each is handed
-    over in: a reshape of the caller's array, never a copy."""
+    over in: a reshape of the caller's array, never a copy.  A call has
+    k, v, dk and dv, or in parts q_r, kv, k_r, dkv and dk_r."""
     q: object       # blocks by q block of t.heads query heads (q, dq)
     o: object       # the same at the values' head size (o, do)
-    k: object       # blocks by k block of the grid row's key head
-    v: object       # the same at the values' head size
     row: object     # rows along the lanes of [n, heads, 1, S] (LSE, delta)
-    dk: object      # blocks by k block, a grid row's own
-    dv: object      # the same at the values' head size
     shapes: dict    # name -> the shape that operand is handed over in
+    k: object = None        # blocks by k block of the grid row's key head
+    v: object = None        # the same at the values' head size
+    dk: object = None       # blocks by k block, a grid row's own
+    dv: object = None       # the same at the values' head size
+    # A call in parts: q is the part without position, as rows like o.
+    q_r: object = None      # the rotary part of q (and of dq), head-major
+    kv: object = None       # a head's key without position and its value
+    k_r: object = None      # the one rotary key head, whatever the grid row
+    dkv: object = None      # kv's gradient, a grid row's own
+    dk_r: object = None     # a grid row's own share of k_r's
 
 
 def _specs(t, dims, rows=False):
@@ -535,9 +622,15 @@ def _specs(t, dims, rows=False):
     operands at the values' head size (v, o, dv) lie as [B, S, H * Dv] and
     a block is the ``block`` rows of the step's heads' lanes, ``heads *
     Dv`` of them at lane-block ``r`` modulo the grid rows of a batch
-    element."""
+    element.  In a call in parts (``dims.Dr``) q is the part without
+    position and lies as rows too; ``kv`` [B, Sk, H * (Dn + Dv)] is one
+    rows operand whose block holds a head's key and value side by side;
+    the rotary parts are head-major ``Dr`` wide, and the one rotary key
+    head [B, Sk, Dr] is every grid row's: its index map takes the batch
+    element of the row and no head, so the head is never laid out 32 times
+    in HBM."""
     from jax.experimental import pallas as pl
-    B, H, Hkv, Sq, Sk, D, Dv = dims
+    B, H, Hkv, Sq, Sk, D, Dv, Dr = dims
     group, n = H // Hkv, B * H // t.heads
     per_b = H // t.heads            # grid rows a batch element
 
@@ -569,9 +662,19 @@ def _specs(t, dims, rows=False):
                 head(r), _step_ki(sched[s]), 0)),
             (B * heads, Sk, d))
 
-    made = {"q": of_q(D, False), "o": of_q(Dv, rows),
-            "k": of_k(D, False), "v": of_k(Dv, rows),
-            "dk": of_k(D, False, own=True), "dv": of_k(Dv, rows, own=True)}
+    if Dr:
+        made = {"q": of_q(D - Dr, True), "o": of_q(Dv, True),
+                "q_r": of_q(Dr, False), "kv": of_k(D - Dr + Dv, True),
+                "dkv": of_k(D - Dr + Dv, True, own=True),
+                "dk_r": of_k(Dr, False, own=True),
+                "k_r": (pl.BlockSpec(
+                    (1, t.block_k, Dr), lambda r, s, sched: (
+                        r // per_b, _step_ki(sched[s]), 0)), (B, Sk, Dr))}
+    else:
+        made = {"q": of_q(D, False), "o": of_q(Dv, rows),
+                "k": of_k(D, False), "v": of_k(Dv, rows),
+                "dk": of_k(D, False, own=True),
+                "dv": of_k(Dv, rows, own=True)}
     return Specs(
         row=pl.BlockSpec((1, t.heads, 1, t.block_q), lambda r, s, sched: (
             r, 0, 0, _step_qi(sched[s]))),
@@ -598,14 +701,21 @@ def _compiler_params(interpret, rows, cols):
 
 # ---------------------------------------------------------------- backward
 
-def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-               dq_ref, dq_scr, lse_scr, di_scr, *, causal, scale, block_q,
-               block_k, q_offset, window=None, rows=False):
+def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
+               window=None, rows=False, parts=False):
     """``lse`` and ``di`` arrive as rows along the lanes (as dk/dv reads
     them); the resident q block's first step turns them into the
-    lane-broadcast columns [heads * bq, 128] the steps subtract."""
+    lane-broadcast columns [heads * bq, 128] the steps subtract.  In parts,
+    dq leaves in q's two."""
     from jax.experimental import pallas as pl
 
+    if parts:
+        (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
+         dq_ref, dqr_ref, dq_scr, dqr_scr, lse_scr, di_scr) = refs
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr,
+         lse_scr, di_scr) = refs
     heads = lse_ref.shape[1]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
@@ -613,6 +723,8 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     @pl.when(step & _FIRST_BIT != 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+        if parts:
+            dqr_scr[...] = jnp.zeros(dqr_scr.shape, jnp.float32)
         for h in range(heads):
             of_h = slice(h * block_q, (h + 1) * block_q)
             for row_ref, col_scr in ((lse_ref, lse_scr), (di_ref, di_scr)):
@@ -621,13 +733,12 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
-        q = _rows(q_ref)                               # [heads * bq, D]
+        q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
         do = _rows(do_ref, heads, rows)
         k = k_ref[0]
         v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        q_r, k_r = (_rows(qr_ref), kr_ref[0]) if parts else (None, None)
+        s = _scores(q, k, q_r, k_r) * scale
         if causal:
             s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
                                       block_k, q_offset, window)
@@ -638,21 +749,34 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         ds = p * (dp - _bcast_lanes(di_scr[...], s.shape[1])) * scale
         dq_scr[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32)
+        if parts:
+            dqr_scr[...] += jax.lax.dot(ds.astype(k.dtype), k_r,
+                                        preferred_element_type=jnp.float32)
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
-        _write_rows(dq_ref, dq_scr[...], heads)
+        _write_rows(dq_ref, dq_scr[...], heads, parts)
+        if parts:
+            _write_rows(dqr_ref, dqr_scr[...], heads)
 
 
-def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, scale, block_q, block_k, q_offset, window=None,
-                rows=False):
+def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
+                window=None, rows=False, parts=False):
     """The scores are formed transposed, ``k q^T`` [bk, bq], so that dv =
     p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
-    along the lanes.  The step's heads add into the one resident dk / dv."""
+    along the lanes.  The step's heads add into the one resident dk / dv.
+    In parts, dk and dv leave side by side as ``kv`` came, and the grid
+    row's share of the one rotary head's gradient beside them."""
     from jax.experimental import pallas as pl
 
+    if parts:
+        (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
+         dkv_ref, dkr_ref, dk_scr, dkr_scr, dv_scr) = refs
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
+        dk_ref, dv_ref = _k_and_v(dkv_ref, q_ref.shape[-1])
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
+         dk_scr, dv_scr) = refs
     heads = lse_ref.shape[1]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
@@ -661,6 +785,8 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        if parts:
+            dkr_scr[...] = jnp.zeros(dkr_scr.shape, jnp.float32)
 
     @pl.when(step & _RUN_BIT != 0)
     def _step():
@@ -672,12 +798,12 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                                      transposed=True)  # [bk, bq]
         dk = dk_scr[...]
         dv = dv_scr[...]
+        k_r, dk_r = (kr_ref[0], dkr_scr[...]) if parts else (None, None)
         for h in range(heads):
-            q = q_ref[0, h]                            # [bq, D]
+            q = _head(q_ref, h, heads, parts)          # [bq, D]
+            q_r = qr_ref[0, h] if parts else None
             do = _head(do_ref, h, heads, rows)
-            st = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [bk, bq]
+            st = _scores(k, q, k_r, q_r) * scale       # [bk, bq]
             if causal:
                 st = st + bias
             pt = jnp.exp(st - lse_ref[0, h])           # lse: [1, bq]
@@ -689,13 +815,20 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             dst = pt * (dpt - di_ref[0, h]) * scale
             dk += jax.lax.dot(dst.astype(q.dtype), q,
                               preferred_element_type=jnp.float32)
+            if parts:
+                dk_r += jax.lax.dot(dst.astype(q.dtype), q_r,
+                                    preferred_element_type=jnp.float32)
         dk_scr[...] = dk
         dv_scr[...] = dv
+        if parts:
+            dkr_scr[...] = dk_r
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        if parts:
+            dkr_ref[0] = dkr_scr[...].astype(dkr_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
@@ -704,7 +837,9 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     dims = _dims(q, k, v)
-    B, H, Hkv, Sq, Sk, D, Dv = dims
+    B, H, Hkv, Sq, Sk, D, Dv, Dr = dims
+    operands = _operands(q, k, v)
+    dtype = operands["q"].dtype
 
     # delta_i = rowsum(dO * O), [B, H, Sq]: one fused elementwise+reduce
     # pass in XLA.
@@ -737,27 +872,31 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         return pl.pallas_call(
             functools.partial(kernel, causal=causal, scale=scale,
                               block_q=t.block_q, block_k=t.block_k,
-                              q_offset=q_offset, window=window, rows=rows),
+                              q_offset=q_offset, window=window, rows=rows,
+                              **({"parts": True} if Dr else {})),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(n, sched.size),
-                in_specs=[sp.q, sp.k, sp.v, sp.o, sp.row, sp.row],
+                in_specs=[*(getattr(sp, name) for name in operands),
+                          sp.o, sp.row, sp.row],
                 out_specs=out_specs,
                 scratch_shapes=scratch),
             out_shape=out_shape,
             interpret=interpret,
             name=_kernel_name(f"flash_{kind}", window, D, Dv),
             **_compiler_params(interpret, *tile),
-        )(sched, q.reshape(sp.shapes["q"]), k.reshape(sp.shapes["k"]),
-          v.reshape(sp.shapes["v"]), dout.reshape(sp.shapes["o"]),
+        )(sched, *(x.reshape(sp.shapes[name])
+                   for name, x in operands.items()),
+          dout.reshape(sp.shapes["o"]),
           lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
 
     # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
     t = _geometry("dq", dims, block_q, block_k, window, rows)
     stacked = t.heads * t.block_q
     dq = call(
-        "dq", _dq_kernel, t, "q", {"q": q.dtype},
-        [_vmem((stacked, D), jnp.float32),
+        "dq", _dq_kernel, t, "q",
+        {"q": dtype, **({"q_r": dtype} if Dr else {})},
+        [*(_vmem((stacked, d), jnp.float32) for d in (D - Dr, Dr) if d),
          _vmem((stacked, LANES), jnp.float32),
          _vmem((stacked, LANES), jnp.float32)],
         (stacked, t.block_k))
@@ -767,18 +906,31 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     # group the results leave per key head in the inputs' dtype; else per
     # step's heads in float32, summed over the group below.
     t = _geometry("dkv", dims, block_q, block_k, window, rows)
-    parts = H // Hkv // t.heads
+    shares = H // Hkv // t.heads
+    if Dr:
+        # A grid row is a head (``_tiles``: a head a step over 128, and no
+        # group in parts).  Its share of the one rotary key head's gradient
+        # leaves in the inputs' dtype, as a 192-wide dk's lanes did, and
+        # the heads are summed here.
+        dkv, dk_r = call(
+            "dkv", _dkv_kernel, t, "k", dict.fromkeys(("dkv", "dk_r"), dtype),
+            [_vmem((t.block_k, d), jnp.float32) for d in (D - Dr, Dr, Dv)],
+            (t.block_q, t.block_k))
+        dk_r = dk_r.reshape(B, H, Sk, Dr).sum(
+            axis=1, keepdims=True, dtype=jnp.float32).astype(dtype)
+        return ((dq[0].reshape(q[0].shape), dq[1].reshape(q[1].shape)),
+                (dkv.reshape(k[0].shape), dk_r), None)
     dk, dv = call(
         "dkv", _dkv_kernel, t, "k",
-        {name: jnp.float32 if parts > 1 else k.dtype
+        {name: jnp.float32 if shares > 1 else k.dtype
          for name in ("dk", "dv")},
         [_vmem((t.block_k, d), jnp.float32) for d in (D, Dv)],
         (t.block_q, t.block_k))
-    if parts > 1:
-        # Over a key head's parts, which lie side by side.
-        dk = dk.reshape(B, Hkv, parts, Sk, D).sum(axis=2).astype(k.dtype)
-        dv = (dv.reshape(B, Sk, Hkv, parts, Dv).sum(axis=3) if rows
-              else dv.reshape(B, Hkv, parts, Sk, Dv).sum(axis=2)
+    if shares > 1:
+        # Over a key head's shares, which lie side by side.
+        dk = dk.reshape(B, Hkv, shares, Sk, D).sum(axis=2).astype(k.dtype)
+        dv = (dv.reshape(B, Sk, Hkv, shares, Dv).sum(axis=3) if rows
+              else dv.reshape(B, Hkv, shares, Sk, Dv).sum(axis=2)
               ).astype(v.dtype)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -812,12 +964,27 @@ def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, window,
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
+
 def _head_major(fn, q, k, v, rows):
     """``fn``, which takes and returns [B, H, S, D], for a call whose v and
     result lie as [B, S, H, D] under ``rows``."""
     if not rows:
         return fn(q, k, v)
     return jnp.swapaxes(fn(q, k, jnp.swapaxes(v, 1, 2)), 1, 2)
+
+
+def _one_part(q, k, v=None):
+    """A call in parts said in one, for the paths the kernels' parts do not
+    serve: (q [B, H, Sq, Dn + Dr], k alike, v [B, Sk, H, Dv]), q and k
+    head-major with the one rotary key head laid under every head."""
+    _dims(q, k, v)      # the shapes a call in parts takes, or a ValueError
+    (q_n, q_r), (kv, k_r) = q, k
+    Dn = q_n.shape[-1]
+    k_n = jnp.swapaxes(kv[..., :Dn], 1, 2)
+    return (jnp.concatenate([jnp.swapaxes(q_n, 1, 2), q_r], axis=-1),
+            jnp.concatenate([k_n, jnp.broadcast_to(
+                k_r, k_n.shape[:3] + k_r.shape[3:])], axis=-1),
+            kv[..., Dn:])
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -842,11 +1009,28 @@ def flash_attention(q, k, v, *, causal: bool = True,
     [B, S, heads, Dv], which lies tiled by (heads, Dv) and is copied on its
     way (PERF.md, PR 49).
 
+    **In parts** (latent attention): ``q = (q_n [B, Sq, H, Dn], q_r [B, H,
+    Sq, Dr])``, ``k = (kv [B, Sk, H, Dn + Dv], k_r [B, 1, Sk, Dr])`` and
+    ``v = None``, as the projections and the rotary kernel write them: a
+    head's key without position and its value side by side in ``kv``, the
+    one rotary key head every query head's.  The result is [B, Sq, H, Dv]
+    (``rows``, which a call in parts implies).  The gradients come back in
+    the same parts, k_r's summed over the heads.  The default scale is
+    ``(Dn + Dr) ** -0.5``.  The kernels take the parts as they are where
+    ``Dn`` and ``Dv`` are multiples of 128; any other such call is put
+    together here (``_one_part``) and goes the way of a 192-wide one.
+
     ``window``: with ``causal``, a key is visible iff ``0 <= t - s <
     window``.  ``block_q`` / ``block_k`` default to what ``_tiles`` picks
     for each kernel from the shapes."""
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if rows and (q.shape[-1] % LANES or v.shape[-1] % LANES):
+    if _in_parts(q):
+        rows = True
+        if q[0].shape[-1] % LANES or k[0].shape[-1] % LANES:
+            q, k, v = _one_part(q, k, v)
+    scale = scale if scale is not None else 1.0 / math.sqrt(
+        sum(x.shape[-1] for x in q) if _in_parts(q) else q.shape[-1])
+    if rows and not _in_parts(q) and (q.shape[-1] % LANES
+                                      or v.shape[-1] % LANES):
         return _head_major(
             functools.partial(flash_attention, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
@@ -867,7 +1051,9 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               window: Optional[int] = None, rows: bool = False):
     """Dispatching entry point: pallas flash on TPU, reference elsewhere.
     ``rows`` is ``flash_attention``'s: v and the result lie as
-    [B, S, H, D].
+    [B, S, H, D]; so are q and k in parts, which only the kernels on one
+    device take as they are (the reference and a mesh's island get the
+    call put together, ``_one_part``).
 
     ``mesh`` is the SPMD mesh q/k/v are laid out on inside a GSPMD
     program.  A Mosaic kernel cannot be partitioned automatically, so on
@@ -875,6 +1061,11 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     island: batch over (dp, fsdp), heads over tp, the sequence whole."""
     if impl is None:
         impl = "flash" if _on_tpu() else "reference"
+    island = mesh is not None and mesh.size > 1
+    if _in_parts(q):
+        rows = True
+        if impl == "reference" or island:
+            q, k, v = _one_part(q, k, v)
     if impl == "reference":
         return _head_major(
             functools.partial(reference_attention, causal=causal,
@@ -882,7 +1073,7 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
                            interpret=impl == "flash_interpret",
                            window=window)
-    if mesh is not None and mesh.size > 1:
+    if island:
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ,

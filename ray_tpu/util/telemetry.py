@@ -426,7 +426,7 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
-                     "scores", "d_qk", "d_v", "d", "rows"),
+                     "scores", "d_qk", "d_v", "d", "rows", "parts"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
                        "from the call's shapes (scores: qk or kq; d_qk "
@@ -435,7 +435,10 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "is not 128; rows=vo only where the kernel "
                        "addresses v and the result as [B, S, H * D], the "
                        "projections' layout; a kernel without the tag "
-                       "took them head-major)."},
+                       "took them head-major; parts=128+64 with "
+                       "rows=qkvo only on a call that hands q and k in "
+                       "the two parts its projections write, every "
+                       "128-wide operand as rows: latent attention's)."},
     "ray_tpu_gmm_tile_geometry_total": {
         "type": "counter",
         "tag_keys": ("kind", "tm", "tk", "tn", "rows_a_group"),

@@ -343,7 +343,9 @@ class TestCatalog:
 def test_flash_geometry_counter_says_which_kernels_took_rows():
     """``ray_tpu_flash_step_geometry_total`` carries ``rows="vo"`` where a
     kernel took v and the result in the projections' layout (PR 49), on
-    each of the three kernels, and no such tag on a head-major call's."""
+    each of the three kernels, and no such tag on a head-major call's; a
+    call in parts (PR 50) says ``parts`` and ``rows="qkvo"`` on its three,
+    and no other call says ``parts``."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
@@ -353,7 +355,7 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
     assert telemetry.CATALOG[name]["type"] == "counter"
     assert tuple(telemetry.CATALOG[name]["tag_keys"]) == (
         "kernel", "block_q", "block_k", "heads_a_step", "scores", "d_qk",
-        "d_v", "d", "rows")
+        "d_v", "d", "rows", "parts")
     metrics_mod._reset_for_tests()
     q = jnp.ones((1, 2, 64, 128), jnp.float32)          # [B, H, S, D]
     for rows in (True, False):
@@ -367,6 +369,18 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
         assert len(mine) == 2, mine
         assert sum('rows="vo"' in l for l in mine) == 1
         assert sum("rows=" not in l for l in mine) == 1
+    assert not any("parts=" in l for l in lines)
+    q_n, q_r = jnp.swapaxes(q, 1, 2), q[..., :64]       # rows, head-major
+    kv = jnp.ones((1, 64, 2, 256), jnp.float32)
+    jax.grad(lambda q_n: jnp.sum(flash_attention(
+        (q_n, q_r), (kv, q_r[:, :1]), None, interpret=True)))(q_n)
+    lines = [l for l in metrics_mod.prometheus_text().splitlines()
+             if l.startswith(name + "{") and "parts=" in l]
+    assert len(lines) == 3, lines
+    for kernel, line in zip(("fwd", "dq", "dkv"), sorted(
+            lines, key=lambda l: ("dkv" in l, "dq" in l))):
+        assert f'kernel="flash_{kernel}_d192v128"' in line, line
+        assert 'parts="128+64"' in line and 'rows="qkvo"' in line, line
     metrics_mod._reset_for_tests()
 
 

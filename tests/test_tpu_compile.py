@@ -717,9 +717,10 @@ def xing4_step(topo):
 
 def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
     """The step compiles for one described v5e chip with the Mosaic kernels
-    in it: the three flash kernels at head sizes 192 / 128 by name, with no
-    operand or result padded to 256, and the grouped products; its memory is
-    stated; the scopes the readers sum are in its text."""
+    in it: the three flash kernels at head sizes 192 / 128 by name, taking q
+    and k in parts with no operand or result 192 or 256 wide, and the
+    grouped products; its memory is stated; the scopes the readers sum are
+    in its text."""
     import re
 
     import jax
@@ -751,18 +752,53 @@ def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
     assert "bf16[1,4,8192,3584]{3,2,1,0" in text
     assert "bf16[1,4,8192,3584]{2,3,1,0" not in text
     assert not re.search(r"= bf16\[1,4,8192,3584\]\S* copy\(", text)
-    # What crosses HBM at a flash call is 192 and 128 wide.
+    # What crosses HBM at a flash call is the parts the projections wrote
+    # (PR 50): q's 128 lanes without position, the result and their
+    # gradients as rows of 32 heads (4,096 lanes), a head's key and value
+    # side by side in the one product's result (8,192), the rotary parts 64
+    # wide; nothing concatenated (192) and nothing padded (256).
     for call in calls:
         if "flash_" in call.partition(" = ")[0]:
             widths = {int(dims.split(",")[-1]) for dims in re.findall(
                 r"bf16\[([0-9,]+)\]", call)}
-            assert widths == {192, 128}, call[:300]
+            assert widths == {4096, 8192, 64}, call[:300]
     by = {"scopes": {scopes.scope_path(name): 1.0
                      for name in scopes.op_names(text).values()}}
     for scope in ("block/hc/maps", "block/hc/collect", "block/hc/deposit",
                   "block/attn/mla", "block/moe/experts", "mtp",
                   "mtp/block/hc", "loss"):
         assert scopes.seconds_under(by, scope) > 0, scope
+
+
+def test_nothing_q_sized_moves_round_latent_attention_s_kernels(xing4_step):
+    """Latent attention, a row a call (PR 50): the kernels take q and k in
+    the parts the projections write, so under ``block/attn`` the step runs
+    no copy or transpose as large as q (192 wide), ``kv`` (256), v / the
+    result (128) or the rotary part (64); the one rotary key head is never
+    laid under 32 heads; nothing as large as q is concatenated (the
+    parent's q and k were, ``block/attn/reshape`` writing
+    ``bf16[32,1,8192,192]`` and ``bf16[32,8192,192]`` six times each), and
+    no instruction there writes a 192-wide array at all."""
+    import math
+    import re
+    text = xing4_step["text"]
+    for width in (192, 256, 128, 64):
+        assert not _q_sized_copies(text, (1, 32, 8192, width)), width
+    own = [line for line in text.splitlines()
+           if '"estimated_cycles"' in line and "block/attn" in line]
+    assert len(own) > 100
+    for line in own:
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = (\w+)\[([0-9,]*)\]\S* ([\w\-]+)\(", line)
+        if not m:       # a tuple's: the kernels', checked by their widths
+            continue
+        dims = [int(n) for n in m.group(2).split(",") if n]
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert not (m.group(3) == "broadcast" and dims == [1, 32, 8192, 64]), \
+            line[:300]
+        assert dims[-1:] != [192], line[:300]
+        assert not (op_name.endswith("/concatenate")
+                    and math.prod(dims) >= 32 * 8192 * 128), line[:300]
 
 
 @pytest.fixture(scope="module")

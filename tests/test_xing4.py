@@ -177,9 +177,85 @@ def test_flash_with_a_value_head_size_of_its_own(D, Dv, Hkv):
         np.testing.assert_allclose(g, w, atol=1e-4)
 
 
+def _parts(B=1, H=2, S=256, Dn=128, Dr=64, Dv=128, seed=0):
+    """A call in parts as ``xing4._mla`` hands it: (q_n [B, S, H, Dn], q_r
+    [B, H, S, Dr]), (kv [B, S, H, Dn + Dv], the ONE k_r [B, 1, S, Dr]) and
+    the result's cotangent [B, S, H, Dv]."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return ((jax.random.normal(ks[0], (B, S, H, Dn)),
+             jax.random.normal(ks[1], (B, H, S, Dr))),
+            (jax.random.normal(ks[2], (B, S, H, Dn + Dv)),
+             jax.random.normal(ks[3], (B, 1, S, Dr))),
+            jax.random.normal(ks[4], (B, S, H, Dv)))
+
+
+def _concatenated(q, k):
+    """The 192-wide operands the parts stand for, head-major: q, k with the
+    one rotary key head under every head, and v."""
+    (q_n, q_r), (kv, k_r) = q, k
+    Dn, H = q_n.shape[-1], q_n.shape[2]
+    turn = lambda x: jnp.swapaxes(x, 1, 2)
+    return (jnp.concatenate([turn(q_n), q_r], axis=-1),
+            jnp.concatenate([turn(kv[..., :Dn]),
+                             jnp.repeat(k_r, H, axis=1)], axis=-1),
+            turn(kv[..., Dn:]))
+
+
+@pytest.mark.parametrize("B,H,S,Dv,blocks", [
+    (1, 2, 256, 128, 128), (2, 2, 256, 128, 128), (1, 4, 128, 256, None)],
+    ids=["192v128", "two_rows", "192v256_default_blocks"])
+def test_flash_in_parts_is_the_reference_on_the_concatenated_operands(
+        B, H, S, Dv, blocks):
+    """The score product in the parts the projections write, in interpret
+    mode against ``reference_attention`` on the concatenated operands: the
+    result, dq in both parts, dk without position and dv side by side as
+    ``kv`` came, and the ONE rotary key head's gradient summed over the
+    query heads; with a second batch element (the shared head's index map
+    takes the row's batch element and no head)."""
+    q, k, do = _parts(B, H, S, Dv=Dv)
+    scale = 0.11
+    flash = lambda q, k: flash_attention(
+        q, k, None, causal=True, scale=scale, block_q=blocks, block_k=blocks,
+        interpret=True)
+
+    def plain(q, k):
+        return jnp.swapaxes(reference_attention(
+            *_concatenated(q, k), causal=True, scale=scale), 1, 2)
+
+    out, vjp = jax.vjp(flash, q, k)
+    want, want_vjp = jax.vjp(plain, q, k)
+    assert out.shape == (B, S, H, Dv)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    (dq_n, dq_r), (dkv, dk_r) = vjp(do)
+    (wq_n, wq_r), (wkv, wk_r) = want_vjp(do)
+    assert dk_r.shape == (B, 1, S, 64) and dkv.shape == (B, S, H, 128 + Dv)
+    for got, w in ((dq_n, wq_n), (dq_r, wq_r), (dkv[..., :128],
+                   wkv[..., :128]), (dkv[..., 128:], wkv[..., 128:]),
+                   (dk_r, wk_r)):
+        assert got.shape == w.shape
+        np.testing.assert_allclose(got, w, atol=2e-4)
+
+
+def test_parts_off_the_lane_tiles_and_on_the_reference_are_put_together():
+    """A call in parts may always say what it holds: where a part is not
+    whole lane tiles the call is put together and goes the 192-wide way,
+    and ``attention``'s reference path takes the same call."""
+    from ray_tpu.ops.attention import attention
+    q, k, do = _parts(H=2, S=128, Dn=64, Dr=32, Dv=64)
+    want = jnp.swapaxes(reference_attention(*_concatenated(q, k)), 1, 2)
+    np.testing.assert_allclose(
+        flash_attention(q, k, None, interpret=True), want, atol=2e-5)
+    np.testing.assert_allclose(
+        attention(q, k, None, impl="reference"), want, atol=2e-6)
+    with pytest.raises(ValueError, match="a call in parts takes"):
+        flash_attention(q, k, do, interpret=True)
+
+
 def test_flash_names_and_counts_both_head_sizes(monkeypatch):
     """A 192 / 128 call says its sizes in its kernels' names and in the
-    geometry counter's tags; a 128 / 128 call says what it said before."""
+    geometry counter's tags, and in parts the parts and which operands lay
+    as rows too, under the same names; a 128 / 128 call says what it said
+    before."""
     from ray_tpu.util import telemetry
     seen = []
     monkeypatch.setattr(telemetry, "inc",
@@ -189,16 +265,27 @@ def test_flash_names_and_counts_both_head_sizes(monkeypatch):
         q, k, v, do = _qkv(D, 128, S=128)
         jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, interpret=True) * do), argnums=(0, 1, 2))).lower(q, k, v)
+    q, k, do = _parts(S=128)
+    jax.jit(jax.grad(lambda q, k: jnp.sum(flash_attention(
+        q, k, None, interpret=True) * do), argnums=(0, 1))).lower(q, k)
     tags = [t for name, t in seen
             if name == "ray_tpu_flash_step_geometry_total"]
-    wide, plain = tags[:3], tags[3:]
-    assert [t["kernel"] for t in wide] == [
-        f"flash_{k}_d192v128" for k in ("fwd", "dq", "dkv")]
+    wide, plain, parts = tags[:3], tags[3:6], tags[6:]
+    names = [f"flash_{k}_d192v128" for k in ("fwd", "dq", "dkv")]
+    assert [t["kernel"] for t in wide] == names
     assert all(t["d_qk"] == "192" and t["d_v"] == "128" for t in wide)
+    assert not any("parts" in t or "rows" in t for t in wide + plain)
     assert [sorted(t) for t in plain] == [
         ["block_k", "block_q", "heads_a_step", "kernel", "scores"]] * 3
     assert [t["kernel"] for t in plain] == ["flash_fwd", "flash_dq",
                                             "flash_dkv"]
+    assert [t["kernel"] for t in parts] == names
+    assert all(t["parts"] == "128+64" and t["rows"] == "qkvo"
+               and t["d_qk"] == "192" and t["d_v"] == "128"
+               and t["heads_a_step"] == "1" for t in parts)
+    # but for the two new tags a call in parts counts what a 192-wide does
+    assert [{k: v for k, v in t.items() if k not in ("parts", "rows")}
+            for t in parts] == wide
 
 
 # --------------------------------------------------- hyper-connections
