@@ -2,12 +2,22 @@
 stream of several lanes mixed by hyper-connections, sigmoid-routed dropless
 experts beside a shared one, and a module that predicts a second token.
 
+A configuration may switch three of its mechanisms off, and what is left is
+DeepSeek-V3's block, which ``models/deepseek_v3.py`` (kanana-2-30b-a3b) runs
+through this stack: ``hc_mult`` 1 (a plain pre-norm stream, no map),
+``mtp_layers`` 0 (no prediction module) and ``q_lora_rank`` None (queries
+straight from the hidden state).  ``yarn`` None leaves the rotary
+frequencies plain and the softmax scale without ``mscale``.  Latent
+attention, the expert layer and the layer-rows loop are always there, and
+are the one copy both models run.
+
 What it has that no other model here has:
 
 - **Latent attention** (DeepSeek-V3's): queries through a ``q_lora_rank``
-  bottleneck with an RMSNorm, keys and values through a ``kv_lora_rank``
-  one; a head's query and key are ``qk_nope_head_dim`` channels without
-  position and ``qk_rope_head_dim`` rotary ones, the rotary key one head
+  bottleneck with an RMSNorm (or, with no rank, one ``wq`` [E, H, 192]),
+  keys and values through a ``kv_lora_rank`` one; a head's query and key
+  are ``qk_nope_head_dim`` channels without position and
+  ``qk_rope_head_dim`` rotary ones, the rotary key one head
   for all query heads; values are ``v_head_dim`` wide.  The flash kernels
   take q and k in those two parts, 128 + 64, the rotary key head once, and
   a head's key and value where the one projection wrote them side by side
@@ -47,6 +57,7 @@ from ..ops import hyper
 from ..ops.attention import attention as _attention
 from ..ops.norms import rms_norm
 from ..ops.rope import Yarn, rope_lane_tables, rotate_heads
+from ..util import telemetry
 from .afmoe import _moe, _swiglu
 
 #: the two sublayers of a layer, as their weights' names say them
@@ -60,7 +71,7 @@ class Xing4Config:
     hidden: int = 3584
     layers: int = 40
     heads: int = 32
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768    # None: no bottleneck, one ``wq``
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -142,12 +153,16 @@ def xing4_tiny(**kw) -> Xing4Config:
 def _layer_axes(cfg: Xing4Config) -> Dict[str, Any]:
     axes = {
         "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
-        "q_norm": ("layers", None), "kv_norm": ("layers", None),
-        "wq_a": ("layers", "embed", None),
-        "wq_b": ("layers", None, "heads", "head_dim"),
+        "kv_norm": ("layers", None),
         "wkv_a": ("layers", "embed", None),
         "wkv_b": ("layers", None, "heads", "head_dim"),
         "wo": ("layers", "heads", "head_dim", "embed")}
+    if cfg.q_lora_rank is None:
+        axes["wq"] = ("layers", "embed", "heads", "head_dim")
+    else:
+        axes |= {"q_norm": ("layers", None),
+                 "wq_a": ("layers", "embed", None),
+                 "wq_b": ("layers", None, "heads", "head_dim")}
     if cfg.hc_mult > 1:
         for s in SUBLAYERS:
             axes |= {f"hc_{s}_phi": ("layers", None, None),
@@ -204,11 +219,15 @@ def param_shapes(cfg: Xing4Config) -> Dict[str, Any]:
     def layer(L):
         shapes = {
             "attn_norm": ((L, E), 0), "mlp_norm": ((L, E), 0),
-            "q_norm": ((L, rq), 0), "kv_norm": ((L, rkv), 0),
-            "wq_a": ((L, E, rq), E), "wq_b": ((L, rq, H, dn + dr), rq),
+            "kv_norm": ((L, rkv), 0),
             "wkv_a": ((L, E, rkv + dr), E),
             "wkv_b": ((L, rkv, H, dn + dv), rkv),
             "wo": ((L, H, dv, E), H * dv)}
+        if rq is None:
+            shapes["wq"] = ((L, E, H, dn + dr), E)
+        else:
+            shapes |= {"q_norm": ((L, rq), 0), "wq_a": ((L, E, rq), E),
+                       "wq_b": ((L, rq, H, dn + dr), rq)}
         if n > 1:
             for s in SUBLAYERS:
                 shapes |= {
@@ -278,27 +297,47 @@ def _project(h, w, dt):
     return flat.reshape(*h.shape[:2], H, D)
 
 
+def _count_geometry(cfg: Xing4Config, h) -> None:
+    """What a traced call of latent attention is, for ``counters.json``."""
+    telemetry.inc("ray_tpu_mla_call_geometry_total", tags={
+        "heads": str(cfg.heads), "dn": str(cfg.qk_nope_head_dim),
+        "dr": str(cfg.qk_rope_head_dim), "dv": str(cfg.v_head_dim),
+        "q_lora": str(cfg.q_lora_rank or "none"),
+        "rows": str(h.shape[0]), "seq": str(h.shape[1])})
+
+
 @jax.named_scope("block/attn")
 def _mla(cfg: Xing4Config, cos, sin, h, layer):
     """Latent attention of h [B, S, E] -> [B, S, E].  q and k reach the
     kernels in the parts the projections write (``ops.attention``, a call
-    in parts): ``wq_b`` is sliced, a weight, so that q's 128 lanes without
-    position and its 64 rotary ones are two products' results, and ``kv``
-    goes as the one product leaves it, a head's key and value side by side.
-    Nothing of q's size is concatenated, broadcast, turned or sliced."""
+    in parts): q's weight (``wq_b`` behind the bottleneck, ``wq`` without
+    one) is sliced, a weight, so that q's 128 lanes without position and
+    its 64 rotary ones are two products' results, and ``kv`` goes as the one
+    product leaves it, a head's key and value side by side.  Nothing of q's
+    size is concatenated, broadcast, turned or sliced.  The four products
+    have scopes of their own under ``mla`` (``q``, ``kv_a``, ``kv_b``,
+    ``out``), the rotary passes lie under ``rope``."""
     dt, eps = cfg.dtype, cfg.norm_eps
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
+    _count_geometry(cfg, h)
     with jax.named_scope("mla"):
-        c_q = rms_norm(jnp.einsum("bse,er->bsr", h, layer["wq_a"].astype(dt),
-                                  preferred_element_type=dt),
-                       layer["q_norm"], eps)
-        q_n = _project(c_q, layer["wq_b"][..., :dn], dt)    # [B, S, H, 128]
-        q_r = _project(c_q, layer["wq_b"][..., dn:], dt)    # [B, S, H, 64]
-        kv_a = jnp.einsum("bse,er->bsr", h, layer["wkv_a"].astype(dt),
-                          preferred_element_type=dt)        # [B, S, 512 + 64]
-        c_kv = rms_norm(kv_a[..., :rkv], layer["kv_norm"], eps)
-        kv = _project(c_kv, layer["wkv_b"], dt)             # [B, S, H, 256]
+        with jax.named_scope("q"):
+            if cfg.q_lora_rank is None:
+                c_q, wq = h, layer["wq"]
+            else:
+                c_q, wq = rms_norm(
+                    jnp.einsum("bse,er->bsr", h, layer["wq_a"].astype(dt),
+                               preferred_element_type=dt),
+                    layer["q_norm"], eps), layer["wq_b"]
+            q_n = _project(c_q, wq[..., :dn], dt)           # [B, S, H, 128]
+            q_r = _project(c_q, wq[..., dn:], dt)           # [B, S, H, 64]
+        with jax.named_scope("kv_a"):
+            kv_a = jnp.einsum("bse,er->bsr", h, layer["wkv_a"].astype(dt),
+                              preferred_element_type=dt)    # [B, S, 512 + 64]
+        with jax.named_scope("kv_b"):
+            c_kv = rms_norm(kv_a[..., :rkv], layer["kv_norm"], eps)
+            kv = _project(c_kv, layer["wkv_b"], dt)         # [B, S, H, 256]
     with jax.named_scope("rope"):
         rope = lambda x: rotate_heads(x, cos, sin,
                                       interpret=impl == "flash_interpret")
@@ -306,7 +345,7 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer):
         k_r = rope(kv_a[..., None, rkv:])                   # [B, 1, S, 64]
     o = _attention((q_n, q_r), (kv, k_r), None, causal=True, impl=impl,
                    scale=cfg.softmax_scale)                 # [B, S, H, 128]
-    with jax.named_scope("mla"):
+    with jax.named_scope("mla/out"):
         H, D, E = layer["wo"].shape
         return jnp.einsum("bsf,fe->bse", o.reshape(*o.shape[:2], H * D),
                           _flat(layer["wo"], (H * D, E), dt),

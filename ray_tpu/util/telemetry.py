@@ -449,6 +449,17 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "the rows' gradient) or tgmm (the weights' "
                        "gradient); rows_a_group the rows a group is "
                        "expected to hold."},
+    "ray_tpu_mla_call_geometry_total": {
+        "type": "counter",
+        "tag_keys": ("heads", "dn", "dr", "dv", "q_lora", "rows", "seq"),
+        "description": "Calls of latent attention traced "
+                       "(models/xing4._mla, which models/deepseek_v3.py "
+                       "runs too), by what the call is: its heads, a "
+                       "head's channels without position (dn), rotary "
+                       "(dr) and of values (dv), the query bottleneck's "
+                       "rank (q_lora: none where queries come straight "
+                       "from the hidden state), and the rows and tokens "
+                       "a row of the call."},
     "ray_tpu_moe_rows_path_total": {
         "type": "counter",
         "tag_keys": ("path", "op", "tokens", "slots", "lanes"),

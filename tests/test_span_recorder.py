@@ -449,6 +449,29 @@ class TestTrainPath:
                       "flash_dq_d16", "flash_dkv_d16"):   # heads of 16
             assert _has_scope(text, scope), scope
 
+    def test_latent_attention_s_products_have_scopes_of_their_own(self):
+        """``models/xing4._mla`` (PR 51), in both latent models' steps:
+        ``mla`` and ``rope`` stay the parents the shipped readers match,
+        and the four products lie under ``mla/q``, ``mla/kv_a``,
+        ``mla/kv_b`` and ``mla/out``."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import deepseek_v3_tiny, xing4_tiny
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+        from ray_tpu.parallel.spmd import make_lm_train_step
+        mesh = build_mesh(MeshSpec(dp=1), jax.devices()[:1])
+        batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+                 for k in ("tokens", "loss_mask")}
+        for cfg in (deepseek_v3_tiny(), xing4_tiny()):
+            init_fn, step_fn, _ = make_lm_train_step(cfg, mesh)
+            text = step_fn.lower(
+                *jax.eval_shape(init_fn, jax.random.key(0)), batch).as_text(
+                    debug_info=True)
+            for scope in ("block/attn", "mla", "rope", "mla/q", "mla/kv_a",
+                          "mla/kv_b", "mla/out", "block/moe"):
+                assert _has_scope(text, scope), (type(cfg).__name__, scope)
+
     def test_trainer_spans_reach_the_session_files(self, tmp_path):
         from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 

@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated")
+              "gated", "mla")
 
 
 class TestCatalog:
@@ -507,6 +507,15 @@ class TestSmokeAllSubsystems:
         from ray_tpu.ops.ssm import gated_short_conv
         c = jnp.ones((1, 8, 4), jnp.float32)
         gated_short_conv(c, c, c, jnp.ones((3, 4), jnp.float32))
+
+        # -- mla: a traced call of latent attention counts what it is
+        # (DeepSeek-V3's block, no query bottleneck; the reference path).
+        from ray_tpu.models import deepseek_v3, xing4
+        mla_cfg = deepseek_v3.deepseek_v3_tiny().stack
+        xing4._mla(mla_cfg, *rope_lane_tables(64, 8),
+                   jnp.ones((1, 8, 64), jnp.float32),
+                   jax.tree.map(lambda a: a[0], xing4.init_params(
+                       mla_cfg, jax.random.key(0))["dense"]))
 
         # -- eva: a traced EVA kernel counts its table's steps (two
         # windows of 32 in chunks of 8, interpreted here).
