@@ -75,6 +75,36 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   (``_one_part``).  The geometry counter tags such kernels ``parts="128+64"``,
   ``rows="qkvo"`` (PERF.md, PR 50: the kernels alone cost 3.7 % more so,
   and Xing4.0's step is 58 ms of 1,177 shorter for what left it round them).
+- **A grid step may hold several tiles** (``Tiles.tiles``; a head size
+  over 128, latent attention's 192 / 128: eight).  The pipeline fetches a
+  *major block* of the streamed operands, ``tiles`` blocks long (keys and
+  values in forward and dq; q, ``do``, LSE and delta in dk/dv), the table
+  lists the (resident block, major block) pairs that hold a visible
+  element, and the body walks the major block's 512 x 512 tiles in a loop,
+  doing for each what a grid step of one tile does: the same products, mask
+  and order of accumulation on the same scratch, so the results are the
+  one-tile walk's bit for bit.  The walk stops at the last tile the step's
+  q rows see (``_visible_tiles``), so nothing above the diagonal is
+  computed, as larger blocks would; the score tile stays 512 x 512, so the
+  kernels stay inside Mosaic's default 16 MiB of scoped VMEM (9.5 MiB at
+  the most, dk/dv's, by the compiler's count) and state no limit.  What it
+  buys is what a grid step costs beside its tiles (block DMAs issued and
+  waited for, the table's word, the index maps, scratch read and written
+  back): at [1, 32, 8192] and 128 + 64 / 128 the three kernels read
+  26.69 ms a call at 136 steps a head, 25.42 at 72 (2 tiles), 24.79 at 40
+  (4), 24.60 at 24 (8) and 24.12 at 16 (the whole side, where dk/dv holds
+  15.5 of the 16 MiB: not shipped); a loop and the tiles unrolled read the
+  same (PERF.md, PR 52).  The geometry counter tags such kernels
+  ``tiles_a_step``; with one tile a step every kernel traces what it
+  traced before.
+- **What the roofline's count can reach on a 128-deep MXU.**  A
+  contraction over 192 is two passes of the array, the second half empty,
+  and a 64-wide result (dq's and dk's rotary parts) a 128-wide pass half
+  used: in 128-units a 192 / 128 step makes 3 / 5 / 6 passes (forward / dq
+  / dk/dv) where the roofline counts 2.5 / 4 / 5 (``benchmark/
+  roofline_mla.flash_call``), so its reading's ceiling is 83 / 80 / 83 %,
+  not 100; at a head size of 64 every product fills half of a pass and the
+  ceiling is 50 (PERF.md section 3, PR 52 has the kernel-alone reading).
 - The grid is ("parallel", "arbitrary"): only the grid rows split across
   the cores of a two-core chip (v4, v5p): B * H of them without a group,
   B * Hkv with one, so a call with one or two key heads a device no longer
@@ -276,11 +306,23 @@ class Tiles(NamedTuple):
     """What one grid step of a kernel holds: ``heads`` query heads of one
     key head, ``block_q`` rows of each, against ``block_k`` keys; and which
     way round the scores are formed (``qk``: [q rows, keys]; ``kq``: [keys,
-    q rows])."""
+    q rows]).  ``tiles`` such pairs share a grid step: the pipeline fetches
+    a major block of ``tiles`` blocks of the streamed side (keys in forward
+    and dq, q rows in dk/dv) and the kernel walks them in order."""
     block_q: int
     block_k: int
     heads: int
     scores: str
+    tiles: int = 1
+
+    @property
+    def major(self):
+        """(q rows, keys) of a grid step's blocks: the streamed side's is a
+        major block, ``tiles`` tiles long (q's in dk/dv, k's in forward and
+        dq); the resident side's is the tile's."""
+        of_q, of_k = (self.tiles, 1) if self.scores == "kq" else (
+            1, self.tiles)
+        return self.block_q * of_q, self.block_k * of_k
 
 
 # The fallback, and what the chip's table (PERF.md, PR 33, step 0) says pays
@@ -289,6 +331,7 @@ _BLOCK = 512            # 512 x 512 pairs, a head a grid row
 _BIG_BLOCK = 1024       # no group to stack and no window: 1,024 x 1,024
 _MAX_HEADS = 8          # query heads a step stacks: 4,096 rows at 512 each
 _FWD_SCORES = 2 ** 20   # the forward's score tile, elements: halves block_k
+_WALK = (8, 4, 2, 1)    # head size over 128: 512 x 512 tiles a grid step
 
 
 def _tiles(kind, Sq, Sk, D, group, window=None):
@@ -310,6 +353,19 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
       a step.  At 192 / 128 alone the three kernels are 5 % faster at
       1,024 x 1,024 (25.97 ms for 27.41 at [1, 32, 8192]); the train step
       that held them did not return from its first call (PERF.md, PR 40).
+    - A head size over 128 and no window: a grid step walks the most of 8,
+      4, 2, 1 tiles of 512 x 512 that divide the streamed side's blocks
+      (k's in forward and dq, q's in dk/dv): 8 at 8,192 tokens, 24 grid
+      steps a head for the same 136 tiles.  From the chip's table (PERF.md,
+      PR 52, step 0; the call in parts at [1, 32, 8192], ms a call of the
+      three kernels / the most scoped VMEM one of them holds): 1 tile
+      26.69 / 4.3 MiB, 2 25.42 / 5.0, 4 24.79 / 6.5, 8 24.60 / 9.5, 16
+      24.12 / 15.5 of the default's 16.  In Kanana's step 2,747 ms at 1,
+      2,631 at 4, 2,617 at 8, 2,591 at 16.  Eight ships: the most that
+      leaves the default limit room.  The 128-wide shapes keep one tile a
+      step: whether Yi's 1,024 x 1,024 (a quarter of each diagonal block
+      computed for nothing) or the stacked groups want the walk is not
+      measured.
     - A head size under 128 (LFM2's 64) takes the answers of 128: at
       [4, 32 / 8, 8192, 64] the three kernels read 68.9 ms at 512 x 512 with
       the group's 4 heads stacked, 70.7 - 71.8 with either block at 1,024
@@ -319,7 +375,10 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
     scores = "kq" if kind == "dkv" else "qk"
     block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
     if D > LANES:
-        return Tiles(block_q, block_k, heads, scores)
+        streamed = Sq // block_q if kind == "dkv" else Sk // block_k
+        tiles = 1 if window is not None else next(
+            n for n in _WALK if streamed % n == 0)
+        return Tiles(block_q, block_k, heads, scores, tiles)
     heads = max(h for h in range(1, min(group, _MAX_HEADS) + 1)
                 if group % h == 0)
     if heads > 1:
@@ -379,12 +438,13 @@ def _operands(q, k, v):
 
 
 def _geometry(kind, dims, block_q, block_k, window, rows):
-    """``_tiles``' answer for this call, an explicit block size winning,
-    checked against the lengths and counted."""
+    """``_tiles``' answer for this call, an explicit block size winning (and
+    walked one tile a grid step), checked against the lengths and counted."""
     _, H, Hkv, Sq, Sk, D, Dv, Dr = dims
     t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window)
-    t = t._replace(block_q=min(block_q or t.block_q, Sq),
-                   block_k=min(block_k or t.block_k, Sk))
+    if block_q or block_k:      # the blocks a call names, one a grid step
+        t = t._replace(block_q=min(block_q or t.block_q, Sq),
+                       block_k=min(block_k or t.block_k, Sk), tiles=1)
     if Sq % t.block_q or Sk % t.block_k:
         raise ValueError(f"seq ({Sq},{Sk}) not divisible by blocks "
                          f"({t.block_q},{t.block_k})")
@@ -392,6 +452,7 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
         "kernel": _kernel_name(f"flash_{kind}", window, D, Dv),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
+        **({"tiles_a_step": str(t.tiles)} if t.tiles > 1 else {}),
         **({"d_qk": str(D), "d_v": str(Dv)} if D != Dv
            else {} if D == LANES else {"d": str(D)}),
         **({"parts": f"{D - Dr}+{Dr}", "rows": "qkvo"} if Dr
@@ -408,12 +469,13 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
 # 128-wide operand as rows, and a head's key without position and its value
 # side by side in one block of ``kv`` (``_k_and_v``).
 
-def _head(ref, h, heads, as_rows=False):
-    """Head ``h`` of a step's block: [rows, n]."""
+def _head(ref, h, heads, as_rows=False, j=None, block=None):
+    """Head ``h`` of a step's block: [rows, n]; of tile ``j`` of a streamed
+    major block (``_tile``), its ``block`` rows."""
     if not as_rows:
-        return ref[0, h]
+        return _tile(ref, j, block, h)
     n = ref.shape[-1] // heads
-    return ref[0, :, h * n:(h + 1) * n]
+    return _tile(ref, j, block, lanes=slice(h * n, (h + 1) * n))
 
 
 def _rows(ref, heads=None, as_rows=False):
@@ -458,10 +520,65 @@ def _scores(a, b, a_r=None, b_r=None):
     return s
 
 
+# A grid step of ``tiles`` tiles (``Tiles.tiles``): the streamed operands'
+# blocks are a major block, ``tiles`` blocks long, and the body does for each
+# tile of it that holds a visible element what a grid step of one tile does,
+# in the same order, on the same scratch.  With one tile a step the three
+# below hand the kernels what they read before, and trace nothing new.
+
+def _visible_tiles(qi, ki, block_q, block_k, q_offset, causal, scores,
+                   tiles):
+    """(first, stop) of the tiles of a step's major block that hold a
+    visible element.  ``qk`` (forward, dq: q block ``qi`` resident, major
+    k block ``ki``): the tiles whose first key is no later than the q
+    block's last row.  ``kq`` (dk/dv: k block ``ki`` resident, major q block
+    ``qi``): the tiles whose last row is no earlier than the k block's first
+    key.  On traced scalars in a kernel and on plain integers alike."""
+    if not causal:
+        return 0, tiles
+    if scores == "qk":
+        seen = ((qi + 1) * block_q - 1 + q_offset) // block_k + 1
+        return 0, jnp.minimum(seen - ki * tiles, tiles)
+    unseen = jnp.maximum(ki * block_k - q_offset, 0) // block_q
+    return jnp.maximum(unseen - qi * tiles, 0), tiles
+
+
+def _walk(tile, tiles, *step):
+    """``tile(j)`` for the visible tiles of a step's major block, in order
+    (``step``: ``_visible_tiles``' other arguments); a step of one tile is
+    ``tile(None)``.  A loop and the tiles unrolled, each under its guard,
+    read the same on the chip to 0.1 % (PERF.md, PR 52): the loop is the
+    shorter program."""
+    if tiles == 1:
+        return tile(None)
+    jax.lax.fori_loop(*_visible_tiles(*step, tiles),
+                      lambda j, _: tile(j), None)
+
+
+def _tile(ref, j, block, *head, lanes=None, along=0):
+    """Tile ``j`` of a streamed block, ``block`` rows of ``ref[0, *head]``
+    (``lanes``: that slice of their lanes; ``along=1``: ``block`` lanes of
+    its rows, LSE's and delta's); ``j`` None: the block is the tile."""
+    if j is None:
+        at = ()
+    else:
+        from jax.experimental import pallas as pl
+        at = (slice(None),) * along + (
+            pl.ds(pl.multiple_of(j * block, block), block),)
+    if lanes is not None:
+        at = (at or (slice(None),)) + (lanes,)
+    return ref[(0, *head, *at)]
+
+
+def _tile_index(i, j, tiles):
+    """The block index of tile ``j`` of major block ``i``."""
+    return i if j is None else i * tiles + j
+
+
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False, parts=False):
+                window=None, rows=False, parts=False, tiles=1):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
@@ -481,15 +598,16 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(step & _RUN_BIT != 0)
-    def _step():
+    def tile(j):
         q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
-        k = k_ref[0]                                   # [bk, D]
-        q_r, k_r = (_rows(qr_ref), kr_ref[0]) if parts else (None, None)
+        k = _tile(k_ref, j, block_k)                   # [bk, D]
+        q_r, k_r = ((_rows(qr_ref), _tile(kr_ref, j, block_k)) if parts
+                    else (None, None))
         s = _scores(q, k, q_r, k_r) * scale            # [heads * bq, bk]
         if causal:
-            s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
-                                      block_k, q_offset, window)
+            s = s + _causal_mask_bias(
+                s.shape[0], block_k, qi, _tile_index(ki, j, tiles), block_q,
+                block_k, q_offset, window)
         m_prev = m_scr[...]                            # [heads * bq, 128]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -497,11 +615,16 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         alpha = jnp.exp(m_prev - m_next)
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
         m_scr[...] = m_next
-        v = v_ref[0]
+        v = _tile(v_ref, j, block_k)
         pv = jax.lax.dot(p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * _bcast_lanes(alpha, acc_scr.shape[1]) \
             + pv
+
+    @pl.when(step & _RUN_BIT != 0)
+    def _step():
+        _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
+              "qk")
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
@@ -540,8 +663,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     t = _geometry("fwd", dims, block_q, block_k, window, rows)
-    sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset, causal,
-                             "q", window)
+    sched = _packed_schedule(Sq, Sk, *t.major, q_offset, causal, "q", window)
     n, stacked = B * H // t.heads, t.heads * t.block_q
 
     sp = _specs(t, dims, rows)
@@ -551,7 +673,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
         block_k=t.block_k, q_offset=q_offset, window=window, rows=rows,
-        **({"parts": True} if dims.Dr else {}))
+        **({"parts": True} if dims.Dr else {}),
+        **({"tiles": t.tiles} if t.tiles > 1 else {}))
 
     out_specs = [sp.o]
     out_shape = [jax.ShapeDtypeStruct(sp.shapes["o"], dtype)]
@@ -633,16 +756,19 @@ def _specs(t, dims, rows=False):
     B, H, Hkv, Sq, Sk, D, Dv, Dr = dims
     group, n = H // Hkv, B * H // t.heads
     per_b = H // t.heads            # grid rows a batch element
+    # The streamed side's blocks are major blocks (dk and dv are their grid
+    # row's resident block's).
+    block_q, block_k = t.major
 
     def of_q(d, as_rows):
         """(spec, shape) of a q-side operand of head size ``d``."""
         if as_rows:
             return (pl.BlockSpec(
-                (1, t.block_q, t.heads * d), lambda r, s, sched: (
+                (1, block_q, t.heads * d), lambda r, s, sched: (
                     r // per_b, _step_qi(sched[s]), r % per_b)),
                 (B, Sq, H * d))
         return (pl.BlockSpec(
-            (1, t.heads, t.block_q, d), lambda r, s, sched: (
+            (1, t.heads, block_q, d), lambda r, s, sched: (
                 r, 0, _step_qi(sched[s]), 0)),
             (n, t.heads, Sq, d))
 
@@ -654,11 +780,11 @@ def _specs(t, dims, rows=False):
         head = (lambda r: r) if own else (lambda r: r * t.heads // group)
         if as_rows:
             return (pl.BlockSpec(
-                (1, t.block_k, d), lambda r, s, sched: (
+                (1, block_k, d), lambda r, s, sched: (
                     head(r) // heads, _step_ki(sched[s]), head(r) % heads)),
                 (B, Sk, heads * d))
         return (pl.BlockSpec(
-            (1, t.block_k, d), lambda r, s, sched: (
+            (1, block_k, d), lambda r, s, sched: (
                 head(r), _step_ki(sched[s]), 0)),
             (B * heads, Sk, d))
 
@@ -668,7 +794,7 @@ def _specs(t, dims, rows=False):
                 "dkv": of_k(D - Dr + Dv, True, own=True),
                 "dk_r": of_k(Dr, False, own=True),
                 "k_r": (pl.BlockSpec(
-                    (1, t.block_k, Dr), lambda r, s, sched: (
+                    (1, block_k, Dr), lambda r, s, sched: (
                         r // per_b, _step_ki(sched[s]), 0)), (B, Sk, Dr))}
     else:
         made = {"q": of_q(D, False), "o": of_q(Dv, rows),
@@ -676,7 +802,7 @@ def _specs(t, dims, rows=False):
                 "dk": of_k(D, False, own=True),
                 "dv": of_k(Dv, rows, own=True)}
     return Specs(
-        row=pl.BlockSpec((1, t.heads, 1, t.block_q), lambda r, s, sched: (
+        row=pl.BlockSpec((1, t.heads, 1, block_q), lambda r, s, sched: (
             r, 0, 0, _step_qi(sched[s]))),
         shapes={name: shape for name, (_, shape) in made.items()},
         **{name: spec for name, (spec, _) in made.items()})
@@ -702,7 +828,7 @@ def _compiler_params(interpret, rows, cols):
 # ---------------------------------------------------------------- backward
 
 def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-               window=None, rows=False, parts=False):
+               window=None, rows=False, parts=False, tiles=1):
     """``lse`` and ``di`` arrive as rows along the lanes (as dk/dv reads
     them); the resident q block's first step turns them into the
     lane-broadcast columns [heads * bq, 128] the steps subtract.  In parts,
@@ -731,17 +857,18 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
                 col_scr[of_h, :] = jnp.broadcast_to(
                     row_ref[0, h], (LANES, block_q)).T
 
-    @pl.when(step & _RUN_BIT != 0)
-    def _step():
+    def tile(j):
         q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
         do = _rows(do_ref, heads, rows)
-        k = k_ref[0]
-        v = v_ref[0]
-        q_r, k_r = (_rows(qr_ref), kr_ref[0]) if parts else (None, None)
+        k = _tile(k_ref, j, block_k)
+        v = _tile(v_ref, j, block_k)
+        q_r, k_r = ((_rows(qr_ref), _tile(kr_ref, j, block_k)) if parts
+                    else (None, None))
         s = _scores(q, k, q_r, k_r) * scale
         if causal:
-            s = s + _causal_mask_bias(s.shape[0], block_k, qi, ki, block_q,
-                                      block_k, q_offset, window)
+            s = s + _causal_mask_bias(
+                s.shape[0], block_k, qi, _tile_index(ki, j, tiles), block_q,
+                block_k, q_offset, window)
         p = jnp.exp(s - _bcast_lanes(lse_scr[...], s.shape[1]))
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -753,6 +880,11 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
             dqr_scr[...] += jax.lax.dot(ds.astype(k.dtype), k_r,
                                         preferred_element_type=jnp.float32)
 
+    @pl.when(step & _RUN_BIT != 0)
+    def _step():
+        _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
+              "qk")
+
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
         _write_rows(dq_ref, dq_scr[...], heads, parts)
@@ -761,7 +893,7 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
 
 
 def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False, parts=False):
+                window=None, rows=False, parts=False, tiles=1):
     """The scores are formed transposed, ``k q^T`` [bk, bq], so that dv =
     p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
     along the lanes.  The step's heads add into the one resident dk / dv.
@@ -788,31 +920,31 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         if parts:
             dkr_scr[...] = jnp.zeros(dkr_scr.shape, jnp.float32)
 
-    @pl.when(step & _RUN_BIT != 0)
-    def _step():
+    def tile(j):
         k = k_ref[0]                                   # [bk, D]
         v = v_ref[0]
         if causal:
-            bias = _causal_mask_bias(block_q, block_k, qi, ki, block_q,
-                                     block_k, q_offset, window,
-                                     transposed=True)  # [bk, bq]
+            bias = _causal_mask_bias(
+                block_q, block_k, _tile_index(qi, j, tiles), ki, block_q,
+                block_k, q_offset, window, transposed=True)     # [bk, bq]
         dk = dk_scr[...]
         dv = dv_scr[...]
         k_r, dk_r = (kr_ref[0], dkr_scr[...]) if parts else (None, None)
         for h in range(heads):
-            q = _head(q_ref, h, heads, parts)          # [bq, D]
-            q_r = qr_ref[0, h] if parts else None
-            do = _head(do_ref, h, heads, rows)
+            q = _head(q_ref, h, heads, parts, j, block_q)      # [bq, D]
+            q_r = _tile(qr_ref, j, block_q, h) if parts else None
+            do = _head(do_ref, h, heads, rows, j, block_q)
             st = _scores(k, q, k_r, q_r) * scale       # [bk, bq]
             if causal:
                 st = st + bias
-            pt = jnp.exp(st - lse_ref[0, h])           # lse: [1, bq]
+            # lse, delta: [1, bq]
+            pt = jnp.exp(st - _tile(lse_ref, j, block_q, h, along=1))
             dv += jax.lax.dot(pt.astype(do.dtype), do,
                               preferred_element_type=jnp.float32)
             dpt = jax.lax.dot_general(
                 v, do, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dst = pt * (dpt - di_ref[0, h]) * scale
+            dst = pt * (dpt - _tile(di_ref, j, block_q, h, along=1)) * scale
             dk += jax.lax.dot(dst.astype(q.dtype), q,
                               preferred_element_type=jnp.float32)
             if parts:
@@ -822,6 +954,11 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         dv_scr[...] = dv
         if parts:
             dkr_scr[...] = dk_r
+
+    @pl.when(step & _RUN_BIT != 0)
+    def _step():
+        _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
+              "kq")
 
     @pl.when(step & _LAST_BIT != 0)
     def _finish():
@@ -862,8 +999,8 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         rows along the lanes, with no broadcast outside."""
         n = B * H // t.heads
         sp = _specs(t, dims, rows)
-        sched = _packed_schedule(Sq, Sk, t.block_q, t.block_k, q_offset,
-                                 causal, major, window)
+        sched = _packed_schedule(Sq, Sk, *t.major, q_offset, causal, major,
+                                 window)
         out_specs = [getattr(sp, name) for name in outs]
         out_shape = [jax.ShapeDtypeStruct(sp.shapes[name], dtype)
                      for name, dtype in outs.items()]
@@ -873,7 +1010,8 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
             functools.partial(kernel, causal=causal, scale=scale,
                               block_q=t.block_q, block_k=t.block_k,
                               q_offset=q_offset, window=window, rows=rows,
-                              **({"parts": True} if Dr else {})),
+                              **({"parts": True} if Dr else {}),
+                              **({"tiles": t.tiles} if t.tiles > 1 else {})),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(n, sched.size),

@@ -426,10 +426,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
-                     "scores", "d_qk", "d_v", "d", "rows", "parts"),
+                     "scores", "d_qk", "d_v", "d", "rows", "parts",
+                     "tiles_a_step"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
-                       "from the call's shapes (scores: qk or kq; d_qk "
+                       "from the call's shapes (scores: qk or kq; "
+                       "tiles_a_step only where a grid step fetches a "
+                       "major block of that many block_q x block_k tiles "
+                       "of the streamed side and walks them, which a head "
+                       "size over 128 does: latent attention's; d_qk "
                        "and d_v only where a call's values are not as "
                        "wide as its keys; d only where the one head size "
                        "is not 128; rows=vo only where the kernel "

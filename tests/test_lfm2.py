@@ -465,8 +465,9 @@ def test_tiles_at_128_and_over_keep_their_answers(kind):
     assert t.block_k == (256 if kind == "fwd" else 512)
     assert att._tiles(kind, 8192, 8192, 128, 8) == t
     assert att._tiles(kind, 4096, 4096, 128, 1).block_q == 1024
+    # over 128: 512 x 512 and a head a step, eight tiles a grid step (PR 52)
     assert att._tiles(kind, 8192, 8192, 192, 1) == att.Tiles(
-        512, 512, 1, "kq" if kind == "dkv" else "qk")
+        512, 512, 1, "kq" if kind == "dkv" else "qk", 8)
     # LFM2's call: four query heads stacked a key head
     assert att._tiles(kind, 8192, 8192, 64, 4).heads == 4
 

@@ -344,7 +344,21 @@ SCHEDULES = {
     # Sk > Sq + q_offset: no q row reaches the second k block.
     "k_block_unseen": ((64, 128, 32, 64, 0, True), None),
     "offset_off_the_blocks": ((128, 256, 32, 64, 48, True), None),
+    # Several tiles a grid step (a 7th entry; PR 52): the streamed side's
+    # blocks are major blocks of that many tiles.  A head of latent
+    # attention's at 8,192 tokens: 24 steps for the 136 tiles of 512 x 512
+    # (40 at four tiles a step).
+    "walk_8192_512_8": ((8192, 8192, 512, 512, 0, True, 8), None),
+    "walk_8192_512_4": ((8192, 8192, 512, 512, 0, True, 4), None),
+    "walk_1024_512_2": ((1024, 1024, 512, 512, 0, True, 2), None),
+    "walk_noncausal": ((256, 512, 64, 64, 0, False, 4), None),
+    # The offset cuts a major block, and a k block is beyond every q row.
+    "walk_offset_cuts_a_major_block": ((256, 512, 64, 64, 96, True, 4),
+                                       None),
 }
+# (steps, tiles walked) of the cases whose counts are written out.
+WALKS = {"walk_8192_512_8": (24, 136), "walk_8192_512_4": (40, 136),
+         "walk_1024_512_2": (2, 3)}
 
 
 @pytest.mark.parametrize("major", ["q", "k"])
@@ -353,7 +367,14 @@ def test_block_schedule(case, major):
     """The schedule alone, no kernel: every visible element lies in
     exactly one step, no step is wholly masked, an interior step has no
     masked element, and FIRST / LAST bracket each resident block."""
-    (Sq, Sk, bq, bk, off, causal), counts = SCHEDULES[case]
+    (Sq, Sk, bq, bk, off, causal, *tiles), counts = SCHEDULES[case]
+    if tiles:
+        # The table of a walk lists (resident block, major block) pairs;
+        # the checks below hold of those as of any pair of blocks.
+        t = attention_ops.Tiles(bq, bk, 1, "qk" if major == "q" else "kq",
+                                tiles[0])
+        _check_the_walk(case, major, Sq, Sk, off, causal, t)
+        bq, bk = t.major
     sched = block_schedule(Sq, Sk, bq, bk, off, causal, major)
     assert sched.dtype == np.int32 and sched.shape[0] == 5
     visible = np.ones((Sq, Sk), bool)
@@ -396,7 +417,8 @@ def test_block_schedule(case, major):
     if counts is not None:
         assert {kind: int((sched[KIND] == kind).sum())
                 for kind in counts} == counts
-    unseen = {"k_block_unseen": 1, "offset_off_the_blocks": 1}.get(case, 0)
+    unseen = {"k_block_unseen": 1, "offset_off_the_blocks": 1,
+              "walk_offset_cuts_a_major_block": 2}.get(case, 0)
     assert (sched[KIND] == EMPTY).sum() == (unseen if major == "k" else 0)
 
     # What the kernels read: one int32 a step, nothing lost in the packing.
@@ -408,6 +430,28 @@ def test_block_schedule(case, major):
     for bit, row in ((A._RUN_BIT, sched[KIND] != EMPTY),
                      (A._FIRST_BIT, sched[FIRST]), (A._LAST_BIT, sched[LAST])):
         assert ((packed & bit != 0) == row.astype(bool)).all()
+
+
+def _check_the_walk(case, major, Sq, Sk, off, causal, t):
+    """The tiles the kernels walk inside the steps of a table of major
+    blocks (``_visible_tiles``, as a kernel asks it of a step) are the
+    steps of the table of one tile a step, in its order."""
+    bq, bk, tiles = t.block_q, t.block_k, t.tiles
+    sched = block_schedule(Sq, Sk, *t.major, off, causal, major)
+    walked = []
+    for qi, ki, kind, _, _ in sched.T:
+        if kind == EMPTY:
+            continue
+        first, stop = (int(x) for x in attention_ops._visible_tiles(
+            qi, ki, bq, bk, off, causal, t.scores, tiles))
+        assert 0 <= first < stop <= tiles, (qi, ki)
+        walked += [(qi, ki * tiles + j) if major == "q"
+                   else (qi * tiles + j, ki) for j in range(first, stop)]
+    one = block_schedule(Sq, Sk, bq, bk, off, causal, major)
+    assert walked == [(qi, ki) for qi, ki, kind, _, _ in one.T
+                      if kind != EMPTY]
+    if case in WALKS:
+        assert (sched.shape[1], len(walked)) == WALKS[case]
 
 
 def test_packed_schedule_holds_the_longest_side():
@@ -445,7 +489,9 @@ def test_k_block_no_q_sees_gets_zero_gradient():
 
 
 # (Sq, Sk, D, group, window) -> (block_q, block_k, heads a step) of forward,
-# dq and dk/dv.  From the chip's table of step 0 (PERF.md, PR 33).
+# dq and dk/dv, and with a fourth entry the tiles a grid step walks (1
+# where none is given).  From the chip's tables of step 0 (PERF.md, PR 33;
+# PR 52 for the tiles a step of a head size over 128).
 TILES = {
     # yi-coder-1.5b.train-sft4k: no group to stack, so larger pairs.
     "yi_4096": ((4096, 4096, 128, 1, None),
@@ -470,7 +516,20 @@ TILES = {
     "smoke_2048": ((2048, 2048, 128, 1, None), [(512, 512, 1)] * 3),
     # A window and no group: large pairs waste at both edges of the band.
     "window_no_group": ((8192, 8192, 128, 1, 2048), [(512, 512, 1)] * 3),
-    "head_dim_256": ((4096, 4096, 256, 4, None), [(512, 512, 1)] * 3),
+    "head_dim_256": ((4096, 4096, 256, 4, None), [(512, 512, 1, 8)] * 3),
+    # Latent attention's 192 / 128 (kanana-2-30b-a3b.train-mla8k,
+    # xing4.0-29b-a4b.train-mhc8k): eight tiles of 512 x 512 a grid step
+    # where they divide the streamed side, else four, two, the one.
+    "latent_8192": ((8192, 8192, 192, 1, None), [(512, 512, 1, 8)] * 3),
+    "latent_2048": ((2048, 2048, 192, 1, None), [(512, 512, 1, 4)] * 3),
+    "latent_1024": ((1024, 1024, 192, 1, None), [(512, 512, 1, 2)] * 3),
+    "latent_512": ((512, 512, 192, 1, None), [(512, 512, 1, 1)] * 3),
+    "latent_1536": ((1536, 1536, 192, 1, None), [(512, 512, 1, 1)] * 3),
+    # The streamed side decides: k's in forward and dq, q's in dk/dv.
+    "latent_ring_shard": ((1024, 4096, 192, 1, None),
+                          [(512, 512, 1, 8), (512, 512, 1, 8),
+                           (512, 512, 1, 2)]),
+    "latent_window": ((8192, 8192, 192, 1, 2048), [(512, 512, 1)] * 3),
     # A group wider than a step: the most heads that divide it, up to 8.
     "group16": ((4096, 4096, 128, 16, None),
                 [(512, 256, 8), (512, 512, 8), (512, 512, 8)]),
@@ -481,9 +540,11 @@ TILES = {
 @pytest.mark.parametrize("case", TILES)
 def test_tiles(case):
     args, want = TILES[case]
-    for kind, (block_q, block_k, heads) in zip(("fwd", "dq", "dkv"), want):
+    for kind, (block_q, block_k, heads, *tiles) in zip(
+            ("fwd", "dq", "dkv"), want):
         assert attention_ops._tiles(kind, *args) == (
-            block_q, block_k, heads, "kq" if kind == "dkv" else "qk"), kind
+            block_q, block_k, heads, "kq" if kind == "dkv" else "qk",
+            *(tiles or [1])), kind
 
 
 @pytest.mark.parametrize("causal,q_offset", [(False, 0), (True, 64)])
@@ -648,6 +709,90 @@ def test_values_where_the_projections_leave_them(case):
                if n > before.get(name, {}).get(tags, 0)}
         assert len(new) == 1, (name, new)
         assert dict(new.pop()).get("rows") == ("vo" if engaged else None)
+
+
+# Several tiles a grid step (PR 52): in parts or not, causal, Sq, Sk, q_offset.
+WALK_CASES = {
+    "parts_causal": (True, True, 1024, 1024, 0),
+    "parts_not_causal": (True, False, 512, 1024, 0),
+    # the offset cuts a major block: its q rows see 3 of its 4 tiles
+    "parts_offset_cuts_a_major_block": (True, True, 512, 1024, 384),
+    "one_part_causal": (False, True, 1024, 1024, 0),
+    "one_part_not_causal": (False, False, 512, 1024, 0),
+    "one_part_offset_cuts_a_major_block": (False, True, 512, 1024, 384),
+}
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_tiles_a_step_are_the_same_work(case, monkeypatch):
+    """A 192 / 128 call whose grid steps walk 2 and up to 8 tiles of a major
+    block (the last is what ``_tiles`` picks: 8 of 8 a side, or 4 of 4) gives,
+    bit for bit, what the same call gives at one tile a step: the result, dq,
+    dk, dv (in parts: dq in both, dk and dv side by side, the one rotary
+    head's share), and the reference's within the tolerances the call in
+    parts is held to.  The geometry counter says ``tiles_a_step`` where it
+    is not 1."""
+    in_parts, causal, Sq, Sk, q_offset = WALK_CASES[case]
+    H, Dn, Dr, Dv, block = 2, 128, 64, 128, 128
+    ks = jax.random.split(jax.random.key(52), 5)
+    q_n, q_r, kv, k_r, do = (
+        jax.random.normal(key, shape) for key, shape in zip(ks, (
+            (1, Sq, H, Dn), (1, H, Sq, Dr), (1, Sk, H, Dn + Dv),
+            (1, 1, Sk, Dr), (1, Sq, H, Dv))))
+    turn = lambda x: jnp.swapaxes(x, 1, 2)
+    q = jnp.concatenate([turn(q_n), q_r], axis=-1)
+    k = jnp.concatenate([turn(kv[..., :Dn]), jnp.repeat(k_r, H, axis=1)],
+                        axis=-1)
+    v = turn(kv[..., Dn:])
+    kw = dict(causal=causal, q_offset=q_offset, scale=0.11)
+    # ``_tiles``' own answer at tiles of 128 x 128, which the interpreter
+    # walks in seconds: a block a call names is one a grid step.
+    monkeypatch.setattr(attention_ops, "_BLOCK", block)
+    flash = partial(flash_attention, interpret=True, **kw)
+
+    def fwd_bwd(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return jax.tree.leaves((out, vjp(do if out.shape == do.shape
+                                         else turn(do))))
+
+    def call():
+        if in_parts:
+            return fwd_bwd(lambda q_n, q_r, kv, k_r: flash(
+                (q_n, q_r), (kv, k_r), None), q_n, q_r, kv, k_r)
+        return fwd_bwd(flash, q, k, v)
+
+    got, walks = {}, ((1,), (2, 1), attention_ops._WALK)
+    assert walks[-1] == (8, 4, 2, 1)
+    for walk in walks:
+        monkeypatch.setattr(attention_ops, "_WALK", walk)
+        before = _geometry_counts()
+        got[walk] = call()
+        after = _geometry_counts()
+        for kernel in ("fwd", "dq", "dkv"):
+            name = f"flash_{kernel}_d192v128"
+            new = {tags for tags, n in after[name].items()
+                   if n > before.get(name, {}).get(tags, 0)}
+            assert len(new) == 1, (name, new)
+            tiles = min(walk[0], (Sq if kernel == "dkv" else Sk) // block)
+            assert dict(new.pop()).get("tiles_a_step") == (
+                None if tiles == 1 else str(tiles)), name
+    for walk in walks[1:]:
+        for a, b in zip(got[walks[0]], got[walk]):
+            assert a.shape == b.shape
+            assert (np.asarray(a) == np.asarray(b)).all(), walk
+
+    want = fwd_bwd(partial(reference_attention, **kw), q, k, v)
+    if in_parts:
+        out, dq_n, dq_r, dkv, dk_r = got[walks[-1]]
+        got_one = (turn(out), jnp.concatenate([turn(dq_n), dq_r], axis=-1),
+                   turn(dkv[..., :Dn]), turn(dkv[..., Dn:]))
+        np.testing.assert_allclose(
+            dk_r, want[2][..., Dn:].sum(axis=1, keepdims=True), atol=2e-4)
+        want = (want[0], want[1], want[2][..., :Dn], want[3])
+    else:
+        got_one = got[walks[-1]]
+    for a, b in zip(got_one, want):
+        np.testing.assert_allclose(a, b, atol=2e-4)
 
 
 def test_dispatcher_turns_rows_for_the_reference():

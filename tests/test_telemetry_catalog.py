@@ -355,7 +355,7 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
     assert telemetry.CATALOG[name]["type"] == "counter"
     assert tuple(telemetry.CATALOG[name]["tag_keys"]) == (
         "kernel", "block_q", "block_k", "heads_a_step", "scores", "d_qk",
-        "d_v", "d", "rows", "parts")
+        "d_v", "d", "rows", "parts", "tiles_a_step")
     metrics_mod._reset_for_tests()
     q = jnp.ones((1, 2, 64, 128), jnp.float32)          # [B, H, S, D]
     for rows in (True, False):

@@ -556,6 +556,37 @@ def test_q_and_k_cross_hbm_once_in_the_dense_step(ouro_step):
                             "64,4096,128"), ("4,16,4096,64",))
 
 
+def _assert_the_flash_kernels_walk_tiles(text, seq=8192, head=192):
+    """Latent attention's three kernels hold several 512 x 512 tiles a grid
+    step (PR 52): each call's table of steps, its scalar-prefetch operand,
+    is the shorter one (24 a head at 8,192 tokens and 8 tiles a step, where
+    a tile a step lists 136), and no kernel of the step, theirs or any
+    other, states a scoped VMEM limit over Mosaic's default 16 MiB: a
+    kernel that did hung Xing4.0's step in its first call (ROADMAP S11
+    (5))."""
+    import importlib
+    import re
+    A = importlib.import_module("ray_tpu.ops.attention")
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [c for c in calls if "flash_" in c.partition(" = ")[0]]
+    assert len(flash) >= 3
+    for call in flash:
+        kind = re.match(r"%\S*flash_(fwd|dq|dkv)_", call).group(1)
+        t = A._tiles(kind, seq, seq, head, 1)
+        steps = A.block_schedule(
+            seq, seq, *t.major, major="k" if kind == "dkv" else "q").shape[1]
+        one = A.block_schedule(seq, seq, t.block_q, t.block_k).shape[1]
+        assert t.tiles > 1 and steps < one / 3, (kind, t, steps, one)
+        assert f"s32[{steps}]" in call.partition("custom-call(")[2][:400], \
+            (kind, steps, call[:400])
+    for call in calls:
+        for limit in re.findall(
+                r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                r'"offset":"0","size":"(\d+)"', call):
+            assert int(limit) <= 16 * 2 ** 20, call[:300]
+
+
 def _q_sized_copies(text, q_shape, dtype="bf16", under="block/attn"):
     """The copies and transposes the program runs on their own (those the
     compiler gives a cost) whose result is as large as ``q_shape`` and of
@@ -762,6 +793,7 @@ def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
             widths = {int(dims.split(",")[-1]) for dims in re.findall(
                 r"bf16\[([0-9,]+)\]", call)}
             assert widths == {4096, 8192, 64}, call[:300]
+    _assert_the_flash_kernels_walk_tiles(text)
     by = {"scopes": {scopes.scope_path(name): 1.0
                      for name in scopes.op_names(text).values()}}
     for scope in ("block/hc/maps", "block/hc/collect", "block/hc/deposit",

@@ -16,6 +16,7 @@ import re
 import pytest
 
 from test_tpu_compile import (ROOT, _cell_step, _kernels,  # noqa: F401
+                              _assert_the_flash_kernels_walk_tiles,
                               _q_sized_copies, topo)
 
 CELL = "kanana-2-30b-a3b.train-mla8k"
@@ -71,6 +72,8 @@ def test_kanana_train_step_compiles_at_the_cell_sizes(kanana_step, capsys):
             widths = {int(dims.split(",")[-1]) for dims in re.findall(
                 r"bf16\[([0-9,]+)\]", call)}
             assert widths == {4096, 8192, 64}, call[:300]
+    # Several tiles a grid step, inside the default scoped VMEM (PR 52).
+    _assert_the_flash_kernels_walk_tiles(text)
     by = {"scopes": {scopes.scope_path(name): 1.0
                      for name in scopes.op_names(text).values()}}
     for scope in ("block/attn/mla/q", "block/attn/mla/kv_a",
