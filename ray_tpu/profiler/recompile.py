@@ -16,10 +16,15 @@ attributes them to *tracked call sites*:
   recompilation: ``ray_tpu_profiler_recompiles_total`` is bumped and a
   once-per-site warning names the argument shapes/dtypes that changed —
   the culprit, not just the symptom.
-* :func:`install` additionally patches ``jax.jit`` so functions jitted
-  after the install are tracked automatically, each site named after its
-  function.  A train worker calls ``install(patch_jit=False)``: the
-  listener and explicit :func:`track`, and ``jax.jit`` left alone.
+* :func:`install` turns the detector on and registers the listener (a
+  train worker calls it); ``jax.jit`` itself stays jax's.
+
+The same listener names the seconds of a program's way to the device,
+tracked site or not: ``jax_trace`` (Python tracing a jitted function),
+``jax_lower`` (the jaxpr to an MLIR module, every Pallas call's lowering
+in it) and ``xla_compile`` (the backend compile, or the fetch from the
+persistent cache that stands for it) are spans of their own.  It does
+work only when jax traces, lowers or compiles: nothing on a step's path.
 
 Everything degrades to a no-op when jax (or its monitoring API) is
 absent — the module never imports jax on its own.
@@ -37,16 +42,34 @@ from ..util import telemetry
 
 logger = logging.getLogger("ray_tpu.profiler")
 
-#: jax.monitoring event that marks one real XLA compilation.
+#: jax.monitoring's events on a program's way to the device, each fired
+#: with ``fun_name`` when the stretch ends: a jitted function traced to a
+#: jaxpr (nested ones too), the jaxpr lowered to an MLIR module, the module
+#: compiled by the backend (or fetched from the persistent cache).
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-#: ... and the one a fetch from the persistent compile cache records.
+#: What a fetch from the persistent cache records inside that compile, on
+#: its thread and before it ends: the hit, the seconds the read took, and
+#: the seconds the stored compile had taken less those.
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+_FETCH_FIELDS = {_RETRIEVAL_EVENT: "retrieval_s", _SAVED_EVENT: "saved_s"}
+#: The two stretches before the compile: (span, counter of its seconds).
+_STRETCHES = {
+    _TRACE_EVENT: ("jax_trace", "ray_tpu_jax_trace_seconds_total"),
+    _LOWER_EVENT: ("jax_lower", "ray_tpu_jax_lower_seconds_total")}
+#: A compile long enough to be stored, about to be written to the cache.
+_CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"
+
+#: A trace inside another (a nested ``jax.jit``) that took less than this
+#: is no span of its own: its seconds are its parent's.
+NESTED_TRACE_FLOOR_S = 0.010
 
 _lock = threading.Lock()
 _listener_registered = False
 _enabled = False
-_jit_patched = False
-_orig_jit = None
 
 #: site name -> _SiteState
 _sites: Dict[str, "_SiteState"] = {}
@@ -73,27 +96,51 @@ class _SiteState:
         self.donate_argnums: tuple = ()
 
 
+def _emit_stretch(span: str, duration_s: float,
+                  extra: Dict[str, Any]) -> None:
+    """One ``jax_trace`` / ``jax_lower`` / ``xla_compile`` span that ends
+    now and began ``duration_s`` ago.  Wall clock on purpose: it anchors
+    the span among the others; the length is jax's own measurement."""
+    end = time.time()
+    telemetry._emit_span(
+        span, "compile", end - duration_s, end,  # ray-tpu: noqa[RT203]
+        extra={**extra, "seconds": duration_s})
+
+
 def _on_event_duration(event: str, duration_s: float, **kw) -> None:
-    """jax.monitoring listener.  EVERY backend compile (or fetch from the
-    persistent cache: jax times both under this event) becomes an
-    ``xla_compile`` span with the program's name, tracked site or not;
-    with the detector on it is also charged to whichever tracked site is
-    currently executing on this thread."""
+    """jax.monitoring listener.  EVERY trace, lowering and backend compile
+    (or fetch from the persistent cache: jax times both under the one
+    event) becomes a span with the program's name, tracked site or not;
+    with the detector on a compile is also charged to whichever tracked
+    site is currently executing on this thread."""
+    if event in _STRETCHES:
+        # ``_on_scalar`` counted this stretch in when it began.
+        depth = _tls.open = max(getattr(_tls, "open", 1) - 1, 0)
+        if depth and event == _TRACE_EVENT \
+                and duration_s < NESTED_TRACE_FLOOR_S:
+            return
+        span, counter = _STRETCHES[event]
+        program = str(kw.get("fun_name") or "unknown")
+        if depth:
+            _emit_stretch(span, duration_s,
+                          {"program": program, "nested": True})
+        else:
+            # The counters hold top-level programs only: every nested
+            # function's name would blow the tag set up.
+            _emit_stretch(span, duration_s, {"program": program})
+            telemetry.inc(counter, duration_s, tags={"program": program})
+        return
+    if event in _FETCH_FIELDS:
+        _fetched()[_FETCH_FIELDS[event]] = duration_s
+        return
     if event != _COMPILE_EVENT:
         return
     program = str(kw.get("fun_name") or "unknown")
-    hit = bool(getattr(_tls, "cache_hit", False))
-    _tls.cache_hit = False
-    # Wall clock on purpose: it anchors the span among the others; the
-    # length is jax's own measurement.
-    end = time.time()
-    telemetry._emit_span(
-        "xla_compile", "compile",
-        end - duration_s, end,  # ray-tpu: noqa[RT203]
-        extra={"program": program, "seconds": duration_s,
-               "cache_hit": hit})
+    fetched = _tls.__dict__.pop("fetched", {})
+    _emit_stretch("xla_compile", duration_s,
+                  {"program": program, "cache_hit": False, **fetched})
     telemetry.inc("ray_tpu_xla_compiles_total", tags={"program": program})
-    if hit:
+    if fetched.get("cache_hit"):
         telemetry.inc("ray_tpu_compile_cache_hits_total")
     if not _enabled:
         return
@@ -104,12 +151,33 @@ def _on_event_duration(event: str, duration_s: float, **kw) -> None:
     frame["compile_s"] += duration_s
 
 
+def _fetched() -> Dict[str, Any]:
+    """What the persistent cache has said of the compile that this thread
+    is inside; the compile's own event takes it."""
+    found = getattr(_tls, "fetched", None)
+    if found is None:
+        found = _tls.fetched = {}
+    return found
+
+
 def _on_event(event: str, **_kw) -> None:
     """jax.monitoring listener: a persistent-cache hit, reported (where
     this jax reports it) inside the compile that it saves, on its
-    thread."""
+    thread; and an entry about to be written."""
     if event == _CACHE_HIT_EVENT:
-        _tls.cache_hit = True
+        _fetched()["cache_hit"] = True
+    elif event == _CACHE_WRITE_EVENT:
+        telemetry.inc("ray_tpu_compile_cache_writes_total")
+
+
+def _on_scalar(event: str, _value: float, **_kw) -> None:
+    """jax.monitoring listener: a trace or a lowering begins (jax records
+    the start of each timed stretch as a scalar).  Counted a thread, so
+    that a trace that ends while another stretch is open (a nested
+    ``jax.jit``; a jitted helper that a lowering rule calls) is known as
+    nested."""
+    if event in _STRETCHES:
+        _tls.open = getattr(_tls, "open", 0) + 1
 
 
 def ensure_listener() -> bool:
@@ -131,10 +199,12 @@ def ensure_listener() -> bool:
         with _lock:
             if not _listener_registered:
                 register(_on_event_duration)
-                on_event = getattr(jax.monitoring,
-                                   "register_event_listener", None)
-                if on_event is not None:
-                    on_event(_on_event)
+                for name, listener in (
+                        ("register_event_listener", _on_event),
+                        ("register_scalar_listener", _on_scalar)):
+                    also = getattr(jax.monitoring, name, None)
+                    if also is not None:
+                        also(listener)
                 _listener_registered = True
     except Exception:  # noqa: BLE001 — detector must never break user code
         return False
@@ -215,8 +285,8 @@ class TrackedFunction:
                  static_argnames: Any = None, donate_argnums: Any = None):
         self.__wrapped__ = fn
         self._site = _site_state(site)
-        # Jit kwargs forwarded from track()/the jax.jit patch: static
-        # args are signature'd by VALUE (a change there is an expected
+        # Jit kwargs forwarded from track(): static args are
+        # signature'd by VALUE (a change there is an expected
         # recompile, not shape churn) and donation is surfaced so
         # tooling reading the wrapper sees the same contract the
         # underlying jit was built with.
@@ -325,8 +395,7 @@ def track(fn, name: Optional[str] = None, static_argnums: Any = None,
     accounting and post-warmup recompile detection.  Pass the same
     ``static_argnums``/``static_argnames``/``donate_argnums`` the jit
     was built with so signatures classify static-value changes as
-    expected recompiles (the ``jax.jit`` patch forwards them
-    automatically)."""
+    expected recompiles."""
     if isinstance(fn, TrackedFunction):
         return fn
     site = name or getattr(fn, "__name__", None) \
@@ -338,52 +407,20 @@ def track(fn, name: Optional[str] = None, static_argnums: Any = None,
                            donate_argnums=donate_argnums)
 
 
-def install(patch_jit: bool = True) -> bool:
-    """Enable the detector process-wide.  With ``patch_jit``, functions
-    jitted AFTER this call are tracked automatically (named by the
-    decorated function's ``__name__``).  Safe to call repeatedly."""
-    global _enabled, _jit_patched, _orig_jit
+def install() -> bool:
+    """Enable the detector process-wide: the listener, and the sites that
+    :func:`track` wraps.  Safe to call repeatedly; returns False where jax
+    is not imported yet (callers install after their own jax import)."""
+    global _enabled
     _enabled = True
-    if not patch_jit or _jit_patched:
-        return ensure_listener()
-    if "jax" not in sys.modules:
-        # Deliberately NOT importing jax here; callers install after
-        # their own jax import (the train worker does).
-        return False
-    import jax
-    _orig_jit = jax.jit
-
-    def _tracking_jit(*args, **kwargs):
-        out = _orig_jit(*args, **kwargs)
-        if args and callable(args[0]) and callable(out):
-            name = getattr(args[0], "__name__", None) or "jit"
-            return track(out, name=name,
-                         static_argnums=kwargs.get("static_argnums"),
-                         static_argnames=kwargs.get("static_argnames"),
-                         donate_argnums=kwargs.get("donate_argnums"))
-        return out
-
-    try:
-        jax.jit = _tracking_jit
-        _jit_patched = True
-    except Exception:  # noqa: BLE001 — fall back to explicit track()
-        return ensure_listener()
     return ensure_listener()
 
 
 def uninstall() -> None:
-    """Disable the detector (the monitoring listener stays registered
-    but inert — jax has no per-listener deregistration) and restore
-    ``jax.jit``."""
-    global _enabled, _jit_patched
+    """Disable the detector (the monitoring listener stays registered:
+    the spans are not the detector's to stop)."""
+    global _enabled
     _enabled = False
-    if _jit_patched and _orig_jit is not None:
-        try:
-            import jax
-            jax.jit = _orig_jit
-        except Exception as e:  # noqa: BLE001
-            telemetry.note_swallowed("profiler.recompile.uninstall", e)
-        _jit_patched = False
 
 
 def report() -> Dict[str, Any]:

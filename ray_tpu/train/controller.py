@@ -113,18 +113,18 @@ class TrainWorker:
             if ctx_info.get("use_tpu"):
                 from ..accelerators.tpu import init_backend
                 init_backend()
-            # Compile accounting: every backend compile of this worker is
-            # an ``xla_compile`` span and a count in
-            # ``ray_tpu_xla_compiles_total`` (the jax.monitoring listener:
-            # it needs jax imported, so this runs AFTER the train fn
-            # deserialized and after any setup_dist import), and a site
-            # wrapped with ``ray_tpu.profiler.track()`` warns when it
-            # compiles again after its warm-up.  ``jax.jit`` itself stays
-            # jax's: the patch named a site after its function, so every
-            # ``<lambda>`` of a process was one site and a helper jitted
-            # after the warm-up read as a recompilation of the step.
+            # Compile accounting: every trace, lowering and backend
+            # compile of this worker is a ``jax_trace`` / ``jax_lower`` /
+            # ``xla_compile`` span (the jax.monitoring listener: it needs
+            # jax imported, so this runs AFTER the train fn deserialized
+            # and after any setup_dist import), and a site wrapped with
+            # ``ray_tpu.profiler.track()`` warns when it compiles again
+            # after its warm-up.  ``jax.jit`` itself stays jax's.  (The
+            # comment keeps its ten lines: a Mosaic kernel's body holds
+            # its callers' line numbers, ``fn(config)``'s below among
+            # them where they reach it, and is in its compile-cache key.)
             from ..profiler import recompile
-            recompile.install(patch_jit=False)
+            recompile.install()
             # group: it holds the whole step loop, whose parts are the
             # user's own and train_place_batch / train_report.
             with telemetry.profile_span(

@@ -423,6 +423,18 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "XLA backend compiles (persistent-cache fetches "
                        "included) by program name; each is also an "
                        "xla_compile span."},
+    "ray_tpu_jax_trace_seconds_total": {
+        "type": "counter", "tag_keys": ("program",),
+        "description": "Seconds jax spent tracing a jitted function to a "
+                       "jaxpr, by the function's name; top-level traces "
+                       "only (a nested jit's seconds are in its "
+                       "parent's).  Each is also a jax_trace span."},
+    "ray_tpu_jax_lower_seconds_total": {
+        "type": "counter", "tag_keys": ("program",),
+        "description": "Seconds jax spent lowering a traced program to "
+                       "an MLIR module (every Pallas call's lowering in "
+                       "it), by the module's name.  Each is also a "
+                       "jax_lower span."},
     "ray_tpu_flash_step_geometry_total": {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
@@ -519,6 +531,12 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "description": "Programs fetched from the persistent compile "
                        "cache instead of compiled (where this jax "
                        "reports it)."},
+    "ray_tpu_compile_cache_writes_total": {
+        "type": "counter", "tag_keys": (),
+        "description": "Programs compiled here and written to the "
+                       "persistent compile cache (a compile long and "
+                       "large enough to be stored): above 0, this "
+                       "process paid cold compiles."},
     "ray_tpu_profiler_recompiles_total": {
         "type": "counter", "tag_keys": ("fn",),
         "description": "POST-WARMUP recompilations: a tracked site that "
@@ -920,8 +938,9 @@ class profile_span:
     ``PjitFunction`` and ``np.asarray`` while one does, so a gap on the
     device can be given to the program's own span.  ``group=True`` marks
     a span that only holds other spans (a whole loop, a whole step): it
-    is recorded like any other but stays off the host plane, where a gap
-    belongs to the part and not to the whole.
+    is recorded like any other, with ``group: true`` in its record so
+    that a reader of named time can leave the holders out, but stays off
+    the host plane, where a gap belongs to the part and not to the whole.
     """
 
     __slots__ = ("name", "category", "extra", "group", "_frames")
@@ -958,6 +977,8 @@ class profile_span:
         dur = time.monotonic() - entry["start_mono"]
         extra = dict(self.extra or {})
         extra.update(_span_exit(entry, dur))
+        if self.group:
+            extra["group"] = True
         _emit_span(self.name, self.category, entry["start"],
                    entry["start"] + dur, extra)
         # Closed last: the annotation then outlasts every host event it

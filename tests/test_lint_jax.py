@@ -674,7 +674,7 @@ def train_loop(params, opt_state, batches):
 def recompile_detector():
     from ray_tpu.profiler import recompile
     recompile._reset_for_tests()
-    recompile.install(patch_jit=True)
+    recompile.install()
     yield recompile
     recompile.uninstall()
     recompile._reset_for_tests()
@@ -687,7 +687,8 @@ class TestTrackedJitKwargs:
 
         def pow_fn(x, k):
             return x ** k
-        f = jax.jit(pow_fn, static_argnums=(1,))
+        f = recompile_detector.track(jax.jit(pow_fn, static_argnums=(1,)),
+                                     static_argnums=(1,))
         assert isinstance(f, recompile_detector.TrackedFunction)
         assert f.static_argnums == (1,)
         f(jnp.ones((4,)), 2)
@@ -706,7 +707,9 @@ class TestTrackedJitKwargs:
 
         def mode_fn(x, mode=None):
             return x + (1 if mode == "a" else 2)
-        g = jax.jit(mode_fn, static_argnames=("mode",))
+        g = recompile_detector.track(
+            jax.jit(mode_fn, static_argnames=("mode",)),
+            static_argnames=("mode",))
         assert g.static_argnames == ("mode",)
         g(jnp.ones((4,)), mode="a")
         rep = recompile_detector.report()["mode_fn"]
@@ -719,7 +722,8 @@ class TestTrackedJitKwargs:
 
         def don_fn(x):
             return x * 2
-        h = jax.jit(don_fn, donate_argnums=(0,))
+        h = recompile_detector.track(jax.jit(don_fn, donate_argnums=(0,)),
+                                     donate_argnums=(0,))
         assert h.donate_argnums == (0,)
         h(jnp.ones((4,)))
         assert recompile_detector.report()["don_fn"][

@@ -286,6 +286,146 @@ class TestCompileSpans:
                     if "never_tracked_fn_27" in s["extra"].get(
                         "program", "")]) <= 1
 
+    def test_trace_lower_compile_in_that_order(self, captured_spans):
+        """An untracked ``jax.jit``: one span for each stretch of its way
+        to the device, under its program's name, one after the other."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.profiler import recompile
+        from ray_tpu.util import metrics
+
+        assert recompile.ensure_listener()
+
+        def staged_fn_53(x):
+            return jnp.tanh(x) * 2 + x
+
+        jax.jit(staged_fn_53)(jnp.ones((7,))).block_until_ready()
+        mine = [s for s in captured_spans
+                if "staged_fn_53" in s["extra"].get("program", "")]
+        assert [s["name"] for s in mine] == [
+            "jax_trace", "jax_lower", "xla_compile"]
+        trace, lower, compile_ = mine
+        assert "nested" not in trace["extra"]
+        for s in mine:
+            assert s["extra"]["seconds"] == pytest.approx(
+                s["end"] - s["start"])
+        assert trace["start"] <= trace["end"] <= lower["end"] \
+            <= compile_["end"]
+        assert trace["start"] <= lower["start"] <= compile_["start"]
+        _by_name, acc = metrics._aggregate_snapshots()
+        for series in ("ray_tpu_jax_trace_seconds_total",
+                       "ray_tpu_jax_lower_seconds_total"):
+            assert any("staged_fn_53" in dict(k).get("program", "")
+                       for k in acc[series]), series
+
+    @pytest.mark.parametrize("floor, spans_of_the_inner", [(0.0, 1),
+                                                           (1e9, 0)])
+    def test_nested_trace_is_a_span_over_the_floor(
+            self, captured_spans, monkeypatch, floor, spans_of_the_inner):
+        """A jit called inside another's trace: a ``nested`` span where it
+        is held longer than the floor, no span under it (its seconds are
+        its parent's), and no series of its own either way."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.profiler import recompile
+        from ray_tpu.util import metrics
+
+        assert recompile.ensure_listener()
+        monkeypatch.setattr(recompile, "NESTED_TRACE_FLOOR_S", floor)
+        tag = f"nest53_{spans_of_the_inner}"
+
+        def inner(x):
+            return x * x + 1
+        inner.__name__ = f"inner_{tag}"
+        inner = jax.jit(inner)
+
+        def outer(x):
+            return inner(x) - 3
+        outer.__name__ = f"outer_{tag}"
+
+        jax.jit(outer)(jnp.ones((3,))).block_until_ready()
+        traces = {s["extra"]["program"]: s for s in captured_spans
+                  if s["name"] == "jax_trace"
+                  and tag in s["extra"]["program"]}
+        assert f"outer_{tag}" in traces
+        assert "nested" not in traces[f"outer_{tag}"]["extra"]
+        assert len(traces) == 1 + spans_of_the_inner
+        if spans_of_the_inner:
+            got, whole = traces[f"inner_{tag}"], traces[f"outer_{tag}"]
+            assert got["extra"]["nested"] is True
+            assert whole["start"] <= got["start"] <= got["end"] \
+                <= whole["end"]
+        _by_name, acc = metrics._aggregate_snapshots()
+        tagged = {dict(k).get("program", "")
+                  for k in acc["ray_tpu_jax_trace_seconds_total"]}
+        assert f"outer_{tag}" in tagged and f"inner_{tag}" not in tagged
+        # The thread's count of open stretches is back where it was.
+        assert recompile._tls.open == 0
+
+    def test_fetch_from_the_persistent_cache_says_what_it_saved(
+            self, tmp_path):
+        """One program compiled twice from a clean in-memory state on a
+        cache directory of its own: the first compile is written there,
+        the second comes from it and says so."""
+        script = tmp_path / "twice.py"
+        script.write_text(
+            "import json\n"
+            "import jax, jax.numpy as jnp\n"
+            "from ray_tpu.profiler import recompile\n"
+            "from ray_tpu.util import metrics, telemetry\n"
+            "spans = []\n"
+            "telemetry._emit_span = lambda name, cat, start, end, "
+            "extra=None: spans.append({'name': name, **(extra or {})})\n"
+            "assert recompile.ensure_listener()\n"
+            "def cached_fn_53(x):\n"
+            "    return jnp.sin(x) @ x.T\n"
+            "def writes():\n"
+            "    acc = metrics._aggregate_snapshots()[1]\n"
+            "    return sum(v for _t, v in acc.get(\n"
+            "        'ray_tpu_compile_cache_writes_total', {}).values())\n"
+            "out = []\n"
+            "for _ in range(2):\n"
+            "    jax.clear_caches()\n"
+            "    jax.jit(cached_fn_53)(jnp.ones((4, 4))).block_until_ready()\n"
+            "    out.append({'writes': writes(), 'compiles': [\n"
+            "        s for s in spans if s['name'] == 'xla_compile'\n"
+            "        and 'cached_fn_53' in s['program']]})\n"
+            "    spans.clear()\n"
+            "print(json.dumps(out))\n")
+        env = dict(os.environ, PYTHONPATH=REPO_ROOT, JAX_PLATFORMS="cpu",
+                   JAX_ENABLE_COMPILATION_CACHE="true",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+        done = subprocess.run([sys.executable, str(script)], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert done.returncode == 0, done.stdout + done.stderr
+        first, second = json.loads(done.stdout.strip().splitlines()[-1])
+        (cold,), (warm,) = first["compiles"], second["compiles"]
+        assert cold["cache_hit"] is False
+        assert "retrieval_s" not in cold and "saved_s" not in cold
+        assert first["writes"] >= 1
+        assert warm["cache_hit"] is True
+        assert warm["retrieval_s"] > 0
+        # jax's own count: the stored compile's seconds (kept whole in
+        # the entry, and a part of the cold span's, which also made the
+        # key and wrote the entry) less the read's.
+        stored = warm["saved_s"] + warm["retrieval_s"]
+        assert stored == pytest.approx(round(stored), abs=1e-6)
+        assert 0 <= stored <= cold["seconds"]
+        assert second["writes"] == first["writes"]
+
+    def test_a_group_span_says_so_in_its_record(self, captured_spans):
+        with telemetry.profile_span("a_holder_53", group=True):
+            with telemetry.profile_span("a_part_53"):
+                pass
+        part, holder = captured_spans
+        assert holder["name"] == "a_holder_53"
+        assert holder["extra"]["group"] is True
+        assert "group" not in part["extra"]
+
     def test_backend_init_is_a_span(self, captured_spans):
         from ray_tpu.accelerators.tpu import init_backend
         assert init_backend() >= 1
