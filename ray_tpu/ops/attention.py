@@ -86,8 +86,8 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   one-tile walk's bit for bit.  The walk stops at the last tile the step's
   q rows see (``_visible_tiles``), so nothing above the diagonal is
   computed, as larger blocks would; the score tile stays 512 x 512, so the
-  kernels stay inside Mosaic's default 16 MiB of scoped VMEM (9.5 MiB at
-  the most, dk/dv's, by the compiler's count) and state no limit.  What it
+  kernels stay inside Mosaic's default 16 MiB of scoped VMEM (dk/dv 9.5
+  MiB, the one pass 13.1 at its 4 tiles) and state no limit.  What it
   buys is what a grid step costs beside its tiles (block DMAs issued and
   waited for, the table's word, the index maps, scratch read and written
   back): at [1, 32, 8192] and 128 + 64 / 128 the three kernels read
@@ -119,16 +119,32 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   layout the jax flash kernel also uses); the log-sum-exp leaves the kernel
   as a residual in rows along the lanes, [.., 1, Sq]: a q block's last step
   transposes its column once, and nothing 128 times its size reaches HBM.
-- Backward is two kernels: dq (the same walk as the forward, accumulating
-  dq for a resident Q block) and dk/dv (K-major: the steps of one K/V block
-  in a row with Q ascending, accumulating dk/dv for the resident block).
-  Both recompute probabilities from the saved LSE — one exp, no second
-  softmax pass — with fp32 accumulation and bf16 MXU inputs.  dk/dv forms
-  its scores transposed (``k q^T``, as upstream's splash kernel does), so
+- Backward has two forms, and ``_tiles`` picks one from the call's shapes.
+  **The pair**: dq (the same walk as the forward, accumulating dq for a
+  resident Q block) and dk/dv (K-major: the steps of one K/V block in a
+  row with Q ascending, accumulating dk/dv for the resident block).  Both
+  recompute probabilities from the saved LSE — one exp, no second softmax
+  pass — with fp32 accumulation and bf16 MXU inputs.  dk/dv forms its
+  scores transposed (``k q^T``, as upstream's splash kernel does), so
   dv = p^T do and dk = ds^T q are plain products with no transpose in the
   kernel, and reads ``lse`` / ``di`` as rows along the lanes.  dq needs
   them as columns and makes those in VMEM, once a resident q block, from
   the same rows: no lane-broadcast copy of either is written outside.
+  **The one pass** (``flash_bwd``, PERF.md, PR 54): the pair forms every
+  tile's scores, probabilities, ``dP = dO V^T`` and ``dS`` twice, seven
+  products a tile (11 passes of a 128-deep MXU at 192 / 128) where five
+  (8) make all three gradients.  Where a grid row is one query head, the
+  K-major kernel also adds ``dS^T`` (one turn of the tile through the
+  XLU) ``k`` into a float32 scratch that holds the row's whole dq, and the
+  dq kernel is not called: q tile ``i`` has seen its last k block at k
+  block ``i``'s last step and leaves then, so nothing of dq crosses HBM
+  but the result (upstream's splash dk/dv kernel carries an optional dq
+  too, as per-k-block partials in HBM that are summed outside).  It
+  serves the causal square (``Sq == Sk``, no offset, no window) with
+  ``block_q == block_k``, which is where a q tile's last k block is its
+  own; a group's stacked heads (the scratch would be 8 - 32 MiB a row), a
+  window, a ring shard's offset or rectangle and ``causal=False`` keep
+  the pair, and trace what they traced.
 
 ``q_offset`` shifts query positions for causal masking so sequence-sharded
 callers (ring attention) can flash-attend a mid-sequence Q shard.
@@ -332,12 +348,28 @@ _BIG_BLOCK = 1024       # no group to stack and no window: 1,024 x 1,024
 _MAX_HEADS = 8          # query heads a step stacks: 4,096 rows at 512 each
 _FWD_SCORES = 2 ** 20   # the forward's score tile, elements: halves block_k
 _WALK = (8, 4, 2, 1)    # head size over 128: 512 x 512 tiles a grid step
+# The one pass (``bwd``): the bytes of float32 dq a grid row may keep in VMEM
+# beside dk/dv's walk, and the tiles a step of it walks at the most.  From
+# the chip's table (PERF.md, PR 54, step 0; ms a call of the backward / the
+# scoped VMEM by the compiler's count).  [4, 16, 4096, 128] with ``rows``,
+# 2 MiB of dq a row: the pair 6.946 at 1,024 x 1,024 (7.503 at 512 x 512),
+# the one pass 4.806 / 11.3 MiB of a stated 56 (5.075 / 5.2 at 512 x 512).
+# In parts at [1, 32, 8192], 128 + 64 / 128, 4 + 2 MiB a row: the pair 18.757
+# (dk/dv 9.5 MiB at 8 tiles a step), the one pass 13.072 / 11.6 at 2 tiles
+# and 12.737 / 13.1 at 4, of the default's 16 and with no limit stated; with
+# the rotary part's sums as rows ([Sq, 64], lane-padded 4 MiB) 14.397 / 12.9
+# at 1, 13.770 / 13.6 at 2, 13.434 / 15.1 at 4.  Six MiB and four tiles are
+# the most that leave the default limit the room PR 52 left it.
+_DQ_ROW = 6 * 2 ** 20
+_BWD_WALK = 4
 
 
-def _tiles(kind, Sq, Sk, D, group, window=None):
+def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
     """The geometry of a grid step of kernel ``kind`` (``fwd``, ``dq``,
-    ``dkv``), from the shapes of the call alone; ``D`` is the larger of the
-    call's two head sizes.
+    ``dkv``; ``bwd``: the one pass in place of the last two, or None where
+    the call keeps the pair), from the shapes of the call alone; ``D`` is
+    the larger of the call's two head sizes, ``Dr`` of them a call in
+    parts' rotary lanes.
 
     - A key head's query heads share one step (the most that divide the
       group, up to 8): forward and dq stack their rows behind each tile of
@@ -371,7 +403,24 @@ def _tiles(kind, Sq, Sk, D, group, window=None):
       the group's 4 heads stacked, 70.7 - 71.8 with either block at 1,024
       and 74.0 - 74.4 with either at 256 (PERF.md, PR 47).  The same
       operations at [4, 16 / 4, 8192, 128] take 33.4 ms: a score product
-      that contracts over 64 fills half of the MXU's 128 x 128 tile."""
+      that contracts over 64 fills half of the MXU's 128 x 128 tile.
+    - ``bwd``: the K-major walk makes dq too, dk/dv's geometry at
+      ``_BWD_WALK`` tiles a step at the most, where a grid row is one query
+      head (a group's stacked heads would want 8 - 32 MiB of dq a row),
+      there is no window, ``Sq == Sk`` (with the blocks equal, q tile ``i``
+      is then complete at k block ``i``; ``_flash_backward`` adds that the
+      call is causal with no offset) and the row's float32 dq fits
+      ``_DQ_ROW``: [Sq, D] lane-padded, a call in parts' rotary lanes lying
+      along the lanes, [Dr, Sq], for nothing.  Yi's and Ouro's
+      [4, 16, 4096, 128] hold 2 MiB, latent attention's [1, 32, 8192] in
+      parts 4 + 2; 128-wide rows over 12,288 tokens, a 192-wide call in
+      one part over 6,144 and every stacked group keep the pair."""
+    if kind == "bwd":
+        t = _tiles("dkv", Sq, Sk, D, group, window)
+        if (t.heads > 1 or window is not None or Sq != Sk
+                or Sq * (-(-(D - Dr) // LANES) * LANES + Dr) * 4 > _DQ_ROW):
+            return None
+        return t._replace(tiles=min(t.tiles, _BWD_WALK))
     scores = "kq" if kind == "dkv" else "qk"
     block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
     if D > LANES:
@@ -441,10 +490,14 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
     """``_tiles``' answer for this call, an explicit block size winning (and
     walked one tile a grid step), checked against the lengths and counted."""
     _, H, Hkv, Sq, Sk, D, Dv, Dr = dims
-    t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window)
+    t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window, Dr)
+    if t is None:               # ``bwd``: this call keeps the pair
+        return None
     if block_q or block_k:      # the blocks a call names, one a grid step
         t = t._replace(block_q=min(block_q or t.block_q, Sq),
                        block_k=min(block_k or t.block_k, Sk), tiles=1)
+        if kind == "bwd" and not t.block_q == t.block_k <= _BIG_BLOCK:
+            return None
     if Sq % t.block_q or Sk % t.block_k:
         raise ValueError(f"seq ({Sq},{Sk}) not divisible by blocks "
                          f"({t.block_q},{t.block_k})")
@@ -735,6 +788,9 @@ class Specs(NamedTuple):
     k_r: object = None      # the one rotary key head, whatever the grid row
     dkv: object = None      # kv's gradient, a grid row's own
     dk_r: object = None     # a grid row's own share of k_r's
+    # The one pass: q's gradient leaves the K-major walk a tile a k block.
+    dq: object = None       # the q tile at the resident k block's index
+    dq_r: object = None     # the same of q_r's gradient
 
 
 def _specs(t, dims, rows=False):
@@ -760,16 +816,17 @@ def _specs(t, dims, rows=False):
     # row's resident block's).
     block_q, block_k = t.major
 
-    def of_q(d, as_rows):
-        """(spec, shape) of a q-side operand of head size ``d``."""
+    def of_q(d, as_rows, block_q=block_q, at=_step_qi):
+        """(spec, shape) of a q-side operand of head size ``d``; the one
+        pass's dq is a tile of it ``at`` the resident k block's index."""
         if as_rows:
             return (pl.BlockSpec(
                 (1, block_q, t.heads * d), lambda r, s, sched: (
-                    r // per_b, _step_qi(sched[s]), r % per_b)),
+                    r // per_b, at(sched[s]), r % per_b)),
                 (B, Sq, H * d))
         return (pl.BlockSpec(
             (1, t.heads, block_q, d), lambda r, s, sched: (
-                r, 0, _step_qi(sched[s]), 0)),
+                r, 0, at(sched[s]), 0)),
             (n, t.heads, Sq, d))
 
     def of_k(d, as_rows, own=False):
@@ -795,12 +852,15 @@ def _specs(t, dims, rows=False):
                 "dk_r": of_k(Dr, False, own=True),
                 "k_r": (pl.BlockSpec(
                     (1, block_k, Dr), lambda r, s, sched: (
-                        r // per_b, _step_ki(sched[s]), 0)), (B, Sk, Dr))}
+                        r // per_b, _step_ki(sched[s]), 0)), (B, Sk, Dr)),
+                "dq": of_q(D - Dr, True, t.block_q, _step_ki),
+                "dq_r": of_q(Dr, False, t.block_q, _step_ki)}
     else:
         made = {"q": of_q(D, False), "o": of_q(Dv, rows),
                 "k": of_k(D, False), "v": of_k(Dv, rows),
                 "dk": of_k(D, False, own=True),
-                "dv": of_k(Dv, rows, own=True)}
+                "dv": of_k(Dv, rows, own=True),
+                "dq": of_q(D, False, t.block_q, _step_ki)}
     return Specs(
         row=pl.BlockSpec((1, t.heads, 1, block_q), lambda r, s, sched: (
             r, 0, 0, _step_qi(sched[s]))),
@@ -898,20 +958,45 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
     p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
     along the lanes.  The step's heads add into the one resident dk / dv.
     In parts, dk and dv leave side by side as ``kv`` came, and the grid
-    row's share of the one rotary head's gradient beside them."""
+    row's share of the one rotary head's gradient beside them.
+
+    **The one pass** (``flash_bwd``: a call hands dq's refs too, its
+    results after dk / dv's and its scratch before theirs).  A grid row is
+    one query head, ``block_q == block_k`` and the call is the causal
+    square, so the tile's ``dst`` is all dq needs: ``dq[q tile] += dst^T
+    k`` into a float32 scratch that holds the row's whole dq, [Sq, D]
+    (in parts [Sq, Dn], and the rotary lanes' ``k_r^T dst`` along the
+    lanes, [Dr, Sq]: half the VMEM of 64 lanes padded, and the faster of
+    the two on the chip), zeroed at the row's first step.  The table
+    visits k blocks in ascending order, so q tile ``i`` is complete at k
+    block ``i``'s last step and leaves then, through a block indexed by the
+    resident side: the same sum over k blocks in the same order as the dq
+    kernel's.  With no dq refs the body traces what it did (``ops/eva.py``
+    calls it so)."""
     from jax.experimental import pallas as pl
 
     if parts:
         (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
-         dkv_ref, dkr_ref, dk_scr, dkr_scr, dv_scr) = refs
+         dkv_ref, dkr_ref, *dq, dk_scr, dkr_scr, dv_scr) = refs
         k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
         dk_ref, dv_ref = _k_and_v(dkv_ref, q_ref.shape[-1])
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
-         dk_scr, dv_scr) = refs
+         *dq, dk_scr, dv_scr) = refs
+    dq_refs, dq_scrs = dq[:len(dq) // 2], dq[len(dq) // 2:]
     heads = lse_ref.shape[1]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
+
+    def q_tile(i):
+        """The rows of q tile ``i`` in the row's dq."""
+        return pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    if dq:
+        @pl.when(pl.program_id(1) == 0)
+        def _init_row():
+            for scr in dq_scrs:
+                scr[...] = jnp.zeros(scr.shape, jnp.float32)
 
     @pl.when(step & _FIRST_BIT != 0)
     def _init():
@@ -950,6 +1035,15 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
             if parts:
                 dk_r += jax.lax.dot(dst.astype(q.dtype), q_r,
                                     preferred_element_type=jnp.float32)
+            if dq:
+                at = q_tile(_tile_index(qi, j, tiles))
+                dq_scrs[0][at, :] += jax.lax.dot(
+                    dst.astype(k.dtype).T, k,                  # [bq, bk]
+                    preferred_element_type=jnp.float32)
+                if parts:       # k_r^T dst, [Dr, bq]: no second turn
+                    dq_scrs[1][:, at] += jax.lax.dot_general(
+                        k_r, dst.astype(k.dtype), (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
         dk_scr[...] = dk
         dv_scr[...] = dv
         if parts:
@@ -966,6 +1060,10 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         if parts:
             dkr_ref[0] = dkr_scr[...].astype(dkr_ref.dtype)
+        if dq:      # q tile ki has seen its last k block
+            _write_rows(dq_refs[0], dq_scrs[0][q_tile(ki), :], 1, parts)
+            if parts:
+                _write_rows(dq_refs[1], dq_scrs[1][:, q_tile(ki)].T, 1)
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
@@ -996,7 +1094,9 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     def call(kind, kernel, t, major, outs, scratch, tile):
         """One backward kernel at its geometry ``t``, its results the
         operands named in ``outs`` (name -> dtype); LSE / delta enter as
-        rows along the lanes, with no broadcast outside."""
+        rows along the lanes, with no broadcast outside.  ``tile``: the
+        step's float32 tile, by which ``_compiler_params`` states a limit
+        of scoped VMEM ((0, 0): none)."""
         n = B * H // t.heads
         sp = _specs(t, dims, rows)
         sched = _packed_schedule(Sq, Sk, *t.major, q_offset, causal, major,
@@ -1028,42 +1128,66 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
           dout.reshape(sp.shapes["o"]),
           lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
 
-    # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-    t = _geometry("dq", dims, block_q, block_k, window, rows)
-    stacked = t.heads * t.block_q
-    dq = call(
-        "dq", _dq_kernel, t, "q",
-        {"q": dtype, **({"q_r": dtype} if Dr else {})},
-        [*(_vmem((stacked, d), jnp.float32) for d in (D - Dr, Dr) if d),
-         _vmem((stacked, LANES), jnp.float32),
-         _vmem((stacked, LANES), jnp.float32)],
-        (stacked, t.block_k))
+    # ---- The one pass, where the shapes admit it (``_tiles``) and the call
+    # is the causal square: the K-major walk below makes dq too, in a
+    # scratch that holds its grid row's, and no dq kernel is called.
+    t = (_geometry("bwd", dims, block_q, block_k, window, rows)
+         if causal and not q_offset else None)
+    kind, dq_outs, dq_scratch = "bwd", {}, []
+    if t is not None:
+        dq_outs = {"dq": dtype, **({"dq_r": dtype} if Dr else {})}
+        dq_scratch = [_vmem(shape, jnp.float32)
+                      for shape in ((Sq, D - Dr), (Dr, Sq)) if all(shape)]
+        # It states no limit: a head a step, it holds 11.3 MiB at 1,024 x
+        # 1,024 and Yi's length and 14.3 at the longest ``_DQ_ROW`` admits,
+        # and a limit over the default on this call of three results
+        # crashed XLA's memory-space assignment where it compiled Yi's
+        # one-row check program (PERF.md, PR 54).
+        tile = (0, 0)
+    else:
+        # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
+        t = _geometry("dq", dims, block_q, block_k, window, rows)
+        stacked = t.heads * t.block_q
+        dq = call(
+            "dq", _dq_kernel, t, "q",
+            {"q": dtype, **({"q_r": dtype} if Dr else {})},
+            [*(_vmem((stacked, d), jnp.float32) for d in (D - Dr, Dr) if d),
+             _vmem((stacked, LANES), jnp.float32),
+             _vmem((stacked, LANES), jnp.float32)],
+            (stacked, t.block_k))
+        kind, t = "dkv", _geometry("dkv", dims, block_q, block_k, window,
+                                   rows)
+        tile = (t.block_q, t.block_k)
 
     # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk), the
     # step's query heads adding into it.  Where a step holds the whole
     # group the results leave per key head in the inputs' dtype; else per
     # step's heads in float32, summed over the group below.
-    t = _geometry("dkv", dims, block_q, block_k, window, rows)
     shares = H // Hkv // t.heads
     if Dr:
         # A grid row is a head (``_tiles``: a head a step over 128, and no
         # group in parts).  Its share of the one rotary key head's gradient
         # leaves in the inputs' dtype, as a 192-wide dk's lanes did, and
         # the heads are summed here.
-        dkv, dk_r = call(
-            "dkv", _dkv_kernel, t, "k", dict.fromkeys(("dkv", "dk_r"), dtype),
-            [_vmem((t.block_k, d), jnp.float32) for d in (D - Dr, Dr, Dv)],
-            (t.block_q, t.block_k))
+        dkv, dk_r, *one = call(
+            kind, _dkv_kernel, t, "k",
+            {**dict.fromkeys(("dkv", "dk_r"), dtype), **dq_outs},
+            [*dq_scratch,
+             *(_vmem((t.block_k, d), jnp.float32) for d in (D - Dr, Dr, Dv))],
+            tile)
+        dq = one or dq
         dk_r = dk_r.reshape(B, H, Sk, Dr).sum(
             axis=1, keepdims=True, dtype=jnp.float32).astype(dtype)
         return ((dq[0].reshape(q[0].shape), dq[1].reshape(q[1].shape)),
                 (dkv.reshape(k[0].shape), dk_r), None)
-    dk, dv = call(
-        "dkv", _dkv_kernel, t, "k",
-        {name: jnp.float32 if shares > 1 else k.dtype
-         for name in ("dk", "dv")},
-        [_vmem((t.block_k, d), jnp.float32) for d in (D, Dv)],
-        (t.block_q, t.block_k))
+    dk, dv, *one = call(
+        kind, _dkv_kernel, t, "k",
+        {**{name: jnp.float32 if shares > 1 else k.dtype
+            for name in ("dk", "dv")}, **dq_outs},
+        [*dq_scratch, *(_vmem((t.block_k, d), jnp.float32) for d in (D, Dv))],
+        tile)
+    if one:
+        dq, = one
     if shares > 1:
         # Over a key head's shares, which lie side by side.
         dk = dk.reshape(B, Hkv, shares, Sk, D).sum(axis=2).astype(k.dtype)

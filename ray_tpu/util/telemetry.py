@@ -442,7 +442,10 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                      "tiles_a_step"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
-                       "from the call's shapes (scores: qk or kq; "
+                       "from the call's shapes (kernel: flash_fwd, and "
+                       "flash_dq with flash_dkv, or flash_bwd alone "
+                       "where the backward made one pass over the "
+                       "tiles; scores: qk or kq; "
                        "tiles_a_step only where a grid step fetches a "
                        "major block of that many block_q x block_k tiles "
                        "of the streamed side and walks them, which a head "

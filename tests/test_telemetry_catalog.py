@@ -364,7 +364,9 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
             q, q, v, interpret=True, rows=rows)))(q)
     lines = [l for l in metrics_mod.prometheus_text().splitlines()
              if l.startswith(name + "{")]
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+    # a key head a query head: the backward is the one pass (PR 54)
+    assert not any("flash_dq" in l or "flash_dkv" in l for l in lines)
+    for kernel in ("flash_fwd", "flash_bwd"):
         mine = [l for l in lines if f'kernel="{kernel}"' in l]
         assert len(mine) == 2, mine
         assert sum('rows="vo"' in l for l in mine) == 1
@@ -376,9 +378,9 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
         (q_n, q_r), (kv, q_r[:, :1]), None, interpret=True)))(q_n)
     lines = [l for l in metrics_mod.prometheus_text().splitlines()
              if l.startswith(name + "{") and "parts=" in l]
-    assert len(lines) == 3, lines
-    for kernel, line in zip(("fwd", "dq", "dkv"), sorted(
-            lines, key=lambda l: ("dkv" in l, "dq" in l))):
+    assert len(lines) == 2, lines
+    for kernel, line in zip(("fwd", "bwd"), sorted(
+            lines, key=lambda l: "bwd" in l)):
         assert f'kernel="flash_{kernel}_d192v128"' in line, line
         assert 'parts="128+64"' in line and 'rows="qkvo"' in line, line
     metrics_mod._reset_for_tests()

@@ -133,7 +133,8 @@ def test_flash_forward_compiles(one_chip):
 
 
 def _flash_grads(q, k, v):
-    """The jitted gradient of a causal flash attention: forward, dq, dk/dv."""
+    """The jitted gradient of a causal flash attention: forward, and dq
+    with dk/dv or the one pass (``flash_bwd``; PR 54)."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
@@ -150,7 +151,9 @@ def test_flash_backward_compiles(one_chip):
 
     x = _sds(FLASH_SHAPE, jnp.bfloat16, one_chip)
     compiled = _flash_grads(x, x, x)
-    assert _kernels(compiled) == 3          # forward, dq, dk/dv
+    # forward and the one pass: no group to stack on the causal square
+    assert _kernels(compiled) == 2
+    assert "flash_bwd" in compiled.as_text()
 
 
 @pytest.mark.parametrize("batch,heads,kv_heads", [
@@ -163,7 +166,10 @@ def test_flash_kernels_return_what_the_roofline_reader_matches(
     kernels apart by the result types at the end of each custom call's
     label: a kernel that returns anything its own pattern does not match,
     or that another's matches too, blinds or falsifies the per-layer
-    metric.  At the benchmark cells' shapes."""
+    metric.  At the benchmark cells' shapes.  The one pass's ``flash_bwd``
+    (Yi's shape; PR 54) returns dk, dv and dq and must match NONE of the
+    three patterns: the reader then reads the forward alone there, until a
+    ``benchmark`` PR counts the new kernel (ROADMAP)."""
     import re
 
     import jax.numpy as jnp
@@ -177,14 +183,15 @@ def test_flash_kernels_return_what_the_roofline_reader_matches(
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
         name, _, rest = line.strip().partition(" = ")
-        kernel = re.search(r"flash_(fwd|dq|dkv)", name).group(1)
+        kernel = re.search(r"flash_(fwd|dq|dkv|bwd)", name).group(1)
         assert kernel not in matched, line
         types = re.findall(r"([a-z]+[0-9]+)\[",
                            rest.partition(" custom-call(")[0])
         label = f"{name}<{','.join(types)}>"
         matched[kernel] = [which for which, pattern in KERNELS.items()
                            if re.search(pattern, label)]
-    assert matched == {which: [which] for which in KERNELS}
+    assert matched == ({"fwd": ["fwd"], "bwd": []} if heads == kv_heads
+                       else {which: [which] for which in KERNELS})
 
 
 def test_flash_compiles_at_128k_tokens(one_chip):
@@ -281,9 +288,10 @@ def test_fsdp4_train_step_compiles(fsdp4_step_text):
     import re
 
     text = fsdp4_step_text
-    # Forward, recomputed forward, dq, dk/dv; q and k into the forward
-    # twice and their gradients out once.
-    assert text.count("tpu_custom_call") == 10
+    # Forward, recomputed forward, the one pass of the backward (a key head
+    # a query head here; dq and dk/dv before PR 54); q and k into the
+    # forward twice and their gradients out once.
+    assert text.count("tpu_custom_call") == 9
     # Per-device batch rows x heads reach the kernel, not the global 8.
     assert "bf16[32,2048,128]" in text
     assert "rope_to_heads" in text and "rope_from_heads" in text
@@ -396,7 +404,8 @@ def test_flash_compiles_at_the_cells_shapes_one_kernel_a_name(
     dq and dk/dv are one Mosaic call each under today's names (the
     roofline readers multiply a trace's calls by a call's least time), and
     dk / dv leave in the inputs' dtype, per key head, with no float32
-    array a query head behind them."""
+    array a query head behind them.  With no group to stack (Yi's shape)
+    the backward is the one call ``flash_bwd``, dq its third result."""
     import re
 
     import jax
@@ -417,12 +426,62 @@ def test_flash_compiles_at_the_cells_shapes_one_kernel_a_name(
     suffix = "" if window is None else f"_w{window}"
     names = sorted(re.search(r"flash_[a-z]+(_w\d+)?", c.partition(" = ")[0])
                    .group(0) for c in calls)
-    assert names == sorted(f"flash_{k}{suffix}"
-                           for k in ("fwd", "dq", "dkv"))
-    dkv = next(c for c in calls if "flash_dkv" in c.partition(" = ")[0])
+    one_pass = heads == kv_heads
+    assert names == sorted(f"flash_{k}{suffix}" for k in (
+        ("fwd", "bwd") if one_pass else ("fwd", "dq", "dkv")))
+    dkv = next(c for c in calls
+               if re.search("flash_(dkv|bwd)", c.partition(" = ")[0]))
     results = dkv.partition(" = ")[2].partition(" custom-call(")[0]
     assert re.findall(r"([a-z]+[0-9]+)\[([0-9,]+)\]", results) == [
-        ("bf16", f"{batch * kv_heads},{seq},128")] * 2, results
+        ("bf16", f"{batch * kv_heads},{seq},128")] * 2 + [
+        ("bf16", f"{batch * heads},1,{seq},128")] * one_pass, results
+    # The one pass states no scoped VMEM limit over the default's 16 MiB
+    # (see the test below).
+    limits = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                        r'"offset":"0","size":"(\d+)"', dkv)
+    assert not one_pass or all(int(n) <= 16 * 2 ** 20 for n in limits)
+
+
+def test_yi_one_row_check_program_compiles(topo, as_on_the_chip):
+    """``benchmark/kinds/train.program_loss_and_norm_grads`` on the check's
+    one row, [1, 16, 4096, 128] at the flash calls: what a run of
+    ``yi-coder-1.5b.train-sft4k`` compiles after its window.  With the one
+    pass stating the 56 MiB limit its 1,024 x 1,024 steps would have had
+    from ``_compiler_params``, the TPU compiler's memory-space assignment
+    crashed in this program (a segmentation fault in
+    ``BestFitRepacker::Finish``, here as on the chip; PR 54) while the
+    4-row train step compiled: the kernel needs 11.3 MiB and states none."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    from benchmark import common, weights
+    from benchmark.kinds.train import norms_of, program_loss_and_norm_grads
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+    from ray_tpu.parallel.spmd import make_lm_train_step
+
+    with open(os.path.join(ROOT, "benchmark/configs/yi-coder-1.5b.json")) as f:
+        config = json.load(f)
+    s, seq = weights.sizes_of(config), 4096
+    cfg = common.llama_config(s, seq, **common.train_options(config["train"]))
+    before = get_global_mesh()
+    try:
+        mesh = build_mesh(MeshSpec(), devices=topo.devices[:1])
+        init_fn, _, _ = make_lm_train_step(cfg, mesh,
+                                           param_dtype=jnp.bfloat16)
+        shard, rowsh = common.mesh_shardings(mesh, cfg)
+        w = jax.tree.map(
+            lambda a, sh: _sds(a.shape, a.dtype, sh),
+            jax.eval_shape(init_fn, jax.random.key(0))[0], shard)
+        norms = jax.tree.map(
+            lambda a: _sds(a.shape, jnp.float32, a.sharding), norms_of(w))
+        row = _sds((1, seq), jnp.int32, rowsh)
+        text = jax.jit(program_loss_and_norm_grads(cfg)).lower(
+            norms, w, {"tokens": row, "loss_mask": row}).compile().as_text()
+    finally:
+        set_global_mesh(before)
+    assert "flash_bwd" in text and "flash_dq" not in text
 
 
 def _cell_step(topo, arch, config_file, seq, **replace):
@@ -543,7 +602,8 @@ def test_ouro_train_step_compiles_at_the_cell_sizes(ouro_step, capsys):
     # 8.66 GB beside 5.04 GB in use (PERF.md, PR 34): every pass's stacked
     # gradient lives until the optimizer's fused sum.
     assert mem.temp_size_in_bytes < 15.5e9
-    for name in ("flash_fwd", "flash_dq", "flash_dkv", "loop/0/", "loop/3/",
+    assert "flash_dq" not in text and "flash_dkv" not in text    # PR 54
+    for name in ("flash_fwd", "flash_bwd", "loop/0/", "loop/3/",
                  "block/attn", "block/mlp", "/loss/"):
         assert name in text, name
 
@@ -557,10 +617,12 @@ def test_q_and_k_cross_hbm_once_in_the_dense_step(ouro_step):
 
 
 def _assert_the_flash_kernels_walk_tiles(text, seq=8192, head=192):
-    """Latent attention's three kernels hold several 512 x 512 tiles a grid
-    step (PR 52): each call's table of steps, its scalar-prefetch operand,
-    is the shorter one (24 a head at 8,192 tokens and 8 tiles a step, where
-    a tile a step lists 136), and no kernel of the step, theirs or any
+    """Latent attention's kernels hold several 512 x 512 tiles a grid step
+    (PR 52): each call's table of steps, its scalar-prefetch operand, is
+    the shorter one (24 a head at 8,192 tokens and 8 tiles a step, 40 at
+    the one pass's 4 (PR 54: the call in parts, its backward
+    ``flash_bwd``), where a tile a step lists 136), and no kernel of the
+    step, theirs or any
     other, states a scoped VMEM limit over Mosaic's default 16 MiB: a
     kernel that did hung Xing4.0's step in its first call (ROADMAP S11
     (5))."""
@@ -572,10 +634,12 @@ def _assert_the_flash_kernels_walk_tiles(text, seq=8192, head=192):
     flash = [c for c in calls if "flash_" in c.partition(" = ")[0]]
     assert len(flash) >= 3
     for call in flash:
-        kind = re.match(r"%\S*flash_(fwd|dq|dkv)_", call).group(1)
-        t = A._tiles(kind, seq, seq, head, 1)
+        kind = re.match(r"%\S*flash_(fwd|dq|dkv|bwd)_", call).group(1)
+        t = A._tiles(kind, seq, seq, head, 1,
+                     **({"Dr": 64} if kind == "bwd" else {}))
         steps = A.block_schedule(
-            seq, seq, *t.major, major="k" if kind == "dkv" else "q").shape[1]
+            seq, seq, *t.major, major="q" if t.scores == "qk" else "k"
+        ).shape[1]
         one = A.block_schedule(seq, seq, t.block_q, t.block_k).shape[1]
         assert t.tiles > 1 and steps < one / 3, (kind, t, steps, one)
         assert f"s32[{steps}]" in call.partition("custom-call(")[2][:400], \
@@ -772,8 +836,8 @@ def test_xing4_train_step_compiles_at_the_cell_sizes(xing4_step, capsys):
     assert 5.4e9 < mem.argument_size_in_bytes < 5.6e9
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    for kernel in ("flash_fwd_d192v128", "flash_dq_d192v128",
-                   "flash_dkv_d192v128", "gmm", "tgmm", "hc_collect_n4",
+    for kernel in ("flash_fwd_d192v128", "flash_bwd_d192v128",
+                   "gmm", "tgmm", "hc_collect_n4",
                    "hc_deposit_n4", "hc_deposit_bwd_n4", "hc_pre_bwd_n4",
                    "hc_collect_bwd_n4"):
         assert any(kernel in c.partition(" = ")[0] for c in calls), kernel

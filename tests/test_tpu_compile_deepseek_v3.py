@@ -59,8 +59,8 @@ def test_kanana_train_step_compiles_at_the_cell_sizes(kanana_step, capsys):
     assert 8.1e9 < mem.argument_size_in_bytes < 8.25e9
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    for kernel in ("flash_fwd_d192v128", "flash_dq_d192v128",
-                   "flash_dkv_d192v128", "gmm", "tgmm", "rope_to_heads",
+    for kernel in ("flash_fwd_d192v128", "flash_bwd_d192v128",
+                   "gmm", "tgmm", "rope_to_heads",
                    "rope_from_heads"):
         assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
     # What crosses HBM at a flash call is the parts the projections wrote

@@ -270,15 +270,16 @@ def test_flash_names_and_counts_both_head_sizes(monkeypatch):
         q, k, None, interpret=True) * do), argnums=(0, 1))).lower(q, k)
     tags = [t for name, t in seen
             if name == "ray_tpu_flash_step_geometry_total"]
-    wide, plain, parts = tags[:3], tags[3:6], tags[6:]
-    names = [f"flash_{k}_d192v128" for k in ("fwd", "dq", "dkv")]
+    # a key head a query head on the causal square: the backward is the one
+    # pass (PR 54), two kernels a call and not three
+    wide, plain, parts = tags[:2], tags[2:4], tags[4:]
+    names = [f"flash_{k}_d192v128" for k in ("fwd", "bwd")]
     assert [t["kernel"] for t in wide] == names
     assert all(t["d_qk"] == "192" and t["d_v"] == "128" for t in wide)
     assert not any("parts" in t or "rows" in t for t in wide + plain)
     assert [sorted(t) for t in plain] == [
-        ["block_k", "block_q", "heads_a_step", "kernel", "scores"]] * 3
-    assert [t["kernel"] for t in plain] == ["flash_fwd", "flash_dq",
-                                            "flash_dkv"]
+        ["block_k", "block_q", "heads_a_step", "kernel", "scores"]] * 2
+    assert [t["kernel"] for t in plain] == ["flash_fwd", "flash_bwd"]
     assert [t["kernel"] for t in parts] == names
     assert all(t["parts"] == "128+64" and t["rows"] == "qkvo"
                and t["d_qk"] == "192" and t["d_v"] == "128"
