@@ -1,0 +1,16 @@
+"""Share of the device's busy time in the traced steps that the KDA layers'
+mixers take: every operation traced under the program's ``block/attn/kda``
+scopes (``proj``, ``conv``, ``gate``, ``scan``, ``norm``, ``out``), forward,
+recomputed and backward, as the runner sums them with ``benchmark/scopes.py``.
+The ``[scopes]`` line of a traced run tells the parts apart.  None where the
+runner found no such scope."""
+
+from benchmark import scopes
+
+
+def read(facts):
+    t, arch = facts.get("trace"), facts.get("arch")
+    if not t or not t.get("busy_s") or not arch or not arch.get("scopes"):
+        return None
+    seconds = scopes.seconds_under(arch["scopes"], "block/attn/kda")
+    return 100.0 * seconds / t["busy_s"] if seconds else None

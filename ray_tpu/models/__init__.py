@@ -9,6 +9,7 @@ from .xing4 import Xing4Config, xing4_tiny
 from .deepseek_v3 import DeepseekV3Config, deepseek_v3_tiny
 from .nemotron_h import NemotronHConfig, nemotron_h_tiny
 from .lfm2 import Lfm2Config, lfm2_tiny
+from .bailing_hybrid import BailingHybridConfig, bailing_hybrid_tiny
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
@@ -17,5 +18,6 @@ __all__ = [
     "EvaByteConfig", "evabyte_tiny", "Xing4Config", "xing4_tiny",
     "DeepseekV3Config", "deepseek_v3_tiny",
     "NemotronHConfig", "nemotron_h_tiny", "Lfm2Config", "lfm2_tiny",
+    "BailingHybridConfig", "bailing_hybrid_tiny",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

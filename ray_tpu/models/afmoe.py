@@ -288,7 +288,9 @@ def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20):
         with jax.named_scope("route"):
             routing = moe.sigmoid_routing(
                 h.reshape(B * S, E), layer["router"], bias, cfg.top_k,
-                cfg.route_scale, cfg.route_norm, route_eps)
+                cfg.route_scale, cfg.route_norm, route_eps,
+                # one group, but for ``models/bailing_hybrid.py``
+                getattr(cfg, "n_group", 1), getattr(cfg, "topk_group", 1))
         shared = None
         if "shared_up" in layer:
             with jax.named_scope("shared"):

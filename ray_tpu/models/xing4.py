@@ -307,7 +307,7 @@ def _count_geometry(cfg: Xing4Config, h) -> None:
 
 
 @jax.named_scope("block/attn")
-def _mla(cfg: Xing4Config, cos, sin, h, layer):
+def _mla(cfg: Xing4Config, cos, sin, h, layer, head_gate=None):
     """Latent attention of h [B, S, E] -> [B, S, E].  q and k reach the
     kernels in the parts the projections write (``ops.attention``, a call
     in parts): q's weight (``wq_b`` behind the bottleneck, ``wq`` without
@@ -316,7 +316,10 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer):
     product leaves it, a head's key and value side by side.  Nothing of q's
     size is concatenated, broadcast, turned or sliced.  The four products
     have scopes of their own under ``mla`` (``q``, ``kv_a``, ``kv_b``,
-    ``out``), the rotary passes lie under ``rope``."""
+    ``out``), the rotary passes lie under ``rope``.  ``head_gate`` [E, H]
+    (``models/bailing_hybrid.py``; absent for Xing4.0 and Kanana): head h's
+    result is multiplied by ``sigmoid(h W)_h`` before the out-projection,
+    under ``mla/gate``."""
     dt, eps = cfg.dtype, cfg.norm_eps
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
@@ -345,6 +348,12 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer):
         k_r = rope(kv_a[..., None, rkv:])                   # [B, 1, S, 64]
     o = _attention((q_n, q_r), (kv, k_r), None, causal=True, impl=impl,
                    scale=cfg.softmax_scale)                 # [B, S, H, 128]
+    if head_gate is not None:
+        with jax.named_scope("mla/gate"):
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bse,eh->bsh", h, head_gate.astype(dt),
+                preferred_element_type=jnp.float32))
+            o = o * gate[..., None].astype(dt)
     with jax.named_scope("mla/out"):
         H, D, E = layer["wo"].shape
         return jnp.einsum("bsf,fe->bse", o.reshape(*o.shape[:2], H * D),

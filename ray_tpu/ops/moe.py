@@ -76,15 +76,31 @@ class SigmoidRouting(NamedTuple):
 
 
 def sigmoid_routing(x, router_w, bias, k: int, route_scale: float = 1.0,
-                    route_norm: bool = True, eps=1e-20) -> SigmoidRouting:
+                    route_norm: bool = True, eps=1e-20, n_group: int = 1,
+                    topk_group: int = 1) -> SigmoidRouting:
     """x [T, E], router_w [E, X], bias [X] float32 (the selection bias: state,
     not a parameter).  ``s = sigmoid(x W)`` in float32; the ``k`` experts are
     chosen by ``s + bias`` and weighted by ``s`` alone, normalised over the
     chosen (``route_norm``, by ``sum + eps``) and scaled.  torchtitan's MoE
-    router with ``score_func="sigmoid"``; LFM2's adds 1e-6 to the sum."""
+    router with ``score_func="sigmoid"``; LFM2's adds 1e-6 to the sum.
+
+    With ``n_group`` > 1 the choice is group-limited (DeepSeek-V3's
+    ``noaux_tc``): the X experts are ``n_group`` consecutive groups, a group
+    scores the sum of its two largest ``s + bias``, the ``topk_group`` best
+    groups are kept and the ``k`` chosen inside them.  One group traces the
+    equations it always did."""
     s = jax.nn.sigmoid(jnp.einsum(
         "te,ex->tx", x.astype(jnp.float32), router_w.astype(jnp.float32)))
-    _, top = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
+    choice = s + jax.lax.stop_gradient(bias)
+    if n_group > 1:
+        with jax.named_scope("groups"):
+            by_group = choice.reshape(choice.shape[0], n_group, -1)
+            best = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(best, topk_group)            # [T, g]
+            keep = jnp.any(kept[..., None] == jnp.arange(n_group), axis=1)
+            choice = jnp.where(keep[..., None], by_group, -jnp.inf
+                               ).reshape(choice.shape)
+    _, top = jax.lax.top_k(choice, k)
     # One compare serves the weights and the counts: as a gather and a
     # scatter-add (and the gather's transpose, a second one) they cost 2.2
     # of a call's 2.7 ms in the router on the chip (PERF.md, PR 30).

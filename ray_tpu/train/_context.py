@@ -195,7 +195,9 @@ _LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
               "mtp_loss": "ray_tpu_lm_mtp_loss",
               "hc_sinkhorn_residual": "ray_tpu_hc_sinkhorn_residual",
               # and of a state-space model's chunked scans
-              "ssm_chunk_carry": "ray_tpu_ssm_chunk_carry"}
+              "ssm_chunk_carry": "ray_tpu_ssm_chunk_carry",
+              # and of a delta-rule model's
+              "kda_chunk_carry": "ray_tpu_kda_chunk_carry"}
 #: and of a model's several output heads a position: one sample a head
 _HEAD_KEYS = {"head_loss": "ray_tpu_train_head_loss"}
 #: the tag a gauge's samples are told apart by where it has one a pass or head

@@ -253,6 +253,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "a whole chunk hands on.  It moves if someone "
                        "changes the chunk, drops the carry or starts A_log "
                        "elsewhere."},
+    "ray_tpu_kda_chunk_carry": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean over a hybrid model's Kimi-Delta-Attention "
+                       "layers, chunks, heads and channels of exp(sum of "
+                       "the log-decay g over a chunk) in the last reported "
+                       "step: the share of a delta-rule state's row that a "
+                       "whole chunk hands on.  It moves if someone changes "
+                       "the chunk, the gate's bound or where A_log and "
+                       "dt_bias start."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,
@@ -480,6 +489,23 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "rank (q_lora: none where queries come straight "
                        "from the hidden state), and the rows and tokens "
                        "a row of the call."},
+    "ray_tpu_kda_call_geometry_total": {
+        "type": "counter",
+        "tag_keys": ("heads", "dk", "dv", "chunk", "rows", "seq", "path"),
+        "description": "Calls of the chunked gated delta rule traced "
+                       "(ops/kda.kda, what models/bailing_hybrid.py's KDA "
+                       "layers run), by what the call is: its heads, a "
+                       "head's key and value channels, the chunk's tokens, "
+                       "the rows and tokens a row of the call, and the "
+                       "path it takes (kernel: the Pallas pair, forward "
+                       "and backward; xla: the jnp form)."},
+    "ray_tpu_moe_groups_kept_total": {
+        "type": "counter", "tag_keys": ("n_group", "topk_group"),
+        "description": "Forward passes traced of a model whose routers "
+                       "limit their choice to groups "
+                       "(models/bailing_hybrid.py on "
+                       "ops/moe.sigmoid_routing): the groups the experts "
+                       "form and the groups a token keeps."},
     "ray_tpu_moe_rows_path_total": {
         "type": "counter",
         "tag_keys": ("path", "op", "tokens", "slots", "lanes"),

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import _lm, deepseek_v3, xing4
+from ray_tpu.models import _lm, afmoe, deepseek_v3, xing4
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -420,3 +420,42 @@ def test_xing4_tiny_train_step_s_jaxpr_is_the_parent_s():
                   str(jax.make_jaxpr(step_fn)(params, state, batch)))
     assert (len(text), hashlib.sha256(text.encode()).hexdigest()) == \
         XING4_STEP_AT_THE_PARENT
+
+
+# ------------------- Kanana's and Trinity-Mini's programs are the parent's
+
+#: as ``XING4_STEP_AT_THE_PARENT``, for the tiny train steps of the two other
+#: models that trace the functions PR 55 opened (``xing4._mla``,
+#: ``ops/moe.sigmoid_routing``), taken on its parent commit (6745cf7) with
+#: the script this test repeats
+STEPS_AT_THE_PARENT = {
+    "kanana": (deepseek_v3.deepseek_v3_tiny, (
+        450537,
+        "7867902a0e120a403b80a10878950adc934d3999c0cb243fdc2ee486b993185c")),
+    "trinity": (afmoe.afmoe_tiny, (
+        556643,
+        "2e79d67e010269ae699609fbf749a6130a569fc9d8b9e555e4960013926bfa71")),
+}
+
+
+@pytest.mark.parametrize("model", sorted(STEPS_AT_THE_PARENT))
+def test_a_tiny_train_step_s_jaxpr_is_the_parent_s(model):
+    """Latent attention serves a third model since PR 55 (a head-wise gate
+    that only ``models/bailing_hybrid.py`` hands it) and the router may
+    limit its choice to groups; the program they trace for Kanana (no gate,
+    one group) and for Trinity-Mini (one group) is, equation for equation,
+    the one the parent commit traced."""
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.spmd import make_lm_train_step
+    tiny, at_the_parent = STEPS_AT_THE_PARENT[model]
+    cfg = tiny(experts_held=4, held_start=4, remat=True, layer_rows=1,
+               loss_chunks=4)
+    mesh = build_mesh(MeshSpec(), devices=jax.devices()[:1])
+    init_fn, step_fn, _ = make_lm_train_step(cfg, mesh, learning_rate=1e-3)
+    params, state = jax.eval_shape(init_fn, jax.random.key(0))
+    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32)
+             for k in ("tokens", "loss_mask")}
+    text = re.sub(r"0x[0-9a-f]+", "0x",
+                  str(jax.make_jaxpr(step_fn)(params, state, batch)))
+    assert (len(text), hashlib.sha256(text.encode()).hexdigest()) == \
+        at_the_parent

@@ -612,6 +612,31 @@ class TestTrainPath:
                           "mla/kv_b", "mla/out", "block/moe"):
                 assert _has_scope(text, scope), (type(cfg).__name__, scope)
 
+    def test_the_hybrid_model_s_mixers_have_scopes_of_their_own(self):
+        """``models/bailing_hybrid.py`` (PR 55): the KDA layers' six parts
+        lie under ``block/attn`` as ``kda/proj``, ``kda/conv``, ``kda/gate``,
+        ``kda/scan`` (the op's own), ``kda/norm`` and ``kda/out``; its
+        latent-attention layer keeps ``mla/*`` and gains ``mla/gate``; the
+        router's groups lie under ``block/moe``."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import bailing_hybrid_tiny
+        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+        from ray_tpu.parallel.spmd import make_lm_train_step
+        mesh = build_mesh(MeshSpec(dp=1), jax.devices()[:1])
+        batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+                 for k in ("tokens", "loss_mask")}
+        init_fn, step_fn, _ = make_lm_train_step(bailing_hybrid_tiny(), mesh)
+        text = step_fn.lower(
+            *jax.eval_shape(init_fn, jax.random.key(0)), batch).as_text(
+                debug_info=True)
+        for scope in ("block/attn", "kda/proj", "kda/conv", "kda/gate",
+                      "kda/scan", "kda/norm", "kda/out", "mla", "rope",
+                      "mla/q", "mla/kv_a", "mla/kv_b", "mla/gate", "mla/out",
+                      "block/moe", "groups"):
+            assert _has_scope(text, scope), scope
+
     def test_trainer_spans_reach_the_session_files(self, tmp_path):
         from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 

@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated", "mla")
+              "gated", "mla", "kda")
 
 
 class TestCatalog:
@@ -423,7 +423,10 @@ def _smoke_train_fn(config):
                       # hyper-connections' Sinkhorn residual ride there too.
                       "mtp_loss": 2.0, "hc_sinkhorn_residual": 1e-5,
                       # ssm: the share of a state a chunk hands on
-                      "ssm_chunk_carry": 0.3})
+                      "ssm_chunk_carry": 0.3,
+                      # kda: the share of a delta-rule state's row a chunk
+                      # hands on
+                      "kda_chunk_carry": 0.9})
 
 
 @serve.deployment(name="telemetry_echo")
@@ -518,6 +521,18 @@ class TestSmokeAllSubsystems:
                    jnp.ones((1, 8, 64), jnp.float32),
                    jax.tree.map(lambda a: a[0], xing4.init_params(
                        mla_cfg, jax.random.key(0))["dense"]))
+
+        # -- kda: a traced call of the chunked gated delta rule counts what
+        # it is (the jnp form here), and a model whose routers limit their
+        # choice to groups counts the groups (a forward of the tiny model).
+        from ray_tpu.models import bailing_hybrid
+        from ray_tpu.ops.kda import kda
+        kx = jnp.ones((1, 16, 2, 8), jnp.float32)
+        kda(kx, kx, kx, -0.1 * kx, jnp.ones((1, 16, 2), jnp.float32), 16)
+        bh_cfg = bailing_hybrid.bailing_hybrid_tiny()
+        bailing_hybrid.forward(
+            bailing_hybrid.init_params(bh_cfg, jax.random.key(0)),
+            jnp.zeros((1, 16), jnp.int32), bh_cfg)
 
         # -- eva: a traced EVA kernel counts its table's steps (two
         # windows of 32 in chunks of 8, interpreted here).
