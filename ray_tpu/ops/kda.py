@@ -22,11 +22,18 @@ the state the chunk starts from::
     O = (Q e^G) S_0 + Aqk U
     S_C = Diag(e^{G_C}) S_0 + (K e^{G_C - G})^T U
 
-``(I + A)^{-1}`` of the unit lower-triangular ``C x C`` matrix is built by
-doubling: the inverse of ``[[L11, 0], [L21, L22]]`` is ``[[T11, 0],
-[-T22 L21 T11, T22]]``, so six rounds of two ``C x C`` products take blocks
-of 1 to blocks of 64, each round exact (``_tri_inv``; its cotangent is
-``-T^T dT T^T``).
+``(I + A)^{-1}`` of the unit lower-triangular ``C x C`` matrix is built
+block by block (``_tri_inv``; its cotangent is ``-T^T dT T^T``): the inverse
+of ``[[L11, 0], [L21, L22]]`` is ``[[T11, 0], [-T22 L21 T11, T22]]``, blocks
+of 2 are ``I - A`` with no product, and a round joins blocks of b to blocks
+of 2 b, each round exact (no power of ``A`` is formed).  A round multiplies
+only what it fills: the blocks of a round lie SIDE BY SIDE as ``[m, C]``
+(``_side_by_side``: the sum of the block-diagonal matrix's slabs of m rows),
+and ONE product of those rows with the block-diagonal ``[C, C]`` multiplies
+every block by its own.  At a chunk of 128 the six rounds' two products
+stream 16, 16, 8, 16, 32 and 64 rows (the first two whole sub-blocks: the
+rows a round under half a sublane tile fills are no whole tiles); doubling
+on masked ``C x C`` products streamed 128 rows twice in each of seven.
 
 ``e^{-G_s}`` alone overflows (a chunk of 64 at the gate's bound -5 sums to
 -320), so ``A`` and ``Aqk`` are formed a sub-block of ``SUB`` = 16 rows at a
@@ -61,16 +68,25 @@ last to first, carries the state's cotangent, and takes a chunk's
 cotangents as ``jax.vjp`` of the chunk function INSIDE the kernel body: one
 definition, three uses.  The state, the running sums, ``A``, ``Aqk`` and
 the inverse are float32 at full precision (``Precision.HIGHEST``: six MXU
-passes a product); the five products with the state and with ``U`` take
+passes a product; the running sum three, ``_sum_where``: its mask is exact
+in bfloat16); the five products with the state and with ``U`` take
 their operands in the dtype q, k and v came in (bfloat16 in a training
 step: one pass) and add up in float32, forward and backward (``_dot_as``).
-At ``[1, 8192, 32, 128]`` on a v5e the pair so takes 9.1 ms forward and
-17.0 backward a call in chunks of 128 (10.5 / 17.1 in chunks of 64; with
-float32 operands throughout 10.0 / 19.2 and 11.7 / 20.3, at 1.7e-3 of the
-recurrence for 4.5e-3: the output's own bfloat16 rounding and the
-operands'), 5.7 % of the floor ``benchmark/roofline_kda.scan_passes``
-counts: what is left is the float32 chain, not the rounded products
-(PERF.md section 6, PR 55).  No kernel states ``vmem_limit_bytes``.
+At ``[1, 8192, 32, 128]`` on a v5e the pair takes 5.8 ms forward and 11.2
+backward a call in chunks of 128 (with float32 operands throughout 1.7e-3 of
+the recurrence for 4.6e-3: the output's own bfloat16 rounding and the
+operands'), 8.5 % of the floor ``benchmark/roofline_kda.scan_passes``
+counts.  It took 9.1 / 17.0 ms with the inverse by doubling on masked
+``C x C`` products, the running sum as one six-pass product and ``A`` and
+``Aqk`` as two products a sub-block against all ``C`` keys (PR 55); step 0
+of PR 56 read each change alone on the chip: the inverse block by block
+-1.6 / -1.6 ms, the sum in three passes -0.1 / -0.5, one product a
+sub-block -1.0 / -3.0, the keys up to the diagonal only -0.6 / -0.8 (a
+float32 product of 16 rows costs about half of one of 128: what a product
+loads counts beside what it streams).  The inverse's products in bfloat16
+would take another 1.1 ms off the forward and move every error by a
+twentieth of itself: read, not shipped (PERF.md sections 6 and 7).  No
+kernel states ``vmem_limit_bytes``.
 
 Which path a call took is counted in ``ray_tpu_kda_call_geometry_total``.
 """
@@ -147,19 +163,53 @@ def _iotas(C: int):
     return t, s
 
 
+def _side_by_side(X, m: int, lo: int = 0):
+    """X [C, C], zero outside its diagonal blocks of m -> rows ``lo`` on of
+    the blocks, side by side: [m - lo, C] with block i in columns
+    [i m, (i + 1) m)."""
+    out = X[lo:m]
+    for at in range(m, X.shape[0], m):
+        out = out + X[at + lo:at + m]
+    return out
+
+
+def _on_the_diagonal(L, m: int, x):
+    """``_side_by_side``'s inverse: L [m - lo, C] -> [C, C] with the blocks
+    on the diagonal and rows ``lo`` on of each filled (``x``: row index xor
+    column index)."""
+    C = L.shape[1]
+    if L.shape[0] < m:
+        L = jnp.concatenate([jnp.zeros((m - L.shape[0], C), F32), L], axis=0)
+    if m == C:
+        return L
+    return jnp.where(x < m, jnp.concatenate([L] * (C // m), axis=0), 0.0)
+
+
 @jax.custom_vjp
 def _tri_inv(A):
     """``(I + A)^{-1}`` for A [C, C] strictly lower triangular, C a power of
-    two: blocks of 1, 2, 4, .. C, each round ``T <- T - T A_off T`` with
-    ``A_off`` the blocks under the diagonal that the round joins."""
+    two: blocks of 2 are ``I - A``; a round joins blocks of b to blocks of
+    2 b, ``T21 = -T22 A21 T11``, on the rows it fills alone.  The blocks of
+    ``m = max(2 b, SUB)`` lie side by side as [m, C] (``_side_by_side``), so
+    that ONE product with the block-diagonal [C, C] multiplies every block
+    by its own: a round is two products of ``b`` rows (of SUB under half a
+    sub-block, whose rows a round fills are no whole sublane tiles) where
+    masked full products are two of C, whatever the round joins."""
     C = A.shape[0]
     t, s = _iotas(C)
-    T = (t == s).astype(F32)
-    shift = 0
+    x = t ^ s
+    # under the diagonal ``x >> shift == 1`` says: same block of 2 b, t in
+    # its lower half, s in its upper
+    T = (t == s).astype(F32) - jnp.where(x == 1, A, 0.0)
+    shift = 1
     while (1 << shift) < C:
-        joined = ((t >> (shift + 1)) == (s >> (shift + 1))) \
-            & (((t >> shift) & 1) == 1) & (((s >> shift) & 1) == 0)
-        T = T - _dot(_dot(T, jnp.where(joined, A, 0.0)), T)
+        b = 1 << shift
+        m = min(max(2 * b, SUB), C)
+        lo = b if m == 2 * b else 0
+        A21 = _side_by_side(jnp.where((x >> shift) == 1, A, 0.0), m, lo)
+        P = _on_the_diagonal(_dot(A21, T), m, x)               # A21 T11
+        T21 = _dot(_side_by_side(T, m, lo), P)                  # T22 (A21 T11)
+        T = T - _on_the_diagonal(T21, m, x)
         shift += 1
     return T
 
@@ -177,6 +227,66 @@ def _tri_inv_bwd(T, dT):
 _tri_inv.defvjp(_tri_inv_fwd, _tri_inv_bwd)
 
 
+def _sum_where(mask, x):
+    """``mask . x`` for a 0 / 1 mask [C, C] and x [C, d] float32, at full
+    precision in three MXU passes: the mask is exact in bfloat16 and x is
+    the sum of three bfloat16 parts, so the three one-pass products add up
+    to what six passes would (the other three multiply the mask's zero low
+    parts)."""
+    total = 0.0
+    for _ in range(3):  # ray-tpu: noqa[RT506] (traced once)
+        part = x.astype(jnp.bfloat16).astype(F32)
+        total = total + _dot_as(mask, part, 1, 0, jnp.bfloat16)
+        x = x - part
+    return total
+
+
+@jax.custom_vjp
+def _running_sum(g):
+    """The sum of g [C, d] over the rows up to each row."""
+    t, s = _iotas(g.shape[0])
+    return _sum_where(s <= t, g)
+
+
+def _running_sum_bwd(_, dG):
+    t, s = _iotas(dG.shape[0])
+    return (_sum_where(t <= s, dG),)
+
+
+_running_sum.defvjp(lambda g: (_running_sum(g), None), _running_sum_bwd)
+
+
+def _within(q, k, g, beta):
+    """What a chunk is before it meets the carried state, all float32 at
+    full precision: q (scaled), k, g [C, dk], beta [C, 1] -> (G [C, dk] the
+    running sum of g, A [C, C] under the diagonal, Aqk [C, C] on and under
+    it).  A sub-block's rows of both come from ONE product of 2 SUB rows
+    against the ``cols`` they share, exponentiated as far as the diagonal
+    reaches."""
+    C = q.shape[0]
+    t, s = _iotas(C)
+    G = _running_sum(g)
+    kb = k * beta
+    both = []
+    for lo in range(0, C, SUB):  # ray-tpu: noqa[RT506] (traced once)
+        mid = lo + SUB // 2 - 1
+        R = G[mid:mid + 1]                                          # [1, dk]
+        rows = jnp.exp(G[lo:lo + SUB] - R)
+        # the keys up to the sub-block's last row: the later ones' columns
+        # are over the diagonal
+        hi = lo + SUB
+        cols = k[:hi] * jnp.exp(jnp.minimum(R - G[:hi], _CLAMP))
+        if hi < C:
+            cols = jnp.concatenate([cols, jnp.zeros((C - hi, k.shape[1]),
+                                                    F32)], axis=0)  # [C, dk]
+        both.append(_dot(jnp.concatenate([kb[lo:lo + SUB] * rows,
+                                          q[lo:lo + SUB] * rows], axis=0),
+                         cols, 1, 1))                               # [2 SUB, C]
+    A = jnp.concatenate([b[:SUB] for b in both], axis=0)
+    Aqk = jnp.concatenate([b[SUB:] for b in both], axis=0)
+    return G, jnp.where(s < t, A, 0.0), jnp.where(s <= t, Aqk, 0.0)
+
+
 def _chunk(q, k, v, g, beta, St, scale: float, mm=F32):
     """One chunk of one head: q, k, g [C, dk], v [C, dv], beta [C, 1], all
     float32, ``St`` [dv, dk] the TRANSPOSED state the chunk starts from ->
@@ -186,20 +296,8 @@ def _chunk(q, k, v, g, beta, St, scale: float, mm=F32):
     running sum, ``A``, ``Aqk`` and the inverse are float32 whatever it
     is."""
     C = q.shape[0]
-    t, s = _iotas(C)
     q = q * scale
-    G = _dot((s <= t).astype(F32), g)                    # running sum
-    kb = k * beta
-    A, Aqk = [], []
-    for lo in range(0, C, SUB):  # ray-tpu: noqa[RT506] (traced once)
-        mid = lo + SUB // 2 - 1
-        R = G[mid:mid + 1]                                          # [1, dk]
-        rows = jnp.exp(G[lo:lo + SUB] - R)
-        cols = k * jnp.exp(jnp.minimum(R - G, _CLAMP))              # [C, dk]
-        A.append(_dot(kb[lo:lo + SUB] * rows, cols, 1, 1))
-        Aqk.append(_dot(q[lo:lo + SUB] * rows, cols, 1, 1))
-    A = jnp.where(s < t, jnp.concatenate(A, axis=0), 0.0)
-    Aqk = jnp.where(s <= t, jnp.concatenate(Aqk, axis=0), 0.0)
+    G, A, Aqk = _within(q, k, g, beta)
     e = jnp.exp(G)
     last = G[C - 1:C]                                               # [1, dk]
     U = _dot_as(_tri_inv(A), beta * (v - _dot_as(k * e, St, 1, 1, mm)),

@@ -5,6 +5,7 @@ token-by-token recurrence: forward and five gradients."""
 from __future__ import annotations
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,13 +53,13 @@ def _close(got, want, tol):
 
 
 @pytest.mark.parametrize("gate", ["random", "bound", "none"])
-@pytest.mark.parametrize("form", ["xla16", "xla64", "kernels64"])
+@pytest.mark.parametrize("form", ["xla16", "xla64", "kernels64", "kernels128"])
 def test_forward_and_five_gradients_against_the_recurrence(form, gate):
     """The chunked form equals the recurrence at the gate's bound (the worst
     case of a sub-block's exponentials), with hardly any decay (the longest
     memory) and in between; the kernels take heads of 128 channels."""
     kernels = form.startswith("kernels")
-    C, d = int(form[-2:]), 128 if kernels else 32
+    C, d = int(re.sub(r"\D", "", form)), 128 if kernels else 32
     args, w = inputs(1, 2, 128, 2, d, d, gate)
     with jax.default_matmul_precision("highest"):
         want = recurrence(*args, d ** -0.5)
@@ -125,13 +126,15 @@ def test_a_mesh_of_more_than_one_device_is_refused():
         set_global_mesh(before)
 
 
+@pytest.mark.parametrize("C", [16, 32, 64, 128])
 @pytest.mark.parametrize("case", ["random", "equal_keys"])
-def test_the_triangular_inverse_by_doubling_is_exact(case):
-    """``(I + A)^-1`` block by block; ``equal_keys`` is the worst case of a
-    series in powers of A (every key the same, beta 1, no decay: A all ones
-    under the diagonal, whose powers reach 1e17 at 64), which the doubling
-    does not form."""
-    C = 64
+def test_the_triangular_inverse_block_by_block_is_exact(case, C):
+    """``(I + A)^-1`` from its diagonal blocks of 2, joined a round a
+    doubling (16: a sub-block alone, the rounds under a sublane tile; 128:
+    the chunk that ships, with the three rounds that stream whole tiles);
+    ``equal_keys`` is the worst case of a series in powers of A (every key
+    the same, beta 1, no decay: A all ones under the diagonal, whose powers
+    reach 1e17 at 64), which joining blocks does not form."""
     A = np.tril(np.ones((C, C), np.float32), -1) if case == "equal_keys" \
         else np.tril(np.asarray(jax.random.normal(jax.random.key(7), (C, C))),
                      -1) * 0.3
@@ -145,6 +148,32 @@ def test_the_triangular_inverse_by_doubling_is_exact(case):
         ref = np.tril(-(want.T @ np.asarray(dT, np.float64) @ want.T), -1)
     np.testing.assert_allclose(np.asarray(got), ref,
                                atol=2e-4 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("gate", ["random", "bound", "none"])
+def test_a_chunk_s_own_values_are_float32_at_full_precision(gate):
+    """What a chunk of 128 computes before it meets the carried state, each
+    against ``numpy.float64`` of what it is computed FROM (the running sum
+    from g, ``A`` and ``Aqk`` from that float32 sum, the inverse from that
+    float32 ``A``): 2e-6 of the largest entry, which three bf16 passes in
+    the place of six, or a bf16 operand, break."""
+    C, d = 128, 128
+    (q, k, _, g, beta), _ = inputs(9, 1, C, 1, d, d, gate)
+    q, k, g, beta = (np.asarray(a[0, :, 0]) for a in (q, k, g, beta))
+    with jax.default_matmul_precision("highest"):
+        G, A, Aqk = K._within(*(jnp.asarray(a) for a in (
+            q, k, g, beta[:, None])))
+        T = K._tri_inv(A)
+    near = lambda got, want: np.testing.assert_allclose(
+        np.asarray(got), want, rtol=0, atol=2e-6 * np.abs(want).max())
+    near(G, np.cumsum(g.astype(np.float64), axis=0))
+    G64 = np.asarray(G, np.float64)
+    decay = np.exp(np.minimum(G64[:, None] - G64[None], 0.0))      # [t, s, d]
+    kk = np.einsum("td,sd,tsd->ts", k.astype(np.float64), k, decay)
+    qk = np.einsum("td,sd,tsd->ts", q.astype(np.float64), k, decay)
+    near(A, np.tril(beta.astype(np.float64)[:, None] * kk, -1))
+    near(Aqk, np.tril(qk))
+    near(T, np.linalg.inv(np.eye(C) + np.asarray(A, np.float64)))
 
 
 def test_chunk_carry_is_the_mean_decay_of_a_whole_chunk():
