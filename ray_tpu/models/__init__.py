@@ -10,6 +10,7 @@ from .deepseek_v3 import DeepseekV3Config, deepseek_v3_tiny
 from .nemotron_h import NemotronHConfig, nemotron_h_tiny
 from .lfm2 import Lfm2Config, lfm2_tiny
 from .bailing_hybrid import BailingHybridConfig, bailing_hybrid_tiny
+from .motif import MotifConfig, motif_tiny
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
@@ -19,5 +20,6 @@ __all__ = [
     "DeepseekV3Config", "deepseek_v3_tiny",
     "NemotronHConfig", "nemotron_h_tiny", "Lfm2Config", "lfm2_tiny",
     "BailingHybridConfig", "bailing_hybrid_tiny",
+    "MotifConfig", "motif_tiny",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

@@ -261,26 +261,33 @@ def _attn_half(cfg: AfmoeConfig, full, cos, sin, x, layer):
     return x + rms_norm(out, layer["attn_post_norm"], eps)
 
 
-def _feed_forward(h, w_gate, w_up, w_down, dt, act="silu"):
+def _feed_forward(h, w_gate, w_up, w_down, dt, act="silu", act_weights=None):
     """``act(h W_gate) * (h W_up)`` then ``W_down`` (a SwiGLU where ``act``
     is silu), or with no gate (``w_gate`` None) ``act(h W_up) W_down``: the
-    forms ``ops/moe.dropless_experts`` takes."""
+    forms ``ops/moe.dropless_experts`` takes, as are ``act`` and an
+    activation's own weights (``moe.activation_of``)."""
+    act = moe.activation_of(act, act_weights)
     if w_gate is not None:
-        return _swiglu(h, w_gate, w_up, w_down, dt, moe.ACTIVATIONS[act])
+        return _swiglu(h, w_gate, w_up, w_down, dt, act)
     up = jnp.einsum("bse,em->bsm", h, w_up.astype(dt),
                     preferred_element_type=dt)
-    return jnp.einsum("bsm,me->bse", moe.ACTIVATIONS[act](up),
+    return jnp.einsum("bsm,me->bse", act(up),
                       w_down.astype(dt), preferred_element_type=dt)
 
 
-def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20):
+def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20,
+         act_weights=lambda p: None):
     """F(h) of an expert layer and its loads: (out [B, S, E], {"counts" [X]
     int32 over all the experts, "dropped" int32, "sliced" int32 (1 if the
     call took the buffer in slices), "top" [B*S, k] the router's choices}).
     A layer without ``w_gate`` / ``shared_gate`` has un-gated experts
     (``act(h W_up) W_down``), one without ``shared_up`` no shared expert
     (``models/lfm2.py``); ``cfg`` is any configuration with this one's
-    routing and share fields (``models/xing4.py``, ``models/nemotron_h.py``)."""
+    routing and share fields (``models/xing4.py``, ``models/nemotron_h.py``).
+    ``act_weights(p)``: the keyword arguments of an activation that has
+    weights of its own from a module's ``p`` (``models/motif.py``: PolyNorm's
+    four numbers, ``layer["shared_poly"]`` for the shared expert and
+    ``layer["expert_poly"]`` for the routed ones together)."""
     dt = cfg.dtype
     gate = layer.get("w_gate")
     B, S, E = h.shape
@@ -294,14 +301,16 @@ def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20):
         shared = None
         if "shared_up" in layer:
             with jax.named_scope("shared"):
-                shared = _feed_forward(h, layer.get("shared_gate"),
-                                       layer["shared_up"],
-                                       layer["shared_down"], dt, act)
+                shared = _feed_forward(
+                    h, layer.get("shared_gate"), layer["shared_up"],
+                    layer["shared_down"], dt, act,
+                    act_weights(layer.get("shared_poly")))
         routed, (held, dropped) = moe.dropless_experts(
             h.reshape(B * S, E), routing,
             None if gate is None else gate.astype(dt),
             layer["w_up"].astype(dt), layer["w_down"].astype(dt),
-            held_start=cfg.held_start, impl=cfg.moe_impl, activation=act)
+            held_start=cfg.held_start, impl=cfg.moe_impl, activation=act,
+            act_weights=act_weights(layer.get("expert_poly")))
         routed = routed.reshape(B, S, E).astype(dt)
         return (routed if shared is None else shared + routed,
                 {"counts": routing.counts, "dropped": dropped,

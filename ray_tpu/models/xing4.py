@@ -297,17 +297,31 @@ def _project(h, w, dt):
     return flat.reshape(*h.shape[:2], H, D)
 
 
-def _count_geometry(cfg: Xing4Config, h) -> None:
-    """What a traced call of latent attention is, for ``counters.json``."""
+def _count_geometry(cfg, h) -> None:
+    """What a traced call of latent attention is, for ``counters.json``; a
+    configuration with fewer key heads than query heads, noise heads or a
+    window (``models/motif.py``) says so in tags of its own."""
+    more = {tag: str(getattr(cfg, field)) for tag, field in (
+        ("kv_heads", "kv_heads"), ("noise_heads", "num_noise_heads"),
+        ("window", "sliding_window")) if hasattr(cfg, field)}
     telemetry.inc("ray_tpu_mla_call_geometry_total", tags={
         "heads": str(cfg.heads), "dn": str(cfg.qk_nope_head_dim),
         "dr": str(cfg.qk_rope_head_dim), "dv": str(cfg.v_head_dim),
         "q_lora": str(cfg.q_lora_rank or "none"),
-        "rows": str(h.shape[0]), "seq": str(h.shape[1])})
+        "rows": str(h.shape[0]), "seq": str(h.shape[1]), **more})
+
+
+def _kernels(cfg, window=None):
+    """The call in parts of a layer's attention kernels: (q's parts, k's
+    parts) -> o [B, S, H, Dv], full-causal or inside ``window``."""
+    impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
+    return lambda q, k: _attention(q, k, None, causal=True, impl=impl,
+                                   scale=cfg.softmax_scale, window=window)
 
 
 @jax.named_scope("block/attn")
-def _mla(cfg: Xing4Config, cos, sin, h, layer, head_gate=None):
+def _mla(cfg: Xing4Config, cos, sin, h, layer, head_gate=None, attend=None,
+         after=None):
     """Latent attention of h [B, S, E] -> [B, S, E].  q and k reach the
     kernels in the parts the projections write (``ops.attention``, a call
     in parts): q's weight (``wq_b`` behind the bottleneck, ``wq`` without
@@ -319,7 +333,12 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer, head_gate=None):
     ``out``), the rotary passes lie under ``rope``.  ``head_gate`` [E, H]
     (``models/bailing_hybrid.py``; absent for Xing4.0 and Kanana): head h's
     result is multiplied by ``sigmoid(h W)_h`` before the out-projection,
-    under ``mla/gate``."""
+    under ``mla/gate``.  The key heads are ``wkv_b``'s, as many as the query
+    heads or a divisor of them (a key head then serves a group of query
+    heads).  ``attend``: what stands for the kernels' call (``_kernels``:
+    full-causal; ``models/motif.py`` hands a window's, or both under a
+    ``lax.cond``); ``after(h, o)``: what follows them before the
+    out-projection, whose ``wo`` takes as many heads as it leaves."""
     dt, eps = cfg.dtype, cfg.norm_eps
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     impl = None if cfg.attention_impl == "auto" else cfg.attention_impl
@@ -346,8 +365,9 @@ def _mla(cfg: Xing4Config, cos, sin, h, layer, head_gate=None):
                                       interpret=impl == "flash_interpret")
         q_r = rope(q_r)                                     # [B, H, S, 64]
         k_r = rope(kv_a[..., None, rkv:])                   # [B, 1, S, 64]
-    o = _attention((q_n, q_r), (kv, k_r), None, causal=True, impl=impl,
-                   scale=cfg.softmax_scale)                 # [B, S, H, 128]
+    o = (attend or _kernels(cfg))((q_n, q_r), (kv, k_r))    # [B, S, H, 128]
+    if after is not None:
+        o = after(h, o)
     if head_gate is not None:
         with jax.named_scope("mla/gate"):
             gate = jax.nn.sigmoid(jnp.einsum(
@@ -366,6 +386,7 @@ def _sublayer(cfg: Xing4Config, X, layer, name: str, F):
     X [B, n, S, C]; ``F`` returns (y, what it reports).  -> (X, the report,
     the largest |row or column sum - 1| of H_res)."""
     norm = layer[f"{name}_norm"]
+    clamp = getattr(cfg, "hidden_clamp", None)
     if cfg.hc_mult == 1:
         y, aux = F(rms_norm(X[:, 0], norm, cfg.norm_eps))
         return X + y[:, None], aux, jnp.zeros((), jnp.float32)
@@ -374,6 +395,8 @@ def _sublayer(cfg: Xing4Config, X, layer, name: str, F):
         X, layer[f"hc_{name}_phi"], layer[f"hc_{name}_b"],
         layer[f"hc_{name}_alpha"], cfg.hc_sinkhorn_iters, cfg.hc_eps,
         cfg.hc_clamp, cfg.norm_eps, interpret=interpret)
+    if clamp:       # ``models/motif.py``: the collected input, clipped
+        u = jnp.clip(u, -clamp, clamp)
     y, aux = F(rms_norm(u, norm, cfg.norm_eps))
     return (hyper.deposit(X, H_res, H_post, y, interpret=interpret), aux,
             jax.lax.stop_gradient(hyper.sinkhorn_residual(H_res)))
@@ -396,11 +419,22 @@ def _layer(cfg: Xing4Config, cos, sin, X, layer, bias=None):
     return X, {**loads, "hc_residual": jnp.maximum(r_attn, r_mlp)}
 
 
-def _run(cfg: Xing4Config, cos, sin, X, layer, bias=None):
+#: how a layer's report over its groups of rows (leading axis) becomes one;
+#: what is not named here is a mean
+_MERGE = {"hc_residual": jnp.max,
+          "counts": lambda a: jnp.sum(a, axis=0), "dropped": jnp.sum,
+          "sliced": jnp.sum,
+          "top": lambda a: a.reshape(-1, a.shape[-1])}
+
+
+def _run(cfg, cos, sin, X, layer, bias=None, layer_fn=None):
     """The layer under the remat, ``layer_rows`` rows at a time (as
-    ``afmoe``'s): (X, the layer's report summed over its groups)."""
-    one = _lm.remat(lambda X, layer, bias: _layer(cfg, cos, sin, X, layer,
-                                                  bias), cfg.remat)
+    ``afmoe``'s): (X, the layer's report merged over its groups).
+    ``layer_fn``: this module's ``_layer``, or another model's with its
+    arguments (``models/motif.py``)."""
+    layer_fn = layer_fn or _layer
+    one = _lm.remat(lambda X, layer, bias: layer_fn(cfg, cos, sin, X, layer,
+                                                    bias), cfg.remat)
     B = X.shape[0]
     n = min(cfg.layer_rows or B, B)
     if B % n:
@@ -410,12 +444,11 @@ def _run(cfg: Xing4Config, cos, sin, X, layer, bias=None):
         return one(X, layer, bias)
     Y, report = jax.lax.map(lambda rows: one(rows, layer, bias),
                             X.reshape((B // n, n) + X.shape[1:]))
-    merged = {"hc_residual": jnp.max(report["hc_residual"])}
-    if bias is not None:
-        merged |= {"counts": jnp.sum(report["counts"], axis=0),
-                   "dropped": jnp.sum(report["dropped"]),
-                   "sliced": jnp.sum(report["sliced"]),
-                   "top": report["top"].reshape(-1, cfg.top_k)}
+    # (the named ones first and in their order: the program traced for the
+    # models without a report of their own is then the one it always was)
+    merged = {k: _MERGE.get(k, jnp.mean)(report[k])
+              for k in (*(k for k in _MERGE if k in report),
+                        *(k for k in report if k not in _MERGE))}
     return Y.reshape(X.shape), merged
 
 
@@ -494,9 +527,11 @@ def mtp_targets_and_mask(targets, mask):
     return shift(targets), shift(mask)
 
 
-def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg: Xing4Config):
+def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg,
+              layer_fn=None):
     """(the module's masked mean loss, its layer's report): ``x_out``
-    [B, S, C] is the stack's result before the final norm."""
+    [B, S, C] is the stack's result before the final norm; ``layer_fn`` as
+    ``_run``'s."""
     dt, eps, p = cfg.dtype, cfg.norm_eps, params["mtp"]
     with jax.named_scope("mtp"):
         with jax.named_scope("project"):
@@ -508,7 +543,8 @@ def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg: Xing4Config):
             z = jnp.einsum("bsf,fe->bse", pair, p["proj"].astype(dt),
                            preferred_element_type=dt)
         Z, report = _run(cfg, *tables, _lanes(z, cfg.hc_mult),
-                         jax.tree.map(lambda a: a[0], p["layer"]), bias)
+                         jax.tree.map(lambda a: a[0], p["layer"]), bias,
+                         layer_fn)
         h = rms_norm(_collapse(Z), p["final_norm"], eps)
         targets2, mask2 = mtp_targets_and_mask(targets, mask)
         with jax.named_scope("loss"):
@@ -517,14 +553,17 @@ def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg: Xing4Config):
         return total / jnp.maximum(jnp.sum(mask2), 1.0), report
 
 
-def loss_and_report(params, batch, cfg: Xing4Config, state=None):
+def loss_and_report(params, batch, cfg, state=None, stack=None,
+                    mtp_layer_fn=None):
     """What the train step differentiates (parallel.spmd): the loss ``main +
     mtp_loss_weight * module's``, and what ``update_state`` turns into the
     step's metrics: both losses, the expert layers' loads (the module's
-    layer last), the largest Sinkhorn residual."""
+    layer last), the largest Sinkhorn residual.  ``stack``: this module's
+    ``_forward_hidden`` or another model's of the same results, and
+    ``mtp_layer_fn`` its module's layer (``models/motif.py``)."""
     state = state or init_state(cfg)
-    x, loads, residual, tables = _forward_hidden(params, state,
-                                                 batch["tokens"], cfg)
+    x, loads, residual, tables = (stack or _forward_hidden)(
+        params, state, batch["tokens"], cfg)
     targets, mask, denom = _lm.targets_and_mask(batch)
     with jax.named_scope("final_norm"):
         h = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -537,7 +576,7 @@ def loss_and_report(params, batch, cfg: Xing4Config, state=None):
         # backward's operations too (models/ouro._scoped has the reason).
         mtp, report = jax.jit(
             lambda params, bias, x, tables, targets, mask: _mtp_loss(
-                params, bias, x, tables, targets, mask, cfg))(
+                params, bias, x, tables, targets, mask, cfg, mtp_layer_fn))(
             params, state["bias"][-1], x, tables, targets, mask)
         residual = jnp.maximum(residual, report.pop("hc_residual"))
         loads = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),

@@ -75,6 +75,14 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   (``_one_part``).  The geometry counter tags such kernels ``parts="128+64"``,
   ``rows="qkvo"`` (PERF.md, PR 50: the kernels alone cost 3.7 % more so,
   and Xing4.0's step is 58 ms of 1,177 shorter for what left it round them).
+  ``kv`` may hold fewer key heads than q has heads, [B, Sk, Hkv, Dn + Dv]
+  with ``H % Hkv == 0`` (PR 57: latent keys decompressed into 16 key heads
+  under 80 query heads): query head ``h`` reads key head ``h // (H //
+  Hkv)`` through the same index maps a grouped one-part call uses, dk/dv
+  add up a group's query heads in float32 (inside the kernel where a step
+  stacks the group, else as float32 shares summed outside) and the rotary
+  head's gradient all the heads; with ``H == Hkv`` a call traces what it
+  traced before.
 - **A grid step may hold several tiles** (``Tiles.tiles``; a head size
   over 128, latent attention's 192 / 128: eight).  The pipeline fetches a
   *major block* of the streamed operands, ``tiles`` blocks long (keys and
@@ -348,6 +356,9 @@ _BIG_BLOCK = 1024       # no group to stack and no window: 1,024 x 1,024
 _MAX_HEADS = 8          # query heads a step stacks: 4,096 rows at 512 each
 _FWD_SCORES = 2 ** 20   # the forward's score tile, elements: halves block_k
 _WALK = (8, 4, 2, 1)    # head size over 128: 512 x 512 tiles a grid step
+# Head size over 128, a window narrower than a block and a group to stack:
+# (q rows, keys) of forward's and dq's tile; dk/dv's is the other way round.
+_BAND = (128, 256)
 # The one pass (``bwd``): the bytes of float32 dq a grid row may keep in VMEM
 # beside dk/dv's walk, and the tiles a step of it walks at the most.  From
 # the chip's table (PERF.md, PR 54, step 0; ms a call of the backward / the
@@ -398,6 +409,32 @@ def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
       step: whether Yi's 1,024 x 1,024 (a quarter of each diagonal block
       computed for nothing) or the stacked groups want the walk is not
       measured.
+    - A head size over 128 under a GROUP (latent keys decompressed into
+      fewer key heads than query heads: 80 over 16, five a key head), from
+      the chip's table (PERF.md, PR 57, step 0; the call in parts at
+      [1, 80 / 16, 8192], ms a call of the three kernels / of everything
+      round them too / the most scoped VMEM one of them holds).  **No
+      window**: a head a grid row as without a group, the forward and dq at
+      8 tiles a step reading their key head's blocks, and the one pass with
+      each query head's share of dk/dv leaving in float32 and summed
+      outside: 46.72 / 51.34 / 13.6 MiB, against the pair 62.22 / 66.93 /
+      10.0 and the pair with the group's five heads added up inside dk/dv
+      59.51 / 60.93 / 13.1; the forward with the five stacked (128 x 256)
+      17.74 for 14.89.  **A window narrower than a block** (128): the band
+      inside 512 x 512 tiles computes eight times what it keeps and a head
+      a step pays a grid step's costs 31 times a head for it, 16.89 /
+      21.60 / 4.7; 256 x 256 15.18 / 19.88, 128 x 128 23.72 / 28.42.  With
+      the group's heads one grid step (the most that divide it, up to 8:
+      their rows stacked behind each tile of keys in forward and dq, added
+      into the one resident block in dk/dv, which then leaves a key head
+      in the inputs' dtype) 128 x 128 reads 9.18 / 10.57 / 3.1, 256 x 128
+      9.53 / 10.92 / 6.2 and 128 x 256 **8.46 / 9.85 / 3.6**; dk/dv alone
+      2.80 at 256 x 128 for 3.27 - 3.30.  So forward and dq take 128 x 256
+      and dk/dv 256 x 128, a stacked step's score tile inside the 512 x
+      512 elements past which ``_compiler_params`` would state a limit.  A
+      window of a block or more, and one with no group to stack, keep 512 x
+      512 and a head a step (256 x 256 is 8 % faster there: not shipped,
+      no caller).
     - A head size under 128 (LFM2's 64) takes the answers of 128: at
       [4, 32 / 8, 8192, 64] the three kernels read 68.9 ms at 512 x 512 with
       the group's 4 heads stacked, 70.7 - 71.8 with either block at 1,024
@@ -423,13 +460,19 @@ def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
         return t._replace(tiles=min(t.tiles, _BWD_WALK))
     scores = "kq" if kind == "dkv" else "qk"
     block_q, block_k, heads = min(_BLOCK, Sq), min(_BLOCK, Sk), 1
+    # the most of a key head's query heads that a step could hold
+    stacked = max(h for h in range(1, min(group, _MAX_HEADS) + 1)
+                  if group % h == 0)
     if D > LANES:
+        if window is not None and window < _BLOCK and stacked > 1:
+            tall, wide = (_BAND[1], _BAND[0]) if kind == "dkv" else _BAND
+            if Sq % tall == 0 and Sk % wide == 0:
+                return Tiles(tall, wide, stacked, scores)
         streamed = Sq // block_q if kind == "dkv" else Sk // block_k
         tiles = 1 if window is not None else next(
             n for n in _WALK if streamed % n == 0)
         return Tiles(block_q, block_k, heads, scores, tiles)
-    heads = max(h for h in range(1, min(group, _MAX_HEADS) + 1)
-                if group % h == 0)
+    heads = stacked
     if heads > 1:
         if (kind == "fwd" and heads * block_q * block_k > _FWD_SCORES
                 and block_k % (2 * LANES) == 0):
@@ -459,16 +502,18 @@ def _dims(q, k, v):
     (``flash_attention``) q's and k's first lie as rows, and k's holds v."""
     if _in_parts(q):
         (q_n, q_r), (kv, k_r) = q, k
-        (B, Sq, H, Dn), Sk, Dr = q_n.shape, kv.shape[1], q_r.shape[-1]
+        (B, Sq, H, Dn), (Sk, Hkv), Dr = q_n.shape, kv.shape[1:3], \
+            q_r.shape[-1]
         if (v is not None or q_r.shape != (B, H, Sq, Dr)
-                or kv.shape[:3] != (B, Sk, H) or kv.shape[3] <= Dn
+                or kv.shape[:2] != (B, Sk) or H % Hkv or kv.shape[3] <= Dn
                 or k_r.shape != (B, 1, Sk, Dr)):
             raise ValueError(
                 f"a call in parts takes q ([B, Sq, H, Dn], [B, H, Sq, Dr]), "
-                f"k ([B, Sk, H, Dn + Dv], [B, 1, Sk, Dr]) and no v: got "
+                f"k ([B, Sk, Hkv, Dn + Dv] with H % Hkv == 0, [B, 1, Sk, "
+                f"Dr]) and no v: got "
                 f"{[x.shape for x in (q_n, q_r, kv, k_r)]} and v "
                 f"{None if v is None else v.shape}")
-        return Dims(B, H, H, Sq, Sk, Dn + Dr, kv.shape[3] - Dn, Dr)
+        return Dims(B, H, Hkv, Sq, Sk, Dn + Dr, kv.shape[3] - Dn, Dr)
     (B, H, Sq, D), (Hkv, Sk) = q.shape, k.shape[1:3]
     if H % Hkv:
         raise ValueError(f"H={H} not divisible by Hkv={Hkv}")
@@ -635,13 +680,13 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
-    if parts:
-        (q_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref, m_scr, l_scr,
-         acc_scr) = refs
-        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    *_, m_scr, l_scr, acc_scr = refs
     heads = m_scr.shape[0] // block_q
+    if parts:
+        q_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref = refs[:-3]
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1] // heads)
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs[:-3]
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
@@ -898,11 +943,12 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
     if parts:
         (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
          dq_ref, dqr_ref, dq_scr, dqr_scr, lse_scr, di_scr) = refs
-        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr,
          lse_scr, di_scr) = refs
     heads = lse_ref.shape[1]
+    if parts:
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1] // heads)
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
@@ -978,13 +1024,14 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
     if parts:
         (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
          dkv_ref, dkr_ref, *dq, dk_scr, dkr_scr, dv_scr) = refs
-        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1])
-        dk_ref, dv_ref = _k_and_v(dkv_ref, q_ref.shape[-1])
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
          *dq, dk_scr, dv_scr) = refs
     dq_refs, dq_scrs = dq[:len(dq) // 2], dq[len(dq) // 2:]
     heads = lse_ref.shape[1]
+    if parts:
+        k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1] // heads)
+        dk_ref, dv_ref = _k_and_v(dkv_ref, q_ref.shape[-1] // heads)
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
@@ -1165,19 +1212,25 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     # step's heads in float32, summed over the group below.
     shares = H // Hkv // t.heads
     if Dr:
-        # A grid row is a head (``_tiles``: a head a step over 128, and no
-        # group in parts).  Its share of the one rotary key head's gradient
-        # leaves in the inputs' dtype, as a 192-wide dk's lanes did, and
-        # the heads are summed here.
+        # A grid row is ``t.heads`` query heads of one key head.  Its share
+        # of the one rotary key head's gradient leaves in the inputs' dtype,
+        # as a 192-wide dk's lanes did, and the rows are summed here; where
+        # a key head's query heads are several grid rows (a group that no
+        # step stacks whole), their shares of ``kv``'s gradient leave in
+        # float32 side by side and are summed here too.
         dkv, dk_r, *one = call(
             kind, _dkv_kernel, t, "k",
-            {**dict.fromkeys(("dkv", "dk_r"), dtype), **dq_outs},
+            {"dkv": jnp.float32 if shares > 1 else dtype, "dk_r": dtype,
+             **dq_outs},
             [*dq_scratch,
              *(_vmem((t.block_k, d), jnp.float32) for d in (D - Dr, Dr, Dv))],
             tile)
         dq = one or dq
-        dk_r = dk_r.reshape(B, H, Sk, Dr).sum(
+        dk_r = dk_r.reshape(B, H // t.heads, Sk, Dr).sum(
             axis=1, keepdims=True, dtype=jnp.float32).astype(dtype)
+        if shares > 1:
+            dkv = dkv.reshape(B, Sk, Hkv, shares, D - Dr + Dv).sum(
+                axis=3).astype(dtype)
         return ((dq[0].reshape(q[0].shape), dq[1].reshape(q[1].shape)),
                 (dkv.reshape(k[0].shape), dk_r), None)
     dk, dv, *one = call(
@@ -1278,7 +1331,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     one rotary key head every query head's.  The result is [B, Sq, H, Dv]
     (``rows``, which a call in parts implies).  The gradients come back in
     the same parts, k_r's summed over the heads.  The default scale is
-    ``(Dn + Dr) ** -0.5``.  The kernels take the parts as they are where
+    ``(Dn + Dr) ** -0.5``.  ``kv`` may hold ``Hkv`` key heads with ``H %
+    Hkv == 0``: query head h reads key head ``h // (H // Hkv)``, and dk/dv
+    come back a key head.  The kernels take the parts as they are where
     ``Dn`` and ``Dv`` are multiples of 128; any other such call is put
     together here (``_one_part``) and goes the way of a 192-wide one.
 

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from ..util import telemetry
+from .norms import poly_norm
 
 
 class SigmoidRouting(NamedTuple):
@@ -629,19 +630,34 @@ def _tokens_bwd(res, g):
 tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
 
 
-#: an expert's activation, by the name a model's configuration gives it
+#: an expert's activation, by the name a model's configuration gives it;
+#: ``poly_norm`` has weights of its own (``activation_of``)
 ACTIVATIONS = {"silu": jax.nn.silu,
-               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+               "relu2": lambda x: jnp.square(jax.nn.relu(x)),
+               "poly_norm": poly_norm}
+
+
+def activation_of(name: str, weights=None):
+    """The activation ``name`` as a function of the rows alone; one with
+    weights of its own closes over them (``weights``: its keyword
+    arguments, arrays and numbers: ``poly_norm``'s ``p``, ``scale``,
+    ``clamp``, ``eps``), and autodiff hands them their gradient."""
+    act = ACTIVATIONS[name]
+    return act if weights is None else functools.partial(act, **weights)
 
 
 def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
-               activation="silu", experts=None):
+               activation="silu", experts=None, act_weights=None):
     """The held experts' part for tokens xt [T, E] in a buffer of ``rows``
     rows: (out [T, E], rows in use).  Right whenever the assignments to held
     experts number at most ``rows``.  ``w_gate`` None: an un-gated expert.
     ``experts``: all X the router chose among, so that a held one expects
-    T * k / X rows (None: the buffer's rows over the held)."""
-    Xh, act = w_up.shape[0], ACTIVATIONS[activation]
+    T * k / X rows (None: the buffer's rows over the held).  An activation
+    with weights (``act_weights``) sums its weights' gradient over the
+    buffer's rows, and the rows no group holds are unspecified on both
+    sides of a grouped product: they are zeroed before it and after it, so
+    that neither they nor their cotangent reach that sum."""
+    Xh, act = w_up.shape[0], activation_of(activation, act_weights)
     expected = top.size / experts if experts else None
     with jax.named_scope("dispatch"):
         local = top - held_start
@@ -651,10 +667,12 @@ def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
     with jax.named_scope("experts"):
         mm = functools.partial(grouped_matmul, group_sizes=at.sizes,
                                impl=impl, rows_a_group=expected)
+        held = (lambda a: a) if act_weights is None else (
+            lambda a: jnp.where(at.live, a, 0))
         if w_gate is None:
-            h = act(mm(x_rows, w_up))
+            h = held(act(held(mm(x_rows, w_up))))
         else:
-            h = act(mm(x_rows, w_gate)) * mm(x_rows, w_up)
+            h = held(act(held(mm(x_rows, w_gate))) * held(mm(x_rows, w_up)))
         y_rows = mm(h, w_down)
     return tokens_from_rows(y_rows, w.astype(jnp.float32), at), used
 
@@ -668,14 +686,16 @@ def buffer_rows(T: int, k: int) -> int:
 
 def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
                      held_start: int = 0, impl: Optional[str] = None,
-                     activation: str = "silu"):
+                     activation: str = "silu", act_weights=None):
     """``sum_j w[t, j] * Expert_{top[t, j]}(xt[t])`` over the assignments
     to the experts held here, ``held_start <= e < held_start + Xh``; every
     such assignment is computed, whatever the imbalance.
 
     xt [T, E]; w_gate / w_up [Xh, E, M], w_down [Xh, M, E].  An expert is
     ``act(x W_gate) * (x W_up)``, then ``W_down``, with ``act`` the
-    ``activation`` named (``silu``, ``relu2``); with ``w_gate`` None it has
+    ``activation`` named (``silu``, ``relu2``; ``poly_norm`` with its
+    weights in ``act_weights``, one set for all the held experts, which get
+    their gradient); with ``w_gate`` None it has
     no gate, ``act(x W_up) W_down``: two grouped products a row for three.
     Returns (out [T, E], stats) with ``stats = (held, dropped)``: the
     assignments to held experts and those of them not computed (identically
@@ -710,7 +730,8 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
                             w_down=w_down, held_start=held_start, rows=rows,
                             impl=impl, activation=activation,
-                            experts=routing.counts.shape[0])
+                            experts=routing.counts.shape[0],
+                            act_weights=act_weights)
     if rows == T * k:
         out, used = run(xt, top, w)
         return out, (held, held - used)

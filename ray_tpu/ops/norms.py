@@ -16,6 +16,10 @@ row-major (``with_layout_constraint``): one hint, which turns the stream
 round everywhere it goes.  bf16 streams, which every other model carries,
 are left to the compiler and compile to what they compiled to.  Which way a
 call went is counted in ``ray_tpu_norm_path_total``.
+
+``poly_norm`` is PolyNorm (arXiv:2411.03884), an activation with weights of
+its own: three powers of its input, each normed over the width, under three
+learned weights and one bias.
 """
 
 from __future__ import annotations
@@ -44,3 +48,21 @@ def rms_norm(x, weight, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     normed = x32 * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(dtype)
+
+
+def poly_norm(x, p, scale: float = 1.0, clamp: float = None,
+              eps: float = 1e-6):
+    """PolyNorm over the last axis: ``scale * (p[0] n(x^3) + p[1] n(x^2) +
+    p[2] n(x) + clip(p[3], -clamp, clamp))`` with ``n(y) = y / rms(y)`` (the
+    mean over the width, ``eps`` under the root); ``p`` [4] holds the three
+    weights and the bias.  Float32 inside, the input's dtype out; the
+    gradients of x and p are autodiff's of these lines."""
+    with jax.named_scope("polynorm"):
+        x32, p = x.astype(jnp.float32), p.astype(jnp.float32)
+        bias = p[3] if clamp is None else jnp.clip(p[3], -clamp, clamp)
+        out, power = bias, x32
+        for weight in (p[2], p[1], p[0]):
+            out = out + weight * power * jax.lax.rsqrt(
+                jnp.mean(jnp.square(power), axis=-1, keepdims=True) + eps)
+            power = power * x32
+        return (scale * out).astype(x.dtype)

@@ -197,7 +197,9 @@ _LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
               # and of a state-space model's chunked scans
               "ssm_chunk_carry": "ray_tpu_ssm_chunk_carry",
               # and of a delta-rule model's
-              "kda_chunk_carry": "ray_tpu_kda_chunk_carry"}
+              "kda_chunk_carry": "ray_tpu_kda_chunk_carry",
+              # and of a differential attention's pair
+              "gdla_lambda_mean": "ray_tpu_gdla_lambda_mean"}
 #: and of a model's several output heads a position: one sample a head
 _HEAD_KEYS = {"head_loss": "ray_tpu_train_head_loss"}
 #: the tag a gauge's samples are told apart by where it has one a pass or head

@@ -262,6 +262,13 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "whole chunk hands on.  It moves if someone changes "
                        "the chunk, the gate's bound or where A_log and "
                        "dt_bias start."},
+    "ray_tpu_gdla_lambda_mean": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean over tokens, signal heads and layers of "
+                       "sigmoid(lambda), the weight a differential "
+                       "attention's noise head is subtracted with "
+                       "(models/motif.py), in the last reported step: 0 "
+                       "or 1 says the pair is dead."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,
@@ -480,15 +487,21 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "expected to hold."},
     "ray_tpu_mla_call_geometry_total": {
         "type": "counter",
-        "tag_keys": ("heads", "dn", "dr", "dv", "q_lora", "rows", "seq"),
+        "tag_keys": ("heads", "dn", "dr", "dv", "q_lora", "rows", "seq",
+                     "kv_heads", "noise_heads", "window"),
         "description": "Calls of latent attention traced "
-                       "(models/xing4._mla, which models/deepseek_v3.py "
-                       "runs too), by what the call is: its heads, a "
+                       "(models/xing4._mla, which models/deepseek_v3.py, "
+                       "models/bailing_hybrid.py and models/motif.py run "
+                       "too), by what the call is: its heads, a "
                        "head's channels without position (dn), rotary "
                        "(dr) and of values (dv), the query bottleneck's "
                        "rank (q_lora: none where queries come straight "
                        "from the hidden state), and the rows and tokens "
-                       "a row of the call."},
+                       "a row of the call; where the model has fewer key "
+                       "heads than query heads, noise heads whose result "
+                       "is subtracted or a window on some layers "
+                       "(models/motif.py), kv_heads, noise_heads and "
+                       "window say so, and are absent otherwise."},
     "ray_tpu_kda_call_geometry_total": {
         "type": "counter",
         "tag_keys": ("heads", "dk", "dv", "chunk", "rows", "seq", "path"),

@@ -226,7 +226,8 @@ def test_rows_no_group_holds_reach_neither_result_nor_gradient(monkeypatch):
 
 
 def _scattered_held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows,
-                         impl, activation="silu", experts=None):
+                         impl, activation="silu", experts=None,
+                         act_weights=None):
     """``ops/moe._held_rows`` as it was before the pair (PR 29): a stable
     sort of the assignments by held expert, ``xt[tok]`` into the buffer and
     ``.at[tok].add`` out of it."""

@@ -212,9 +212,11 @@ def test_a_traced_call_counts_its_geometry(monkeypatch):
             cfg, *rope_lane_tables(64, 64), h, layer),
             jax.ShapeDtypeStruct((3, 32, 64), jnp.float32))
     keys = telemetry.CATALOG["ray_tpu_mla_call_geometry_total"]["tag_keys"]
-    assert [tuple(t[k] for k in keys) for t in seen] == [
-        ("2", "128", "64", "128", "none", "3", "32"),
-        ("2", "128", "64", "128", "48", "3", "32")]
+    # (the tags a model with key heads, noise heads or a window adds,
+    # ``models/motif.py``, are absent: these stacks keep their series)
+    assert [tuple(t.get(k) for k in keys) for t in seen] == [
+        ("2", "128", "64", "128", "none", "3", "32", None, None, None),
+        ("2", "128", "64", "128", "48", "3", "32", None, None, None)]
 
 
 # ----------------------------------------------- the selection bias, shares

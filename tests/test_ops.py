@@ -574,10 +574,21 @@ ONE_PASS = {
 ONE_PASS_IN_PARTS = {
     "latent_8192": (512, 512, 1, 4),            # 4 + 2 MiB: both latent cells
     "latent_1024": (512, 512, 1, 2),
+    "latent_group5": (512, 512, 1, 4),          # a head a row under a group
+    "latent_group5_window": None,
     "latent_window": None,
     "latent_ring_shard": None,
 }
 TILES.update({
+    # a group under a head size over 128 (PR 57): a head a row and the walk
+    # without a window; under a window narrower than a block the group's
+    # five heads one step, 128 x 256 (dk/dv 256 x 128)
+    "latent_group5": ((8192, 8192, 192, 5, None), [(512, 512, 1, 8)] * 3),
+    "latent_group5_window": ((8192, 8192, 192, 5, 128),
+                             [(128, 256, 5), (128, 256, 5), (256, 128, 5)]),
+    "latent_group5_wide_window": ((8192, 8192, 192, 5, 2048),
+                                  [(512, 512, 1)] * 3),
+    "latent_narrow_window": ((8192, 8192, 192, 1, 128), [(512, 512, 1)] * 3),
     "tokens_12k": ((12288, 12288, 128, 1, None), [(1024, 1024, 1)] * 3),
     "tokens_16k": ((16384, 16384, 128, 1, None), [(1024, 1024, 1)] * 3),
     "tokens_32k": ((32768, 32768, 128, 1, None), [(1024, 1024, 1)] * 3),
@@ -1158,3 +1169,182 @@ class TestMeshSharding:
         logical = {"w": ("embed", "mlp"), "b": ("mlp",)}
         sharded = shard_pytree(tree, logical, mesh)
         assert sharded["w"].sharding.spec[1] == "tp"
+
+
+# ------------------------------------------- a call in parts with a group
+# (PR 57: latent keys decompressed into fewer key heads than query heads)
+
+def _grouped_parts(group, Hkv=2, S=256, seed=21, dtype=jnp.float32):
+    H = Hkv * group
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return ((jax.random.normal(ks[0], (1, S, H, 128), dtype),
+             jax.random.normal(ks[1], (1, H, S, 64), dtype)),
+            (jax.random.normal(ks[2], (1, S, Hkv, 256), dtype),
+             jax.random.normal(ks[3], (1, 1, S, 64), dtype)),
+            jax.random.normal(ks[4], (1, S, H, 128), dtype))
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("group", [5, 1])
+def test_a_call_in_parts_with_a_group_and_a_window(group, window):
+    """``kv`` [B, Sk, Hkv, Dn + Dv] under H = group x Hkv query heads: query
+    head h reads key head h // group; forward and all four gradients (dq in
+    both parts, dk and dv side by side added up over a group's heads in
+    float32, the one rotary head's over all heads) against the reference on
+    the operands put together, at 128 x 128 tiles over 256 tokens, so that
+    the causal diagonal and the band's lower edge (96 back) each cross a
+    tile: the one pass without a window, the pair with one.  Float32 on
+    both sides: 2e-5 is the order of the sums."""
+    q, k, do = _grouped_parts(group)
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, q, k)
+        return (out, *jax.tree.leaves(vjp(do)))
+
+    got = both(lambda q, k: flash_attention(
+        q, k, None, interpret=True, window=window, block_q=128, block_k=128))
+    want = both(lambda q, k: attention(q, k, None, impl="reference",
+                                       window=window))
+    assert got[0].shape == (1, 256, 2 * group, 128)
+    for a, b, name in zip(want, got, ("o", "dq_n", "dq_r", "dkv", "dk_r")):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(b, a, atol=2e-5, rtol=1e-4, err_msg=name)
+
+
+def test_a_call_in_parts_at_the_geometry_tiles_picks_for_a_band(monkeypatch):
+    """The windowed, grouped call with no blocks named: ``_tiles``' own
+    answer for a window narrower than a block (a group's heads stacked in a
+    step where it says so), in bfloat16 against the reference in float32 on
+    the very inputs the kernels saw; the kernels' names carry the window
+    and both head sizes, and the counter the group's geometry."""
+    q, k, do = _grouped_parts(5, S=512, dtype=jnp.bfloat16)
+    before = _geometry_counts()
+    out, vjp = jax.vjp(lambda q, k: flash_attention(
+        q, k, None, interpret=True, window=128), q, k)
+    got = (out, *jax.tree.leaves(vjp(do)))
+    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
+    ref_out, ref_vjp = jax.vjp(lambda q, k: attention(
+        q, k, None, impl="reference", window=128), f32(q), f32(k))
+    want = (ref_out, *jax.tree.leaves(ref_vjp(f32(do))))
+    for a, b, name in zip(want, got, ("o", "dq_n", "dq_r", "dkv", "dk_r")):
+        scale = float(jnp.max(jnp.abs(a)))
+        np.testing.assert_allclose(b.astype(jnp.float32), a,
+                                   atol=2e-2 * scale, err_msg=name)
+    after = _geometry_counts()
+    new = {kernel for kernel in after if after[kernel] != before.get(kernel)}
+    assert new == {"flash_fwd_d192v128_w128", "flash_dq_d192v128_w128",
+                   "flash_dkv_d192v128_w128"}
+    for kernel in new:
+        tags = dict(next(iter(set(after[kernel]) - set(
+            before.get(kernel, {})))))
+        assert tags["parts"] == "128+64" and tags["rows"] == "qkvo"
+        want_t = attention_ops._tiles(kernel.split("_")[1], 512, 512, 192,
+                                      5, 128, 64)
+        assert (int(tags["block_q"]), int(tags["block_k"]),
+                int(tags["heads_a_step"])) == want_t[:3]
+
+
+def test_a_group_that_does_not_divide_the_heads_is_refused():
+    q, k, _ = _grouped_parts(5)
+    with pytest.raises(ValueError, match="H % Hkv == 0"):
+        flash_attention((q[0][:, :, :9], q[1][:, :9]), k, None,
+                        interpret=True)
+
+
+# ------------------------------------------------------------- PolyNorm
+
+def test_poly_norm_is_the_formula_and_so_are_its_gradients():
+    """``s (p0 x^3/rms(x^3) + p1 x^2/rms(x^2) + p2 x/rms(x) + clip(p3))``
+    against the formula written out in float64 numpy, and the gradients of x
+    and of the four numbers against central differences of it (1e-6 steps
+    in float64: 1e-6 relative).  A bias past its clamp has no gradient;
+    bfloat16 in gives bfloat16 out, computed in float32."""
+    from ray_tpu.ops.norms import poly_norm
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(6, 40))
+    eps, scale, clamp = 1e-5, 0.5, 0.5
+
+    def formula(x, p):
+        n = lambda y: y / np.sqrt(np.mean(y * y, -1, keepdims=True) + eps)
+        return scale * (p[0] * n(x ** 3) + p[1] * n(x ** 2) + p[2] * n(x)
+                        + np.clip(p[3], -clamp, clamp))
+
+    for p in (np.array([0.7, -0.4, 1.1, 0.3]), np.array([0.2, 0.9, -1.3, 0.8])):
+        got = poly_norm(jnp.asarray(x, jnp.float32),
+                        jnp.asarray(p, jnp.float32), scale, clamp, eps)
+        np.testing.assert_allclose(got, formula(x, p), atol=2e-6)
+        c = rng.normal(size=x.shape)        # the cotangent
+        gx, gp = jax.grad(lambda x, p: jnp.sum(poly_norm(
+            x, p, scale, clamp, eps) * c), argnums=(0, 1))(
+                jnp.asarray(x, jnp.float32), jnp.asarray(p, jnp.float32))
+        f = lambda x, p: np.sum(formula(x, p) * c)
+        want_p = [(f(x, p + h) - f(x, p - h)) / 2e-6
+                  for h in 1e-6 * np.eye(4)]
+        np.testing.assert_allclose(gp, want_p, rtol=2e-4, atol=2e-5)
+        assert (float(gp[3]) == 0.0) == (abs(p[3]) > clamp)
+        at = (2, 7)
+        h = np.zeros_like(x)
+        h[at] = 1e-6
+        np.testing.assert_allclose(
+            gx[at], (f(x + h, p) - f(x - h, p)) / 2e-6, rtol=2e-4, atol=2e-5)
+    low = poly_norm(jnp.asarray(x, jnp.bfloat16), jnp.asarray(p, jnp.bfloat16),
+                    scale, clamp, eps)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(low.astype(jnp.float32), formula(
+        np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32),
+                   np.float64),
+        np.asarray(jnp.asarray(p, jnp.bfloat16).astype(jnp.float32),
+                   np.float64)), atol=2e-2)
+
+
+def test_an_activation_with_weights_gets_their_gradient_through_the_experts(
+        monkeypatch):
+    """``dropless_experts`` with ``activation="poly_norm"`` and its four
+    numbers: the result and the gradients of the rows, the experts' weights
+    and PolyNorm's numbers are those of a plain loop over the held experts;
+    rows of the buffer that no group holds (made NaN here on their way out
+    of every grouped product, as the Pallas kernels may leave them) reach
+    neither the result nor any gradient."""
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.norms import poly_norm
+    T, E, M, X, k = 64, 16, 24, 8, 2
+    ks = jax.random.split(jax.random.key(9), 6)
+    x = jax.random.normal(ks[0], (T, E))
+    router = jax.random.normal(ks[1], (E, X))
+    w = {"gate": jax.random.normal(ks[2], (4, E, M)) / 4,
+         "up": jax.random.normal(ks[3], (4, E, M)) / 4,
+         "down": jax.random.normal(ks[4], (4, M, E)) / 4,
+         "p": jnp.asarray([0.6, -0.5, 0.9, 0.2])}
+    routing = moe.sigmoid_routing(x, router, jnp.zeros((X,)), k)
+    kw = {"scale": 0.5, "clamp": 0.5, "eps": 1e-5}
+    real = moe.grouped_matmul
+
+    def holed(lhs, rhs, group_sizes, **more):
+        out = real(lhs, rhs, group_sizes, **more)
+        live = (jnp.arange(out.shape[0]) < jnp.sum(group_sizes))[:, None]
+        return jnp.where(live, out, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", holed)
+
+    def got(x, w):
+        out, _ = moe.dropless_experts(
+            x, routing, w["gate"], w["up"], w["down"], held_start=2,
+            activation="poly_norm", act_weights={"p": w["p"], **kw})
+        return out
+
+    def want(x, w):
+        out = jnp.zeros_like(x)
+        for e in range(4):
+            coef = jnp.sum(jnp.where(routing.expert_index == 2 + e,
+                                     routing.weights, 0.0), axis=-1)
+            h = poly_norm(x @ w["gate"][e], w["p"], **kw) * (x @ w["up"][e])
+            out = out + coef[:, None] * (h @ w["down"][e])
+        return out
+
+    np.testing.assert_allclose(got(x, w), want(x, w), atol=1e-5)
+    c = jax.random.normal(ks[5], x.shape)
+    g = jax.grad(lambda x, w: jnp.sum(got(x, w) * c), argnums=(0, 1))(x, w)
+    gw = jax.grad(lambda x, w: jnp.sum(want(x, w) * c), argnums=(0, 1))(x, w)
+    for a, b in zip(jax.tree.leaves(gw), jax.tree.leaves(g)):
+        assert bool(jnp.all(jnp.isfinite(b)))
+        np.testing.assert_allclose(b, a, atol=2e-5, rtol=1e-4)

@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated", "mla", "kda")
+              "gated", "mla", "kda", "gdla")
 
 
 class TestCatalog:
@@ -426,7 +426,10 @@ def _smoke_train_fn(config):
                       "ssm_chunk_carry": 0.3,
                       # kda: the share of a delta-rule state's row a chunk
                       # hands on
-                      "kda_chunk_carry": 0.9})
+                      "kda_chunk_carry": 0.9,
+                      # gdla: the mean weight a differential attention's
+                      # noise heads are subtracted with
+                      "gdla_lambda_mean": 0.5})
 
 
 @serve.deployment(name="telemetry_echo")
