@@ -7,10 +7,13 @@ none holds a copy."""
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.attention import FLASH_LSE, FLASH_OUT
+from ..util import telemetry
 
 
 def is_shape(x) -> bool:
@@ -179,18 +182,84 @@ def next_token_loss(x, lm_head, batch: Dict[str, jax.Array],
         return token_nll(x, lm_head, targets, loss_chunks, dt, mask) / denom
 
 
-def remat(block: Callable, mode: Any) -> Callable:
+#: The share of a device's memory (``bytes_limit``) that the flash calls'
+#: results of a whole step may take where they outlive the layers' remat
+#: (``flash_keep``): all of them or none.  What the benchmark's training
+#: steps would keep, a chip, against 16.91 GB (ISSUE 58; bf16 ``out``, and
+#: a float32 ``lse`` 1/64 of it beside):
+#:
+#:   calls x [rows, S, H * Dv]                    GB     share
+#:   lfm2      2 x [4, 8192, 32 * 64]            0.27    1.6 %   kept
+#:   ling      1 x [4, 8192, 32 * 128]           0.27    1.6 %   kept
+#:   nemotron  2 x [4, 8192, 32 * 128]           0.55    3.2 %   kept
+#:   motif     4 x [1, 8192, 80 * 128]           0.68    4.0 %   kept
+#:   xing4.0   6 x [2, 8192, 32 * 128]           0.82    4.9 %   kept
+#:   mistral  28 x [1, 4096, 32 * 128]           0.96    5.7 %   kept
+#:   yi       24 x [4, 4096, 16 * 128]           1.61    9.5 %
+#:   trinity   9 x [4, 8192, 32 * 128]           2.42   14.3 %
+#:   ouro     48 x [4, 4096, 16 * 128]           3.22   19.0 %
+#:   kanana   12 x [4, 8192, 32 * 128]           3.22   19.0 %
+FLASH_KEEP_SHARE = 1 / 16
+
+
+def _bytes_limit() -> Optional[int]:
+    """A local device's memory in bytes (the least of them), or None where
+    the backend states none (the CPU)."""
+    from ..profiler.capture import device_memory_stats
+    return min((d["bytes_limit"] for d in device_memory_stats()
+                if d.get("bytes_limit")), default=None)
+
+
+def flash_keep(mode: Any, calls: int, shape, dtype, chips: int = 1) -> tuple:
+    """What a stack's layers keep past their remat (``remat``'s ``keep``):
+    the names of the flash kernels' two results (``ops.attention.FLASH_OUT``
+    / ``FLASH_LSE``), so that the forward kernel runs once a layer and not
+    again in the backward's recomputation, or nothing.  All or nothing,
+    from what a stack sees when it is traced: ``calls`` attention calls a
+    step under the remat (layers x loop passes, a prediction module's),
+    each with a result of ``shape`` [B, S, H, Dv] and ``dtype`` whose rows
+    lie over ``chips`` devices.  The names are kept iff ``mode`` is
+    ``remat``'s True / "full" and ``out`` + ``lse`` (float32 [B, H, S]) of
+    every call together are at most ``FLASH_KEEP_SHARE`` of the device's
+    ``bytes_limit``; where the backend gives no limit (the CPU), nothing.
+    No model is asked its name.  A step that stood within that share of
+    the limit while it recomputed everything can stop fitting: say
+    ``remat="dots"`` or fewer rows there.
+
+    Counts ``ray_tpu_remat_kept_total``, one a stack traced."""
+    B, S, H, Dv = shape
+    rows = -(-B // chips)
+    need = calls * rows * S * H * (Dv * jnp.dtype(dtype).itemsize + 4)
+    limit = _bytes_limit()
+    budget = int(limit * FLASH_KEEP_SHARE) if limit else None
+    kept = mode in (True, "full") and bool(calls) \
+        and budget is not None and need <= budget
+    names = (FLASH_OUT, FLASH_LSE)
+    telemetry.inc("ray_tpu_remat_kept_total", tags={
+        "names": "+".join(names), "kept": "true" if kept else "false",
+        "calls": str(calls), "bytes": str(need), "budget": str(budget)})
+    return names if kept else ()
+
+
+def remat(block: Callable, mode: Any, keep: tuple = ()) -> Callable:
     """``block`` under the remat mode of a model configuration: False saves
     everything (small models only); True/"full" recomputes the whole block
-    in the backward pass; "dots" keeps every matmul output (the MXU work
-    worth not repeating) and recomputes the cheap VPU elementwise ops
+    in the backward pass but for the arrays named in ``keep``
+    (``checkpoint_name``; ``flash_keep`` gives the flash kernels' results
+    where a sixteenth of the device holds the whole step's, and nothing
+    else: with an empty ``keep`` nothing outlives the forward pass but the
+    block's inputs); "dots" keeps every matmul output (the MXU work worth
+    not repeating) and recomputes the cheap VPU elementwise ops
     (norms/rope/silu); "dots_nobatch" keeps only batch-free dots
-    (weights-stationary projections)."""
+    (weights-stationary projections).  These two and False take no notice
+    of ``keep``."""
     if mode is False:
         return block
+    full = (jax.checkpoint_policies.save_only_these_names(*keep) if keep
+            else jax.checkpoint_policies.nothing_saveable)
     policies = {
-        True: jax.checkpoint_policies.nothing_saveable,
-        "full": jax.checkpoint_policies.nothing_saveable,
+        True: full,
+        "full": full,
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_nobatch":
             jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims}

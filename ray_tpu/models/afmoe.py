@@ -348,6 +348,8 @@ def _forward_hidden(params, state, tokens, cfg: AfmoeConfig):
             x = x * jnp.asarray(math.sqrt(cfg.hidden), dt)
     cos, sin = rope_lane_tables(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
+    keep = _lm.flash_keep(cfg.remat, cfg.layers,
+                          (*tokens.shape, cfg.heads, cfg.head_dim), dt)
 
     def run(x, layer, full, bias=None):
         """The layer under the remat, ``layer_rows`` rows at a time.  A
@@ -358,7 +360,7 @@ def _forward_hidden(params, state, tokens, cfg: AfmoeConfig):
         one = _lm.remat(
             lambda x, layer, flag, bias: _layer(
                 cfg, full if static else flag, cos, sin, x, layer, bias),
-            cfg.remat)
+            cfg.remat, keep)
         B = x.shape[0]
         n = min(cfg.layer_rows or B, B)
         if B % n:

@@ -346,11 +346,13 @@ def _merge(report, cfg: BailingHybridConfig):
     return merged
 
 
-def _run(cfg: BailingHybridConfig, kind: str, tables, x, layer, bias=None):
+def _run(cfg: BailingHybridConfig, kind: str, tables, x, layer, bias=None,
+         keep=()):
     """The layer under the remat, ``layer_rows`` rows at a time (as
-    ``afmoe``'s)."""
+    ``afmoe``'s); ``keep``: ``_lm.remat``'s, the stack's."""
     one = _lm.remat(lambda x, layer, bias: _layer(cfg, kind, tables, x,
-                                                  layer, bias), cfg.remat)
+                                                  layer, bias), cfg.remat,
+                    keep)
     B = x.shape[0]
     n = min(cfg.layer_rows or B, B)
     if B % n:
@@ -389,10 +391,13 @@ def _forward_hidden(params, state, tokens, cfg: BailingHybridConfig):
         x = params["embed"].astype(cfg.dtype)[tokens]
     tables = rope_lane_tables(cfg.qk_rope_head_dim, cfg.max_seq_len,
                               cfg.rope_theta)
+    keep = _lm.flash_keep(
+        cfg.remat, sum(kind == MLA for kind in cfg.kinds),
+        (*tokens.shape, cfg.heads, cfg.v_head_dim), cfg.dtype)
     loads, carries = [], []
     for i, (kind, layer) in enumerate(zip(cfg.kinds, params["layers"])):
         bias = state["bias"][len(loads)] if _sparse(cfg, i) else None
-        x, report = _run(cfg, kind, tables, x, layer, bias)
+        x, report = _run(cfg, kind, tables, x, layer, bias, keep)
         if "carry" in report:
             carries.append(report.pop("carry"))
         if bias is not None:

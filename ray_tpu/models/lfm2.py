@@ -254,11 +254,13 @@ def _layer(cfg: Lfm2Config, kind: str, cos, sin, x, layer, bias=None):
     return h + f, loads
 
 
-def _run(cfg: Lfm2Config, kind: str, cos, sin, x, layer, bias=None):
+def _run(cfg: Lfm2Config, kind: str, cos, sin, x, layer, bias=None,
+         keep=()):
     """The layer under the remat, ``layer_rows`` rows at a time (as
-    ``afmoe``'s)."""
+    ``afmoe``'s); ``keep``: ``_lm.remat``'s, the stack's."""
     one = _lm.remat(lambda x, layer, bias: _layer(cfg, kind, cos, sin, x,
-                                                  layer, bias), cfg.remat)
+                                                  layer, bias), cfg.remat,
+                    keep)
     B = x.shape[0]
     n = min(cfg.layer_rows or B, B)
     if B % n:
@@ -298,10 +300,13 @@ def _forward_hidden(params, state, tokens, cfg: Lfm2Config):
         x = params["embed"].astype(cfg.dtype)[tokens]
     cos, sin = rope_lane_tables(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
+    keep = _lm.flash_keep(
+        cfg.remat, sum(kind == FULL for kind, _ in _stack(cfg)),
+        (*tokens.shape, cfg.heads, cfg.head_dim), cfg.dtype)
     loads = []
     for (kind, dense), layer in zip(_stack(cfg), params["layers"]):
         bias = None if dense else state["bias"][len(loads)]
-        x, report = _run(cfg, kind, cos, sin, x, layer, bias)
+        x, report = _run(cfg, kind, cos, sin, x, layer, bias, keep)
         if not dense:
             loads.append(report)
     if loads:

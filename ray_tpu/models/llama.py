@@ -196,6 +196,24 @@ def _values_as_rows(cfg: LlamaConfig) -> bool:
             and (mesh is None or mesh.size == 1))
 
 
+def flash_keep(cfg: LlamaConfig, tokens, calls: int) -> tuple:
+    """``_lm.flash_keep`` for a stack of ``calls`` calls of ``_attend`` a
+    step on tokens [B, S] (models/ouro.py's too): a call's result as a
+    device holds it, the rows over the mesh's (dp, fsdp) and the heads over
+    tp, as ``ops.attention``'s island splits them.  Nothing is kept where
+    the calls are not flash's (ring / ulysses) or run inside a pipeline
+    stage, whose microbatches in flight are not counted here."""
+    mesh = _kernel_mesh(cfg)
+    over = lambda *axes: math.prod(mesh.shape.get(a, 1) for a in axes) \
+        if mesh is not None else 1
+    flash = (cfg.attention_impl in ("auto", "flash", "flash_interpret")
+             and not cfg.pp_microbatches)
+    return _lm.flash_keep(
+        cfg.remat, calls if flash else 0,
+        (*tokens.shape, cfg.heads // over("tp"), cfg.head_dim), cfg.dtype,
+        chips=over("dp", "fsdp"))
+
+
 def attention_branch(cfg: LlamaConfig, cos, sin, positions, h, layer):
     """Attn(h) of a layer for normed h [B, S, E]: q, k, v, rotary embedding,
     the configured attention, the output projection.  ``cos`` / ``sin`` are
@@ -281,7 +299,7 @@ def _forward_hidden(params: Dict[str, Any], tokens: jax.Array,
             return mlp(_attn_half(cfg, cos, sin, positions, x, layer), layer)
     else:
         block = _lm.remat(partial(_block, cfg, cos, sin, positions),
-                          cfg.remat)
+                          cfg.remat, flash_keep(cfg, tokens, cfg.layers))
 
     def scan_body(x, layer):
         return block(x, layer), None

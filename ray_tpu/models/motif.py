@@ -385,7 +385,7 @@ def _refuse_a_mesh(cfg: MotifConfig) -> None:
             "lane, not hc_mult (ROADMAP M4)")
 
 
-def _forward_hidden(params, state, tokens, cfg: MotifConfig):
+def _forward_hidden(params, state, tokens, cfg: MotifConfig, keep=()):
     """``xing4._forward_hidden``'s results for this stack: (the lanes' sum
     after the last layer [B, S, C], before the final norm; the expert
     layers' loads {"counts" [Lm, X], "dropped" [Lm], "sliced" [Lm], "top"
@@ -402,7 +402,7 @@ def _forward_hidden(params, state, tokens, cfg: MotifConfig):
     for i in range(Ld):
         X, report = xing4._run(
             cfg, cos, sin, X, jax.tree.map(lambda a: a[i], params["dense"]),
-            layer_fn=_layer_of(cfg.full(i)))
+            layer_fn=_layer_of(cfg.full(i)), keep=keep)
         residual = jnp.maximum(residual, report["hc_residual"])
         lambdas.append(report["gdla_lambda"][None])
 
@@ -415,7 +415,7 @@ def _forward_hidden(params, state, tokens, cfg: MotifConfig):
 
     def body(X, group):
         return xing4._run(cfg, cos, sin, X, group["layer"], group["bias"],
-                          layer_fn)
+                          layer_fn, keep)
 
     if Lm:
         X, loads = jax.lax.scan(body, X, stacked)

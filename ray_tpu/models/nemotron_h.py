@@ -301,11 +301,11 @@ def _merge(kind: str, report, cfg: NemotronHConfig):
     return {}
 
 
-def _run(cfg: NemotronHConfig, kind: str, x, layer, bias=None):
+def _run(cfg: NemotronHConfig, kind: str, x, layer, bias=None, keep=()):
     """The layer under the remat, ``layer_rows`` rows at a time (as
-    ``afmoe``'s)."""
+    ``afmoe``'s); ``keep``: ``_lm.remat``'s, the stack's."""
     one = _lm.remat(lambda x, layer, bias: _layer(cfg, kind, x, layer, bias),
-                    cfg.remat)
+                    cfg.remat, keep)
     B = x.shape[0]
     n = min(cfg.layer_rows or B, B)
     if B % n:
@@ -338,10 +338,13 @@ def _forward_hidden(params, state, tokens, cfg: NemotronHConfig):
     _refuse_a_mesh(cfg)
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
+    keep = _lm.flash_keep(
+        cfg.remat, sum(kind == ATTENTION for kind in cfg.kinds),
+        (*tokens.shape, cfg.heads, cfg.head_dim), cfg.dtype)
     loads, carries = [], []
     for kind, layer in zip(cfg.kinds, params["layers"]):
         bias = state["bias"][len(loads)] if kind == EXPERTS else None
-        x, report = _run(cfg, kind, x, layer, bias)
+        x, report = _run(cfg, kind, x, layer, bias, keep)
         if kind == EXPERTS:
             loads.append(report)
         elif kind == MAMBA:

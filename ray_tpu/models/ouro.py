@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from . import _lm
-from .llama import LlamaConfig, attention_branch, mlp_branch
+from .llama import LlamaConfig, attention_branch, flash_keep, mlp_branch
 from .llama import param_logical_axes as llama_logical_axes
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_lane_tables
@@ -165,7 +165,8 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array, cfg: OuroConfig,
         x = params["embed"].astype(dt)[tokens]
     cos, sin = rope_lane_tables(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
-    layer = _lm.remat(partial(_layer, cfg, cos, sin, positions), cfg.remat)
+    layer = _lm.remat(partial(_layer, cfg, cos, sin, positions), cfg.remat,
+                      flash_keep(cfg, tokens, cfg.layers * cfg.loops))
 
     def one_pass(x, blocks, final_norm):
         x, _ = jax.lax.scan(lambda x, w: (layer(x, w), None), x, blocks)

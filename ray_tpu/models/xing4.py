@@ -427,14 +427,15 @@ _MERGE = {"hc_residual": jnp.max,
           "top": lambda a: a.reshape(-1, a.shape[-1])}
 
 
-def _run(cfg, cos, sin, X, layer, bias=None, layer_fn=None):
+def _run(cfg, cos, sin, X, layer, bias=None, layer_fn=None, keep=()):
     """The layer under the remat, ``layer_rows`` rows at a time (as
     ``afmoe``'s): (X, the layer's report merged over its groups).
     ``layer_fn``: this module's ``_layer``, or another model's with its
-    arguments (``models/motif.py``)."""
+    arguments (``models/motif.py``); ``keep``: ``_lm.remat``'s, the step's
+    (``_flash_keep``)."""
     layer_fn = layer_fn or _layer
     one = _lm.remat(lambda X, layer, bias: layer_fn(cfg, cos, sin, X, layer,
-                                                    bias), cfg.remat)
+                                                    bias), cfg.remat, keep)
     B = X.shape[0]
     n = min(cfg.layer_rows or B, B)
     if B % n:
@@ -475,11 +476,21 @@ def _collapse(X):
     return jnp.sum(X.astype(jnp.float32), axis=1).astype(X.dtype)
 
 
-def _forward_hidden(params, state, tokens, cfg: Xing4Config):
+def _flash_keep(cfg, tokens) -> tuple:
+    """``_lm.flash_keep`` for a step on tokens [B, S]: a call of latent
+    attention a layer and one for the prediction module's, where there is
+    one (``models/motif.py`` counts its noise heads among ``heads``)."""
+    return _lm.flash_keep(cfg.remat, cfg.layers + bool(cfg.mtp_layers),
+                          (*tokens.shape, cfg.heads, cfg.v_head_dim),
+                          cfg.dtype)
+
+
+def _forward_hidden(params, state, tokens, cfg: Xing4Config, keep=()):
     """tokens [B, S] -> (the lanes' sum after the last layer [B, S, C],
     before the final norm; the expert layers' loads {"counts" [Lm, X],
     "dropped" [Lm], "sliced" [Lm], "top" [Lm, B*S, k]}; the largest
-    Sinkhorn residual of the stack; the rotary tables)."""
+    Sinkhorn residual of the stack; the rotary tables).  ``keep``:
+    ``_run``'s."""
     _refuse_a_mesh(cfg)
     dt = cfg.dtype
     with jax.named_scope("embed"):
@@ -489,11 +500,13 @@ def _forward_hidden(params, state, tokens, cfg: Xing4Config):
     residual = jnp.zeros((), jnp.float32)
     for i in range(cfg.num_dense_layers):
         X, report = _run(cfg, cos, sin, X,
-                         jax.tree.map(lambda a: a[i], params["dense"]))
+                         jax.tree.map(lambda a: a[i], params["dense"]),
+                         keep=keep)
         residual = jnp.maximum(residual, report["hc_residual"])
 
     def body(X, group):
-        return _run(cfg, cos, sin, X, group["layer"], group["bias"])
+        return _run(cfg, cos, sin, X, group["layer"], group["bias"],
+                    keep=keep)
 
     Lm = cfg.expert_layers
     if Lm:
@@ -528,10 +541,10 @@ def mtp_targets_and_mask(targets, mask):
 
 
 def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg,
-              layer_fn=None):
+              layer_fn=None, keep=()):
     """(the module's masked mean loss, its layer's report): ``x_out``
-    [B, S, C] is the stack's result before the final norm; ``layer_fn`` as
-    ``_run``'s."""
+    [B, S, C] is the stack's result before the final norm; ``layer_fn``
+    and ``keep`` as ``_run``'s."""
     dt, eps, p = cfg.dtype, cfg.norm_eps, params["mtp"]
     with jax.named_scope("mtp"):
         with jax.named_scope("project"):
@@ -544,7 +557,7 @@ def _mtp_loss(params, bias, x_out, tables, targets, mask, cfg,
                            preferred_element_type=dt)
         Z, report = _run(cfg, *tables, _lanes(z, cfg.hc_mult),
                          jax.tree.map(lambda a: a[0], p["layer"]), bias,
-                         layer_fn)
+                         layer_fn, keep)
         h = rms_norm(_collapse(Z), p["final_norm"], eps)
         targets2, mask2 = mtp_targets_and_mask(targets, mask)
         with jax.named_scope("loss"):
@@ -562,8 +575,9 @@ def loss_and_report(params, batch, cfg, state=None, stack=None,
     ``_forward_hidden`` or another model's of the same results, and
     ``mtp_layer_fn`` its module's layer (``models/motif.py``)."""
     state = state or init_state(cfg)
+    keep = _flash_keep(cfg, batch["tokens"])
     x, loads, residual, tables = (stack or _forward_hidden)(
-        params, state, batch["tokens"], cfg)
+        params, state, batch["tokens"], cfg, keep)
     targets, mask, denom = _lm.targets_and_mask(batch)
     with jax.named_scope("final_norm"):
         h = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -576,7 +590,8 @@ def loss_and_report(params, batch, cfg, state=None, stack=None,
         # backward's operations too (models/ouro._scoped has the reason).
         mtp, report = jax.jit(
             lambda params, bias, x, tables, targets, mask: _mtp_loss(
-                params, bias, x, tables, targets, mask, cfg, mtp_layer_fn))(
+                params, bias, x, tables, targets, mask, cfg, mtp_layer_fn,
+                keep))(
             params, state["bias"][-1], x, tables, targets, mask)
         residual = jnp.maximum(residual, report.pop("hc_residual"))
         loads = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),

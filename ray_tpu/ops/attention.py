@@ -178,6 +178,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..util import telemetry
 
@@ -1262,11 +1263,19 @@ def _flash(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
     return out
 
 
+#: What the forward rule calls its two results (``checkpoint_name``), so
+#: that a remat policy can keep them past a layer's recomputation and the
+#: forward kernel runs once (``models/_lm.flash_keep``).  Under a policy
+#: that does not name them the names are the identity.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, q_offset, interpret,
                window, rows):
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                               q_offset, interpret, need_lse=True,
                               window=window, rows=rows)
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

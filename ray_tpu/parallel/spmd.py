@@ -113,7 +113,14 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
 
     ``param_dtype`` overrides parameter (and hence optimizer-state)
     storage: bfloat16 halves the adamw footprint so ~1.5B params fit one
-    v5e chip with remat (HBM budget: params+m+v at 2 bytes each).
+    v5e chip with remat (HBM budget: params+m+v at 2 bytes each).  Under
+    ``remat=True`` / "full" a model's stack keeps the flash kernels' ``out``
+    and ``lse`` of every attention call past the layers' recomputation where
+    all of them together are at most a sixteenth of the device's
+    ``bytes_limit``, and nothing but the layers' inputs otherwise
+    (``models/_lm.flash_keep``; ``ray_tpu_remat_kept_total`` says which):
+    a step that stood within a sixteenth of the limit while it recomputed
+    everything can stop fitting, and fits again with fewer rows.
 
     ``grad_accum`` > 1 splits the batch's leading dim into that many
     microbatches, accumulating gradients in an f32 scan before ONE

@@ -475,6 +475,20 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "rows=qkvo only on a call that hands q and k in "
                        "the two parts its projections write, every "
                        "128-wide operand as rows: latent attention's)."},
+    "ray_tpu_remat_kept_total": {
+        "type": "counter",
+        "tag_keys": ("names", "kept", "calls", "bytes", "budget"),
+        "description": "Stacks traced whose layers run under the remat "
+                       "(models/_lm.flash_keep), by what the rule saw and "
+                       "did: the names it may keep past a layer's "
+                       "recomputation (the flash kernels' out and lse), "
+                       "kept=true where it kept them, so that the forward "
+                       "kernel runs once a layer, else false; the "
+                       "attention calls of the step under the remat, the "
+                       "bytes their results take on a device together, "
+                       "and the budget they were held to, a sixteenth of "
+                       "the device's bytes_limit (None where the backend "
+                       "states no limit: the CPU keeps nothing)."},
     "ray_tpu_gmm_tile_geometry_total": {
         "type": "counter",
         "tag_keys": ("kind", "tm", "tk", "tn", "rows_a_group"),

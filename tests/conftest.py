@@ -105,3 +105,17 @@ def ray_start_isolated():
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def no_mesh_left_by_another_file():
+    """``build_mesh`` sets the process's global mesh, and a test file that
+    ran before in the same worker may have left one of several devices,
+    which a model that runs on one device refuses by name: the test starts
+    without one and hands back what it found (a file of such a model's
+    tests asks for it by ``pytestmark``)."""
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+    before = get_global_mesh()
+    set_global_mesh(None)
+    yield
+    set_global_mesh(before)

@@ -25,6 +25,8 @@ sys.path.insert(0, ROOT)
 from benchmark import reference_bailing_hybrid as ref  # noqa: E402
 from benchmark.archs import bailing_hybrid as arch  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("no_mesh_left_by_another_file")
+
 
 def _sizes(cfg):
     """The reference's sizes of a program configuration."""

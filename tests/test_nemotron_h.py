@@ -23,6 +23,8 @@ sys.path.insert(0, ROOT)
 from benchmark import reference_nemotron_h as ref  # noqa: E402
 from benchmark.archs import nemotron_h as arch  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("no_mesh_left_by_another_file")
+
 
 def _sizes(cfg):
     """The reference's sizes for a program configuration."""

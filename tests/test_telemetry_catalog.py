@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated", "mla", "kda", "gdla")
+              "gated", "mla", "kda", "gdla", "remat")
 
 
 class TestCatalog:
