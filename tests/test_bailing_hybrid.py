@@ -7,6 +7,7 @@ routing.  That the programs which share code with it did not change:
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -48,19 +49,29 @@ def _sizes(cfg):
             "bias_update_rate": cfg.bias_update_rate}
 
 
+@functools.lru_cache(maxsize=None)
 def _case(seed=0, **kw):
+    """Made once a configuration of this module (nothing writes into what it
+    returns), under one ``jax.jit``: run eagerly the initialisation is one
+    program a leaf shape."""
     cfg = M.bailing_hybrid_tiny(**kw)
-    params = M.init_params(cfg, jax.random.key(seed))
-    # norms and the convolution away from their constant starts
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    params = jax.tree.unflatten(tree, [
-        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
-        for a, k in zip(leaves, keys)])
-    bias = 0.02 * jax.random.normal(jax.random.key(seed + 2),
-                                    (cfg.expert_layers, cfg.num_experts))
-    tokens = jax.random.randint(jax.random.key(seed + 3), (2, 48), 0,
-                                cfg.vocab_size)
+
+    @jax.jit
+    def make():
+        params = M.init_params(cfg, jax.random.key(seed))
+        # norms and the convolution away from their constant starts
+        leaves, tree = jax.tree.flatten(params)
+        keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
+        params = jax.tree.unflatten(tree, [
+            a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
+            for a, k in zip(leaves, keys)])
+        bias = 0.02 * jax.random.normal(jax.random.key(seed + 2),
+                                        (cfg.expert_layers, cfg.num_experts))
+        tokens = jax.random.randint(jax.random.key(seed + 3), (2, 48), 0,
+                                    cfg.vocab_size)
+        return params, bias, tokens
+
+    params, bias, tokens = make()
     mask = jnp.ones((2, 48), jnp.int32).at[:, -1].set(0)
     return cfg, params, bias, tokens, mask
 
@@ -86,10 +97,10 @@ def test_loss_and_every_gradient_against_the_reference(held):
         experts_held=held, held_start=4 if held else 0, loss_chunks=2)
     batch = {"tokens": tokens, "loss_mask": mask}
     with jax.default_matmul_precision("highest"):
-        got, ggot = jax.value_and_grad(M.loss_fn)(
-            params, batch, cfg, {"bias": bias})
-        want, gwant = jax.value_and_grad(ref.loss)(
-            params, bias, tokens, mask, _sizes(cfg))
+        got, ggot = jax.jit(jax.value_and_grad(lambda p: M.loss_fn(
+            p, batch, cfg, {"bias": bias})))(params)
+        want, gwant = jax.jit(jax.value_and_grad(lambda p: ref.loss(
+            p, bias, tokens, mask, _sizes(cfg))))(params)
     assert float(got) == pytest.approx(float(want), rel=2e-5)
     flat_got = jax.tree_util.tree_leaves_with_path(ggot)
     for (path, a), b in zip(flat_got, jax.tree.leaves(gwant)):
@@ -101,8 +112,10 @@ def test_loss_and_every_gradient_against_the_reference(held):
 def test_logits_against_the_reference_with_a_remat_and_row_groups():
     cfg, params, bias, tokens, _ = _case(seed=3, remat=True, layer_rows=1)
     with jax.default_matmul_precision("highest"):
-        got = M.forward(params, tokens, cfg, {"bias": bias})
-        want = ref.logits(params, bias, tokens, _sizes(cfg))
+        got = jax.jit(lambda p: M.forward(p, tokens, cfg, {"bias": bias}))(
+            params)
+        want = jax.jit(lambda p: ref.logits(p, bias, tokens, _sizes(cfg)))(
+            params)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
                                atol=2e-4)
 

@@ -6,6 +6,7 @@ kernels in parts (interpreted) against the reference attention; the
 selection bias's rule; the share of an expert-parallel layer; the train
 step's state and report; and that Xing4.0's own program did not change."""
 
+import functools
 import hashlib
 import json
 import os
@@ -42,17 +43,28 @@ def _sizes(cfg):
             "eps": cfg.norm_eps}
 
 
+@functools.lru_cache(maxsize=None)
 def _setup(seed=0, rows=2, seq=48, **kw):
+    """Made once a configuration of this module (nothing writes into what it
+    returns), the parameters under one ``jax.jit``: run eagerly the
+    initialisation is one program a leaf shape."""
     cfg = deepseek_v3.deepseek_v3_tiny(**kw)
-    params = deepseek_v3.init_params(cfg, jax.random.key(seed))
-    # Norm weights away from one, and a selection bias large enough to
-    # change which experts are chosen.
-    keys = iter(jax.random.split(jax.random.key(seed + 1), 64))
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, a: a * (1 + 0.2 * jax.random.normal(next(keys), a.shape))
-        if "norm" in str(path[-1]) else a, params)
-    bias = 0.3 * jax.random.normal(next(keys),
-                                   (cfg.expert_layers, cfg.num_experts))
+
+    @jax.jit
+    def make(key, shake_key):
+        params = deepseek_v3.init_params(cfg, key)
+        # Norm weights away from one, and a selection bias large enough to
+        # change which experts are chosen.
+        keys = iter(jax.random.split(shake_key, 64))
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: a * (1 + 0.2 * jax.random.normal(
+                next(keys), a.shape)) if "norm" in str(path[-1]) else a,
+            params)
+        bias = 0.3 * jax.random.normal(next(keys),
+                                       (cfg.expert_layers, cfg.num_experts))
+        return params, bias
+
+    params, bias = make(jax.random.key(seed), jax.random.key(seed + 1))
     rng = np.random.default_rng(seed)
     batch = {"tokens": jnp.asarray(rng.integers(
         0, cfg.vocab_size, (rows, seq), dtype=np.int32)),
@@ -139,7 +151,8 @@ def test_remat_rows_at_a_time_and_loss_chunks_do_not_change_the_loss(how):
 def _one_layer(cfg, seed=0):
     """(one layer's weights of the shared stack's tree, the reference's
     sizes) for a stack configuration."""
-    params = xing4.init_params(cfg, jax.random.key(seed))
+    params = jax.jit(lambda key: xing4.init_params(cfg, key))(
+        jax.random.key(seed))
     layer = jax.tree.map(lambda a: a[0], params["dense"])
     s = {"H": cfg.heads, "rkv": cfg.kv_lora_rank, "dn": cfg.qk_nope_head_dim,
          "dr": cfg.qk_rope_head_dim, "dv": cfg.v_head_dim,
@@ -168,10 +181,10 @@ def test_one_latent_attention_with_and_without_a_query_bottleneck(rank):
     h = jax.random.normal(jax.random.key(1), (2, 48, cfg.hidden))
     weights = {k: v for k, v in layer.items() if k.startswith(("w", "q_",
                                                                "kv_"))}
-    got, g = jax.value_and_grad(lambda w: jnp.sum(jnp.sin(xing4._mla(
-        cfg, *tables, h, w))))(weights)
-    want, gw = jax.value_and_grad(lambda w: jnp.sum(jnp.sin(
-        ref.latent_attention(h, w, s))))(weights)
+    got, g = jax.jit(jax.value_and_grad(lambda w: jnp.sum(jnp.sin(
+        xing4._mla(cfg, *tables, h, w)))))(weights)
+    want, gw = jax.jit(jax.value_and_grad(lambda w: jnp.sum(jnp.sin(
+        ref.latent_attention(h, w, s)))))(weights)
     assert abs(float(got) - float(want)) < 1e-4 * abs(float(want)) + 1e-4
     for name in g:
         if float(jnp.linalg.norm(gw[name])) == 0:   # unused by attention
@@ -192,10 +205,10 @@ def test_flash_in_parts_interpreted_is_the_reference_attention():
     layer, s = _one_layer(cfg, seed=3)
     tables = rope_lane_tables(64, 256, cfg.rope_theta, None)
     h = jax.random.normal(jax.random.key(2), (1, 256, cfg.hidden))
-    got, g = jax.value_and_grad(lambda h: jnp.sum(jnp.sin(xing4._mla(
-        cfg, *tables, h, layer))))(h)
-    want, gw = jax.value_and_grad(lambda h: jnp.sum(jnp.sin(
-        ref.latent_attention(h, layer, s))))(h)
+    got, g = jax.jit(jax.value_and_grad(lambda h: jnp.sum(jnp.sin(
+        xing4._mla(cfg, *tables, h, layer)))))(h)
+    want, gw = jax.jit(jax.value_and_grad(lambda h: jnp.sum(jnp.sin(
+        ref.latent_attention(h, layer, s)))))(h)
     assert abs(float(got) - float(want)) < 1e-4 * abs(float(want)) + 1e-3
     assert float(jnp.linalg.norm(g - gw)) < 2e-3 * float(jnp.linalg.norm(gw))
 

@@ -4,7 +4,7 @@ chunked loss whose head is gathered once a call gives the one-device
 program's loss and gradients, with float32 master weights too; where the
 mesh or the batch does not allow the manual region, and without an ``fsdp``
 axis over 1, the program is the one it always was.  What the TPU compiler
-makes of the collectives is read in ``tests/test_tpu_compile.py``.
+makes of the collectives is read in ``tests/test_tpu_compile_fsdp4.py``.
 """
 
 from __future__ import annotations

@@ -4,6 +4,7 @@ backward, flash attention and the rotary placement at a head size of 64, an
 expert layer with no shared expert, the router's epsilon, the tied head, the
 share of an expert-parallel layer, and the train step's state and report."""
 
+import functools
 import importlib
 import os
 import sys
@@ -40,29 +41,50 @@ def _sizes(cfg):
             "theta": cfg.rope_theta, "eps": cfg.norm_eps}
 
 
+@functools.lru_cache(maxsize=None)
 def _setup(seed=0, rows=2, seq=40, **kw):
     """Tiny widths that keep a head size that is not 128, four query heads a
     key head, 8 experts with 4 a token and no shared one, both kinds of
-    operator (``c a c c a``) and one dense layer."""
+    operator (``c a c c a``) and one dense layer.  Made once a configuration
+    of this module (nothing writes into what it returns), the parameters
+    under one ``jax.jit``: run eagerly the initialisation is one program a
+    leaf shape."""
     cfg = lfm2.lfm2_tiny(**kw)
-    params = lfm2.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), 64))
 
-    def shake(path, a):
-        if "norm" in str(path[-1]):             # away from one
-            return a * (1 + 0.2 * jax.random.normal(next(keys), a.shape))
-        return a
+    @jax.jit
+    def make(key, shake_key):
+        params = lfm2.init_params(cfg, key)
+        keys = iter(jax.random.split(shake_key, 64))
 
-    params = jax.tree_util.tree_map_with_path(shake, params)
-    # A selection bias large enough to change which experts are chosen.
-    bias = 0.3 * jax.random.normal(
-        next(keys), (cfg.expert_layers, cfg.num_experts))
+        def shake(path, a):
+            if "norm" in str(path[-1]):             # away from one
+                return a * (1 + 0.2 * jax.random.normal(next(keys), a.shape))
+            return a
+
+        params = jax.tree_util.tree_map_with_path(shake, params)
+        # A selection bias large enough to change which experts are chosen.
+        bias = 0.3 * jax.random.normal(
+            next(keys), (cfg.expert_layers, cfg.num_experts))
+        return params, bias
+
+    params, bias = make(jax.random.key(seed), jax.random.key(seed + 1))
     rng = np.random.default_rng(seed)
     batch = {"tokens": jnp.asarray(rng.integers(
         0, cfg.vocab_size, (rows, seq), dtype=np.int32)),
         "loss_mask": jnp.asarray(rng.integers(0, 2, (rows, seq),
                                               dtype=np.int32))}
     return cfg, params, bias, batch
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_and_grads(**kw):
+    """(loss, gradients) of ``_setup(experts_held=4)``'s case under ``kw``
+    of the configuration, computed once: the plain one is what every variant
+    below and the tied leaf's test compare against."""
+    cfg, params, bias, batch = _setup(experts_held=4)
+    cfg = cfg.replace(**kw)
+    return jax.jit(jax.value_and_grad(
+        lambda p: lfm2.loss_fn(p, batch, cfg, {"bias": bias})))(params)
 
 
 def _whole_loss(params, bias, batch, s):
@@ -127,10 +149,7 @@ def test_model_matches_reference_loss_and_every_gradient():
     dict(remat="full", layer_rows=1, loss_chunks=4)],
     ids=["remat", "rows_at_a_time", "loss_chunks", "all_three"])
 def test_remat_rows_at_a_time_and_loss_chunks_do_not_change_the_loss(variant):
-    cfg, params, bias, batch = _setup(experts_held=4)
-    f = lambda c: jax.value_and_grad(
-        lambda p: lfm2.loss_fn(p, batch, c, {"bias": bias}))(params)
-    (plain, g0), (other, g1) = f(cfg), f(cfg.replace(**variant))
+    (plain, g0), (other, g1) = _loss_and_grads(), _loss_and_grads(**variant)
     assert abs(float(plain) - float(other)) < 1e-5 * float(plain)
     assert float(ref.relative_distance(g1, g0)) < 1e-4
 
@@ -145,10 +164,9 @@ def test_the_tied_leafs_gradient_is_the_sum_of_an_untied_pairs():
                                     {"bias": bias}, batch["tokens"], cfg)
         return lfm2._lm.next_token_loss(x, head.T, batch, 0, cfg.dtype)
 
-    g_lookup, g_head = jax.grad(untied, (0, 1))(params["embed"],
-                                                params["embed"])
-    tied = jax.grad(lambda p: lfm2.loss_fn(p, batch, cfg, {"bias": bias}))(
-        params)["embed"]
+    g_lookup, g_head = jax.jit(jax.grad(untied, (0, 1)))(params["embed"],
+                                                         params["embed"])
+    tied = _loss_and_grads()[1]["embed"]
     assert float(jnp.linalg.norm(g_lookup)) > 0
     assert float(jnp.linalg.norm(g_head)) > 0
     np.testing.assert_allclose(np.asarray(tied),
@@ -159,7 +177,8 @@ def test_the_tied_leafs_gradient_is_the_sum_of_an_untied_pairs():
 
 def test_the_tied_matrix_counts_once_and_the_published_count_is_the_cards():
     cfg = lfm2.lfm2_tiny()
-    params = lfm2.init_params(cfg, jax.random.key(0))
+    params = jax.jit(lambda key: lfm2.init_params(cfg, key))(
+        jax.random.key(0))
     assert lfm2.num_params(cfg) == sum(a.size for a in jax.tree.leaves(params))
     whole = lfm2.Lfm2Config()
     assert whole.kinds.count(lfm2.FULL) == 10
@@ -324,8 +343,10 @@ def test_gated_conv_sees_nothing_after_t_and_nothing_of_another_row():
 
 # --------------------------------------------------- the expert layer
 
+@functools.lru_cache(maxsize=None)
 def _layer_inputs(cfg, seed=9):
-    layer = lfm2.init_params(cfg, jax.random.key(seed))["layers"][1]
+    layer = jax.jit(lambda key: lfm2.init_params(cfg, key)["layers"][1])(
+        jax.random.key(seed))
     h = jax.random.normal(jax.random.key(seed + 1), (2, 24, cfg.hidden))
     bias = 0.3 * jax.random.normal(jax.random.key(seed + 2),
                                    (cfg.num_experts,))

@@ -12,7 +12,10 @@ import ray_tpu
 
 
 @pytest.fixture
-def logged_runtime():
+def logged_runtime(tmp_path, monkeypatch):
+    # A session directory of its own: every cluster that starts under the
+    # default one re-points its ``session_latest``, other workers' too.
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(tmp_path))
     rt = ray_tpu.init(num_cpus=2)
     yield rt
     ray_tpu.shutdown()

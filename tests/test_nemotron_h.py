@@ -5,6 +5,7 @@ un-gated experts in ``ops/moe.py``, flash attention at 16 query heads a key
 head, the share of an expert-parallel layer, and the train step's state and
 report."""
 
+import functools
 import os
 import sys
 
@@ -39,24 +40,34 @@ def _sizes(cfg):
             "route_scale": cfg.route_scale, "eps": cfg.norm_eps}
 
 
+@functools.lru_cache(maxsize=None)
 def _setup(seed=0, rows=2, seq=56, **kw):
     """Tiny widths that keep two heads a state group, a head size that is not
     the state size, 16 query heads a key head, 8 experts, the pattern
-    ``MEM*EM`` and a row of three chunks of 16 plus a remainder of 8."""
+    ``MEM*EM`` and a row of three chunks of 16 plus a remainder of 8.
+    Made once a configuration of this module (nothing writes into what it
+    returns), the parameters under one ``jax.jit``: run eagerly the
+    initialisation is one program a leaf shape."""
     cfg = nemotron_h.nemotron_h_tiny(**kw)
-    params = nemotron_h.init_params(cfg, jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), 64))
 
-    def shake(path, a):
-        name = str(path[-1])
-        if "norm" in name or "'D'" in name:     # away from one
-            return a * (1 + 0.2 * jax.random.normal(next(keys), a.shape))
-        return a
+    @jax.jit
+    def make(key, shake_key):
+        params = nemotron_h.init_params(cfg, key)
+        keys = iter(jax.random.split(shake_key, 64))
 
-    params = jax.tree_util.tree_map_with_path(shake, params)
-    # A selection bias large enough to change which experts are chosen.
-    bias = 0.3 * jax.random.normal(
-        next(keys), (cfg.expert_layers, cfg.num_experts))
+        def shake(path, a):
+            name = str(path[-1])
+            if "norm" in name or "'D'" in name:     # away from one
+                return a * (1 + 0.2 * jax.random.normal(next(keys), a.shape))
+            return a
+
+        params = jax.tree_util.tree_map_with_path(shake, params)
+        # A selection bias large enough to change which experts are chosen.
+        bias = 0.3 * jax.random.normal(
+            next(keys), (cfg.expert_layers, cfg.num_experts))
+        return params, bias
+
+    params, bias = make(jax.random.key(seed), jax.random.key(seed + 1))
     rng = np.random.default_rng(seed)
     batch = {"tokens": jnp.asarray(rng.integers(
         0, cfg.vocab_size, (rows, seq), dtype=np.int32)),
@@ -131,10 +142,11 @@ def test_model_matches_reference_loss_and_every_gradient():
 
 def test_remat_rows_at_a_time_and_loss_chunks_do_not_change_the_loss():
     cfg, params, bias, batch = _setup(experts_held=4)
-    plain = nemotron_h.loss_fn(params, batch, cfg, {"bias": bias})
-    other = nemotron_h.loss_fn(
-        params, batch, cfg.replace(remat="full", layer_rows=1, loss_chunks=4),
-        {"bias": bias})
+    run = jax.jit(lambda p, c: nemotron_h.loss_fn(p, batch, c, {"bias": bias}),
+                  static_argnums=1)
+    plain = run(params, cfg)
+    other = run(params,
+                cfg.replace(remat="full", layer_rows=1, loss_chunks=4))
     assert abs(float(plain) - float(other)) < 1e-5 * float(plain)
 
 
@@ -455,7 +467,8 @@ def test_a_mesh_and_a_pipeline_are_refused_by_name():
 def test_mamba_start_is_the_published_one():
     cfg = nemotron_h.nemotron_h_tiny(mamba_heads=256, mamba_head_dim=2,
                                      ssm_groups=2, layers=1, pattern="M")
-    layer = nemotron_h.init_params(cfg, jax.random.key(1))["layers"][0]
+    layer = jax.jit(lambda key: nemotron_h.init_params(cfg, key))(
+        jax.random.key(1))["layers"][0]
     A = np.exp(np.asarray(layer["A_log"]))
     step = np.asarray(jax.nn.softplus(layer["dt_bias"]))
     assert 1.0 <= A.min() < 2.5 and 14.0 < A.max() <= 16.0

@@ -7,6 +7,7 @@ scopes the readers sum; the cell's rehearsal; the int8 control."""
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import subprocess
@@ -33,9 +34,10 @@ LEAVES = sorted("/".join(str(k.key) for k in path) for path, _ in
                     ouro.param_shapes(CFG), is_leaf=ouro._is_shape)[0])
 
 
+@functools.lru_cache(maxsize=None)
 def _weights(seed=5):
     """float32 weights in the program's layout, norms off 1 so that their
-    gradients say something."""
+    gradients say something.  Made once a seed: nothing writes into them."""
     w = jax.tree.map(lambda a: a.astype(jnp.float32),
                      archs.make_weights(arch.shapes(S), seed))
     keys = iter(jax.random.split(jax.random.key(seed), 16))
@@ -53,6 +55,12 @@ def _batch(seed=1, rows=2, seq=32):
     return {"tokens": tokens, "loss_mask": mask}
 
 
+# The program's loss, report and gradients, one compiled program a
+# configuration: run eagerly it is a program an operation.
+_value_and_grads = jax.jit(jax.value_and_grad(
+    ouro.loss_and_report, has_aux=True), static_argnums=2)
+
+
 def _at(tree, leaf):
     for k in leaf.split("/"):
         tree = tree[k]
@@ -63,13 +71,12 @@ def _at(tree, leaf):
 def both():
     """(program, reference): each (loss, report, gradient of every leaf)."""
     w, batch = _weights(), _batch()
-    (loss, report), grads = jax.value_and_grad(
-        ouro.loss_and_report, has_aux=True)(w, batch, CFG)
+    (loss, report), grads = _value_and_grads(w, batch, CFG)
     with jax.default_matmul_precision("highest"):
-        (want, want_report), want_grads = jax.value_and_grad(
+        (want, want_report), want_grads = jax.jit(jax.value_and_grad(
             lambda w: ref.loss_and_report(w, batch["tokens"],
                                           batch["loss_mask"], S),
-            has_aux=True)(w)
+            has_aux=True))(w)
     return (loss, report, grads), (want, want_report, want_grads)
 
 
@@ -130,16 +137,16 @@ def test_the_walk_is_the_whole_function(both):
 def test_forward_is_the_last_pass():
     w, batch = _weights(), _batch()
     with jax.default_matmul_precision("highest"):
-        want = ref.logits(w, batch["tokens"], S)
-    np.testing.assert_allclose(ouro.forward(w, batch["tokens"], CFG), want,
-                               atol=2e-5)
+        want = jax.jit(lambda w: ref.logits(w, batch["tokens"], S))(w)
+    np.testing.assert_allclose(
+        jax.jit(lambda w: ouro.forward(w, batch["tokens"], CFG))(w), want,
+        atol=2e-5)
 
 
 def test_one_loop_is_plain_cross_entropy_and_moves_no_gate():
     w, batch = _weights(), _batch()
     cfg = CFG.replace(loops=1)
-    (loss, report), grads = jax.value_and_grad(
-        ouro.loss_and_report, has_aux=True)(w, batch, cfg)
+    (loss, report), grads = _value_and_grads(w, batch, cfg)
     h, = ouro.hidden_states(w, batch["tokens"], cfg)
     plain = _lm.next_token_loss(h, w["lm_head"], batch, 0, cfg.dtype)
     assert float(loss) == pytest.approx(float(plain), rel=1e-6)
@@ -174,7 +181,7 @@ def test_tied_gradient_is_the_sum_over_untied_copies(both):
                              S["beta"])[0]
 
     with jax.default_matmul_precision("highest"):
-        per_copy = jax.grad(untied)([wide["blocks"]] * S["T"])
+        per_copy = jax.jit(jax.grad(untied))([wide["blocks"]] * S["T"])
     assert len(per_copy) == S["T"]
     summed = jax.tree.map(lambda *g: sum(g), *per_copy)
     for name, got in grads["blocks"].items():
@@ -209,9 +216,8 @@ def test_loss_chunks_do_not_change_the_per_token_nll(chunks):
     ids=lambda o: "-".join(f"{k}={v}" for k, v in o.items()))
 def test_remat_and_loss_chunks_do_not_change_loss_or_gradient(both, options):
     (want, want_report, want_grads), _ = both
-    (loss, report), grads = jax.value_and_grad(
-        ouro.loss_and_report, has_aux=True)(_weights(), _batch(),
-                                            CFG.replace(**options))
+    (loss, report), grads = _value_and_grads(_weights(), _batch(),
+                                             CFG.replace(**options))
     assert float(loss) == pytest.approx(float(want), rel=1e-6)
     np.testing.assert_allclose(report["loop_loss"], want_report["loop_loss"],
                                rtol=1e-6)
@@ -408,15 +414,16 @@ def test_a_pass_left_out_or_a_loss_on_the_last_pass_alone_is_called_wrong():
     limits = CONTROL_CELL[1]["correct"]
     w, batch = _weights(), _batch()
     with jax.default_matmul_precision("highest"):
-        _, want = ref.loss_and_report(w, batch["tokens"],
-                                      batch["loss_mask"], S)
+        _, want = jax.jit(lambda w: ref.loss_and_report(
+            w, batch["tokens"], batch["loss_mask"], S))(w)
     want = train_loop.loop_readings(want)
-    _, sound = ouro.loss_and_report(w, batch, CFG)
+    (_, sound), _ = _value_and_grads(w, batch, CFG)
     d = train_loop.loop_distances(train_loop.loop_readings(sound), want)
     assert d["loop_loss_distance"] < limits["loop_loss_distance"]
     assert d["exit_share_distance"] < limits["exit_share_distance"]
     # a pass fewer: the last reported pass is the third's state run again
-    _, short = ouro.loss_and_report(w, batch, CFG.replace(loops=3))
+    _, short = jax.jit(ouro.loss_and_report, static_argnums=2)(
+        w, batch, CFG.replace(loops=3))
     short = train_loop.loop_readings(short)
     padded = {k: (v + v[-1:] if isinstance(v, list) else v)
               for k, v in short.items()}

@@ -424,10 +424,15 @@ class TestTrainWorker:
         [proc] = stalls["processes"]
         assert proc["periods"] == 5 and proc["max_step"] == 3
         [stall] = stalls["stalls"]
-        assert stall["step"] == 3 and 2.5 < stall["seconds"] < 3.5
+        # no upper bound: a loaded host only lengthens the pause
+        assert stall["step"] == 3 and stall["seconds"] > 2.5
         # 2.6 s hold at least one flusher tick of 2 s
         assert "worker_flush" in {o["name"] for o in stall["overlapping"]}
-        assert stall["samples"]["difference"]["cpu_user_s"] >= 0
+        # bracketed where the flusher's first tick (2 s) came before step 3,
+        # which on a loaded host the clock decides: ``_bracket``'s own tests
+        # are above
+        difference = stall["samples"].get("difference")
+        assert difference is None or difference["cpu_user_s"] >= 0
 
 
 def test_the_new_instruments_are_spans_and_no_series():

@@ -1,9 +1,9 @@
 """``ling-3.0-flash.train-kda8k``'s train step compiles for a described v5e,
-without a chip (``tests/test_tpu_compile.py`` has the why and the how).  A
-file of its own: ``--dist loadfile`` keeps a file on one worker.  The
-fixtures and the helpers are that file's, imported: describing the topology
-happens inside the fixture, in the worker that is given THIS file, never
-while a module is imported.
+without a chip.  A file a cell: ``--dist loadfile`` keeps a file on one
+worker, and the step is compiled here and nowhere else.  The fixtures and
+the readers of a compiled program's text are ``tests/v5e_compile.py``'s,
+imported: describing the topology happens inside the fixture, in the worker
+that is given THIS file, never while a module is imported.
 """
 
 from __future__ import annotations
@@ -13,13 +13,14 @@ import os
 
 import pytest
 
-from test_tpu_compile import ROOT, _cell_step, _kernels, topo  # noqa: F401
+from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
+    ROOT, _cell_step, _kernels, topo)
 
 CELL = "ling-3.0-flash.train-kda8k"
 
 
 @pytest.fixture(scope="module")
-def ling_step(topo):  # noqa: F811
+def ling_step(topo):
     """The cell's step (1 dense + 6 sparse layers, six KDA mixers and one
     of latent attention, 16 of 512 experts, rows of 8,192, full remat, the
     delta rule's Pallas pair, flash at 128 + 64 / 128, Pallas grouped

@@ -1,10 +1,9 @@
-"""``kanana-2-30b-a3b.train-mla8k``'s train step compiles for a described
-v5e, without a chip (``tests/test_tpu_compile.py`` has the why and the
-how).  A file of its own: ``--dist loadfile`` keeps a file on one worker, and
-that file's compiles already take the longest of the suite (ROADMAP D9 (5)).
-The fixtures and the helpers are that file's, imported: describing the
-topology happens inside the fixture, in the worker that is given THIS file,
-never while a module is imported.
+"""``kanana-2-30b-a3b.train-mla8k``'s train step compiles for a described v5e,
+without a chip.  A file a cell: ``--dist loadfile`` keeps a file on one
+worker, and the step is compiled here and nowhere else.  The fixtures and
+the readers of a compiled program's text are ``tests/v5e_compile.py``'s,
+imported: describing the topology happens inside the fixture, in the worker
+that is given THIS file, never while a module is imported.
 """
 
 from __future__ import annotations
@@ -15,15 +14,15 @@ import re
 
 import pytest
 
-from test_tpu_compile import (ROOT, _cell_step, _kernels,  # noqa: F401
-                              _assert_the_flash_kernels_walk_tiles,
-                              _q_sized_copies, topo)
+from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
+    ROOT, _assert_the_flash_kernels_walk_tiles, _cell_step, _kernels,
+    _q_sized_copies, topo)
 
 CELL = "kanana-2-30b-a3b.train-mla8k"
 
 
 @pytest.fixture(scope="module")
-def kanana_step(topo):  # noqa: F811
+def kanana_step(topo):
     """The cell's step (1 dense + 11 expert layers, 16 of 128 experts, rows
     of 8,192, full remat, flash at 128 + 64 / 128, Pallas grouped
     products)."""

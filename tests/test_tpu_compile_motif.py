@@ -1,9 +1,9 @@
 """``motif-3-beta.train-gdla8k``'s train step compiles for a described v5e,
-without a chip (``tests/test_tpu_compile.py`` has the why and the how).  A
-file of its own: ``--dist loadfile`` keeps a file on one worker.  The
-fixtures and the helpers are that file's, imported: describing the topology
-happens inside the fixture, in the worker that is given THIS file, never
-while a module is imported.
+without a chip.  A file a cell: ``--dist loadfile`` keeps a file on one
+worker, and the step is compiled here and nowhere else.  The fixtures and
+the readers of a compiled program's text are ``tests/v5e_compile.py``'s,
+imported: describing the topology happens inside the fixture, in the worker
+that is given THIS file, never while a module is imported.
 """
 
 from __future__ import annotations
@@ -14,13 +14,14 @@ import re
 
 import pytest
 
-from test_tpu_compile import ROOT, _cell_step, _kernels, topo  # noqa: F401
+from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
+    ROOT, _cell_step, _kernels, topo)
 
 CELL = "motif-3-beta.train-gdla8k"
 
 
 @pytest.fixture(scope="module")
-def motif_step(topo):  # noqa: F811
+def motif_step(topo):
     """The cell's step (4 sparse layers on a four-lane stream, 80 query
     heads over 16 key heads at 128 + 64 / 128, three window layers and one
     full under one scanned body, 8 of 384 experts, one row of 8,192, full
