@@ -21,8 +21,9 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   128 x 128 tile of the other operand as the shapes offer.  Under
   grouped-query attention a grid row is a *key* head and a step holds the
   ``block_q`` rows of all its query heads (up to 8), stacked: K / V blocks
-  are fetched once a group, the group shares one mask, and dk / dv add up
-  inside the kernel and leave per key head in the inputs' dtype.  With no
+  are fetched once a group, the group shares one mask, and the pair's
+  dk / dv add up inside the kernel and leave per key head in the inputs'
+  dtype (the one pass, below, takes a query head a row).  With no
   group to stack (and no window) the blocks are 1,024 x 1,024.  Anything
   else keeps 512 x 512 and a head a row.  Each traced kernel counts its
   geometry in ``ray_tpu_flash_step_geometry_total``.
@@ -148,11 +149,16 @@ FlashAttention-2 on TPU, forward *and* backward as pallas kernels:
   block ``i``'s last step and leaves then, so nothing of dq crosses HBM
   but the result (upstream's splash dk/dv kernel carries an optional dq
   too, as per-k-block partials in HBM that are summed outside).  It
-  serves the causal square (``Sq == Sk``, no offset, no window) with
-  ``block_q == block_k``, which is where a q tile's last k block is its
-  own; a group's stacked heads (the scratch would be 8 - 32 MiB a row), a
-  window, a ring shard's offset or rectangle and ``causal=False`` keep
-  the pair, and trace what they traced.
+  serves the causal square (``Sq == Sk``, no offset) with ``block_q ==
+  block_k``, which is where a q tile's last k block is its own.  Under a
+  group a grid row is one query head all the same (the stacked group's dq
+  would be 8 - 32 MiB a row): it reads its key head's blocks, its share
+  of dk / dv leaves in float32 and the shares are summed outside, the sum
+  the pair makes in its float32 accumulator (PERF.md, PR 60: 20 - 23 %
+  off the backward at the four grouped cells' shapes, 12 % under
+  Trinity-Mini's window).  A ring shard's offset or rectangle,
+  ``causal=False``, a row whose dq does not fit and a window with no group
+  or at a head size over 128 keep the pair, and trace what they traced.
 
 ``q_offset`` shifts query positions for causal masking so sequence-sharded
 callers (ring attention) can flash-attend a mid-sequence Q shard.
@@ -444,18 +450,60 @@ def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
       that contracts over 64 fills half of the MXU's 128 x 128 tile.
     - ``bwd``: the K-major walk makes dq too, dk/dv's geometry at
       ``_BWD_WALK`` tiles a step at the most, where a grid row is one query
-      head (a group's stacked heads would want 8 - 32 MiB of dq a row),
-      there is no window, ``Sq == Sk`` (with the blocks equal, q tile ``i``
-      is then complete at k block ``i``; ``_flash_backward`` adds that the
-      call is causal with no offset) and the row's float32 dq fits
-      ``_DQ_ROW``: [Sq, D] lane-padded, a call in parts' rotary lanes lying
-      along the lanes, [Dr, Sq], for nothing.  Yi's and Ouro's
-      [4, 16, 4096, 128] hold 2 MiB, latent attention's [1, 32, 8192] in
-      parts 4 + 2; 128-wide rows over 12,288 tokens, a 192-wide call in
-      one part over 6,144 and every stacked group keep the pair."""
+      head, ``Sq == Sk`` (with the blocks equal, q tile ``i`` is then
+      complete at k block ``i``; ``_flash_backward`` adds that the call is
+      causal with no offset) and the row's float32 dq fits ``_DQ_ROW``:
+      [Sq, D] lane-padded, a call in parts' rotary lanes lying along the
+      lanes, [Dr, Sq], for nothing.  Yi's and Ouro's [4, 16, 4096, 128]
+      hold 2 MiB, latent attention's [1, 32, 8192] in parts 4 + 2; 128-wide
+      rows over 12,288 tokens and a 192-wide call in one part over 6,144
+      keep the pair.
+    - ``bwd`` under a GROUP at a head size of 128 or under: a grid row is
+      ONE query head all the same (the pair stacks the group, whose dq
+      would want 8 - 32 MiB a row), 512 x 512 and the most of 4, 2, 1 tiles
+      a step, reading its key head's resident K / V block; each query
+      head's share of dk/dv leaves in float32 and the shares are summed
+      outside, the float32 sum the pair makes inside its kernel.  From the
+      chip's table (PERF.md, PR 60, step 0; head-major, ms a call of the
+      backward's kernels / with delta and the shares' sum too / the scoped
+      VMEM of the default's 16 MiB, no limit stated):
+
+          [1, 32 / 2, 8192, 128]: the pair 11.008 / 11.325; the one pass
+            8.920 / 9.570 at 1 tile, 8.584 / 9.234 at 2, 8.396 / 9.046 /
+            7.7 MiB at 4; 8.392 / 9.042 / 12.8 at 1,024 x 1,024
+          [1, 32 / 4, 8192, 128]: 11.043 / 11.331; 8.920 / 9.601, 8.584 /
+            9.266, 8.396 / 9.078 / 7.7; 8.392 / 9.075 / 12.8
+          [1, 32 / 8, 4096, 128]: 3.124 / 3.315; 2.395 / 2.671, 2.311 /
+            2.587, 2.262 / 2.537 / 5.5; 2.362 / 2.637 / 10.3
+          [4, 32 / 8, 8192, 64]: 45.887 / 49.358; 37.109 / 41.905, 35.111 /
+            39.905, 34.408 / 39.204 / 9.0; 33.948 / 38.744 / 13.8
+
+      (the pair's dq holds 20.0 of a stated 96 MiB at 8 stacked heads).  So
+      -20 % at 8,192 x 128 whatever the group (16 shares or 8), -23 % at
+      Mistral's 4,096, -21 % at LFM2's 64; 1,024 x 1,024 is 1 % ahead at 64
+      and 4 % behind at 4,096 for half as much VMEM again, and four tiles
+      of 512 x 512 ship everywhere.  Two heads a grid row at Mistral's shape
+      (their dq 4 MiB; dk/dv's shares halved) read 2.188 / 2.370 / 11.8 at 4
+      tiles, 7 % under one head's: not shipped, the kernel body would index
+      its dq scratch by head for 0.3 % of one cell's step (ROADMAP S6 (b)).
+    - ``bwd`` under a group AND a window (Trinity-Mini's 2,048 at
+      [1, 32 / 4, 8192, 128]): a q tile is still complete at its diagonal k
+      block, the table drops the pairs left of the band, and the same
+      kernel body serves: the pair 5.847 / 6.135, the one pass 4.742 /
+      5.427 / 7.0 at ONE tile a step (-12 %), 4.959 / 5.645 at 2 and 5.602
+      / 6.288 at 4, whose major blocks reach past the band's lower edge
+      and walk tiles that hold nothing.  So one tile a step under a
+      window.  A window with no group at 128 and any window over 128 keep
+      the pair: not measured, no caller."""
     if kind == "bwd":
         t = _tiles("dkv", Sq, Sk, D, group, window)
-        if (t.heads > 1 or window is not None or Sq != Sk
+        if D <= LANES and t.heads > 1:
+            # A group's query heads a grid row each, in dk/dv's blocks.
+            t = Tiles(t.block_q, t.block_k, 1, "kq",
+                      _walked(Sq // t.block_q, window))
+        elif window is not None:
+            return None
+        if (t.heads > 1 or Sq != Sk
                 or Sq * (-(-(D - Dr) // LANES) * LANES + Dr) * 4 > _DQ_ROW):
             return None
         return t._replace(tiles=min(t.tiles, _BWD_WALK))
@@ -470,9 +518,8 @@ def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
             if Sq % tall == 0 and Sk % wide == 0:
                 return Tiles(tall, wide, stacked, scores)
         streamed = Sq // block_q if kind == "dkv" else Sk // block_k
-        tiles = 1 if window is not None else next(
-            n for n in _WALK if streamed % n == 0)
-        return Tiles(block_q, block_k, heads, scores, tiles)
+        return Tiles(block_q, block_k, heads, scores,
+                     _walked(streamed, window))
     heads = stacked
     if heads > 1:
         if (kind == "fwd" and heads * block_q * block_k > _FWD_SCORES
@@ -482,6 +529,15 @@ def _tiles(kind, Sq, Sk, D, group, window=None, Dr=0):
           and Sq % _BIG_BLOCK == 0 and Sk % _BIG_BLOCK == 0):
         block_q = block_k = _BIG_BLOCK
     return Tiles(block_q, block_k, heads, scores)
+
+
+def _walked(streamed, window):
+    """The tiles a grid step walks: the most of ``_WALK`` that divide the
+    streamed side's blocks; one under a window, where a major block would
+    reach past the band."""
+    if window is not None:
+        return 1
+    return next(n for n in _WALK if streamed % n == 0)
 
 
 class Dims(NamedTuple):
@@ -547,11 +603,14 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
     if Sq % t.block_q or Sk % t.block_k:
         raise ValueError(f"seq ({Sq},{Sk}) not divisible by blocks "
                          f"({t.block_q},{t.block_k})")
+    # the grid rows whose dk / dv are summed into a key head's outside
+    shares = H // Hkv // t.heads if kind in ("dkv", "bwd") else 1
     telemetry.inc("ray_tpu_flash_step_geometry_total", tags={
         "kernel": _kernel_name(f"flash_{kind}", window, D, Dv),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
         **({"tiles_a_step": str(t.tiles)} if t.tiles > 1 else {}),
+        **({"shares": str(shares)} if shares > 1 else {}),
         **({"d_qk": str(D), "d_v": str(Dv)} if D != Dv
            else {} if D == LANES else {"d": str(D)}),
         **({"parts": f"{D - Dr}+{Dr}", "rows": "qkvo"} if Dr

@@ -455,7 +455,7 @@ CATALOG: Dict[str, Dict[str, Any]] = {
         "type": "counter",
         "tag_keys": ("kernel", "block_q", "block_k", "heads_a_step",
                      "scores", "d_qk", "d_v", "d", "rows", "parts",
-                     "tiles_a_step"),
+                     "tiles_a_step", "shares"),
         "description": "Flash-attention kernels traced, by the geometry "
                        "of a grid step that ops/attention._tiles chose "
                        "from the call's shapes (kernel: flash_fwd, and "
@@ -464,8 +464,14 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "tiles; scores: qk or kq; "
                        "tiles_a_step only where a grid step fetches a "
                        "major block of that many block_q x block_k tiles "
-                       "of the streamed side and walks them, which a head "
-                       "size over 128 does: latent attention's; d_qk "
+                       "of the streamed side and walks them: a head size "
+                       "over 128, latent attention's, and the one pass "
+                       "under a group; shares "
+                       "only on a flash_dkv or flash_bwd whose grid rows "
+                       "hold fewer query heads than a key head has: that "
+                       "many rows' dk / dv leave in float32 and are summed "
+                       "outside, which is how the one pass serves a group; "
+                       "d_qk "
                        "and d_v only where a call's values are not as "
                        "wide as its keys; d only where the one head size "
                        "is not 128; rows=vo only where the kernel "

@@ -463,15 +463,21 @@ def test_a_64_wide_call_says_so_in_its_names_and_tags():
     assert att._kernel_name("flash_dq", 2048, 128, 128) == "flash_dq_w2048"
     assert att._kernel_name("flash_fwd", None, 192, 128) == \
         "flash_fwd_d192v128"
+    # LFM2's backward is the one pass under its group of four (PR 60)
+    assert att._kernel_name("flash_bwd", None, 64, 64) == "flash_bwd_d64"
     from ray_tpu.util import metrics as metrics_mod
     metrics_mod._reset_for_tests()
     x = jnp.ones((1, 4, 64, 64), jnp.float32)
-    flash_attention(x, x[:, :1], x[:, :1], interpret=True)
+    jax.grad(lambda x: jnp.sum(flash_attention(
+        x, x[:, :1], x[:, :1], interpret=True)))(x)
     y = jnp.ones((1, 4, 128, 128), jnp.float32)
     flash_attention(y, y[:, :1], y[:, :1], interpret=True)
     text = metrics_mod.prometheus_text()
     lines = [l for l in text.splitlines()
              if l.startswith("ray_tpu_flash_step_geometry_total{")]
+    bwd, = [l for l in lines if "flash_fwd" not in l]
+    assert 'kernel="flash_bwd_d64"' in bwd and 'shares="4"' in bwd
+    assert 'd="64"' in bwd and 'heads_a_step="1"' in bwd
     d64 = [l for l in lines if 'kernel="flash_fwd_d64"' in l]
     d128 = [l for l in lines if 'kernel="flash_fwd"' in l]
     assert d64 and all('d="64"' in l for l in d64)

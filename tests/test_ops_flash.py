@@ -355,6 +355,13 @@ TILES = {
     "group3_short": ((192, 192, 32, 3, None), [(192, 192, 3)] * 3),
 }
 TILES.update({
+    # the grouped cells' other shapes (PR 60): forward, dq and dk/dv stack
+    "nemotron_8192_group16": ((8192, 8192, 128, 16, None),
+                              [(512, 256, 8), (512, 512, 8), (512, 512, 8)]),
+    "lfm2_8192_group4_d64": ((8192, 8192, 64, 4, None),
+                             [(512, 512, 4)] * 3),
+    "group4_16k": ((16384, 16384, 128, 4, None), [(512, 512, 4)] * 3),
+    "group4_ring_shard": ((4096, 8192, 128, 4, None), [(512, 512, 4)] * 3),
     # a group under a head size over 128 (PR 57): a head a row and the walk
     # without a window; under a window narrower than a block the group's
     # five heads one step, 128 x 256 (dk/dv 256 x 128)
@@ -374,13 +381,20 @@ TILES.update({
 # The backward of the same shapes (PR 54): the one pass's (block_q, block_k,
 # heads a step, tiles a step), ``flash_bwd`` in place of dq and dk/dv where
 # the call is the causal square, or None: the pair.  One query head a grid
-# step, no window, Sq == Sk and the row's float32 dq, lane-padded, inside
-# ``_DQ_ROW`` (6 MiB); four tiles a step at the most.
+# step, Sq == Sk and the row's float32 dq, lane-padded, inside ``_DQ_ROW``
+# (6 MiB); four tiles a step at the most; a window only under a group at a
+# head size of 128 or under.
 ONE_PASS = {
     "yi_4096": (1024, 1024, 1, 1),              # 2 MiB of dq a row
-    "mistral_4096_group4": None,                # groups stacked: the pair
-    "trinity_8192_group8": None,
-    "trinity_8192_group8_window": None,
+    # a group at 128 or under (PR 60): a query head a grid row all the
+    # same, dk/dv's shares summed outside; one tile a step under a window
+    "mistral_4096_group4": (512, 512, 1, 4),
+    "trinity_8192_group8": (512, 512, 1, 4),
+    "trinity_8192_group8_window": (512, 512, 1, 1),
+    "nemotron_8192_group16": (512, 512, 1, 4),
+    "lfm2_8192_group4_d64": (512, 512, 1, 4),   # [8192, 64] pads to 4 MiB
+    "group4_16k": None,                         # 8 MiB of dq a row
+    "group4_ring_shard": None,
     "tokens_128k": None,                        # 64 MiB of dq a row
     "ring_shard": None,                         # a rectangle
     "not_divided_4608": (512, 512, 1, 1),
@@ -395,8 +409,8 @@ ONE_PASS = {
     "latent_1536": (512, 512, 1, 1),
     "latent_ring_shard": None,
     "latent_window": None,
-    "group16": None,
-    "group3_short": None,
+    "group16": (512, 512, 1, 4),
+    "group3_short": (192, 192, 1, 1),
     # the budget: 6 MiB of dq fit, 8 and 16 do not
     "tokens_12k": (1024, 1024, 1, 1),
     "tokens_16k": None,
@@ -453,14 +467,21 @@ def test_default_geometry_off_the_causal_square(causal, q_offset):
         np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
 
 
+@pytest.mark.parametrize("backward", ["one_pass", "pair"])
 @pytest.mark.parametrize("window", [None, 96])
 @pytest.mark.parametrize("group", [4, 8])
-def test_a_key_heads_query_heads_share_a_step(group, window):
-    """Grouped-query attention: the group's heads are one grid step, its
-    rows stacked in forward and dq, its dk / dv added up inside the kernel
-    and handed out per key head in the inputs' dtype; against the
-    reference in float32 on the very inputs the kernels saw."""
+def test_a_key_heads_query_heads_share_a_step(group, window, backward,
+                                              monkeypatch):
+    """Grouped-query attention: the group's heads are one grid step of the
+    forward, their rows stacked.  The backward is the one pass (PR 60): a
+    query head a grid row, its share of dk / dv summed over the group
+    outside; where no row's dq fits (``_DQ_ROW`` 0 here) it is the pair,
+    the group stacked in dq and its dk / dv added up inside the kernel.
+    Either way dk / dv come back per key head in the inputs' dtype;
+    against the reference in float32 on the very inputs the kernels saw."""
     dtype = jnp.bfloat16
+    if backward == "pair":
+        monkeypatch.setattr(attention_ops, "_DQ_ROW", 0)
     q, k, v = _qkv(jax.random.key(11), B=2, H=2 * group, Hkv=2, S=192,
                    dtype=dtype)
     do = jax.random.normal(jax.random.key(12), q.shape, dtype)
@@ -486,20 +507,31 @@ def test_a_key_heads_query_heads_share_a_step(group, window):
     # Which geometry each kernel took is counted where it is chosen.
     after = _geometry_counts()
     w = "" if window is None else f"_w{window}"
-    for kernel, scores in (("fwd", "qk"), ("dq", "qk"), ("dkv", "kq")):
+    for kernel, scores in ((("fwd", "qk"), ("dq", "qk"), ("dkv", "kq"))
+                           if backward == "pair"
+                           else (("fwd", "qk"), ("bwd", "kq"))):
         # a head size that is not 128 (32 here) is in the name and a tag
         tags = (("block_k", "64"), ("block_q", "64"), ("d", "32"),
                 ("heads_a_step", str(group)), ("scores", scores))
+        if kernel == "bwd":
+            tags = tags[:3] + (("heads_a_step", "1"), ("scores", scores),
+                               ("shares", str(group)))
         name = f"flash_{kernel}_d32{w}"
         assert after[name][tags] > before.get(name, {}).get(tags, 0), name
 
 
-def test_group_wider_than_a_step_is_summed_outside():
-    """A group of more heads than a step takes (16 > 8): the steps hold 8,
-    dk / dv leave per step's heads in float32 and are summed after."""
+@pytest.mark.parametrize("backward", ["one_pass", "pair"])
+def test_group_wider_than_a_step_is_summed_outside(backward, monkeypatch):
+    """A group of more heads than a step takes (16 > 8): the pair's steps
+    hold 8, dk / dv leave per step's heads in float32 and are summed after
+    (two shares); the one pass sums sixteen."""
     q, k, v = _qkv(jax.random.key(13), B=1, H=16, Hkv=1, S=128)
     do = jax.random.normal(jax.random.key(14), q.shape)
     assert attention_ops._tiles("dkv", 128, 128, 32, 16).heads == 8
+    assert attention_ops._tiles("bwd", 128, 128, 32, 16).heads == 1
+    if backward == "pair":
+        monkeypatch.setattr(attention_ops, "_DQ_ROW", 0)
+        assert attention_ops._tiles("bwd", 128, 128, 32, 16) is None
 
     def grads(fn):
         return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * do),

@@ -118,8 +118,9 @@ def _eqns(jaxpr):
 
 def test_no_transpose_beside_the_kernels_in_a_llama_layer(monkeypatch):
     """The gradient of ``llama.attention_branch`` at a head size of 128:
-    between the projections and the seven kernels (the rotary pair forward
-    and back for q and k, flash forward, dq, dk/dv) nothing q-sized is
+    between the projections and the six kernels (the rotary pair forward
+    and back for q and k, flash forward and the backward's one pass, which
+    serves a group since PR 60) nothing q-sized is
     transposed: the rotary kernels place q and k, and flash reads v and
     ``do`` and writes ``out`` and ``dv`` as the projections hold them."""
     from ray_tpu.models import llama
@@ -151,7 +152,7 @@ def test_no_transpose_beside_the_kernels_in_a_llama_layer(monkeypatch):
                 turned.append(shape)
     assert sorted(kernels) == sorted(
         ["rope_to_heads"] * 2 + ["rope_from_heads"] * 2
-        + ["flash_fwd", "flash_dq", "flash_dkv"]), kernels
+        + ["flash_fwd", "flash_bwd"]), kernels
     assert not turned, turned
 
 

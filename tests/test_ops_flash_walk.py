@@ -111,6 +111,15 @@ def test_tiles_a_step_are_the_same_work(case, monkeypatch):
         np.testing.assert_allclose(a, b, atol=2e-4)
 
 
+def _kernels_since(before):
+    """{kernel, its head sizes and window off its name: its tags} of the
+    geometry counter's series that grew since ``before``."""
+    after = _geometry_counts()
+    return {re.sub(r"_[dw]\d.*", "", name): dict(tags)
+            for name in after for tags, n in after[name].items()
+            if n > before.get(name, {}).get(tags, 0)}
+
+
 # The one pass against the pair (PR 54): Dn, Dr, Dv, in parts, rows, the
 # walk (``_WALK``; None: blocks the call names, one tile a step), dtype.
 ONE_PASS_CASES = {
@@ -165,11 +174,7 @@ def test_one_pass_is_the_pairs_work(case, monkeypatch):
                 (q_n, q_r), (kv, k_r), None), q_n, q_r, kv, k_r)
         else:
             got = fwd_bwd(partial(flash, rows=rows), q, k, v)
-        after = _geometry_counts()
-        return got, {
-            re.sub(r"_d\d.*", "", name): dict(tags)
-            for name in after for tags, n in after[name].items()
-            if n > before.get(name, {}).get(tags, 0)}
+        return got, _kernels_since(before)
 
     one, kernels = call()
     assert sorted(kernels) == ["flash_bwd", "flash_fwd"]
@@ -214,6 +219,125 @@ def test_one_pass_is_the_pairs_work(case, monkeypatch):
             else 3e-2 * np.abs(b).max(), rtol=3e-2)
 
 
+# The one pass under a GROUP at a head size of 128 or under (PR 60): group,
+# key heads, head size, rows, dtype, window.  A query head a grid row, its
+# share of dk / dv leaving in float32 and summed over the group outside.
+GROUPED_CASES = {
+    "group2_d128": (2, 2, 128, False, jnp.float32, None),
+    "group4_d128_rows": (4, 1, 128, True, jnp.float32, None),
+    "group4_d128_bf16": (4, 2, 128, False, jnp.bfloat16, None),
+    "group8_d128_rows_bf16": (8, 1, 128, True, jnp.bfloat16, None),
+    "group16_d128": (16, 1, 128, False, jnp.float32, None),
+    "group2_d64_bf16": (2, 1, 64, False, jnp.bfloat16, None),
+    "group4_d64": (4, 2, 64, False, jnp.float32, None),
+    "group8_d64_rows": (8, 1, 64, True, jnp.float32, None),
+    "group16_d64_bf16": (16, 1, 64, False, jnp.bfloat16, None),
+    # the band's lower edge crosses a tile, 96 back; one tile a step
+    "group4_d128_window": (4, 1, 128, False, jnp.float32, 96),
+    "group8_d64_window_bf16": (8, 1, 64, False, jnp.bfloat16, 96),
+}
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_one_pass_under_a_group_is_the_pairs_work(case, monkeypatch):
+    """``flash_bwd`` on a grouped call at ``_tiles``' own geometry (tiles of
+    128 x 128 here, four a grid step and one under a window) against the
+    pair of the same call, which stacks the group and adds it up inside
+    dk/dv (``_DQ_ROW`` 0: no dq fits): dq as the un-grouped cases hold it,
+    dk and dv to the order of a float32 sum said in another order (the
+    pair adds tile by tile over the stacked heads, the one pass head by
+    head), in bfloat16 to the result's own rounding; and all three against
+    the reference's.  The counter names the one kernel and its shares."""
+    group, Hkv, D, rows, dtype, window = GROUPED_CASES[case]
+    B, H, S, block = 1, group * Hkv, 512, 128
+    ks = jax.random.split(jax.random.key(60), 4)
+    turn = lambda x: jnp.swapaxes(x, 1, 2)
+    q = jax.random.normal(ks[0], (B, H, S, D), dtype)
+    k, v = (jax.random.normal(key, (B, Hkv, S, D), dtype) for key in ks[1:3])
+    do = jax.random.normal(ks[3], (B, H, S, D), dtype)
+    monkeypatch.setattr(attention_ops, "_BLOCK", block)
+    flash = partial(flash_attention, interpret=True, scale=0.11,
+                    window=window, rows=rows)
+
+    def call():
+        before = _geometry_counts()
+        _, vjp = jax.vjp(flash, q, k, turn(v) if rows else v)
+        dq, dk, dv = vjp(turn(do) if rows else do)
+        return (dq, dk, turn(dv) if rows else dv), _kernels_since(before)
+
+    one, kernels = call()
+    assert sorted(kernels) == ["flash_bwd", "flash_fwd"]
+    tags = kernels["flash_bwd"]
+    assert (tags["heads_a_step"], tags["shares"], tags["scores"]) == (
+        "1", str(group), "kq")
+    assert tags.get("tiles_a_step") == (None if window else "4")
+    assert "shares" not in kernels["flash_fwd"]
+    monkeypatch.setattr(attention_ops, "_DQ_ROW", 0)
+    pair, kernels = call()
+    assert sorted(kernels) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    # the pair stacks up to 8 of a group: 16 are two stacks a key head
+    assert kernels["flash_dkv"].get("shares") == (
+        "2" if group == 16 else None)
+
+    f32 = lambda x: np.asarray(x, np.float32)
+    for a, b in zip(one, pair):
+        assert a.dtype == dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=1e-5 if dtype == jnp.float32 else 2e-2,
+            rtol=1e-5 if dtype == jnp.float32 else 2e-2)
+    want = jax.vjp(partial(reference_attention, scale=0.11, window=window),
+                   *(x.astype(jnp.float32) for x in (q, k, v)))[1](
+                       do.astype(jnp.float32))
+    for a, b in zip(one, want):
+        b = np.asarray(b)
+        np.testing.assert_allclose(
+            f32(a), b, atol=2e-4 if dtype == jnp.float32
+            else 3e-2 * np.abs(b).max(), rtol=3e-2)
+
+
+# What a grouped call's backward traces (PR 60): H, Hkv, Sq, Sk, D, and the
+# call's q_offset and window; True: the one pass.
+GROUPED_TRACES = {
+    "lfm2_32_8_d64": ((32, 8, 8192, 8192, 64, 0, None), True),
+    "nemotron_32_2": ((32, 2, 8192, 8192, 128, 0, None), True),
+    "trinity_32_4": ((32, 4, 8192, 8192, 128, 0, None), True),
+    "trinity_32_4_window": ((32, 4, 8192, 8192, 128, 0, 2048), True),
+    "mistral_32_8": ((32, 8, 4096, 4096, 128, 0, None), True),
+    "group2": ((4, 2, 1024, 1024, 128, 0, None), True),
+    # the shapes the rule leaves: the pair, the group stacked
+    "offset": ((8, 2, 1024, 2048, 128, 1024, None), False),
+    "rectangle": ((8, 2, 1024, 2048, 128, 0, None), False),
+    "dq_over_the_row": ((8, 2, 16384, 16384, 128, 0, None), False),
+    "window_no_group": ((2, 2, 8192, 8192, 128, 0, 2048), False),
+    "window_over_128": ((10, 2, 8192, 8192, 192, 0, 2048), False),
+}
+
+
+@pytest.mark.parametrize("case", GROUPED_TRACES)
+def test_what_a_grouped_calls_backward_traces(case):
+    """The traced program of a grouped call's gradient at the cells' shapes
+    names ``flash_bwd`` and no ``flash_dq`` / ``flash_dkv``, with each query
+    head's dk / dv a float32 result summed outside; the shapes the rule
+    leaves name the pair and no ``flash_bwd``."""
+    (H, Hkv, Sq, Sk, D, q_offset, window), one_pass = GROUPED_TRACES[case]
+    bf16 = partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    Dv = min(D, 128)
+    fn = partial(flash_attention, interpret=True, q_offset=q_offset,
+                 window=window)
+
+    def backward(q, k, v):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return vjp(jnp.ones_like(out))
+
+    text = str(jax.make_jaxpr(backward)(
+        bf16((1, H, Sq, D)), bf16((1, Hkv, Sk, D)), bf16((1, Hkv, Sk, Dv))))
+    names = set(re.findall(r"name=flash_(fwd|dq|dkv|bwd)", text))
+    assert names == ({"fwd", "bwd"} if one_pass else {"fwd", "dq", "dkv"})
+    if one_pass:
+        assert f"float32[{H},{Sk},{D}]" in text       # a share a query head
+        assert f"f32[1,{Hkv},{H // Hkv},{Sk},{D}]" in text
+
+
 # Calls the one pass leaves alone trace the parent's program (PR 54): the
 # sha256 of ``jax.make_jaxpr``'s text of each call's backward as commit
 # 760264c printed it (its length beside it), kernel bodies, block specs and
@@ -248,6 +372,9 @@ def test_calls_that_keep_the_pair_trace_the_parents_program(case,
                     interpret=True)
     q, k_long = bf16((1, 2, 256, 128)), bf16((1, 2, 512, 128))
     if case.startswith("group"):
+        # Since PR 60 a group takes the one pass where its row's dq fits;
+        # where none fits the group is stacked in the pair, as it was.
+        monkeypatch.setattr(attention_ops, "_DQ_ROW", 0)
         k = bf16((1, 8 // int(case[5:]), 256, 128))
         fn, args = flash, (bf16((1, 8, 256, 128)), k, k)
     elif case == "parts_offset":

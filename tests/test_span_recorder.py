@@ -586,7 +586,7 @@ class TestTrainPath:
         assert "jit_train_step" in text
         for scope in ("forward_backward", "optimizer", "embed", "block/attn",
                       "block/mlp", "final_norm", "loss", "flash_fwd_d16",
-                      "flash_dq_d16", "flash_dkv_d16"):   # heads of 16
+                      "flash_bwd_d16"):   # heads of 16, the one pass
             assert _has_scope(text, scope), scope
 
     def test_latent_attention_s_products_have_scopes_of_their_own(self):

@@ -355,7 +355,7 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
     assert telemetry.CATALOG[name]["type"] == "counter"
     assert tuple(telemetry.CATALOG[name]["tag_keys"]) == (
         "kernel", "block_q", "block_k", "heads_a_step", "scores", "d_qk",
-        "d_v", "d", "rows", "parts", "tiles_a_step")
+        "d_v", "d", "rows", "parts", "tiles_a_step", "shares")
     metrics_mod._reset_for_tests()
     q = jnp.ones((1, 2, 64, 128), jnp.float32)          # [B, H, S, D]
     for rows in (True, False):
@@ -383,6 +383,39 @@ def test_flash_geometry_counter_says_which_kernels_took_rows():
             lines, key=lambda l: "bwd" in l)):
         assert f'kernel="flash_{kernel}_d192v128"' in line, line
         assert 'parts="128+64"' in line and 'rows="qkvo"' in line, line
+    metrics_mod._reset_for_tests()
+
+
+@pytest.mark.parametrize("group,kernels", [
+    (1, {"flash_bwd": None}), (4, {"flash_bwd": "4"}),
+    (16, {"flash_bwd": "16"})])
+def test_flash_geometry_counter_says_the_shares_of_a_group(group, kernels):
+    """``shares`` (PR 60): the query-head grid rows a key head's dk / dv is
+    summed over outside the kernel, on the backward's kernel that leaves
+    them so and on no forward; absent where a grid row holds the whole
+    group (1).  ``counters.json`` of a run says by it which calls took the
+    one pass under a group."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+    from ray_tpu.util import metrics as metrics_mod
+
+    name = "ray_tpu_flash_step_geometry_total"
+    assert "shares" in telemetry.CATALOG[name]["tag_keys"]
+    assert "shares" in telemetry.CATALOG[name]["description"]
+    metrics_mod._reset_for_tests()
+    q = jnp.ones((1, group, 64, 128), jnp.float32)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, q[:, :1], q[:, :1], interpret=True)))(q)
+    lines = [l for l in metrics_mod.prometheus_text().splitlines()
+             if l.startswith(name + "{")]
+    assert not any("shares=" in l for l in lines if "flash_fwd" in l)
+    for kernel, shares in kernels.items():
+        mine, = [l for l in lines if f'kernel="{kernel}"' in l]
+        assert 'heads_a_step="1"' in mine
+        assert (f'shares="{shares}"' in mine) if shares else (
+            "shares=" not in mine), mine
+    assert len(lines) == 1 + len(kernels), lines
     metrics_mod._reset_for_tests()
 
 

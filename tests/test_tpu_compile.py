@@ -76,8 +76,9 @@ def test_flash_kernels_return_what_the_roofline_reader_matches(
     label: a kernel that returns anything its own pattern does not match,
     or that another's matches too, blinds or falsifies the per-layer
     metric.  At the benchmark cells' shapes.  The one pass's ``flash_bwd``
-    (Yi's shape; PR 54) returns dk, dv and dq and must match NONE of the
-    three patterns: the reader then reads the forward alone there, until a
+    (Yi's shape, PR 54; Mistral's group of four, PR 60, whose dk and dv are
+    float32 shares) returns dk, dv and dq and must match NONE of the three
+    patterns: the reader then reads the forward alone there, until a
     ``benchmark`` PR counts the new kernel (ROADMAP)."""
     import re
 
@@ -99,8 +100,7 @@ def test_flash_kernels_return_what_the_roofline_reader_matches(
         label = f"{name}<{','.join(types)}>"
         matched[kernel] = [which for which, pattern in KERNELS.items()
                            if re.search(pattern, label)]
-    assert matched == ({"fwd": ["fwd"], "bwd": []} if heads == kv_heads
-                       else {which: [which] for which in KERNELS})
+    assert matched == {"fwd": ["fwd"], "bwd": []}
 
 
 def test_flash_compiles_at_128k_tokens(one_chip):
@@ -160,8 +160,9 @@ def test_decode_step_compiles_at_smoke_shapes(one_chip, v5e_block_sizes,
 
 
 def test_windowed_flash_kernels_compile_and_are_named(one_chip):
-    """The three kernels at Trinity's shape with its window: in the compiled
-    program under names that tell them from the full-causal calls."""
+    """The kernels at Trinity's shape with its window (the forward and, since
+    PR 60, the backward's one pass): in the compiled program under names
+    that tell them from the full-causal calls."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
@@ -175,32 +176,39 @@ def test_windowed_flash_kernels_compile_and_are_named(one_chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    for name in ("flash_fwd_w2048", "flash_dq_w2048", "flash_dkv_w2048"):
+    for name in ("flash_fwd_w2048", "flash_bwd_w2048"):
         assert name in text, name
+    assert "flash_dq" not in text and "flash_dkv" not in text
 
 
-@pytest.mark.parametrize("batch,heads,kv_heads,seq,window", [
-    (4, 16, 16, 4096, None),    # yi-coder-1.5b.train-sft4k
-    (1, 32, 8, 4096, None),     # mistral-7b-v0.3.train-fsdp4, a chip's share
-    (1, 32, 4, 8192, None),     # trinity-mini.train-moe8k, a full layer
-    (1, 32, 4, 8192, 2048),     # ... and a window layer
+@pytest.mark.parametrize("batch,heads,kv_heads,seq,window,d", [
+    (4, 16, 16, 4096, None, 128),   # yi-coder-1.5b.train-sft4k
+    (1, 32, 8, 4096, None, 128),    # mistral-7b-v0.3.train-fsdp4, a chip's
+    (1, 32, 4, 8192, None, 128),    # trinity-mini.train-moe8k, a full layer
+    (1, 32, 4, 8192, 2048, 128),    # ... and a window layer
+    (1, 32, 2, 8192, None, 128),    # nemotron-3-nano-30b-a3b.train-ssm8k
+    (4, 32, 8, 8192, None, 64),     # lfm2-24b-a2b.train-conv8k
+    (1, 32, 8, 8192, None, 64),     # ... and its one-row check program's
 ])
 def test_flash_compiles_at_the_cells_shapes_one_kernel_a_name(
-        one_chip, batch, heads, kv_heads, seq, window):
-    """With the geometry ``_tiles`` picks at each cell's shapes: forward,
-    dq and dk/dv are one Mosaic call each under today's names (the
-    roofline readers multiply a trace's calls by a call's least time), and
-    dk / dv leave in the inputs' dtype, per key head, with no float32
-    array a query head behind them.  With no group to stack (Yi's shape)
-    the backward is the one call ``flash_bwd``, dq its third result."""
+        one_chip, batch, heads, kv_heads, seq, window, d):
+    """With the geometry ``_tiles`` picks at each cell's shapes: the
+    forward and the backward's one pass ``flash_bwd`` are one Mosaic call
+    each under today's names (the roofline readers multiply a trace's
+    calls by a call's least time), dq the one pass's third result.  With
+    no group (Yi's shape) dk / dv leave in the inputs' dtype; under a
+    group (PR 60) a grid row is a query head and its share of dk / dv
+    leaves in float32, [B * H, S, D], summed over the group outside.  At
+    none of these shapes does the one pass state a limit of scoped VMEM
+    (the check program below says why)."""
     import re
 
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
 
-    q = _sds((batch, heads, seq, 128), jnp.bfloat16, one_chip)
-    kv = _sds((batch, kv_heads, seq, 128), jnp.bfloat16, one_chip)
+    q = _sds((batch, heads, seq, d), jnp.bfloat16, one_chip)
+    kv = _sds((batch, kv_heads, seq, d), jnp.bfloat16, one_chip)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, window=window)
@@ -210,23 +218,22 @@ def test_flash_compiles_at_the_cells_shapes_one_kernel_a_name(
         q, kv, kv).compile().as_text()
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    suffix = "" if window is None else f"_w{window}"
-    names = sorted(re.search(r"flash_[a-z]+(_w\d+)?", c.partition(" = ")[0])
-                   .group(0) for c in calls)
-    one_pass = heads == kv_heads
-    assert names == sorted(f"flash_{k}{suffix}" for k in (
-        ("fwd", "bwd") if one_pass else ("fwd", "dq", "dkv")))
-    dkv = next(c for c in calls
-               if re.search("flash_(dkv|bwd)", c.partition(" = ")[0]))
-    results = dkv.partition(" = ")[2].partition(" custom-call(")[0]
+    suffix = ("" if d == 128 else f"_d{d}") + (
+        "" if window is None else f"_w{window}")
+    names = sorted(re.search(r"flash_[a-z]+(_d\d+)?(_w\d+)?",
+                             c.partition(" = ")[0]).group(0) for c in calls)
+    assert names == sorted(f"flash_{k}{suffix}" for k in ("fwd", "bwd"))
+    bwd = next(c for c in calls if "flash_bwd" in c.partition(" = ")[0])
+    results = bwd.partition(" = ")[2].partition(" custom-call(")[0]
+    shares = (("bf16", f"{batch * kv_heads},{seq},{d}") if heads == kv_heads
+              else ("f32", f"{batch * heads},{seq},{d}"))
     assert re.findall(r"([a-z]+[0-9]+)\[([0-9,]+)\]", results) == [
-        ("bf16", f"{batch * kv_heads},{seq},128")] * 2 + [
-        ("bf16", f"{batch * heads},1,{seq},128")] * one_pass, results
+        shares] * 2 + [("bf16", f"{batch * heads},1,{seq},{d}")], results
     # The one pass states no scoped VMEM limit over the default's 16 MiB
     # (see the test below).
     limits = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1",'
-                        r'"offset":"0","size":"(\d+)"', dkv)
-    assert not one_pass or all(int(n) <= 16 * 2 ** 20 for n in limits)
+                        r'"offset":"0","size":"(\d+)"', bwd)
+    assert all(int(n) <= 16 * 2 ** 20 for n in limits)
 
 
 def test_yi_one_row_check_program_compiles(topo, as_on_the_chip):

@@ -75,6 +75,49 @@ def test_fsdp4_train_step_compiles(fsdp4_step_text):
     assert len(gathered) <= 35
 
 
+def test_the_island_takes_the_one_pass_under_mistrals_group(topo):
+    """``mistral-7b-v0.3.train-fsdp4``'s flash call, 32 query heads on 8 key
+    heads at 4,096 tokens, a row a chip of the ``{fsdp: 4}`` mesh (the
+    step above has a key head a query head): inside the ``shard_map``
+    island the backward is the one pass (PR 60), a query head a grid row
+    whose share of dk / dv leaves in float32, and no dq or dk/dv kernel."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
+
+    before = get_global_mesh()
+    try:
+        mesh = build_mesh(MeshSpec(fsdp=4), devices=topo.devices)
+    finally:
+        set_global_mesh(before)
+    sds = lambda heads: jax.ShapeDtypeStruct(
+        (4, heads, 4096, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+
+    def loss(q, k, v):
+        return jnp.sum(attention(q, k, v, impl="flash", mesh=mesh)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(32), sds(8), sds(8)).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(c.partition(" = ")[0].lstrip("%").split(".")[0].split(
+        "flash_")[1] for c in calls) == ["bwd", "fwd"], calls
+    bwd = next(c for c in calls if "flash_bwd" in c.partition(" = ")[0])
+    assert bwd.partition(" custom-call(")[0].count("f32[32,4096,128]") == 2
+    # no limit of scoped VMEM over the default's 16 MiB, which is what the
+    # compiler writes where a kernel states none
+    assert all(int(n) <= 16 * 2 ** 20 for n in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', bwd))
+
+
 @pytest.mark.parametrize("what", ["no_collective_in_the_loss_s_loops",
                                   "one_gather", "one_reduce_scatter"])
 def test_fsdp4_head_crosses_the_ici_once_a_step(fsdp4_step_text, what):

@@ -59,12 +59,18 @@ def test_lfm2_train_step_compiles_at_the_cell_sizes(lfm2_step, capsys):
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     name = lambda c: c.partition(" = ")[0].lstrip("%")
-    for kernel in ("flash_fwd_d64", "flash_dq_d64", "flash_dkv_d64", "gmm",
-                   "tgmm"):
+    for kernel in ("flash_fwd_d64", "flash_bwd_d64", "gmm", "tgmm"):
         assert any(name(c).startswith(kernel) for c in calls), kernel
+    # The backward is the one pass under the group of four (PR 60): no dq
+    # or dk/dv kernel, and each query head's dk / dv a float32 share.
+    assert not any("flash_dq" in name(c) or "flash_dkv" in name(c)
+                   for c in calls)
+    assert all(c.partition(" custom-call(")[0].count("f32[128,8192,64]") == 2
+               for c in calls if name(c).startswith("flash_bwd_d64"))
     # No 128-wide flash kernel's name: a reader tells the two by name.
     assert not any(name(c).startswith(k + ".") or name(c) == k for c in calls
-                   for k in ("flash_fwd", "flash_dq", "flash_dkv"))
+                   for k in ("flash_fwd", "flash_dq", "flash_dkv",
+                             "flash_bwd"))
     # Four rows of 32 query heads on 8 key heads: four heads stacked behind
     # each key head, and K / V cross HBM 64 wide.
     assert any("bf16[32,4,8192,64]" in c and "bf16[32,8192,64]" in c

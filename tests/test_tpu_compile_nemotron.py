@@ -61,9 +61,15 @@ def test_nemotron_train_step_compiles_at_the_cell_sizes(nemotron_step,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.91e9
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv", "gmm", "tgmm",
+    for kernel in ("flash_fwd", "flash_bwd", "gmm", "tgmm",
                    "ssd_fwd_q128", "ssd_bwd_q128"):
         assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
+    # The backward is the one pass under the group of 16 (PR 60): a query
+    # head a grid row, 16 float32 shares of dk / dv a key head.
+    assert not any(k in c.partition(" = ")[0] for c in calls
+                   for k in ("flash_dq", "flash_dkv"))
+    assert any("f32[32,8192,128]" in c.partition(" custom-call(")[0]
+               for c in calls if "flash_bwd" in c.partition(" = ")[0])
     names = scopes.op_names(text)
     for c in calls:
         if c.startswith("%ssd_") or c.startswith("ssd_"):

@@ -38,9 +38,12 @@ def test_trinity_train_step_compiles_at_the_cell_sizes(trinity_step, capsys):
               f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
               f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, "
               f"{_kernels(compiled)} kernels")
-    for name in ("flash_fwd_w2048", "flash_dkv_w2048", "flash_fwd",
-                 "flash_dq", "gmm", "tgmm"):
+    for name in ("flash_fwd_w2048", "flash_bwd_w2048", "flash_fwd",
+                 "flash_bwd", "gmm", "tgmm"):
         assert name in text, name
+    # Full and window layers alike take the backward's one pass under the
+    # group of eight (PR 60).
+    assert "flash_dq" not in text and "flash_dkv" not in text
     # bf16 weights and two bf16 moments of 1,243 M parameters.
     assert 7.4e9 < mem.argument_size_in_bytes < 7.6e9
     # 9.69 GB of temporaries with the scatters (PR 29); 9.71 GB since the
