@@ -319,9 +319,8 @@ def run(cell: Dict[str, Any]) -> Dict[str, Any]:
     common.say("train", memory_analysis=worker["memory_analysis"],
                memory_stats=worker["memory_stats"])
     if cell["trace"]:
-        # ``per_layer`` is full (128 of 128 entries), so this cell's own
-        # five readers have no entry there yet: a traced run says what they
-        # read on a line of its own (PERF.md section 7).
+        # This cell's own five readers have entries in ``per_layer`` since
+        # PR 61; a traced run still says what they read on one line.
         own = ("gdla_device_share", "gdla_attn_roofline",
                "gdla_window_roofline", "gdla_lambda_mean",
                "polynorm_device_share")

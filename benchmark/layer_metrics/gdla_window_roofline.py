@@ -12,4 +12,5 @@ KERNEL = r"/flash_(fwd|dq|dkv|bwd)_d\d+v\d+_w\d*<"
 
 def read(facts):
     arch = facts.get("arch") or {}
-    return share(facts, KERNEL, arch.get("sizes", {}).get("W"))
+    return share("gdla_window_roofline", facts, KERNEL,
+                 arch.get("sizes", {}).get("W"))

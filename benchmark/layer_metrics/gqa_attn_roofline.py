@@ -1,15 +1,16 @@
 """The unwindowed flash kernels' share of their roofline in the traced steps
 of a model whose attention layers have no window and no positions: the least
-time the chip could take for every ``flash_fwd``, ``flash_dq`` and
-``flash_dkv`` call the trace shows (operations over the causal triangle;
-``benchmark/roofline_moe.banded_flash_call`` at the model's query and key
-heads), over the time it shows for them.  A call holds the rows the program
-gives a layer at a time.  None where the trace holds no such kernel or the
-model is another."""
+time the chip could take for every ``flash_fwd`` and ``flash_bwd`` call the
+trace shows (the backward's one pass under the group, its float32 shares'
+sum outside the kernel in neither the count nor the time: counted since
+PR 61, the reading was the forward's alone from PR 60 until then), and
+``flash_dq`` / ``flash_dkv`` where a call keeps the pair (operations over
+the causal triangle; ``benchmark/roofline.flash_call`` at the model's query
+and key heads), over the time it shows for them.  A call holds the rows the
+program gives a layer at a time.  None where the trace holds no such kernel
+or the model is another."""
 
-import re
-
-from benchmark import roofline, roofline_moe
+from benchmark import roofline
 
 
 def read(facts):
@@ -17,15 +18,9 @@ def read(facts):
     if not t or not arch or "Hm" not in arch.get("sizes", {}):
         return None
     s = arch["sizes"]
-    least = spent = 0.0
-    for key, seconds in t.get("op_seconds", {}).items():
-        m = re.search(r"/flash_(fwd|dq|dkv)<", key)
-        if not m:
-            continue
-        ops, moved = roofline_moe.banded_flash_call(
+    return roofline.kernels_share(
+        "gqa_attn_roofline", t, facts["device"]["kind"],
+        r"/flash_(fwd|dq|dkv|bwd)<",
+        lambda m: roofline.flash_call(
             m.group(1), arch["rows_a_call"], s["H"], s["Hkv"],
-            facts["seq_len"], s["D"])
-        least += t["op_counts"][key] * roofline.least_seconds(
-            ops, moved, facts["device"]["kind"])
-        spent += seconds
-    return 100.0 * least / spent if spent else None
+            facts["seq_len"], s["D"]))

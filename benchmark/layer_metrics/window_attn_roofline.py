@@ -2,12 +2,13 @@
 operations counted over the visible band only: the window layers' calls
 (kernels named ``flash_*_w<window>``; a trace's label drops trailing digits,
 so ``flash_fwd_w``, and either form is matched) at the band's count, the
-full layers' (``flash_*``) at the causal triangle's.  A call holds the rows the program gives a layer at a
-time (``rows_a_call`` in the runner's facts), not the step's."""
+full layers' (``flash_*``) at the causal triangle's; forward, the backward's
+one pass (``flash_bwd``, ``flash_bwd_w``: since PR 61; the reading was the
+forward's alone from PR 60 until then) and the pair where a call keeps it.
+A call holds the rows the program gives a layer at a time (``rows_a_call``
+in the runner's facts), not the step's."""
 
-import re
-
-from benchmark import roofline, roofline_moe
+from benchmark import roofline
 
 
 def read(facts):
@@ -15,15 +16,10 @@ def read(facts):
     if not t or not arch:
         return None
     s = arch["sizes"]
-    least = spent = 0.0
-    for key, seconds in t.get("op_seconds", {}).items():
-        m = re.search(r"/flash_(fwd|dq|dkv)(_w\d*)?<", key)
-        if not m:
-            continue
-        ops, moved = roofline_moe.banded_flash_call(
+    return roofline.kernels_share(
+        "window_attn_roofline", t, facts["device"]["kind"],
+        r"/flash_(fwd|dq|dkv|bwd)(_w\d*)?<",
+        lambda m: roofline.flash_call(
             m.group(1), arch["rows_a_call"], s["H"], s["Hkv"],
-            facts["seq_len"], s["D"], s["window"] if m.group(2) else None)
-        least += t["op_counts"][key] * roofline.least_seconds(
-            ops, moved, facts["device"]["kind"])
-        spent += seconds
-    return 100.0 * least / spent if spent else None
+            facts["seq_len"], s["D"],
+            window=s["window"] if m.group(2) else None))

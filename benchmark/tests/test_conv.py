@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from benchmark import roofline, roofline_conv, roofline_moe
+from benchmark import roofline, roofline_conv
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -69,13 +69,15 @@ def test_readers_of_the_new_scopes_and_kernels():
     ) == pytest.approx(512.0)
     ops = {"jit_train_step/flash_fwd_d<bf16,f32>": 0.1,
            "jit_train_step/flash_dkv_d<bf16,bf16>": 0.2,
+           # the one pass under the group (PR 60), counted since PR 61
+           "jit_train_step/flash_bwd_d<f32,f32,bf16>": 0.25,
            "jit_train_step/flash_fwd<bf16,f32>": 9.0,
            "jit_train_step/flash_fwd_dv<bf16,f32>": 9.0,
            "jit_train_step/gmm<bf16>": 0.4, "jit_train_step/tgmm<bf16>": 0.2}
     facts = _facts(ops=ops)
-    want = 6 * sum(roofline.least_seconds(*roofline_moe.banded_flash_call(
-        w, 4, 32, 8, 8192, 64), V5E) for w in ("fwd", "dkv"))
-    assert flash_d64_roofline.read(facts) == pytest.approx(100 * want / 0.3)
+    want = 6 * sum(roofline.least_seconds(*roofline.flash_call(
+        w, 4, 32, 8, 8192, 64), V5E) for w in ("fwd", "dkv", "bwd"))
+    assert flash_d64_roofline.read(facts) == pytest.approx(100 * want / 0.55)
     assert 0 < grouped_mm_roofline.read(facts) < 100
     flops = 6.0 * 3 * (228671744 * 32768 + 9437184 * 8 * 16384.0)
     assert mfu_active_pct.read(facts) == pytest.approx(
@@ -145,8 +147,8 @@ def test_the_cell_rehearses_and_names_no_device_metric():
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and "metrics" not in last
     named = set(last["metrics_named"])
-    assert "expert_rows_a_call.conv8k" in named
-    assert "moe_load_max_over_mean.conv8k" in named
+    assert "expert_rows_a_call" in named
+    assert "moe_load_max_over_mean" in named
     assert not {n for n in named if "roofline" in n or "idle" in n
                 or "mfu" in n or "share" in n}
     for name in ("norm_grad_distance", "step_moments_distance",

@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from benchmark import roofline, roofline_gdla, roofline_mla, scopes, trace
+from benchmark import roofline, scopes, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -36,7 +36,7 @@ def test_the_banded_grouped_call_s_operations_and_bytes_by_hand():
     x 128 pairs a head, 2 x (192 + 128) operations a pair, 80 heads; bytes
     q and o once a query head, k and v once a KEY head, LSE in float32."""
     pairs = 128 * 129 // 2 + (8192 - 128) * 128
-    ops, moved = roofline_gdla.flash_call("fwd", 1, 80, 16, 8192, 192, 128,
+    ops, moved = roofline.flash_call("fwd", 1, 80, 16, 8192, 192, 128,
                                           128)
     assert pairs == 1_040_448 and ops == 2 * 80 * pairs * 320
     assert moved == 2 * 8192 * (80 * (192 + 128) + 16 * (192 + 128)) \
@@ -45,19 +45,19 @@ def test_the_banded_grouped_call_s_operations_and_bytes_by_hand():
     # triangle's compute bound
     assert roofline.least_seconds(ops, moved, V5E) == \
         pytest.approx(moved / 819e9)
-    full = roofline_gdla.flash_call("fwd", 1, 80, 16, 8192, 192, 128)
+    full = roofline.flash_call("fwd", 1, 80, 16, 8192, 192, 128)
     assert full[0] == 2 * 80 * (8192 * 8193 // 2) * 320 and full[1] == moved
     assert roofline.least_seconds(*full, V5E) == pytest.approx(
         full[0] / 197e12)
-    # with as many key heads as query heads and no window it is
-    # ``roofline_mla``'s count; the one pass is dq + dk/dv less the second
-    # S and dP and the operands read twice
-    for which in ("fwd", "dq", "dkv"):
-        assert roofline_gdla.flash_call(which, 1, 32, 32, 8192, 192, 128) \
-            == roofline_mla.flash_call(which, 1, 32, 32, 8192, 192, 128)
-    one = roofline_gdla.flash_call("bwd", 1, 80, 16, 8192, 192, 128)
-    dq = roofline_gdla.flash_call("dq", 1, 80, 16, 8192, 192, 128)
-    dkv = roofline_gdla.flash_call("dkv", 1, 80, 16, 8192, 192, 128)
+    # with as many key heads as query heads and no window it is the latent
+    # cells' count (``test_mhc.py`` has it by hand; one function since
+    # PR 61); the one pass is dq + dk/dv less the second S and dP and the
+    # operands read twice
+    assert roofline.flash_call("fwd", 1, 32, 32, 8192, 192, 128)[0] == \
+        2 * 32 * (8192 * 8193 / 2) * (192 + 128)
+    one = roofline.flash_call("bwd", 1, 80, 16, 8192, 192, 128)
+    dq = roofline.flash_call("dq", 1, 80, 16, 8192, 192, 128)
+    dkv = roofline.flash_call("dkv", 1, 80, 16, 8192, 192, 128)
     assert one[0] == dq[0] + dkv[0] - full[0]
     assert one[1] < dq[1] + dkv[1]
 
@@ -85,7 +85,7 @@ def test_the_gdla_readers_by_their_scopes_and_names():
     assert gdla_device_share.read(facts) == pytest.approx(40.0)
     assert polynorm_device_share.read(facts) == pytest.approx(7.5)
     least = lambda which, window: 3 * roofline.least_seconds(
-        *roofline_gdla.flash_call(which, 1, 80, 16, 8192, 192, 128, window),
+        *roofline.flash_call(which, 1, 80, 16, 8192, 192, 128, window),
         V5E)
     assert gdla_attn_roofline.read(facts) == pytest.approx(
         100 * (least("fwd", None) + least("bwd", None)) / 0.16)
@@ -180,25 +180,37 @@ def test_the_arch_module_s_counts_by_hand():
 
 
 def test_the_cell_rehearses_and_names_every_entry_a_cpu_can():
-    """``per_layer`` held 128 of its 128 entries before this cell, so the
-    cell adds none: it is appended to the lists of fifteen entries whose
-    readers find something in it (thirteen of Xing4.0's, the nearest
-    sibling, and one each of Kanana's and Ling's), and a traced run says
-    what the cell's own five readers read on a ``[gdla]`` line."""
+    """Found by name (one entry a reader since PR 61; ``per_layer`` was
+    full before it, and the cell stood under other cells' suffixes): the
+    cell's own five readers, which a traced run also says on a ``[gdla]``
+    line, and the fifteen it shares.  ``mla_attn_roofline`` is not among
+    them: it counts keys and values once a QUERY head, and
+    ``gdla_attn_roofline`` reads the same kernels at 80 over 16.
+    ``hc_sinkhorn_residual.mhc8k`` keeps the name ``tests/`` asserts."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert len(bench["per_layer"]) == 128
     entries = {m["name"] for m in bench["per_layer"]
                if CELL in m.get("workloads", ())}
-    assert entries == {
-        "mfu_active_pct.mhc8k", "grouped_mm_roofline.mhc8k",
-        "moe_device_share.mhc8k", "moe_load_max_over_mean.mhc8k",
-        "idle_share.mhc8k", "place_batch_ms.mhc8k",
-        "compiles_in_window.mhc8k", "worker_chip_s.mhc8k",
-        "step_period_max_over_median.mhc8k", "hbm_held_share.mhc8k",
-        "hc_device_share.mhc8k", "hc_sinkhorn_residual.mhc8k",
-        "mla_attn_roofline.mhc8k", "mla_device_share.mla8k",
-        "expert_rows_a_call.kda8k"}
+    step, kernels = "train step", "kernels"
+    brought = {
+        "gdla_device_share": ("%", "lower", "device_trace", step),
+        "gdla_attn_roofline": ("%", "higher", "device_trace", kernels),
+        "gdla_window_roofline": ("%", "higher", "device_trace", kernels),
+        "gdla_lambda_mean": ("share", "higher", "program_counter", step),
+        "polynorm_device_share": ("%", "lower", "device_trace", step)}
+    assert entries == set(brought) | {
+        "mfu_active_pct", "grouped_mm_roofline", "moe_device_share",
+        "moe_load_max_over_mean", "idle_share", "place_batch_ms",
+        "compiles_in_window", "worker_chip_s", "cluster_start_s",
+        "step_period_max_over_median", "hbm_held_share", "hc_device_share",
+        "hc_sinkhorn_residual.mhc8k", "mla_device_share",
+        "expert_rows_a_call"}
+    for m in bench["per_layer"]:
+        if m["name"] in brought:
+            assert (m["unit"], m["better"], m["source"], m["layer"]) == \
+                brought[m["name"]], m
+            assert m["workloads"] == [CELL]
+            assert m["moves"] == "train_tok_s_chip"
     assert CELL in next(m for m in bench["end_to_end"]
                         if m["name"] == "train_tok_s_chip")["workloads"]
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
@@ -214,9 +226,9 @@ def test_the_cell_rehearses_and_names_every_entry_a_cpu_can():
     # those that need no device trace
     for name in entries:
         assert f"[metric] name={name} " in done.stdout, name
-    assert {"hc_sinkhorn_residual.mhc8k", "expert_rows_a_call.kda8k",
-            "moe_load_max_over_mean.mhc8k", "place_batch_ms.mhc8k",
-            "step_period_max_over_median.mhc8k"} <= named
+    assert {"hc_sinkhorn_residual.mhc8k", "expert_rows_a_call",
+            "moe_load_max_over_mean", "place_batch_ms",
+            "step_period_max_over_median", "gdla_lambda_mean"} <= named
     assert not {n for n in named if "roofline" in n or "idle" in n
                 or "mfu" in n or "device_share" in n}
     for name in ("norm_grad_distance", "step_moments_distance",

@@ -49,7 +49,7 @@ def test_the_scan_s_operations_and_bytes_by_hand():
 
 def test_the_kda_readers_by_their_scopes():
     from benchmark.layer_metrics import (kda_conv_roofline, kda_device_share,
-                                         kda_scan_roofline)
+                                         kda_scan_roofline, mla_device_share)
     by = {"forward_backward/block/attn/kda/scan": 1.5,
           "forward_backward/block/attn/kda/scan/kda_bwd_c64": 0.5,
           "forward_backward/block/attn/kda/conv/block/ssm/conv": 0.25,
@@ -60,6 +60,9 @@ def test_the_kda_readers_by_their_scopes():
           "forward_backward/block/moe/experts": 3.0}
     facts = _facts(by)
     assert kda_device_share.read(facts) == pytest.approx(40.0)
+    # the one latent layer shares ``block/attn`` with the six KDA layers:
+    # its share is what lies there and not under ``kda``
+    assert mla_device_share.read(facts) == pytest.approx(10.0)
     tokens = 3 * 32768
     scan = 6 * roofline.least_seconds(
         *roofline_kda.scan_passes(tokens, 32, 128, 128, 64), V5E)
@@ -90,10 +93,9 @@ def test_the_shipped_latent_reader_counts_this_cell_s_one_layer():
     """``mla_attn_roofline`` at this cell's shape: one latent layer, 12 calls
     of a kernel over three traced steps at one row a call (1 layer x 4
     rows), by hand."""
-    from benchmark import roofline_mla
     from benchmark.layer_metrics import mla_attn_roofline
     ops = {"jit_train_step/flash_fwd_d192v<bf16,f32>": 0.1}
-    want = 12 * roofline_mla.flash_call("fwd", 1, 32, 32, 8192, 192,
+    want = 12 * roofline.flash_call("fwd", 1, 32, 32, 8192, 192,
                                         128)[0] / 197e12
     assert mla_attn_roofline.read(_facts(ops=ops)) == \
         pytest.approx(100 * want / 0.1)
@@ -152,11 +154,20 @@ def test_the_arch_module_s_counts_by_hand():
 def test_the_cell_rehearses_and_names_every_entry_a_cpu_can():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # found by name (one entry a reader since PR 61): the cell's own four,
+    # the twelve it shares with other cells, and the two PR 55 had no room
+    # for, ``cluster_start_s`` and ``mla_device_share``
     entries = {m["name"] for m in bench["per_layer"]
-               if m["name"].endswith(".kda8k")}
-    assert len(entries) == 16 and all(
-        m["workloads"] == [CELL] for m in bench["per_layer"]
-        if m["name"] in entries)
+               if CELL in m.get("workloads", ())}
+    assert entries == {
+        "kda_scan_roofline", "kda_conv_roofline", "kda_device_share",
+        "kda_chunk_carry", "mla_attn_roofline", "mla_device_share",
+        "grouped_mm_roofline", "moe_device_share", "moe_load_max_over_mean",
+        "expert_rows_a_call", "mfu_active_pct", "hbm_held_share",
+        "idle_share", "place_batch_ms", "compiles_in_window",
+        "worker_chip_s", "cluster_start_s", "step_period_max_over_median"}
+    assert all(m["workloads"] == [CELL] for m in bench["per_layer"]
+               if m["name"].startswith("kda_"))
     assert CELL in next(m for m in bench["end_to_end"]
                         if m["name"] == "train_tok_s_chip")["workloads"]
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
@@ -172,9 +183,9 @@ def test_the_cell_rehearses_and_names_every_entry_a_cpu_can():
     # those that need no device trace
     for name in entries:
         assert f"[metric] name={name} " in done.stdout, name
-    assert {"kda_chunk_carry.kda8k", "expert_rows_a_call.kda8k",
-            "moe_load_max_over_mean.kda8k", "place_batch_ms.kda8k",
-            "step_period_max_over_median.kda8k"} <= named
+    assert {"kda_chunk_carry", "expert_rows_a_call",
+            "moe_load_max_over_mean", "place_batch_ms",
+            "step_period_max_over_median"} <= named
     assert not {n for n in named if "roofline" in n or "idle" in n
                 or "mfu" in n or "device_share" in n}
     for name in ("norm_grad_distance", "step_moments_distance",

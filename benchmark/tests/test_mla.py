@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from benchmark import roofline, roofline_mla, scopes, trace
+from benchmark import roofline, scopes, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -60,13 +60,13 @@ def test_the_shipped_roofline_reader_counts_the_rows_of_a_call(rows):
     ops = {"jit_train_step/flash_fwd_d192v<bf16,f32>": 0.3,
            "jit_train_step/flash_dkv_d192v<bf16>": 0.6,
            "jit_train_step/flash_fwd<bf16,f32>": 9.0}
-    want = 36 * sum(roofline_mla.flash_call(w, rows, 32, 32, 8192, 192,
+    want = 36 * sum(roofline.flash_call(w, rows, 32, 32, 8192, 192,
                                             128)[0]
                     for w in ("fwd", "dkv")) / 197e12
     assert mla_attn_roofline.read(_facts(ops=ops, rows_a_call=rows)) == \
         pytest.approx(100 * want / 0.9)
     # compute bound on a v5e at either size of call
-    fwd, moved = roofline_mla.flash_call("fwd", rows, 32, 32, 8192, 192, 128)
+    fwd, moved = roofline.flash_call("fwd", rows, 32, 32, 8192, 192, 128)
     assert roofline.least_seconds(fwd, moved, V5E) == \
         pytest.approx(fwd / 197e12)
 
@@ -122,9 +122,8 @@ def test_the_cell_rehearses_and_names_no_device_metric():
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and "metrics" not in last
     named = set(last["metrics_named"])
-    assert {"expert_rows_a_call.mla8k", "moe_load_max_over_mean.mla8k",
-            "place_batch_ms.mla8k",
-            "step_period_max_over_median.mla8k"} <= named
+    assert {"expert_rows_a_call", "moe_load_max_over_mean",
+            "place_batch_ms", "step_period_max_over_median"} <= named
     assert not {n for n in named if "roofline" in n or "idle" in n
                 or "mfu" in n or "share" in n}
     for name in ("norm_grad_distance", "step_moments_distance",

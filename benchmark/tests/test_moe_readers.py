@@ -46,27 +46,29 @@ def recorded_facts(**arch):
 
 
 def test_visible_pairs_by_hand():
-    assert roofline_moe.visible_pairs(8192) == 8192 * 8193 / 2 == 33558528
+    assert roofline.visible_pairs(8192) == 8192 * 8193 / 2 == 33558528
     # the first 2,048 rows see a triangle, the other 6,144 see 2,048 each
-    assert roofline_moe.visible_pairs(8192, 2048) == \
+    assert roofline.visible_pairs(8192, 2048) == \
         2048 * 2049 / 2 + 6144 * 2048 == 14681088
-    assert roofline_moe.visible_pairs(8192, 8192) == \
-        roofline_moe.visible_pairs(8192, 9000) == 33558528
-    assert roofline_moe.visible_pairs(4, 2) == 3 + 2 * 2
+    assert roofline.visible_pairs(8192, 8192) == \
+        roofline.visible_pairs(8192, 9000) == 33558528
+    assert roofline.visible_pairs(4, 2) == 3 + 2 * 2
 
 
 def test_banded_flash_call_by_hand():
-    ops, moved = roofline_moe.banded_flash_call("fwd", 1, 32, 4, 8192, 128,
-                                                2048)
+    # ``roofline.flash_call`` at one head size and a window: until PR 61
+    # this file's own ``roofline_moe.banded_flash_call``
+    ops, moved = roofline.flash_call("fwd", 1, 32, 4, 8192, 128,
+                                     window=2048)
     assert ops == 2 * 2 * 32 * 14681088 * 128
     q, kv, lse = 32 * 8192 * 128 * 2, 4 * 8192 * 128 * 2, 32 * 8192 * 4
     assert moved == 2 * q + 2 * kv + lse
     # compute bound on a v5e: 1.22 ms against 0.17 ms
     assert roofline.least_seconds(ops, moved, V5E) == \
         pytest.approx(ops / 197e12)
-    full, _ = roofline_moe.banded_flash_call("fwd", 1, 32, 4, 8192, 128)
-    dq, _ = roofline_moe.banded_flash_call("dq", 1, 32, 4, 8192, 128)
-    dkv, moved = roofline_moe.banded_flash_call("dkv", 1, 32, 4, 8192, 128)
+    full, _ = roofline.flash_call("fwd", 1, 32, 4, 8192, 128)
+    dq, _ = roofline.flash_call("dq", 1, 32, 4, 8192, 128)
+    dkv, moved = roofline.flash_call("dkv", 1, 32, 4, 8192, 128)
     assert (dq, dkv) == (1.5 * full, 2 * full)
     assert full == roofline.flash_attention_call(
         "fwd", 1, 32, 4, 8192, 128)[0] * 8193 / 8192    # the diagonal's half
@@ -109,6 +111,27 @@ def test_window_calls_are_told_from_full_calls_with_or_without_digits():
     # 136 against 70 block pairs: the full layers alone read higher
     assert only_full["trace"]["op_seconds"] and \
         window_attn_roofline.read(only_full) == pytest.approx(66.837, abs=1e-3)
+
+
+def test_the_one_pass_is_counted_beside_the_recorded_pair():
+    """The recorded run is PR 29's, from before the one pass; since PR 60
+    the cell's backward is ``flash_bwd_w2048`` and ``flash_bwd``, whose
+    labels end in ``<f32,f32,bf16>``, and since PR 61 the reader counts
+    them: five products a tile over the band or the triangle."""
+    facts = recorded_facts()
+    before = window_attn_roofline.read(facts)
+    added = {"flash_bwd_w<f32,f32,bf16>": (0.40, 7 * 4 * 3),
+             "flash_bwd<f32,f32,bf16>": (0.20, 2 * 4 * 3)}
+    for k, (seconds, calls) in added.items():
+        facts["trace"]["op_seconds"][STEP + k] = seconds
+        facts["trace"]["op_counts"][STEP + k] = calls
+    pair = sum(v[0] for k, v in RECORDED.items() if k.startswith("flash_"))
+    least = before / 100 * pair + sum(
+        calls * roofline.least_seconds(*roofline.flash_call(
+            "bwd", 1, 32, 4, 8192, 128, window=window), V5E)
+        for calls, window in ((7 * 4 * 3, 2048), (2 * 4 * 3, None)))
+    assert window_attn_roofline.read(facts) == pytest.approx(
+        100 * least / (pair + 0.60))
 
 
 def test_grouped_products_are_matched_by_kernel_name():

@@ -207,10 +207,9 @@ def test_the_four_entries():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     got = {m["name"]: m for m in bench["per_layer"] if m["name"] in NEW}
+    # found by name, in this order among themselves: where they stand in
+    # the table is no one's to assert (PR 61 merged the copies before them)
     assert list(got) == list(NEW)
-    # appended in this order after everything the benchmark had (108
-    # entries at PR 52); a later PR's entries come after them
-    assert [m["name"] for m in bench["per_layer"][108:112]] == list(NEW)
     for name, m in got.items():
         assert m == {"name": name, "unit": "s", "better": "lower",
                      "source": "program_span", "layer": NEW[name],

@@ -75,12 +75,14 @@ def test_readers_of_the_new_scopes_and_kernels():
     assert ssm_conv_roofline.read(facts) == pytest.approx(100 * least / 0.1)
     ops = {"jit_train_step/flash_fwd<bf16,f32>": 0.1,
            "jit_train_step/flash_dkv<bf16,bf16>": 0.2,
+           # the one pass under the group (PR 60), counted since PR 61
+           "jit_train_step/flash_bwd<f32,f32,bf16>": 0.25,
            "jit_train_step/flash_fwd_w<bf16,f32>": 9.0,
            "jit_train_step/gmm<bf16>": 0.4, "jit_train_step/tgmm<bf16>": 0.2}
     facts = _facts(ops=ops)
-    want = 6 * sum(roofline.least_seconds(*roofline_moe.banded_flash_call(
-        w, 1, 32, 2, 8192, 128), V5E) for w in ("fwd", "dkv"))
-    assert gqa_attn_roofline.read(facts) == pytest.approx(100 * want / 0.3)
+    want = 6 * sum(roofline.least_seconds(*roofline.flash_call(
+        w, 1, 32, 2, 8192, 128), V5E) for w in ("fwd", "dkv", "bwd"))
+    assert gqa_attn_roofline.read(facts) == pytest.approx(100 * want / 0.55)
     two = expert2_mm_roofline.read(facts)
     assert two == pytest.approx(grouped_mm_roofline.read(facts) * 2 / 3)
     assert 0 < two < 100
@@ -142,8 +144,8 @@ def test_the_cell_rehearses_and_names_no_device_metric():
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and "metrics" not in last
     named = set(last["metrics_named"])
-    assert "ssm_chunk_carry.ssm8k" in named
-    assert "moe_load_max_over_mean.ssm8k" in named
+    assert "ssm_chunk_carry" in named
+    assert "moe_load_max_over_mean" in named
     assert not {n for n in named if "roofline" in n or "idle" in n
                 or "mfu" in n or "share" in n}
     assert "[correct] name=norm_grad_distance" in done.stdout
