@@ -11,6 +11,7 @@ from .nemotron_h import NemotronHConfig, nemotron_h_tiny
 from .lfm2 import Lfm2Config, lfm2_tiny
 from .bailing_hybrid import BailingHybridConfig, bailing_hybrid_tiny
 from .motif import MotifConfig, motif_tiny
+from .mimo_v2 import MimoV2Config, mimo_v2_tiny
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
@@ -21,5 +22,6 @@ __all__ = [
     "NemotronHConfig", "nemotron_h_tiny", "Lfm2Config", "lfm2_tiny",
     "BailingHybridConfig", "bailing_hybrid_tiny",
     "MotifConfig", "motif_tiny",
+    "MimoV2Config", "mimo_v2_tiny",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

@@ -266,3 +266,27 @@ def remat(block: Callable, mode: Any, keep: tuple = ()) -> Callable:
     if mode not in policies:
         raise ValueError(f"unknown remat mode {mode!r}")
     return jax.checkpoint(block, policy=policies[mode])
+
+
+def rows_at_a_time(one: Callable, x, layer_rows: Optional[int], top_k: int):
+    """``one(rows) -> (y, report)`` over x [B, S, E], ``layer_rows`` rows at
+    a time, one group after the other (``jax.lax.map``), so that a layer's
+    temporaries are one group's; ``one`` is the layer under its remat.  The
+    groups' reports are joined as an expert layer's loads are: ``counts``,
+    ``dropped`` and ``sliced`` summed, ``top`` [tokens, k] laid end to end,
+    and anything else a report holds averaged over the groups.  None, or a
+    batch of no more rows: the whole batch at once."""
+    B = x.shape[0]
+    n = min(layer_rows or B, B)
+    if B % n:
+        raise ValueError(f"a batch of {B} rows does not split into groups "
+                         f"of layer_rows={n}")
+    if n == B:
+        return one(x)
+    y, report = jax.lax.map(one, x.reshape((B // n, n) + x.shape[1:]))
+    if report is not None:
+        summed = ("counts", "dropped", "sliced")
+        report = {k: (v.reshape(-1, top_k) if k == "top" else
+                      jnp.sum(v, axis=0) if k in summed else
+                      jnp.mean(v, axis=0)) for k, v in report.items()}
+    return y.reshape(x.shape), report

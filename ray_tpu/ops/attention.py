@@ -166,6 +166,26 @@ callers (ring attention) can flash-attend a mid-sequence Q shard.
 ``0 <= t - s < window``): the pairs left of the band leave the grid, the
 mask gains the band's lower edge, and the three kernels carry the window in
 their names (``flash_fwd_w2048``).  With ``window=None`` nothing changes.
+``sink`` ([H] float32, a learned attention sink: MiMo-V2-Flash's window
+layers) gives every row of query head ``h`` one more column, of score ``b_h``
+and value zero: ``p_ts = exp(s_ts) / (exp(b_h) + sum_s' exp(s_ts'))``, mass
+that goes nowhere.  The forward's online softmax starts such a row at ``m =
+b_h, l = 1`` with an empty accumulator, where it starts every other at ``m =
+-inf, l = 0``: the state after the sink's column, had it been a key block of
+its own; a step's heads read theirs from one [heads, 1, 128] block.  Nothing
+else of the forward changes (no second softmax pass, no concatenated column
+in HBM), and its kernel says so in its name (``flash_fwd_d192v128_w128_sink``).
+The saved log-sum-exp then holds the sink, so the backward kernels need no
+change: their ``p = exp(s - lse)`` and ``ds = p (dp - delta)`` are the
+softmax's over keys and sink as they stand, and run under the names they
+have.  The column's value is zero, so its ``dp`` is 0 and ``db_h = - sum_t
+exp(b_h - lse_t) delta_t``, ``delta_t = o_t . do_t``: formed beside the
+kernels (``_flash_sink_bwd``, under the scope ``attn/sink_grad``) from the
+LSE and the delta they read anyway.  Such a call is a ``custom_vjp`` of its
+own that hands out (result, LSE), the LSE differentiable (its cotangent is
+taken off delta once): ``exp(b_h - lse_t)`` is the share of the row's mass
+the sink took, which a model reports.  With ``sink=None`` a call traces what
+it traced.
 
 Design provenance (patterns, not code): the reference delegates attention to
 engines (SURVEY §2.4 SP/CP row — no in-repo kernel); the block/layout recipe
@@ -195,7 +215,8 @@ LANES = 128
 
 def reference_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        q_offset: int = 0, window: Optional[int] = None):
+                        q_offset: int = 0, window: Optional[int] = None,
+                        sink=None, lse: bool = False):
     """Plain-jnp attention. q: [B, H, Sq, D]; k: [B, Hkv, Sk, D];
     v: [B, Hkv, Sk, Dv] (``Dv`` may differ from ``D``; the default scale is
     ``D ** -0.5``).  Returns [B, H, Sq, Dv].
@@ -203,6 +224,10 @@ def reference_attention(q, k, v, *, causal: bool = True,
     ``q_offset`` shifts query positions for causal masking (used by
     sequence-sharded callers where the local Q block starts mid-sequence).
     ``window`` (with ``causal``) keeps the keys with ``0 <= t - s < window``.
+    ``sink`` [H] float32: one more column of every row of head ``h``, of
+    score ``sink[h]`` (no scale) and value zero, so it takes mass and adds
+    nothing.  ``lse``: also return the rows' log-sum-exp [B, H, Sq] float32,
+    the sink's column in it.
     """
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -220,8 +245,15 @@ def reference_attention(q, k, v, *, causal: bool = True,
         if window is not None:
             mask &= qpos[:, None] - kpos[None, :] < window
         scores = jnp.where(mask[None, None], scores, NEG_INF)
+    if sink is not None:
+        scores = jnp.concatenate([scores, jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            (B, H, Sq, 1))], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+    if sink is not None:
+        probs = probs[..., :Sk]
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+    return (out, jax.nn.logsumexp(scores, axis=-1)) if lse else out
 
 
 def _bcast_lanes(x128, n):
@@ -588,7 +620,7 @@ def _operands(q, k, v):
     return {"q": q, "k": k, "v": v}
 
 
-def _geometry(kind, dims, block_q, block_k, window, rows):
+def _geometry(kind, dims, block_q, block_k, window, rows, sink=False):
     """``_tiles``' answer for this call, an explicit block size winning (and
     walked one tile a grid step), checked against the lengths and counted."""
     _, H, Hkv, Sq, Sk, D, Dv, Dr = dims
@@ -606,7 +638,7 @@ def _geometry(kind, dims, block_q, block_k, window, rows):
     # the grid rows whose dk / dv are summed into a key head's outside
     shares = H // Hkv // t.heads if kind in ("dkv", "bwd") else 1
     telemetry.inc("ray_tpu_flash_step_geometry_total", tags={
-        "kernel": _kernel_name(f"flash_{kind}", window, D, Dv),
+        "kernel": _kernel_name(f"flash_{kind}", window, D, Dv, sink),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
         **({"tiles_a_step": str(t.tiles)} if t.tiles > 1 else {}),
@@ -736,24 +768,35 @@ def _tile_index(i, j, tiles):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False, parts=False, tiles=1):
+                window=None, rows=False, parts=False, tiles=1, sink=False):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
     *_, m_scr, l_scr, acc_scr = refs
     heads = m_scr.shape[0] // block_q
+    *ins, o_ref, lse_ref = refs[:-3]
+    if sink:        # the step's heads' sinks, [1, heads, 1, 128] along lanes
+        *ins, sink_ref = ins
     if parts:
-        q_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref = refs[:-3]
+        q_ref, qr_ref, kv_ref, kr_ref = ins
         k_ref, v_ref = _k_and_v(kv_ref, q_ref.shape[-1] // heads)
     else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref = refs[:-3]
+        q_ref, k_ref, v_ref = ins
     step = sched_ref[pl.program_id(1)]
     qi, ki = _step_qi(step), _step_ki(step)
 
     @pl.when(step & _FIRST_BIT != 0)
     def _init():
-        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        if sink:
+            # A row starts as if it had seen the sink's column: max b_h,
+            # sum 1, and nothing accumulated (the column's value is zero).
+            for h in range(heads):
+                m_scr[h * block_q:(h + 1) * block_q, :] = jnp.broadcast_to(
+                    sink_ref[0, h], (block_q, LANES))
+            l_scr[...] = jnp.ones(l_scr.shape, jnp.float32)
+        else:
+            m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def tile(j):
@@ -798,21 +841,25 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
                 lse_ref[0, h] = lse[h * block_q:(h + 1) * block_q].T[:1]
 
 
-def _kernel_name(base, window, D=None, Dv=None):
+def _kernel_name(base, window, D=None, Dv=None, sink=False):
     """A windowed call carries its window in its name, so that a device
     trace tells it from a full-causal call (``flash_fwd_w2048``), and a
     call whose values are not as wide as its keys both head sizes
     (``flash_fwd_d192v128``), one whose one head size is not 128 that size
-    (``flash_fwd_d64``)."""
+    (``flash_fwd_d64``); a forward whose rows start at a sink says so last
+    (``flash_fwd_d192v128_w128_sink``)."""
     if D != Dv:
         base = f"{base}_d{D}v{Dv}"
     elif D is not None and D != LANES:
         base = f"{base}_d{D}"
-    return base if window is None else f"{base}_w{window}"
+    if window is not None:
+        base = f"{base}_w{window}"
+    return f"{base}_sink" if sink else base
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
-                   interpret, *, need_lse, window=None, rows=False):
+                   interpret, *, need_lse, window=None, rows=False,
+                   sink=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -820,7 +867,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     B, H, Hkv, Sq, Sk, D, Dv, _ = dims
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
-    t = _geometry("fwd", dims, block_q, block_k, window, rows)
+    with_sink = sink is not None
+    t = _geometry("fwd", dims, block_q, block_k, window, rows, with_sink)
     sched = _packed_schedule(Sq, Sk, *t.major, q_offset, causal, "q", window)
     n, stacked = B * H // t.heads, t.heads * t.block_q
 
@@ -832,7 +880,19 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         _fwd_kernel, causal=causal, scale=scale, block_q=t.block_q,
         block_k=t.block_k, q_offset=q_offset, window=window, rows=rows,
         **({"parts": True} if dims.Dr else {}),
-        **({"tiles": t.tiles} if t.tiles > 1 else {}))
+        **({"tiles": t.tiles} if t.tiles > 1 else {}),
+        **({"sink": True} if with_sink else {}))
+    in_specs = [getattr(sp, name) for name in operands]
+    handed = [x.reshape(sp.shapes[name]) for name, x in operands.items()]
+    if with_sink:
+        # A head's sink along the lanes, a grid row's heads a block: 512 B
+        # a head in HBM, and the kernel reads a [1, 128] row as it reads
+        # the backward's LSE.
+        in_specs.append(pl.BlockSpec((1, t.heads, 1, LANES),
+                                     lambda r, s, sched: (r, 0, 0, 0)))
+        handed.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            (B, H, 1, LANES)).reshape(n, t.heads, 1, LANES))
 
     out_specs = [sp.o]
     out_shape = [jax.ShapeDtypeStruct(sp.shapes["o"], dtype)]
@@ -842,7 +902,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             jax.ShapeDtypeStruct((n, t.heads, 1, Sq), jnp.float32))
     else:
         # No LSE output at all: nothing of it is computed or written.
-        with_lse, n_io = kernel, len(operands) + 1
+        with_lse, n_io = kernel, len(handed) + 1
 
         def kernel(sched, *refs):
             return with_lse(sched, *refs[:n_io], None, *refs[n_io:])
@@ -852,7 +912,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n, sched.size),
-            in_specs=[getattr(sp, name) for name in operands],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 _vmem((stacked, LANES), jnp.float32),
@@ -861,9 +921,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             ]),
         out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, D, Dv),
+        name=_kernel_name("flash_fwd", window, D, Dv, with_sink),
         **_compiler_params(interpret, stacked, t.block_k),
-    )(sched, *(x.reshape(sp.shapes[name]) for name, x in operands.items()))
+    )(sched, *handed)
     out = res[0].reshape((B, Sq, H, Dv) if rows else (B, H, Sq, Dv))
     if not need_lse:
         return out, None
@@ -1173,8 +1233,27 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
                 _write_rows(dq_refs[1], dq_scrs[1][:, q_tile(ki)].T, 1)
 
 
+def _delta(out, dout, rows):
+    """delta_i = rowsum(dO * O), float32 [B, H, Sq]: one fused
+    elementwise+reduce pass in XLA."""
+    di = dout.astype(jnp.float32) * out.astype(jnp.float32)
+    if not rows:
+        return jnp.sum(di, axis=-1)
+    B, Sq, H, Dv = di.shape
+    if Sq % 8:
+        return jnp.swapaxes(jnp.sum(di, axis=-1), 1, 2)
+    # Said with the rows in the groups of eight sublanes they lie in:
+    # summed straight to [B, Sq, H] the TPU compiler writes the float32
+    # product out and re-tiles it by heads before it sums it, three
+    # q-sized float32 passes for this one (PERF.md, PR 49).
+    return jnp.moveaxis(di.reshape(B, Sq // 8, 8, H, Dv).sum(axis=-1),
+                        3, 1).reshape(B, H, Sq)
+
+
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
-                    q_offset, interpret, window=None, rows=False):
+                    q_offset, interpret, window=None, rows=False, di=None):
+    """``di``: the rows' delta where the caller has formed it already (a
+    call with a sink, whose LSE is a result with a cotangent of its own)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1183,20 +1262,8 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
     operands = _operands(q, k, v)
     dtype = operands["q"].dtype
 
-    # delta_i = rowsum(dO * O), [B, H, Sq]: one fused elementwise+reduce
-    # pass in XLA.
-    di = dout.astype(jnp.float32) * out.astype(jnp.float32)
-    if not rows:
-        di = jnp.sum(di, axis=-1)
-    elif Sq % 8:
-        di = jnp.swapaxes(jnp.sum(di, axis=-1), 1, 2)
-    else:
-        # Said with the rows in the groups of eight sublanes they lie in:
-        # summed straight to [B, Sq, H] the TPU compiler writes the float32
-        # product out and re-tiles it by heads before it sums it, three
-        # q-sized float32 passes for this one (PERF.md, PR 49).
-        di = jnp.moveaxis(di.reshape(B, Sq // 8, 8, H, Dv).sum(axis=-1),
-                          3, 1).reshape(B, H, Sq)
+    if di is None:
+        di = _delta(out, dout, rows)
 
     def call(kind, kernel, t, major, outs, scratch, tile):
         """One backward kernel at its geometry ``t``, its results the
@@ -1348,12 +1415,60 @@ def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, window,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# A call with a sink is a function of its own, so that a call without one
+# traces what it traced: (result, LSE), the LSE a result like the other
+# because the sink's share of a row's mass is exp(b_h - lse) and a model
+# reports it.
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _flash_sink(q, k, v, sink, causal, scale, block_q, block_k, q_offset,
+                interpret, window, rows):
+    return _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
+                          interpret, need_lse=True, window=window, rows=rows,
+                          sink=sink)
+
+
+def _flash_sink_fwd(q, k, v, sink, causal, scale, block_q, block_k, q_offset,
+                    interpret, window, rows):
+    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                              q_offset, interpret, need_lse=True,
+                              window=window, rows=rows, sink=sink)
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+    return (out, lse), (q, k, v, sink, out, lse)
+
+
+def _flash_sink_bwd(causal, scale, block_q, block_k, q_offset, interpret,
+                    window, rows, res, cotangents):
+    """The saved LSE holds the sink's column, so the backward kernels'
+    ``p = exp(s - lse)`` and ``ds = p (dp - delta)`` are a softmax's over
+    the keys AND the sink as they stand.  The LSE's own cotangent reaches
+    the scores as ``p dlse`` and the sink as ``p_sink dlse``: it is taken
+    off delta once.  The sink's column has value zero, so its ``dp`` is 0
+    and ``db_h = - sum_t exp(b_h - lse_t) delta_t``, from what is saved."""
+    q, k, v, sink, out, lse = res
+    dout, dlse = cotangents
+    di = _delta(out, dout, rows) - dlse
+    dq, dk, dv = _flash_backward(q, k, v, out, lse, dout, causal, scale,
+                                 block_q, block_k, q_offset, interpret,
+                                 window, rows, di=di)
+    with jax.named_scope("attn/sink_grad"):
+        db = -jnp.sum(jnp.exp(sink.astype(jnp.float32)[None, :, None] - lse)
+                      * di, axis=(0, 2))
+    return dq, dk, dv, db.astype(sink.dtype)
+
+
+_flash_sink.defvjp(_flash_sink_fwd, _flash_sink_bwd)
+
+
 def _head_major(fn, q, k, v, rows):
     """``fn``, which takes and returns [B, H, S, D], for a call whose v and
     result lie as [B, S, H, D] under ``rows``."""
     if not rows:
         return fn(q, k, v)
-    return jnp.swapaxes(fn(q, k, jnp.swapaxes(v, 1, 2)), 1, 2)
+    got = fn(q, k, jnp.swapaxes(v, 1, 2))
+    if isinstance(got, tuple):      # (result, LSE [B, H, S]: nothing to turn)
+        return (jnp.swapaxes(got[0], 1, 2), *got[1:])
+    return jnp.swapaxes(got, 1, 2)
 
 
 def _one_part(q, k, v=None):
@@ -1375,7 +1490,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, q_offset: int = 0,
                     interpret: bool = False, window: Optional[int] = None,
-                    rows: bool = False):
+                    rows: bool = False, sink=None, lse: bool = False):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
 
     q: [B, H, Sq, D]; k: [B, Hkv, Sk, D], head-major as
@@ -1407,7 +1522,24 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     ``window``: with ``causal``, a key is visible iff ``0 <= t - s <
     window``.  ``block_q`` / ``block_k`` default to what ``_tiles`` picks
-    for each kernel from the shapes."""
+    for each kernel from the shapes.
+
+    ``sink`` [H] float32 (a learned attention sink): every row of query
+    head ``h`` has one more column, of score ``sink[h]`` (in the softmax's
+    own units: no scale) and value zero, ``p_ts = exp(s_ts) / (exp(b_h) +
+    sum_s' exp(s_ts'))``: it takes mass and adds nothing.  The forward
+    kernel starts the row's running max at ``b_h`` and its sum at 1 (where
+    no sink starts them at ``-inf`` and 0) and is named for it
+    (``flash_fwd_.._sink``); no second softmax pass, no column in HBM.  The
+    saved log-sum-exp holds the sink, so the backward kernels run as they
+    are, and ``db_h = - sum_t exp(b_h - lse_t) delta_t`` is formed beside
+    them from what they read (``_flash_sink_bwd``).  With ``lse`` the call
+    returns (result, the rows' log-sum-exp [B, H, Sq] float32), itself
+    differentiable: ``exp(b_h - lse)`` is the mass the sink took.  With
+    ``sink=None`` a call traces what it traced."""
+    if sink is None and lse:
+        raise ValueError("the log-sum-exp is handed out for a call with a "
+                         "sink only")
     if _in_parts(q):
         rows = True
         if q[0].shape[-1] % LANES or k[0].shape[-1] % LANES:
@@ -1420,9 +1552,19 @@ def flash_attention(q, k, v, *, causal: bool = True,
             functools.partial(flash_attention, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
                               q_offset=q_offset, interpret=interpret,
-                              window=window), q, k, v, rows)
-    return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
-                  interpret, window, bool(rows))
+                              window=window,
+                              **({} if sink is None
+                                 else {"sink": sink, "lse": lse})),
+            q, k, v, rows)
+    if sink is None:
+        return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
+                      interpret, window, bool(rows))
+    heads = _dims(q, k, v).H
+    if sink.shape != (heads,):
+        raise ValueError(f"a sink a query head: {sink.shape} for {heads}")
+    got = _flash_sink(q, k, v, sink, causal, scale, block_q, block_k,
+                      q_offset, interpret, window, bool(rows))
+    return got if lse else got[0]
 
 
 def _on_tpu() -> bool:
@@ -1433,8 +1575,10 @@ def _on_tpu() -> bool:
 
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               impl: Optional[str] = None, mesh=None,
-              window: Optional[int] = None, rows: bool = False):
+              window: Optional[int] = None, rows: bool = False,
+              sink=None, lse: bool = False):
     """Dispatching entry point: pallas flash on TPU, reference elsewhere.
+    ``sink`` and ``lse`` are ``flash_attention``'s (one device only).
     ``rows`` is ``flash_attention``'s: v and the result lie as
     [B, S, H, D]; so are q and k in parts, which only the kernels on one
     device take as they are (the reference and a mesh's island get the
@@ -1451,14 +1595,20 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         rows = True
         if impl == "reference" or island:
             q, k, v = _one_part(q, k, v)
+    with_sink = {} if sink is None else {"sink": sink, "lse": lse}
     if impl == "reference":
         return _head_major(
             functools.partial(reference_attention, causal=causal,
-                              scale=scale, window=window), q, k, v, rows)
+                              scale=scale, window=window, **with_sink),
+            q, k, v, rows)
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
                            interpret=impl == "flash_interpret",
-                           window=window)
+                           window=window, **with_sink)
     if island:
+        if sink is not None:
+            raise NotImplementedError(
+                "a sink on a mesh: the island's specs know no [H] operand "
+                "(ROADMAP M16)")
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ,

@@ -257,10 +257,16 @@ _rotate_and_place.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def rotate_heads(x, cos2, sin2, positions=None, *, interpret: bool = False,
-                 mesh=None):
+                 mesh=None, rotary_dim: Optional[int] = None):
     """Rotary embedding of a projection's result, placed for attention.
     x: [B, S, H, D] -> [B, H, S, D]; ``cos2`` / ``sin2`` are
     ``rope_lane_tables``'.
+
+    ``rotary_dim`` under ``D`` (a partial rotary factor): only the FIRST
+    ``rotary_dim`` lanes of a head turn, as two halves of ``rotary_dim //
+    2`` by tables ``rotary_dim`` wide, and the others pass with no position:
+    ``apply_rope`` on the slice behind the transpose (no kernel serves a
+    head of 64 + 128 lanes; counted as ``path="xla"``).
 
     The kernel where the module's docstring says it applies (``interpret``
     runs it in interpret mode wherever the shapes allow, for the tests),
@@ -271,6 +277,14 @@ def rotate_heads(x, cos2, sin2, positions=None, *, interpret: bool = False,
     whether or not it says so with ``positions``."""
     from .attention import LANES, _on_tpu   # at the call: tests steer it
     _, S, H, D = x.shape
+    if rotary_dim is not None and rotary_dim < D:
+        telemetry.inc("ray_tpu_rope_path_total", tags={
+            "path": "xla", "rows": str(S), "heads": str(H)})
+        x = jnp.swapaxes(x, 1, 2)
+        return jnp.concatenate([
+            apply_rope(x[..., :rotary_dim], cos2[..., :rotary_dim // 2],
+                       sin2[..., rotary_dim // 2:], positions),
+            x[..., rotary_dim:]], axis=-1)
     island = mesh is not None and mesh.size > 1
     if island:
         from ..parallel.mesh import (AXIS_DATA, AXIS_FSDP, AXIS_SEQ,

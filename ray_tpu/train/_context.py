@@ -199,7 +199,9 @@ _LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
               # and of a delta-rule model's
               "kda_chunk_carry": "ray_tpu_kda_chunk_carry",
               # and of a differential attention's pair
-              "gdla_lambda_mean": "ray_tpu_gdla_lambda_mean"}
+              "gdla_lambda_mean": "ray_tpu_gdla_lambda_mean",
+              # and of a window attention's learned sink
+              "sink_mass_mean": "ray_tpu_attn_sink_mass_mean"}
 #: and of a model's several output heads a position: one sample a head
 _HEAD_KEYS = {"head_loss": "ray_tpu_train_head_loss"}
 #: the tag a gauge's samples are told apart by where it has one a pass or head

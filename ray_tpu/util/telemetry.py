@@ -269,6 +269,14 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "attention's noise head is subtracted with "
                        "(models/motif.py), in the last reported step: 0 "
                        "or 1 says the pair is dead."},
+    "ray_tpu_attn_sink_mass_mean": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean over a model's window layers, query heads and "
+                       "positions of exp(b_h - lse_t), the share of a "
+                       "row's softmax mass that the learned attention sink "
+                       "took (models/mimo_v2.py on ops/attention's sink), "
+                       "in the last reported step: near 0 the sink does "
+                       "nothing, near 1 the layers attend to nothing."},
     "ray_tpu_train_checkpoint_seconds": {
         "type": "histogram", "tag_keys": ("op",),
         "boundaries": _STEP_BUCKETS,

@@ -375,6 +375,13 @@ TILES.update({
     "tokens_16k": ((16384, 16384, 128, 1, None), [(1024, 1024, 1)] * 3),
     "tokens_32k": ((32768, 32768, 128, 1, None), [(1024, 1024, 1)] * 3),
     "latent_6144": ((6144, 6144, 192, 1, None), [(512, 512, 1, 4)] * 3),
+    # mimo-v2-flash.train-sink8k (PR 62), 192 / 128 in ONE part: a window
+    # layer's 16 query heads over 2 key heads are the band's stacked 8, the
+    # full layer's 16 over one key head a head a row at 8 tiles a step
+    "mimo_window_group8": ((8192, 8192, 192, 8, 128),
+                           [(128, 256, 8), (128, 256, 8), (256, 128, 8)]),
+    "mimo_full_group16": ((8192, 8192, 192, 16, None),
+                          [(512, 512, 1, 8)] * 3),
 })
 
 
@@ -416,6 +423,8 @@ ONE_PASS = {
     "tokens_16k": None,
     "tokens_32k": None,
     "latent_6144": (512, 512, 1, 4),
+    "mimo_window_group8": None,     # a window at a head size over 128
+    "mimo_full_group16": None,      # in one part [8192, 192] pads to 8 MiB
 }
 
 
@@ -542,3 +551,111 @@ def test_group_wider_than_a_step_is_summed_outside(backward, monkeypatch):
     want = grads(lambda q, k, v: reference_attention(q, k, v))
     for a, b, name in zip(want, got, ("dq", "dk", "dv")):
         np.testing.assert_allclose(b, a, atol=5e-4, rtol=1e-3, err_msg=name)
+
+
+# A learned sink (PR 62): (H, Hkv, S, D, Dv, window, blocks, rows, dtype).
+# The forward starts a row's online softmax at the sink; the backward
+# kernels are the call's without one, and db is formed beside them.
+SINKS = {
+    # a window narrower than the row, the group's 4 heads one step
+    "window_group4": (8, 2, 256, 48, 32, 40, (64, 64), False, jnp.float32),
+    # 16 query heads over ONE key head: two steps' shares summed outside
+    "full_group16": (16, 1, 128, 48, 32, None, (64, 64), False, jnp.float32),
+    # the cell's window geometry at its head sizes: 128 x 256, 8 stacked
+    "band_192_128": (8, 1, 512, 192, 128, 128, (None, None), False,
+                     jnp.float32),
+    # the one pass makes dq beside dk/dv from the delta it is handed
+    "one_pass": (4, 4, 128, 32, 32, None, (64, 64), False, jnp.float32),
+    # v and the result as rows, in place at 128
+    "rows_128": (2, 1, 128, 128, 128, None, (64, 64), True, jnp.float32),
+    "bf16": (8, 2, 256, 48, 32, 40, (64, 64), False, jnp.bfloat16),
+}
+
+
+def _sink_case(case):
+    H, Hkv, S, D, Dv, window, (bq, bk), rows, dtype = SINKS[case]
+    ks = jax.random.split(jax.random.key(21), 6)
+    q = jax.random.normal(ks[0], (1, H, S, D), dtype)
+    k = jax.random.normal(ks[1], (1, Hkv, S, D), dtype)
+    v = jax.random.normal(ks[2], (1, S, Hkv, Dv) if rows
+                          else (1, Hkv, S, Dv), dtype)
+    sink = 1.0 + jax.random.normal(ks[3], (H,), jnp.float32)
+    do = jax.random.normal(ks[4], (1, S, H, Dv) if rows else (1, H, S, Dv),
+                           dtype)
+    dlse = 0.3 * jax.random.normal(ks[5], (1, H, S), jnp.float32)
+    flash = lambda q, k, v, **kw: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, window=window,
+        rows=rows, **kw)
+    return q, k, v, sink, do, dlse, flash, window, rows, dtype
+
+
+@pytest.mark.parametrize("case", SINKS)
+def test_sink_matches_the_reference_forward_and_backward(case):
+    """The result, the log-sum-exp that holds the sink, and every gradient,
+    the sink's among them and with a cotangent on the LSE too, against
+    ``reference_attention``'s extra column in float32 on the very inputs;
+    the forward kernel is counted under its sink's name and the backward
+    kernels under the names they have without one."""
+    q, k, v, sink, do, dlse, flash, window, rows, dtype = _sink_case(case)
+    before = _geometry_counts()
+
+    def fwd_bwd(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return out + vjp((do.astype(out[0].dtype), dlse))
+
+    got = fwd_bwd(lambda q, k, v, b: flash(q, k, v, sink=b, lse=True),
+                  q, k, v, sink)
+
+    def reference(q, k, v, b):
+        v = jnp.swapaxes(v, 1, 2) if rows else v
+        out, lse = reference_attention(q, k, v, window=window, sink=b,
+                                       lse=True)
+        return (jnp.swapaxes(out, 1, 2) if rows else out), lse
+
+    want = fwd_bwd(reference, *(x.astype(jnp.float32) for x in (q, k, v)),
+                   sink)
+    tol = 5e-4 if dtype == jnp.float32 else 3e-2
+    for a, b, name in zip(want, got,
+                          ("out", "lse", "dq", "dk", "dv", "db")):
+        a = np.asarray(a)
+        assert b.shape == a.shape, name
+        np.testing.assert_allclose(
+            np.asarray(b, np.float32), a, atol=tol * np.abs(a).max(),
+            rtol=tol, err_msg=name)
+    assert got[1].dtype == got[5].dtype == jnp.float32
+    after = _geometry_counts()
+    grew = {name for name in after
+            if sum(after[name].values()) > sum(before.get(name,
+                                                          {}).values())}
+    assert any(n.startswith("flash_fwd") and n.endswith("_sink")
+               for n in grew), grew
+    assert not any(n.endswith("_sink") for n in grew
+                   if not n.startswith("flash_fwd")), grew
+
+
+@pytest.mark.parametrize("case", ["window_group4", "one_pass", "rows_128"])
+def test_a_sink_that_takes_no_mass_is_the_call_without_one(case):
+    """Bit for bit: a row that starts at (m, l) = (-1e30, 1) scales that 1
+    away at its first key, as a row that starts at (-inf, 0) has nothing to
+    scale; and ``sink=None`` is the call that does not name a sink (the
+    same kernels by the same names: nothing of the sink is traced)."""
+    q, k, v, _, do, _, flash, _, _, _ = _sink_case(case)
+    H = q.shape[1]
+
+    def fwd_bwd(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(do)
+
+    plain = fwd_bwd(flash)
+    for other in (fwd_bwd(lambda q, k, v: flash(q, k, v, sink=None)),
+                  fwd_bwd(lambda q, k, v: flash(
+                      q, k, v, sink=jnp.full((H,), -1e30, jnp.float32)))):
+        for a, b in zip(plain, other):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    text = str(jax.make_jaxpr(lambda q, k, v: flash(q, k, v, sink=None))(
+        q, k, v))
+    assert "sink" not in text
+    with pytest.raises(ValueError, match="sink"):
+        flash(q, k, v, lse=True)
+    with pytest.raises(ValueError, match="a sink a query head"):
+        flash(q, k, v, sink=jnp.zeros((H + 1,), jnp.float32))

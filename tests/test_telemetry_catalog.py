@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated", "mla", "kda", "gdla", "remat")
+              "gated", "mla", "kda", "gdla", "remat", "attn")
 
 
 class TestCatalog:
@@ -462,7 +462,10 @@ def _smoke_train_fn(config):
                       "kda_chunk_carry": 0.9,
                       # gdla: the mean weight a differential attention's
                       # noise heads are subtracted with
-                      "gdla_lambda_mean": 0.5})
+                      "gdla_lambda_mean": 0.5,
+                      # sink: the share of a window row's softmax mass
+                      # that the learned attention sink took
+                      "sink_mass_mean": 0.4})
 
 
 @serve.deployment(name="telemetry_echo")
