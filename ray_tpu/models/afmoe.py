@@ -314,8 +314,9 @@ def _moe(cfg, h, layer, bias, act: str = "silu", route_eps=1e-20,
         routed = routed.reshape(B, S, E).astype(dt)
         return (routed if shared is None else shared + routed,
                 {"counts": routing.counts, "dropped": dropped,
-                 "sliced": (held > moe.buffer_rows(B * S, cfg.top_k)
-                            ).astype(jnp.int32),
+                 "sliced": (held > moe.buffer_rows(
+                     B * S, cfg.top_k, layer["w_up"].shape[0],
+                     routing.counts.shape[0])).astype(jnp.int32),
                  "top": routing.expert_index})
 
 

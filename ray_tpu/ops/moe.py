@@ -10,7 +10,12 @@ scores all ``X`` experts and picks ``k`` of them (8 of 128 there); the
 layer computes the part of the result that its own ``Xh`` experts give,
 as one chip of an expert-parallel group does, without the exchange.  The
 assignments to held experts are sorted by expert into a buffer of static
-size and multiplied by grouped matrix products over the ragged groups:
+size, R rows: twice the load expected at the share of the experts the
+layer holds (``buffer_tiers``: T * k over the largest power of two that
+leaves twice T * k * Xh / X, never over a quarter of the worst case; a call
+whose load passes R takes its tokens through the buffer in as many slices,
+so nothing is ever dropped), and multiplied by grouped matrix products over
+the ragged groups:
 three a row for a gated expert (``act(x W_gate) * (x W_up)``, then
 ``W_down``; Trinity's and Xing4.0's SwiGLU), two for an un-gated one
 (``act(x W_up) W_down``; Nemotron-H's ``relu2``).
@@ -123,7 +128,9 @@ def update_selection_bias(bias, counts, rate: float = 1e-3):
     return bias + d - jnp.mean(d, axis=-1, keepdims=True)
 
 
-#: The dropless buffer holds 1 / BUFFER_TIERS of the worst case's rows.
+#: The dropless buffer holds at most 1 / BUFFER_TIERS of the worst case's
+#: rows: the tiers where an eighth of the experts or more is held
+#: (``buffer_tiers`` takes more where less is).
 BUFFER_TIERS = 4
 
 #: The tiles every grouped product took until PR 44 (PERF.md, PR 29: best
@@ -677,11 +684,32 @@ def _held_rows(xt, top, w, w_gate, w_up, w_down, held_start, rows, impl,
     return tokens_from_rows(y_rows, w.astype(jnp.float32), at), used
 
 
-def buffer_rows(T: int, k: int) -> int:
+def buffer_tiers(T: int, held: Optional[int] = None,
+                 routed: Optional[int] = None) -> int:
+    """The tiers a call of T tokens divides its worst case by, where
+    ``held`` of the router's ``routed`` experts are held: the largest power
+    of two that leaves the buffer TWICE the expected load, ``2 ** floor(log2(
+    routed / (2 * held)))``, never under ``BUFFER_TIERS`` (the share unknown,
+    or an eighth and over), and only while a slice of T / tiers tokens is
+    whole tiles of the sum's kernel (``_GRANULE``).  1 where the tokens do
+    not split into ``BUFFER_TIERS``: the buffer is the worst case's."""
+    if T % BUFFER_TIERS:
+        return 1
+    tiers = BUFFER_TIERS
+    if held and routed:
+        while (4 * tiers * held <= routed
+               and T % (2 * tiers * _GRANULE) == 0):
+            tiers *= 2
+    return tiers
+
+
+def buffer_rows(T: int, k: int, held: Optional[int] = None,
+                routed: Optional[int] = None) -> int:
     """Rows of the buffer a call of T tokens with k assignments each goes
-    through: a call whose held assignments pass them takes the buffer in
-    BUFFER_TIERS slices (``dropless_experts``)."""
-    return T * k if T % BUFFER_TIERS else T * k // BUFFER_TIERS
+    through, ``held`` of ``routed`` experts held: T * k over
+    ``buffer_tiers``.  A call whose held assignments pass them takes the
+    buffer in that many slices (``dropless_experts``)."""
+    return T * k // buffer_tiers(T, held, routed)
 
 
 def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
@@ -702,11 +730,23 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     0).
 
     Buffers are static.  All T*k assignments may go to held experts, so the
-    worst case needs T*k rows; a share of Xh / X is the usual case.  The
-    tokens therefore go through a buffer of T*k / BUFFER_TIERS rows at once
-    when the held assignments fit it, and otherwise in BUFFER_TIERS slices
-    of the tokens, one after the other through the same buffer (a slice of
-    T / BUFFER_TIERS tokens has at most that many assignments).
+    worst case needs T*k rows; a share of Xh / X is the usual case, T*k *
+    Xh / X rows.  The tokens therefore go through a buffer of R = T*k /
+    tiers rows at once when the held assignments fit it, and otherwise in
+    ``tiers`` slices of the tokens, one after the other through the same
+    buffer (a slice of T / tiers tokens has at most that many assignments).
+    ``tiers`` follows the share the call can see (``buffer_tiers``: Xh of
+    ``routing.counts``' X): the largest power of two that leaves R twice
+    the expected load, never under BUFFER_TIERS.  An eighth held (Trinity,
+    Xing4.0, Nemotron, Kanana, LFM2) is 4 tiers, 16,384 rows for 8,192
+    tokens at k 8; a thirty-second or a forty-eighth (MiMo-V2-Flash,
+    Ling-3.0-flash, Motif-3-beta) 16 tiers and 4,096 rows, where four
+    tiers were 8 and 12 times the load.  What that cost, a layer call's
+    recomputed forward and backward alone on a TPU v5e at R 16,384 and at
+    4,096 (PERF.md, PR 63, step 0): MiMo's shape (E 4,096, M 2,048, 8 of
+    256) 8.66 -> 4.86 ms, Ling's (E 2,560, M 768, 16 of 512) 3.68 -> 2.06,
+    Motif's (E 4,096, M 1,280, 8 of 384, PolyNorm) 7.36 -> 3.89; the
+    grouped products 4.25 -> 4.08, 1.56 -> 1.40 and 3.07 -> 2.92 of them.
 
     Rows move without a scatter: into the buffer each row reads its token
     (``rows_of_tokens``), out of it each token sums the rows it holds, each
@@ -716,23 +756,29 @@ def dropless_experts(xt, routing: SigmoidRouting, w_gate, w_up, w_down,
     tokens, an eighth of the slots held; PERF.md, PR 45): the gather into
     the buffer its whole size (R rows read and written, 0.10 ms, and the
     select that zeroes the rows no group holds 0.09-0.20 ms more), as do
-    the activation and the backward's row passes; the sum out of it the
+    the activation and the backward's row passes (``_tokens_bwd``'s
+    float32 [R, E] gather, select and row-dot, the sum of the two
+    products' cotangents, PolyNorm's ``held`` selects), R being twice the
+    expected load at any share; the sum out of it the
     rows in use (the kernel: 0.27-0.31 ms, a third of it a tile's fixed
     costs, the rest two cycles a row's vector register: each row is added
     to its token's row of the sum on its own); the grouped products the
     rows in use; ``_places`` about 0.2 ms, twice a call (the recomputed
     forward makes it again)."""
     T, k = routing.expert_index.shape
-    Xh, tiers = w_up.shape[0], BUFFER_TIERS
+    Xh, X = w_up.shape[0], routing.counts.shape[0]
     top, w = routing.expert_index, routing.weights
     held = jnp.sum(routing.counts[held_start:held_start + Xh])
-    rows = buffer_rows(T, k)
+    tiers = buffer_tiers(T, Xh, X)
+    rows = T * k // tiers
+    telemetry.inc("ray_tpu_moe_buffer_total", tags={
+        "rows": str(rows), "tiers": str(tiers), "held": str(Xh),
+        "routed": str(X), "tokens": str(T), "slots": str(k)})
     run = functools.partial(_held_rows, w_gate=w_gate, w_up=w_up,
                             w_down=w_down, held_start=held_start, rows=rows,
-                            impl=impl, activation=activation,
-                            experts=routing.counts.shape[0],
+                            impl=impl, activation=activation, experts=X,
                             act_weights=act_weights)
-    if rows == T * k:
+    if tiers == 1:
         out, used = run(xt, top, w)
         return out, (held, held - used)
 

@@ -513,6 +513,16 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "the rows' gradient) or tgmm (the weights' "
                        "gradient); rows_a_group the rows a group is "
                        "expected to hold."},
+    "ray_tpu_moe_buffer_total": {
+        "type": "counter",
+        "tag_keys": ("rows", "tiers", "held", "routed", "tokens", "slots"),
+        "description": "Expert-layer calls traced (ops/moe.dropless_experts), "
+                       "by the buffer each took: its rows, the tiers the "
+                       "worst case of tokens x slots assignments was divided "
+                       "by (ops/moe.buffer_tiers: twice the expected load "
+                       "where held of the routed experts are held, never "
+                       "under 4) and so the slices of the fallback that "
+                       "ray_tpu_moe_sliced_calls_total counts."},
     "ray_tpu_mla_call_geometry_total": {
         "type": "counter",
         "tag_keys": ("heads", "dn", "dr", "dv", "q_lora", "rows", "seq",

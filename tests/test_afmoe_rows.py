@@ -3,6 +3,10 @@
 the ``jnp`` and the kernel path, and that rows no group holds reach nothing.
 (Cut from ``tests/test_afmoe.py``, PR 59.)"""
 
+import functools
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +14,11 @@ import pytest
 
 from ray_tpu.ops import moe
 
-from ops_cases import _counted, _experts
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import reference_afmoe as ref  # noqa: E402
+from ops_cases import _counted, _experts  # noqa: E402
 
 pytestmark = pytest.mark.usefixtures("no_mesh_left_by_another_file")
 
@@ -141,6 +149,87 @@ def test_the_pair_is_the_gather_and_scatter_add_it_replaced(
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, atol=atol, rtol=rtol)
+
+
+# share of the router's experts held: (held, routed, tiers at 8,192 tokens,
+# the buffer's rows there at k 8 / 6 / 4).  An eighth in Trinity-Mini,
+# Xing4.0, Nemotron, Kanana and LFM2, a thirty-second in MiMo-V2-Flash and
+# Ling-3.0-flash, a forty-eighth in Motif-3-beta, all in a one-chip model.
+_SHARES = {"eighth": (16, 128, 4, (16384, 12288, 8192)),
+           "thirty-second": (8, 256, 16, (4096, 3072, 2048)),
+           "forty-eighth": (8, 384, 16, (4096, 3072, 2048)),
+           "all": (8, 8, 4, (16384, 12288, 8192))}
+
+
+def _buffers_counted():
+    return _counted("ray_tpu_moe_buffer_total",
+                    ("rows", "tiers", "held", "routed", "tokens", "slots"))
+
+
+@pytest.mark.parametrize("share,load", [
+    *((share, load) for share in _SHARES
+      for load in ("usual", "every-slot-held")),
+    ("thirty-second", "every-slot-held-kernel")])
+def test_the_buffer_follows_the_share_of_the_experts_held(share, load,
+                                                          monkeypatch):
+    """The buffer is twice the expected load at any share, and never over a
+    quarter of the worst case: its rows and tiers for 8,192 tokens (an
+    eighth and all held: what the four tiers gave), the (T, k) form the
+    share unknown; the layer against the dense sum, result and gradients,
+    with the usual load through the buffer at once and with every slot of
+    every token held, 8 times the mean and more, through all its slices
+    (16 of 64 tokens where a thirty-second or less is held; once with the
+    sums on the kernel path, interpreted), nothing dropped; and the counter
+    says which buffer the call took."""
+    Xh, X, tiers, rows = _SHARES[share]
+    assert moe.buffer_tiers(8192, Xh, X) == tiers
+    assert tuple(moe.buffer_rows(8192, k, Xh, X) for k in (8, 6, 4)) == rows
+    assert tuple(moe.buffer_rows(8192, k) for k in (8, 6, 4)) == (
+        16384, 12288, 8192)
+    # a slice is whole tiles of the sum's kernel, or the tiers stay lower
+    assert moe.buffer_tiers(512, Xh, X) == min(tiers, 8)
+    assert moe.buffer_tiers(62, Xh, X) == 1
+
+    T, k, E = 1024, 8, 128 if load.endswith("kernel") else 32
+    xt, rw, wg, wu, wd = _experts(T=T, E=E, X=X, Xh=Xh, k=k)
+    R = moe.buffer_rows(T, k, Xh, X)
+    assert R == T * k // tiers
+    if E == 128:
+        monkeypatch.setattr(moe, "_INTERPRET_ROWS", True)
+        assert moe._rows_tile(T // tiers, R, E, Xh, xt.dtype) == 64
+    bias = jnp.where(jnp.arange(X) < Xh, 10.0 * (load != "usual"), 0.0)
+
+    def layer(reference, xt, rw, wg, wu, wd):
+        routing = moe.sigmoid_routing(xt, rw, bias, k, 2.5)
+        if reference:
+            out, stats = ref.held_experts(xt, routing.expert_index,
+                                          routing.weights, wg, wu, wd, 0), ()
+        else:
+            out, stats = moe.dropless_experts(xt, routing, wg, wu, wd, 0)
+        return jnp.sum(jnp.sin(out)), (out, stats)
+
+    run = lambda which: jax.jit(jax.value_and_grad(
+        functools.partial(layer, which), argnums=(0, 1, 2, 3, 4),
+        has_aux=True))(xt, rw, wg, wu, wd)
+    before = _buffers_counted()
+    (got, (out, (held, dropped))), grads = run(False)
+    key = (str(R), str(tiers), str(Xh), str(X), str(T), str(k))
+    assert _buffers_counted().get(key, 0) == before.get(key, 0) + 1
+    (want, (want_out, _)), want_grads = run(True)
+    sliced = int(held) > R
+    if load == "usual":
+        # twice the mean holds what a seeded router sends (all held: T * k)
+        assert sliced == (share == "all") and int(held) >= T * k * Xh // X // 2
+    else:
+        assert sliced and int(held) == T * k
+    assert int(dropped) == 0
+    np.testing.assert_allclose(out, want_out, atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    # float32 sums over E features and, for the router's, T tokens
+    atol = 5e-5 if E == 32 else 4e-4
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b, atol=atol, rtol=5e-4)
 
 
 def _equations(jaxpr):

@@ -14,7 +14,7 @@ import os
 import pytest
 
 from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
-    ROOT, _cell_step, _kernels, topo)
+    ROOT, _assert_the_experts_buffer_has, _cell_step, _kernels, topo)
 
 CELL = "ling-3.0-flash.train-kda8k"
 
@@ -77,3 +77,9 @@ def test_ling_train_step_compiles_at_the_cell_sizes(ling_step, capsys):
                   "block/attn/mla/out", "block/moe/route",
                   "block/moe/experts", "block/moe/shared", "block/mlp"):
         assert scopes.seconds_under(by, scope) > 0, scope
+
+
+def test_the_experts_buffer_is_twice_the_expected_load(ling_step):
+    """16 of 512 experts held and 8 choices a token: a row of 8,192 tokens goes
+    through 4,096 rows (16 tiers), not the 16,384 of four."""
+    _assert_the_experts_buffer_has(ling_step["text"], 4096, 16384)

@@ -15,7 +15,7 @@ import re
 import pytest
 
 from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
-    ROOT, _cell_step, _kernels, topo)
+    ROOT, _assert_the_experts_buffer_has, _cell_step, _kernels, topo)
 
 CELL = "mimo-v2-flash.train-sink8k"
 
@@ -80,3 +80,9 @@ def test_mimo_train_step_compiles_at_the_cell_sizes(mimo_step, capsys):
                   "attn/sink_grad", "block/moe/route", "block/moe/experts"):
         assert scopes.seconds_under(by, scope) > 0, scope
     assert scopes.seconds_under(by, "block/moe/shared") == 0
+
+
+def test_the_experts_buffer_is_twice_the_expected_load(mimo_step):
+    """8 of 256 experts held and 8 choices a token: a row of 8,192 tokens goes
+    through 4,096 rows (16 tiers), not the 16,384 of four."""
+    _assert_the_experts_buffer_has(mimo_step["text"], 4096, 16384)

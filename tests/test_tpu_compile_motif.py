@@ -15,7 +15,7 @@ import re
 import pytest
 
 from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
-    ROOT, _cell_step, _kernels, topo)
+    ROOT, _assert_the_experts_buffer_has, _cell_step, _kernels, topo)
 
 CELL = "motif-3-beta.train-gdla8k"
 
@@ -89,3 +89,9 @@ def test_motif_train_step_compiles_at_the_cell_sizes(motif_step, capsys):
                   "block/moe/route", "block/moe/experts",
                   "block/moe/shared"):
         assert scopes.seconds_under(by, scope) > 0, scope
+
+
+def test_the_experts_buffer_is_twice_the_expected_load(motif_step):
+    """8 of 384 experts held and 8 choices a token: a row of 8,192 tokens goes
+    through 4,096 rows (16 tiers), not the 16,384 of four."""
+    _assert_the_experts_buffer_has(motif_step["text"], 4096, 16384)

@@ -12,8 +12,8 @@ import pytest
 
 from v5e_compile import (  # noqa: F401 (``topo`` is a fixture)
     _assert_q_and_k_cross_hbm_once,
-    _assert_rows_leave_the_experts_buffer_by_the_rows_in_use, _cell_step,
-    _kernels, topo)
+    _assert_rows_leave_the_experts_buffer_by_the_rows_in_use,
+    _assert_the_experts_buffer_has, _cell_step, _kernels, topo)
 
 
 @pytest.fixture(scope="module")
@@ -75,3 +75,39 @@ def test_rows_leave_the_experts_buffer_by_the_rows_in_use(trinity_step, T, k,
     asserted: the helper's docstring)."""
     _assert_rows_leave_the_experts_buffer_by_the_rows_in_use(
         trinity_step["text"], T, k, E)
+
+
+@pytest.mark.parametrize("impl", ["gmm", "ragged_dot"])
+def test_an_eighth_share_layer_traces_what_the_four_tiers_traced(
+        trinity_step, impl, monkeypatch):
+    """Where an eighth of the experts is held (16 of 128 here) the buffer's
+    rule gives the four tiers every share had until PR 63: the layer's
+    jaxpr at this cell's shapes, forward and backward, is letter for letter
+    the one the fixed rule traces (PR 63 read both equal to the parent
+    commit's too, 484,636 characters under the Pallas products), and the
+    compiled step holds the 16,384 rows it held."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+
+    T, E, M, Xh, X, k = 8192, 2048, 1024, 16, 128, 8
+    assert moe.buffer_rows(T, k, Xh, X) == moe.buffer_rows(T, k) == 16384
+
+    def layer(xt, rw, wg, wu, wd):
+        routing = moe.sigmoid_routing(xt, rw, jnp.zeros((X,)), k, 2.5)
+        out, stats = moe.dropless_experts(xt, routing, wg, wu, wd, 0, impl)
+        return jnp.sum(out.astype(jnp.float32)), stats
+
+    def traced():
+        S, bf16 = jax.ShapeDtypeStruct, jnp.bfloat16
+        return str(jax.make_jaxpr(jax.grad(
+            layer, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                S((T, E), bf16), S((E, X), jnp.float32), S((Xh, E, M), bf16),
+                S((Xh, E, M), bf16), S((Xh, M, E), bf16)))
+
+    now = traced()
+    monkeypatch.setattr(moe, "buffer_tiers", lambda T, held=None,
+                        routed=None: 1 if T % 4 else 4)
+    assert traced() == now and f"[{T * k // 4},{E}]" in now
+    if impl == "gmm":
+        _assert_the_experts_buffer_has(trinity_step["text"], 16384, 4096)

@@ -276,3 +276,19 @@ def _assert_rows_leave_the_experts_buffer_by_the_rows_in_use(text, T, k, E):
                 if " scatter(" in line and "block/moe" in line
                 and "/experts/" not in line]
     assert not scatters, scatters[:2]
+
+
+def _assert_the_experts_buffer_has(text, rows, never):
+    """In a cell's compiled step the arrays of the expert layers that lead
+    with the dropless buffer's rows lead with ``rows`` (twice the expected
+    load at the share of the experts the cell holds,
+    ``ops/moe.buffer_rows``), and none under ``block/moe`` leads with
+    ``never``, the rows another share's tiers would give."""
+    import re
+    lead = {}
+    for line in text.splitlines():
+        if "block/moe" in line:
+            for n in re.findall(r"\[(\d+),\d+\]", line.partition(" = ")[2]
+                                .partition("metadata=")[0]):
+                lead[int(n)] = lead.get(int(n), 0) + 1
+    assert lead.get(rows, 0) > 0 and never not in lead, sorted(lead.items())
