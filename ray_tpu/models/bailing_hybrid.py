@@ -15,7 +15,11 @@ What it has that no other model here has:
   gated delta rule over a matrix state a head (``ops/kda.py``), an RMSNorm
   over each head's 128 channels with one learned weight, an output gate
   ``sigmoid(x W_g)`` and the out-projection.  No positional term: the
-  recurrence carries position.  Every row starts from a zero state.
+  recurrence carries position.  Every row starts from a zero state.  What
+  stands between the projections and the scan, and between the scan and
+  the out-projection, is ``ops/kda.kda_mixer`` / ``gated_head_norm``: on a
+  TPU one Pallas pass each way on the flat arrays the scan's kernels read
+  and write, ``jnp`` elsewhere.
 - **Latent attention with a head-wise output gate**: DeepSeek-V3's as
   ``models/xing4._mla`` runs it for Kanana (no query bottleneck, 128 + 64 /
   128), the result of head ``h`` multiplied by ``sigmoid(x W_theta)_h``
@@ -49,7 +53,6 @@ import jax.numpy as jnp
 
 from . import _lm, afmoe
 from ..ops import kda as kda_ops
-from ..ops import ssm
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_lane_tables
 from ..util import telemetry
@@ -267,45 +270,30 @@ def init_state(cfg: BailingHybridConfig) -> Dict[str, jax.Array]:
 
 # ------------------------------------------------------------------ layers
 
-def _unit(x):
-    """x / ||x|| over the last axis, in float32, in x's dtype."""
-    x32 = x.astype(F32)
-    return (x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)
-                                + 1e-6)).astype(x.dtype)
-
-
 @jax.named_scope("block/attn")
 def _kda(cfg: BailingHybridConfig, x, layer):
     """F of a KDA layer on the normed stream x [B, S, E] -> (out [B, S, E],
     the mean share of a state's row that a chunk hands on)."""
     dt = cfg.dtype
-    B, S, _ = x.shape
-    H, D, F = cfg.heads, cfg.head_dim, cfg.kda_dim
     proj = lambda w: jnp.einsum("bse,ef->bsf", x, w.astype(dt),
                                 preferred_element_type=dt)
     with jax.named_scope("kda/proj"):
         qkv, a, gate = proj(layer["w_qkv"]), proj(layer["w_a"]), \
             proj(layer["w_g"])
-    with jax.named_scope("kda/conv"):
-        conv_w = layer["conv_w"]
-        qkv = ssm.causal_conv(qkv, conv_w,
-                              jnp.zeros(conv_w.shape[1:], conv_w.dtype))
     with jax.named_scope("kda/gate"):
-        q, k, v = (c.reshape(B, S, H, D) for c in jnp.split(qkv, 3, axis=-1))
-        q, k = _unit(q), _unit(k)
         beta = jax.nn.sigmoid(jnp.einsum(
             "bse,eh->bsh", x.astype(F32), layer["w_beta"].astype(F32)))
-        rate = jnp.repeat(jnp.exp(layer["A_log"].astype(F32)), D)
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(
-            rate * (a.astype(F32) + layer["dt_bias"].astype(F32)))
-        g = g.reshape(B, S, H, D)
+    # under ``kda/conv``, ``kda/gate`` and ``kda/scan``, the op's own scopes
+    o, g = kda_ops.kda_mixer(
+        qkv, a, beta, layer["conv_w"], layer["A_log"], layer["dt_bias"],
+        bound=cfg.kda_lower_bound, chunk=cfg.kda_chunk,
+        interpret=cfg.attention_impl == "flash_interpret")
+    with jax.named_scope("kda/gate"):
         carry = kda_ops.chunk_carry(g, cfg.kda_chunk)
-    # under ``kda/scan``, the op's own scope
-    o = kda_ops.kda(q, k, v, g, beta, cfg.kda_chunk,
-                    interpret=cfg.attention_impl == "flash_interpret")
-    with jax.named_scope("kda/norm"):
-        o = rms_norm(o, layer["o_norm"], cfg.norm_eps).reshape(B, S, F)
-        o = o * jax.nn.sigmoid(gate.astype(F32)).astype(dt)
+    # under ``kda/norm``, the op's own scope
+    o = kda_ops.gated_head_norm(
+        o, gate, layer["o_norm"], cfg.norm_eps,
+        interpret=cfg.attention_impl == "flash_interpret")
     with jax.named_scope("kda/out"):
         return jnp.einsum("bsf,fe->bse", o, layer["wo"].astype(dt),
                           preferred_element_type=dt), carry

@@ -550,6 +550,19 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "the rows and tokens a row of the call, and the "
                        "path it takes (kernel: the Pallas pair, forward "
                        "and backward; xla: the jnp form)."},
+    "ray_tpu_kda_pass_path_total": {
+        "type": "counter",
+        "tag_keys": ("pass", "path", "heads", "d", "rows", "seq"),
+        "description": "Calls traced of a KDA layer's elementwise passes "
+                       "round its recurrence (ops/kda.py), by the pass "
+                       "(inputs: the convolution, the unit norm and the "
+                       "decay between the projections and the scan, "
+                       "kda_mixer; norm: the head norm times the output "
+                       "gate after it, gated_head_norm), the path it takes "
+                       "(kernel: one Pallas pass each way on the flat "
+                       "arrays the scan's kernels read and write; xla: "
+                       "jnp), the heads, a head's channels, and the rows "
+                       "and tokens a row of the call."},
     "ray_tpu_moe_groups_kept_total": {
         "type": "counter", "tag_keys": ("n_group", "topk_group"),
         "description": "Forward passes traced of a model whose routers "

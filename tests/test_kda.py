@@ -181,3 +181,163 @@ def test_chunk_carry_is_the_mean_decay_of_a_whole_chunk():
     assert float(chunk_carry(g, 16)) == pytest.approx(np.exp(-0.16), rel=1e-5)
     assert float(chunk_carry(g[:, :8], 16)) == 1.0
     assert float(jax.grad(lambda g: chunk_carry(g, 16))(g).sum()) == 0.0
+
+
+# ------------------------- the pass between the projections and the scan
+
+def mixer_inputs(seed, B, S, H, D, gate, w_dtype=jnp.float32):
+    """A KDA layer's projections' results (bfloat16, as a training step has
+    them) and its small weights; ``gate``: the decay's projection random,
+    saturated at the bound, or so far under it that nothing decays."""
+    ks = jax.random.split(jax.random.key(seed), 10)
+    F, bf = H * D, jnp.bfloat16
+    qkv = jax.random.normal(ks[0], (B, S, 3 * F)).astype(bf)
+    a = (jax.random.normal(ks[1], (B, S, F))
+         * {"random": 2.0, "bound": 0.1, "none": 0.1}[gate]
+         + {"random": 0.0, "bound": 12.0, "none": -12.0}[gate]).astype(bf)
+    conv_w = (0.5 * jax.random.normal(ks[2], (4, 3 * F))).astype(w_dtype)
+    A_log = jnp.log(jax.random.uniform(ks[3], (H,), minval=1.0, maxval=16.0))
+    dt_bias = jax.random.normal(ks[4], (F,))
+    cts = tuple(jax.random.normal(k, (B, S, F)).astype(dt) for k, dt in zip(
+        ks[5:9], (bf, bf, bf, jnp.float32)))
+    return (qkv, a, conv_w, A_log, dt_bias), cts
+
+
+def _a_bf16_step(got, want):
+    """Equal but for roundings to bfloat16 that an operation order moved (a
+    cotangent rounded on its way, or the result): at most a thousandth of
+    the entries differ at all, none by more than a step of the format at
+    the largest entry."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    off = np.abs(got - want)
+    return off.max() <= 2.0 ** -8 * np.abs(want).max() \
+        and np.count_nonzero(off) <= got.size // 1000
+
+
+@pytest.mark.parametrize("gate,w_dtype", [
+    ("random", jnp.float32), ("bound", jnp.float32), ("none", jnp.float32),
+    ("random", jnp.bfloat16)])
+def test_the_pass_before_the_scan_is_the_jnp_form(gate, w_dtype):
+    """``kda_in_fwd`` / ``kda_in_bwd`` (interpreted) against ``_inputs_xla``
+    on two rows of 1,024 tokens: two tiles of a row forward and four
+    backward, so that taps cross a tile's edge both ways and the carried
+    rows reset at a row's start; six heads are two columns of three (a
+    column range's offset is not its tile).  Forward: the same bits, q, k,
+    v (bfloat16) and g (float32).  The five gradients through cotangents of
+    all four: the float32 ones to an accumulation order, the bfloat16 ones
+    (d qkv, d a, and d conv_w where the weights are bfloat16) to a rounding
+    that the order moved."""
+    B, S, H, D = 2, 1024, 6, 128
+    args, cts = mixer_inputs(11, B, S, H, D, gate, w_dtype)
+    assert K._in_tile(S, H, D, 4, False) == (512, 384)
+    assert K._in_tile(S, H, D, 4, True) == (256, 384)
+    flat = lambda t: tuple(x.reshape(B, S, H * D) for x in t)
+    want, pull_want = jax.vjp(jax.jit(lambda *a: flat(K._inputs_xla(
+        *a, H, -5.0))), *args)
+    got, pull_got = jax.vjp(lambda *a: K._inputs(
+        *a, heads=H, bound=-5.0, interpret=True), *args)
+    for name, x, y in zip("qkvg", got, want):
+        assert x.dtype == y.dtype and x.shape == y.shape, name
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32), err_msg=name)
+    if gate != "random":
+        g = np.asarray(got[3])
+        assert (g.max() < -4.99) if gate == "bound" else (g.min() > -1e-2)
+    dgot, dwant = jax.jit(pull_got)(cts), jax.jit(pull_want)(cts)
+    for name, x, y in zip(("qkv", "a", "conv_w", "A_log", "dt_bias"),
+                          dgot, dwant):
+        assert x.dtype == y.dtype and x.shape == y.shape, name
+        if x.dtype == jnp.bfloat16:
+            assert _a_bf16_step(x, y), name
+        else:
+            # (the taps' sums see the few cotangents whose rounding moved)
+            assert _close(x, y, 1e-4 if name == "conv_w" else 1e-5), name
+
+
+def _within_a_bf16_step(got, want):
+    """No entry further from ``want`` than two steps of bfloat16 at the larger
+    of the two, or than that at a hundredth of the largest entry where
+    terms cancelled: what a rounding more or less of each factor of a
+    product moves."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    size = np.maximum(np.maximum(np.abs(got), np.abs(want)),
+                      1e-2 * np.abs(want).max())
+    return bool(np.all(np.abs(got - want) <= 2.0 ** -6 * size))
+
+
+def test_the_pass_after_the_scan_is_the_jnp_form():
+    """``kda_norm_fwd`` / ``kda_norm_bwd`` (interpreted) against the head
+    norm times the gate's sigmoid in ``jnp``, two rows of two tiles, six
+    heads in two columns.  The pass is float32 from its reads to the one
+    rounding of each result; ``jnp`` here rounds the normed head and the
+    sigmoid before their product (inside the TPU compiler's fusion it does
+    not): two steps of bfloat16 apart at most forward, and each gradient
+    within a step of the format at its largest entry (d o cancels two
+    terms, the weight's is a sum over every token and head)."""
+    B, S, H, D = 2, 1024, 6, 128
+    ks = jax.random.split(jax.random.key(14), 4)
+    bf = jnp.bfloat16
+    o = jax.random.normal(ks[0], (B, S, H, D)).astype(bf)
+    gate = (2 * jax.random.normal(ks[1], (B, S, H * D))).astype(bf)
+    w = (1 + 0.1 * jax.random.normal(ks[2], (D,))).astype(bf)
+    dy = jax.random.normal(ks[3], (B, S, H * D)).astype(bf)
+    want, pull_want = jax.vjp(jax.jit(lambda *a: K._gated_norm_xla(
+        *a, 1e-6)), o, gate, w)
+    got, pull_got = jax.vjp(lambda *a: K._gated_norm(
+        *a, heads=H, eps=1e-6, interpret=True), o, gate, w)
+    assert got.dtype == want.dtype == bf and got.shape == want.shape
+    assert _within_a_bf16_step(got, want)
+    # against float32 of the same lines the pass is the nearer of the two
+    o32, g32, w32 = (a.astype(jnp.float32) for a in (o, gate, w))
+    exact = K._gated_norm_xla(o32, g32, w32, 1e-6)
+    err = lambda y: float(jnp.mean(jnp.abs(y.astype(jnp.float32) - exact)))
+    assert err(got) <= err(want)
+    for name, x, y in zip(("o", "gate", "w"), jax.jit(pull_got)(dy),
+                          jax.jit(pull_want)(dy)):
+        assert x.dtype == y.dtype and x.shape == y.shape, name
+        assert _close(x.astype(jnp.float32), y.astype(jnp.float32),
+                      2.0 ** -7), name
+
+
+def test_a_layer_takes_the_pass_where_a_row_is_whole_tiles_and_says_which():
+    """``kda_mixer`` and ``gated_head_norm`` read the path from the call: a
+    row of 512 tokens of heads of 128 goes through the Pallas passes (the
+    scan handed flat arrays), 64 tokens through ``jnp``; both give the
+    numbers of the ``jnp`` forms round ``kda``, and the counter names the
+    path of each pass."""
+    from ray_tpu.util import metrics as metrics_mod
+    H, D = 1, 128
+    metrics_mod._reset_for_tests()
+    for S, path in ((512, "kernel"), (64, "xla")):
+        (qkv, a, conv_w, A_log, dt_bias), _ = mixer_inputs(
+            12, 1, S, H, D, "random")
+        beta = jax.nn.sigmoid(jax.random.normal(jax.random.key(13),
+                                                (1, S, H)))
+        o, g = K.kda_mixer(qkv, a, beta, conv_w, A_log, dt_bias, bound=-5.0,
+                           chunk=64, interpret=True)
+        # (jitted, as a step runs it: eagerly its sums are not fused)
+        q, k, v, g4 = jax.jit(lambda *args: K._inputs_xla(*args, H, -5.0))(
+            qkv, a, conv_w, A_log, dt_bias)
+        want = kda(q, k, v, g4, beta, 64, interpret=True)
+        assert o.shape == (1, S, H, D) and o.dtype == qkv.dtype
+        assert g.shape == ((1, S, H * D) if path == "kernel"
+                           else (1, S, H, D))
+        assert _a_bf16_step(o, want)
+        assert float(chunk_carry(g, 64)) == pytest.approx(
+            float(chunk_carry(g4, 64)), rel=1e-6)
+        text = metrics_mod.prometheus_text()
+        line = next(l for l in text.splitlines() if l.startswith(
+            "ray_tpu_kda_pass_path_total{") and 'pass="inputs"' in l
+            and f'seq="{S}"' in l)
+        assert f'path="{path}"' in line and 'heads="1"' in line, line
+        gate = jax.random.normal(jax.random.key(15), (1, S, H * D)).astype(
+            qkv.dtype)
+        w = jnp.ones((D,), qkv.dtype)
+        y = K.gated_head_norm(o, gate, w, 1e-6, interpret=True)
+        assert _within_a_bf16_step(y, jax.jit(lambda *a: K._gated_norm_xla(
+            *a, 1e-6))(o, gate, w))
+        line = next(l for l in metrics_mod.prometheus_text().splitlines()
+                    if l.startswith("ray_tpu_kda_pass_path_total{")
+                    and 'pass="norm"' in l and f'seq="{S}"' in l)
+        assert f'path="{path}"' in line, line
+    metrics_mod._reset_for_tests()

@@ -340,6 +340,23 @@ class TestCatalog:
         telemetry.inc("ray_tpu_profiler_captures_total", 0.0)
 
 
+def test_kda_series_registered():
+    """The delta rule's two counters: what a call of the scan is, and which
+    path the passes before and after it took (``ops/kda.kda_mixer``,
+    ``gated_head_norm``; the kernels against ``jnp``:
+    ``tests/test_kda.py``)."""
+    specs = {
+        "ray_tpu_kda_call_geometry_total":
+            ("heads", "dk", "dv", "chunk", "rows", "seq", "path"),
+        "ray_tpu_kda_pass_path_total":
+            ("pass", "path", "heads", "d", "rows", "seq"),
+    }
+    for name, tags in specs.items():
+        assert telemetry.CATALOG[name]["type"] == "counter", name
+        assert tuple(telemetry.CATALOG[name]["tag_keys"]) == tags, name
+        assert telemetry.CATALOG[name]["description"].strip(), name
+
+
 def test_flash_geometry_counter_says_which_kernels_took_rows():
     """``ray_tpu_flash_step_geometry_total`` carries ``rows="vo"`` where a
     kernel took v and the result in the projections' layout (PR 49), on
@@ -562,8 +579,9 @@ class TestSmokeAllSubsystems:
                        mla_cfg, jax.random.key(0))["dense"]))
 
         # -- kda: a traced call of the chunked gated delta rule counts what
-        # it is (the jnp form here), and a model whose routers limit their
-        # choice to groups counts the groups (a forward of the tiny model).
+        # it is (the jnp form here), a KDA layer which path the passes round
+        # its scan take, and a model whose routers limit their choice to
+        # groups counts the groups (a forward of the tiny model).
         from ray_tpu.models import bailing_hybrid
         from ray_tpu.ops.kda import kda
         kx = jnp.ones((1, 16, 2, 8), jnp.float32)
@@ -682,6 +700,7 @@ class TestSmokeAllSubsystems:
                    if not any(s.startswith(f"ray_tpu_{sub}_")
                               for s in series)}
         assert not missing, f"no series for {missing}; got {sorted(series)}"
+        assert "ray_tpu_kda_pass_path_total" in series, sorted(series)
         assert len(series) >= 15, sorted(series)
 
         # Timeline carries engine-step and train-step profile spans.

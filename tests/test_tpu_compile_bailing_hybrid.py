@@ -34,8 +34,10 @@ def ling_step(topo):
 
 def test_ling_train_step_compiles_at_the_cell_sizes(ling_step, capsys):
     """The step compiles for one described v5e chip with the Mosaic kernels
-    in it: the delta rule's pair by name and inside the DEFAULT scoped VMEM
-    (no limit stated), latent attention's flash kernels at 192 / 128, the
+    in it: the delta rule's pair and the passes before and after it
+    (``kda_in_fwd`` / ``kda_in_bwd``, ``kda_norm_fwd`` / ``kda_norm_bwd``)
+    by name and inside the DEFAULT scoped VMEM (no limit stated), latent
+    attention's flash kernels at 192 / 128, the
     grouped products; the parameter count is the config file's and the
     issue's; the scopes the readers sum are in its text."""
     import re
@@ -59,9 +61,21 @@ def test_ling_train_step_compiles_at_the_cell_sizes(ling_step, capsys):
     assert 7.0e9 < mem.argument_size_in_bytes < 7.1e9
     calls = [line.strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    for kernel in ("kda_fwd_c128", "kda_bwd_c128", "flash_fwd_d192v128",
+    for kernel in ("kda_fwd_c128", "kda_bwd_c128", "kda_in_fwd", "kda_in_bwd",
+                   "kda_norm_fwd", "kda_norm_bwd", "flash_fwd_d192v128",
                    "flash_bwd_d192v128", "gmm", "tgmm", "rope_to_heads"):
         assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
+    # The pass between the projections and the scan, forward (and
+    # recomputed) and backward, runs under the convolution's scope (what
+    # ``kda_conv_roofline`` divides by), the one after the scan under the
+    # norm's.
+    named = scopes.op_names(text)
+    for kernel, scope in (("kda_in_fwd", "conv"), ("kda_in_bwd", "conv"),
+                          ("kda_norm_fwd", "norm"), ("kda_norm_bwd", "norm")):
+        under = [scopes.scope_path(op) for name, op in named.items()
+                 if name.startswith(kernel)]
+        assert under and all(f"block/attn/kda/{scope}" in u
+                             for u in under), (kernel, under[:3])
     for call in calls:
         if "kda_" in call.partition(" = ")[0]:
             assert '"scoped_memory_configs":[]' in call, call[:200]
