@@ -12,6 +12,7 @@ from .lfm2 import Lfm2Config, lfm2_tiny
 from .bailing_hybrid import BailingHybridConfig, bailing_hybrid_tiny
 from .motif import MotifConfig, motif_tiny
 from .mimo_v2 import MimoV2Config, mimo_v2_tiny
+from .granite_hybrid import GraniteHybridConfig, granite_hybrid_tiny
 from .mlp import MLPConfig, init_mlp, mlp_forward, mlp_loss
 
 __all__ = [
@@ -23,5 +24,6 @@ __all__ = [
     "BailingHybridConfig", "bailing_hybrid_tiny",
     "MotifConfig", "motif_tiny",
     "MimoV2Config", "mimo_v2_tiny",
+    "GraniteHybridConfig", "granite_hybrid_tiny",
     "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss",
 ]

@@ -13,7 +13,8 @@ What it has that no other model here has:
   ``S_t = exp(dt A) S_{t-1} + dt X_t (x) B_t``, ``y_t = S_t C_t + D X_t`` in
   chunks of ``chunk_size`` with the state handed on between them; a gate and
   an RMSNorm over ``ssm_groups`` groups of channels; one out-projection.
-  Every row starts from a zero state.
+  Every row starts from a zero state (``_mixer`` takes ``segment_ids`` for
+  the model that packs documents into a row: ``models/granite_hybrid.py``).
 - **Un-gated experts**: ``relu(x W_up)^2 W_down``, the shared one alike (the
   expert layer is ``models/afmoe.py``'s ``_moe`` on ``ops/moe.py``, as it
   is: sigmoid router, dropless held experts, the selection bias as state; a
@@ -233,9 +234,15 @@ def init_state(cfg: NemotronHConfig) -> Dict[str, jax.Array]:
 
 # ------------------------------------------------------------------ layers
 
-def _mixer(cfg: NemotronHConfig, x, layer):
+def _mixer(cfg, x, layer, segment_ids=None):
     """F of a Mamba-2 layer on the normed stream x [B, S, E] -> (out
-    [B, S, E], the mean share of a state a chunk hands on)."""
+    [B, S, E], the mean share of a state a chunk hands on).  ``cfg``: any
+    configuration with the mixer's sizes (``mamba_dim``, ``conv_dim``,
+    ``mamba_heads``, ``mamba_head_dim``, ``ssm_groups``, ``ssm_state``,
+    ``chunk_size``, ``norm_eps``, ``dtype``): ``models/granite_hybrid.py``
+    runs this body too.  ``segment_ids`` [B, S]: a packed row's documents,
+    at whose starts the convolution and the state start anew; the second
+    result is then ``ops/ssm.chunk_carry``'s pair."""
     dt_ = cfg.dtype
     B, S, _ = x.shape
     d, H, P = cfg.mamba_dim, cfg.mamba_heads, cfg.mamba_head_dim
@@ -244,15 +251,16 @@ def _mixer(cfg: NemotronHConfig, x, layer):
         zcd = jnp.einsum("bse,ef->bsf", x, layer["w_in"].astype(dt_),
                          preferred_element_type=dt_)
     z, c, delta = jnp.split(zcd, (d, d + cfg.conv_dim), axis=-1)
-    c = ssm.causal_conv(c, layer["conv_w"], layer["conv_b"])
+    c = ssm.causal_conv(c, layer["conv_w"], layer["conv_b"], segment_ids)
     X, Bm, Cm = jnp.split(c, (d, d + G * N), axis=-1)
     with jax.named_scope("block/ssm/scan"):
         step = jax.nn.softplus(delta.astype(jnp.float32)
                                + layer["dt_bias"].astype(jnp.float32))
         A = -jnp.exp(layer["A_log"].astype(jnp.float32))
-        carry = ssm.chunk_carry(step, A, cfg.chunk_size)
+        carry = ssm.chunk_carry(step, A, cfg.chunk_size, segment_ids)
     y = ssm.ssd_scan(X.reshape(B, S, H, P), step, A, Bm.reshape(B, S, G, N),
-                     Cm.reshape(B, S, G, N), layer["D"], cfg.chunk_size)
+                     Cm.reshape(B, S, G, N), layer["D"], cfg.chunk_size,
+                     segment_ids=segment_ids)
     v = ssm.gated_group_norm(y.reshape(B, S, d), z, layer["gate_norm"], G,
                              cfg.norm_eps)
     with jax.named_scope("block/ssm/proj"):

@@ -187,6 +187,19 @@ taken off delta once): ``exp(b_h - lse_t)`` is the share of the row's mass
 the sink took, which a model reports.  With ``sink=None`` a call traces what
 it traced.
 
+``segment_ids`` ([B, S] integers: a PACKED row, several documents end to
+end, a run of equal ids a document; PR 65) keeps a query to the keys of its
+own document, under the causal mask: the causal square with no window, sink,
+offset or parts.  The ids reach the three kernels as rows along the lanes,
+the mask is the triangle's and ``same document`` in one ``where``, and a
+pair of blocks whose documents cannot meet computes and fetches nothing (two
+more scalar-prefetch operands say which; the note on documents above
+``_documents``).  Such a call is a ``custom_vjp`` of its own
+(``_flash_seg``), its kernels are named ``flash_seg_fwd_d64`` and so on, so
+that a reader that counts the whole triangle by a kernel's name never counts
+them, and the geometry counter tags them so.  With ``segment_ids=None`` a
+call traces what it traced: a row is one document.
+
 Design provenance (patterns, not code): the reference delegates attention to
 engines (SURVEY §2.4 SP/CP row — no in-repo kernel); the block/layout recipe
 follows jax.experimental.pallas.ops.tpu.flash_attention (LSE lane broadcast,
@@ -216,7 +229,7 @@ LANES = 128
 def reference_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         q_offset: int = 0, window: Optional[int] = None,
-                        sink=None, lse: bool = False):
+                        sink=None, lse: bool = False, segment_ids=None):
     """Plain-jnp attention. q: [B, H, Sq, D]; k: [B, Hkv, Sk, D];
     v: [B, Hkv, Sk, Dv] (``Dv`` may differ from ``D``; the default scale is
     ``D ** -0.5``).  Returns [B, H, Sq, Dv].
@@ -227,7 +240,9 @@ def reference_attention(q, k, v, *, causal: bool = True,
     ``sink`` [H] float32: one more column of every row of head ``h``, of
     score ``sink[h]`` (no scale) and value zero, so it takes mass and adds
     nothing.  ``lse``: also return the rows' log-sum-exp [B, H, Sq] float32,
-    the sink's column in it.
+    the sink's column in it.  ``segment_ids`` [B, S] (with ``causal``, Sq ==
+    Sk): a query sees the keys of its own document only, a document a run
+    of equal ids.
     """
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -244,7 +259,11 @@ def reference_attention(q, k, v, *, causal: bool = True,
         mask = qpos[:, None] >= kpos[None, :]
         if window is not None:
             mask &= qpos[:, None] - kpos[None, :] < window
-        scores = jnp.where(mask[None, None], scores, NEG_INF)
+        mask = mask[None, None]
+        if segment_ids is not None:
+            doc = _documents(segment_ids)
+            mask = mask & (doc[:, None, :, None] == doc[:, None, None, :])
+        scores = jnp.where(mask, scores, NEG_INF)
     if sink is not None:
         scores = jnp.concatenate([scores, jnp.broadcast_to(
             sink.astype(jnp.float32)[None, :, None, None],
@@ -341,11 +360,12 @@ def _step_ki(step):
 
 
 def _causal_mask_bias(q_rows, k_rows, qi, ki, block_q, block_k, q_offset,
-                      window=None, transposed=False):
+                      window=None, transposed=False, same=None):
     """0.0 where a key is visible and MASK_VALUE elsewhere, for one pair of
     blocks: [q_rows, k_rows], or [k_rows, q_rows] when ``transposed``.
     ``q_rows`` may hold several heads' ``block_q`` rows one after another:
-    they share the positions."""
+    they share the positions.  ``same``: a bool of that shape, where query
+    and key are of one document (a call with ``segment_ids``)."""
     shape = (k_rows, q_rows) if transposed else (q_rows, k_rows)
     q_in = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
     if q_rows != block_q:
@@ -360,7 +380,85 @@ def _causal_mask_bias(q_rows, k_rows, qi, ki, block_q, block_k, q_offset,
         # running max there; the first visible score then scales that
         # tile's sums by exp(MASK_VALUE - max) = 0.
         visible &= row - col < window
+    if same is not None:
+        # In ONE ``where`` with the triangle: two MASK_VALUEs added up are
+        # -inf, and a row of them a softmax of NaNs.
+        visible &= same
     return jnp.where(visible, 0.0, MASK_VALUE)
+
+
+# ------------------------------------------------------------- documents
+#
+# A call with ``segment_ids`` (a packed row: several documents end to end):
+# a query sees the keys of its own document only, under the causal mask.
+# The ids are made ``documents`` (``ops/ssm.py``: a run of equal ids a
+# document, counted from 0, so they never fall along a row) and reach the
+# three kernels as ONE float32 array [B, 1, S] through two blocks, rows
+# along the lanes as the LSE's are: the streamed side's ids are read as that
+# row, the resident side's are turned into lane-broadcast columns in VMEM
+# scratch at the resident block's first step (as dq turns the LSE), and the
+# mask is the triangle's and ``column == row`` in one ``where``.  Which
+# pairs of blocks hold a visible element is data, and the grid is static:
+# a second scalar-prefetch operand, ``meet`` [B * steps] int32, says of each
+# step of the causal table whether the k block's last document is at least
+# the q block's first (ids never fall, so then and only then they share
+# one), and a step that does not meet runs nothing.  Nor does it fetch: a
+# third, ``fetch`` [B * steps], names the streamed side's block of the last
+# step that met (its own, where it meets), and the streamed operands' index
+# maps read it, so the pipeline finds the block it holds and starts no copy
+# (a skipped step of dk/dv cost 1.7 us with its four heads' q and dO
+# fetched, the forward's 0.5; PERF.md, PR 65).  A q block's diagonal block
+# always meets, so every row has seen itself when its block is written.
+# One tile a grid step.  The kernels carry names of their own
+# (``flash_seg_fwd_d64``).
+
+def _documents(segment_ids):
+    from .ssm import documents
+    return documents(segment_ids)
+
+
+def _meet(doc, sched, block_q, block_k, streamed):
+    """(``meet``, ``fetch``) of a causal table (``block_schedule``'s rows)
+    over blocks of ``block_q`` x ``block_k`` whose ``streamed`` side (``QI``
+    or ``KI``) changes from step to step: int32 [B * steps] each."""
+    first_q, last_k = doc[:, ::block_q], doc[:, block_k - 1::block_k]
+    meet = last_k[:, sched[KI]] >= first_q[:, sched[QI]]
+    # the last step that met, up to each one (step 0 before any has)
+    last = jax.lax.cummax(jnp.where(meet, jnp.arange(sched.shape[1]), 0),
+                          axis=1)
+    return (meet.astype(jnp.int32).reshape(-1),
+            jnp.asarray(sched[streamed])[last].reshape(-1))
+
+
+def _seg_refs(refs, seg):
+    """A kernel's refs of a call with documents, ``seg = (where the ids'
+    blocks stand among them, grid rows a batch element)``: (whether the
+    step's blocks meet, the q block's ids [1, 1, bq], the k block's, the
+    resident side's as columns (scratch, the last), the refs a call without
+    documents hands over)."""
+    from jax.experimental import pallas as pl
+    at, per_b = seg
+    meet_ref, _fetch_ref, *refs, col_scr = refs
+    meets = meet_ref[pl.program_id(0) // per_b * pl.num_programs(1)
+                     + pl.program_id(1)] != 0
+    return (meets, refs[at], refs[at + 1], col_scr,
+            [*refs[:at], *refs[at + 2:]])
+
+
+def _runs(step, meets=None):
+    """Whether a grid step computes: the table says so, and in a call with
+    documents its blocks meet."""
+    runs = step & _RUN_BIT != 0
+    return runs if meets is None else runs & meets
+
+
+def _id_columns(row_ref, col_scr, heads=1):
+    """The resident block's ids [1, 1, rows] as lane-broadcast columns
+    [heads * rows, 128], the heads' rows one after another."""
+    rows = row_ref.shape[-1]
+    col = jnp.broadcast_to(row_ref[0], (LANES, rows)).T
+    for h in range(heads):
+        col_scr[h * rows:(h + 1) * rows, :] = col
 
 
 # --------------------------------------------------------------- geometry
@@ -620,13 +718,17 @@ def _operands(q, k, v):
     return {"q": q, "k": k, "v": v}
 
 
-def _geometry(kind, dims, block_q, block_k, window, rows, sink=False):
+def _geometry(kind, dims, block_q, block_k, window, rows, sink=False,
+              seg=False):
     """``_tiles``' answer for this call, an explicit block size winning (and
-    walked one tile a grid step), checked against the lengths and counted."""
+    walked one tile a grid step), checked against the lengths and counted.
+    ``seg``: a call with documents, one tile a grid step."""
     _, H, Hkv, Sq, Sk, D, Dv, Dr = dims
     t = _tiles(kind, Sq, Sk, max(D, Dv), H // Hkv, window, Dr)
     if t is None:               # ``bwd``: this call keeps the pair
         return None
+    if seg:
+        t = t._replace(tiles=1)
     if block_q or block_k:      # the blocks a call names, one a grid step
         t = t._replace(block_q=min(block_q or t.block_q, Sq),
                        block_k=min(block_k or t.block_k, Sk), tiles=1)
@@ -638,7 +740,8 @@ def _geometry(kind, dims, block_q, block_k, window, rows, sink=False):
     # the grid rows whose dk / dv are summed into a key head's outside
     shares = H // Hkv // t.heads if kind in ("dkv", "bwd") else 1
     telemetry.inc("ray_tpu_flash_step_geometry_total", tags={
-        "kernel": _kernel_name(f"flash_{kind}", window, D, Dv, sink),
+        "kernel": _kernel_name(f"flash_{'seg_' if seg else ''}{kind}",
+                               window, D, Dv, sink),
         "block_q": str(t.block_q), "block_k": str(t.block_k),
         "heads_a_step": str(t.heads), "scores": t.scores,
         **({"tiles_a_step": str(t.tiles)} if t.tiles > 1 else {}),
@@ -768,10 +871,13 @@ def _tile_index(i, j, tiles):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False, parts=False, tiles=1, sink=False):
+                window=None, rows=False, parts=False, tiles=1, sink=False,
+                seg=None):
     # lse_ref is None when the caller doesn't need residuals (inference).
     from jax.experimental import pallas as pl
 
+    if seg:
+        meets, idq_ref, idk_ref, idcol_scr, refs = _seg_refs(refs, seg)
     *_, m_scr, l_scr, acc_scr = refs
     heads = m_scr.shape[0] // block_q
     *ins, o_ref, lse_ref = refs[:-3]
@@ -798,6 +904,8 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
             m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
             l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        if seg:
+            _id_columns(idq_ref, idcol_scr, heads)
 
     def tile(j):
         q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
@@ -808,7 +916,8 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         if causal:
             s = s + _causal_mask_bias(
                 s.shape[0], block_k, qi, _tile_index(ki, j, tiles), block_q,
-                block_k, q_offset, window)
+                block_k, q_offset, window, same=_bcast_lanes(
+                    idcol_scr[...], block_k) == idk_ref[0] if seg else None)
         m_prev = m_scr[...]                            # [heads * bq, 128]
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -822,7 +931,7 @@ def _fwd_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         acc_scr[...] = acc_scr[...] * _bcast_lanes(alpha, acc_scr.shape[1]) \
             + pv
 
-    @pl.when(step & _RUN_BIT != 0)
+    @pl.when(_runs(step, meets if seg else None))
     def _step():
         _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
               "qk")
@@ -859,7 +968,7 @@ def _kernel_name(base, window, D=None, Dv=None, sink=False):
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
                    interpret, *, need_lse, window=None, rows=False,
-                   sink=None):
+                   sink=None, doc=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -868,7 +977,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     with_sink = sink is not None
-    t = _geometry("fwd", dims, block_q, block_k, window, rows, with_sink)
+    seg = doc is not None
+    t = _geometry("fwd", dims, block_q, block_k, window, rows, with_sink,
+                  seg)
     sched = _packed_schedule(Sq, Sk, *t.major, q_offset, causal, "q", window)
     n, stacked = B * H // t.heads, t.heads * t.block_q
 
@@ -881,15 +992,24 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
         block_k=t.block_k, q_offset=q_offset, window=window, rows=rows,
         **({"parts": True} if dims.Dr else {}),
         **({"tiles": t.tiles} if t.tiles > 1 else {}),
-        **({"sink": True} if with_sink else {}))
+        **({"sink": True} if with_sink else {}),
+        **({"seg": (len(operands), H // t.heads)} if seg else {}))
     in_specs = [getattr(sp, name) for name in operands]
     handed = [x.reshape(sp.shapes[name]) for name, x in operands.items()]
+    prefetch, scratch = [sched], []
+    if seg:
+        ids = doc.astype(jnp.float32).reshape(B, 1, Sq)
+        in_specs += [sp.ids_q, sp.ids_k]
+        handed += [ids, ids]
+        prefetch += _meet(doc, block_schedule(
+            Sq, Sk, *t.major, q_offset, causal, "q", window), *t.major, KI)
+        scratch.append(_vmem((stacked, LANES), jnp.float32))
     if with_sink:
         # A head's sink along the lanes, a grid row's heads a block: 512 B
         # a head in HBM, and the kernel reads a [1, 128] row as it reads
         # the backward's LSE.
         in_specs.append(pl.BlockSpec((1, t.heads, 1, LANES),
-                                     lambda r, s, sched: (r, 0, 0, 0)))
+                                     lambda r, s, sched, *_: (r, 0, 0, 0)))
         handed.append(jnp.broadcast_to(
             sink.astype(jnp.float32)[None, :, None, None],
             (B, H, 1, LANES)).reshape(n, t.heads, 1, LANES))
@@ -902,7 +1022,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
             jax.ShapeDtypeStruct((n, t.heads, 1, Sq), jnp.float32))
     else:
         # No LSE output at all: nothing of it is computed or written.
-        with_lse, n_io = kernel, len(handed) + 1
+        with_lse, n_io = kernel, len(handed) + len(prefetch)
 
         def kernel(sched, *refs):
             return with_lse(sched, *refs[:n_io], None, *refs[n_io:])
@@ -910,7 +1030,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
     res = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(n, sched.size),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -918,12 +1038,13 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, q_offset,
                 _vmem((stacked, LANES), jnp.float32),
                 _vmem((stacked, LANES), jnp.float32),
                 _vmem((stacked, Dv), jnp.float32),
-            ]),
+                *scratch]),
         out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, D, Dv, with_sink),
+        name=_kernel_name("flash_seg_fwd" if seg else "flash_fwd", window, D,
+                          Dv, with_sink),
         **_compiler_params(interpret, stacked, t.block_k),
-    )(sched, *handed)
+    )(*prefetch, *handed)
     out = res[0].reshape((B, Sq, H, Dv) if rows else (B, H, Sq, Dv))
     if not need_lse:
         return out, None
@@ -956,6 +1077,9 @@ class Specs(NamedTuple):
     # The one pass: q's gradient leaves the K-major walk a tile a k block.
     dq: object = None       # the q tile at the resident k block's index
     dq_r: object = None     # the same of q_r's gradient
+    # A call with documents: the ids [B, 1, S], rows along the lanes.
+    ids_q: object = None    # the q block's
+    ids_k: object = None    # the k block's
 
 
 def _specs(t, dims, rows=False):
@@ -981,17 +1105,33 @@ def _specs(t, dims, rows=False):
     # row's resident block's).
     block_q, block_k = t.major
 
-    def of_q(d, as_rows, block_q=block_q, at=_step_qi):
+    def block_of(side):
+        """The index map's block of the ``q`` or the ``k`` side at a step:
+        the table's; of the STREAMED side of a call with documents (two
+        more scalar-prefetch operands, ``_meet``'s), the block of the last
+        step that met."""
+        plain = _step_qi if side == "q" else _step_ki
+        streamed = (side == "k") == (t.scores == "qk")
+
+        def block(r, s, sched, *docs):
+            if docs and streamed:
+                return docs[1][r // per_b * sched.shape[0] + s]
+            return plain(sched[s])
+        return block
+
+    q_block, k_block = block_of("q"), block_of("k")
+
+    def of_q(d, as_rows, block_q=block_q, at=q_block):
         """(spec, shape) of a q-side operand of head size ``d``; the one
         pass's dq is a tile of it ``at`` the resident k block's index."""
         if as_rows:
             return (pl.BlockSpec(
-                (1, block_q, t.heads * d), lambda r, s, sched: (
-                    r // per_b, at(sched[s]), r % per_b)),
+                (1, block_q, t.heads * d), lambda r, s, sched, *docs: (
+                    r // per_b, at(r, s, sched, *docs), r % per_b)),
                 (B, Sq, H * d))
         return (pl.BlockSpec(
-            (1, t.heads, block_q, d), lambda r, s, sched: (
-                r, 0, at(sched[s]), 0)),
+            (1, t.heads, block_q, d), lambda r, s, sched, *docs: (
+                r, 0, at(r, s, sched, *docs), 0)),
             (n, t.heads, Sq, d))
 
     def of_k(d, as_rows, own=False):
@@ -1002,12 +1142,13 @@ def _specs(t, dims, rows=False):
         head = (lambda r: r) if own else (lambda r: r * t.heads // group)
         if as_rows:
             return (pl.BlockSpec(
-                (1, block_k, d), lambda r, s, sched: (
-                    head(r) // heads, _step_ki(sched[s]), head(r) % heads)),
+                (1, block_k, d), lambda r, s, sched, *docs: (
+                    head(r) // heads, k_block(r, s, sched, *docs),
+                    head(r) % heads)),
                 (B, Sk, heads * d))
         return (pl.BlockSpec(
-            (1, block_k, d), lambda r, s, sched: (
-                head(r), _step_ki(sched[s]), 0)),
+            (1, block_k, d), lambda r, s, sched, *docs: (
+                head(r), k_block(r, s, sched, *docs), 0)),
             (B * heads, Sk, d))
 
     if Dr:
@@ -1016,19 +1157,23 @@ def _specs(t, dims, rows=False):
                 "dkv": of_k(D - Dr + Dv, True, own=True),
                 "dk_r": of_k(Dr, False, own=True),
                 "k_r": (pl.BlockSpec(
-                    (1, block_k, Dr), lambda r, s, sched: (
+                    (1, block_k, Dr), lambda r, s, sched, *_: (
                         r // per_b, _step_ki(sched[s]), 0)), (B, Sk, Dr)),
-                "dq": of_q(D - Dr, True, t.block_q, _step_ki),
-                "dq_r": of_q(Dr, False, t.block_q, _step_ki)}
+                "dq": of_q(D - Dr, True, t.block_q, k_block),
+                "dq_r": of_q(Dr, False, t.block_q, k_block)}
     else:
         made = {"q": of_q(D, False), "o": of_q(Dv, rows),
                 "k": of_k(D, False), "v": of_k(Dv, rows),
                 "dk": of_k(D, False, own=True),
                 "dv": of_k(Dv, rows, own=True),
-                "dq": of_q(D, False, t.block_q, _step_ki)}
+                "dq": of_q(D, False, t.block_q, k_block)}
     return Specs(
-        row=pl.BlockSpec((1, t.heads, 1, block_q), lambda r, s, sched: (
-            r, 0, 0, _step_qi(sched[s]))),
+        ids_q=pl.BlockSpec((1, 1, block_q), lambda r, s, sched, *docs: (
+            r // per_b, 0, q_block(r, s, sched, *docs))),
+        ids_k=pl.BlockSpec((1, 1, block_k), lambda r, s, sched, *docs: (
+            r // per_b, 0, k_block(r, s, sched, *docs))),
+        row=pl.BlockSpec((1, t.heads, 1, block_q), lambda r, s, sched, *docs: (
+            r, 0, 0, q_block(r, s, sched, *docs))),
         shapes={name: shape for name, (_, shape) in made.items()},
         **{name: spec for name, (spec, _) in made.items()})
 
@@ -1053,13 +1198,15 @@ def _compiler_params(interpret, rows, cols):
 # ---------------------------------------------------------------- backward
 
 def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-               window=None, rows=False, parts=False, tiles=1):
+               window=None, rows=False, parts=False, tiles=1, seg=None):
     """``lse`` and ``di`` arrive as rows along the lanes (as dk/dv reads
     them); the resident q block's first step turns them into the
     lane-broadcast columns [heads * bq, 128] the steps subtract.  In parts,
     dq leaves in q's two."""
     from jax.experimental import pallas as pl
 
+    if seg:
+        meets, idq_ref, idk_ref, idcol_scr, refs = _seg_refs(refs, seg)
     if parts:
         (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
          dq_ref, dqr_ref, dq_scr, dqr_scr, lse_scr, di_scr) = refs
@@ -1082,6 +1229,8 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
             for row_ref, col_scr in ((lse_ref, lse_scr), (di_ref, di_scr)):
                 col_scr[of_h, :] = jnp.broadcast_to(
                     row_ref[0, h], (LANES, block_q)).T
+        if seg:
+            _id_columns(idq_ref, idcol_scr, heads)
 
     def tile(j):
         q = _rows(q_ref, heads, parts)                 # [heads * bq, D]
@@ -1094,7 +1243,8 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         if causal:
             s = s + _causal_mask_bias(
                 s.shape[0], block_k, qi, _tile_index(ki, j, tiles), block_q,
-                block_k, q_offset, window)
+                block_k, q_offset, window, same=_bcast_lanes(
+                    idcol_scr[...], block_k) == idk_ref[0] if seg else None)
         p = jnp.exp(s - _bcast_lanes(lse_scr[...], s.shape[1]))
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -1106,7 +1256,7 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
             dqr_scr[...] += jax.lax.dot(ds.astype(k.dtype), k_r,
                                         preferred_element_type=jnp.float32)
 
-    @pl.when(step & _RUN_BIT != 0)
+    @pl.when(_runs(step, meets if seg else None))
     def _step():
         _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
               "qk")
@@ -1119,7 +1269,7 @@ def _dq_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
 
 
 def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
-                window=None, rows=False, parts=False, tiles=1):
+                window=None, rows=False, parts=False, tiles=1, seg=None):
     """The scores are formed transposed, ``k q^T`` [bk, bq], so that dv =
     p^T do and dk = ds^T q are plain products; ``lse`` and ``di`` are rows
     along the lanes.  The step's heads add into the one resident dk / dv.
@@ -1141,6 +1291,8 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
     calls it so)."""
     from jax.experimental import pallas as pl
 
+    if seg:
+        meets, idq_ref, idk_ref, idcol_scr, refs = _seg_refs(refs, seg)
     if parts:
         (q_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, di_ref,
          dkv_ref, dkr_ref, *dq, dk_scr, dkr_scr, dv_scr) = refs
@@ -1171,6 +1323,8 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
         if parts:
             dkr_scr[...] = jnp.zeros(dkr_scr.shape, jnp.float32)
+        if seg:
+            _id_columns(idk_ref, idcol_scr)
 
     def tile(j):
         k = k_ref[0]                                   # [bk, D]
@@ -1178,7 +1332,9 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         if causal:
             bias = _causal_mask_bias(
                 block_q, block_k, _tile_index(qi, j, tiles), ki, block_q,
-                block_k, q_offset, window, transposed=True)     # [bk, bq]
+                block_k, q_offset, window, transposed=True,     # [bk, bq]
+                same=_bcast_lanes(idcol_scr[...], block_q) == idq_ref[0]
+                if seg else None)
         dk = dk_scr[...]
         dv = dv_scr[...]
         k_r, dk_r = (kr_ref[0], dkr_scr[...]) if parts else (None, None)
@@ -1216,7 +1372,7 @@ def _dkv_kernel(sched_ref, *refs, causal, scale, block_q, block_k, q_offset,
         if parts:
             dkr_scr[...] = dk_r
 
-    @pl.when(step & _RUN_BIT != 0)
+    @pl.when(_runs(step, meets if seg else None))
     def _step():
         _walk(tile, tiles, qi, ki, block_q, block_k, q_offset, causal,
               "kq")
@@ -1251,9 +1407,11 @@ def _delta(out, dout, rows):
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
-                    q_offset, interpret, window=None, rows=False, di=None):
+                    q_offset, interpret, window=None, rows=False, di=None,
+                    doc=None):
     """``di``: the rows' delta where the caller has formed it already (a
-    call with a sink, whose LSE is a result with a cotangent of its own)."""
+    call with a sink, whose LSE is a result with a cotangent of its own).
+    ``doc``: a call with documents (``documents`` of its ids)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1264,6 +1422,8 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
 
     if di is None:
         di = _delta(out, dout, rows)
+    seg = doc is not None
+    ids = doc.astype(jnp.float32).reshape(B, 1, Sq) if seg else None
 
     def call(kind, kernel, t, major, outs, scratch, tile):
         """One backward kernel at its geometry ``t``, its results the
@@ -1280,32 +1440,46 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
                      for name, dtype in outs.items()]
         if len(outs) == 1:      # one result, not a list of one, as before
             out_specs, out_shape = out_specs[0], out_shape[0]
+        with_docs = {}
+        if seg:
+            # The resident side's ids as columns: q's heads stacked, or k's.
+            resident = t.heads * t.block_q if major == "q" else t.block_k
+            with_docs = dict(
+                kernel={"seg": (len(operands) + 3, H // t.heads)},
+                specs=[sp.ids_q, sp.ids_k], handed=[ids, ids],
+                prefetch=_meet(doc, block_schedule(
+                    Sq, Sk, *t.major, q_offset, causal, major, window),
+                    *t.major, KI if major == "q" else QI),
+                scratch=[_vmem((resident, LANES), jnp.float32)])
         return pl.pallas_call(
             functools.partial(kernel, causal=causal, scale=scale,
                               block_q=t.block_q, block_k=t.block_k,
                               q_offset=q_offset, window=window, rows=rows,
                               **({"parts": True} if Dr else {}),
-                              **({"tiles": t.tiles} if t.tiles > 1 else {})),
+                              **({"tiles": t.tiles} if t.tiles > 1 else {}),
+                              **with_docs.get("kernel", {})),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=1 + 2 * seg,
                 grid=(n, sched.size),
                 in_specs=[*(getattr(sp, name) for name in operands),
-                          sp.o, sp.row, sp.row],
+                          sp.o, sp.row, sp.row, *with_docs.get("specs", [])],
                 out_specs=out_specs,
-                scratch_shapes=scratch),
+                scratch_shapes=[*scratch, *with_docs.get("scratch", [])]),
             out_shape=out_shape,
             interpret=interpret,
-            name=_kernel_name(f"flash_{kind}", window, D, Dv),
+            name=_kernel_name(f"flash_{'seg_' if seg else ''}{kind}", window,
+                              D, Dv),
             **_compiler_params(interpret, *tile),
-        )(sched, *(x.reshape(sp.shapes[name])
-                   for name, x in operands.items()),
+        )(sched, *with_docs.get("prefetch", []),
+          *(x.reshape(sp.shapes[name]) for name, x in operands.items()),
           dout.reshape(sp.shapes["o"]),
-          lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq))
+          lse.reshape(n, t.heads, 1, Sq), di.reshape(n, t.heads, 1, Sq),
+          *with_docs.get("handed", []))
 
     # ---- The one pass, where the shapes admit it (``_tiles``) and the call
     # is the causal square: the K-major walk below makes dq too, in a
     # scratch that holds its grid row's, and no dq kernel is called.
-    t = (_geometry("bwd", dims, block_q, block_k, window, rows)
+    t = (_geometry("bwd", dims, block_q, block_k, window, rows, seg=seg)
          if causal and not q_offset else None)
     kind, dq_outs, dq_scratch = "bwd", {}, []
     if t is not None:
@@ -1320,7 +1494,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
         tile = (0, 0)
     else:
         # ---- dq: Q block resident, K/V blocks stream (the forward's walk).
-        t = _geometry("dq", dims, block_q, block_k, window, rows)
+        t = _geometry("dq", dims, block_q, block_k, window, rows, seg=seg)
         stacked = t.heads * t.block_q
         dq = call(
             "dq", _dq_kernel, t, "q",
@@ -1330,7 +1504,7 @@ def _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q, block_k,
              _vmem((stacked, LANES), jnp.float32)],
             (stacked, t.block_k))
         kind, t = "dkv", _geometry("dkv", dims, block_q, block_k, window,
-                                   rows)
+                                   rows, seg=seg)
         tile = (t.block_q, t.block_k)
 
     # ---- dk/dv: K/V block resident, Q blocks stream (K-major walk), the
@@ -1415,6 +1589,35 @@ def _flash_bwd(causal, scale, block_q, block_k, q_offset, interpret, window,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# A call with documents is a function of its own too: causal, the square,
+# no window and no sink, the ids an operand with no gradient.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_seg(q, k, v, segment_ids, scale, block_q, block_k, interpret,
+               rows):
+    return _flash_forward(q, k, v, True, scale, block_q, block_k, 0,
+                          interpret, need_lse=False, rows=rows,
+                          doc=_documents(segment_ids))[0]
+
+
+def _flash_seg_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret,
+                   rows):
+    out, lse = _flash_forward(q, k, v, True, scale, block_q, block_k, 0,
+                              interpret, need_lse=True, rows=rows,
+                              doc=_documents(segment_ids))
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+    return out, (q, k, v, segment_ids, out, lse)
+
+
+def _flash_seg_bwd(scale, block_q, block_k, interpret, rows, res, dout):
+    q, k, v, segment_ids, out, lse = res
+    return (*_flash_backward(q, k, v, out, lse, dout, True, scale, block_q,
+                             block_k, 0, interpret, None, rows,
+                             doc=_documents(segment_ids)), None)
+
+
+_flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
+
+
 # A call with a sink is a function of its own, so that a call without one
 # traces what it traced: (result, LSE), the LSE a result like the other
 # because the sink's share of a row's mass is exp(b_h - lse) and a model
@@ -1490,7 +1693,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, q_offset: int = 0,
                     interpret: bool = False, window: Optional[int] = None,
-                    rows: bool = False, sink=None, lse: bool = False):
+                    rows: bool = False, sink=None, lse: bool = False,
+                    segment_ids=None):
     """Pallas flash attention (fwd + bwd kernels) with custom VJP.
 
     q: [B, H, Sq, D]; k: [B, Hkv, Sk, D], head-major as
@@ -1536,7 +1740,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
     them from what they read (``_flash_sink_bwd``).  With ``lse`` the call
     returns (result, the rows' log-sum-exp [B, H, Sq] float32), itself
     differentiable: ``exp(b_h - lse)`` is the mass the sink took.  With
-    ``sink=None`` a call traces what it traced."""
+    ``sink=None`` a call traces what it traced.
+
+    ``segment_ids`` [B, S] integers (a packed row; the causal square with no
+    window, sink or parts): a query sees the keys of its own document only,
+    a document a run of equal ids; blocks whose documents cannot meet are
+    not computed (the module's note on documents), and the kernels are
+    named ``flash_seg_*``.  With None a call traces what it traced."""
+    if segment_ids is not None:
+        if (not causal or q_offset or window is not None or sink is not None
+                or _in_parts(q) or q.shape[2] != k.shape[2]
+                or segment_ids.shape != (q.shape[0], q.shape[2])):
+            raise NotImplementedError(
+                "segment_ids [B, S] go with the causal square, one part, no "
+                "window, sink or offset (ROADMAP M6)")
     if sink is None and lse:
         raise ValueError("the log-sum-exp is handed out for a call with a "
                          "sink only")
@@ -1552,10 +1769,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
             functools.partial(flash_attention, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
                               q_offset=q_offset, interpret=interpret,
-                              window=window,
+                              window=window, segment_ids=segment_ids,
                               **({} if sink is None
                                  else {"sink": sink, "lse": lse})),
             q, k, v, rows)
+    if segment_ids is not None:
+        return _flash_seg(q, k, v, segment_ids, scale, block_q, block_k,
+                          interpret, bool(rows))
     if sink is None:
         return _flash(q, k, v, causal, scale, block_q, block_k, q_offset,
                       interpret, window, bool(rows))
@@ -1576,9 +1796,10 @@ def _on_tpu() -> bool:
 def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
               impl: Optional[str] = None, mesh=None,
               window: Optional[int] = None, rows: bool = False,
-              sink=None, lse: bool = False):
+              sink=None, lse: bool = False, segment_ids=None):
     """Dispatching entry point: pallas flash on TPU, reference elsewhere.
-    ``sink`` and ``lse`` are ``flash_attention``'s (one device only).
+    ``sink`` and ``lse`` are ``flash_attention``'s (one device only), and
+    so is ``segment_ids`` (a packed row's documents).
     ``rows`` is ``flash_attention``'s: v and the result lie as
     [B, S, H, D]; so are q and k in parts, which only the kernels on one
     device take as they are (the reference and a mesh's island get the
@@ -1596,6 +1817,12 @@ def attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,
         if impl == "reference" or island:
             q, k, v = _one_part(q, k, v)
     with_sink = {} if sink is None else {"sink": sink, "lse": lse}
+    if segment_ids is not None:
+        if island:
+            raise NotImplementedError(
+                "segment_ids on a mesh: the island's specs know no [B, S] "
+                "operand (ROADMAP M6)")
+        with_sink = {**with_sink, "segment_ids": segment_ids}
     if impl == "reference":
         return _head_major(
             functools.partial(reference_attention, causal=causal,

@@ -1,13 +1,15 @@
 """State-space layers (Mamba-2, the SSD form of arXiv:2405.21060): a causal
 depthwise convolution, the chunked scan of a recurrence that carries a state
 from chunk to chunk, and the gated RMSNorm over groups of channels.  What
-``models/nemotron_h.py``'s mixer runs.  Beside the mixer's convolution
+``models/nemotron_h.py``'s mixer runs, and ``models/granite_hybrid.py``'s
+through it.  Beside the mixer's convolution
 (``causal_conv``: a bias and a silu) lives LFM2's, which shares its taps
 (``gated_short_conv``: two gates, three operands, no bias and no silu; what
 ``models/lfm2.py``'s ``conv`` layers run).
 
 The recurrence, a head ``h`` of ``P`` channels on a state of ``P x N`` (in
-float32, the state zero at the start of every row)::
+float32, the state zero at the start of every row, and of every DOCUMENT of a
+packed row where a call says ``segment_ids``: below)::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t X_t (x) B_t
     y_t = S_t C_t + D X_t
@@ -56,8 +58,29 @@ times the bytes: by XLA's own count for a described v5e, a row of 8,192
 forward and backward, the convolution 4.23 -> 0.81 GB and the norm 3.06 ->
 1.12 GB (PERF.md, PR 43).
 
+**A row is one document, or several end to end** (``segment_ids`` [B, S],
+a run of equal ids a document; ``documents``).  The convolution's taps read
+zero on another document's token, forward and in the written-out backward
+(``causal_conv``).  The scan takes ``_DROP`` off the decay's exponent of a
+document's first token: ``exp`` of it is zero in float32, so the token
+decays the state before it to nothing, which is the recurrence with ``S_{t-1}
+= 0`` there, and in the chunked forms the one term does everything a boundary
+asks for, in ``_scan_xla`` and in the kernels as they are, forward and
+backward (``ssd_scan``).  ``chunk_carry`` then averages over the chunks that
+hold no boundary and counts the others.  Without the argument every call
+traces the program it traced.
+
+**A group wider than a grid step should hold** (Granite-4.0-H's 64 heads of
+64 on ONE B and C: 4,096 channels) goes through the kernels in slices of its
+heads, grid rows of their own that read the same B and C block (``_slices``,
+``_laid_out``); dB and dC leave a slice's share in float32 and are summed
+outside.  Nemotron's eight heads a group are one slice: the program they
+were.  On the chip at [1, 32768, 64, 64] the pair reads 2.00 + 5.66 ms a
+call at a chunk of 256 (2.65 + 4.80 at 128), with ids and without alike
+(PERF.md, PR 65).
+
 Which path a scan took is counted in ``ray_tpu_ssm_path_total`` (``kernel``
-or ``xla``).
+or ``xla``; ``segments`` yes / no; the channels of a state group).
 """
 
 from __future__ import annotations
@@ -72,7 +95,24 @@ from ..util import telemetry
 F32 = jnp.float32
 
 
-def _conv_taps(c, w, b=None):
+def documents(segment_ids):
+    """A row's documents counted from 0, int32 [B, S]: a document is a run
+    of equal ids, so a token whose id is not its predecessor's starts one.
+    What the ops here, and ``ops.attention``, make of a batch's
+    ``segment_ids``: ids that never fall along a row."""
+    return jnp.cumsum(_starts(segment_ids), axis=1, dtype=jnp.int32)
+
+
+def _same(doc, d: int):
+    """bool [B, S, 1]: token t - d lies in the row and in token t's document
+    (``d`` negative: a token after t)."""
+    S = doc.shape[1]
+    shifted = jnp.pad(doc, ((0, 0), (max(d, 0), max(-d, 0))),
+                      constant_values=-1)
+    return (shifted[:, max(-d, 0):max(-d, 0) + S] == doc)[..., None]
+
+
+def _conv_taps(c, w, b=None, doc=None):
     """The convolution before its silu, float32 [B, S, Ch], and the padded
     input its taps read (in c's dtype: a float32 copy of it would be written
     and read four times).  ``b`` None: no bias.  ``c`` a tuple of arrays:
@@ -80,7 +120,8 @@ def _conv_taps(c, w, b=None):
     multiplied in float32 tap by tap (``gated_short_conv``: the TPU compiler
     then reads each operand where it lies, and no product is ever written:
     a row of 8,192 at 2,048 channels forward, 134 MB for 403 with the
-    product padded, by its own count for a described v5e; PERF.md, PR 47)."""
+    product padded, by its own count for a described v5e; PERF.md, PR 47).
+    ``doc`` (``documents``): a tap on another document's token reads zero."""
     several = isinstance(c, tuple)
     cs = c if several else (c,)
     K, S = w.shape[0], cs[0].shape[1]
@@ -90,15 +131,17 @@ def _conv_taps(c, w, b=None):
         x = padded[0][:, j:j + S].astype(F32)
         for p in padded[1:]:
             x = x * p[:, j:j + S].astype(F32)
+        if doc is not None and j < K - 1:       # (tap K - 1 reads t itself)
+            x = jnp.where(_same(doc, K - 1 - j), x, 0.0)
         acc = acc + x * w[j].astype(F32)
     return acc, (padded if several else padded[0])
 
 
-def _ahead(ga, w):
+def _ahead(ga, w, doc=None):
     """A convolution's taps read the other way: token t's cotangent ``ga``
     (float32 [B, S, Ch], or a tuple of arrays whose product it is, as
     ``_conv_taps`` takes them) reaches the inputs of tokens t - (K - 1) ..
-    t."""
+    t, those of its document (``doc``)."""
     gs = ga if isinstance(ga, tuple) else (ga,)
     K, S = w.shape[0], gs[0].shape[1]
     ahead = tuple(jnp.pad(x, ((0, 0), (0, K - 1), (0, 0))) for x in gs)
@@ -107,40 +150,49 @@ def _ahead(ga, w):
         x = ahead[0][:, K - 1 - j:K - 1 - j + S]
         for p in ahead[1:]:
             x = x.astype(F32) * p[:, K - 1 - j:K - 1 - j + S].astype(F32)
+        if doc is not None and j < K - 1:
+            x = jnp.where(_same(doc, j - (K - 1)), x, 0.0)
         out = out + x * w[j].astype(F32)
     return out
 
 
 @jax.custom_vjp
-def causal_conv(c, w, b):
+def causal_conv(c, w, b, segment_ids=None):
     """``silu(b + sum_j w[j] * c[t - (K - 1) + j])`` with ``c[s] = 0`` before
     the row's start: c [B, S, Ch], w [K, Ch] (tap K - 1 reads the token
     itself), b [Ch] -> [B, S, Ch] in c's dtype.  Depthwise: a channel reads
-    its own past and nothing else; a row reads nothing of another row.
+    its own past and nothing else; a row reads nothing of another row, and
+    with ``segment_ids`` [B, S] (``documents``) a token nothing of another
+    document: ``c[s] = 0`` before its document's start.
 
     The backward is written out (the taps read the other way, the weight's
     gradient K reductions over the same shifted reads): what JAX derives
     moves 2.6 times the bytes by XLA's own count for a described v5e."""
+    doc = None if segment_ids is None else documents(segment_ids)
     with jax.named_scope("block/ssm/conv"):
-        return jax.nn.silu(_conv_taps(c, w, b)[0]).astype(c.dtype)
+        return jax.nn.silu(_conv_taps(c, w, b, doc)[0]).astype(c.dtype)
 
 
-def _conv_fwd(c, w, b):
-    return causal_conv(c, w, b), (c, w, b)
+def _conv_fwd(c, w, b, segment_ids=None):
+    return causal_conv(c, w, b, segment_ids), (c, w, b, segment_ids)
 
 
 def _conv_bwd(res, g):
-    c, w, b = res
+    c, w, b, segment_ids = res
     K, S = w.shape[0], c.shape[1]
+    doc = None if segment_ids is None else documents(segment_ids)
     with jax.named_scope("block/ssm/conv"):
-        acc, padded = _conv_taps(c, w, b)
+        acc, padded = _conv_taps(c, w, b, doc)
         sig = jax.nn.sigmoid(acc)
         ga = g.astype(F32) * sig * (1.0 + acc * (1.0 - sig))
-        dc = _ahead(ga, w)
-        dw = jnp.stack([jnp.sum(padded[:, j:j + S].astype(F32) * ga,
-                                axis=(0, 1)) for j in range(K)])
+        dc = _ahead(ga, w, doc)
+        read = lambda j: padded[:, j:j + S].astype(F32) \
+            if doc is None or j == K - 1 else jnp.where(
+                _same(doc, K - 1 - j), padded[:, j:j + S].astype(F32), 0.0)
+        dw = jnp.stack([jnp.sum(read(j) * ga, axis=(0, 1))
+                        for j in range(K)])
         return (dc.astype(c.dtype), dw.astype(w.dtype),
-                jnp.sum(ga, axis=(0, 1)).astype(b.dtype))
+                jnp.sum(ga, axis=(0, 1)).astype(b.dtype), None)
 
 
 causal_conv.defvjp(_conv_fwd, _conv_bwd)
@@ -365,16 +417,48 @@ def gated_short_conv(B, C, u, w, *, impl=None):
     return _gconv(B, C, u, w, impl)
 
 
-def chunk_carry(dt, A, chunk: int):
+def chunk_carry(dt, A, chunk: int, segment_ids=None):
     """Mean over rows, chunks and heads of ``exp(l_Q)``, the share of a state
     that a whole chunk hands on: dt [B, S, H] float32, A [H].  Only whole
-    chunks count.  No gradient."""
+    chunks count.  No gradient.  With ``segment_ids`` [B, S]: (the mean over
+    the chunks in which no document starts, which alone hand a state on;
+    the number of chunks in which one does, float32)."""
     B, S, H = dt.shape
     n = S // chunk
     if not n:
-        return jnp.ones((), F32)
+        one = jnp.ones((), F32)
+        return one if segment_ids is None else (one, jnp.zeros((), F32))
     a = dt[:, :n * chunk].astype(F32).reshape(B, n, chunk, H) * A.astype(F32)
-    return jax.lax.stop_gradient(jnp.mean(jnp.exp(jnp.sum(a, axis=2))))
+    share = jnp.exp(jnp.sum(a, axis=2))                     # [B, n, H]
+    if segment_ids is None:
+        return jax.lax.stop_gradient(jnp.mean(share))
+    cut = jnp.any(_starts(segment_ids)[:, :n * chunk].reshape(B, n, chunk),
+                  axis=2)
+    whole = jnp.sum(~cut)
+    mean = jnp.sum(jnp.where(cut[..., None], 0.0, share)) / jnp.maximum(
+        whole * H, 1)
+    return jax.lax.stop_gradient(
+        (jnp.where(whole > 0, mean, 1.0).astype(F32),
+         jnp.sum(cut).astype(F32)))
+
+
+#: What a document's first token adds to the exponent of its decay: ``exp``
+#: of it is zero in float32 (the least it holds is ``exp(-103.3)``), so the
+#: token decays the state before it, and every product that spans it, to
+#: nothing, in the chunked forms as in the recurrence.  Every exponent the
+#: scan forms is a difference of running sums that is never positive, so one
+#: boundary between two tokens is enough, and two add up.  The running sums
+#: are float32: n boundaries inside a chunk cost the decays of its last
+#: documents ``n * 128 * 2 ** -24`` of absolute precision in the exponent
+#: (6e-5 at 8), under the products' bfloat16 operands by two orders.
+_DROP = 128.0
+
+
+def _starts(segment_ids):
+    """bool [B, S]: whether a token starts a document inside its row (its id
+    is not its predecessor's); the row's first does not."""
+    ids = segment_ids
+    return jnp.pad(ids[:, 1:] != ids[:, :-1], ((0, 0), (1, 0)))
 
 
 def _refuse_a_mesh() -> None:
@@ -386,17 +470,22 @@ def _refuse_a_mesh() -> None:
             "split over sp handing its state on, are not built (ROADMAP M8)")
 
 
-def _scan_xla(X, dt, A, B, C, D, Q: int):
+def _scan_xla(X, dt, A, B, C, D, Q: int, drop=None):
     """The chunked form in ``jnp`` over whole chunks: X [Bt, S, H, P] with
-    ``Q`` dividing S -> y float32 of X's shape."""
+    ``Q`` dividing S -> y float32 of X's shape.  ``drop`` [Bt, S]: what a
+    token takes off its decay's exponent (``_DROP`` where a document
+    starts)."""
     Bt, S, H, P = X.shape
     G, N = B.shape[2:]
     R, nc, dtype = H // G, S // Q, X.dtype
     Xc = X.reshape(Bt, nc, Q, G, R, P)
     Bc, Cc = B.reshape(Bt, nc, Q, G, N), C.reshape(Bt, nc, Q, G, N)
     dtc = dt.reshape(Bt, nc, Q, H)
+    a = dtc * A.astype(F32)
+    if drop is not None:
+        a = a - drop.reshape(Bt, nc, Q, 1)
     # [Bt, nc, H, Q]: the running sum of dt A inside a chunk, and dt.
-    l = jnp.moveaxis(jnp.cumsum(dtc * A.astype(F32), axis=2), 2, 3)
+    l = jnp.moveaxis(jnp.cumsum(a, axis=2), 2, 3)
     dth = jnp.moveaxis(dtc, 2, 3)
     total = l[..., -1]                                   # [Bt, nc, H]
 
@@ -437,7 +526,11 @@ def _scan_xla(X, dt, A, B, C, D, Q: int):
 # ------------------------------------------------------- the Pallas kernels
 #
 # A grid step is one chunk of one group of one row: the group's ``R`` heads
-# (R P channels along the lanes) on the one B and C they share.  The chunks
+# (R P channels along the lanes) on the one B and C they share; of a group
+# wider than ``_STEP_LANES`` channels (Granite-4.0-H's one group of 64 heads,
+# 4,096 channels) a slice of its heads, the slices grid rows of their own
+# that read the same B and C block, form ``C . B`` each and hand out each
+# its share of dB and dC in float32, summed outside (``_laid_out``).  The chunks
 # of a row run in order (``arbitrary``) and hand the group's state on in
 # VMEM scratch, [N, R P] float32; the backward walks them the other way and
 # hands the state's cotangent back.  The decayed masks [Q, Q] a head, the
@@ -623,15 +716,15 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, lcol_ref, dtcol_ref, lrow_ref,
 
 
 def _ssd_call(kernel, name, dims, reverse, extra_in, outs, interpret):
-    """A kernel over the grid (row, group, chunk): the eight arrays every
-    kernel reads first (``_laid_out``), then ``extra_in`` / ``outs`` as
-    (spec, array or shape) pairs."""
+    """A kernel over the grid (row, group or slice of one, chunk): the eight
+    arrays every kernel reads first (``_laid_out``), then ``extra_in`` /
+    ``outs`` as (spec, array or shape) pairs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    Bt, G, nc, Q, R, P, N = dims
+    Bt, G, nc, Q, R, P, N, slices = dims
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
-    tokens = lambda w: pl.BlockSpec((None, Q, w),
-                                    lambda b, g, c: (b, at(c), g))
+    tokens = lambda w, of=(lambda g: g): pl.BlockSpec(
+        (None, Q, w), lambda b, g, c: (b, at(c), of(g)))
     col = pl.BlockSpec((None, None, Q, R), lambda b, g, c: (b, g, at(c), 0))
     row = pl.BlockSpec((None, None, R, Q), lambda b, g, c: (b, g, 0, at(c)))
     lanes = pl.BlockSpec((None, 1, R * P), lambda b, g, c: (g, 0, 0))
@@ -639,8 +732,13 @@ def _ssd_call(kernel, name, dims, reverse, extra_in, outs, interpret):
                          lambda b, g, c: (b, g, at(c), 0, 0))
     part = pl.BlockSpec((None, None, None, 1, R * P),
                         lambda b, g, c: (b, g, at(c), 0, 0))
-    specs = {"tokens": tokens(R * P), "bc": tokens(N), "col": col,
-             "row": row, "lanes": lanes, "state": state, "part": part}
+    # B and C are their group's, whatever slice of it the grid row is; a
+    # row's share of their gradients is its own.
+    specs = {"tokens": tokens(R * P), "share": tokens(N),
+             "bc": tokens(N) if slices == 1 else tokens(
+                 N, lambda g: g // slices),
+             "col": col, "row": row, "lanes": lanes, "state": state,
+             "part": part}
     first = ["tokens", "bc", "bc", "col", "col", "row", "row", "lanes"]
     return pl.pallas_call(
         functools.partial(kernel, R=R, P=P), grid=(Bt, G, nc),
@@ -667,25 +765,50 @@ def _running_sum(a, Q: int, layout: str, back: bool = False):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _laid_out(X, dt, A, B, C, D, Q):
+#: The channels of a group that a grid step holds at the most: Nemotron-3-
+#: Nano's whole group, 8 heads of 64.  (At a chunk of 256 a step of 512
+#: channels holds 2.4 MiB of blocks and scratch beside the [Q, Q] float32
+#: mask a head; a group of 4,096 whole would hold 2 MB an array.)
+_STEP_LANES = 512
+
+
+def _slices(R: int, P: int) -> int:
+    """The slices a group of ``R`` heads of ``P`` channels goes through the
+    kernels in: the fewest whose heads fill whole lane tiles inside
+    ``_STEP_LANES`` channels; 1 where the group fits or nothing divides."""
+    from .attention import LANES
+    per = _heads_a_tile(P)[0]
+    return next((n for n in range(1, R + 1)
+                 if R % n == 0 and (R // n) % per == 0
+                 and (R // n * P) % LANES == 0
+                 and R // n * P <= _STEP_LANES), 1)
+
+
+def _laid_out(X, dt, A, B, C, D, Q, drop=None):
     """(dims, the eight arrays a kernel reads first) from the scan's
-    arguments over whole chunks."""
+    arguments over whole chunks.  ``dims`` counts a wide group's slices as
+    groups (``G`` of them, of ``R`` heads each) and says how many of them
+    share a B and C."""
     Bt, S, H, P = X.shape
-    G, N = B.shape[2:]
+    groups, N = B.shape[2:]
+    slices = _slices(H // groups, P)
+    G = groups * slices
     R = H // G
     a = dt * A.astype(F32)
+    if drop is not None:
+        a = a - drop[..., None]
     col = lambda v: jnp.moveaxis(v.reshape(Bt, S, G, R), 1, 2)
     row = lambda v: v.reshape(Bt, G, R, S)
     lanes = jnp.repeat(D.astype(F32), P).reshape(G, 1, R * P)
-    return (Bt, G, S // Q, Q, R, P, N), (
-        X.reshape(Bt, S, H * P), B.reshape(Bt, S, G * N),
-        C.reshape(Bt, S, G * N), col(_running_sum(a, Q, "bcqh")), col(dt),
+    return (Bt, G, S // Q, Q, R, P, N, slices), (
+        X.reshape(Bt, S, H * P), B.reshape(Bt, S, groups * N),
+        C.reshape(Bt, S, groups * N), col(_running_sum(a, Q, "bcqh")), col(dt),
         row(_running_sum(a, Q, "bhcq")), row(jnp.swapaxes(dt, 1, 2)), lanes)
 
 
-def _kernel_forward(X, dt, A, B, C, D, Q, interpret, keep: bool):
-    dims, ins = _laid_out(X, dt, A, B, C, D, Q)
-    Bt, G, nc, _, R, P, N = dims
+def _kernel_forward(X, dt, A, B, C, D, drop, Q, interpret, keep: bool):
+    dims, ins = _laid_out(X, dt, A, B, C, D, Q, drop)
+    Bt, G, nc, _, R, P, N, _ = dims
     outs = [("tokens", jax.ShapeDtypeStruct(ins[0].shape, X.dtype))]
     if keep:
         outs.append(("state", jax.ShapeDtypeStruct((Bt, G, nc, N, R * P),
@@ -695,33 +818,40 @@ def _kernel_forward(X, dt, A, B, C, D, Q, interpret, keep: bool):
     return [out[0].reshape(X.shape)] + list(out[1:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _scan_kernels(X, dt, A, B, C, D, Q, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _scan_kernels(X, dt, A, B, C, D, drop, Q, interpret):
     with jax.named_scope("block/ssm/scan"):
-        return _kernel_forward(X, dt, A, B, C, D, Q, interpret, False)[0]
+        return _kernel_forward(X, dt, A, B, C, D, drop, Q, interpret,
+                               False)[0]
 
 
-def _scan_kernels_fwd(X, dt, A, B, C, D, Q, interpret):
+def _scan_kernels_fwd(X, dt, A, B, C, D, drop, Q, interpret):
     with jax.named_scope("block/ssm/scan"):
-        y, states = _kernel_forward(X, dt, A, B, C, D, Q, interpret, True)
-    return y, (X, dt, A, B, C, D, states)
+        y, states = _kernel_forward(X, dt, A, B, C, D, drop, Q, interpret,
+                                    True)
+    return y, (X, dt, A, B, C, D, drop, states)
 
 
 def _scan_kernels_bwd(Q, interpret, saved, dy):
-    X, dt, A, B, C, D, states = saved
+    X, dt, A, B, C, D, drop, states = saved
     with jax.named_scope("block/ssm/scan"):
-        dims, ins = _laid_out(X, dt, A, B, C, D, Q)
-        Bt, G, nc, _, R, P, N = dims
+        dims, ins = _laid_out(X, dt, A, B, C, D, Q, drop)
+        Bt, G, nc, _, R, P, N, slices = dims
         S, H = X.shape[1], X.shape[2]
         like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, F32)
+        # B's and C's gradients: a group's own, or a slice's share of them.
+        dbc = ("bc", like(ins[1])) if slices == 1 else (
+            "share", f32(Bt, S, G * N))
         dx, db, dc, dl, ddt, dd = _ssd_call(
             _ssd_bwd_kernel, f"ssd_bwd_q{Q}", dims, True,
             [("state", states), ("tokens", dy)],
-            [("tokens", like(ins[0])), ("bc", like(ins[1])),
-             ("bc", like(ins[2])), ("col", f32(Bt, G, S, R)),
+            [("tokens", like(ins[0])), dbc, dbc, ("col", f32(Bt, G, S, R)),
              ("col", f32(Bt, G, S, R)), ("part", f32(Bt, G, nc, 1, R * P))],
             interpret)(*ins, states, dy.reshape(ins[0].shape))
+        if slices > 1:
+            db, dc = (g.reshape(Bt, S, G // slices, slices, N).sum(axis=3)
+                      .astype(B.dtype) for g in (db, dc))
         by_token = lambda c: jnp.moveaxis(c, 1, 2).reshape(Bt, S, H)
         # l is the running sum of dt A inside a chunk: a token's dt A is in
         # the l of every token from it on there.
@@ -732,7 +862,7 @@ def _scan_kernels_bwd(Q, interpret, saved, dy):
                 jnp.sum(da * dt, axis=(0, 1)).astype(A.dtype),
                 db.reshape(B.shape), dc.reshape(C.shape),
                 jnp.sum(dd.reshape(Bt, G, nc, R, P), axis=(0, 2, 4)
-                        ).reshape(H).astype(D.dtype))
+                        ).reshape(H).astype(D.dtype), None)
 
 
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
@@ -749,14 +879,24 @@ def _kernels(X, B, Q: int, interpret: bool) -> bool:
                 and N % LANES == 0 and Q % LANES == 0)
 
 
-def ssd_scan(X, dt, A, B, C, D, chunk: int, *, interpret: bool = False):
+def ssd_scan(X, dt, A, B, C, D, chunk: int, *, segment_ids=None,
+             interpret: bool = False):
     """The recurrence above over every row, in chunks of ``chunk`` tokens.
 
     X [Bt, S, H, P]; dt [Bt, S, H] float32, positive (after the softplus);
     A [H] negative; B, C [Bt, S, G, N] with H a multiple of G (head h reads
     group ``h // (H / G)``); D [H].  Returns y [Bt, S, H, P] in X's dtype.
-    Every row starts from a zero state.  On a TPU (or with ``interpret``, for
-    the tests) and where the shapes tile (``_kernels``) the Pallas pair
+    Every row starts from a zero state, and with ``segment_ids`` [Bt, S]
+    every document of a row (a run of equal ids): a token whose predecessor
+    has another id decays the state before it by ``exp(-_DROP)``, zero in
+    float32, which is the recurrence with ``S_{t-1} = 0`` there.  In the
+    chunked forms that one term in the running sums does all four things a
+    boundary asks for: the decayed mask is zero between two documents'
+    tokens, a chunk's own state adds up its last document's tokens alone,
+    the state handed in reaches the tokens before the chunk's first
+    boundary alone, and is handed on iff the chunk holds none; forward and
+    backward, by the kernels as they are.  On a TPU (or with ``interpret``,
+    for the tests) and where the shapes tile (``_kernels``) the Pallas pair
     computes it, elsewhere ``jnp``."""
     _refuse_a_mesh()
     S, H = X.shape[1:3]
@@ -766,8 +906,12 @@ def ssd_scan(X, dt, A, B, C, D, chunk: int, *, interpret: bool = False):
     kernel = _kernels(X, B, chunk, interpret)
     telemetry.inc("ray_tpu_ssm_path_total",
                   tags={"path": "kernel" if kernel else "xla",
-                        "chunk": str(chunk)})
+                        "chunk": str(chunk),
+                        "segments": "no" if segment_ids is None else "yes",
+                        "group_channels": str(H // G * X.shape[3])})
     dt = dt.astype(F32)
+    drop = None if segment_ids is None else _DROP * _starts(
+        segment_ids).astype(F32)
     pad = -S % chunk
     if pad:
         # Tokens that keep the state (dt 0: decay 1, nothing added) and that
@@ -775,11 +919,12 @@ def ssd_scan(X, dt, A, B, C, D, chunk: int, *, interpret: bool = False):
         grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
                                  * (a.ndim - 2))
         X, dt, B, C = grow(X), grow(dt), grow(B), grow(C)
+        drop = None if drop is None else grow(drop)
     if kernel:
-        y = _scan_kernels(X, dt, A, B, C, D, chunk, interpret)
+        y = _scan_kernels(X, dt, A, B, C, D, drop, chunk, interpret)
     else:
         with jax.named_scope("block/ssm/scan"):
-            y = _scan_xla(X, dt, A, B, C, D, chunk)
+            y = _scan_xla(X, dt, A, B, C, D, chunk, drop)
     return y[:, :S].astype(X.dtype)
 
 

@@ -111,6 +111,13 @@ def make_lm_train_step(cfg, mesh, *, rules: Optional[ShardingRules] = None,
     ``moe_sliced_calls`` and the routers' choices ``moe_choices`` [expert
     layers, tokens, k]).
 
+    A batch is a dictionary of [B, S] arrays placed alike: ``tokens``, an
+    optional ``loss_mask``, and since PR 65 an optional ``segment_ids`` (a
+    packed row's documents, a run of equal ids one), which reaches the
+    model's loss with the rest of the batch; a model that knows the key
+    (models/granite_hybrid.py) stops its state, its convolution and its
+    attention at a document's start, the others never read it.
+
     ``param_dtype`` overrides parameter (and hence optimizer-state)
     storage: bfloat16 halves the adamw footprint so ~1.5B params fit one
     v5e chip with remat (HBM budget: params+m+v at 2 bytes each).  Under

@@ -196,6 +196,10 @@ _LOOP_KEYS = {"loop_loss": "ray_tpu_train_loop_loss",
               "hc_sinkhorn_residual": "ray_tpu_hc_sinkhorn_residual",
               # and of a state-space model's chunked scans
               "ssm_chunk_carry": "ray_tpu_ssm_chunk_carry",
+              "ssm_chunks_with_boundary": "ray_tpu_ssm_chunks_with_boundary",
+              # and of a batch of packed rows
+              "pack_documents_a_row": "ray_tpu_pack_documents_a_row",
+              "pack_pairs_share": "ray_tpu_pack_pairs_share",
               # and of a delta-rule model's
               "kda_chunk_carry": "ray_tpu_kda_chunk_carry",
               # and of a differential attention's pair

@@ -253,6 +253,25 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "a whole chunk hands on.  It moves if someone "
                        "changes the chunk, drops the carry or starts A_log "
                        "elsewhere."},
+    "ray_tpu_ssm_chunks_with_boundary": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Chunks of the last reported step's scans, over all "
+                       "mixers and rows, in which a document starts "
+                       "(a batch with segment_ids): they hand no state on, "
+                       "and ray_tpu_ssm_chunk_carry is the mean over the "
+                       "others."},
+    "ray_tpu_pack_documents_a_row": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Mean documents a row of the last reported step's "
+                       "batch (runs of equal segment_ids): how a packed "
+                       "row was filled."},
+    "ray_tpu_pack_pairs_share": {
+        "type": "gauge", "tag_keys": (),
+        "description": "Sum over the last reported step's documents of "
+                       "length squared, over rows x S squared: the share "
+                       "of the causal square that attention inside "
+                       "documents keeps (1: a row is one document), and so "
+                       "what skipping blocks between documents can save."},
     "ray_tpu_kda_chunk_carry": {
         "type": "gauge", "tag_keys": (),
         "description": "Mean over a hybrid model's Kimi-Delta-Attention "
@@ -469,7 +488,9 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "from the call's shapes (kernel: flash_fwd, and "
                        "flash_dq with flash_dkv, or flash_bwd alone "
                        "where the backward made one pass over the "
-                       "tiles; scores: qk or kq; "
+                       "tiles; flash_seg_fwd and so on of a call with "
+                       "segment_ids, a packed row whose queries see their "
+                       "own document's keys alone; scores: qk or kq; "
                        "tiles_a_step only where a grid step fetches a "
                        "major block of that many block_q x block_k tiles "
                        "of the streamed side and walks them: a head size "
@@ -601,11 +622,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "the stream take (kernel: Pallas, forward and "
                        "backward; xla: the jnp forms) and its lanes."},
     "ray_tpu_ssm_path_total": {
-        "type": "counter", "tag_keys": ("path", "chunk"),
+        "type": "counter",
+        "tag_keys": ("path", "chunk", "segments", "group_channels"),
         "description": "Chunked state-space scans traced "
                        "(ops/ssm.ssd_scan), by the path they take (kernel: "
                        "the Pallas pair, forward and backward; xla: the jnp "
-                       "form) and the chunk's tokens."},
+                       "form), the chunk's tokens, whether the call had "
+                       "segment ids (yes: a state starts anew at every "
+                       "document) and the channels of a state group (over "
+                       "512 the kernels take it in slices of its heads)."},
     "ray_tpu_gated_conv_path_total": {
         "type": "counter", "tag_keys": ("path", "taps"),
         "description": "Double-gated short convolutions traced "

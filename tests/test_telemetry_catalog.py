@@ -26,7 +26,7 @@ SUBSYSTEMS = ("serve", "llm", "train", "ckpt", "data", "node", "profiler",
               "internal", "autoscaler", "slice", "sched", "metricsview",
               "alerts", "store", "lock", "jax", "xla", "compile", "moe",
               "flash", "rope", "eva", "norm", "hc", "lm", "ssm", "gmm",
-              "gated", "mla", "kda", "gdla", "remat", "attn")
+              "gated", "mla", "kda", "gdla", "remat", "attn", "pack")
 
 
 class TestCatalog:
@@ -482,7 +482,11 @@ def _smoke_train_fn(config):
                       "gdla_lambda_mean": 0.5,
                       # sink: the share of a window row's softmax mass
                       # that the learned attention sink took
-                      "sink_mass_mean": 0.4})
+                      "sink_mass_mean": 0.4,
+                      # pack: how a batch of packed rows was filled, and
+                      # the chunks of its scans that a boundary cut
+                      "pack_documents_a_row": 13.0, "pack_pairs_share": 0.15,
+                      "ssm_chunks_with_boundary": 110.0})
 
 
 @serve.deployment(name="telemetry_echo")

@@ -150,10 +150,13 @@ def _while_bodies(comps):
     return out
 
 
-def _cell_step(topo, arch, config_file, seq, **replace):
+def _cell_step(topo, arch, config_file, seq, batch_keys=("tokens",
+                                                        "loss_mask"),
+               **replace):
     """A cell's train step as the benchmark builds it, compiled for one
     described v5e chip with the platform's choices made as on the chip:
-    {"compiled", "text", "params", "state", "config", "sizes"}."""
+    {"compiled", "text", "params", "state", "config", "sizes"}.
+    ``batch_keys``: the [rows, seq] int32 arrays of a batch."""
     import importlib
     import json
     import os
@@ -181,7 +184,7 @@ def _cell_step(topo, arch, config_file, seq, **replace):
                 cfg, mesh, learning_rate=1e-5, param_dtype=jnp.bfloat16)
             params, state = jax.eval_shape(init_fn, jax.random.key(0))
             batch = {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
-                     for k in ("tokens", "loss_mask")}
+                     for k in batch_keys}
             compiled = step_fn.lower(params, state, batch).compile()
         finally:
             set_global_mesh(before)
