@@ -250,9 +250,11 @@ def _mixer(cfg, x, layer, segment_ids=None):
     with jax.named_scope("block/ssm/proj"):
         zcd = jnp.einsum("bse,ef->bsf", x, layer["w_in"].astype(dt_),
                          preferred_element_type=dt_)
-    z, c, delta = jnp.split(zcd, (d, d + cfg.conv_dim), axis=-1)
-    c = ssm.causal_conv(c, layer["conv_w"], layer["conv_b"], segment_ids)
-    X, Bm, Cm = jnp.split(c, (d, d + G * N), axis=-1)
+    # The convolution reads its columns of ``zcd`` where they lie and writes
+    # X, B and C apart; the views below are bitcasts.
+    z, delta = zcd[..., :d], zcd[..., d + cfg.conv_dim:]
+    X, Bm, Cm = ssm.causal_conv(zcd, layer["conv_w"], layer["conv_b"],
+                                segment_ids, start=d, split=(d, G * N, G * N))
     with jax.named_scope("block/ssm/scan"):
         step = jax.nn.softplus(delta.astype(jnp.float32)
                                + layer["dt_bias"].astype(jnp.float32))

@@ -620,12 +620,15 @@ def _unit(x):
 
 
 def _inputs_xla(qkv, a, conv_w, A_log, dt_bias, H: int, bound: float):
-    """-> q, k, v [B, S, H, D] in qkv's dtype and g [B, S, H, D] float32."""
+    """-> q, k, v [B, S, H, D] in qkv's dtype and g [B, S, H, D] float32.
+    The definition: its convolution is ``ssm.causal_conv``'s ``jnp`` form
+    whatever the shapes (``impl="xla"``), never that op's own kernels."""
     from . import ssm
     B, S, _ = a.shape
     with jax.named_scope("kda/conv"):
         qkv = ssm.causal_conv(qkv, conv_w,
-                              jnp.zeros(conv_w.shape[1:], conv_w.dtype))
+                              jnp.zeros(conv_w.shape[1:], conv_w.dtype),
+                              impl="xla")
     with jax.named_scope("kda/gate"):
         q, k, v = (c.reshape(B, S, H, -1) for c in jnp.split(qkv, 3, axis=-1))
         q, k = _unit(q), _unit(k)

@@ -50,13 +50,28 @@ path; on the chip its masks and partial results cross HBM at HBM pace: 2.4
 ms a row of 8,192 forward and 6.1 forward and backward, against the
 kernels' 1.0 and 2.7 (PERF.md, PR 43).
 
-The convolutions and the gated norm are elementwise passes over 6,144,
-2,048 (LFM2's) and 4,096 channels a token, bound by HBM; each has its
-backward written out
-(``jax.custom_vjp``, still ``jnp``), because what JAX derives moves several
-times the bytes: by XLA's own count for a described v5e, a row of 8,192
-forward and backward, the convolution 4.23 -> 0.81 GB and the norm 3.06 ->
-1.12 GB (PERF.md, PR 43).
+The convolutions and the gated norm are elementwise passes over 6,144 (or
+Granite's 4,352), 2,048 (LFM2's) and 4,096 channels a token; each has its
+backward written out (``jax.custom_vjp``), because what JAX derives moves
+several times the bytes: by XLA's own count for a described v5e, a row of
+8,192 forward and backward, the convolution 4.23 -> 0.81 GB and the norm
+3.06 -> 1.12 GB (PERF.md, PR 43).  The norm is still ``jnp``.  Both
+convolutions are a Pallas pair on a TPU where the shapes tile (LFM2's since
+PR 47, the mixer's since PR 66) and ``jnp`` elsewhere, which is their
+definition.  In ``jnp`` the mixer's taps are slices of packed rows a token
+off the tile, a packed row's documents three ``where`` a tap pass, and the
+backward's dw and db five column sums that XLA runs as a pass of their own:
+on the chip, one row of 32,768 tokens by 4,352 channels with 24 documents'
+ids, 2.21 ms forward and 8.01 backward (32 % and 13 % of HBM pace by the
+array read and written once, and read twice and written once; the sums
+alone 4.46).  The pair (``ssm_conv_fwd`` / ``ssm_conv_bwd``, ``causal_conv``)
+reads 1.07 and 2.04 there (63 % and 51 %), 1.05 and 1.92 without ids, and
+0.37 and 0.72 for 0.72 and 2.69 at Nemotron's [1, 8192, 6144]: its forward
+equal to ``jnp``'s to the last bit, and no copy between the projection, the
+pair and the scan (``jnp``'s result was cut out of the projection's and cut
+into X, B and C by copies of their own, 31 ms of Granite's step).  What is
+left is the vector units' work, not HBM's: without its silu the forward
+reads 12 % less (PERF.md, PR 66).
 
 **A row is one document, or several end to end** (``segment_ids`` [B, S],
 a run of equal ids a document; ``documents``).  The convolution's taps read
@@ -156,46 +171,27 @@ def _ahead(ga, w, doc=None):
     return out
 
 
-@jax.custom_vjp
-def causal_conv(c, w, b, segment_ids=None):
-    """``silu(b + sum_j w[j] * c[t - (K - 1) + j])`` with ``c[s] = 0`` before
-    the row's start: c [B, S, Ch], w [K, Ch] (tap K - 1 reads the token
-    itself), b [Ch] -> [B, S, Ch] in c's dtype.  Depthwise: a channel reads
-    its own past and nothing else; a row reads nothing of another row, and
-    with ``segment_ids`` [B, S] (``documents``) a token nothing of another
-    document: ``c[s] = 0`` before its document's start.
-
-    The backward is written out (the taps read the other way, the weight's
-    gradient K reductions over the same shifted reads): what JAX derives
-    moves 2.6 times the bytes by XLA's own count for a described v5e."""
+def _conv_xla(c, w, b, segment_ids):
     doc = None if segment_ids is None else documents(segment_ids)
-    with jax.named_scope("block/ssm/conv"):
-        return jax.nn.silu(_conv_taps(c, w, b, doc)[0]).astype(c.dtype)
+    return jax.nn.silu(_conv_taps(c, w, b, doc)[0]).astype(c.dtype)
 
 
-def _conv_fwd(c, w, b, segment_ids=None):
-    return causal_conv(c, w, b, segment_ids), (c, w, b, segment_ids)
-
-
-def _conv_bwd(res, g):
-    c, w, b, segment_ids = res
+def _conv_xla_bwd(c, w, b, segment_ids, g):
+    """(dc, dw float32 [K, Ch], db float32 [Ch]), written out: the taps read
+    the other way, the weight's gradient K reductions over the same shifted
+    reads (what JAX derives moves 2.6 times the bytes by XLA's own count for
+    a described v5e)."""
     K, S = w.shape[0], c.shape[1]
     doc = None if segment_ids is None else documents(segment_ids)
-    with jax.named_scope("block/ssm/conv"):
-        acc, padded = _conv_taps(c, w, b, doc)
-        sig = jax.nn.sigmoid(acc)
-        ga = g.astype(F32) * sig * (1.0 + acc * (1.0 - sig))
-        dc = _ahead(ga, w, doc)
-        read = lambda j: padded[:, j:j + S].astype(F32) \
-            if doc is None or j == K - 1 else jnp.where(
-                _same(doc, K - 1 - j), padded[:, j:j + S].astype(F32), 0.0)
-        dw = jnp.stack([jnp.sum(read(j) * ga, axis=(0, 1))
-                        for j in range(K)])
-        return (dc.astype(c.dtype), dw.astype(w.dtype),
-                jnp.sum(ga, axis=(0, 1)).astype(b.dtype), None)
-
-
-causal_conv.defvjp(_conv_fwd, _conv_bwd)
+    acc, padded = _conv_taps(c, w, b, doc)
+    sig = jax.nn.sigmoid(acc)
+    ga = g.astype(F32) * sig * (1.0 + acc * (1.0 - sig))
+    dc = _ahead(ga, w, doc)
+    read = lambda j: padded[:, j:j + S].astype(F32) \
+        if doc is None or j == K - 1 else jnp.where(
+            _same(doc, K - 1 - j), padded[:, j:j + S].astype(F32), 0.0)
+    dw = jnp.stack([jnp.sum(read(j) * ga, axis=(0, 1)) for j in range(K)])
+    return dc.astype(c.dtype), dw, jnp.sum(ga, axis=(0, 1))
 
 
 # LFM2's double-gated short convolution.  In ``jnp`` (``_gconv_xla``) the
@@ -415,6 +411,379 @@ def gated_short_conv(B, C, u, w, *, impl=None):
         "path": "xla" if impl == "xla" else "kernel",
         "taps": str(w.shape[0])})
     return _gconv(B, C, u, w, impl)
+
+
+# The mixer's convolution (``causal_conv``) as a Pallas pair, ``_gc_*``'s form
+# with a bias, a silu and documents.  A grid step is a tile of tokens by a
+# column of channels, the columns of a tile innermost, so that what a tile's
+# tokens say of their documents is fetched once a tile.  A tile is worked
+# through in chunks of ``_CC_CHUNK`` numbers (a loop: what a chunk makes
+# stays in registers, where a whole tile's arrays each went to VMEM and
+# back); a tap is a sublane rotation of the chunk behind the 8 rows before
+# it, which ride the loop and, from tile to tile, VMEM scratch a column
+# (zeros at a row's start).  The backward walks a row's tiles, and a tile's
+# chunks, LAST TO FIRST as ``ops/kda._in_bwd_kernel`` does: the taps read the
+# other way take the first rows of the chunk after's cotangent from the loop
+# (or the scratch), the rows before a tile come from HBM (a block of
+# ``_GC_HEAD`` rows), and dw and db of every column stay in VMEM (one block,
+# the whole array) until the last step.  With ids ONE int32 a token
+# (``_since_start``) carries every tap's mask, and a flag a chunk rides in by
+# scalar prefetch: a chunk in which no document starts runs without a
+# select, as a call without ids does everywhere.
+
+def _since_start(segment_ids, K: int):
+    """int32 [B, S]: how many tokens of its document, and of its row, lie
+    before a token, clipped at K - 1.  A tap ``d`` tokens back reads its
+    token iff this is at least ``d``: documents are runs, so the one number
+    a token carries every tap's mask, forward and backward."""
+    S = segment_ids.shape[1]
+    start = _starts(segment_ids).at[:, 0].set(True)
+    pos = jnp.full(segment_ids.shape, K - 1, jnp.int32)
+    for d in range(K - 2, -1, -1):      # (the nearest start wins)
+        pos = jnp.where(jnp.pad(start, ((0, 0), (d, 0)))[:, :S], d, pos)
+    return pos
+
+
+def _cc_taps(prev, x, w, bias, keep):
+    """The convolution's sum before its silu on a chunk: x [n, d] float32
+    behind ``prev``, the 8 rows before it; w [K, d] float32 (tap K - 1 reads
+    the token itself), ``bias`` [1, d] -> (the sum, added up bias first and
+    then oldest tap first as ``_conv_taps`` does, and the chunk seen K - 1
+    .. 1 tokens back, oldest first).  ``keep`` (``_cc_keeps``): a tap on
+    another document's token reads zero, in the sum and in the list."""
+    from jax.experimental.pallas import tpu as pltpu
+    K = w.shape[0]
+    ext = jnp.concatenate([prev, x], axis=0)
+    back = [pltpu.roll(ext, K - 1 - j, 0)[8:] for j in range(K - 1)]
+    if keep is not None:
+        back = [jnp.where(m, sh, 0.0) for m, sh in zip(keep, back)]
+    acc = bias
+    for j, sh in enumerate(back):  # ray-tpu: noqa[RT506]
+        acc = acc + sh * w[j]
+    return acc + x * w[K - 1], back
+
+
+def _cc_keeps(pos_ref, r0, n: int, lanes: int, K: int):
+    """The masks [n, lanes] of a chunk's taps, oldest first: ``_since_start``
+    of its tokens (along 128 lanes in ``pos_ref``) at least the tap's
+    distance."""
+    from jax.experimental import pallas as pl
+    from .attention import LANES
+    pos = pos_ref[pl.ds(r0, n), :]
+    pos = jnp.concatenate([pos] * (lanes // LANES), axis=1)
+    return [pos >= K - 1 - j for j in range(K - 1)]
+
+
+def _cc_fwd_kernel(*refs, ids: bool, sub: int):
+    """``tail`` [columns, 8, lanes]: a column's last rows, from tile to
+    tile."""
+    from jax.experimental import pallas as pl
+    cut_ref, refs = (refs[0], refs[1:]) if ids else (None, refs)
+    x_ref, w_ref, b_ref, *pos_refs, o_ref, tail = refs
+    r, s, c = (pl.program_id(i) for i in range(3))
+    K, (rows, lanes) = w_ref.shape[1], x_ref.shape
+    n = rows // sub
+
+    @pl.when(s == 0)
+    def _row_start():
+        tail[c] = jnp.zeros(tail.shape[1:], F32)
+
+    w, bias = w_ref[c], b_ref[c]
+
+    def chunk(i, prev):
+        r0 = pl.multiple_of(i * sub, sub)
+        x = x_ref[pl.ds(r0, sub), :].astype(F32)
+
+        def run(keep):
+            acc, _ = _cc_taps(prev, x, w, bias, keep)
+            o_ref[pl.ds(r0, sub), :] = jax.nn.silu(acc).astype(o_ref.dtype)
+
+        if ids:
+            jax.lax.cond(
+                cut_ref[r, s * n + i] != 0,
+                lambda: run(_cc_keeps(pos_refs[0], r0, sub, lanes, K)),
+                lambda: run(None))
+        else:
+            run(None)
+        return x[sub - 8:]
+
+    tail[c] = jax.lax.fori_loop(0, n, chunk, tail[c])
+
+
+def _cc_bwd_kernel(*refs, ids: bool, sub: int):
+    """``after`` [columns, K - 1, 8, lanes] carries, a tap, the first rows
+    of the cotangent of the convolution's sum of the chunk after, masked as
+    that chunk's tokens ask (they are the ones the taps read the other way
+    reach)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    cut_ref, refs = (refs[0], refs[1:]) if ids else (None, refs)
+    (x_ref, xb_ref, g_ref, w_ref, b_ref, *pos_refs, dx_ref, dw_ref, db_ref,
+     after) = refs
+    r, s, c = (pl.program_id(i) for i in range(3))
+    K, (rows, lanes) = w_ref.shape[1], x_ref.shape
+    n = rows // sub
+    tile = pl.num_programs(1) - 1 - s
+
+    @pl.when(s == 0)
+    def _row_end():
+        after[c] = jnp.zeros(after.shape[1:], F32)
+
+    @pl.when((r == 0) & (s == 0))
+    def _first_tile():
+        dw_ref[c] = jnp.zeros(dw_ref.shape[1:], F32)
+        db_ref[c] = jnp.zeros(db_ref.shape[1:], F32)
+
+    sum0 = lambda t: jnp.sum(t, axis=0, keepdims=True)
+    w, bias = w_ref[c], b_ref[c]
+
+    def chunk(k, carry):
+        i = n - 1 - k
+        r0 = pl.multiple_of(i * sub, sub)
+        x = x_ref[pl.ds(r0, sub), :].astype(F32)
+        g = g_ref[pl.ds(r0, sub), :].astype(F32)
+        # the 8 rows before the chunk: the tile's own, the tile before's,
+        # nothing at the row's start
+        inside = pl.multiple_of(jnp.maximum(r0 - _GC_HEAD, 0), _GC_HEAD)
+        prev = jnp.where(i > 0, x_ref[pl.ds(inside, _GC_HEAD), :],
+                         xb_ref[...]).astype(F32)[_GC_HEAD - 8:]
+        prev = jnp.where((i == 0) & (tile == 0), 0.0, prev)
+
+        def run(keep):
+            nxt, dw, db = carry
+            acc, back = _cc_taps(prev, x, w, bias, keep)
+            sig = jax.nn.sigmoid(acc)
+            ga = g * sig * (1.0 + acc * (1.0 - sig))
+            dx, mine = 0.0, []
+            for j in range(K - 1):  # ray-tpu: noqa[RT506]
+                # the taps read the other way: token t's input reaches
+                # t + d, where that token lies d tokens into t's document
+                d = K - 1 - j
+                there = ga if keep is None else jnp.where(keep[j], ga, 0.0)
+                dx = dx + pltpu.roll(jnp.concatenate(
+                    [there, nxt[j]], axis=0), sub + 8 - d, 0)[:sub] * w[j]
+                mine.append(there[:8])
+            dx_ref[pl.ds(r0, sub), :] = (dx + ga * w[K - 1]).astype(
+                dx_ref.dtype)
+            sums = [sum0(sh * ga) for sh in back] + [sum0(x * ga)]
+            return (tuple(mine), dw + jnp.concatenate(sums, axis=0),
+                    db + sum0(ga))
+
+        if not ids:
+            return run(None)
+        return jax.lax.cond(
+            cut_ref[r, tile * n + i] != 0,
+            lambda: run(_cc_keeps(pos_refs[0], r0, sub, lanes, K)),
+            lambda: run(None))
+
+    nxt, dw, db = jax.lax.fori_loop(0, n, chunk, (
+        tuple(after[c, j] for j in range(K - 1)),
+        jnp.zeros((K, lanes), F32), jnp.zeros((1, lanes), F32)))
+    for j in range(K - 1):  # ray-tpu: noqa[RT506]
+        after[c, j] = nxt[j]
+    dw_ref[c] += dw
+    db_ref[c] += db
+
+
+#: tokens of a forward grid step of the pair (the backward's: half): a step
+#: costs 0.35 us beside its work
+_CC_ROWS = 2048
+#: numbers of a chunk, what a tile is worked through at a time (64 tokens of
+#: 512 channels: 32 vector registers an array)
+_CC_CHUNK = 64 * 512
+
+
+def _cc_tile(S: int, lo: int, width: int, backward: bool):
+    """(tokens, channels) of a grid step on ``width`` channels that start at
+    column ``lo`` of their array, or None where the shapes do not tile: a
+    row whole tiles of ``_CC_ROWS`` tokens, the channels whole blocks of
+    lanes from column 0 on."""
+    lanes = next((n for n in (_GC_LANES, 256, 128)
+                  if width % n == 0 and lo % n == 0), None)
+    if lanes is None or S % _CC_ROWS:
+        return None
+    return _CC_ROWS // 2 if backward else _CC_ROWS, lanes
+
+
+@functools.partial(jax.jit, static_argnames=("backward", "lo", "interpret"))
+def _cc_call(backward: bool, c, lo: int, w, b, since, g, interpret: bool):
+    """One kernel call on the columns ``lo : lo + w.shape[1]`` of c [R, S,
+    F], read where they lie: the forward's result [R, S, width], or with
+    the cotangent ``g`` of it (dc [R, S, width], dw float32 [K, width], db
+    float32 [width]).  ``since``: ``_since_start`` [R, S], or None.  One
+    traced body for every call site of a shape: a model's mixers trace and
+    lower each kernel once (``ops/kda._kda``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from .attention import LANES
+    R, S, _ = c.shape
+    K, width = w.shape
+    fits = _cc_tile(S, lo, width, backward)
+    if fits is None:
+        raise ValueError(
+            f"causal_conv's kernels do not tile {width} channels from column "
+            f"{lo} of rows of {S} tokens: whole tiles of {_CC_ROWS} tokens "
+            "and of 128 lanes, or impl='xla'")
+    rows, lanes = fits
+    sub = min(_CC_CHUNK // lanes, rows)
+    nC, nS, off, step = width // lanes, S // rows, lo // lanes, \
+        rows // _GC_HEAD
+    # A column's taps and bias: one block, the whole array, indexed by the
+    # column inside the kernel (no copy a grid step).
+    by_column = lambda a: jnp.moveaxis(
+        a.astype(F32).reshape(-1, nC, lanes), 1, 0)
+    held = lambda n: pl.BlockSpec((nC, n, lanes), lambda r, s, c, *_: (0, 0, 0))
+    # the backward walks a row's tiles last to first
+    at = (lambda s: nS - 1 - s) if backward else (lambda s: s)
+    tile = lambda o: pl.BlockSpec((None, rows, lanes),
+                                  lambda r, s, c, *_: (r, at(s), o + c))
+    like = jax.ShapeDtypeStruct((R, S, width), c.dtype)
+    operands, in_specs = [c], [tile(off)]
+    if backward:
+        before = pl.BlockSpec((None, _GC_HEAD, lanes), lambda r, s, c, *_: (
+            r, jnp.maximum(at(s) * step - 1, 0), off + c))
+        operands += [c, g]
+        in_specs += [before, tile(0)]
+    operands += [by_column(w), by_column(b)]
+    in_specs += [held(K), held(1)]
+    if since is not None:
+        # What a tile's tokens say of their documents, along 128 lanes, and
+        # (by scalar prefetch) a flag a chunk: whether a document starts in
+        # it.
+        operands += [jnp.broadcast_to(since[..., None],
+                                      since.shape + (LANES,))]
+        in_specs += [pl.BlockSpec((None, rows, LANES),
+                                  lambda r, s, c, *_: (r, at(s), 0))]
+        operands.insert(0, jnp.any(since.reshape(R, S // sub, sub) < K - 1,
+                                   axis=2).astype(jnp.int32))
+    if backward:
+        sums = lambda n: jax.ShapeDtypeStruct((nC, n, lanes), F32)
+        out_specs, out_shape = [tile(0), held(K), held(1)], [
+            like, sums(K), sums(1)]
+    else:
+        out_specs, out_shape = tile(0), like
+    out = pl.pallas_call(
+        functools.partial(_cc_bwd_kernel if backward else _cc_fwd_kernel,
+                          ids=since is not None, sub=sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(since is not None), grid=(R, nS, nC),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM(
+                (nC, K - 1, 8, lanes) if backward else (nC, 8, lanes), F32)]),
+        out_shape=out_shape, interpret=interpret,
+        name="ssm_conv_bwd" if backward else "ssm_conv_fwd",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3)}),
+    )(*operands)
+    if not backward:
+        return out
+    flat = lambda a: jnp.moveaxis(a, 0, 1).reshape(-1, width)
+    return out[0], flat(out[1]), flat(out[2])[0]
+
+
+def _columns(start: int, widths):
+    """(first column in the array, its columns among the taps') of every
+    part of the convolution's channels."""
+    out, at = [], 0
+    for n in widths:
+        out.append((start + at, slice(at, at + n)))
+        at += n
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _conv(c, w, b, segment_ids, start, widths, impl):
+    """``causal_conv`` on the columns ``start : start + Ch`` of c, the
+    result in parts of ``widths`` channels: a tuple of [B, S, width]."""
+    K, Ch = w.shape
+    with jax.named_scope("block/ssm/conv"):
+        if impl == "xla":
+            y = _conv_xla(c[..., start:start + Ch], w, b, segment_ids)
+            return tuple(y[..., at] for _, at in _columns(start, widths))
+        pos = None if segment_ids is None else _since_start(segment_ids,
+                                                             K)
+        return tuple(_cc_call(False, c, lo, w[:, at], b[at], pos, None,
+                              impl == "kernel_interpret")
+                     for lo, at in _columns(start, widths))
+
+
+def _conv_fwd(c, w, b, segment_ids, start, widths, impl):
+    return (_conv(c, w, b, segment_ids, start, widths, impl),
+            (c, w, b, segment_ids))
+
+
+def _conv_bwd(start, widths, impl, res, gs):
+    c, w, b, segment_ids = res
+    K, Ch = w.shape
+    with jax.named_scope("block/ssm/conv"):
+        if impl == "xla":
+            dc, dw, db = _conv_xla_bwd(
+                c[..., start:start + Ch], w, b, segment_ids,
+                jnp.concatenate(gs, axis=-1))
+        else:
+            pos = None if segment_ids is None else _since_start(
+                segment_ids, K)
+            parts = [_cc_call(True, c, lo, w[:, at], b[at], pos, g,
+                              impl == "kernel_interpret")
+                     for (lo, at), g in zip(_columns(start, widths), gs)]
+            dc, dw, db = (jnp.concatenate(a, axis=-1) for a in zip(*parts))
+        if c.shape[-1] > Ch:    # the columns the convolution does not read
+            dc = jnp.pad(dc, ((0, 0), (0, 0),
+                              (start, c.shape[-1] - start - Ch)))
+        return dc, dw.astype(w.dtype), db.astype(b.dtype), None
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def causal_conv(c, w, b, segment_ids=None, *, start: int = 0, split=None,
+                impl=None):
+    """``silu(b + sum_j w[j] * c[t - (K - 1) + j])`` with ``c[s] = 0`` before
+    the row's start: c [B, S, Ch], w [K, Ch] (tap K - 1 reads the token
+    itself), b [Ch] -> [B, S, Ch] in c's dtype.  Depthwise: a channel reads
+    its own past and nothing else; a row reads nothing of another row, and
+    with ``segment_ids`` [B, S] (``documents``) a token nothing of another
+    document: ``c[s] = 0`` before its document's start.  The taps, their
+    sum, the silu and the gradients' sums are float32, rounded once.
+
+    ``start``: c is wider than the convolution, which reads its columns
+    ``start : start + Ch`` where they lie (a mixer's projection writes the
+    gate, these and the time step side by side); ``split`` (widths that add
+    up to Ch): the result in that many arrays, as the scan reads them.  The
+    backward is written out: dc, dw and db from one pass over c and the
+    cotangent.
+
+    ``impl``: None, which is the Pallas pair (kernels ``ssm_conv_fwd`` /
+    ``ssm_conv_bwd``, a call a part) on a TPU where a row is whole tiles of
+    ``_CC_ROWS`` tokens and every part whole lane tiles from a column that
+    is one (``_cc_tile``), and ``jnp`` elsewhere: on the CPU, under a mesh,
+    at a row that is not whole tiles (``_conv_xla``, the definition: the
+    pair's forward is equal to it to the last bit, its gradients to an
+    accumulation order); ``"xla"``, ``"kernel"``, ``"kernel_interpret"``
+    (the tests).  A call without ids compiles the kernels without the
+    documents' operand and without a select.  Which form a traced call took
+    is counted in ``ray_tpu_ssm_conv_path_total`` (``path`` kernel / xla,
+    ``taps``, ``segments`` yes / no)."""
+    from .attention import _on_tpu          # at the call: tests steer it
+    S = c.shape[1]
+    K, Ch = w.shape
+    widths = (Ch,) if split is None else tuple(split)
+    if sum(widths) != Ch or start + Ch > c.shape[-1]:
+        raise ValueError(f"parts {widths} at column {start} of "
+                         f"{c.shape[-1]} are not the taps' {Ch} channels")
+    if impl is None:
+        from ..parallel.mesh import get_global_mesh
+        mesh = get_global_mesh()
+        tiles = 2 <= K <= 8 and all(
+            _cc_tile(S, lo, at.stop - at.start, False)
+            for lo, at in _columns(start, widths))
+        impl = "kernel" if (_on_tpu() and tiles and not (
+            mesh is not None and mesh.size > 1)) else "xla"
+    telemetry.inc("ray_tpu_ssm_conv_path_total", tags={
+        "path": "xla" if impl == "xla" else "kernel", "taps": str(K),
+        "segments": "no" if segment_ids is None else "yes"})
+    out = _conv(c, w, b, segment_ids, start, widths, impl)
+    return out[0] if split is None else out
 
 
 def chunk_carry(dt, A, chunk: int, segment_ids=None):

@@ -638,6 +638,15 @@ CATALOG: Dict[str, Dict[str, Any]] = {
                        "(kernel: the Pallas pair, forward and backward; "
                        "xla: jnp with the backward written out) and the "
                        "taps."},
+    "ray_tpu_ssm_conv_path_total": {
+        "type": "counter", "tag_keys": ("path", "taps", "segments"),
+        "description": "A mixer's causal convolutions traced "
+                       "(ops/ssm.causal_conv), by the form they take "
+                       "(kernel: the Pallas pair, forward and backward, on "
+                       "the columns the projection wrote; xla: jnp with "
+                       "the backward written out), the taps, and whether "
+                       "the call had segment ids (yes: a tap reads zero on "
+                       "another document's token)."},
     "ray_tpu_norm_path_total": {
         "type": "counter", "tag_keys": ("path", "rows"),
         "description": "Calls of ops/norms.rms_norm traced, by the path "

@@ -258,6 +258,90 @@ def test_convolution_sees_nothing_after_t_and_nothing_before_the_row():
     assert set(map(tuple, changed)) == {(0, t, 2) for t in (6, 7, 8, 9)}
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_convolution_pair_is_the_jnp_form_without_ids(dtype):
+    """The Pallas pair interpreted against the ``jnp`` form as the mixer
+    calls it: the columns after the gate's of a wider array, X, B and C
+    written apart; two rows (a row reads nothing of another), each one tile
+    of 2,048 tokens forward and two backward, worked through in chunks.  The
+    forward to the last bit, the gradients to an accumulation order."""
+    k = jax.random.split(jax.random.key(11), 4)
+    c = jax.random.normal(k[0], (2, 2048, 512)).astype(dtype)
+    w, b = jax.random.normal(k[1], (4, 384)), jax.random.normal(k[2], (384,))
+    do = jax.random.normal(k[3], (2, 2048, 384))
+
+    @functools.partial(jax.jit, static_argnames="impl")
+    def both(c, w, b, impl):
+        def loss(c, w, b):
+            y = jnp.concatenate(ssm.causal_conv(
+                c, w, b, start=128, split=(128, 128, 128), impl=impl), -1)
+            return jnp.sum(y.astype(jnp.float32) * do), y
+        (_, y), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+            c, w, b)
+        return y, grads
+
+    y, grads = both(c, w, b, "kernel_interpret")
+    want_y, want = both(c, w, b, "xla")
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(want_y, np.float32))
+    for got, to in zip(grads, want):
+        got, to = np.asarray(got, np.float32), np.asarray(to, np.float32)
+        np.testing.assert_allclose(
+            got, to, atol=(1e-2 if dtype == jnp.bfloat16 else 1e-5)
+            * np.abs(to).max())
+    # a row starts from zeros, across tiles too
+    moved = both(c.at[0].add(1.0), w, b, "kernel_interpret")[0]
+    np.testing.assert_array_equal(np.asarray(moved[1], np.float32),
+                                  np.asarray(y[1], np.float32))
+
+
+def test_the_convolution_takes_the_jnp_form_where_a_row_is_not_tiles():
+    """On a TPU a row that is not whole tiles of 2,048 tokens, or parts that
+    are not whole lane tiles, take ``jnp``, and the counter says which."""
+    import importlib
+    from ray_tpu.util import telemetry
+    att = importlib.import_module("ray_tpu.ops.attention")
+    seen = []
+    real, on_tpu = telemetry.inc, att._on_tpu
+    telemetry.inc = lambda name, *a, tags=None, **k: seen.append((name, tags))
+    att._on_tpu = lambda: True
+    try:
+        k = jax.random.split(jax.random.key(3), 3)
+        w, b = jax.random.normal(k[1], (4, 128)), jnp.zeros((128,))
+        ssm.causal_conv(jax.random.normal(k[0], (1, 1536, 128)), w, b)
+        ssm.causal_conv(jax.random.normal(k[0], (1, 2048, 192)), w, b,
+                        jnp.zeros((1, 2048), jnp.int32), start=64)
+    finally:
+        telemetry.inc, att._on_tpu = real, on_tpu
+    assert [s for s in seen if s[0] == "ray_tpu_ssm_conv_path_total"] == [
+        ("ray_tpu_ssm_conv_path_total",
+         {"path": "xla", "taps": "4", "segments": "no"}),
+        ("ray_tpu_ssm_conv_path_total",
+         {"path": "xla", "taps": "4", "segments": "yes"})]
+    # the cells' shapes tile: Granite's parts from column 4,096 of 8,512,
+    # Nemotron's of 10,304
+    tiles = lambda S, widths, backward: [
+        ssm._cc_tile(S, lo, at.stop - at.start, backward)
+        for lo, at in ssm._columns(4096, widths)]
+    assert tiles(32768, (4096, 128, 128), False) == [
+        (2048, 512), (2048, 128), (2048, 128)]
+    assert tiles(8192, (4096, 1024, 1024), True) == [(1024, 512)] * 3
+    assert ssm._cc_tile(1536, 0, 384, False) is None
+
+
+def test_a_forced_kernel_on_a_shape_that_does_not_tile_names_the_shape():
+    w, b = jnp.zeros((4, 128)), jnp.zeros((128,))
+    with pytest.raises(ValueError, match="128 channels from column 0 of "
+                                         "rows of 1536 tokens"):
+        ssm.causal_conv(jnp.zeros((1, 1536, 128)), w, b,
+                        impl="kernel_interpret")
+    with pytest.raises(ValueError, match="column 64"):
+        jax.grad(lambda c: jnp.sum(ssm.causal_conv(
+            c, w, b, start=64, impl="kernel_interpret")))(
+                jnp.zeros((1, 2048, 192)))
+
+
 def test_gated_group_norm_norms_each_group_alone():
     k = jax.random.split(jax.random.key(4), 3)
     y, z = (jax.random.normal(k[i], (2, 7, 12)) for i in (0, 1))

@@ -357,6 +357,16 @@ def test_kda_series_registered():
         assert telemetry.CATALOG[name]["description"].strip(), name
 
 
+def test_ssm_conv_series_registered():
+    """Which form a mixer's convolution took (``ops/ssm.causal_conv``: the
+    Pallas pair against ``jnp``; ``tests/test_nemotron_h.py``,
+    ``tests/test_ops_ssm_segments.py``)."""
+    entry = telemetry.CATALOG["ray_tpu_ssm_conv_path_total"]
+    assert entry["type"] == "counter"
+    assert tuple(entry["tag_keys"]) == ("path", "taps", "segments")
+    assert entry["description"].strip()
+
+
 def test_flash_geometry_counter_says_which_kernels_took_rows():
     """``ray_tpu_flash_step_geometry_total`` carries ``rows="vo"`` where a
     kernel took v and the result in the projections' layout (PR 49), on

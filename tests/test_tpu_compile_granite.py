@@ -32,7 +32,8 @@ def granite_step(topo):
 def test_granite_train_step_compiles_at_the_cell_sizes(granite_step, capsys):
     """The step compiles for one described v5e chip with the Mosaic kernels
     in it: the scan's pair at the published chunk over slices of the one
-    wide group, under the scope its reader sums, and the three flash
+    wide group, under the scope its reader sums, the convolution's pair
+    (``ssm_conv_fwd`` / ``ssm_conv_bwd``) feeding it, and the three flash
     kernels of a call with documents under names of their own; its memory
     is stated and inside the chip; no kernel states a scoped limit over
     Mosaic's default; the parameters are the configuration's."""
@@ -71,6 +72,25 @@ def test_granite_train_step_compiles_at_the_cell_sizes(granite_step, capsys):
     for c in scans:
         assert "block/ssm/scan" in names[
             c.partition(" = ")[0].lstrip("%")], c[:200]
+    # The convolution is the Pallas pair, a call a part (X, B, C): three
+    # forward, three recomputed and three backward a mixer, under the scope
+    # its reader sums, at Mosaic's own scoped limit (none stated); the
+    # scan's kernels read what the pair wrote, and no ``split`` of the
+    # convolution's result stands between.
+    convs = [c for c in calls if c.lstrip("%").startswith("ssm_conv_")]
+    assert sum("ssm_conv_fwd" in c.partition(" = ")[0] for c in convs) == 54
+    assert sum("ssm_conv_bwd" in c.partition(" = ")[0] for c in convs) == 27
+    for c in convs:
+        assert "block/ssm/conv" in names[
+            c.partition(" = ")[0].lstrip("%")], c[:200]
+        assert re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                          r'"offset":"0","size":"(\d+)"', c) == [
+                              str(16 * 2 ** 20)], c[:300]
+    for c in scans:
+        if "ssd_fwd" in c.partition(" = ")[0]:
+            fed = c.partition("custom-call(")[2].split(", ")[:3]
+            assert all(o.lstrip("%").startswith("ssm_conv_fwd")
+                       for o in fed), c[:300]
     # The one group of 64 heads goes through in 8 slices of 512 channels:
     # a slice's share of dB and dC leaves in float32.
     assert any("f32[1,32768,1024]" in c.partition(" custom-call(")[0]

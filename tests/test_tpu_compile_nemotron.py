@@ -35,9 +35,12 @@ def test_nemotron_train_step_compiles_at_the_cell_sizes(nemotron_step,
                                                         capsys):
     """The step compiles for one described v5e chip with the Mosaic kernels
     in it: the three flash kernels at 16 query heads a key head and the
-    grouped products of un-gated experts (two a pass) and the chunked scan's
-    pair, under the scope its reader sums; its memory is stated; the scopes the readers sum are in its
+    grouped products of un-gated experts (two a pass), the chunked scan's
+    pair and the convolution's, under the scopes their readers sum; its
+    memory is stated; the scopes the readers sum are in its
     text; the whole share's count is the configuration's."""
+    import re
+
     import jax
     from benchmark import scopes
     from benchmark.archs import nemotron_h as arch
@@ -75,6 +78,17 @@ def test_nemotron_train_step_compiles_at_the_cell_sizes(nemotron_step,
         if c.startswith("%ssd_") or c.startswith("ssd_"):
             assert "block/ssm/scan" in names[
                 c.partition(" = ")[0].lstrip("%")], c[:200]
+    # The mixers' convolution is the Pallas pair under the scope its reader
+    # sums, at Mosaic's own scoped limit (none stated).
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        assert any(kernel in c.partition(" = ")[0] for c in calls), kernel
+    for c in calls:
+        if c.lstrip("%").startswith("ssm_conv_"):
+            assert "block/ssm/conv" in names[
+                c.partition(" = ")[0].lstrip("%")], c[:200]
+            assert re.findall(
+                r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                r'"offset":"0","size":"(\d+)"', c) == [str(16 * 2 ** 20)]
     # One row of 32 query heads on 2 key heads: four stacks of 8 heads, two
     # behind each key head, and K / V cross HBM 2 heads wide.
     assert any("bf16[4,8,8192,128]" in c and "bf16[2,8192,128]" in c
