@@ -89,6 +89,80 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.quick)
 
 
+# -- the order in which ``--dist loadfile`` hands the files out ------------
+#
+# xdist's own order (``loadscopereorder``: most tests first) ends the run
+# with the files of one to five cases, and those are the heavy ones: the
+# whole-step compiles (``tests/test_tpu_compile_<cell>.py``, 100-200 s in
+# ONE fixture each) and a model's longest cases, six workers in them at
+# once with nothing left to fill the gaps.  The order is the tests' own
+# instead: the files whose seconds are known to be many go out first, a
+# compile and a model's file in turn, and the rest follow by number of
+# cases as before, so that the run ends on small files.  What says that a
+# file is heavy is a fixture its cases ask for: no list of names, no table
+# of seconds, nothing read from the machine, so every worker arrives at the
+# same order and a new ``test_tpu_compile_<cell>.py`` or ``test_<model>.py``
+# falls in its place.
+
+
+#: what a file's cases ask for that says its seconds are many: the described
+#: v5e (a whole step compiled for it) and a process without a mesh (a model
+#: run on one CPU device against its reference)
+_COMPILES, _MODEL = "topo", "no_mesh_left_by_another_file"
+
+
+def _hand_out_order(files):
+    """``{file: (number of cases, the fixtures its cases ask for)}`` -> the
+    files in the order the work queue hands them out: the files that compile
+    for the described v5e and the models' files first and in turn, then the
+    rest; each kind by number of cases, most first, equal counts by name."""
+    most_first = sorted(files, key=lambda f: (-files[f][0], f))
+    compiles = [f for f in most_first if _COMPILES in files[f][1]]
+    models = [f for f in most_first
+              if _MODEL in files[f][1] and f not in compiles]
+    heavy = []
+    while compiles or models:
+        heavy += compiles[:1] + models[:1]
+        del compiles[:1], models[:1]
+    return heavy + [f for f in most_first if f not in heavy]
+
+
+def _file_of(nodeid):
+    return nodeid.split("::", 1)[0]             # ``loadfile``'s unit
+
+
+def _files_of(cases):
+    """``(node id, the fixtures it asks for)`` a case -> what
+    ``_hand_out_order`` takes."""
+    files = {}
+    for nodeid, asks in cases:
+        n, asked = files.get(_file_of(nodeid), (0, frozenset()))
+        files[_file_of(nodeid)] = (n + 1, asked | frozenset(asks))
+    return files
+
+
+class _FilesInOrder:
+    """On an xdist worker, after ``-m`` has deselected: the files in
+    ``_hand_out_order``, the cases of a file in the order they had."""
+
+    @pytest.hookimpl(trylast=True)
+    def pytest_collection_modifyitems(self, items):
+        order = _hand_out_order(_files_of(
+            (item.nodeid, getattr(item, "fixturenames", ()))
+            for item in items))
+        place = {f: i for i, f in enumerate(order)}
+        items.sort(key=lambda item: place[_file_of(item.nodeid)])
+
+
+def pytest_configure(config):
+    if not hasattr(config.option, "loadscopereorder"):
+        return                                  # ``-p no:xdist``: as it was
+    # the controller queues the files in the order the workers collected
+    config.option.loadscopereorder = False
+    if hasattr(config, "workerinput"):
+        config.pluginmanager.register(_FilesInOrder(), "files-in-order")
+
+
 @pytest.fixture(scope="module")
 def ray_start():
     """Module-scoped runtime (reference: conftest ray_start_regular)."""
@@ -113,7 +187,8 @@ def no_mesh_left_by_another_file():
     ran before in the same worker may have left one of several devices,
     which a model that runs on one device refuses by name: the test starts
     without one and hands back what it found (a file of such a model's
-    tests asks for it by ``pytestmark``)."""
+    tests asks for it by ``pytestmark``; ``_hand_out_order`` reads the same
+    request as "a model's file", and hands it out early)."""
     from ray_tpu.parallel.mesh import get_global_mesh, set_global_mesh
     before = get_global_mesh()
     set_global_mesh(None)

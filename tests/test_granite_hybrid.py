@@ -21,6 +21,8 @@ sys.path.insert(0, ROOT)
 from benchmark import reference_granite_hybrid as ref  # noqa: E402
 from benchmark.archs import granitemoehybrid as arch  # noqa: E402
 
+from ssm_segments_cases import _ids  # noqa: E402
+
 pytestmark = pytest.mark.usefixtures("no_mesh_left_by_another_file")
 
 #: documents of a row of 56 at a chunk of 16: boundaries on a chunk's edge
@@ -41,11 +43,6 @@ def _sizes(cfg):
             "residual_multiplier": cfg.residual_multiplier,
             "attention_multiplier": cfg.attention_multiplier,
             "logits_scaling": cfg.logits_scaling}
-
-
-def _ids(lengths, rows=1):
-    return jnp.asarray(np.tile(np.repeat(np.arange(len(lengths)), lengths),
-                               (rows, 1)), jnp.int32)
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,12 +133,22 @@ def test_a_packed_row_is_its_documents_run_alone():
     weight = jax.random.normal(jax.random.key(5), logits.shape)
     packed = jax.jit(jax.grad(lambda p: jnp.sum(granite_hybrid.forward(
         p, tokens, cfg, segment_ids=ids) * weight)))(params)
+
+    @jax.jit
+    def one(p, tokens, weight):
+        """A document's logits and gradients: one program a length (16, 17
+        and 3: the lengths are the case's and stay as they are)."""
+        def loss(p):
+            out = granite_hybrid.forward(p, tokens, cfg)
+            return jnp.sum(out * weight), out
+        (_, out), g = jax.value_and_grad(loss, has_aux=True)(p)
+        return out, g
+
     alone, total, at = [], None, 0
     for n in LENGTHS:
         part = slice(at, at + n)
-        alone.append(granite_hybrid.forward(params, tokens[:, part], cfg))
-        g = jax.grad(lambda p: jnp.sum(granite_hybrid.forward(
-            p, tokens[:, part], cfg) * weight[:, part]))(params)
+        out, g = one(params, tokens[:, part], weight[:, part])
+        alone.append(out)
         total = g if total is None else jax.tree.map(jnp.add, total, g)
         at += n
     np.testing.assert_allclose(logits, jnp.concatenate(alone, 1), rtol=1e-4,

@@ -4,6 +4,7 @@ token-by-token recurrence: forward and five gradients."""
 
 from __future__ import annotations
 
+import functools
 import importlib
 import re
 
@@ -52,6 +53,23 @@ def _close(got, want, tol):
         float(jnp.max(jnp.abs(want))), 1e-30)
 
 
+@functools.lru_cache(maxsize=None)
+def _by_the_recurrence(d, gate):
+    """(inputs, the cotangent, the recurrence's output and five gradients) at
+    heads of ``d`` channels, once a module under one ``jax.jit``: the two
+    chunk sizes of a form compare against the same."""
+    args, w = inputs(1, 2, 128, 2, d, d, gate)
+
+    def loss(*a):
+        out = recurrence(*a, d ** -0.5)
+        return jnp.sum(out * w), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), dwant = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    return args, w, want, dwant
+
+
 @pytest.mark.parametrize("gate", ["random", "bound", "none"])
 @pytest.mark.parametrize("form", ["xla16", "xla64", "kernels64", "kernels128"])
 def test_forward_and_five_gradients_against_the_recurrence(form, gate):
@@ -60,15 +78,16 @@ def test_forward_and_five_gradients_against_the_recurrence(form, gate):
     memory) and in between; the kernels take heads of 128 channels."""
     kernels = form.startswith("kernels")
     C, d = int(re.sub(r"\D", "", form)), 128 if kernels else 32
-    args, w = inputs(1, 2, 128, 2, d, d, gate)
+    args, w, want, dwant = _by_the_recurrence(d, gate)
+
+    def loss(*a):
+        out = kda(*a, C, interpret=kernels)
+        return jnp.sum(out * w), out
+
     with jax.default_matmul_precision("highest"):
-        want = recurrence(*args, d ** -0.5)
-        got = kda(*args, C, interpret=kernels)
-        assert _close(got, want, 1e-5)
-        dwant = jax.grad(lambda *a: jnp.sum(recurrence(*a, d ** -0.5) * w),
-                         argnums=(0, 1, 2, 3, 4))(*args)
-        dgot = jax.grad(lambda *a: jnp.sum(kda(*a, C, interpret=kernels) * w),
-                        argnums=(0, 1, 2, 3, 4))(*args)
+        (_, got), dgot = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    assert _close(got, want, 1e-5)
     # g's gradient at the bound is six orders under q's: judged looser
     for name, a, b in zip("qkvgb", dgot, dwant):
         assert _close(a, b, 3e-4 if name == "g" else 2e-5), name
@@ -214,6 +233,22 @@ def _a_bf16_step(got, want):
         and np.count_nonzero(off) <= got.size // 1000
 
 
+@functools.partial(jax.jit, static_argnames=("heads", "kernels"))
+def _pass_and_its_pull(args, cts, heads, kernels):
+    """The pass before the scan and its five gradients through ``cts``: one
+    program a dtype of the taps and a path for every gate (what differs
+    between the gates is the operands' values), the same jit on both sides
+    so that the forward's bits are the paths' own."""
+    B, S, F = args[1].shape
+    if kernels:
+        fn = lambda *a: K._inputs(*a, heads=heads, bound=-5.0, interpret=True)
+    else:
+        fn = lambda *a: tuple(x.reshape(B, S, F) for x in K._inputs_xla(
+            *a, heads, -5.0))
+    out, pull = jax.vjp(fn, *args)
+    return out, pull(cts)
+
+
 @pytest.mark.parametrize("gate,w_dtype", [
     ("random", jnp.float32), ("bound", jnp.float32), ("none", jnp.float32),
     ("random", jnp.bfloat16)])
@@ -231,11 +266,8 @@ def test_the_pass_before_the_scan_is_the_jnp_form(gate, w_dtype):
     args, cts = mixer_inputs(11, B, S, H, D, gate, w_dtype)
     assert K._in_tile(S, H, D, 4, False) == (512, 384)
     assert K._in_tile(S, H, D, 4, True) == (256, 384)
-    flat = lambda t: tuple(x.reshape(B, S, H * D) for x in t)
-    want, pull_want = jax.vjp(jax.jit(lambda *a: flat(K._inputs_xla(
-        *a, H, -5.0))), *args)
-    got, pull_got = jax.vjp(lambda *a: K._inputs(
-        *a, heads=H, bound=-5.0, interpret=True), *args)
+    want, dwant = _pass_and_its_pull(args, cts, H, False)
+    got, dgot = _pass_and_its_pull(args, cts, H, True)
     for name, x, y in zip("qkvg", got, want):
         assert x.dtype == y.dtype and x.shape == y.shape, name
         np.testing.assert_array_equal(np.asarray(x, np.float32),
@@ -243,7 +275,6 @@ def test_the_pass_before_the_scan_is_the_jnp_form(gate, w_dtype):
     if gate != "random":
         g = np.asarray(got[3])
         assert (g.max() < -4.99) if gate == "bound" else (g.min() > -1e-2)
-    dgot, dwant = jax.jit(pull_got)(cts), jax.jit(pull_want)(cts)
     for name, x, y in zip(("qkv", "a", "conv_w", "A_log", "dt_bias"),
                           dgot, dwant):
         assert x.dtype == y.dtype and x.shape == y.shape, name

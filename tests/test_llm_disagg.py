@@ -29,6 +29,10 @@ def params():
     return init_params(CFG, jax.random.key(0))
 
 
+#: the gold's forward, one program a length of the prefix (eagerly every
+#: operation of the model is a program of its own for every length)
+_forward = jax.jit(lambda params, toks: forward(params, toks, CFG))
+
 _GOLD: dict = {}
 
 
@@ -42,7 +46,7 @@ def naive_greedy(params, prompt, max_new):
     toks = list(prompt)
     out = []
     for _ in range(max_new):
-        logits = forward(params, jnp.asarray([toks]), CFG)
+        logits = _forward(params, jnp.asarray([toks]))
         nxt = int(jnp.argmax(logits[0, len(toks) - 1]))
         out.append(nxt)
         toks.append(nxt)

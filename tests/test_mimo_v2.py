@@ -222,8 +222,8 @@ def test_the_four_head_shares_add_up_to_the_uncut_layer(kind, layer):
     s = _sizes(cfg)
     x = jax.random.normal(jax.random.key(3), (2, 40, cfg.hidden))
     with jax.default_matmul_precision("highest"):
-        want = ref.attention_operator(x, params["layers"][layer], s,
-                                      LETTER[kind])
+        want = jax.jit(lambda x, w: ref.attention_operator(
+            x, w, s, LETTER[kind]))(x, params["layers"][layer])
     tables = {k: rope_lane_tables(cfg.rotary_dim, cfg.max_seq_len, theta)
               for k, theta in ((FULL, cfg.rope_theta),
                                (WINDOW, cfg.swa_rope_theta))}
@@ -234,14 +234,15 @@ def test_the_four_head_shares_add_up_to_the_uncut_layer(kind, layer):
             (2, 1, share) if kind == WINDOW else (2, 1, share // 2))
         w = mimo_v2.take_share(params, held)["layers"][layer]
         assert w["wq"].shape[1] == 2 and w["wk"].shape[1] == 1
-        part, mass = mimo_v2._attn(held, kind, tables, x, w)
+        part, mass = jax.jit(lambda x, w: mimo_v2._attn(
+            held, kind, tables, x, w))(x, w)
         parts.append(part)
         masses.append(mass)
     np.testing.assert_allclose(sum(parts), want, atol=2e-5, rtol=2e-4)
     assert float(jnp.abs(parts[0] - want).max()) > 1e-2
     if kind == WINDOW:      # the shares' masses are means over their heads
-        _, whole = mimo_v2._attn(cfg, kind, tables, x,
-                                 params["layers"][layer])
+        _, whole = jax.jit(lambda x, w: mimo_v2._attn(
+            cfg, kind, tables, x, w))(x, params["layers"][layer])
         np.testing.assert_allclose(np.mean(masses), whole, rtol=1e-5)
         assert 0.02 < float(whole) < 0.98
     else:

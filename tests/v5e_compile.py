@@ -156,7 +156,14 @@ def _cell_step(topo, arch, config_file, seq, batch_keys=("tokens",
     """A cell's train step as the benchmark builds it, compiled for one
     described v5e chip with the platform's choices made as on the chip:
     {"compiled", "text", "params", "state", "config", "sizes"}.
-    ``batch_keys``: the [rows, seq] int32 arrays of a batch."""
+    ``batch_keys``: the [rows, seq] int32 arrays of a batch.
+
+    ``topo`` is what ``tests/conftest.py``'s order of the files keys on: a
+    file one of whose cases asks for that fixture (by way of a module's own
+    fixture, as ``granite_step(topo)``) is handed out early, so that the
+    150-300 s of this call do not end the run.  A compile file that builds
+    its step another way still takes ``topo``, or it goes out last with
+    the files of few cases (``tests/test_conftest.py`` fails on it)."""
     import importlib
     import json
     import os
